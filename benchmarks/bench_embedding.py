@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_shuffling_data_loader_tpu.ops import embedding
+from ray_shuffling_data_loader_tpu.utils.compile_cache import (
+    enable_compile_cache)
 
 VOCABS = [64, 512, 2048, 8192, 131072, 1048576]
 MODES = ["take", "one_hot", "pallas"]
@@ -43,6 +45,7 @@ def main() -> None:
     parser.add_argument("--embed", type=int, default=32)
     parser.add_argument("--iters", type=int, default=20)
     args = parser.parse_args()
+    enable_compile_cache()
 
     print(f"backend={jax.default_backend()} batch={args.batch} "
           f"embed={args.embed}")

@@ -274,4 +274,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from ray_shuffling_data_loader_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     main()

@@ -1,0 +1,394 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once through the entry points a user calls, at the
+full width of one model the repo supports, and checks what comes out:
+
+1. kernels: the Pallas embedding gather (bit-equal to XLA ``take`` at the
+   largest DLRM table) and flash attention forward + backward (against
+   the plain-XLA reference, at an aligned and at a padded sequence
+   length), compiled by Mosaic — not interpreted;
+2. loader -> device feed -> train step: seeded DLRM Parquet
+   (``data_generation.generate_data``) through ``JaxShufflingDataset`` at
+   library defaults into ``parallel.trainer.SpmdTrainer`` over
+   ``parallel.mesh.make_mesh()``, DLRM at the MLPerf widths
+   (``models.dlrm.mlperf_config()``), two short epochs. Every row must
+   arrive exactly once per epoch, on the accelerator, over the bulk
+   device path, with a finite falling loss and one compilation of the step.
+
+It refuses to run unless JAX reports a TPU: no CPU run, no flag to
+continue. One process holds the chip; the loader's pool workers are
+pinned to the CPU. On success the last line of stdout is
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+and the exit code is 0; any failed check raises and the exit code is 1.
+
+    python3 chip_smoke.py        # from the root of a checkout, on the chip
+
+Module import stays free of jax on purpose: the loader's process pool
+spawns, and every worker re-imports this file as its ``__main__``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import faulthandler
+import json
+import os
+import shutil
+import statistics
+import sys
+import tempfile
+import timeit
+from typing import Any, List, Optional, Sequence, Tuple
+
+# A hang must fail inside the driver's 1200 s limit instead of burning
+# chip budget: at the deadline every thread's stack goes to stderr and
+# the process exits non-zero.
+DEADLINE_S = 1100
+
+
+class SmokeFailure(AssertionError):
+    """A chip_smoke check did not hold."""
+
+
+def _check(condition: bool, message: str) -> None:
+    if not condition:
+        raise SmokeFailure(message)
+
+
+def _info(message: str) -> None:
+    print(f"# {message}", flush=True)
+
+
+@dataclasses.dataclass(frozen=True)
+class SmokeSize:
+    """What one run covers. ``full_size`` is what the chip runs;
+    ``tiny_size`` is the same body cut down for the CPU test."""
+
+    model: Any                        # models.dlrm.DLRMConfig
+    batch_per_device: int
+    steps_per_epoch: int
+    num_files: int
+    learning_rate: float
+    gather_table: Tuple[int, int]     # (vocab, embed_dim)
+    gather_batch: int
+    attention_shape: Tuple[int, int, int]  # (batch, heads, head_dim)
+    attention_seqs: Tuple[int, int]   # (aligned, padded)
+    epochs: int = 2
+
+
+def full_size() -> SmokeSize:
+    from ray_shuffling_data_loader_tpu.models import dlrm
+    cfg = dlrm.mlperf_config()
+    return SmokeSize(
+        model=cfg, batch_per_device=2048, steps_per_epoch=32, num_files=8,
+        learning_rate=1e-3,
+        gather_table=(max(cfg.vocab_sizes), cfg.embed_dim),
+        gather_batch=2048, attention_shape=(2, 4, 64),
+        attention_seqs=(2048, 1000))
+
+
+def tiny_size() -> SmokeSize:
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.models import dlrm
+    cfg = dlrm.DLRMConfig(
+        vocab_sizes=tuple(min(v, 512) for v in dlrm.DATA_SPEC_VOCAB_SIZES),
+        embed_dim=8, top_hidden=(32, 16), compute_dtype=jnp.float32)
+    return SmokeSize(
+        model=cfg, batch_per_device=16, steps_per_epoch=8, num_files=2,
+        learning_rate=1e-2, gather_table=(4096, 128), gather_batch=64,
+        attention_shape=(1, 2, 32), attention_seqs=(256, 200))
+
+
+# -- kernels ---------------------------------------------------------------
+
+
+def _mosaic_calls(jitted, *args) -> int:
+    """How many Mosaic kernels the lowered program holds."""
+    return jitted.lower(*args).as_text().count("tpu_custom_call")
+
+
+def kernels_phase(size: SmokeSize, interpret: bool) -> None:
+    """Gather and flash attention against their references, compiled for
+    the device in use (``interpret`` only where there is no chip)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import bench_attention
+    from ray_shuffling_data_loader_tpu.ops import embedding
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+
+    vocab, embed = size.gather_table
+    table = jax.random.normal(jax.random.key(1), (vocab, embed), jnp.float32)
+    indices = jax.random.randint(jax.random.key(2), (size.gather_batch,),
+                                 0, vocab, jnp.int32)
+    gather = jax.jit(lambda t, i: embedding.lookup(t, i, jnp.float32,
+                                                   mode="pallas"))
+    take = jax.jit(lambda t, i: embedding.lookup(t, i, jnp.float32,
+                                                 mode="take"))
+    if not interpret:
+        _check(_mosaic_calls(gather, table, indices) == 1,
+               "the Pallas gather did not lower to a Mosaic kernel")
+    _check(np.array_equal(np.asarray(gather(table, indices)),
+                          np.asarray(take(table, indices))),
+           f"Pallas gather differs from XLA take at {vocab}x{embed}")
+    _info(f"kernels: gather {vocab}x{embed} batch {size.gather_batch} "
+          f"bit-equal to take ({'interpreted' if interpret else 'Mosaic'})")
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, interpret=interpret)
+
+    b, h, d = size.attention_shape
+    for seq in size.attention_seqs:
+        if not interpret:
+            probe = jnp.zeros((b, h, seq, d), jnp.bfloat16)
+            _check(_mosaic_calls(jax.jit(flash), probe, probe, probe) == 1,
+                   f"flash attention at S={seq} did not lower to a Mosaic "
+                   "kernel")
+        # Asserts forward and backward against the plain-XLA reference.
+        bench_attention.check_correctness(flash, seq, b, h, d)
+
+
+# -- loader -> device feed -> train step -------------------------------------
+
+_MIX = 0x9E3779B97F4A7C15
+
+
+def _rows_digest(columns: Sequence[Any], labels: Any) -> int:
+    """Order-independent digest of a set of rows: one 64-bit hash per row
+    over all its columns, summed modulo 2**64. Equal for two row sets
+    exactly when (up to hash collisions) they hold the same rows the same
+    number of times, whatever the order and the batch boundaries."""
+    import numpy as np
+    mix = np.uint64(_MIX)
+    with np.errstate(over="ignore"):
+        h = np.asarray(labels, np.float32).reshape(-1).view(
+            np.uint32).astype(np.uint64)
+        for col in columns:
+            values = np.asarray(col).reshape(-1).astype(np.int64)
+            h = (h ^ values.view(np.uint64)) * mix
+            h ^= h >> np.uint64(29)
+        return int(h.sum(dtype=np.uint64))
+
+
+def _files_digest(filenames: Sequence[str]) -> Tuple[int, int]:
+    """(row count, digest) of what the Parquet files hold."""
+    import numpy as np
+    import pyarrow.parquet as pq
+
+    from ray_shuffling_data_loader_tpu import data_generation as dg
+    rows, digest = 0, 0
+    for filename in filenames:
+        table = pq.read_table(
+            filename, columns=list(dg.FEATURE_COLUMNS) + [dg.LABEL_COLUMN])
+        digest += _rows_digest(
+            [table.column(c).to_numpy() for c in dg.FEATURE_COLUMNS],
+            table.column(dg.LABEL_COLUMN).to_numpy().astype(np.float32))
+        rows += table.num_rows
+    return rows, digest % 2**64
+
+
+def train_phase(size: SmokeSize, data_dir: str) -> dict:
+    """Generate data, feed it through the loader to the devices, train.
+    Returns the numbers printed as information."""
+    import jax
+    import optax
+
+    from ray_shuffling_data_loader_tpu import data_generation as dg
+    from ray_shuffling_data_loader_tpu import executor
+    from ray_shuffling_data_loader_tpu import stats as rsdl_stats
+    from ray_shuffling_data_loader_tpu.jax_dataset import JaxShufflingDataset
+    from ray_shuffling_data_loader_tpu.models import dlrm
+    from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
+    from ray_shuffling_data_loader_tpu.parallel.trainer import SpmdTrainer
+    from ray_shuffling_data_loader_tpu.utils.config import (
+        default_num_reducers)
+    from ray_shuffling_data_loader_tpu.workloads.dlrm_criteo import dlrm_spec
+
+    devices = jax.devices()
+    n = len(devices)
+    platform = devices[0].platform
+    batch = size.batch_per_device * n
+    rows = batch * size.steps_per_epoch
+    cfg = size.model
+
+    filenames, _ = dg.generate_data(rows, size.num_files, 2, 0.0, data_dir,
+                                    seed=0)
+    want_rows, want_digest = _files_digest(filenames)
+    _check(want_rows == rows, f"generated {want_rows} rows, asked {rows}")
+
+    mesh = mesh_mod.make_mesh()
+    trainer = SpmdTrainer(
+        mesh, lambda p, cols, y: dlrm.loss_fn(cfg, p, None, cols, y, mesh),
+        dlrm.init(cfg, jax.random.key(0)), optax.adam(size.learning_rate))
+
+    wd_before = rsdl_stats.watchdog_stats().snapshot()
+    # Library defaults throughout (device_rebatch="auto", executor backend
+    # auto). Only the reducer count is capped, as bench.py caps it: the
+    # host-core default would cut this small corpus into reducer outputs
+    # shorter than a few batches, and the bulk path moves batch-aligned
+    # spans of whole reducer outputs.
+    ds = JaxShufflingDataset(
+        filenames, num_epochs=size.epochs, num_trainers=1, batch_size=batch,
+        rank=0, seed=0, queue_name="chip-smoke",
+        num_reducers=max(1, min(default_num_reducers(1),
+                                size.steps_per_epoch // 4)),
+        # As examples/jax_train_shuffle.py: one device needs no sharded
+        # transfers, several get every batch split over the data axis.
+        mesh=mesh if n > 1 else None, **dlrm_spec())
+    losses: List[float] = []
+    step_s: List[float] = []
+    lowered_kernels: Optional[int] = None
+    try:
+        _check(ds.device_rebatch == (platform != "cpu"),
+               f"device_rebatch='auto' resolved to {ds.device_rebatch} on "
+               f"platform {platform!r}")
+        run_start = timeit.default_timer()
+        for epoch in range(size.epochs):
+            ds.set_epoch(epoch)
+            got_rows, got_digest = 0, 0
+            for features, label in ds:
+                for array in (*features, label):
+                    _check(isinstance(array, jax.Array)
+                           and {d.platform for d in array.devices()}
+                           == {platform},
+                           f"a batch array is not a jax.Array on "
+                           f"{platform}: {type(array)}")
+                    _check(len(array.devices()) == n
+                           and array.sharding.shard_shape(array.shape)[0]
+                           == size.batch_per_device,
+                           f"batch array {array.shape} is not split "
+                           f"{size.batch_per_device} rows per device over "
+                           f"{n} device(s): {array.sharding}")
+                if lowered_kernels is None:
+                    lowered_kernels = _mosaic_calls(
+                        trainer.step_fn, trainer.params, trainer.opt_state,
+                        features, label)
+                t0 = timeit.default_timer()
+                # The loss comes to the host every step: the fetch cannot
+                # finish before the step has, so this times the step and
+                # not its enqueue.
+                losses.append(float(trainer.train_step(features, label)))
+                step_s.append(timeit.default_timer() - t0)
+                got_rows += label.shape[0]
+                got_digest += _rows_digest(features, label)
+            _check(got_rows == rows and got_digest % 2**64 == want_digest,
+                   f"epoch {epoch} delivered {got_rows} rows (digest "
+                   f"{got_digest % 2**64:#x}); the files hold {rows} "
+                   f"(digest {want_digest:#x})")
+        wall_s = timeit.default_timer() - run_start
+        _check(ds.device_rebatch == (platform != "cpu")
+               and not ds.fallback_engaged,
+               "the bulk device path did not stay on "
+               f"(fallback_engaged={ds.fallback_engaged})")
+    finally:
+        ds.close()
+    wd_after = rsdl_stats.watchdog_stats().snapshot()
+    watchdog_events = (wd_after["watchdog_events"]
+                       - wd_before["watchdog_events"])
+    _check(watchdog_events == 0, f"{watchdog_events} watchdog event(s)")
+
+    steps = len(losses)
+    _check(steps == size.epochs * size.steps_per_epoch,
+           f"ran {steps} steps, expected "
+           f"{size.epochs * size.steps_per_epoch}")
+    _check(all(loss == loss and abs(loss) != float("inf")
+               for loss in losses), f"non-finite loss in {losses}")
+    head = statistics.fmean(losses[:max(1, steps // 8)])
+    tail = statistics.fmean(losses[-max(1, steps // 8):])
+    _check(tail < head, f"loss did not fall: first steps {head:.4f}, "
+                        f"last steps {tail:.4f}")
+    compiles = trainer.step_fn._cache_size()
+    _check(compiles == 1, f"the train step compiled {compiles} times")
+    on_chip_tables = sum(
+        1 for v in cfg.vocab_sizes
+        if v > 2048 and cfg.embed_dim % 128 == 0) if platform == "tpu" else 0
+    _check(lowered_kernels == on_chip_tables,
+           f"the lowered step holds {lowered_kernels} Mosaic gather(s), "
+           f"expected {on_chip_tables}")
+
+    pool = executor.last_worker_pool()
+    peak = (devices[0].memory_stats() or {}).get("peak_bytes_in_use")
+    return {
+        "devices": n, "batch": batch, "rows_per_epoch": rows, "steps": steps,
+        "first_step_s": round(step_s[0], 3),
+        "median_step_ms": round(
+            1e3 * statistics.median(step_s[1:] or step_s), 3),
+        "rows_per_s": round(rows * size.epochs / wall_s, 1),
+        "loss_first": round(head, 4), "loss_last": round(tail, 4),
+        "step_compiles": compiles, "step_mosaic_gathers": lowered_kernels,
+        "executor_backend": pool["backend"],
+        "executor_workers": pool["workers"],
+        "device_rebatch": ds.device_rebatch,
+        "batch_wait": {k: round(v, 4) if isinstance(v, float) else v
+                       for k, v in ds.batch_wait_stats.summary().items()},
+        "peak_bytes_in_use": peak,
+    }
+
+
+def run(size: SmokeSize, interpret: bool) -> None:
+    """Every phase, in one process; raises on the first failed check."""
+    start = timeit.default_timer()
+    kernels_phase(size, interpret)
+    _info(f"kernels phase: {timeit.default_timer() - start:.1f}s")
+    start = timeit.default_timer()
+    data_dir = tempfile.mkdtemp(prefix="chip-smoke-data-")
+    try:
+        report = train_phase(size, data_dir)
+    finally:
+        shutil.rmtree(data_dir, ignore_errors=True)
+    _info(f"train phase: {timeit.default_timer() - start:.1f}s "
+          f"{json.dumps(report)}")
+
+
+def _version(dist: str) -> str:
+    import importlib.metadata
+    try:
+        return importlib.metadata.version(dist)
+    except importlib.metadata.PackageNotFoundError:
+        return "not installed"
+
+
+def main() -> int:
+    start = timeit.default_timer()
+    import jax
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: JAX reports platform {device['platform']!r}, "
+              "not 'tpu'; refusing to run", file=sys.stderr)
+        return 1
+    faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+
+    from ray_shuffling_data_loader_tpu import native
+    from ray_shuffling_data_loader_tpu import procpool
+    from ray_shuffling_data_loader_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    cache_dir = enable_compile_cache()
+
+    def cache_entries() -> int:
+        return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+    _info(f"device: {json.dumps(device)}")
+    _info(f"versions: jax {jax.__version__}, jaxlib {_version('jaxlib')}, "
+          f"libtpu {_version('libtpu')}, python "
+          f"{sys.version.split()[0]}")
+    _info(f"host: {os.cpu_count()} CPUs, executor backend "
+          f"{procpool.resolve_backend()}")
+    _info(f"native.available(): {native.available()}")
+    _info(f"compile cache: {cache_dir} ({cache_entries()} entries at start)")
+    _check(native.available(), "the native library is switched off")
+
+    run(full_size(), interpret=False)
+
+    _info(f"compile cache: {cache_entries()} entries at end")
+    _info(f"wall: {timeit.default_timer() - start:.1f}s")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -93,6 +93,9 @@ def main(argv=None):
         jax.config.update("jax_platforms", "cpu")
     else:
         import jax
+    from ray_shuffling_data_loader_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     if args.distributed:
         # On TPU pods initialize() self-configures from the metadata
         # service; elsewhere (the slice launcher's SSH/local fan-out) the
@@ -146,7 +149,7 @@ def main(argv=None):
     if args.mock_train_step_time is None:
         params = dlrm.init(cfg, jax.random.key(args.seed))
         trainer = SpmdTrainer(
-            mesh, lambda p, s, y: dlrm.loss_fn(cfg, p, None, s, y),
+            mesh, lambda p, s, y: dlrm.loss_fn(cfg, p, None, s, y, mesh),
             params, optax.adam(args.learning_rate))
 
     sorted_files = sorted(filenames)

@@ -25,8 +25,11 @@ this machine, no SSH):
 
     RSDL_HOSTS="127.0.0.1:18515,127.0.0.1:18516" \\
     python examples/launch_slice.py --local --out /tmp/slice_stats \\
-        -- --cpu --tiny-model --num-rows 4000 --num-files 2 \\
+        -- --tiny-model --num-rows 4000 --num-files 2 \\
            --num-epochs 2 --batch-size 500
+
+Local mode pins every trainer to the CPU backend itself: a chip belongs
+to one process, so N trainers on one machine cannot share it.
 
 Everything after ``--`` is passed through to jax_train_shuffle.py
 verbatim. ``--distributed`` and ``--stats-dir`` are appended
@@ -139,6 +142,7 @@ def main(argv=None) -> int:
         ]
         if args.local:
             env = dict(os.environ, **env_pairs,
+                       JAX_PLATFORMS="cpu",
                        PYTHONPATH=repo_dir + os.pathsep
                        + os.environ.get("PYTHONPATH", ""))
             env.setdefault("PYTHONUNBUFFERED", "1")

@@ -8,7 +8,7 @@ cd "$(dirname "$0")"
 PY_DIRS=(ray_shuffling_data_loader_tpu tests benchmarks examples)
 
 echo "-- compile check"
-python -m compileall -q "${PY_DIRS[@]}" bench.py __graft_entry__.py setup.py
+python -m compileall -q "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py setup.py
 
 if python -c 'import yapf' 2>/dev/null; then
     echo "-- yapf (diff mode)"
@@ -37,11 +37,11 @@ if python -c 'import ray_shuffling_data_loader_tpu.analysis' 2>/dev/null; then
         echo "-- rsdl-lint (concurrency + locksan cross-check)"
         python -m ray_shuffling_data_loader_tpu.analysis --concurrency \
             --locksan-graph .rsdl-locksan-graph.json \
-            "${PY_DIRS[@]}" bench.py __graft_entry__.py tools
+            "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py tools
     else
         echo "-- rsdl-lint (concurrency)"
         python -m ray_shuffling_data_loader_tpu.analysis --concurrency \
-            "${PY_DIRS[@]}" bench.py __graft_entry__.py tools
+            "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py tools
     fi
 else
     echo "-- rsdl-lint deps not importable, skipping"
@@ -60,7 +60,7 @@ if [ "${RSDL_LOCKSAN_SUITE:-0}" = "1" ]; then
         -p no:cacheprovider >/dev/null
     python -m ray_shuffling_data_loader_tpu.analysis --concurrency \
         --locksan-graph .rsdl-locksan-graph.json \
-        "${PY_DIRS[@]}" bench.py __graft_entry__.py tools
+        "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py tools
 fi
 
 # Epoch-plan IR self-test (tools/rsdl_plan.py, stdlib-only): builds a

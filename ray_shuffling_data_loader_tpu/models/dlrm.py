@@ -27,7 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu.models import mlp as mlp_mod
 from ray_shuffling_data_loader_tpu.ops import embedding
@@ -47,9 +47,11 @@ class DLRMConfig:
     bottom_hidden: Tuple[int, ...] = (64,)
     top_hidden: Tuple[int, ...] = (512, 256)
     compute_dtype: Any = jnp.bfloat16
-    # Embedding lookup strategy (ops/embedding.py): "auto" sends small
-    # tables through a one-hot MXU matmul and large ones through XLA
-    # gather; "pallas" opts into the scalar-prefetch Pallas kernel.
+    # Embedding lookup strategy (ops/embedding.py). "auto" sends tables
+    # of <= 2048 rows through a one-hot MXU matmul; larger ones go through
+    # the scalar-prefetch Pallas gather on the chip when embed_dim is a
+    # multiple of 128, and through XLA gather otherwise (narrower embed
+    # dims, and every backend that is not a TPU).
     lookup_mode: str = "auto"
 
     @property
@@ -76,6 +78,15 @@ class DLRMConfig:
             # top MLP sees first-order signal, not only interactions.
             base += self.embed_dim
         return base
+
+
+def mlperf_config() -> DLRMConfig:
+    """MLPerf-DLRM-v2-like widths on the reference's 19-column schema:
+    full ``DATA_SPEC_VOCAB_SIZES``, embed 128, top MLP 1024-1024-512-256.
+    The tables alone are 2,912,607 x 128 float32 = 1.49 GB, so dense Adam
+    holds 4.5 GB of state — the configuration bench.py's train phase and
+    chip_smoke.py run at full width on one chip."""
+    return DLRMConfig(embed_dim=128, top_hidden=(1024, 1024, 512, 256))
 
 
 def _mlp_cfg(in_dim: int, hidden: Tuple[int, ...], out_dim: int,
@@ -123,12 +134,15 @@ def param_specs(config: DLRMConfig, model_axis: str = "model"
 
 
 def apply(config: DLRMConfig, params: Dict[str, Any],
-          dense: Optional[jax.Array], sparse) -> jax.Array:
+          dense: Optional[jax.Array], sparse,
+          mesh: Optional[Mesh] = None) -> jax.Array:
     """Forward: sparse is a (batch, num_sparse) int index array OR a list
     of per-feature (batch,)/(batch, 1) index arrays — the latter is what
     ``JaxShufflingDataset`` yields with per-column narrow dtypes
     (workloads/dlrm_criteo.py). dense (batch, dense_dim) or None.
-    Returns (batch, 1) f32 logits."""
+    ``mesh``: the mesh the step is jitted over when it spans more than
+    one device, batch on its "data" axis — the Pallas gather needs it
+    (ops/embedding.py). Returns (batch, 1) f32 logits."""
     dtype = config.compute_dtype
     is_columns = isinstance(sparse, (list, tuple))
     if is_columns and len(sparse) != config.num_sparse:
@@ -142,7 +156,7 @@ def apply(config: DLRMConfig, params: Dict[str, Any],
         idx = sparse[i].reshape(-1) if is_columns else sparse[:, i]
         vectors.append(
             embedding.lookup(params["embeddings"][f"table_{i}"], idx,
-                             dtype, mode=config.lookup_mode))
+                             dtype, mode=config.lookup_mode, mesh=mesh))
     if config.dense_dim > 0:
         bottom_cfg = _mlp_cfg(config.dense_dim, config.bottom_hidden,
                               config.embed_dim, dtype)
@@ -197,9 +211,10 @@ def validate_sparse_batch(config: DLRMConfig, sparse) -> None:
 
 def loss_fn(config: DLRMConfig, params: Dict[str, Any],
             dense: Optional[jax.Array], sparse,
-            labels: jax.Array) -> jax.Array:
-    """Sigmoid BCE-with-logits, mean over the batch."""
-    logits = apply(config, params, dense, sparse)
+            labels: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
+    """Sigmoid BCE-with-logits, mean over the batch. ``mesh`` as in
+    :func:`apply`."""
+    logits = apply(config, params, dense, sparse, mesh)
     return jnp.mean(
         jnp.maximum(logits, 0) - logits * labels
         + jnp.log1p(jnp.exp(-jnp.abs(logits))))

@@ -2,27 +2,35 @@
 
 The reference's native substrate is Ray's C++ core (SURVEY.md §2.3). Here the
 native layer is a small shared library built from ``src/shuffle_native.cpp``
-at first import (g++ -O3, cached next to the source). Everything has a NumPy
-fallback, so the package works even when no compiler is present — but the
-native path is the default on TPU-VM hosts.
+at first use (g++ -O3) and kept next to the source under a name that carries
+a hash of what it was built from. Every kernel has a NumPy twin, selected
+only by ``RSDL_TPU_DISABLE_NATIVE=1``: a build or load that fails without
+that switch is an error, never a silent drop to the slow path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import threading
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "src", "shuffle_native.cpp")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "src", "libshuffle_native.so")
+_FLAGS = ("-O3", "-march=native", "-std=c++17", "-pthread")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 _load_lock = threading.Lock()
+
+
+class NativeBuildError(RuntimeError):
+    """The native library could not be built or loaded on this host."""
 
 
 def _notify_release() -> None:
@@ -41,16 +49,67 @@ def _notify_release() -> None:
 _DEFAULT_FILL_THREADS = 8
 
 
-def _build() -> bool:
-    cmd = [
-        "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-        "-pthread", _SRC, "-o", _LIB_PATH,
-    ]
+def _host_cpu_key() -> str:
+    """What ``-march=native`` resolves against: the architecture plus the
+    first processor's model and feature lines of /proc/cpuinfo."""
+    lines = [platform.machine()]
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return proc.returncode == 0
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if not line.strip():
+                    break  # end of the first processor block
+                if line.split(":", 1)[0].strip() in (
+                        "model name", "flags", "Features", "CPU part"):
+                    lines.append(line.strip())
+    except OSError:
+        pass
+    return "\n".join(lines)
+
+
+def compiled_library(src: str, flags: Sequence[str],
+                     libs: Sequence[str] = ()) -> str:
+    """Path of the shared library for ``src``, built here if absent.
+
+    The name carries a hash of the source bytes, the compiler flags, the
+    ``libs`` linked after the source, and the host CPU (the build may use
+    ``-march=native``), so a library left by other source or copied from
+    another machine is never loaded — it is rebuilt under a new name and
+    the stale one removed. The compiler writes to a private name that is
+    renamed into place, so concurrently spawned workers each load a whole
+    file whoever finishes first.
+    """
+    digest = hashlib.sha256()
+    with open(src, "rb") as f:
+        digest.update(f.read())
+    digest.update("\0".join((*flags, *libs)).encode())
+    digest.update(_host_cpu_key().encode())
+    src_dir = os.path.dirname(src)
+    stem = "lib" + os.path.splitext(os.path.basename(src))[0]
+    lib_path = os.path.join(src_dir,
+                            f"{stem}-{digest.hexdigest()[:16]}.so")
+    if os.path.exists(lib_path):
+        return lib_path
+    tmp_path = os.path.join(src_dir, f".build-{os.getpid()}-{stem}.so")
+    cmd = ["g++", *flags, "-shared", "-fPIC", src, "-o", tmp_path, *libs]
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True,
+                              timeout=120)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"{' '.join(cmd)} did not run: {e}") from e
+    if proc.returncode != 0:
+        if os.path.exists(tmp_path):
+            os.unlink(tmp_path)
+        raise NativeBuildError(
+            f"{' '.join(cmd)} exited {proc.returncode}:\n"
+            f"{proc.stderr[-2000:]}")
+    os.replace(tmp_path, lib_path)
+    for stale in glob.glob(os.path.join(src_dir, f"{stem}*.so")):
+        if stale != lib_path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass  # a racing worker removed it first
+    return lib_path
 
 
 def _bind(lib: ctypes.CDLL) -> None:
@@ -108,40 +167,31 @@ def _bind(lib: ctypes.CDLL) -> None:
 
 
 def _load() -> Optional[ctypes.CDLL]:
+    """The bound library; ``None`` only under ``RSDL_TPU_DISABLE_NATIVE``.
+    A failed build or load raises :class:`NativeBuildError` — on every
+    call, so no caller proceeds on the NumPy twin by accident."""
     global _lib, _load_attempted
     if _load_attempted:
         return _lib
     with _load_lock:
         if _load_attempted:
             return _lib
-        if os.environ.get("RSDL_TPU_DISABLE_NATIVE"):
-            _load_attempted = True
-            return None
-        try:
-            needs_build = (not os.path.exists(_LIB_PATH)
-                           or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC))
-            if needs_build and not _build():
-                return None
-            lib = ctypes.CDLL(_LIB_PATH)
-            _bind(lib)
-            _lib = lib
-        except (OSError, AttributeError):
-            # AttributeError = stale .so missing a newly-bound symbol; try one
-            # rebuild, then fall back to NumPy permanently.
+        if not os.environ.get("RSDL_TPU_DISABLE_NATIVE"):
+            lib_path = compiled_library(_SRC, _FLAGS)
             try:
-                if _build():
-                    lib = ctypes.CDLL(_LIB_PATH)
-                    _bind(lib)
-                    _lib = lib
-            except (OSError, AttributeError):
-                _lib = None
-        finally:
-            _load_attempted = True
+                lib = ctypes.CDLL(lib_path)
+                _bind(lib)
+            except (OSError, AttributeError) as e:
+                raise NativeBuildError(
+                    f"cannot load {lib_path}: {e}") from e
+            _lib = lib
+        _load_attempted = True
         return _lib
 
 
 def available() -> bool:
-    """True if the native library is built and loaded."""
+    """True if the native library is loaded; False only when
+    ``RSDL_TPU_DISABLE_NATIVE`` turned it off."""
     return _load() is not None
 
 
@@ -482,10 +532,9 @@ class NativeBufferPool:
 
 
 class PythonBufferLedger:
-    """Pure-Python fallback with NativeBufferPool's accounting API, used
-    when no compiler is present (RSDL_TPU_DISABLE_NATIVE, minimal images)
-    so pipeline memory accounting works everywhere. ``alloc`` entries are
-    backed by numpy arrays."""
+    """Pure-Python twin with NativeBufferPool's accounting API, used
+    under RSDL_TPU_DISABLE_NATIVE so pipeline memory accounting works
+    without the library. ``alloc`` entries are backed by numpy arrays."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -1,43 +1,34 @@
 """ctypes loader for the native batch image decoder (src/image_decode.cpp).
 
-Built lazily on first use (g++, linked against the system libjpeg/libpng);
-every caller must handle :func:`available` returning False — the PIL
-fallback in workloads/imagenet.py keeps the pipeline working on hosts
-without a compiler or the codec libraries.
+Built on first use (g++, linked against the system libjpeg/libpng) by the
+same hash-named, atomically written build as the shuffle kernels
+(``native.compiled_library``). :func:`available` is False only under
+``RSDL_TPU_DISABLE_NATIVE`` — workloads/imagenet.py then decodes with PIL;
+a build or load that fails without that switch raises.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import List, Optional
 
 import numpy as np
 
+from ray_shuffling_data_loader_tpu.native import (NativeBuildError,
+                                                  compiled_library)
+
 _SRC = os.path.join(os.path.dirname(__file__), "src", "image_decode.cpp")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "src",
-                         "libimage_decode.so")
+# Libraries after the source: the linker resolves left to right.
+_FLAGS = ("-O2", "-std=c++17", "-pthread")
+_LIBS = ("-ljpeg", "-lpng")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 _load_lock = threading.Lock()
 
 _DEFAULT_THREADS = max(1, min(8, (os.cpu_count() or 1)))
-
-
-def _build() -> bool:
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread", _SRC,
-        "-o", _LIB_PATH, "-ljpeg", "-lpng",
-    ]
-    try:
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return proc.returncode == 0
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -47,33 +38,28 @@ def _load() -> Optional[ctypes.CDLL]:
     with _load_lock:
         if _load_attempted:
             return _lib
-        if os.environ.get("RSDL_TPU_DISABLE_NATIVE"):
-            _load_attempted = True
-            return None
-        try:
-            needs_build = (not os.path.exists(_LIB_PATH)
-                           or os.path.getmtime(_LIB_PATH)
-                           < os.path.getmtime(_SRC))
-            if needs_build and not _build():
-                return None
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.rsdl_decode_images.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int
-            ]
-            lib.rsdl_decode_images.restype = ctypes.c_int64
+        if not os.environ.get("RSDL_TPU_DISABLE_NATIVE"):
+            lib_path = compiled_library(_SRC, _FLAGS, _LIBS)
+            try:
+                lib = ctypes.CDLL(lib_path)
+                lib.rsdl_decode_images.argtypes = [
+                    ctypes.POINTER(ctypes.c_char_p),
+                    ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+                    ctypes.c_int64, ctypes.c_int64,
+                    ctypes.POINTER(ctypes.c_uint8), ctypes.c_int
+                ]
+                lib.rsdl_decode_images.restype = ctypes.c_int64
+            except (OSError, AttributeError) as e:
+                raise NativeBuildError(
+                    f"cannot load {lib_path}: {e}") from e
             _lib = lib
-        except (OSError, AttributeError):
-            _lib = None
-        finally:
-            _load_attempted = True
+        _load_attempted = True
         return _lib
 
 
 def available() -> bool:
-    """True if the native decoder is built and loaded."""
+    """True if the native decoder is loaded; False only when
+    ``RSDL_TPU_DISABLE_NATIVE`` turned it off."""
     return _load() is not None
 
 

@@ -29,10 +29,14 @@ XLA ``take``.
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+from ray_shuffling_data_loader_tpu.ops import on_tpu
+from ray_shuffling_data_loader_tpu.parallel.mesh import DATA_AXIS
 
 # Above this vocab size the one-hot matmul's wasted FLOPs and VMEM
 # pressure outgrow the gather's latency; 2048 keeps the one-hot tile
@@ -130,29 +134,43 @@ def _pallas_gather_bwd(interpret, residual, cotangent):
 _pallas_gather.defvjp(_pallas_gather_fwd, _pallas_gather_bwd)
 
 
-def pallas_lookup(table: jax.Array, indices: jax.Array,
-                  dtype: Any) -> jax.Array:
+def pallas_lookup(table: jax.Array, indices: jax.Array, dtype: Any,
+                  mesh: Optional[Mesh] = None) -> jax.Array:
     """Pallas scalar-prefetch row gather (interpret mode off-TPU).
 
     On real TPUs Mosaic requires HBM row-slice DMAs to be 128-lane
     aligned, so tables whose embed dim is not a multiple of 128 fall back
     to the XLA gather (numerically identical).
+
+    GSPMD cannot partition a Mosaic kernel, so a step jitted over more
+    than one device must say so: with ``mesh``, the gather runs once per
+    shard of the mesh's "data" axis under ``shard_map`` — the table
+    replicated, the indices and the rows they select split over that
+    axis — and the table's gradient is summed over the shards.
     """
     vocab, embed_dim = table.shape
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     if not interpret and embed_dim % 128 != 0:
         return take_lookup(table, indices, dtype)
     indices = jnp.clip(indices.astype(jnp.int32), 0, vocab - 1)
+
+    def gather(table, indices):
+        return _pallas_gather(table, indices, interpret)
+
+    if mesh is not None and mesh.size > 1:
+        gather = jax.shard_map(gather, mesh=mesh,
+                               in_specs=(P(), P(DATA_AXIS)),
+                               out_specs=P(DATA_AXIS), check_vma=False)
     # Gather in the table's storage dtype and cast afterwards: Mosaic
     # supports single-row HBM DMAs for 4-byte types but not 2-byte ones,
     # and cast-then-gather == gather-then-cast elementwise.
-    return _pallas_gather(table, indices, interpret).astype(dtype)
+    return gather(table, indices).astype(dtype)
 
 
 def _auto_mode(vocab: int, embed_dim: int) -> str:
     if vocab <= ONE_HOT_MAX_VOCAB:
         return "one_hot"
-    if jax.default_backend() == "tpu" and embed_dim % 128 == 0:
+    if on_tpu() and embed_dim % 128 == 0:
         return "pallas"
     return "take"
 
@@ -160,11 +178,14 @@ def _auto_mode(vocab: int, embed_dim: int) -> str:
 def lookup(table: jax.Array,
            indices: jax.Array,
            dtype: Any,
-           mode: str = "auto") -> jax.Array:
+           mode: str = "auto",
+           mesh: Optional[Mesh] = None) -> jax.Array:
     """Embedding lookup: ``table (vocab, embed)``, ``indices (batch,)`` ->
     ``(batch, embed)`` in ``dtype``. All modes clip out-of-range indices
     and return bit-identical results; they differ only in which hardware
-    unit does the work."""
+    unit does the work. ``mesh``: the mesh the calling step is jitted
+    over, when it spans more than one device (see :func:`pallas_lookup`;
+    the XLA modes partition themselves and ignore it)."""
     if mode == "auto":
         mode = _auto_mode(table.shape[0], table.shape[1])
     if mode == "take":
@@ -172,6 +193,6 @@ def lookup(table: jax.Array,
     if mode == "one_hot":
         return one_hot_lookup(table, indices, dtype)
     if mode == "pallas":
-        return pallas_lookup(table, indices, dtype)
+        return pallas_lookup(table, indices, dtype, mesh)
     raise ValueError(
         f"unknown lookup mode {mode!r}; expected auto/take/one_hot/pallas")

@@ -37,22 +37,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ray_shuffling_data_loader_tpu.ops import on_tpu
+
 NEG_INF = -1e9  # finite, like models/bert.py — keeps softmax NaN-free
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the API move: the public ``jax.shard_map``
-    (with ``check_vma``) landed after 0.4.x; earlier jax ships it as
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep``). Both
-    replication checks are disabled — the attention bodies run collectives
-    whose replication the checker cannot infer."""
-    public = getattr(jax, "shard_map", None)
-    if public is not None:
-        return public(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-    from jax.experimental.shard_map import shard_map as experimental
-    return experimental(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the replication check off — the attention
+    bodies run collectives whose replication the checker cannot infer."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 _ACC_MIN = -1e30
 
 
@@ -407,7 +403,7 @@ def ring_self_attention(q: jax.Array,
 
     Returns (B, H, S, D), sharded like ``q``.
     """
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     if use_flash is None:
         use_flash = not causal and not interpret
     if use_flash and causal:
@@ -485,7 +481,7 @@ def ulysses_attention(q: jax.Array,
         raise ValueError(
             f"ulysses_attention needs num_heads ({q.shape[1]}) divisible by "
             f"mesh axis '{seq_axis}' size ({n})")
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     if use_flash is None:
         use_flash = not causal and not interpret
     if use_flash and causal:

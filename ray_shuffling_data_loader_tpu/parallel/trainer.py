@@ -74,8 +74,17 @@ class SpmdTrainer:
             is_leaf=lambda x: isinstance(x, P))
         self.params = jax.device_put(params, self._param_shardings)
         # Optimizer state sharding is inferred by XLA from the param
-        # shardings (mu/nu mirror params; scalars replicate).
-        self.opt_state = jax.jit(optimizer.init)(self.params)
+        # shardings (mu/nu mirror params). Scalars such as Adam's step
+        # count come back off the mesh, while the step returns them on
+        # it — and a step whose input types changed between the first
+        # call and the second compiles twice. Replicate them over the
+        # mesh up front.
+        replicated = NamedSharding(mesh, P())
+        self.opt_state = jax.tree.map(
+            lambda x: x if (isinstance(x.sharding, NamedSharding)
+                            and x.sharding.mesh == mesh)
+            else jax.device_put(x, replicated),
+            jax.jit(optimizer.init)(self.params))
         step = make_train_step(loss_fn, optimizer)
         self._step = jax.jit(
             step, donate_argnums=(0, 1) if donate else ())
@@ -88,6 +97,12 @@ class SpmdTrainer:
                 self.params, self.opt_state, *batch)
         self._step_count += 1
         return loss
+
+    @property
+    def step_fn(self):
+        """The jitted step, for inspection: ``.lower(...)`` shows what it
+        compiles to and ``._cache_size()`` how often it compiled."""
+        return self._step
 
     def block_until_ready(self) -> None:
         jax.block_until_ready((self.params, self.opt_state))

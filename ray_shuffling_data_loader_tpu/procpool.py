@@ -41,6 +41,8 @@ import concurrent.futures as cf
 import multiprocessing as mp
 import os
 import pickle
+import signal
+import sys
 import tempfile
 import threading
 import timeit
@@ -522,15 +524,22 @@ def _worker_main(conn, worker_index: int) -> None:
     except (ValueError, OSError):  # non-main thread / exotic platform
         pass
     threading.current_thread().name = f"rsdl-proc-worker-{worker_index}"
-    # The worker owns host CPU work only; it must never initialize (or
-    # wait on) an accelerator the driver owns.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # The driver owns the chip; a worker does host CPU work only and must
+    # never ask for it, whatever the inherited environment says. jax reads
+    # JAX_PLATFORMS when it is imported, and the spawn re-import of the
+    # driver's __main__ may already have imported it.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax_mod = sys.modules.get("jax")
+    if jax_mod is not None:
+        jax_mod.config.update("jax_platforms", "cpu")
     # Ops-plane federation (inherited through the spawn env like
     # RSDL_CHAOS_SPEC): the worker writes its per-pid metrics shard
     # under RSDL_TELEMETRY_DIR so the driver's merged exposition counts
     # the processes doing the work, and answers the incident capture's
     # SIGUSR1 with a flight-recorder dump into RSDL_TRACE_DIR.
     rt_telemetry.install_signal_dump()
+    # Born with SIGUSR1 blocked (see _spawn_worker); deliverable now.
+    _signal.pthread_sigmask(_signal.SIG_UNBLOCK, {_signal.SIGUSR1})
     rt_metrics.maybe_start_shard_writer()
     tasks_done = rt_metrics.counter(
         "rsdl_worker_tasks_total",
@@ -555,7 +564,12 @@ def _worker_main(conn, worker_index: int) -> None:
         except BaseException as e:  # noqa: BLE001 - shipped to the driver
             import traceback as _tb
             try:
-                pickle.dumps(e)
+                # Round trip, not just dumps: an exception whose
+                # __init__ takes other arguments than its .args pickles
+                # fine and then raises in the driver's recv(), which
+                # would kill the dispatcher and leave the task's future
+                # unresolved forever.
+                pickle.loads(pickle.dumps(e))
                 err: Any = e
             except Exception:  # noqa: BLE001 - unpicklable exception
                 err = RemoteTaskError(
@@ -854,7 +868,19 @@ class ProcessPoolExecutor:
         proc = self._ctx.Process(
             target=_worker_main, args=(child_conn, index),
             name=f"{self._name}-worker-{index}", daemon=True)
-        proc.start()
+        # A spawned worker imports for seconds before _worker_main can
+        # install its SIGUSR1 dump handler, and its pid is published at
+        # once — the ops plane's incident capture signals every pool pid,
+        # and until the handler is in, SIGUSR1's default action kills the
+        # worker. The signal mask survives fork+exec: start the child
+        # with SIGUSR1 blocked, so one sent during boot stays pending
+        # until the worker unblocks it behind its handler.
+        old_mask = signal.pthread_sigmask(signal.SIG_BLOCK,
+                                          {signal.SIGUSR1})
+        try:
+            proc.start()
+        finally:
+            signal.pthread_sigmask(signal.SIG_SETMASK, old_mask)
         child_conn.close()
         return _Worker(proc, parent_conn, index)
 

@@ -134,6 +134,11 @@ class InjectedFault(RuntimeError):
         self.task = task
         self.rule = rule
 
+    def __reduce__(self):
+        # Crosses the process-pool pipe: the default reduce would rebuild
+        # from the formatted message alone and fail __init__.
+        return (type(self), (self.site, self.epoch, self.task, self.rule))
+
 
 @dataclasses.dataclass
 class QuarantinedFile:

@@ -11,8 +11,8 @@ this module, with one precedence order everywhere::
 
 Components are short names for the subsystem consulting the policy
 (``jax_dataset``, ``shuffle``, ``spill``, ``bench``). Example: a host
-whose device tunnel is known-flaky exports ``RSDL_DEVICE_REBATCH=0`` and
-every loader in every process degrades to per-batch transfers, while
+whose bulk device transfers stall exports ``RSDL_DEVICE_REBATCH=0`` and
+every loader in every process uses per-batch transfers, while
 ``RSDL_JAX_DATASET_BULK_TRANSFER_DEADLINE_S=5`` tightens only the
 loader's bulk-transfer watchdog.
 

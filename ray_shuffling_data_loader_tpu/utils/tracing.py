@@ -20,20 +20,6 @@ import contextlib
 import os
 from typing import Iterator, Optional
 
-_trace_annotation = None
-
-
-def _get_trace_annotation():
-    """Lazy import: keep jax out of pure-host code paths until needed."""
-    global _trace_annotation
-    if _trace_annotation is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _trace_annotation = TraceAnnotation
-        except ImportError:  # pragma: no cover - jax is a hard dep in CI
-            _trace_annotation = False
-    return _trace_annotation
-
 
 @contextlib.contextmanager
 def trace_span(name: str, kind: Optional[str] = None,
@@ -47,31 +33,23 @@ def trace_span(name: str, kind: Optional[str] = None,
     correlation ids — one annotation, two consumers: the XLA profiler
     timeline and the online bottleneck attribution.
     """
-    annotation = _get_trace_annotation()
-    if kind is not None:
-        from ray_shuffling_data_loader_tpu.runtime import telemetry
-        if not annotation:
-            with telemetry.span(kind, epoch=epoch, task=task, batch=batch):
-                yield
-            return
-        with telemetry.span(kind, epoch=epoch, task=task, batch=batch):
-            with annotation(name):
-                yield
+    # Imported here, not at module top: pure-host code paths (pool
+    # workers) pay for jax only when they open their first span.
+    from jax.profiler import TraceAnnotation
+    if kind is None:
+        with TraceAnnotation(name):
+            yield
         return
-    if not annotation:
-        yield
-        return
-    with annotation(name):
-        yield
+    from ray_shuffling_data_loader_tpu.runtime import telemetry
+    with telemetry.span(kind, epoch=epoch, task=task, batch=batch):
+        with TraceAnnotation(name):
+            yield
 
 
 def step_span(step: int):
     """Train-step marker: lets the profiler group device ops per step.
     Returns a context manager."""
-    try:
-        from jax.profiler import StepTraceAnnotation
-    except ImportError:  # pragma: no cover
-        return contextlib.nullcontext()
+    from jax.profiler import StepTraceAnnotation
     return StepTraceAnnotation("train", step_num=step)
 
 

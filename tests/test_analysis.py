@@ -21,7 +21,8 @@ from ray_shuffling_data_loader_tpu.analysis import cli, core
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: The trees the format.sh gate runs over.
 GATE_PATHS = ["ray_shuffling_data_loader_tpu", "tests", "benchmarks",
-              "examples", "bench.py", "__graft_entry__.py", "tools"]
+              "examples", "bench.py", "chip_smoke.py", "__graft_entry__.py",
+              "tools"]
 
 
 def lint(source, path="pkg/mod.py", **config_kwargs):

@@ -84,7 +84,7 @@ def test_use_old_data_missing_raises(tmp_path):
         ])
 
 
-def test_bench_py_json_contract(tmp_path):
+def test_bench_py_json_contract(bench_env):
     """bench.py is the driver-facing artifact: it must exit 0 and print
     ONE parseable JSON line with the contract keys, on a tiny CPU config."""
     import json
@@ -92,12 +92,8 @@ def test_bench_py_json_contract(tmp_path):
     import subprocess
     import sys
 
-    env = dict(os.environ)
-    env.update(RSDL_BENCH_CPU="1", RSDL_BENCH_ROWS="20000",
-               RSDL_BENCH_FILES="2", RSDL_BENCH_EPOCHS="2",
-               RSDL_BENCH_BATCH="2048",
-               RSDL_BENCH_TRAIN_EPOCHS="2", RSDL_BENCH_TRAIN_BATCH="2048",
-               RSDL_BENCH_DATA=str(tmp_path / "data"))
+    env = bench_env(RSDL_BENCH_TRAIN_EPOCHS="2",
+                    RSDL_BENCH_TRAIN_BATCH="2048")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "bench.py")],
@@ -138,7 +134,7 @@ def test_bench_py_json_contract(tmp_path):
     assert 0 <= record["stall_pct_under_train"] <= 100
 
 
-def test_bench_py_phase_subset(tmp_path):
+def test_bench_py_phase_subset(bench_env):
     """RSDL_BENCH_PHASES trims phases; a cold-only run keeps the legacy
     cold headline metric name."""
     import json
@@ -146,11 +142,7 @@ def test_bench_py_phase_subset(tmp_path):
     import subprocess
     import sys
 
-    env = dict(os.environ)
-    env.update(RSDL_BENCH_CPU="1", RSDL_BENCH_ROWS="20000",
-               RSDL_BENCH_FILES="2", RSDL_BENCH_EPOCHS="2",
-               RSDL_BENCH_BATCH="2048", RSDL_BENCH_PHASES="cold",
-               RSDL_BENCH_DATA=str(tmp_path / "data"))
+    env = bench_env(RSDL_BENCH_PHASES="cold")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "bench.py")],
@@ -163,7 +155,7 @@ def test_bench_py_phase_subset(tmp_path):
     assert record["cache_mode"] == "cold"
 
 
-def test_bench_py_tenancy_phase_contract(tmp_path):
+def test_bench_py_tenancy_phase_contract(bench_env):
     """A tenancy-only bench run (the CI contention leg in dryrun scale)
     exits 0 and reports the structural tenancy keys: fairness ratio,
     per-tenant rates, p99s and the journaled admission evidence. The
@@ -175,13 +167,9 @@ def test_bench_py_tenancy_phase_contract(tmp_path):
     import subprocess
     import sys
 
-    env = dict(os.environ)
-    env.update(RSDL_BENCH_CPU="1", RSDL_BENCH_ROWS="20000",
-               RSDL_BENCH_FILES="2", RSDL_BENCH_EPOCHS="2",
-               RSDL_BENCH_BATCH="2048", RSDL_BENCH_PHASES="tenancy",
-               RSDL_BENCH_TENANCY_REDUCERS="8",
-               RSDL_BENCH_TENANCY_EPOCHS="1",
-               RSDL_BENCH_DATA=str(tmp_path / "data"))
+    env = bench_env(RSDL_BENCH_PHASES="tenancy",
+                    RSDL_BENCH_TENANCY_REDUCERS="8",
+                    RSDL_BENCH_TENANCY_EPOCHS="1")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "bench.py")],

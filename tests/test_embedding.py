@@ -140,3 +140,56 @@ def test_auto_mode_dispatch_rules(monkeypatch):
     assert embedding._auto_mode(small_v, 128) == "one_hot"
     assert embedding._auto_mode(large_v, 128) == "pallas"
     assert embedding._auto_mode(large_v, 32) == "take"  # unaligned rows
+
+
+def _mesh_table_indices(rng):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
+    mesh = mesh_mod.make_mesh()
+    table = jax.device_put(
+        jnp.asarray(rng.standard_normal((640, 128)), jnp.float32),
+        NamedSharding(mesh, P()))
+    indices = jax.device_put(
+        jnp.asarray(rng.integers(0, 640, 64), jnp.int32),
+        NamedSharding(mesh, P("data")))
+    return mesh, table, indices
+
+
+def test_pallas_lookup_under_a_mesh_matches_take(rng):
+    """Batch-sharded indices, replicated table: one gather per data shard
+    under shard_map, same rows and same table gradient as XLA take."""
+    mesh, table, indices = _mesh_table_indices(rng)
+    weights = jnp.asarray(rng.standard_normal((64, 128)), jnp.float32)
+
+    def loss(mode, mesh):
+        def fn(table, indices):
+            out = embedding.lookup(table, indices, jnp.float32, mode=mode,
+                                   mesh=mesh)
+            return jnp.sum(out * weights), out
+        return jax.jit(jax.value_and_grad(fn, has_aux=True))
+
+    (_, got), got_grad = loss("pallas", mesh)(table, indices)
+    (_, want), want_grad = loss("take", None)(table, indices)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got_grad), np.asarray(want_grad),
+                               atol=1e-6)
+    assert got.sharding.shard_shape(got.shape) == (64 // mesh.size, 128)
+
+
+def test_pallas_lookup_lowers_for_the_chip_only_with_the_mesh(
+        rng, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: lowering a multi-device
+    step for the TPU fails unless the gather was told the mesh."""
+    mesh, table, indices = _mesh_table_indices(rng)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def lower(mesh):
+        fn = jax.jit(lambda t, i: embedding.lookup(
+            t, i, jnp.float32, mode="pallas", mesh=mesh))
+        return fn.trace(table, indices).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    assert lower(mesh).count("tpu_custom_call") == 1
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lower(None)

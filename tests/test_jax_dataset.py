@@ -523,6 +523,7 @@ def test_device_rebatch_sharded_mesh_matches_host_path(tmp_path):
             feature_types=[np.int32, np.int32],
             label_column="labels", num_reducers=3, seed=7,
             queue_name=qname, mesh=mesh, device_rebatch=device_rebatch)
+        touch = jax.jit(lambda fs, y: sum(f.sum() for f in fs) + y.sum())
         out, shardings = [], []
         for epoch in range(2):
             ds.set_epoch(epoch)
@@ -530,14 +531,19 @@ def test_device_rebatch_sharded_mesh_matches_host_path(tmp_path):
                 out.append((tuple(np.asarray(f) for f in features),
                             np.asarray(label)))
                 shardings.append(label.sharding)
-        return out, shardings
+                touch(features, label)
+        return out, shardings, touch._cache_size()
 
-    host, _ = run(False, "drm-host")
-    dev, dev_shardings = run(True, "drm-dev")
+    host, _, _ = run(False, "drm-host")
+    dev, dev_shardings, dev_programs = run(True, "drm-dev")
     _assert_batches_equal(host, dev)
     expected = NamedSharding(mesh, P("data", None))
     for s in dev_shardings:
         assert s.is_equivalent_to(expected, 2)
+    # Carved and stitched batches must be ONE input type to a consumer's
+    # jitted step: equal layouts under different sharding objects (the
+    # carve used to come back as P("data")) make it compile twice.
+    assert dev_programs == 1
 
 
 def test_device_rebatch_repacking_spec_rejected(tmp_path):

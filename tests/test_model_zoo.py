@@ -143,19 +143,10 @@ def test_bert_base_config():
 
 
 def _assert_grads_match(g0, g1):
-    """remat grads vs exact grads: bitwise on jax lines whose remat
-    re-runs the identical XLA program; jax 0.4.x (no public
-    ``jax.shard_map`` — the API-era marker this suite version-gates on)
-    reassociates reductions in the rematerialized backward, so there the
-    contract is float32-rounding-tight closeness (measured 3e-8 absolute
-    / 2e-7 relative on these fixtures), not bit equality."""
-    bitwise = hasattr(jax, "shard_map")
+    """remat re-runs the identical XLA program, so its grads equal the
+    exact grads bit for bit."""
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
-        if bitwise:
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        else:
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-5, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_bert_remat_matches_exact_grads():

@@ -88,6 +88,50 @@ def test_worker_pids_are_real_subprocesses():
         assert reply["pid"] in pids
 
 
+def test_sigusr1_during_worker_boot_does_not_kill_the_worker():
+    """The incident capture SIGUSR1s every published pool pid, and a
+    spawned worker imports for seconds before it can install its dump
+    handler. A signal in that window must wait for the handler, not take
+    the default action."""
+    with procpool.ProcessPoolExecutor(num_workers=2) as pool:
+        pids = pool.worker_pids()
+        for pid in pids:
+            os.kill(pid, signal.SIGUSR1)  # workers are still importing
+        replies = [pool.submit_kind("ping", {}).result(timeout=60.0)
+                   for _ in range(4)]
+        assert {r["pid"] for r in replies} <= set(pids)
+        assert pool.worker_pids() == pids, "a worker died and was respawned"
+
+
+class _TwoArgError(Exception):
+    """Pickles, but cannot be rebuilt from its ``.args`` alone."""
+
+    def __init__(self, code, detail):
+        super().__init__(f"{code}: {detail}")
+
+
+def _raise(kind):
+    if kind == "injected":
+        from ray_shuffling_data_loader_tpu.runtime import faults
+        raise faults.InjectedFault("map_read", 0, 3, "map_read:file3")
+    raise _TwoArgError(7, "boom")
+
+
+def test_worker_exception_that_cannot_be_rebuilt_comes_back():
+    """An exception whose __init__ wants more than its .args dumps fine
+    and then fails to load in the driver; that used to kill the
+    dispatcher thread and leave the task's future pending forever."""
+    from ray_shuffling_data_loader_tpu.runtime import faults
+    with procpool.ProcessPoolExecutor(num_workers=1) as pool:
+        with pytest.raises(faults.InjectedFault) as info:
+            pool.submit_once(_raise, "injected").result(timeout=60.0)
+        assert (info.value.site, info.value.task) == ("map_read", 3)
+        with pytest.raises(procpool.RemoteTaskError, match="7: boom"):
+            pool.submit_once(_raise, "custom").result(timeout=60.0)
+        # The dispatcher survived both.
+        assert pool.submit_kind("ping", {}).result(timeout=60.0)["pid"]
+
+
 def test_submit_after_shutdown_raises():
     pool = procpool.ProcessPoolExecutor(num_workers=1)
     pool.shutdown()

@@ -57,13 +57,6 @@ def test_ring_with_padding_bias(rng):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax 0.4.x experimental shard_map lowers lax.axis_index to a "
-           "PartitionId instruction that the SPMD partitioner rejects "
-           "under an OUTER jit with sharded inputs; the un-jitted call "
-           "paths (every other test here) are unaffected, and the public "
-           "jax.shard_map API lowers it correctly")
 def test_ring_under_jit_with_sharded_inputs(rng):
     q, k, v = _qkv(rng)
     mesh = _seq_mesh()

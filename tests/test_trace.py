@@ -373,27 +373,37 @@ def _load_bench_diff():
     return module
 
 
-def test_bench_diff_flags_r03_to_r05_regression():
-    bd = _load_bench_diff()
-    base = bd.load_record(os.path.join(REPO_ROOT, "BENCH_r03.json"))
+def _faster_old_baseline(tmp_path, bd):
+    """A baseline in the oldest record shape (headline keys only, no
+    ``rows_per_s_per_core``) whose cached ingest is 2.9x BENCH_r05's —
+    the size of drop the gate exists to catch."""
     cur = bd.load_record(os.path.join(REPO_ROOT, "BENCH_r05.json"))
-    findings = bd.compare_records(base, cur)
+    base = {key: cur[key] for key in (
+        "metric", "unit", "vs_baseline", "stall_pct", "stall_s",
+        "batch_wait_mean_ms", "step_ms", "cache_mode",
+        "baseline_files_fraction", "host_cpus", "timed_epochs")}
+    base["value"] = round(cur["value"] * 2.9, 1)
+    path = tmp_path / "faster_baseline.json"
+    path.write_text(json.dumps(base))
+    return str(path)
+
+
+def test_bench_diff_flags_headline_regression(tmp_path):
+    bd = _load_bench_diff()
+    base_path = _faster_old_baseline(tmp_path, bd)
+    cur_path = os.path.join(REPO_ROOT, "BENCH_r05.json")
+    findings = bd.compare_records(bd.load_record(base_path),
+                                  bd.load_record(cur_path))
     bad = [f for f in findings if not f["ok"]]
     assert any(f["key"] == "value" for f in bad), findings
     # CLI form: rc 1, the acceptance-gate invocation.
-    rc = bd.main([os.path.join(REPO_ROOT, "BENCH_r03.json"),
-                  os.path.join(REPO_ROOT, "BENCH_r05.json")])
-    assert rc == 1
+    assert bd.main([base_path, cur_path]) == 1
     # Identical records: clean.
-    assert bd.main([os.path.join(REPO_ROOT, "BENCH_r05.json"),
-                    os.path.join(REPO_ROOT, "BENCH_r05.json")]) == 0
-    # Threshold override: a 99% allowance forgives even r03 -> r05.
+    assert bd.main([cur_path, cur_path]) == 0
+    # Threshold override: a 99% allowance forgives even this drop.
     assert bd.main(["--threshold", "value=99",
                     "--threshold", "rows_per_s_per_core=99",
-                    "--threshold", "cold_rows_per_sec=99",
-                    "--threshold", "train_rows_per_sec=99",
-                    os.path.join(REPO_ROOT, "BENCH_r03.json"),
-                    os.path.join(REPO_ROOT, "BENCH_r05.json")]) == 0
+                    base_path, cur_path]) == 0
 
 
 def test_bench_diff_check_mode_is_informational():
@@ -401,13 +411,13 @@ def test_bench_diff_check_mode_is_informational():
     assert bd.main(["--check", REPO_ROOT]) == 0
 
 
-def test_bench_diff_derives_per_core_rate_for_old_records():
+def test_bench_diff_derives_per_core_rate_for_old_records(tmp_path):
     bd = _load_bench_diff()
-    # r03 predates the rows_per_s_per_core key but carries value +
-    # host_cpus; the per-core lower-bad rule must fire against it
-    # instead of silently skipping the one host-width-proof metric.
+    # The oldest records predate the rows_per_s_per_core key but carry
+    # value + host_cpus; the per-core lower-bad rule must fire against
+    # them instead of silently skipping the one host-width-proof metric.
     base = bd.derive_metrics(
-        bd.load_record(os.path.join(REPO_ROOT, "BENCH_r03.json")))
+        bd.load_record(_faster_old_baseline(tmp_path, bd)))
     assert base["rows_per_s_per_core"] == pytest.approx(
         base["value"] / base["host_cpus"])
     findings = bd.compare_records(

@@ -1,19 +1,19 @@
 #!/usr/bin/env python
 """rsdl-bench-diff: per-metric regression gate between two bench records.
 
-BENCH_r05 regressed cached ingest from BENCH_r03's 26.2M rows/s to
-9.2M and NOTHING in the repo noticed — the record format carries the
-numbers but no machinery compared them. This tool is that machinery:
+A past round's record showed cached ingest 65% below the round before
+and NOTHING in the repo noticed — the record format carries the numbers
+but no machinery compared them. This tool is that machinery:
 
-    tools/rsdl_bench_diff.py BENCH_r03.json BENCH_r05.json
-        # rc 1, 'value ... REGRESSED' — the r03->r05 drop, flagged
+    tools/rsdl_bench_diff.py BENCH_r10.json BENCH_r11.json
+        # rc 1 and 'value ... REGRESSED' when a threshold is breached
 
     tools/rsdl_bench_diff.py --check [DIR]
         # informational mode for format.sh: compares the two newest
         # committed BENCH_r*.json records, prints the verdict, rc 0
         # (add --strict to make it a hard gate)
 
-    python bench.py --baseline BENCH_r03.json
+    python bench.py --baseline BENCH_r11.json
         # the hard gate at measurement time: bench loads this module
         # and exits non-zero on a threshold breach
 
@@ -160,7 +160,7 @@ def derive_metrics(record: Dict[str, Any]) -> Dict[str, Any]:
     """Fill in metrics computable from what the record does carry.
 
     ``rows_per_s_per_core`` only started being emitted in r05, but
-    r03/r04 already carried ``value`` (rows/s) and ``host_cpus`` — and
+    earlier records already carried ``value`` (rows/s) and ``host_cpus`` — and
     the per-core rule is the one that survives a host-width change, so
     silently skipping it against pre-r05 baselines hides exactly the
     normalization it exists for. Derive it (value / host_cpus) when
@@ -317,7 +317,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         description="per-metric regression gate between two bench records")
     parser.add_argument("baseline", nargs="?",
-                        help="baseline record (e.g. BENCH_r03.json)")
+                        help="baseline record (e.g. BENCH_r11.json)")
     parser.add_argument("current", nargs="?",
                         help="current record (e.g. BENCH_r05.json)")
     parser.add_argument("--check", metavar="DIR", nargs="?", const=".",
