@@ -1,0 +1,147 @@
+"""The comparison that decides ``correct`` for a train cell: the program's
+first steps against the plain reference's.
+
+The program's numbers are read off the very trainer the window then
+drives: each step's loss, the norm of the first gradient as the optimizer
+got it (Adam's first moment after one step is ``(1 - b1) * g``), and the
+norm of the parameters' change after the compared steps. The reference
+follows the same batches from the same seeded weights in float32 with
+``jax.default_matmul_precision("highest")`` and a plain Adam written here.
+Norms are compared leaf by leaf, as the gap between the two norms over the
+reference's norm of that leaf or of the median leaf, whichever is larger.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import statistics
+import time
+from typing import Any, Dict, List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+STEPS = 3
+
+
+@dataclasses.dataclass
+class Compared:
+    """One number of the comparison beside its limit."""
+
+    name: str
+    value: float
+    limit: float
+
+    @property
+    def ok(self) -> bool:
+        return math.isfinite(self.value) and self.value <= self.limit
+
+    def line(self) -> str:
+        verdict = "ok" if self.ok else "FAILED"
+        return (f"# compared {self.name}: {self.value:.6g} "
+                f"(limit {self.limit:g}) {verdict}")
+
+
+def leaf_norms(tree: Any) -> Dict[str, float]:
+    """Euclidean norm of every leaf, by its path, as host floats."""
+    norms = jax.jit(lambda t: jax.tree.map(
+        lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), t))(
+            tree)
+    flat, _ = jax.tree_util.tree_flatten_with_path(norms)
+    return {jax.tree_util.keystr(path): float(value) for path, value in flat}
+
+
+def diff_norms(after: Any, before: Any) -> Dict[str, float]:
+    return leaf_norms(jax.jit(lambda a, b: jax.tree.map(
+        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b))(
+            after, before))
+
+
+def worst_leaf_gap(program: Dict[str, float], reference: Dict[str, float]
+                   ) -> Tuple[float, str]:
+    """(largest gap, its leaf): ``|program - reference|`` over the larger
+    of the reference's norm of that leaf and of its median leaf."""
+    if program.keys() != reference.keys():
+        raise ValueError("the program's and the reference's parameters have "
+                         f"different leaves: {sorted(program)[:3]}... vs "
+                         f"{sorted(reference)[:3]}...")
+    floor = statistics.median(reference.values())
+    worst, where = 0.0, ""
+    for leaf, ref in reference.items():
+        scale = max(ref, floor)
+        gap = abs(program[leaf] - ref) / scale if scale > 0 else (
+            0.0 if program[leaf] == 0 else math.inf)
+        if not math.isfinite(gap):
+            return math.inf, leaf
+        if gap >= worst:
+            worst, where = gap, leaf
+    return worst, where
+
+
+def adam_update(params, grads, mu, nu, count: int, opt: Dict[str, Any]):
+    """One step of Adam (Kingma & Ba 2015, bias-corrected, no decay)."""
+    b1, b2, eps, lr = opt["b1"], opt["b2"], opt["eps"], opt["learning_rate"]
+    mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, mu, grads)
+    nu = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, nu, grads)
+    c1, c2 = 1 - b1 ** count, 1 - b2 ** count
+    params = jax.tree.map(
+        lambda p, m, v: p - lr * (m / c1) / (jnp.sqrt(v / c2) + eps),
+        params, mu, nu)
+    return params, mu, nu
+
+
+def reference_trajectory(ref, sizes: Dict[str, Any], params0: Any,
+                         batches: Sequence[Tuple[Sequence[Any], Any]],
+                         opt: Dict[str, Any], seed_key,
+                         lower_precision: bool = False) -> Dict[str, Any]:
+    """Losses, first-gradient norms and parameter-change norms of the
+    plain reference over ``batches`` from ``params0``.
+
+    ``lower_precision`` is the control: the same reference with its
+    parameters, moments and arithmetic in bfloat16, the precision below
+    the float32 parameters the configurations state. Put in the program's
+    place, it has to come out as not correct."""
+    if opt["name"] != "adam":
+        raise ValueError(f"the reference knows Adam, not {opt['name']!r}")
+    if lower_precision:
+        params0 = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params0)
+    with jax.default_matmul_precision(
+            "default" if lower_precision else "highest"):
+        params = params0
+        mu = jax.tree.map(jnp.zeros_like, params0)
+        nu = jax.tree.map(jnp.zeros_like, params0)
+        losses: List[float] = []
+        grad_norms: Dict[str, float] = {}
+        for i, (features, labels) in enumerate(batches):
+            t0 = time.perf_counter()
+            value, grads = ref.value_and_grad(sizes, params, features,
+                                              labels, i, seed_key)
+            losses.append(float(value))
+            print(f"# reference step {i}: loss and gradient "
+                  f"{time.perf_counter() - t0:.2f} s", flush=True)
+            if i == 0:
+                grad_norms = leaf_norms(grads)
+            params, mu, nu = jax.jit(
+                lambda p, g, m, v, c=i + 1: adam_update(p, g, m, v, c, opt))(
+                    params, grads, mu, nu)
+        return {"losses": losses, "grad_norms": grad_norms,
+                "change_norms": diff_norms(params, params0)}
+
+
+def compare(program: Dict[str, Any], reference: Dict[str, Any],
+            limits: Dict[str, float]) -> List[Compared]:
+    """The numbers of the comparison, each beside its limit."""
+    loss_gap = max(abs(p - r) / max(abs(r), 1e-12)
+                   for p, r in zip(program["losses"], reference["losses"]))
+    grad_gap, grad_leaf = worst_leaf_gap(program["grad_norms"],
+                                         reference["grad_norms"])
+    change_gap, change_leaf = worst_leaf_gap(program["change_norms"],
+                                             reference["change_norms"])
+    return [
+        Compared("loss_gap", loss_gap, limits["loss_gap"]),
+        Compared(f"first_grad_norm_gap[{grad_leaf}]", grad_gap,
+                 limits["first_grad_norm_gap"]),
+        Compared(f"param_change_norm_gap[{change_leaf}]", change_gap,
+                 limits["param_change_norm_gap"]),
+    ]
