@@ -274,6 +274,10 @@ class ShufflingDataset:
         self._seed = seed
         self._skip_batches = 0
         self._epoch: Optional[int] = None
+        # Set by a binding that ends the epoch itself (JaxShufflingDataset):
+        # the epoch's log line then waits for that layer, which adds the
+        # split of the turnover into the next epoch.
+        self.hold_epoch_log = False
         # Guards against iterating without a fresh set_epoch
         # (reference: dataset.py:143-168).
         self._last_epoch: Optional[int] = None
@@ -424,7 +428,8 @@ class ShufflingDataset:
         # Epoch-complete hook: logs the one-line bottleneck verdict
         # (first completion wins — the JAX binding's consumer-side end
         # calls this too, whichever finishes first).
-        rt_telemetry.epoch_complete(self._epoch, source="dataset")
+        rt_telemetry.epoch_complete(self._epoch, source="dataset",
+                                    hold_log=self.hold_epoch_log)
         if (self._num_epochs is not None
                 and self._epoch == self._num_epochs - 1
                 and self._shuffle_result is not None):

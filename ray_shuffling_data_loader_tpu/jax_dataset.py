@@ -27,7 +27,7 @@ import concurrent.futures as cf
 import itertools
 import queue as _queue
 import threading
-import timeit
+import time
 from typing import Any, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,11 +38,11 @@ from ray_shuffling_data_loader_tpu.dataset import (ShufflingDataset,
                                                    slice_batches)
 from ray_shuffling_data_loader_tpu.runtime import faults as rt_faults
 from ray_shuffling_data_loader_tpu.runtime import latency as rt_latency
+from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 from ray_shuffling_data_loader_tpu.runtime import retry as rt_retry
 from ray_shuffling_data_loader_tpu.runtime import telemetry as rt_telemetry
 from ray_shuffling_data_loader_tpu.stats import BatchWaitStats
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
-from ray_shuffling_data_loader_tpu.utils.tracing import trace_span
 
 logger = setup_custom_logger(__name__)
 
@@ -327,7 +327,8 @@ class _BatchConverter:
             # the same task key the device_transfer fault site draws on,
             # so an injected transfer fault joins telemetry by
             # (kind, epoch, task) like every other site. The stage's
-            # latency samples come from the epoch-tagged transfer spans.
+            # latency samples come from the epoch-tagged transfer spans
+            # (_timed_transfer).
             rt_telemetry.record("device_transfer", task=self._transfer_seq,
                                 attempt=True)
             rt_faults.inject("device_transfer", task=self._transfer_seq)
@@ -343,9 +344,9 @@ class _BatchConverter:
 
     def _note_device_done(self) -> None:
         """One device-transfer completion on the latency plane (no-op
-        without a probe). ``device_put`` is async — the span closed here
-        is dispatch-complete, the same boundary the ``device_transfer``
-        telemetry stage measures."""
+        without a probe). ``device_put`` is async — the hop closed here
+        is dispatch-complete; the ``device_transfer`` telemetry span runs
+        on to the landed copy (:func:`_timed_transfer`)."""
         if self.latency_probe is not None:
             self.latency_probe.device_done()
 
@@ -521,6 +522,78 @@ class _BatchConverter:
         return slicer(features, label, np.int32(batch_index))
 
 
+class _TransferReaper:
+    """Closes ``device_transfer`` spans when the copy has landed.
+
+    ``jax.device_put`` returns at dispatch, and no thread of the pipeline
+    waits on the host for the copy: the consumer's carve and train step
+    wait for it on the device. This thread waits in their place, out of
+    the pipeline's path (the producer and the consumer gain no wait), and
+    closes each span the dispatching thread handed off. One daemon thread
+    a process, started with the first transfer that telemetry times."""
+
+    def __init__(self):
+        self._queue: "_queue.SimpleQueue" = _queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+
+    def submit(self, span, arrays, **attrs) -> None:
+        if self._thread is None:
+            with self._lock:
+                if self._thread is None:
+                    self._thread = threading.Thread(
+                        target=self._run, daemon=True,
+                        name="rsdl-transfer-reaper")
+                    self._thread.start()
+        self._queue.put((span, arrays, attrs))
+
+    def _run(self) -> None:
+        while True:
+            span, arrays, attrs = self._queue.get()
+            error = self._wait_landed(arrays)
+            arrays = None
+            if error is not None:
+                attrs["error"] = error
+            rt_telemetry.span_end(span, **attrs)
+
+    @staticmethod
+    def _wait_landed(arrays) -> Optional[str]:
+        """Wait for the copy; the name of what went wrong, if anything.
+        A buffer the consumer donated away, a device error the consumer
+        will meet itself: the span still closes."""
+        import jax
+        try:
+            jax.block_until_ready(arrays)
+        except Exception as e:  # noqa: BLE001 - observation only
+            return type(e).__name__
+        return None
+
+
+_TRANSFER_REAPER = _TransferReaper()
+
+
+def _timed_transfer(epoch: Optional[int], transfer, *args):
+    """One host->device transfer under the ``device_transfer`` span
+    (``rsdl.feed.transfer``). The span opens before the dispatch and
+    closes when the copy has landed, on the reaper's thread;
+    ``dispatch_s`` on the event is the part this thread spent in
+    ``device_put``."""
+    # Closed by the reaper, or right here when the dispatch raises: the
+    # handoff is the point. rsdl-lint: disable=span-unbalanced
+    span = rt_telemetry.span_begin("device_transfer", epoch=epoch,
+                                   handoff=True)
+    if span is None:
+        return transfer(*args)
+    try:
+        out = transfer(*args)
+    except BaseException:
+        rt_telemetry.span_end(span, failed=True)
+        raise
+    _TRANSFER_REAPER.submit(span, out,
+                            dispatch_s=rt_telemetry.stamp() - span.t0)
+    return out
+
+
 def _persistent_producer(dataset: ShufflingDataset,
                          converter: _BatchConverter,
                          out: "_queue.Queue",
@@ -567,12 +640,10 @@ def _persistent_producer(dataset: ShufflingDataset,
                     return
             else:
                 for table in dataset:
-                    with trace_span("batch_convert", kind="convert",
-                                    epoch=epoch):
+                    with rt_telemetry.span("convert", epoch=epoch):
                         arrays = converter.convert(table)
-                    with trace_span("batch_transfer",
-                                    kind="device_transfer", epoch=epoch):
-                        batch = converter.transfer(arrays)
+                    batch = _timed_transfer(epoch, converter.transfer,
+                                            arrays)
                     if not put(("batch", epoch, batch)):
                         return
             if not put(("end", epoch, None)):
@@ -585,8 +656,7 @@ def _staged_transfer(converter: _BatchConverter, arrays, epoch):
     """One per-batch host->device transfer on the staging thread — the
     same retried/fault-injected ``converter.transfer`` (and the same
     telemetry span) the serial path runs inline."""
-    with trace_span("batch_transfer", kind="device_transfer", epoch=epoch):
-        return converter.transfer(arrays)
+    return _timed_transfer(epoch, converter.transfer, arrays)
 
 
 def _produce_epoch_batches_staged(dataset, converter: _BatchConverter,
@@ -602,7 +672,7 @@ def _produce_epoch_batches_staged(dataset, converter: _BatchConverter,
             max_workers=1, thread_name_prefix="rsdl-device-stage") as pool:
         pending = None
         for table in dataset:
-            with trace_span("batch_convert", kind="convert", epoch=epoch):
+            with rt_telemetry.span("convert", epoch=epoch):
                 arrays = converter.convert(table)
             fut = pool.submit(_staged_transfer, converter, arrays, epoch)
             if pending is not None and not put(
@@ -682,14 +752,13 @@ def _produce_epoch_tables(dataset: ShufflingDataset,
         pieces_f = [np.concatenate([p[0][i] for p in carry], axis=0)
                     for i in range(len(carry[0][0]))]
         pieces_l = np.concatenate([p[1] for p in carry], axis=0)
-        with trace_span("batch_transfer", kind="device_transfer",
-                        epoch=epoch):
-            return converter.transfer((pieces_f, pieces_l))
+        return _timed_transfer(epoch, converter.transfer,
+                               (pieces_f, pieces_l))
 
     tables = dataset.iter_tables()
     emitted = False  # anything put() or carried yet this epoch
     for table in tables:
-        with trace_span("table_convert", kind="convert", epoch=epoch):
+        with rt_telemetry.span("convert", epoch=epoch):
             features, label = converter.convert(table)
         n = table.num_rows
         if any(f.shape[0] != n for f in features) or label.shape[0] != n:
@@ -714,12 +783,10 @@ def _produce_epoch_tables(dataset: ShufflingDataset,
                 for batch_table in slice_batches(
                         itertools.chain([table], tables), bs,
                         dataset.drop_last):
-                    with trace_span("batch_convert", kind="convert",
-                                    epoch=epoch):
+                    with rt_telemetry.span("convert", epoch=epoch):
                         arrays = converter.convert(batch_table)
-                    with trace_span("batch_transfer",
-                                    kind="device_transfer", epoch=epoch):
-                        batch = converter.transfer(arrays)
+                    batch = _timed_transfer(epoch, converter.transfer,
+                                            arrays)
                     if not put(("batch", epoch, batch)):
                         return False
                 return True
@@ -765,22 +832,18 @@ def _produce_epoch_tables(dataset: ShufflingDataset,
                 nb = min(k, full_batches - done)
                 lo = offset + done * bs
                 hi = lo + nb * bs
-                with trace_span("table_transfer", kind="device_transfer",
-                                epoch=epoch):
-                    item = _supervised_transfer_table(
-                        converter,
-                        ([f[lo:hi] for f in features], label[lo:hi]),
-                        nb, bs, queue_depth)
+                item = _timed_transfer(
+                    epoch, _supervised_transfer_table, converter,
+                    ([f[lo:hi] for f in features], label[lo:hi]),
+                    nb, bs, queue_depth)
                 if not put(("table", epoch, (item, nb))):
                     return False
                 done += nb
             for b in range(done, full_batches):
                 lo = offset + b * bs
-                with trace_span("batch_transfer", kind="device_transfer",
-                                epoch=epoch):
-                    batch = converter.transfer(
-                        ([f[lo:lo + bs] for f in features],
-                         label[lo:lo + bs]))
+                batch = _timed_transfer(
+                    epoch, converter.transfer,
+                    ([f[lo:lo + bs] for f in features], label[lo:lo + bs]))
                 if not put(("batch", epoch, batch)):
                     return False
             offset += full_batches * bs
@@ -791,6 +854,61 @@ def _produce_epoch_tables(dataset: ShufflingDataset,
         if not put(("batch", epoch, flush_carry())):
             return False
     return True
+
+
+class _ConsumerSpans:
+    """The spans of the consumer's thread inside the feed, and what is
+    summed from them: the wall and CPU seconds of the two
+    ``rsdl_feed_consumer_*`` counters and the split of the epoch turnover
+    under way. The counters leave the wait on the queue out: wall minus
+    CPU in the spans that do no waiting of their own (the carve's
+    dispatch, ``set_epoch``, the epoch's end) is time spent waiting for
+    the GIL or the scheduler.
+
+    A turnover runs from the consumer's ``next()`` that meets an epoch's
+    end to the yield of the next epoch's first batch; its parts are the
+    spans closed with a ``part`` meanwhile, the rest is the caller's own
+    work between them."""
+
+    def __init__(self):
+        self._wall = rt_metrics.counter(
+            "rsdl_feed_consumer_wall_seconds_total",
+            "consumer-thread seconds inside the device feed's spans, the "
+            "wait on its queue apart")
+        self._cpu = rt_metrics.counter(
+            "rsdl_feed_consumer_cpu_seconds_total",
+            "consumer-thread CPU seconds inside those spans")
+        self._turnover: Optional[dict] = None
+
+    def span_begin(self, kind: str, epoch: Optional[int], **attrs):
+        return rt_telemetry.span_begin(kind, epoch=epoch, cpu=True, **attrs)
+
+    def span_end(self, span, part: Optional[str] = None,
+                 counted: bool = True) -> None:
+        rt_telemetry.span_end(span)
+        if span is None:
+            return
+        if counted:
+            self._wall.inc(span.dur_s)
+            self._cpu.inc(span.cpu_s)
+        if part is not None and self._turnover is not None:
+            parts = self._turnover["parts"]
+            parts[part] = parts.get(part, 0.0) + span.dur_s
+
+    def turnover_begin(self, epoch: int, entered_at: float, end_get) -> None:
+        """The get that ``end_get`` timed returned ``epoch``'s end; the
+        consumer had entered that ``next()`` at ``entered_at``."""
+        if end_get is not None:
+            self._turnover = {"epoch": epoch, "t0": entered_at,
+                              "parts": {"end_get": end_get.dur_s}}
+
+    def turnover_end(self) -> None:
+        """The next epoch's first batch is about to be yielded."""
+        turnover, self._turnover = self._turnover, None
+        if turnover is not None:
+            rt_telemetry.turnover_complete(
+                turnover["epoch"], rt_telemetry.stamp() - turnover["t0"],
+                turnover["parts"])
 
 
 def _release_producer(stop: threading.Event, out: "_queue.Queue") -> None:
@@ -1014,6 +1132,7 @@ class JaxShufflingDataset:
             start_epoch=start_epoch, map_transform=map_transform,
             reduce_transform=reduce_transform, file_cache=file_cache,
             max_inflight_bytes=max_inflight_bytes, spill_dir=spill_dir)
+        self._dataset.hold_epoch_log = True  # this layer logs it
         self._mesh = mesh
         self._data_axis = data_axis
         self._prefetch_size = max(1, prefetch_size)
@@ -1066,8 +1185,16 @@ class JaxShufflingDataset:
         self._epoch_set = False          # set_epoch called since last iter
         self._closed = False             # close() is terminal
         self._active_gen = None          # live persistent-epoch generator
+        self._spans = _ConsumerSpans()   # consumer-thread telemetry
 
     def set_epoch(self, epoch: int, skip_batches: int = 0) -> None:
+        span = self._spans.span_begin("set_epoch", epoch)
+        try:
+            self._set_epoch(epoch, skip_batches)
+        finally:
+            self._spans.span_end(span, part="set_epoch")
+
+    def _set_epoch(self, epoch: int, skip_batches: int) -> None:
         if not self._persistent:
             self._dataset.set_epoch(epoch, skip_batches=skip_batches)
             return
@@ -1162,6 +1289,26 @@ class JaxShufflingDataset:
     def _convert(self, table: pa.Table):
         return self._converter.convert(table)
 
+    def _timed_get(self, out: "_queue.Queue", epoch: Optional[int],
+                   first: bool):
+        """The consumer's get of the next item, under the ``batch_wait``
+        span (``rsdl.feed.queue_get``; an epoch's first marked, and a
+        part of the turnover). Returns the item and the closed span.
+        Durations are on the recorder's own clock (``time.monotonic``),
+        ``batch_wait_stats``' too."""
+        wait_start = time.monotonic()
+        span = self._spans.span_begin("batch_wait", epoch,
+                                      **({"first": True} if first else {}))
+        try:
+            item = out.get()
+        finally:
+            # The wait is not the consumer's own work: it stays out of
+            # the wall/CPU counters.
+            self._spans.span_end(span, part="first_get" if first else None,
+                                 counted=False)
+        self.batch_wait_stats.record(time.monotonic() - wait_start)
+        return item, span
+
     def _transfer(self, arrays_label):
         return self._converter.transfer(arrays_label)
 
@@ -1211,21 +1358,17 @@ class JaxShufflingDataset:
                 daemon=True, name="rsdl-jax-prefetch")
             weakref.finalize(self, _release_producer, self._stop, self._out)
             self._thread.start()
-        resume_t = None  # when the consumer last resumed after a yield
+        spans = self._spans
+        # The last epoch has no turnover to wait for: its line is logged
+        # at its end. An earlier epoch's is held for the turnover's split.
+        last_epoch = (self._dataset.num_epochs is not None
+                      and epoch >= self._dataset.num_epochs - 1)
+        entered_at = rt_telemetry.stamp()  # this next() began here
+        first_get = True
         try:
             while True:
-                wait_start = timeit.default_timer()
-                if resume_t is not None:
-                    # The gap between the previous batch's yield and this
-                    # get() is the consumer's own work — the train_step
-                    # stage of the bottleneck decomposition.
-                    rt_telemetry.record("train_step", epoch=epoch,
-                                        dur_s=wait_start - resume_t,
-                                        t=wait_start)
-                item = self._out.get()
-                wait_s = timeit.default_timer() - wait_start
-                self.batch_wait_stats.record(wait_s)
-                rt_telemetry.record("batch_wait", epoch=epoch, dur_s=wait_s)
+                item, get_span = self._timed_get(self._out, epoch, first_get)
+                first_get = False
                 if isinstance(item, BaseException):
                     raise item
                 kind, item_epoch, payload = item
@@ -1235,17 +1378,24 @@ class JaxShufflingDataset:
                     continue
                 assert item_epoch == epoch, (item_epoch, epoch)
                 if kind == "end":
-                    rt_telemetry.epoch_complete(epoch, source="jax")
+                    if not last_epoch:
+                        spans.turnover_begin(epoch, entered_at, get_span)
+                    end_span = spans.span_begin("epoch_end", epoch)
+                    try:
+                        rt_telemetry.epoch_complete(
+                            epoch, source="jax", hold_log=not last_epoch)
+                    finally:
+                        spans.span_end(end_span, part="epoch_end")
                     break
                 if kind == "table":
                     # Bulk device table: carve batches on-device. Later
-                    # batches of the same item record zero wait — accurate:
-                    # they are already in HBM. The FIRST carve of each item
-                    # is watchdog-supervised (it dispatches the jitted
-                    # slicer — the carve half of the bulk path's liveness
-                    # contract); a deadline miss files a stall and, under
-                    # "degrade", stops the producer sending further bulk
-                    # items.
+                    # batches of the same item count as zero wait —
+                    # accurate: they are already in HBM. The FIRST carve of
+                    # each item is watchdog-supervised (it dispatches the
+                    # jitted slicer — the carve half of the bulk path's
+                    # liveness contract); a deadline miss files a stall
+                    # and, under "degrade", stops the producer sending
+                    # further bulk items.
                     dev_table, n_batches = payload
                     start = 0
                     if self._consumer_skip:
@@ -1255,35 +1405,48 @@ class JaxShufflingDataset:
                     wd = self._converter.watchdog
                     for b in range(start, n_batches):
                         if b > start:
-                            now = timeit.default_timer()
                             self.batch_wait_stats.record(0.0)
-                            rt_telemetry.record("batch_wait", epoch=epoch,
-                                                dur_s=0.0, t=now)
-                            if resume_t is not None:
-                                rt_telemetry.record(
-                                    "train_step", epoch=epoch,
-                                    dur_s=now - resume_t, t=now)
-                            batch = self._converter.slice_batch(
-                                dev_table, b, bs)
-                        elif wd is not None:
-                            with wd.watch(
-                                    "jax_dataset.bulk_carve",
-                                    deadline_s=(self._converter
-                                                .bulk_transfer_deadline_s),
-                                    on_stall=self._converter._on_bulk_stall):
+                            rt_telemetry.observe_batch_wait(epoch)
+                        carve_span = spans.span_begin("carve", epoch, batch=b)
+                        try:
+                            if b == start and wd is not None:
+                                with wd.watch(
+                                        "jax_dataset.bulk_carve",
+                                        deadline_s=(
+                                            self._converter
+                                            .bulk_transfer_deadline_s),
+                                        on_stall=(self._converter
+                                                  ._on_bulk_stall)):
+                                    batch = self._converter.slice_batch(
+                                        dev_table, b, bs)
+                            else:
                                 batch = self._converter.slice_batch(
                                     dev_table, b, bs)
-                        else:
-                            batch = self._converter.slice_batch(
-                                dev_table, b, bs)
-                        yield batch
-                        resume_t = timeit.default_timer()
+                        finally:
+                            spans.span_end(carve_span, part="first_carve")
+                        spans.turnover_end()
+                        # From the yield to the consumer's next next():
+                        # the consumer's own work, the train_step stage of
+                        # the bottleneck decomposition.
+                        step_span = rt_telemetry.span_begin("train_step",
+                                                            epoch=epoch)
+                        try:
+                            yield batch
+                        finally:
+                            rt_telemetry.span_end(step_span)
+                        entered_at = rt_telemetry.stamp()
                     continue
                 if self._consumer_skip:
                     self._consumer_skip -= 1
                     continue
-                yield payload
-                resume_t = timeit.default_timer()
+                spans.turnover_end()
+                step_span = rt_telemetry.span_begin("train_step",
+                                                    epoch=epoch)
+                try:
+                    yield payload
+                finally:
+                    rt_telemetry.span_end(step_span)
+                entered_at = rt_telemetry.stamp()
         finally:
             # Runs on normal completion AND on mid-epoch abandonment
             # (GeneratorExit from iterator.close() / going out of scope):
@@ -1312,6 +1475,7 @@ class JaxShufflingDataset:
         """
         self._closed = True
         self._stop.set()
+        rt_telemetry.flush_epoch_log()
         if self._thread is not None:
             # Join BEFORE draining: the producer notices the stop event
             # within one bounded-put poll (0.1s) and exits, so nothing
@@ -1369,13 +1533,10 @@ class JaxShufflingDataset:
                         return
                 else:
                     for table in self._dataset:
-                        with trace_span("batch_convert", kind="convert",
-                                        epoch=epoch):
+                        with rt_telemetry.span("convert", epoch=epoch):
                             arrays = self._convert(table)
-                        with trace_span("batch_transfer",
-                                        kind="device_transfer",
-                                        epoch=epoch):
-                            batch = self._transfer(arrays)
+                        batch = _timed_transfer(epoch, self._transfer,
+                                                arrays)
                         if not _put(batch):
                             return
                 _put(SENTINEL)
@@ -1386,26 +1547,35 @@ class JaxShufflingDataset:
                                   name="rsdl-jax-prefetch")
         thread.start()
         epoch = getattr(self._dataset, "_epoch", None)
-        resume_t = None
+        spans = self._spans
+        entered_at = rt_telemetry.stamp()
+        first_get = True
         try:
             while True:
-                wait_start = timeit.default_timer()
-                if resume_t is not None:
-                    rt_telemetry.record("train_step", epoch=epoch,
-                                        dur_s=wait_start - resume_t,
-                                        t=wait_start)
-                item = out.get()
-                wait_s = timeit.default_timer() - wait_start
-                self.batch_wait_stats.record(wait_s)
-                rt_telemetry.record("batch_wait", epoch=epoch, dur_s=wait_s)
+                item, get_span = self._timed_get(out, epoch, first_get)
+                first_get = False
                 if item is SENTINEL:
                     if epoch is not None:
-                        rt_telemetry.epoch_complete(epoch, source="jax")
+                        # Epochs come in any order here, so the verdict
+                        # line is logged now and a turnover's split on a
+                        # line of its own.
+                        spans.turnover_begin(epoch, entered_at, get_span)
+                        end_span = spans.span_begin("epoch_end", epoch)
+                        try:
+                            rt_telemetry.epoch_complete(epoch, source="jax")
+                        finally:
+                            spans.span_end(end_span, part="epoch_end")
                     break
                 if isinstance(item, BaseException):
                     raise item
-                yield item
-                resume_t = timeit.default_timer()
+                spans.turnover_end()
+                step_span = rt_telemetry.span_begin("train_step",
+                                                    epoch=epoch)
+                try:
+                    yield item
+                finally:
+                    rt_telemetry.span_end(step_span)
+                entered_at = rt_telemetry.stamp()
         finally:
             # Consumer done or abandoned mid-epoch: release the producer
             # (it would otherwise block forever on the bounded queue,

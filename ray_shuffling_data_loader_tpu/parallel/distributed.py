@@ -37,6 +37,7 @@ sh = importlib.import_module("ray_shuffling_data_loader_tpu.shuffle")
 from ray_shuffling_data_loader_tpu.dataset import batch_consumer as queue_batch_consumer
 from ray_shuffling_data_loader_tpu.ops import partition as ops
 from ray_shuffling_data_loader_tpu.parallel.transport import TcpTransport
+from ray_shuffling_data_loader_tpu.runtime import telemetry as rt_telemetry
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
 
 logger = setup_custom_logger(__name__)
@@ -158,17 +159,20 @@ def _reduce_task(reducer_index: int, seed: int, epoch: int,
                  spill_manager=None, gather_threads=None) -> pa.Table:
     """Collect this reducer's chunk from every global file, then
     concat + seeded permute (global-index RNG => topology-independent)."""
-    chunks: List = []  # LazyChunk (local) or pa.Table (remote)
-    for file_index in range(plan.num_files):
-        src = plan.file_host(file_index)
-        if src == transport.host_id:
-            chunks.append(local_map_refs[file_index].result()[reducer_index])
-        else:
-            payload = transport.recv(src, (epoch, reducer_index, file_index))
-            chunks.append(deserialize_table(payload))
-    shuffled = sh.shuffle_reduce(reducer_index, seed, epoch, chunks,
-                                 stats_collector, reduce_transform,
-                                 gather_threads)
+    # The whole reduce task body under one span, as on one host
+    # (shuffle._reduce_task): the receive from the other hosts included.
+    with rt_telemetry.span("reduce_gather", epoch=epoch, task=reducer_index):
+        chunks: List = []  # LazyChunk (local) or pa.Table (remote)
+        for file_index in range(plan.num_files):
+            src = plan.file_host(file_index)
+            if src == transport.host_id:
+                chunks.append(local_map_refs[file_index].result()[reducer_index])
+            else:
+                payload = transport.recv(src, (epoch, reducer_index, file_index))
+                chunks.append(deserialize_table(payload))
+        shuffled = sh.shuffle_reduce(reducer_index, seed, epoch, chunks,
+                                     stats_collector, reduce_transform,
+                                     gather_threads)
     return sh.account_and_maybe_spill(shuffled, spill_manager,
                                       epoch=epoch, task=reducer_index,
                                       seed=seed)

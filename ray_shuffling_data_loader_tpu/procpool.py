@@ -309,89 +309,95 @@ def _worker_task_map(payload: dict) -> dict:
     seed = payload["seed"]
     rt_telemetry.set_trace_seed(seed)
     start = timeit.default_timer()
-    table = None
-    table_seg = payload.get("table_seg")
-    wrote_table_bytes = 0
-    cached = False
-    if table_seg is not None:
-        try:
-            table = _cached_segment_table(table_seg)
-            cached = True
-        except (OSError, pa.ArrowInvalid) as e:
-            logger.warning("table segment %s unreadable (%s); re-decoding",
-                           table_seg, e)
-            _seg_table_cache.pop(table_seg, None)
-            table = None
-            table_seg = None
-    grouped = False
-    grouped_offsets = None
-    if table is None:
-        import functools
-        read_retry = rt_retry.RetryPolicy.for_component(
-            "map_read", retryable=sh._transient_read_retryable)
-        map_transform = _load_blob(payload.get("map_transform"))
-        streamed = None
-        tried_fused = False
-        # Streaming fast path — epoch-scoped segments only: a cross-epoch
-        # cache grant must publish the DECODED table (the grouped layout
-        # depends on (seed, epoch), so it cannot be reused next epoch).
-        if not payload.get("cache_grant") and sh._fused_pipeline_enabled():
-            tried_fused = True
-            rt_faults.inject("map_read", epoch=epoch, task=file_index)
-            fused_fn = functools.partial(
-                sh._fused_stream_columns, filename,
-                payload["num_reducers"], seed, epoch, file_index,
-                map_transform)
+    # The worker's own recorder gets the real map_read span (the driver
+    # only hears the duration back, through observe_stage). It covers the
+    # read and its retries, or its quarantine; not the partition plan.
+    read_span = rt_telemetry.span_begin("map_read", epoch=epoch,
+                                        task=file_index)
+    try:
+        table = None
+        table_seg = payload.get("table_seg")
+        wrote_table_bytes = 0
+        cached = False
+        if table_seg is not None:
             try:
-                streamed = read_retry.call(
-                    fused_fn, describe=f"stream {filename}")
+                table = _cached_segment_table(table_seg)
+                cached = True
             except (OSError, pa.ArrowInvalid) as e:
-                if payload.get("on_bad_file") != "skip":
-                    raise
-                return {"quarantined": rt_faults.QuarantinedFile(
-                    filename=filename, epoch=epoch, file_index=file_index,
-                    error=f"{type(e).__name__}: {e}")}
-        if streamed is not None:
-            out_cols, grouped_offsets, names = streamed
-            # The segment IS the grouped layout: reducer r's rows are the
-            # contiguous slice [offsets[r], offsets[r+1]) in original row
-            # order, so the reduce stage slices instead of gathering —
-            # bit-identical rows either way (same stable order).
-            table = pa.table({name: out_cols[name] for name in names})
-            grouped = True
-        else:
-            try:
-                table = sh._read_map_table(filename, epoch, file_index,
-                                           read_retry,
-                                           inject=not tried_fused)
-            except (OSError, pa.ArrowInvalid) as e:
-                if payload.get("on_bad_file") != "skip":
-                    raise
-                return {"quarantined": rt_faults.QuarantinedFile(
-                    filename=filename, epoch=epoch, file_index=file_index,
-                    error=f"{type(e).__name__}: {e}")}
-            if map_transform is not None:
-                table = map_transform(table)
-            # Single-chunk columns => zero-copy numpy views for every
-            # reducer that maps this segment (same invariant as the
-            # thread-mode cache).
-            table = table.combine_chunks()
-        # The reducers gather from the SEGMENT, so the decoded table must
-        # always be published — either into the cross-epoch cache slot the
-        # driver granted, or into an epoch-scoped segment the driver
-        # unlinks when the epoch's reduces finish. A write failure is a
-        # task failure (there is nothing for the reduce stage to read).
-        write_seg = payload.get("write_table_seg") or \
-            f"{payload['idx_seg']}.table.arrow"
-        wrote_table_bytes = write_table_segment(table, write_seg)
-        cached = bool(payload.get("cache_grant")) and \
-            write_seg == payload.get("write_table_seg")
-        if cached:
-            _seg_table_cache[write_seg] = table
-        table_seg = write_seg
+                logger.warning("table segment %s unreadable (%s); re-decoding",
+                               table_seg, e)
+                _seg_table_cache.pop(table_seg, None)
+                table = None
+                table_seg = None
+        grouped = False
+        grouped_offsets = None
+        if table is None:
+            import functools
+            read_retry = rt_retry.RetryPolicy.for_component(
+                "map_read", retryable=sh._transient_read_retryable)
+            map_transform = _load_blob(payload.get("map_transform"))
+            streamed = None
+            tried_fused = False
+            # Streaming fast path — epoch-scoped segments only: a cross-epoch
+            # cache grant must publish the DECODED table (the grouped layout
+            # depends on (seed, epoch), so it cannot be reused next epoch).
+            if not payload.get("cache_grant") and sh._fused_pipeline_enabled():
+                tried_fused = True
+                rt_faults.inject("map_read", epoch=epoch, task=file_index)
+                fused_fn = functools.partial(
+                    sh._fused_stream_columns, filename,
+                    payload["num_reducers"], seed, epoch, file_index,
+                    map_transform)
+                try:
+                    streamed = read_retry.call(
+                        fused_fn, describe=f"stream {filename}")
+                except (OSError, pa.ArrowInvalid) as e:
+                    if payload.get("on_bad_file") != "skip":
+                        raise
+                    return {"quarantined": rt_faults.QuarantinedFile(
+                        filename=filename, epoch=epoch, file_index=file_index,
+                        error=f"{type(e).__name__}: {e}")}
+            if streamed is not None:
+                out_cols, grouped_offsets, names = streamed
+                # The segment IS the grouped layout: reducer r's rows are the
+                # contiguous slice [offsets[r], offsets[r+1]) in original row
+                # order, so the reduce stage slices instead of gathering —
+                # bit-identical rows either way (same stable order).
+                table = pa.table({name: out_cols[name] for name in names})
+                grouped = True
+            else:
+                try:
+                    table = sh._read_map_table(filename, epoch, file_index,
+                                               read_retry,
+                                               inject=not tried_fused)
+                except (OSError, pa.ArrowInvalid) as e:
+                    if payload.get("on_bad_file") != "skip":
+                        raise
+                    return {"quarantined": rt_faults.QuarantinedFile(
+                        filename=filename, epoch=epoch, file_index=file_index,
+                        error=f"{type(e).__name__}: {e}")}
+                if map_transform is not None:
+                    table = map_transform(table)
+                # Single-chunk columns => zero-copy numpy views for every
+                # reducer that maps this segment (same invariant as the
+                # thread-mode cache).
+                table = table.combine_chunks()
+            # The reducers gather from the SEGMENT, so the decoded table must
+            # always be published — either into the cross-epoch cache slot the
+            # driver granted, or into an epoch-scoped segment the driver
+            # unlinks when the epoch's reduces finish. A write failure is a
+            # task failure (there is nothing for the reduce stage to read).
+            write_seg = payload.get("write_table_seg") or \
+                f"{payload['idx_seg']}.table.arrow"
+            wrote_table_bytes = write_table_segment(table, write_seg)
+            cached = bool(payload.get("cache_grant")) and \
+                write_seg == payload.get("write_table_seg")
+            if cached:
+                _seg_table_cache[write_seg] = table
+            table_seg = write_seg
+    finally:
+        rt_telemetry.span_end(read_span)
     end_read = timeit.default_timer()
-    rt_telemetry.record("map_read", epoch=epoch, task=file_index,
-                        dur_s=end_read - start)
     if grouped:
         # The stream already placed every row; the index segment carries
         # only the region offsets (empty flat array).

@@ -28,6 +28,10 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_trace_cp_seconds": ("gauge", ("stage",)),
     "rsdl_trace_straggler_task": ("gauge", ("stage",)),
     "rsdl_trace_straggler_seconds": ("gauge", ("stage",)),
+    "rsdl_epoch_turnover_seconds": ("histogram", ()),
+    # -- device feed, consumer's thread (jax_dataset.py) --
+    "rsdl_feed_consumer_wall_seconds_total": ("counter", ()),
+    "rsdl_feed_consumer_cpu_seconds_total": ("counter", ()),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

@@ -43,7 +43,16 @@ Overhead: disabled, ``record()`` is one global load (the
 by :func:`measure_record_overhead` and reported by bench.py as
 ``telemetry_overhead_pct`` (contract: <= 2% of the ingest path).
 
-Stdlib-only (importable before jax/pyarrow and from the native layer).
+**One span vocabulary** — :func:`span` / :func:`span_begin` /
+:func:`span_end` are the only way the program times a stage. One call
+lands the interval in the recorder and, for the kinds of
+:data:`SPAN_NAMES`, in the JAX profiler's trace under the kind's fixed
+``rsdl.<layer>.<what>`` name (the annotation class is resolved lazily,
+once ``jax`` is in the process), so the host's stages sit on the device
+trace's clock without a second recording.
+
+Stdlib-only at import (importable before jax/pyarrow and from the native
+layer).
 """
 
 from __future__ import annotations
@@ -81,6 +90,42 @@ STAGE_BY_KIND: Dict[str, str] = {
     "device_transfer": "device_transfer",
     "train_step": "train_step",
 }
+
+#: THE span vocabulary: recorder kind -> the span's fixed name in the
+#: profiler's trace (``jax.profiler.TraceAnnotation``). A span opened
+#: through :func:`span` / :func:`span_begin` whose kind is listed here
+#: also holds that annotation while it is open (once ``jax`` is imported
+#: in the process), with ``epoch`` / ``task`` / ``batch`` as the
+#: annotation's arguments and never in its name, so one interval lands
+#: in both sinks from one call. Kinds not listed are recorder-only.
+#: PERF.md section 3 tabulates this list with each span's reader.
+SPAN_NAMES: Dict[str, str] = {
+    "map_read": "rsdl.loader.map_read",
+    "reduce_gather": "rsdl.loader.reduce",
+    "spill_write": "rsdl.loader.spill_write",
+    "spill_read": "rsdl.loader.spill_read",
+    "queue_wait": "rsdl.loader.queue_wait",
+    "queue_fetch": "rsdl.loader.queue_fetch",
+    "convert": "rsdl.feed.convert",
+    "device_transfer": "rsdl.feed.transfer",
+    "batch_wait": "rsdl.feed.queue_get",
+    "carve": "rsdl.feed.carve",
+    "set_epoch": "rsdl.feed.set_epoch",
+    "epoch_end": "rsdl.feed.epoch_end",
+    "epoch_verdict": "rsdl.feed.epoch_verdict",
+    "trace_gauges": "rsdl.feed.trace_gauges",
+}
+
+#: The one annotation the program opens outside :func:`span`: the
+#: trainer's per-step marker (``utils/tracing.step_span``), which lets
+#: the profiler group device operations by step. Profiler-only.
+STEP_ANNOTATION = "rsdl.trainer.step"
+
+
+def annotation_names() -> frozenset:
+    """Every ``TraceAnnotation`` name the program may emit."""
+    return frozenset(SPAN_NAMES.values()) | {STEP_ANNOTATION}
+
 
 #: The decomposition's stage order (CSV columns, bench JSON, rsdl_top).
 STAGES: Tuple[str, ...] = ("map_read", "reduce", "queue_wait", "fetch",
@@ -174,6 +219,9 @@ class StageAttribution:
         # epoch -> [first_t, last_t] monotonic bounds (wall clock of epoch)
         self._bounds: Dict[Optional[int], List[float]] = {}
         self._verdict_logged: set = set()
+        # epoch -> its verdict line, held back until the turnover into the
+        # next epoch is known (release_line appends the turnover's split)
+        self._held_lines: Dict[int, str] = {}
 
     def observe(self, stage: str, epoch: Optional[int], dur_s: float,
                 t: float) -> None:
@@ -205,6 +253,7 @@ class StageAttribution:
             self._hists.pop(stale, None)
             self._waits.pop(stale, None)
             self._bounds.pop(stale, None)
+            self._held_lines.pop(stale, None)
 
     def _verdict_locked(self, epochs: List[Optional[int]]
                         ) -> Optional[Dict[str, Any]]:
@@ -272,26 +321,58 @@ class StageAttribution:
                                         + [e for e in self._waits
                                            if e not in self._hists])
 
-    def epoch_complete(self, epoch: int, source: str = "") -> None:
+    def epoch_complete(self, epoch: int, source: str = "",
+                       hold: bool = False) -> None:
         """Log the epoch's one-line verdict (once per epoch per process;
         the dataset layer and the JAX binding both call this and the
-        first completion wins)."""
+        first completion wins). With ``hold`` the line is kept until
+        :meth:`release_line` logs it, so that it can carry the split of
+        the turnover into the next epoch; a later caller that does not
+        hold releases a line an earlier one held."""
         with self._lock:
             if epoch in self._verdict_logged:
-                return
-            self._verdict_logged.add(epoch)
-            verdict = self._verdict_locked([epoch])
+                held = None if hold else self._held_lines.pop(epoch, None)
+                verdict = None
+            else:
+                held = None
+                self._verdict_logged.add(epoch)
+                verdict = self._verdict_locked([epoch])
+        if held is not None:
+            logger.info("%s", held)
         if verdict is None:
             return
         busiest = verdict["stages"].get(verdict["bottleneck_stage"], {})
-        logger.info(
+        line = (
             "epoch %d bottleneck=%s stall=%.1f%% (wait %.2fs over %.2fs"
-            "%s); %s p95=%.1fms over %d events",
-            epoch, verdict["bottleneck_stage"], verdict["stall_pct"],
-            verdict["batch_wait_s"], verdict["wall_s"],
-            f", {source}" if source else "",
-            verdict["bottleneck_stage"], busiest.get("p95_ms", 0.0),
-            busiest.get("count", 0))
+            "%s); %s p95=%.1fms over %d events" % (
+                epoch, verdict["bottleneck_stage"], verdict["stall_pct"],
+                verdict["batch_wait_s"], verdict["wall_s"],
+                f", {source}" if source else "",
+                verdict["bottleneck_stage"], busiest.get("p95_ms", 0.0),
+                busiest.get("count", 0)))
+        if hold:
+            with self._lock:
+                self._held_lines[epoch] = line
+        else:
+            logger.info("%s", line)
+
+    def release_line(self, epoch: Optional[int] = None,
+                     suffix: str = "") -> None:
+        """Log the held verdict line of ``epoch`` (of every epoch when
+        ``None``) with ``suffix`` appended. An epoch whose line another
+        caller already logged still gets its suffix, on a line of its
+        own."""
+        with self._lock:
+            if epoch is None:
+                lines = list(self._held_lines.values())
+                self._held_lines.clear()
+            else:
+                line = self._held_lines.pop(epoch, None)
+                if line is None and not suffix:
+                    return
+                lines = [line or f"epoch {epoch}"]
+        for line in lines:
+            logger.info("%s%s", line, suffix)
 
 
 # ---------------------------------------------------------------------------
@@ -305,10 +386,18 @@ _recorder: Optional[FlightRecorder] = None
 _attribution: Optional[StageAttribution] = None
 _events_counter_cache: Dict[str, metrics.Counter] = {}
 _stage_hist_cache: Dict[str, metrics.Histogram] = {}
-#: thread ident -> currently-open span kind (the sampling profiler
-#: reads this to bill stack samples to pipeline stages). Plain-dict
-#: writes are GIL-atomic; no lock on the hot path.
-_active_kinds: Dict[int, str] = {}
+#: thread ident -> the spans open on that thread, innermost last (the
+#: sampling profiler bills stack samples to the innermost one's kind; a
+#: span's ``parent`` is the one below it). A stack, not a saved
+#: "previous kind": a span held across a generator's ``yield`` can close
+#: out of order. Plain dict and list operations are GIL-atomic; no lock
+#: on the hot path.
+_open_spans: Dict[int, List["_Span"]] = {}
+#: ``jax.profiler.TraceAnnotation``, resolved on the first span opened
+#: after ``jax`` was imported in this process (this module stays
+#: stdlib-only at import, and a process that never imports jax never
+#: pays for it).
+_annotation_cls: Any = None
 #: Lineage seed of the run this process participates in (trace.py's
 #: deterministic trace/span ids derive from it). Stamped into dumps.
 _trace_seed: Optional[int] = None
@@ -459,12 +548,13 @@ def speculative_attempt() -> int:
 def _record_impl(kind: str, epoch: Optional[int] = None,
                  task: Optional[int] = None, batch: Optional[int] = None,
                  dur_s: Optional[float] = None, t: Optional[float] = None,
-                 **attrs: Any) -> None:
+                 tid: Optional[int] = None, **attrs: Any) -> None:
     """Record one structured event (free when telemetry is disabled).
 
     ``t`` is the event's END in ``time.monotonic()`` terms (defaults to
     now); events with ``dur_s`` therefore span ``[t - dur_s, t]``. The
-    recording thread's ident rides along so multi-thread traces export
+    recording thread's ident (or ``tid``: the thread that opened a span
+    another thread closed) rides along so multi-thread traces export
     with real tids (Perfetto pid/tid mapping).
     """
     if not _ENABLED:
@@ -479,7 +569,8 @@ def _record_impl(kind: str, epoch: Optional[int] = None,
         attrs = {**attrs, "spec": spec}
     now = time.monotonic() if t is None else t
     rec.record((now, kind, epoch, task, batch, dur_s,
-                threading.get_ident(), attrs or None))
+                threading.get_ident() if tid is None else tid,
+                attrs or None))
     if spec:
         # Ring-only: the duplicate attempt is visible evidence (joined to
         # the original by its lineage key) but must not double-count the
@@ -495,12 +586,7 @@ def _record_impl(kind: str, epoch: Optional[int] = None,
         return
     if kind == "batch_wait":
         attribution().observe_wait(epoch, dur_s, now)
-        hist = _stage_hist_cache.get("batch_wait")
-        if hist is None:
-            hist = _stage_hist_cache["batch_wait"] = metrics.histogram(
-                "rsdl_batch_wait_seconds",
-                "consumer time blocked waiting on the next batch")
-        hist.observe(dur_s)
+        _batch_wait_hist().observe(dur_s)
         return
     stage = STAGE_BY_KIND.get(kind)
     if stage is None:
@@ -513,71 +599,162 @@ def _record_impl(kind: str, epoch: Optional[int] = None,
     hist.observe(dur_s)
 
 
-@contextlib.contextmanager
-def _span_impl(kind: str, epoch: Optional[int] = None,
-               task: Optional[int] = None, batch: Optional[int] = None,
-               **attrs: Any) -> Iterator[None]:
-    """Record the enclosed block as one duration event (disabled: the
-    overhead is the generator frame alone). While open, the thread's
-    active kind is published for the sampling profiler's stage
-    attribution (runtime/profiler.py)."""
-    if not _ENABLED:
-        yield
-        return
-    ident = threading.get_ident()
-    prev = _active_kinds.get(ident)
-    _active_kinds[ident] = kind
-    start = time.monotonic()
-    try:
-        yield
-    finally:
-        end = time.monotonic()
-        if prev is None:
-            _active_kinds.pop(ident, None)
-        else:
-            _active_kinds[ident] = prev
-        record(kind, epoch=epoch, task=task, batch=batch,
-               dur_s=end - start, t=end, **attrs)
+class _Span:
+    """An open span: what :func:`span_begin` hands out and
+    :func:`span_end` closes. Once closed it carries what was measured
+    (``t1``, ``dur_s`` and, when asked for, ``cpu_s``) for a caller that
+    sums spans into something larger (the feed's turnover split)."""
+
+    __slots__ = ("kind", "epoch", "task", "batch", "attrs", "ident",
+                 "bound", "annotation", "t0", "c0", "t1", "dur_s", "cpu_s")
+
+
+def _open_annotation(kind: str, epoch: Optional[int], task: Optional[int],
+                     batch: Optional[int]) -> Any:
+    """The entered ``TraceAnnotation`` of a span of ``kind``, or ``None``
+    where the kind has no fixed name or jax is not in the process."""
+    global _annotation_cls
+    name = SPAN_NAMES.get(kind)
+    if name is None:
+        return None
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:  # jax is still being imported on another thread
+            return None
+        _annotation_cls = cls
+    args = {}
+    if epoch is not None:
+        args["epoch"] = epoch
+    if task is not None:
+        args["task"] = task
+    if batch is not None:
+        args["batch"] = batch
+    annotation = cls(name, **args)
+    annotation.__enter__()
+    return annotation
 
 
 def _span_begin_impl(kind: str, epoch: Optional[int] = None,
                      task: Optional[int] = None,
-                     batch: Optional[int] = None,
-                     **attrs: Any) -> Optional[tuple]:
-    """Open a span that cannot be a ``with`` block (a wait measured
-    across loop iterations, a handoff between threads). Returns an
-    opaque token for :func:`span_end` — which MUST run on all exit
-    paths (``finally``); the ``span-unbalanced`` rsdl-lint rule enforces
-    the shape."""
+                     batch: Optional[int] = None, cpu: bool = False,
+                     handoff: bool = False,
+                     **attrs: Any) -> Optional[_Span]:
+    """Open a span: THE way the program times a stage. One call lands the
+    interval in both sinks on one clock: the flight recorder (an event of
+    ``kind`` at the close) and, for the kinds of :data:`SPAN_NAMES`, the
+    profiler's trace (an annotation under the kind's fixed name, held
+    while the span is open). Returns the token for :func:`span_end`,
+    which MUST run on all exit paths (``finally``); the
+    ``span-unbalanced`` rsdl-lint rule enforces the shape.
+
+    ``cpu`` also reads ``time.thread_time()`` at both ends (``cpu_s`` on
+    the event): wall minus CPU inside a span that does no I/O is time
+    spent waiting for the GIL or the scheduler. Where the kernel accounts
+    a thread's CPU time by the tick (10 ms on the TPU v5e host's, PERF.md
+    PR 24) one span's ``cpu_s`` is zero or a tick, and only a sum over
+    many spans says anything. ``handoff`` opens a span
+    that ANOTHER thread will close (a transfer that ends when the copy
+    has landed): it is not published as this thread's open span and
+    cannot carry ``cpu_s``."""
     if not _ENABLED:
         return None
-    ident = threading.get_ident()
-    prev = _active_kinds.get(ident)
-    _active_kinds[ident] = kind
-    return (kind, epoch, task, batch, attrs, time.monotonic(), prev, ident)
+    sp = _Span()
+    sp.kind, sp.epoch, sp.task, sp.batch = kind, epoch, task, batch
+    sp.ident = threading.get_ident()
+    sp.bound = not handoff
+    if sp.bound:
+        stack = _open_spans.get(sp.ident)
+        if stack is None:
+            stack = _open_spans[sp.ident] = []
+        elif stack:
+            attrs["parent"] = stack[-1].kind
+        stack.append(sp)
+    sp.attrs = attrs
+    sp.annotation = _open_annotation(kind, epoch, task, batch)
+    sp.t0 = time.monotonic()
+    # CPU reads nest inside the wall reads: cpu_s <= dur_s, up to the
+    # CPU clock's own step.
+    sp.c0 = time.thread_time() if (cpu and sp.bound) else None
+    return sp
 
 
-def _span_end_impl(token: Optional[tuple], **late_attrs: Any) -> None:
+def _span_end_impl(sp: Optional[_Span], **late_attrs: Any) -> None:
     """Close a :func:`span_begin` token, recording the duration event.
     ``None`` tokens (telemetry disabled at begin time) are a no-op, so
     callers never need to guard."""
-    if token is None:
+    if sp is None:
         return
-    kind, epoch, task, batch, attrs, start, prev, ident = token
-    if prev is None:
-        _active_kinds.pop(ident, None)
+    attrs = sp.attrs
+    if sp.c0 is not None:
+        sp.cpu_s = time.thread_time() - sp.c0
+        attrs["cpu_s"] = sp.cpu_s
     else:
-        _active_kinds[ident] = prev
-    end = time.monotonic()
+        sp.cpu_s = None
+    sp.t1 = time.monotonic()
+    sp.dur_s = sp.t1 - sp.t0
+    if sp.annotation is not None:
+        sp.annotation.__exit__(None, None, None)
+    if sp.bound:
+        stack = _open_spans.get(sp.ident)
+        if stack:
+            if stack[-1] is sp:
+                stack.pop()
+            elif sp in stack:
+                stack.remove(sp)
+            if not stack:
+                _open_spans.pop(sp.ident, None)
     if late_attrs:
-        attrs = {**attrs, **late_attrs}
-    record(kind, epoch=epoch, task=task, batch=batch,
-           dur_s=end - start, t=end, **attrs)
+        attrs.update(late_attrs)
+    record(sp.kind, epoch=sp.epoch, task=sp.task, batch=sp.batch,
+           dur_s=sp.dur_s, t=sp.t1, tid=sp.ident, **attrs)
+
+
+@contextlib.contextmanager
+def _span_impl(kind: str, epoch: Optional[int] = None,
+               task: Optional[int] = None, batch: Optional[int] = None,
+               **attrs: Any) -> Iterator[Optional[_Span]]:
+    """:func:`span_begin` / :func:`span_end` around the enclosed block
+    (disabled: the overhead is the generator frame alone)."""
+    sp = _span_begin_impl(kind, epoch, task, batch, **attrs)
+    try:
+        yield sp
+    finally:
+        _span_end_impl(sp)
 
 
 def active_kinds() -> Dict[int, str]:
-    """Snapshot of thread ident -> currently-open span kind."""
-    return dict(_active_kinds)
+    """Snapshot of thread ident -> innermost open span kind."""
+    out = {}
+    for ident, stack in list(_open_spans.items()):
+        try:
+            out[ident] = stack[-1].kind
+        except IndexError:  # closed between the two reads
+            continue
+    return out
+
+
+def observe_batch_wait(epoch: Optional[int] = None,
+                       dur_s: float = 0.0) -> None:
+    """Count a batch the consumer did not have to wait for (a later batch
+    of a bulk chunk that is already on the device) in the wait histogram
+    and the epoch's verdict. No ring event: nothing was timed."""
+    if not _ENABLED:
+        return
+    attribution().observe_wait(epoch, dur_s, time.monotonic())
+    _batch_wait_hist().observe(dur_s)
+
+
+def _batch_wait_hist() -> metrics.Histogram:
+    hist = _stage_hist_cache.get("batch_wait")
+    if hist is None:
+        hist = _stage_hist_cache["batch_wait"] = metrics.histogram(
+            "rsdl_batch_wait_seconds",
+            "consumer time blocked waiting on the next batch")
+    return hist
 
 
 def observe_stage(kind: str, epoch: Optional[int] = None,
@@ -684,13 +861,55 @@ def _update_trace_gauges(epoch: int) -> None:
         logger.exception("trace gauge update failed (epoch %d)", epoch)
 
 
-def epoch_complete(epoch: int, source: str = "") -> None:
+def epoch_complete(epoch: int, source: str = "",
+                   hold_log: bool = False) -> None:
     """Epoch-end hook for dataset layers: logs the one-line verdict and
-    refreshes the critical-path exposition gauges."""
+    refreshes the critical-path exposition gauges, each under a span of
+    its own (both run on the caller's thread, the consumer's in the JAX
+    binding). ``hold_log`` keeps the line for :func:`turnover_complete`
+    to log with the turnover's split."""
     if not _ENABLED:
         return
-    attribution().epoch_complete(epoch, source=source)
-    _update_trace_gauges(epoch)
+    with span("epoch_verdict", epoch=epoch):
+        attribution().epoch_complete(epoch, source=source, hold=hold_log)
+    with span("trace_gauges", epoch=epoch):
+        _update_trace_gauges(epoch)
+
+
+def turnover_complete(epoch: int, total_s: float,
+                      parts: Dict[str, float]) -> None:
+    """The turnover out of ``epoch`` is over: the consumer asked for a
+    batch, got the epoch's end instead, and now holds the next epoch's
+    first batch. ``parts`` are the seconds of ``total_s`` spent in the
+    feed's own spans (``epoch_end``, ``set_epoch``, ``first_get``, ...);
+    the rest is the caller's own work between them (``other``). One
+    ``epoch_turnover`` event, one histogram sample, and the epoch's held
+    log line with the split appended."""
+    if not _ENABLED:
+        return
+    other_s = total_s - sum(parts.values())
+    record("epoch_turnover", epoch=epoch, dur_s=total_s, other_s=other_s,
+           **{f"{name}_s": value for name, value in parts.items()})
+    hist = _stage_hist_cache.get("epoch_turnover")
+    if hist is None:
+        hist = _stage_hist_cache["epoch_turnover"] = metrics.histogram(
+            "rsdl_epoch_turnover_seconds",
+            "consumer stall from one epoch's end to the next epoch's "
+            "first batch")
+    hist.observe(total_s)
+    split = ", ".join(f"{name} {value * 1e3:.1f}"
+                      for name, value in parts.items())
+    attribution().release_line(
+        epoch, suffix=f"; turnover={total_s * 1e3:.1f}ms ({split}, "
+                      f"other {other_s * 1e3:.1f})")
+
+
+def flush_epoch_log() -> None:
+    """Log every held epoch line as it stands (a dataset that is closed
+    between an epoch's end and the next epoch's first batch)."""
+    if not _ENABLED:
+        return
+    attribution().release_line()
 
 
 # ---------------------------------------------------------------------------

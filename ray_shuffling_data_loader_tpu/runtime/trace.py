@@ -420,6 +420,26 @@ def analyze(events: Sequence[Dict[str, Any]],
     }
 
 
+def turnovers(events: Sequence[Dict[str, Any]],
+              epoch: Optional[int] = None) -> List[Dict[str, Any]]:
+    """The ``epoch_turnover`` events (the device feed's consumer stall
+    from one epoch's end to the next epoch's first batch) as
+    ``{epoch, pid, total_ms, parts: {name: ms}}``; ``parts`` ends with
+    ``other`` (the caller's own work) and sums to the total."""
+    out = []
+    for e in events:
+        if e.get("kind") != "epoch_turnover" or (
+                epoch is not None and e.get("epoch") != epoch):
+            continue
+        parts = {k[:-2]: round(float(v) * 1e3, 3) for k, v in e.items()
+                 if k.endswith("_s") and k not in ("dur_s", "other_s")}
+        parts["other"] = round(float(e.get("other_s", 0.0)) * 1e3, 3)
+        out.append({"epoch": e.get("epoch"), "pid": e.get("pid"),
+                    "total_ms": round(float(e.get("dur_s") or 0.0) * 1e3, 3),
+                    "parts": parts})
+    return out
+
+
 def stage_table(analysis: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     """Per-stage summary of one :func:`analyze` result, normalized per
     epoch so two runs with different epoch counts compare directly:

@@ -56,7 +56,6 @@ from ray_shuffling_data_loader_tpu.storage.cache import (  # noqa: F401
 # shuffle namespace.
 from ray_shuffling_data_loader_tpu.utils import fileio  # noqa: F401
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
-from ray_shuffling_data_loader_tpu.utils.tracing import trace_span
 
 logger = setup_custom_logger(__name__)
 
@@ -610,7 +609,11 @@ def shuffle_map(filename: str,
     if stats_collector is not None:
         stats_collector.map_start(epoch)
     start = timeit.default_timer()
-    with trace_span(f"shuffle_map e{epoch} f{file_index}"):
+    # Flight-recorder stage span: the kind reuses the fault-site name, so
+    # a chaos run's map_read faults join this event by (kind, epoch,
+    # task). It covers the read (and its retries, or its quarantine), not
+    # the partition plan below it.
+    with rt_telemetry.span("map_read", epoch=epoch, task=file_index):
         # Streaming fast path: cache-less reads only — the decoded table is
         # never materialized, so there is nothing to publish into a
         # cross-epoch cache (cached runs keep the legacy read: their
@@ -643,8 +646,6 @@ def shuffle_map(filename: str,
                 return report
             if shard is not None:
                 end_read = timeit.default_timer()
-                rt_telemetry.record("map_read", epoch=epoch,
-                                    task=file_index, dur_s=end_read - start)
                 if stats_collector is not None:
                     stats_collector.map_done(
                         epoch, timeit.default_timer() - start,
@@ -694,14 +695,9 @@ def shuffle_map(filename: str,
             from ray_shuffling_data_loader_tpu import native
             native.account_table(table)
         end_read = timeit.default_timer()
-        # Flight-recorder stage event: kind reuses the fault-site name,
-        # so a chaos run's map_read faults join this event by
-        # (kind, epoch, task).
-        rt_telemetry.record("map_read", epoch=epoch, task=file_index,
-                            dur_s=end_read - start)
-        index_parts = plan_map_partition(table.num_rows, num_reducers,
-                                         seed, epoch, file_index)
-        shard = MapShard(table, index_parts)
+    index_parts = plan_map_partition(table.num_rows, num_reducers,
+                                     seed, epoch, file_index)
+    shard = MapShard(table, index_parts)
     if stats_collector is not None:
         stats_collector.map_done(epoch, timeit.default_timer() - start,
                                  end_read - start)
@@ -801,9 +797,10 @@ def shuffle_reduce(reduce_index: int,
     if stats_collector is not None:
         stats_collector.reduce_start(epoch)
     start = timeit.default_timer()
-    with trace_span(f"shuffle_reduce e{epoch} r{reduce_index}"):
-        shuffled = _shuffle_reduce_body(reduce_index, seed, epoch, chunks,
-                                        reduce_transform, gather_threads)
+    # No span of its own: a reduce task times its whole body, this call
+    # included, under its reduce_gather span (rsdl.loader.reduce).
+    shuffled = _shuffle_reduce_body(reduce_index, seed, epoch, chunks,
+                                    reduce_transform, gather_threads)
     if stats_collector is not None:
         stats_collector.reduce_done(epoch, timeit.default_timer() - start)
     return shuffled

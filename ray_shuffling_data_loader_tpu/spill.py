@@ -28,6 +28,7 @@ from typing import Callable, Optional
 import pyarrow as pa
 
 from ray_shuffling_data_loader_tpu.runtime import faults as rt_faults
+from ray_shuffling_data_loader_tpu.runtime import telemetry as rt_telemetry
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
 
 logger = setup_custom_logger(__name__)
@@ -129,11 +130,10 @@ class SpilledTable:
     def load(self) -> pa.Table:
         from ray_shuffling_data_loader_tpu import stats as stats_mod
         from ray_shuffling_data_loader_tpu.runtime import retry as rt_retry
-        from ray_shuffling_data_loader_tpu.utils.tracing import trace_span
         with self._lock:
             if self._table is None:
-                with trace_span("spill_load", kind="spill_read",
-                                epoch=self._epoch, task=self._task):
+                with rt_telemetry.span("spill_read", epoch=self._epoch,
+                                       task=self._task):
                     try:
                         self._table = self._read_back()
                     except (OSError, pa.ArrowInvalid, SpillCorruption,
@@ -216,7 +216,6 @@ class SpillManager:
         over_budget = self._over_budget
         if table.num_rows == 0 or over_budget is None or not over_budget():
             return table
-        from ray_shuffling_data_loader_tpu.utils.tracing import trace_span
         with self._lock:
             path = os.path.join(self._dir, f"reduce_{self._seq}.arrow")
             self._seq += 1
@@ -225,8 +224,7 @@ class SpillManager:
             # failure still records a spill_write event with this task
             # key, so chaos and telemetry stay joinable even when the
             # write degrades to in-memory.
-            with trace_span("spill_write", kind="spill_write",
-                            task=self._seq - 1):
+            with rt_telemetry.span("spill_write", task=self._seq - 1):
                 rt_faults.inject("spill_write", task=self._seq - 1)
                 with pa.OSFile(path, "wb") as sink:
                     with pa.ipc.new_file(sink, table.schema) as writer:

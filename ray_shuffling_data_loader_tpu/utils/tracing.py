@@ -1,15 +1,12 @@
-"""Stage-span tracing bridged into the JAX/XLA profiler.
+"""Capturing a JAX/XLA profiler trace, and the trainer's step marker.
 
-The reference's only tracing is wall-clock spans recorded into a stats
-actor (reference: shuffle.py:204-263, stats.py:68-246); device time is
-invisible to it. Here every hot stage (map, reduce, consume, convert,
-transfer, train step) is wrapped in a ``jax.profiler.TraceAnnotation`` so
-a captured trace shows the host pipeline stages on the same timeline as
-XLA device ops — the stall analysis the reference can't do: you SEE
-whether the device waits on the loader or vice versa.
-
-Zero-cost by default: annotations are no-ops until a trace is active.
-Capture is explicit (:func:`profile_trace`) or env-driven
+The program's stage spans are ``runtime/telemetry.span`` /
+``span_begin`` / ``span_end``: one call lands an interval in the flight
+recorder and, under its fixed ``rsdl.*`` name
+(``telemetry.SPAN_NAMES``), in the profiler's trace, so a captured trace
+shows the host pipeline stages on the same timeline as the XLA device
+operations. This module only captures: explicitly
+(:func:`profile_trace`) or env-driven
 (``RSDL_PROFILE_DIR=/tmp/trace python ...`` via :func:`maybe_profile`);
 view with TensorBoard's profile plugin or Perfetto.
 """
@@ -20,37 +17,15 @@ import contextlib
 import os
 from typing import Iterator, Optional
 
-
-@contextlib.contextmanager
-def trace_span(name: str, kind: Optional[str] = None,
-               epoch: Optional[int] = None, task: Optional[int] = None,
-               batch: Optional[int] = None) -> Iterator[None]:
-    """Named host span, visible in captured profiler traces. No-op cheap
-    when no trace is active; safe to call from worker threads.
-
-    With ``kind`` set, the span is ALSO recorded as a structured
-    flight-recorder event (runtime/telemetry.py) carrying the given
-    correlation ids — one annotation, two consumers: the XLA profiler
-    timeline and the online bottleneck attribution.
-    """
-    # Imported here, not at module top: pure-host code paths (pool
-    # workers) pay for jax only when they open their first span.
-    from jax.profiler import TraceAnnotation
-    if kind is None:
-        with TraceAnnotation(name):
-            yield
-        return
-    from ray_shuffling_data_loader_tpu.runtime import telemetry
-    with telemetry.span(kind, epoch=epoch, task=task, batch=batch):
-        with TraceAnnotation(name):
-            yield
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 
 def step_span(step: int):
     """Train-step marker: lets the profiler group device ops per step.
-    Returns a context manager."""
+    Returns a context manager. Profiler-only (the consumer's loop records
+    the ``train_step`` stage)."""
     from jax.profiler import StepTraceAnnotation
-    return StepTraceAnnotation("train", step_num=step)
+    return StepTraceAnnotation(telemetry.STEP_ANNOTATION, step_num=step)
 
 
 @contextlib.contextmanager

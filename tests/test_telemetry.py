@@ -391,3 +391,207 @@ def test_trial_csv_gains_bottleneck_columns(tmp_path):
     assert row["bottleneck_stage"] == "reduce"
     assert float(row["telemetry_stall_pct"]) > 10.0
     assert float(row["p95_reduce_ms"]) > 0.0
+
+
+# ---------------------------------------------------------------------------
+# One span vocabulary: consumer spans, CPU beside wall, the epoch turnover
+# ---------------------------------------------------------------------------
+
+
+def _three_epochs(tmp_parquet_dir, queue_name, **kwargs):
+    filenames, _ = dg.generate_data_local(1200, 2, 1, 0.0, tmp_parquet_dir)
+    ds = JaxShufflingDataset(
+        filenames, num_epochs=3, num_trainers=1, batch_size=100, rank=0,
+        num_reducers=2, queue_name=queue_name,
+        feature_columns=list(dg.FEATURE_COLUMNS),
+        feature_types=[np.int32] * len(dg.FEATURE_COLUMNS),
+        label_column=dg.LABEL_COLUMN, **kwargs)
+    for epoch in range(3):
+        ds.set_epoch(epoch)
+        assert sum(label.shape[0] for _, label in ds) == 1200
+    # The last transfers' spans close on the reaper's thread.
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline:
+        events = telemetry.recorder().events()
+        spans = [e for e in events
+                 if e["kind"] == "device_transfer" and "dur_s" in e]
+        attempts = [e for e in events if e.get("attempt")]
+        if len(spans) >= len(attempts):
+            break
+        time.sleep(0.01)
+    return telemetry.recorder().events()
+
+
+def _cpu_clock_step():
+    """The step ``time.thread_time`` moves by: nanoseconds where the
+    kernel accounts a thread's CPU time precisely (0.0 then), a scheduler
+    tick of some 10 ms where it does not."""
+    step = None
+    prev = time.thread_time()
+    end = time.monotonic() + 0.05
+    while time.monotonic() < end:
+        now = time.thread_time()
+        if now != prev:
+            step = now - prev if step is None else min(step, now - prev)
+            prev = now
+    return 0.0 if step is not None and step < 1e-4 else (step or 0.05)
+
+
+def test_consumer_spans_carry_cpu_within_wall(tmp_parquet_dir):
+    """Every consumer-thread span of a bulk-path run carries ``cpu_s``
+    (``thread_time`` at both ends, read inside the wall reads), and a
+    sleeping span shows the difference."""
+    tick = _cpu_clock_step()
+    sp = telemetry.span_begin("carve", epoch=0, cpu=True)
+    try:
+        time.sleep(0.05)
+    finally:
+        telemetry.span_end(sp)
+    assert sp.cpu_s <= sp.dur_s + tick and sp.dur_s - sp.cpu_s >= 0.03
+    events = _three_epochs(tmp_parquet_dir, "telemetry-cpu",
+                           device_rebatch=True)
+    by_kind = {}
+    for e in events:
+        by_kind.setdefault(e["kind"], []).append(e)
+    for kind in ("batch_wait", "carve", "set_epoch", "epoch_end"):
+        assert by_kind[kind], kind
+        for e in by_kind[kind]:
+            assert 0.0 <= e["cpu_s"] <= e["dur_s"] + tick, e
+    # Children of the epoch's end name it as their parent.
+    assert {e.get("parent") for e in by_kind["trace_gauges"]} >= {"epoch_end"}
+    # One carve a batch; one timed get an item, the first of each epoch
+    # marked.
+    assert len(by_kind["carve"]) >= 30
+    assert sum(1 for e in by_kind["batch_wait"] if e.get("first")) == 3
+
+
+@pytest.mark.parametrize("kwargs", [
+    {"device_rebatch": True},
+    {"device_rebatch": False, "persistent_prefetch": False},
+], ids=["bulk-persistent", "per-batch-single-epoch"])
+def test_turnover_parts_sum_to_its_total(tmp_parquet_dir, kwargs):
+    hist = metrics.histogram("rsdl_epoch_turnover_seconds", "")
+    before = hist.count
+    events = _three_epochs(tmp_parquet_dir, "telemetry-turnover", **kwargs)
+    turnovers = [e for e in events if e["kind"] == "epoch_turnover"]
+    assert [e["epoch"] for e in turnovers] == [0, 1]
+    assert hist.count - before == 2
+    for e in turnovers:
+        parts = {k: v for k, v in e.items()
+                 if k.endswith("_s") and k != "dur_s"}
+        assert {"end_get_s", "epoch_end_s", "set_epoch_s", "first_get_s",
+                "other_s"} <= set(parts)
+        assert sum(parts.values()) == pytest.approx(e["dur_s"], abs=1e-9)
+        assert e["other_s"] >= 0.0
+        # The parts are the spans the recorder holds for that turnover.
+        end = [x for x in events if x["kind"] == "epoch_end"
+               and x["epoch"] == e["epoch"]][0]
+        assert e["epoch_end_s"] == end["dur_s"]
+
+
+def test_transfer_span_runs_to_the_landed_copy(tmp_parquet_dir):
+    """``device_transfer`` opens at the dispatch on the producer's thread
+    and is closed by the reaper once the copy has landed: the event keeps
+    the opening thread's id and the dispatch's share."""
+    events = _three_epochs(tmp_parquet_dir, "telemetry-transfer",
+                           device_rebatch=True)
+    spans = [e for e in events
+             if e["kind"] == "device_transfer" and "dur_s" in e]
+    assert spans and all(0.0 <= e["dispatch_s"] <= e["dur_s"]
+                         for e in spans)
+    consumer = {e["tid"] for e in events if e["kind"] == "carve"}
+    assert consumer.isdisjoint({e["tid"] for e in spans})
+    # Nothing is recorded twice: one span per dispatch attempt.
+    assert len(spans) == len([e for e in events if e.get("attempt")])
+
+
+def test_a_batch_already_on_the_device_counts_without_a_ring_event():
+    hist = metrics.histogram("rsdl_batch_wait_seconds", "")
+    before = hist.count
+    recorded = telemetry.recorder().total_recorded
+    telemetry.observe_batch_wait(epoch=4)
+    assert hist.count == before + 1
+    assert telemetry.recorder().total_recorded == recorded
+    verdict = telemetry.attribution().epoch_verdict(4)
+    assert verdict["batches_waited"] == 1 and verdict["batch_wait_s"] == 0.0
+
+
+def test_held_epoch_line_is_logged_once_with_the_turnover(monkeypatch):
+    lines = []
+    monkeypatch.setattr(telemetry.logger, "info",
+                        lambda fmt, *args: lines.append(fmt % args))
+    telemetry.record("train_step", epoch=0, dur_s=0.01)
+    telemetry.epoch_complete(0, source="dataset", hold_log=True)
+    telemetry.epoch_complete(0, source="jax", hold_log=True)
+    assert lines == []
+    telemetry.turnover_complete(0, 0.5, {"epoch_end": 0.3, "set_epoch": 0.01,
+                                         "first_get": 0.1})
+    assert len(lines) == 1 and lines[0].startswith("epoch 0 bottleneck=")
+    assert lines[0].endswith("turnover=500.0ms (epoch_end 300.0, "
+                             "set_epoch 10.0, first_get 100.0, other 90.0)")
+    # The last epoch has no turnover: its second caller releases the line.
+    telemetry.record("train_step", epoch=1, dur_s=0.01)
+    telemetry.epoch_complete(1, source="dataset", hold_log=True)
+    telemetry.epoch_complete(1, source="jax")
+    assert len(lines) == 2 and lines[1].startswith("epoch 1 bottleneck=")
+    # A dataset closed mid-turnover flushes what is held.
+    telemetry.record("train_step", epoch=2, dur_s=0.01)
+    telemetry.epoch_complete(2, source="dataset", hold_log=True)
+    telemetry.flush_epoch_log()
+    telemetry.flush_epoch_log()
+    assert len(lines) == 3
+
+
+def test_hard_off_opens_no_annotation_and_reads_no_clock(monkeypatch):
+    """``RSDL_TELEMETRY=0``: the span entry points are no-ops that touch
+    neither a clock nor the profiler."""
+    class _NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read on the hard-off path")
+
+    class _NoAnnotation:
+        def __init__(self, *args, **kwargs):
+            raise AssertionError("annotation opened on the hard-off path")
+
+    telemetry.configure(enabled_flag=False)
+    try:
+        monkeypatch.setattr(telemetry, "time", _NoClock())
+        monkeypatch.setattr(telemetry, "_annotation_cls", _NoAnnotation)
+        with telemetry.span("carve", epoch=0, batch=1) as sp:
+            assert sp is None
+        # rsdl-lint: disable=span-unbalanced
+        token = telemetry.span_begin("batch_wait", epoch=0, cpu=True)
+        telemetry.span_end(token)
+        assert token is None
+        telemetry.observe_batch_wait(0)
+        telemetry.epoch_complete(0)
+        telemetry.turnover_complete(0, 1.0, {"epoch_end": 0.5})
+        assert telemetry.stamp() == 0.0
+    finally:
+        monkeypatch.undo()
+        telemetry.configure(enabled_flag=True)
+
+
+def test_consumer_span_overhead_is_a_few_microseconds():
+    """What one instrumented batch adds on the consumer's thread (a carve
+    span with CPU time and a train_step span), beside the cost of one
+    recorded event."""
+    import jax  # noqa: F401 - with jax imported, spans open annotations
+    per_event = telemetry.measure_record_overhead(samples=500)
+    n = 500
+    start = time.perf_counter()
+    for i in range(n):
+        carve = telemetry.span_begin("carve", epoch=0, batch=i, cpu=True)
+        try:
+            pass
+        finally:
+            telemetry.span_end(carve)
+        step = telemetry.span_begin("train_step", epoch=0)
+        try:
+            pass
+        finally:
+            telemetry.span_end(step)
+    per_batch = (time.perf_counter() - start) / n
+    assert telemetry._annotation_cls is not None
+    assert per_batch < 2e-4, per_batch   # 200us: 10x what was observed
+    assert per_batch < 40 * per_event + 1e-4

@@ -521,3 +521,31 @@ def test_rsdl_trace_cli_merges_and_exports(tmp_path):
     assert proc.returncode == 0, proc.stderr
     payload = json.loads(proc.stdout)
     assert payload["critical_path"] and payload["whatif"]
+
+
+def test_rsdl_trace_cli_prints_the_epoch_turnover_split(tmp_path):
+    """The turnover's split from any run's dumps: ``epoch_turnover``
+    events come out as a table (and under ``turnovers`` with ``--json``)
+    whose parts, ``other`` included, sum to the total."""
+    _write_dump(tmp_path / "a.jsonl", 100, 5000.0, 1010.0,
+                [{"kind": "train_step", "epoch": 0, "dur_s": 0.02,
+                  "t_mono": 1002.0, "tid": 11},
+                 {"kind": "epoch_turnover", "epoch": 0, "dur_s": 0.35,
+                  "t_mono": 1003.0, "tid": 11, "end_get_s": 0.0001,
+                  "epoch_end_s": 0.34, "set_epoch_s": 0.0001,
+                  "first_get_s": 0.0018, "first_carve_s": 0.003,
+                  "other_s": 0.005}])
+    tool = os.path.join(REPO_ROOT, "tools", "rsdl_trace.py")
+    proc = subprocess.run([sys.executable, tool, str(tmp_path)],
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr
+    assert "epoch 0 -> 1: 350.0 (end_get 0.1, epoch_end 340.0, " \
+        "set_epoch 0.1, first_get 1.8, first_carve 3.0, other 5.0)" \
+        in proc.stdout
+    proc = subprocess.run([sys.executable, tool, str(tmp_path), "--json"],
+                          capture_output=True, text=True, timeout=60)
+    (turnover,) = json.loads(proc.stdout)["turnovers"]
+    assert turnover["epoch"] == 0 and turnover["pid"] == 100
+    assert sum(turnover["parts"].values()) == pytest.approx(
+        turnover["total_ms"])
+    assert list(turnover["parts"])[-1] == "other"

@@ -10,6 +10,9 @@ clock and answers the questions one process can't:
 - per-stage **self time** vs the consumer's wait;
 - the **straggler ranking** ((stage, task) by critical-path share);
 - **what-if attribution**: "2x faster <stage> => -X% epoch time";
+- the **epoch turnovers**: the device feed's `epoch_turnover` events,
+  each consumer stall between two epochs split into its parts
+  (`epoch_end`, `set_epoch`, `first_get`, ..., `other`);
 - a **Perfetto export** (`--perfetto out.json`): chrome-trace JSON with
   real pid/tid mapping, loadable in ui.perfetto.dev / chrome://tracing.
 
@@ -126,9 +129,11 @@ def main(argv=None) -> int:
             json.dump(trace.to_perfetto(merged, seed=seed), f)
         print(f"perfetto trace -> {args.perfetto} "
               f"({len(merged['events'])} events)", file=sys.stderr)
+    turnovers = trace.turnovers(merged["events"], epoch=args.epoch)
     if args.json:
         analysis = dict(analysis)
         analysis.pop("path_segments", None)
+        analysis["turnovers"] = turnovers
         analysis["processes"] = [
             {"pid": m["pid"], "role": m.get("role"),
              "trace_seed": m.get("trace_seed")}
@@ -136,6 +141,13 @@ def main(argv=None) -> int:
         print(json.dumps(analysis))
     else:
         print(render(analysis, merged["processes"]))
+        if turnovers:
+            print("\nepoch turnovers (consumer stall, ms):")
+            for t in turnovers:
+                split = ", ".join(f"{k} {v:.1f}"
+                                  for k, v in t["parts"].items())
+                print(f"  epoch {t['epoch']} -> {t['epoch'] + 1}: "
+                      f"{t['total_ms']:.1f} ({split})")
     return 0
 
 
