@@ -17,7 +17,11 @@ non-matmul op. Three strategies, dispatched by :func:`lookup`:
 - ``pallas``: a Pallas kernel using ``PrefetchScalarGridSpec`` — indices
   are scalar-prefetched into SMEM so each grid step's BlockSpec index_map
   selects the table row to DMA HBM->VMEM, overlapping row fetches with the
-  pipeline. Backward is an XLA scatter-add via ``custom_vjp``.
+  pipeline. Backward is an XLA scatter-add via ``custom_vjp``. Under a
+  multi-device mesh the gather runs once per data shard against the
+  replicated table, and the backward moves the looked-up rows' gradients
+  between the shards, not the table's dense gradient
+  (:func:`pallas_lookup`).
 
 ``auto`` picks ``one_hot`` for vocab <= ONE_HOT_MAX_VOCAB; above it, the
 Pallas gather on a real TPU when the embed dim is 128-lane aligned
@@ -37,6 +41,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu.ops import on_tpu
 from ray_shuffling_data_loader_tpu.parallel.mesh import DATA_AXIS
+from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 
 # Above this vocab size the one-hot matmul's wasted FLOPs and VMEM
 # pressure outgrow the gather's latency; 2048 keeps the one-hot tile
@@ -134,6 +139,53 @@ def _pallas_gather_bwd(interpret, residual, cotangent):
 _pallas_gather.defvjp(_pallas_gather_fwd, _pallas_gather_bwd)
 
 
+def _shard_gather(mesh: Mesh, interpret: bool):
+    """The Pallas gather once per shard of the mesh's "data" axis: table
+    replicated, indices and the rows they select split over that axis."""
+    return jax.shard_map(
+        lambda table, indices: _pallas_gather(table, indices, interpret),
+        mesh=mesh, in_specs=(P(), P(DATA_AXIS)), out_specs=P(DATA_AXIS),
+        check_vma=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _mesh_gather_rows(table: jax.Array, indices: jax.Array, mesh: Mesh,
+                      interpret: bool) -> jax.Array:
+    """:func:`_shard_gather` with a backward of its own. Left to
+    ``shard_map``'s transpose, the replicated table's cotangent is each
+    shard's dense ``f32[vocab, embed]`` scatter, summed over the data
+    axis by an all-reduce of the table's size. Here the shards exchange
+    what they looked up instead (indices and cotangent rows, all-gathered)
+    and every one scatter-adds all ``global_batch`` rows itself: the same
+    replicated sum, and nothing of the table's size crosses the ICI."""
+    return _shard_gather(mesh, interpret)(table, indices)
+
+
+def _mesh_gather_rows_fwd(table, indices, mesh, interpret):
+    return _shard_gather(mesh, interpret)(table, indices), (
+        indices, table.shape[0])
+
+
+def _mesh_gather_rows_bwd(mesh, interpret, residual, cotangent):
+    indices, vocab = residual
+
+    def exchange(indices, cotangent):
+        # The name a device trace shows these ops under.
+        with jax.named_scope("rsdl.embedding.grad_exchange"):
+            indices = jax.lax.all_gather(indices, DATA_AXIS, tiled=True)
+            cotangent = jax.lax.all_gather(cotangent, DATA_AXIS, tiled=True)
+            return jnp.zeros((vocab, cotangent.shape[-1]),
+                             cotangent.dtype).at[indices].add(cotangent)
+
+    d_table = jax.shard_map(
+        exchange, mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
+        out_specs=P(), check_vma=False)(indices, cotangent)
+    return d_table, None
+
+
+_mesh_gather_rows.defvjp(_mesh_gather_rows_fwd, _mesh_gather_rows_bwd)
+
+
 def pallas_lookup(table: jax.Array, indices: jax.Array, dtype: Any,
                   mesh: Optional[Mesh] = None) -> jax.Array:
     """Pallas scalar-prefetch row gather (interpret mode off-TPU).
@@ -146,25 +198,37 @@ def pallas_lookup(table: jax.Array, indices: jax.Array, dtype: Any,
     than one device must say so: with ``mesh``, the gather runs once per
     shard of the mesh's "data" axis under ``shard_map`` — the table
     replicated, the indices and the rows they select split over that
-    axis — and the table's gradient is summed over the shards.
+    axis. The table's gradient comes back replicated, and how the shards
+    agree on it follows the shapes: with fewer rows looked up than the
+    table holds (``global_batch < vocab``) they all-gather the indices and
+    the cotangent rows and each scatter-adds all of them
+    (:func:`_mesh_gather_rows`); otherwise each scatters its own rows and
+    the dense gradients are all-reduced (``shard_map``'s own transpose),
+    which then moves fewer bytes. ``rsdl_embedding_grad_exchange_total``
+    counts the lookups traced either way.
     """
     vocab, embed_dim = table.shape
     interpret = not on_tpu()
     if not interpret and embed_dim % 128 != 0:
         return take_lookup(table, indices, dtype)
     indices = jnp.clip(indices.astype(jnp.int32), 0, vocab - 1)
-
-    def gather(table, indices):
-        return _pallas_gather(table, indices, interpret)
-
-    if mesh is not None and mesh.size > 1:
-        gather = jax.shard_map(gather, mesh=mesh,
-                               in_specs=(P(), P(DATA_AXIS)),
-                               out_specs=P(DATA_AXIS), check_vma=False)
     # Gather in the table's storage dtype and cast afterwards: Mosaic
     # supports single-row HBM DMAs for 4-byte types but not 2-byte ones,
     # and cast-then-gather == gather-then-cast elementwise.
-    return gather(table, indices).astype(dtype)
+    if mesh is None or mesh.size == 1:
+        return _pallas_gather(table, indices, interpret).astype(dtype)
+    exchange = "rows" if indices.shape[0] < vocab else "dense"
+    rt_metrics.counter(
+        "rsdl_embedding_grad_exchange_total",
+        "Pallas lookups traced under a mesh, by how the replicated "
+        "table's gradient crosses the data axis: looked-up rows "
+        "all-gathered, or the dense gradient all-reduced",
+        kind=exchange).inc()
+    if exchange == "rows":
+        rows = _mesh_gather_rows(table, indices, mesh, interpret)
+    else:
+        rows = _shard_gather(mesh, interpret)(table, indices)
+    return rows.astype(dtype)
 
 
 def _auto_mode(vocab: int, embed_dim: int) -> str:

@@ -4,8 +4,9 @@ The reference's gradient plane is Horovod/NCCL allreduce and its control
 plane is Ray GCS (SURVEY.md §2.4). TPU-native, both collapse into the XLA
 device mesh: ``jax.sharding.Mesh`` over the slice's chips, gradients
 synced by XLA collectives over ICI (inserted automatically under jit from
-sharding annotations), multi-host coordination via
-``jax.distributed.initialize``.
+sharding annotations; the one written by hand is the row exchange of the
+Pallas embedding lookup's backward, ``ops/embedding.py``), multi-host
+coordination via ``jax.distributed.initialize``.
 
 Axis convention used across the framework:
 - ``"data"``  — batch-dim sharding (DP). One trainer rank per data-axis

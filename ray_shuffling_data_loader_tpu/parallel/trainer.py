@@ -5,12 +5,15 @@ examples/horovod/ray_torch_shuffle.py:126-243): instead of
 ``hvd.DistributedOptimizer`` wrapping a torch optimizer with NCCL allreduce
 hooks (:173-177) and explicit parameter broadcast (:165-166), the whole
 train step — forward, backward, optimizer update — is one ``jax.jit``
-program over a ``Mesh``. Gradient synchronization is not written anywhere:
+program over a ``Mesh``. The trainer writes no gradient synchronization:
 batches arrive sharded along the "data" axis, params are replicated (or TP-
 sharded along "model"), and XLA inserts the ``psum``/``all_gather``
-collectives over ICI that the sharding layout implies. fp16 compression /
-Adasum knobs (:80-87) map to bf16 compute in the models and optax
-transforms here.
+collectives over ICI that the sharding layout implies. The one exchange
+written by hand is in the loss it is given: the Pallas embedding lookup
+under a mesh (``ops/embedding.py``) all-gathers the looked-up rows'
+gradients rather than let the replicated table's dense gradient be
+all-reduced. fp16 compression / Adasum knobs (:80-87) map to bf16 compute
+in the models and optax transforms here.
 
 The trainer owns sharded params + optimizer state and exposes
 ``train_step(batch) -> loss``; donation keeps params/opt-state in place in

@@ -32,6 +32,9 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- device feed, consumer's thread (jax_dataset.py) --
     "rsdl_feed_consumer_wall_seconds_total": ("counter", ()),
     "rsdl_feed_consumer_cpu_seconds_total": ("counter", ()),
+    # -- embedding lookup under a mesh (ops/embedding.py; counted when a
+    #    step is traced, not when it runs; kind = rows | dense) --
+    "rsdl_embedding_grad_exchange_total": ("counter", ("kind",)),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

@@ -142,39 +142,153 @@ def test_auto_mode_dispatch_rules(monkeypatch):
     assert embedding._auto_mode(large_v, 32) == "take"  # unaligned rows
 
 
-def _mesh_table_indices(rng):
+# (vocab, how the table's gradient crosses the data axis) at a global
+# batch of 64: rows while the batch is below the vocab, dense from there.
+EXCHANGES = [(640, "rows"), (65, "rows"), (64, "dense"), (48, "dense")]
+_BATCH, _EMBED = 64, 128
+
+
+def _mesh_table_indices(rng, vocab=640):
+    """A replicated table, and batch-sharded indices that repeat within
+    a shard and across shards and leave the table at both ends."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
     mesh = mesh_mod.make_mesh()
     table = jax.device_put(
-        jnp.asarray(rng.standard_normal((640, 128)), jnp.float32),
+        jnp.asarray(rng.standard_normal((vocab, _EMBED)), jnp.float32),
         NamedSharding(mesh, P()))
-    indices = jax.device_put(
-        jnp.asarray(rng.integers(0, 640, 64), jnp.int32),
-        NamedSharding(mesh, P("data")))
+    indices = rng.integers(0, vocab, _BATCH)
+    per_shard = _BATCH // mesh.size
+    indices[1] = indices[0]                      # within the first shard
+    indices[per_shard::per_shard] = indices[0]   # once in every other shard
+    indices[2], indices[-1] = -7, vocab + 1000   # clipped to rows 0, vocab-1
+    indices[3], indices[-2] = 0, vocab - 1
+    indices = jax.device_put(jnp.asarray(indices, jnp.int32),
+                             NamedSharding(mesh, P("data")))
     return mesh, table, indices
 
 
-def test_pallas_lookup_under_a_mesh_matches_take(rng):
+def _value_and_table_grad(mode, mesh, weights):
+    def fn(table, indices):
+        out = embedding.lookup(table, indices, jnp.float32, mode=mode,
+                               mesh=mesh)
+        return jnp.sum(out * weights), out
+    return jax.jit(jax.value_and_grad(fn, has_aux=True))
+
+
+def _collectives_holding(hlo, op, rows, embed=_EMBED):
+    """How many ``op``s of a compiled program's text have a result (alone
+    or in a combined tuple) that holds an ``f32[rows, embed]``; XLA may
+    carry a unit axis between the two."""
+    import re
+    results = re.findall(rf"= (.*?) {op}(?:-start)?\(", hlo)
+    return sum(bool(re.search(rf"f32\[{rows},(?:1,)?{embed}\]", r))
+               for r in results)
+
+
+def _exchanges_counted():
+    from ray_shuffling_data_loader_tpu.runtime import metrics
+    counts = {}
+    for kind in ("rows", "dense"):
+        counter = metrics.get("rsdl_embedding_grad_exchange_total",
+                              {"kind": kind})
+        counts[kind] = 0 if counter is None else int(counter.value)
+    return counts
+
+
+@pytest.mark.parametrize("vocab", [vocab for vocab, _ in EXCHANGES])
+def test_pallas_lookup_under_a_mesh_matches_take(rng, vocab):
     """Batch-sharded indices, replicated table: one gather per data shard
-    under shard_map, same rows and same table gradient as XLA take."""
-    mesh, table, indices = _mesh_table_indices(rng)
-    weights = jnp.asarray(rng.standard_normal((64, 128)), jnp.float32)
-
-    def loss(mode, mesh):
-        def fn(table, indices):
-            out = embedding.lookup(table, indices, jnp.float32, mode=mode,
-                                   mesh=mesh)
-            return jnp.sum(out * weights), out
-        return jax.jit(jax.value_and_grad(fn, has_aux=True))
-
-    (_, got), got_grad = loss("pallas", mesh)(table, indices)
-    (_, want), want_grad = loss("take", None)(table, indices)
+    under shard_map, same rows and same table gradient as XLA take on one
+    device, whichever way the gradient is exchanged."""
+    mesh, table, indices = _mesh_table_indices(rng, vocab)
+    weights = jnp.asarray(rng.standard_normal((_BATCH, _EMBED)), jnp.float32)
+    (_, got), got_grad = _value_and_table_grad(
+        "pallas", mesh, weights)(table, indices)
+    one = jax.devices()[0]
+    (_, want), want_grad = _value_and_table_grad("take", None, weights)(
+        jax.device_put(table, one), jax.device_put(indices, one))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_allclose(np.asarray(got_grad), np.asarray(want_grad),
                                atol=1e-6)
-    assert got.sharding.shard_shape(got.shape) == (64 // mesh.size, 128)
+    assert got.sharding.shard_shape(got.shape) == (_BATCH // mesh.size,
+                                                   _EMBED)
+    assert got_grad.sharding.is_fully_replicated
+
+
+@pytest.mark.parametrize("vocab,kind", EXCHANGES)
+def test_mesh_lookup_gradient_exchanges_rows_below_the_vocab(
+        rng, vocab, kind):
+    """What crosses the data axis in the compiled gradient: the looked-up
+    rows (one all-gather of the cotangent, none of the table's size) while
+    the global batch is below the vocab, the dense all-reduce from there.
+    The counter says which was traced."""
+    mesh, table, indices = _mesh_table_indices(rng, vocab)
+    weights = jnp.asarray(rng.standard_normal((_BATCH, _EMBED)), jnp.float32)
+    before = _exchanges_counted()
+    hlo = _value_and_table_grad("pallas", mesh, weights).lower(
+        table, indices).compile().as_text()
+    after = _exchanges_counted()
+    assert {k: after[k] - before[k] for k in after} == {
+        "rows": int(kind == "rows"), "dense": int(kind == "dense")}
+
+    if kind == "rows":
+        assert _collectives_holding(hlo, "all-reduce", vocab) == 0
+        assert _collectives_holding(hlo, "all-gather", _BATCH) == 1
+        assert "rsdl.embedding.grad_exchange" in hlo
+    else:
+        assert _collectives_holding(hlo, "all-reduce", vocab) == 1
+        assert _collectives_holding(hlo, "all-gather", _BATCH) == 0
+
+
+@pytest.mark.parametrize("vocab_sizes", [(40, 300, 3000), (3000, 65)])
+def test_dlrm_trainer_steps_over_the_mesh_match_one_device(rng, vocab_sizes):
+    """Three SpmdTrainer steps of a small DLRM through the Pallas lookup
+    over the 8-device mesh (vocabs above the global batch exchange rows,
+    40 the dense gradient): the losses and parameters of the same three
+    steps on one device over the same global batches."""
+    import optax
+
+    from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
+    from ray_shuffling_data_loader_tpu.parallel.trainer import (
+        SpmdTrainer, batch_shardings)
+    cfg = dlrm.DLRMConfig(vocab_sizes=vocab_sizes, embed_dim=16,
+                          top_hidden=(32, 16), compute_dtype=jnp.float32,
+                          lookup_mode="pallas")
+    # On the host: each trainer donates its own device copy.
+    params = jax.device_get(dlrm.init(cfg, jax.random.key(1)))
+    batches = [
+        (np.stack([rng.integers(0, v, _BATCH) for v in vocab_sizes],
+                  axis=1).astype(np.int32),
+         rng.random((_BATCH, 1)).astype(np.float32)) for _ in range(3)]
+
+    def run(mesh):
+        multi = mesh.size > 1
+
+        def loss(params, sparse, labels):
+            return dlrm.loss_fn(cfg, params, None, sparse, labels,
+                                mesh if multi else None)
+
+        trainer = SpmdTrainer(mesh, loss, params, optax.adam(1e-2))
+        shardings = batch_shardings(mesh, batches[0])
+        losses = [float(trainer.train_step(*jax.device_put(b, shardings)))
+                  for b in batches]
+        return losses, jax.device_get(trainer.params)
+
+    before = _exchanges_counted()
+    got_losses, got_params = run(mesh_mod.make_mesh())
+    traced = _exchanges_counted()
+    want_losses, want_params = run(mesh_mod.make_mesh(num_devices=1))
+    assert _exchanges_counted() == traced      # one device counts nothing
+    assert traced["rows"] - before["rows"] == sum(
+        v > _BATCH for v in vocab_sizes)
+    assert traced["dense"] - before["dense"] == sum(
+        v <= _BATCH for v in vocab_sizes)
+    np.testing.assert_allclose(got_losses, want_losses, rtol=1e-5)
+    jax.tree.map(
+        lambda got, want: np.testing.assert_allclose(got, want, atol=2e-5),
+        got_params, want_params)
 
 
 def test_pallas_lookup_lowers_for_the_chip_only_with_the_mesh(
@@ -193,3 +307,44 @@ def test_pallas_lookup_lowers_for_the_chip_only_with_the_mesh(
     assert lower(mesh).count("tpu_custom_call") == 1
     with pytest.raises(NotImplementedError, match="shard_map"):
         lower(None)
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    """A described v5e 2x2 (the TPU's compiler is installed here; no chip
+    is attached), as a data mesh."""
+    from jax.experimental import topologies
+
+    from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return mesh_mod.make_mesh(devices=list(topo.devices))
+
+
+def test_mesh_lookup_gradient_compiles_for_four_chips(four_chips,
+                                                      monkeypatch):
+    """dlrm-mlperf's largest table at the four-chip cell's batch, through
+    the TPU's own compiler: the Mosaic gather once a chip in the forward,
+    8,192 cotangent rows all-gathered in the backward, and no collective
+    of the table's 484 MB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    vocab, embed, batch = 945195, 128, 8192
+    table = jax.ShapeDtypeStruct((vocab, embed), jnp.float32,
+                                 sharding=NamedSharding(four_chips, P()))
+    indices = jax.ShapeDtypeStruct(
+        (batch,), jnp.int32, sharding=NamedSharding(four_chips, P("data")))
+
+    def loss(table, indices):
+        out = embedding.lookup(table, indices, jnp.bfloat16, mode="pallas",
+                               mesh=four_chips)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss)).lower(table, indices).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    for op in ("all-reduce", "all-gather", "reduce-scatter"):
+        assert _collectives_holding(hlo, op, vocab, embed) == 0
+    assert _collectives_holding(hlo, "all-gather", batch, embed) == 1
