@@ -362,6 +362,18 @@ class TracedWindow:
             shutil.copy(self.path, keep)
 
 
+def keep_completions(ctx: Context, kept: Dict[str, Any]) -> None:
+    """Where ``CHIPBENCH_KEEP_GAPS`` names a directory: every completion
+    time of the run there as JSON, for whoever reads a noisy check."""
+    keep = os.environ.get("CHIPBENCH_KEEP_GAPS")
+    if not keep:
+        return
+    os.makedirs(keep, exist_ok=True)
+    name = f"{ctx.cell.name}-{ctx.seed}-{os.getpid()}.json"
+    with open(os.path.join(keep, name), "w") as f:
+        json.dump(dict(kept, cell=ctx.cell.name, seed=ctx.seed), f)
+
+
 def span(name: str):
     """A host span in the profiler's trace (free when nothing traces)."""
     import jax

@@ -7,6 +7,11 @@ pipeline up, and hands that same trainer to the window. A step completes
 when its loss has arrived on the host; the host runs a fixed number of
 steps ahead of the last completion. ``train_rows_per_s`` is whole steps
 over the time between two completions (``chipbench/window.py``).
+
+Where the traffic mix sets ``open_after_epoch_ends``, the warm-up runs on
+until the consumer has met that many epoch ends and the run-ahead has
+refilled: what a process pays once (the first epoch's end) is then part of
+``setup_s`` and not of the window.
 """
 
 from __future__ import annotations
@@ -17,12 +22,14 @@ import importlib
 import math
 import os
 import statistics
-from typing import Any, Dict, Iterator, List, Tuple
+import time
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
 from chipbench import check, harness, manifest, window
 from chipbench.harness import clock, info, span
+from chipbench.readers import program as program_readers
 
 
 @dataclasses.dataclass
@@ -42,6 +49,46 @@ class _Run:
     data_job: harness.DataJob
     watchdog_before: Dict[str, Any]
     dataset_built_at: float
+
+
+def _stall_control(control: Optional[str]) -> Tuple[float, int]:
+    """``stall:<ms>:<every>`` -> (seconds to sleep, every how many batches
+    of the window); (0.0, 0) for any other control."""
+    if not control or not control.startswith("stall:"):
+        return 0.0, 0
+    _, ms, every = control.split(":")
+    if float(ms) <= 0 or int(every) < 1:
+        raise ValueError(f"--control {control}: a stall of more than 0 ms, "
+                         "every 1 batch or more")
+    return float(ms) / 1e3, int(every)
+
+
+def _step_gap_tail(gaps_ms: List[float], group: int, strict: bool) -> float:
+    """``step_gap_p95_ms``: the 95th percentile of the mean gap of ``group``
+    steps in a row, with the line that says what it was read from. NaN
+    where a window that reports no tail is too short for one group."""
+    means = window.group_means(gaps_ms, group)
+    if strict or means:
+        tail = window.group_tail(
+            gaps_ms, group, 95,
+            least_beyond=window.LEAST_BEYOND if strict else 0)
+    else:
+        tail = math.nan
+    median = statistics.median(gaps_ms)
+    # An epoch end's own gaps (one long, the next ones short) are events,
+    # not the stamps' noise, and would swamp it: left out of this one.
+    near = [g for g in gaps_ms if abs(g - median) < 0.25 * median]
+    lag1 = (window.lag1_autocorrelation(near) if len(near) >= 3
+            else math.nan)
+    info(f"step gap: mean of {group} in a row: p95 {tail:.4f} ms over "
+         f"{len(means)} groups ({window.samples_beyond(means, 95)} beyond "
+         f"the 95th); single gaps: p50 / p90 / p95 / p99 " + " / ".join(
+             f"{window.percentile(gaps_ms, q):.4f}" for q in (50, 90, 95, 99))
+         + f" ms over {len(gaps_ms)} gaps, max {max(gaps_ms):.4f} ms, "
+         f"{sum(g > 2 * median for g in gaps_ms)} over twice the median, "
+         f"lag-1 autocorrelation {lag1:.4f} over the {len(near)} within a "
+         f"quarter of the median")
+    return tail
 
 
 def _stream(ds, num_epochs: int, ended: List[int]
@@ -127,21 +174,42 @@ def _drive(run: _Run) -> Dict[str, Any]:
     chips = len(ctx.devices)
     run_ahead = ctx.traffic("run_ahead_steps")
     warmup_steps = ctx.traffic("warmup_steps")
+    open_after = ctx.traffic("open_after_epoch_ends", 0)
     num_epochs = ctx.traffic("num_epochs")
     steps_per_epoch = sizes["data"]["rows"] // batch
+    stall_s, stall_every = _stall_control(ctx.control)
     digests = harness.EpochDigests()
     ended: List[int] = []
+    # One entry for each epoch end the consumer met: the harness's clock
+    # around that next(batch), and the program's own histograms as they
+    # stood when it returned (readers/program.py tells one epoch's sample
+    # from the next's by them).
+    epoch_ends: List[Dict[str, Any]] = []
+    histograms_before = program_readers.histogram_totals()
     stream = _stream(ds, num_epochs, ended)
     step_no = 0
     wait_s = 0.0
+    opened = False
+    attempted = 0
 
     def take():
         """The next batch of the feed, digested on the device."""
         nonlocal wait_s
+        if stall_every and opened and attempted % stall_every == 0:
+            # The tail metric's control: the host late with a batch. Longer
+            # than the run-ahead's steps, it starves the device.
+            time.sleep(stall_s)
         t_a = clock()
         with span("chipbench.next_batch"):
             epoch, features, label = next(stream)
-        wait_s += clock() - t_a
+        took = clock() - t_a
+        wait_s += took
+        if len(ended) != len(epoch_ends):
+            epoch_ends.append({
+                "epoch": ended[-1], "step": step_no, "in_window": opened,
+                "next_batch_s": took,
+                "program": program_readers.histogram_totals(
+                    since=histograms_before)})
         with span("chipbench.digest_dispatch"):
             digests.add(epoch, features, label, batch)
         return features, label
@@ -193,9 +261,11 @@ def _drive(run: _Run) -> Dict[str, Any]:
         seconds = min(seconds, ctx.traffic("trace_seconds"))
     pending = collections.deque()
     completions: List[float] = []
+    warm_completions: List[float] = []
     warm_left = warmup_steps
-    opened = False
-    attempted = 0
+    # Completions still to see once the consumer has met the epoch ends the
+    # traffic asks for, before the run-ahead counts as refilled.
+    refill_left = run_ahead if open_after else 0
     last_loss = math.nan
     traced = harness.TracedWindow(ctx)
     setup_s = None
@@ -211,8 +281,12 @@ def _drive(run: _Run) -> Dict[str, Any]:
             last_loss = float(pending.popleft())
         now = clock()
         if not opened:
+            warm_completions.append(now)
             warm_left -= 1
-            if warm_left > 0:
+            if len(ended) >= open_after and refill_left > 0:
+                refill_left -= 1
+                continue
+            if warm_left > 0 or len(ended) < open_after:
                 continue
             if ctx.trace and not traced.started:
                 # Tracing starts one completion ahead of the window, so
@@ -298,19 +372,35 @@ def _drive(run: _Run) -> Dict[str, Any]:
     reference_s = clock() - t0
     for c in compared:
         info(c.line()[2:])
+    left_out = check.not_compared(program, reference, ctx.limits())
+    if left_out:
+        info("not compared (the limits name other numbers): " + left_out)
     info(f"losses program {program['losses']} reference "
          f"{reference['losses']}")
 
     gaps_ms = [g * 1e3 for g in win.gaps]
     rate = win.rate(batch)
+    turnovers = ", ".join(
+        f"epoch {e['epoch']} at step {e['step']} "
+        f"({'window' if e['in_window'] else 'warm-up'}) "
+        f"{e['next_batch_s'] * 1e3:.1f} ms" for e in epoch_ends)
     info(f"window: {win.counted} steps of {batch} rows counted over "
          f"{win.elapsed:.4f} s (asked {seconds} s), run-ahead {run_ahead}, "
-         f"warm-up {warmup_steps} steps, {steps_per_epoch} steps an epoch, "
-         f"epochs ended {ended}, checked {epochs_checked}")
-    info(f"step gap: median {statistics.median(gaps_ms):.4f} ms, p95 "
-         f"{window.percentile(gaps_ms, 95):.4f} ms over {len(gaps_ms)} gaps "
-         f"({window.samples_beyond(gaps_ms, 95)} beyond the 95th), max "
-         f"{max(gaps_ms):.4f} ms")
+         f"warm-up {len(warm_completions)} completions (at least "
+         f"{warmup_steps} steps and {open_after} epoch end(s)), "
+         f"{steps_per_epoch} steps an epoch, epochs ended {ended}, checked "
+         f"{epochs_checked}; next(batch) at each epoch end: "
+         f"{turnovers or 'none met'}")
+    # Only a run that reports the tail insists on ten groups beyond it;
+    # the line prints what there is in any run.
+    reports_tail = (not ctx.trace and not ctx.rehearse and any(
+        m["name"] == "step_gap_p95_ms" for m in ctx.cell.end_to_end))
+    gap_p95 = _step_gap_tail(gaps_ms, run_ahead, strict=reports_tail)
+    harness.keep_completions(ctx, {
+        "seconds": seconds, "rows_per_step": batch, "run_ahead": run_ahead,
+        "warmup_steps": warmup_steps, "setup_s": setup_s,
+        "warm_completions": warm_completions, "completions": completions,
+        "epoch_ends": epoch_ends})
     queue_wait = ds.batch_wait_stats.summary()["total"]
     info(f"host: {os.cpu_count()} CPUs, pool {pool}; loader "
          f"batches asked {attempted}, short {digests.short_batches}, "
@@ -329,13 +419,14 @@ def _drive(run: _Run) -> Dict[str, Any]:
         "first_batch_s": first_batch_s,
         "step_compiles": compiles_after - compiles_before,
         "device": device, "trace_path": traced.path,
-        "setup_s": setup_s,
+        "setup_s": setup_s, "epoch_ends": epoch_ends,
     }
     metrics = {
         "train_rows_per_s": rate,
-        "step_gap_p95_ms": window.percentile(gaps_ms, 95),
+        "step_gap_p95_ms": gap_p95,
         "setup_s": setup_s,
     }
-    return {"correct": all(c.ok for c in compared), "attempted": attempted,
+    return {"correct": all(c.ok for c in compared), "compared": compared,
+            "attempted": attempted,
             "failed": failed, "metrics": metrics, "facts": facts,
             "device": device}

@@ -86,6 +86,29 @@ def idle_under_pct(by_span: Dict[str, float], prefix: str,
                   100.0 * under / (window[1] - window[0]))
 
 
+def histogram_totals(since: Optional[Dict[str, Tuple[int, float]]] = None
+                     ) -> Dict[str, Tuple[int, float]]:
+    """(count, sum) of each unlabelled histogram the program's catalog
+    (``runtime/metric_names``) names and its registry holds by now, less
+    what an earlier reading ``since`` held (the registry is the process's:
+    the tests rehearse several runs in one). The loop takes it when the
+    consumer has met an epoch's end, so that a reader can tell one epoch's
+    sample from the next's; empty for a program without the catalog."""
+    try:
+        from ray_shuffling_data_loader_tpu.runtime import metric_names, metrics
+    except ImportError:
+        return {}
+    totals: Dict[str, Tuple[int, float]] = {}
+    for name, (kind, labels) in metric_names.METRIC_NAMES.items():
+        if kind != "histogram" or labels:
+            continue
+        held = metrics.get(name)
+        if held is not None:
+            count, total = (since or {}).get(name, (0, 0.0))
+            totals[name] = (int(held.count) - count, float(held.sum) - total)
+    return totals
+
+
 def counter_offcpu_pct(wall_s: Optional[float], cpu_s: Optional[float]
                        ) -> Optional[float]:
     """100 x (1 - cpu / wall); ``None`` where nothing was counted."""
@@ -130,6 +153,23 @@ def feed_offcpu_pct(facts: Dict[str, Any], wall: str, cpu: str
         counter = metrics.get(name)
         values.append(None if counter is None else float(counter.value))
     return counter_offcpu_pct(*values)
+
+
+def first_turnover_ms(facts: Dict[str, Any], histogram: str
+                      ) -> Optional[float]:
+    """What the consumer's first epoch end cost (the end met in
+    ``next()`` to the next epoch's first batch: the program's
+    ``epoch_turnover`` event, sampled into ``histogram``): the histogram's
+    first sample, from the totals the loop took at each epoch end
+    (``histogram_totals``). The first end at which it holds a sample has
+    to hold exactly one; ``None`` where no end has been met or the program
+    never sampled it. A process pays it once; where the traffic opens the
+    window after it, it is part of ``setup_s``."""
+    for end in facts.get("epoch_ends", ()):
+        count, total = end["program"].get(histogram, (0, 0.0))
+        if count:
+            return 1e3 * total if count == 1 else None
+    return None
 
 
 def idle_under_feed_pct(facts: Dict[str, Any]) -> Optional[float]:

@@ -76,6 +76,19 @@ def per_layer_metrics(cell, facts: Dict[str, Any], manifest_mod
     return out
 
 
+def _with_compared(line: Dict[str, Any], result: Dict[str, Any]) -> None:
+    """Each number that decided ``correct`` beside its limit: the last
+    lines on standard error, and the last key of the result's line (the
+    leaf a worst gap was found at is in the ``# compared`` lines)."""
+    compared = result.get("compared", ())
+    sys.stdout.flush()
+    for c in compared:
+        print(c.line()[2:], file=sys.stderr, flush=True)
+    line["compared"] = {c.name.partition("[")[0]: {"value": c.value,
+                                                   "limit": c.limit}
+                        for c in compared}
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     args = _parse(argv)
     from chipbench import harness, manifest
@@ -127,6 +140,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             line["device"] = {"platform": device["platform"],
                               "kind": device["kind"],
                               "count": device["count"]}
+            _with_compared(line, result)
             harness.print_result(line)
             return 0
         if args.trace:
@@ -148,6 +162,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 name: {"value": result["metrics"][name], "unit": unit}
                 for name, unit in units.items()}
         line["device"] = device
+        _with_compared(line, result)
         harness.print_result(line)
     return 0
 

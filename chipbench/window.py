@@ -7,13 +7,24 @@ completion and closes AT the first completion at or after ``seconds``
 later, so nothing is counted by a wall-clock edge: a run that happens to
 start 5 ms later counts the same whole steps over the same kind of
 interval.
+
+A completion is SEEN on the host, after the step has ended on the device:
+the thread that reads the loss has to take the interpreter back from the
+loader's threads first. Seen late, it makes its own gap long and the next
+one short by as much, and the device notices nothing. So the tail of the
+gaps is taken over groups of consecutive gaps (``group_tail``): inside a
+group the two cancel, and what stalls the device does not.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
+import statistics
 from typing import List, Sequence
+
+#: A tail is read only where this many samples lie beyond it.
+LEAST_BEYOND = 10
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,3 +88,42 @@ def samples_beyond(values: Sequence[float], q: float) -> int:
     """How many samples lie beyond the ``q``-th percentile's position: a
     tail wants ten or more."""
     return int(len(values) * (100.0 - q) / 100.0)
+
+
+def group_means(gaps: Sequence[float], size: int) -> List[float]:
+    """Mean gap of each run of ``size`` consecutive gaps, the runs not
+    overlapping; gaps left over at the end are dropped."""
+    if size < 1:
+        raise ValueError(f"a group holds one gap or more, not {size}")
+    whole = len(gaps) - len(gaps) % size
+    return [sum(gaps[i:i + size]) / size for i in range(0, whole, size)]
+
+
+def group_tail(gaps: Sequence[float], size: int, q: float = 95.0,
+               least_beyond: int = LEAST_BEYOND) -> float:
+    """The ``q``-th percentile of the groups' mean gaps: the tail of the
+    time a step takes as ``size`` steps in a row show it. An error where
+    fewer than ``least_beyond`` groups lie beyond the percentile."""
+    means = group_means(gaps, size)
+    beyond = samples_beyond(means, q)
+    if beyond < least_beyond:
+        raise ValueError(
+            f"{len(gaps)} gaps make {len(means)} groups of {size}, "
+            f"{beyond} beyond the {q:g}th percentile: a tail wants "
+            f"{least_beyond} or more")
+    return percentile(means, q)
+
+
+def lag1_autocorrelation(values: Sequence[float]) -> float:
+    """Correlation of each value with the one after it: near -0.5 where
+    the values are a steady period plus the noise of the stamps at their
+    ends, near 0 where each varies on its own. 0.0 where nothing varies."""
+    if len(values) < 3:
+        raise ValueError("an autocorrelation wants three values or more")
+    mean = statistics.fmean(values)
+    centred = [v - mean for v in values]
+    var = sum(c * c for c in centred)
+    if var <= 0.0:
+        return 0.0
+    return sum(a * b for a, b in zip(centred, centred[1:])) / var
+

@@ -86,3 +86,18 @@ def test_allocator_probe_verdict(temp, before, after, left_out):
 def test_allocator_probe_refuses_off_the_chip(capsys):
     assert allocator_peak.main() == 2
     assert "not 'tpu'" in capsys.readouterr().err
+
+
+def test_completions_are_kept_only_where_asked(tmp_path, monkeypatch):
+    import json
+    ctx = _context("dlrm_train_x4")
+    kept = {"completions": [1.0, 1.5], "epoch_ends": []}
+    monkeypatch.delenv("CHIPBENCH_KEEP_GAPS", raising=False)
+    harness.keep_completions(ctx, kept)
+    assert not list(tmp_path.iterdir())
+    monkeypatch.setenv("CHIPBENCH_KEEP_GAPS", str(tmp_path / "gaps"))
+    harness.keep_completions(ctx, kept)
+    (path,) = (tmp_path / "gaps").iterdir()
+    assert path.name.startswith("dlrm_train_x4-1-")
+    assert json.loads(path.read_text()) == dict(
+        kept, cell="dlrm_train_x4", seed=1)

@@ -52,6 +52,22 @@ def test_every_workload_resolves_to_files_that_exist(cell):
         assert callable(manifest.layer_reader(metric["name"]))
 
 
+@pytest.mark.parametrize("cell,want", [
+    ("dlrm_train_x4", 1), ("dlrm_train", 0), ("bert_train", 0)])
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_open_after_epoch_ends_is_0_where_a_traffic_file_omits_it(
+        cell, want, rehearse):
+    """Only ``train-cached-x4`` names the parameter; a mix that does not
+    opens its window after ``warmup_steps``, as before there was one."""
+    from chipbench import harness
+    resolved = manifest.resolve_cell(cell)
+    assert ("open_after_epoch_ends" in resolved.traffic) is bool(want)
+    ctx = harness.Context(cell=resolved, seed=1, seconds=1.0, trace=False,
+                          rehearse=rehearse, control=None, started_at=0.0,
+                          scratch="")
+    assert ctx.traffic("open_after_epoch_ends", 0) == want
+
+
 def test_names_units_and_lengths_keep_to_the_contract(bench):
     names = []
     for section in ("configs", "workloads", "end_to_end", "per_layer"):

@@ -84,7 +84,78 @@ def test_idle_goes_to_the_innermost_span_program_spans_included():
         xplane.Trace(ops={}, modules={}, spans=SPANS), WINDOW) == {}
 
 
-@pytest.mark.parametrize("name", _NAMES)
+def test_first_sample_is_told_apart_by_the_totals_at_each_epoch_end():
+    name = "rsdl_epoch_turnover_seconds"
+
+    def first(*totals):
+        return program.first_turnover_ms(
+            {"epoch_ends": [{"program": t} for t in totals]}, histogram=name)
+
+    assert first({name: (1, 0.4668)}, {name: (2, 0.4846)}) == pytest.approx(
+        466.8)
+    # no end met yet; a program that samples nothing; a program from
+    # before the histogram; two samples by the first reading
+    assert first() is None
+    assert program.first_turnover_ms({"kind": "train"}, histogram=name) is None
+    assert first({name: (0, 0.0)}) is None
+    assert first({}) is None
+    assert first({name: (2, 0.9)}) is None
+    # the first end may hold nothing and the second the first sample
+    assert first({}, {name: (1, 0.02)}) == pytest.approx(20.0)
+
+
+def test_histogram_totals_follow_the_programs_registry():
+    from ray_shuffling_data_loader_tpu.runtime import metrics
+    name = "rsdl_epoch_turnover_seconds"
+    metrics.histogram(name).observe(0.0)
+    count, total = program.histogram_totals()[name]
+    metrics.histogram(name).observe(0.25)
+    after = program.histogram_totals()
+    assert after[name] == (count + 1, pytest.approx(total + 0.25))
+    # less an earlier reading: what came since
+    assert program.histogram_totals(since={name: (count, total)})[name] == (
+        1, pytest.approx(0.25))
+    # unlabelled histograms of the catalog only
+    assert "rsdl_stage_seconds" not in after
+    assert "rsdl_events_total" not in after
+
+
+@pytest.mark.parametrize("cell,seconds,met", [
+    ("dlrm_train_x4", 0.3, True),    # the warm-up holds epoch 0's end
+    ("dlrm_train", 0.2, False),      # 256 steps an epoch: none met
+])
+def test_first_turnover_on_a_rehearsal(cell, seconds, met, monkeypatch,
+                                       capsys):
+    """``first_turnover_ms`` through its layer file, on the facts of a CPU
+    rehearsal: the program's own first sample where the consumer has met
+    an epoch's end, nothing where it has not."""
+    from chipbench.loops import train
+    kept = {}
+    loop_run = train.run
+
+    def keeping(ctx, data_job):
+        result = loop_run(ctx, data_job)
+        kept.update(result["facts"])
+        return result
+
+    monkeypatch.setattr(train, "run", keeping)
+    assert run.main(["--workload", cell, "--seed", "31", "--seconds",
+                     str(seconds), "--trace", "0", "--rehearse"]) == 0
+    capsys.readouterr()
+    value = manifest.layer_reader("first_turnover_ms")(kept)
+    if met:
+        first = kept["epoch_ends"][0]
+        assert first["epoch"] == 0 and first["in_window"] is False
+        assert 0.0 < value <= 1e3 * first["next_batch_s"]
+    else:
+        assert kept["epoch_ends"] == [] and value is None
+    entry = next(m for m in manifest.load_manifest()["per_layer"]
+                 if m["name"] == "first_turnover_ms")
+    assert entry["workloads"] == ["dlrm_train_x4"]
+    assert (entry["moves"], entry["layer"]) == ("setup_s", "device feed")
+
+
+@pytest.mark.parametrize("name", _NAMES + ("first_turnover_ms",))
 def test_a_program_without_the_span_or_counter_gives_nothing(
         name, monkeypatch):
     """The parent commit's side of a traced run: the recorded TPU trace
