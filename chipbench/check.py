@@ -28,7 +28,7 @@ import dataclasses
 import math
 import statistics
 import time
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -119,12 +119,38 @@ def adam_update(params, grads, mu, nu, count: int, opt: Dict[str, Any]):
     return params, mu, nu
 
 
-def reference_trajectory(ref, sizes: Dict[str, Any], params0: Any,
+def copy_of(tree: Any) -> Callable[[], Any]:
+    """A ``make_params0`` for ``reference_trajectory`` over a tree the
+    caller goes on using: every call hands out a fresh copy."""
+    return lambda: jax.tree.map(jnp.copy, tree)
+
+
+def _adam_in_place(count: int, opt: Dict[str, Any]):
+    """``adam_update`` with the parameters' and both moments' buffers given
+    to its outputs: the update costs no memory of its own (the TPU and the
+    CPU both honour the donation; three copies where a backend does not)."""
+    return jax.jit(lambda p, g, m, v: adam_update(p, g, m, v, count, opt),
+                   donate_argnums=(0, 2, 3))
+
+
+def reference_trajectory(ref, sizes: Dict[str, Any],
+                         make_params0: Callable[[], Any],
                          batches: Sequence[Tuple[Sequence[Any], Any]],
                          opt: Dict[str, Any], seed_key,
                          lower_precision: bool = False) -> Dict[str, Any]:
     """Losses, first-gradient norms and parameter-change norms of the
-    plain reference over ``batches`` from ``params0``.
+    plain reference over ``batches`` from the parameters that
+    ``make_params0`` returns.
+
+    The footprint is the program's own, 16 bytes a float32 parameter (the
+    parameters, Adam's two moments, one gradient) plus the reference's
+    activations. So no copy of the starting parameters is kept while the
+    steps run: the trajectory owns what ``make_params0`` returns and
+    updates it in place, drops each gradient once Adam has read it, and
+    calls ``make_params0`` again at the end, the moments freed, for the
+    norm of the change (three copies: both parameters and their
+    difference). A caller that goes on using its starting tree passes
+    ``copy_of(tree)``.
 
     ``lower_precision`` is the control: the same reference with its
     parameters, moments and arithmetic in bfloat16, the precision below
@@ -132,13 +158,18 @@ def reference_trajectory(ref, sizes: Dict[str, Any], params0: Any,
     place, it has to come out as not correct."""
     if opt["name"] != "adam":
         raise ValueError(f"the reference knows Adam, not {opt['name']!r}")
-    if lower_precision:
-        params0 = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params0)
+
+    def start():
+        params0 = make_params0()
+        if lower_precision:
+            params0 = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params0)
+        return params0
+
     with jax.default_matmul_precision(
             "default" if lower_precision else "highest"):
-        params = params0
-        mu = jax.tree.map(jnp.zeros_like, params0)
-        nu = jax.tree.map(jnp.zeros_like, params0)
+        params = start()
+        mu = jax.tree.map(jnp.zeros_like, params)
+        nu = jax.tree.map(jnp.zeros_like, params)
         losses: List[float] = []
         grad_norms: Dict[str, float] = {}
         for i, (features, labels) in enumerate(batches):
@@ -150,11 +181,14 @@ def reference_trajectory(ref, sizes: Dict[str, Any], params0: Any,
                   f"{time.perf_counter() - t0:.2f} s", flush=True)
             if i == 0:
                 grad_norms = leaf_norms(grads)
-            params, mu, nu = jax.jit(
-                lambda p, g, m, v, c=i + 1: adam_update(p, g, m, v, c, opt))(
-                    params, grads, mu, nu)
+            params, mu, nu = _adam_in_place(i + 1, opt)(params, grads, mu, nu)
+            # Dispatch is asynchronous: a gradient dropped while its reader
+            # still runs is freed after the next one has been allocated.
+            jax.block_until_ready(nu)
+            del grads
+        del mu, nu
         return {"losses": losses, "grad_norms": grad_norms,
-                "change_norms": diff_norms(params, params0)}
+                "change_norms": diff_norms(params, start())}
 
 
 def step_loss_gaps(program: Dict[str, Any], reference: Dict[str, Any]

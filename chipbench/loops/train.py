@@ -27,7 +27,7 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from chipbench import check, harness, manifest, window
+from chipbench import check, harness, manifest, window, xplane
 from chipbench.harness import clock, info, span
 from chipbench.readers import program as program_readers
 
@@ -323,9 +323,14 @@ def _drive(run: _Run) -> Dict[str, Any]:
     # ``memory_peak_bytes`` is their sum: the chip's peak while a step
     # runs over the resident state.
     t0 = clock()
-    step_temp = int(trainer.step_fn.lower(
+    step_program = trainer.step_fn.lower(
         trainer.params, trainer.opt_state, features, label, np.int32(0),
-        mask_key).compile().memory_analysis().temp_size_in_bytes)
+        mask_key).compile()
+    step_temp = int(step_program.memory_analysis().temp_size_in_bytes)
+    # The same text names each instruction's scope for the trace's readers.
+    step_op_names = (xplane.hlo_op_names(step_program.as_text())
+                     if ctx.trace else {})
+    del step_program
     device["allocator_peak_bytes"] = device["memory_peak_bytes"]
     device["step_temp_bytes"] = step_temp
     device["memory_peak_bytes"] += step_temp
@@ -345,13 +350,17 @@ def _drive(run: _Run) -> Dict[str, Any]:
     # -- the reference, once the window is closed ---------------------------------
     t0 = clock()
     trainer.params = trainer.opt_state = None   # free the program's state
+    # The trajectory holds what the program's state held and no copy of the
+    # starting parameters beside it: it makes them anew when it needs them.
     if touched is not None:
         ref_batches = [(ref.remap(f, touched), y) for f, y in host_batches]
-        ref_p0 = p0_small
+        ref_p0 = check.copy_of(p0_small)
     else:
         ref_batches = host_batches
-        ref_p0 = jax.jit(lambda k: ref.init_params(sizes, k))(
-            jax.random.fold_in(key, 0))
+        ref_init = jax.jit(lambda k: ref.init_params(sizes, k))
+
+        def ref_p0():
+            return ref_init(jax.random.fold_in(key, 0))
     reference = check.reference_trajectory(ref, sizes, ref_p0, ref_batches,
                                            opt_cfg, mask_key)
     if ctx.control == "ref_bf16":
@@ -420,6 +429,7 @@ def _drive(run: _Run) -> Dict[str, Any]:
         "step_compiles": compiles_after - compiles_before,
         "device": device, "trace_path": traced.path,
         "setup_s": setup_s, "epoch_ends": epoch_ends,
+        "step_module": "^jit_train_step$", "step_op_names": step_op_names,
     }
     metrics = {
         "train_rows_per_s": rate,

@@ -154,16 +154,17 @@ def main(argv: Optional[List[str]] = None) -> int:
         program["change_norms"] = check.diff_norms(
             take_rows(trainer.params, touched), p0_small)
         ref_batches = [(ref.remap(f, touched), y) for f, y in host_batches]
+        ref_p0 = check.copy_of(p0_small)
         reference = check.reference_trajectory(
-            ref, sizes, p0_small, ref_batches, opt_cfg, mask_key)
+            ref, sizes, ref_p0, ref_batches, opt_cfg, mask_key)
         record = {"workload": cell.name, "seed": seed,
                   "losses": program["losses"],
                   "sound": summary(program, reference)}
         if n < args.control_seeds:
             record["control"] = summary(check.reference_trajectory(
-                ref, sizes, p0_small, ref_batches, opt_cfg, mask_key,
+                ref, sizes, ref_p0, ref_batches, opt_cfg, mask_key,
                 lower_precision=True), reference)
-        del p0_small
+        del p0_small, ref_p0
         records.append(record)
         print(json.dumps(record), flush=True)
         if args.out:

@@ -81,6 +81,31 @@ def kernel_pct_of_step(facts: Dict[str, Any], pattern: str, module: str
     return _share("kernel_pct_of_step", 100.0 * kernel / steps)
 
 
+def scope_pct_of_step(facts: Dict[str, Any], scope: str, module: str
+                      ) -> Optional[float]:
+    """Device time of the operations the program ran under ``scope`` (a
+    ``jax.named_scope`` of its own) over the device time of the jitted
+    step's runs. Which operations those are comes from the compiled
+    step's text (``facts["step_op_names"]``): whatever XLA made of the
+    scope's work, fusions and collectives alike."""
+    if facts.get("trace") is None or not facts.get("step_op_names"):
+        return None
+    trace, win = facts["trace"], facts["trace_window"]
+    runs = xplane.module_durations(trace, win, module)
+    by_op = xplane.scope_op_seconds(trace, win, scope,
+                                    facts["step_op_names"], module)
+    under = sum(by_op.values())
+    if not runs or under <= 0:
+        return None
+    print(f"# scope {scope}: {1e3 * under / len(runs):.4f} ms a step of "
+          f"{1e3 * sum(runs) / len(runs):.4f} ms over {len(runs)} steps: "
+          + ", ".join(f"{what or '(itself)'} {1e3 * s / len(runs):.4f}"
+                      for what, s in sorted(by_op.items(),
+                                            key=lambda kv: -kv[1])),
+          flush=True)
+    return _share("scope_pct_of_step", 100.0 * under / sum(runs))
+
+
 def collective_exposed_pct(facts: Dict[str, Any]) -> Optional[float]:
     """Collective time during which no other operation runs on that chip,
     over the traced window."""

@@ -76,6 +76,20 @@ def per_layer_metrics(cell, facts: Dict[str, Any], manifest_mod
     return out
 
 
+def print_op_scopes(device_ops: List[List[Any]], facts: Dict[str, Any]
+                    ) -> None:
+    """Beside each of the breakdown's operations, the scope the program
+    ran it under (its instruction's ``op_name``), where the loop kept its
+    step's compiled text: what tells ``fusion.9`` from ``fusion.11``."""
+    from chipbench import harness, xplane
+    names = facts.get("step_op_names")
+    scopes = (xplane.op_scopes(facts["trace"], facts["trace_window"], names,
+                               facts["step_module"]) if names else {})
+    for name, seconds in device_ops:
+        harness.info(f"device op {name}: {seconds:.6f} s under "
+                     f"{scopes.get(name, '(no op_name)')}")
+
+
 def _with_compared(line: Dict[str, Any], result: Dict[str, Any]) -> None:
     """Each number that decided ``correct`` beside its limit: the last
     lines on standard error, and the last key of the result's line (the
@@ -156,6 +170,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             device["window_s"] = win[1] - win[0]
             line["metrics"] = per_layer_metrics(cell, facts, manifest)
             line["breakdown"] = xplane.breakdown(trace, win)
+            print_op_scopes(line["breakdown"]["device_ops"], facts)
         else:
             units = {m["name"]: m["unit"] for m in cell.end_to_end}
             line["metrics"] = {

@@ -16,6 +16,7 @@ recorded trace.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -90,12 +91,67 @@ def parse_hlo(text: str) -> Tuple[str, str, str]:
     return name, opcode, label
 
 
+def hlo_name(text: str) -> str:
+    """``parse_hlo``'s first result alone, for a pass over every operation
+    of a trace."""
+    return text.partition(" = ")[0].lstrip("%").strip()
+
+
 def op_label(text: str) -> Tuple[str, str]:
     """(display name, opcode) for an operation's HLO text."""
     name, opcode, shape = parse_hlo(text)
     if not opcode:
         return name, ""
     return f"{name}_{opcode}_{shape}", opcode
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def hlo_op_names(module_text: str) -> Dict[str, str]:
+    """Instruction name -> ``op_name`` of its ``metadata``, over the text of
+    a compiled module (``compiled.as_text()``): the path of ``jit``,
+    transform and ``jax.named_scope`` names the program ran that
+    operation under, ``jit(step)/transpose(jvp())/<scope>/scatter-add``.
+    An instruction that has none and calls a computation (a fusion the
+    compiler made around an asynchronous collective) takes the ``op_name``
+    most of that computation's instructions have. The names are the ones
+    the trace's operations carry (``hlo_name``)."""
+    own: Dict[str, str] = {}
+    calls: Dict[str, str] = {}
+    inside: Dict[str, List[str]] = {}
+    computation = None
+    for line in module_text.splitlines():
+        if not line.startswith(" "):
+            found = _COMPUTATION.match(line)
+            computation = found.group(1) if found else None
+            continue
+        found = _INSTRUCTION.match(line)
+        if not found or computation is None:
+            continue
+        name = found.group(1)
+        op_name = _OP_NAME.search(line)
+        if op_name:
+            own[name] = op_name.group(1)
+            inside.setdefault(computation, []).append(op_name.group(1))
+        else:
+            called = _CALLS.search(line)
+            if called:
+                calls[name] = called.group(1)
+    for name, called in calls.items():
+        held = inside.get(called)
+        if held:
+            own[name] = max(sorted(set(held)), key=held.count)
+    return own
+
+
+def under_scope(op_name: str, scope: str) -> bool:
+    """Whether ``scope`` (one ``jax.named_scope`` name, or several joined
+    by ``/``) is on the path ``op_name``."""
+    return f"/{scope}/" in f"/{op_name}/"
 
 
 # -- reading -------------------------------------------------------------------
@@ -247,6 +303,62 @@ def matching_seconds(trace: Trace, window: Interval, pattern: str
                             if rx.search(op.text)), window))
                 for ops in trace.ops.values()]
     return sum(per_chip) / max(1, len(per_chip))
+
+
+def _ops_in_runs(trace: Trace, window: Interval, module: str
+                 ) -> Iterable[Op]:
+    """The operations that ran inside a run of a program whose name
+    matches ``module``, the run wholly inside ``window``. Another program
+    of the window (the loader's carve, the digest) has instructions of the
+    same names, ``fusion.3``: a name says which instruction only within
+    its own program."""
+    rx = re.compile(module)
+    for chip, ops in trace.ops.items():
+        runs = sorted((m.start, m.end) for m in trace.modules.get(chip, ())
+                      if rx.search(m.name) and m.start >= window[0]
+                      and m.end <= window[1])
+        starts = [r[0] for r in runs]
+        for op in ops:
+            at = bisect.bisect_right(starts, op.start) - 1
+            if at >= 0 and op.start < runs[at][1]:
+                yield op
+
+
+def scope_op_seconds(trace: Trace, window: Interval, scope: str,
+                     names: Dict[str, str], module: str) -> Dict[str, float]:
+    """Seconds of the operations the program ran under ``scope``
+    (``jax.named_scope``), averaged over the chips, by what follows the
+    scope on their path (``scatter-add``, ``all_gather``): those of the
+    runs of ``module`` inside ``window`` whose instruction ``names``
+    (``hlo_op_names`` of that program's compiled text) puts under it."""
+    sums: Dict[str, float] = {}
+    for op in _ops_in_runs(trace, window, module):
+        op_name = names.get(hlo_name(op.text), "")
+        if under_scope(op_name, scope):
+            what = f"/{op_name}/".partition(f"/{scope}/")[2].strip("/")
+            sums[what] = sums.get(what, 0.0) + op.end - op.start
+    chips = max(1, len(trace.ops))
+    return {what: s / chips for what, s in sums.items()}
+
+
+def scope_seconds(trace: Trace, window: Interval, scope: str,
+                  names: Dict[str, str], module: str) -> float:
+    """``scope_op_seconds`` summed: all the scope's device time."""
+    return sum(scope_op_seconds(trace, window, scope, names,
+                                module).values())
+
+
+def op_scopes(trace: Trace, window: Interval, names: Dict[str, str],
+              module: str) -> Dict[str, str]:
+    """Display name (``op_seconds``' keys) -> ``op_name`` for the
+    operations of ``module``'s runs that have one."""
+    out: Dict[str, str] = {}
+    for op in _ops_in_runs(trace, window, module):
+        if op.name not in out:
+            op_name = names.get(hlo_name(op.text))
+            if op_name:
+                out[op.name] = op_name
+    return out
 
 
 def module_durations(trace: Trace, window: Interval, name_pattern: str

@@ -2,12 +2,21 @@
 the configuration's ``limits`` (``chipbench/check.compare``), and the two
 DLRM takes (loss at the seeded weights, median leaf's change) let an
 ill-conditioned seed through while every fault they are there for fails.
+
+The reference's trajectory (``check.reference_trajectory``) holds what the
+program's state holds and no more, and computes what the one before it
+(``chipbench_oracle.py``) computed, digit for digit.
 """
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
+from chipbench_oracle import old_reference_trajectory
 
 from chipbench import check, manifest
-from chipbench.probes import first_steps
+from chipbench.probes import check_footprint, first_steps
+from chipbench.references import dlrm as dlrm_reference
 
 _WORST = {"loss_gap": 0.006, "first_grad_norm_gap": 0.1,
           "param_change_norm_gap": 0.013}
@@ -146,3 +155,158 @@ def test_the_probe_refuses_off_the_chip(capsys):
     assert first_steps.main(["--workload", "dlrm_train", "--first-seed", "1",
                              "--seeds", "1"]) == 2
     assert "TPU chip" in capsys.readouterr().err
+
+
+# -- the reference's trajectory: the old one's numbers at the program's footprint
+
+_ADAM = {"name": "adam", "learning_rate": 1e-3, "b1": 0.9, "b2": 0.999,
+         "eps": 1e-8}
+
+
+class _Synthetic:
+    """A plain reference over a seeded tree of leaves of mixed shapes: a
+    loss whose gradient reaches every element and differs from batch to
+    batch, next to no activations."""
+
+    def __init__(self, shapes):
+        self.shapes = shapes
+        self._value_and_grad = jax.jit(jax.value_and_grad(self._loss))
+        self.init = jax.jit(self._init)
+
+    def _init(self):
+        keys = jax.random.split(jax.random.key(28), len(self.shapes))
+        leaves = [jax.random.normal(k, s, jnp.float32)
+                  for k, s in zip(keys, self.shapes)]
+        return {"a": {"w": leaves[0], "b": leaves[1]},
+                "rest": tuple(leaves[2:])}
+
+    @staticmethod
+    def _loss(params, x, y):
+        z = jnp.stack([jnp.mean(jnp.tanh(p))
+                       for p in jax.tree.leaves(params)])
+        pred = x[:, :z.shape[0]].astype(z.dtype) @ z
+        decay = sum(jnp.mean(jnp.square(p)) for p in jax.tree.leaves(params))
+        return (jnp.mean(jnp.square(pred - y.astype(z.dtype)))
+                + 1e-3 * decay).astype(jnp.float32)
+
+    def value_and_grad(self, sizes, params, features, labels, step,
+                       seed_key):
+        return self._value_and_grad(params, features[0], labels)
+
+    def batches(self, steps=check.STEPS):
+        rng = np.random.default_rng(5)
+        return [([rng.normal(size=(16, len(self.shapes))).astype(np.float32)],
+                 rng.normal(size=(16,)).astype(np.float32))
+                for _ in range(steps)]
+
+
+def _live_bytes():
+    return sum(x.nbytes for x in jax.live_arrays())
+
+
+_MIXED = [(300, 64), (64,), (7, 5, 3), (1,), (1000, 16), (33, 9), (2, 2)]
+
+
+@pytest.mark.parametrize("lower_precision", [False, True],
+                         ids=["sound", "lower_precision"])
+def test_the_trajectory_computes_what_the_old_one_did(lower_precision,
+                                                      capsys):
+    ref = _Synthetic(_MIXED)
+    new = check.reference_trajectory(ref, {}, ref.init, ref.batches(), _ADAM,
+                                     None, lower_precision=lower_precision)
+    old = old_reference_trajectory(ref, {}, ref.init(), ref.batches(), _ADAM,
+                                   None, lower_precision=lower_precision)
+    assert len(new["losses"]) == check.STEPS
+    assert len(new["change_norms"]) == len(_MIXED)
+    # (a step of 1e-3 is under bfloat16's grid around 1: the control moves
+    # only the elements near nought, and a leaf of one element not at all)
+    assert all(v > 0 for v in new["change_norms"].values()) != lower_precision
+    assert new == old, "not one digit may differ"
+
+
+def test_the_trajectory_holds_what_the_program_holds_where_the_old_held_eight(
+        monkeypatch, capsys):
+    """Live bytes over the footprint probe's own reference (a dozen
+    unequal leaves) when each gradient has just been made and around each
+    update (its inputs still held by the wrapper, its outputs made): the
+    parameters, two moments and one gradient. The ceiling is five copies
+    and a leaf; the old function reads eight."""
+    seen = []
+
+    class Watched(check_footprint.Reference):
+        def value_and_grad(self, *args, **kwargs):
+            out = super().value_and_grad(*args, **kwargs)
+            seen.append(_live_bytes())
+            return out
+
+    before = _live_bytes()
+    ref = Watched(1_200_000)
+    copy = 4 * ref.params
+    leaf = 4 * max(check_footprint.leaf_sizes(1_200_000))
+    key = jax.random.key(2)
+    batches = [([], np.float32(0.25 * (i + 1))) for i in range(check.STEPS)]
+    adam_in_place = check._adam_in_place
+
+    def watched(count, opt):
+        update = adam_in_place(count, opt)
+
+        def around(p, g, m, v):
+            out = update(p, g, m, v)
+            seen.append(_live_bytes())
+            return out
+        return around
+
+    monkeypatch.setattr(check, "_adam_in_place", watched)
+    check.reference_trajectory(ref, {}, lambda: ref.init(key), batches,
+                               _ADAM, None)
+    monkeypatch.undo()
+    assert len(seen) == 2 * check.STEPS
+    new_peak = max(seen) - before
+    assert new_peak <= 5 * copy + leaf
+    assert 3.9 * copy <= new_peak <= 4.1 * copy
+    del seen[:]
+    old_reference_trajectory(ref, {}, ref.init(key), batches, _ADAM, None,
+                             observe=lambda: seen.append(_live_bytes()))
+    assert max(seen) - before >= 7.9 * copy
+
+
+def test_a_kept_starting_tree_outlives_two_trajectories(capsys):
+    """The ``touched`` path of ``dlrm-mlperf``: the cut tables are small,
+    are read again by the control and the probe, and so are copied for
+    the trajectory, which gives away what it is handed."""
+    sizes = {"vocab_sizes": [50, 30, 1000, 7], "embed_dim": 8,
+             "top_hidden": [16, 8]}
+    rng = np.random.default_rng(3)
+    batches = [([rng.integers(0, v, size=(32, 1)).astype(np.int32)
+                 for v in sizes["vocab_sizes"]],
+                rng.random((32, 1)).astype(np.float32)) for _ in range(3)]
+    touched = dlrm_reference.touched_rows(sizes, batches)
+    p0_small = jax.jit(dlrm_reference.take_rows)(
+        dlrm_reference.init_params(sizes, jax.random.key(1)), touched)
+    kept = jax.tree.map(np.asarray, p0_small)
+    cut = [(dlrm_reference.remap(f, touched), y) for f, y in batches]
+    make = check.copy_of(p0_small)
+    sound = check.reference_trajectory(dlrm_reference, sizes, make, cut,
+                                       _ADAM, None)
+    control = check.reference_trajectory(dlrm_reference, sizes, make, cut,
+                                         _ADAM, None, lower_precision=True)
+    for leaf, was in zip(jax.tree.leaves(p0_small), jax.tree.leaves(kept)):
+        assert not leaf.is_deleted()
+        assert (np.asarray(leaf) == was).all()
+    assert check.median_leaf_gap(control["change_norms"],
+                                 sound["change_norms"]) > 0.005
+
+
+def test_the_footprint_probe_holds_the_trajectory_to_twenty_bytes(capsys):
+    """``chipbench/probes/check_footprint.py`` off the chip: it refuses to
+    read a peak the CPU cannot give; its leaves are a dozen unequal ones;
+    its verdict is 20 bytes a parameter (its reference drives the
+    trajectory in the test of the footprint above)."""
+    assert check_footprint.main(["--params", "1e6"]) == 2
+    assert "allocator" in capsys.readouterr().err
+    sizes = check_footprint.leaf_sizes(640_000_000)
+    assert len(sizes) == 12 and len(set(sizes)) == 12
+    assert abs(sum(sizes) - 640_000_000) < 12 * 1024
+    assert max(sizes) == 160_000_000
+    assert check_footprint.verdict(20 * 640_000_000, 640_000_000)
+    assert not check_footprint.verdict(20 * 640_000_000 + 1, 640_000_000)

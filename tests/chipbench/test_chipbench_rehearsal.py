@@ -13,8 +13,9 @@ import subprocess
 import sys
 
 import pytest
+from chipbench_oracle import BothTrajectories
 
-from chipbench import manifest, run
+from chipbench import check, manifest, run
 
 _ROOT = manifest.CHECKOUT
 
@@ -41,10 +42,25 @@ def _rehearse(capsys, cell, *extra, seed=11, seconds=1.0):
 _SOUND = {}
 
 
+#: What the reference's trajectory returned in each of them, beside what
+#: the trajectory of before PR 28 returns for the same arguments.
+_TRAJECTORIES = {}
+
+
+def _rehearse_both(capsys, cell, *extra, **kwargs):
+    """``_rehearse`` with the old trajectory run beside the new one:
+    (result, lines, [(lower_precision, new, old), ...])."""
+    both = BothTrajectories()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(check, "reference_trajectory", both)
+        return (*_rehearse(capsys, cell, *extra, **kwargs), both.pairs)
+
+
 def _sound(capsys, cell):
     if cell not in _SOUND:
         seed = 2**31 + 77 if cell == "dlrm_train" else 5
-        _SOUND[cell] = _rehearse(capsys, cell, seed=seed)
+        *_SOUND[cell], _TRAJECTORIES[cell] = _rehearse_both(capsys, cell,
+                                                            seed=seed)
     return _SOUND[cell]
 
 
@@ -75,6 +91,21 @@ def test_sound_rehearsal_is_correct_and_names_the_cpu(capsys, cell):
     assert compared and all(ln.endswith(" ok") for ln in compared)
     assert any(ln.startswith("# window: ") for ln in lines)
     assert any(ln.startswith("# setup split s: ") for ln in lines)
+
+
+@pytest.mark.parametrize("cell", ["dlrm_train", "bert_train",
+                                  "dlrm_train_x4"])
+def test_the_references_numbers_are_those_of_the_trajectory_before(capsys,
+                                                                   cell):
+    """Every number ``correct`` is decided from, at a fixed seed: the
+    trajectory that holds the program's footprint returns what the one
+    that held twice as much did (``chipbench_oracle.py``), on the cell's
+    own reference, batches and weights."""
+    _sound(capsys, cell)
+    (lower_precision, new, old), = _TRAJECTORIES[cell]
+    assert not lower_precision
+    assert len(new["losses"]) == check.STEPS and new["change_norms"]
+    assert new == old
 
 
 _WINDOW_LINE = re.compile(
@@ -131,7 +162,15 @@ def test_the_precision_below_is_not_correct(capsys, cell, control):
     plain reference, computed in bfloat16, in the program's place (and
     prints the sound program's numbers on earlier lines);
     ``bf16_params`` hands the program bfloat16 parameters."""
-    result, lines = _rehearse(capsys, cell, "--control", control)
+    if (cell, control) == ("dlrm_train", "ref_bf16"):
+        # the control goes through the same trajectory, after the sound
+        # one, and reads what the trajectory of before read
+        result, lines, trajectories = _rehearse_both(capsys, cell,
+                                                     "--control", control)
+        assert [lower for lower, _, _ in trajectories] == [False, True]
+        assert all(new == old for _, new, old in trajectories)
+    else:
+        result, lines = _rehearse(capsys, cell, "--control", control)
     assert result["correct"] is False
     assert any("param_change_" in ln and ln.endswith("FAILED")
                for ln in lines if ln.startswith("# compared"))

@@ -131,3 +131,158 @@ def test_gap_goes_to_the_innermost_span_and_the_rest_to_no_span():
     got = xplane.attribute_gaps([(1.0, 5.0), (11.0, 12.0)], spans)
     assert got == {"inner": pytest.approx(2.0), "outer": pytest.approx(2.0),
                    "no_span": pytest.approx(1.0)}
+
+
+# -- operations by the program's own scope ------------------------------------
+
+@pytest.fixture(scope="module")
+def compiled_step():
+    """(text of a small compiled module, its entry computation's
+    instructions as the trace would name them). The scatter-add and what
+    feeds it run under ``jax.named_scope("probe.exchange")`` and end up
+    inside fusions; the tanh and the sums do not."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(w, x):
+        h = jnp.tanh(x @ w)
+        with jax.named_scope("probe.exchange"):
+            z = jnp.zeros((64, 8), jnp.float32).at[jnp.arange(16) * 3].add(
+                h[:16] * 2.0 + 1.0)
+        return jnp.sum(z) + jnp.sum(h)
+
+    text = jax.jit(step).lower(jnp.ones((8, 8)),
+                               jnp.ones((32, 8))).compile().as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()[2:]
+    entry = [ln.strip().removeprefix("ROOT ")
+             for ln in entry[:entry.index("}")]]
+    return text, entry
+
+
+def test_a_compiled_modules_text_names_each_instructions_scope(compiled_step):
+    text, entry = compiled_step
+    names = xplane.hlo_op_names(text)
+    by_name = {xplane.parse_hlo(ln)[0]: xplane.parse_hlo(ln)[1]
+               for ln in entry}
+    under = {n for n in by_name
+             if xplane.under_scope(names.get(n, ""), "probe.exchange")}
+    fusions = {n for n, opcode in by_name.items() if opcode == "fusion"}
+    assert under and under <= fusions, "the scope's work is inside fusions"
+    assert any(names[n].endswith("probe.exchange/scatter-add")
+               for n in under)
+    assert fusions - under, "and other fusions are not under it"
+    tanh = [n for n in by_name if names.get(n, "").endswith("/tanh")]
+    assert tanh and not set(tanh) & under
+    assert all(names[n].startswith("jit(step)/") for n in under)
+
+
+@pytest.mark.parametrize("op_name,scope,want", [
+    ("jit(f)/transpose(jvp())/shard_map/rsdl.a.b/scatter-add", "rsdl.a.b",
+     True),
+    ("jit(f)/rsdl.a.b2/add", "rsdl.a.b", False),
+    ("jit(f)/rsdl.a.b/add", "a.b", False),
+    ("jit(f)/outer/inner/add", "outer/inner", True),
+    ("rsdl.a.b", "rsdl.a.b", True),
+    ("", "rsdl.a.b", False),
+])
+def test_a_scope_is_whole_names_on_the_path(op_name, scope, want):
+    assert xplane.under_scope(op_name, scope) is want
+
+
+def test_an_instruction_with_no_op_name_takes_its_computations():
+    text = """HloModule jit_f
+
+%fused_computation.7 (p: s32[8]) -> s32[32] {
+  %p = s32[8]{0} parameter(0)
+  %all-gather.1 = s32[32]{0} all-gather(%p), dimensions={0}, metadata={op_name="jit(f)/rsdl.x/all_gather" stack_frame_id=2}
+  ROOT %custom-call.7 = s32[32]{0} custom-call(%all-gather.1)
+}
+
+ENTRY %main (a: s32[8]) -> s32[32] {
+  %a = s32[8]{0} parameter(0)
+  %copy-start.1 = s32[8]{0} copy-start(%a)
+  ROOT %async-collective-start = s32[32]{0} fusion(%a), kind=kCustom, calls=%fused_computation.7
+}
+"""
+    names = xplane.hlo_op_names(text)
+    assert names["async-collective-start"] == "jit(f)/rsdl.x/all_gather"
+    assert names["all-gather.1"] == "jit(f)/rsdl.x/all_gather"
+    assert "copy-start.1" not in names and "a" not in names
+
+
+def test_scope_seconds_counts_the_scopes_operations_in_the_modules_runs(
+        compiled_step):
+    text, entry = compiled_step
+    names = xplane.hlo_op_names(text)
+    under = [ln for ln in entry if xplane.under_scope(
+        names.get(xplane.parse_hlo(ln)[0], ""), "probe.exchange")]
+
+    def run_of(start):
+        """Every entry instruction for 0.01 s, one after the other."""
+        return [xplane.Op(*xplane.op_label(ln), ln, start + 0.01 * i,
+                          start + 0.01 * (i + 1))
+                for i, ln in enumerate(entry)]
+
+    def module(name, start):
+        return xplane.Op(name, "module", f"{name}(123)", start, start + 1.0)
+
+    # Two runs of the step; between them another program whose
+    # instructions carry the same names, which says nothing about them.
+    chip = {"ops": run_of(0.0) + run_of(1.0) + run_of(2.0),
+            "modules": [module("jit_step", 0.0), module("jit_other", 1.0),
+                        module("jit_step", 2.0)]}
+    trace = xplane.Trace(ops={0: chip["ops"], 1: list(chip["ops"])},
+                         modules={0: chip["modules"], 1: chip["modules"]},
+                         spans=[])
+    got = xplane.scope_seconds(trace, (0.0, 3.5), "probe.exchange", names,
+                               "^jit_step$")
+    assert got == pytest.approx(2 * 0.01 * len(under))
+    # a run that the window cuts is left out, as module_durations leaves it
+    assert xplane.scope_seconds(trace, (0.0, 2.5), "probe.exchange", names,
+                                "^jit_step$") == pytest.approx(
+                                    0.01 * len(under))
+    assert xplane.scope_seconds(trace, (0.0, 3.5), "probe.other", names,
+                                "^jit_step$") == 0.0
+    scopes = xplane.op_scopes(trace, (0.0, 3.5), names, "^jit_step$")
+    for ln in under:
+        assert xplane.under_scope(scopes[xplane.op_label(ln)[0]],
+                                  "probe.exchange")
+    from chipbench.readers import device
+    facts = {"trace": trace, "trace_window": (0.0, 3.5),
+             "step_op_names": names, "step_module": "^jit_step$"}
+    assert device.scope_pct_of_step(
+        facts, "probe.exchange", "^jit_step$") == pytest.approx(
+            100 * 2 * 0.01 * len(under) / 2.0)
+    by_op = xplane.scope_op_seconds(trace, (0.0, 3.5), "probe.exchange",
+                                    names, "^jit_step$")
+    assert "scatter-add" in by_op and sum(by_op.values()) == pytest.approx(
+        got)
+    assert device.scope_pct_of_step(facts, "probe.other",
+                                    "^jit_step$") is None
+    assert device.scope_pct_of_step(dict(facts, step_op_names={}),
+                                    "probe.exchange", "^jit_step$") is None
+
+
+def test_the_run_prints_each_breakdown_operations_scope(compiled_step,
+                                                        capsys):
+    from chipbench import run
+    text, entry = compiled_step
+    names = xplane.hlo_op_names(text)
+    ops = [xplane.Op(*xplane.op_label(ln), ln, 0.01 * i, 0.01 * (i + 1))
+           for i, ln in enumerate(entry)]
+    trace = xplane.Trace(
+        ops={0: ops}, spans=[],
+        modules={0: [xplane.Op("jit_step", "module", "jit_step(1)", 0.0,
+                               1.0)]})
+    facts = {"trace": trace, "trace_window": (0.0, 1.0),
+             "step_op_names": names, "step_module": "^jit_step$"}
+    top = xplane.breakdown(trace, (0.0, 1.0))["device_ops"]
+    run.print_op_scopes(top, facts)
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == len(top) <= 10
+    assert all(ln.startswith("# device op ") for ln in lines)
+    assert any(ln.endswith("probe.exchange/scatter-add") for ln in lines)
+    assert any(ln.endswith("jit(step)/tanh") for ln in lines)
+    # a loop that keeps no compiled text: the names alone, as before
+    run.print_op_scopes(top[:1], dict(facts, step_op_names={}))
+    assert capsys.readouterr().out.strip().endswith("under (no op_name)")
