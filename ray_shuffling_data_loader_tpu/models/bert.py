@@ -14,7 +14,8 @@ TPU-first choices:
 - bf16 compute / f32 params & softmax accumulation; static seq_len, fused
   QKV projection; attention is two batched matmuls on the MXU.
 - MLM loss masks with a -100 ignore-id convention (positions to predict
-  carry their target id, others -100).
+  carry their target id, others -100) and projects only those positions
+  onto the vocabulary, in blocks (``_masked_nll``).
 """
 
 from __future__ import annotations
@@ -26,7 +27,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
+
 IGNORE_ID = -100
+
+# The name a device trace shows the head's operations under.
+MLM_HEAD_SCOPE = "rsdl.bert.mlm_head"
+# The loss walks a row's masked positions in this many blocks at most: at
+# the paper's 15 % the fullest row of a batch ends in the second.
+_MLM_BLOCKS_PER_ROW = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,11 +138,12 @@ def _layer_norm(x, scale, bias, eps=1e-12):
     return out.astype(x.dtype)
 
 
-def apply(config: BertConfig, params: Dict[str, Any],
-          token_ids: jax.Array,
-          attention_mask: jax.Array = None,
-          attention_fn=None) -> jax.Array:
-    """token_ids (B, S) int32 -> logits (B, S, vocab).
+def encode(config: BertConfig, params: Dict[str, Any],
+           token_ids: jax.Array,
+           attention_mask: jax.Array = None,
+           attention_fn=None) -> jax.Array:
+    """token_ids (B, S) int32 -> hidden states (B, S, hidden_dim) in the
+    compute dtype, after the last transformer layer.
 
     ``attention_mask`` (B, S) with 1 = attend, 0 = padding; None = all 1.
 
@@ -185,24 +195,154 @@ def apply(config: BertConfig, params: Dict[str, Any],
         layer_fn = jax.checkpoint(layer_fn)
     for layer in range(config.num_layers):
         x = layer_fn(x, params[f"layer_{layer}"], bias)
+    return x
 
-    # MLM head: tied to the token embedding (standard BERT).
-    logits = jnp.einsum("bsh,vh->bsv", x,
-                        params["token_emb"].astype(dtype))
-    return logits.astype(jnp.float32) + params["mlm_bias"]
+
+def _head(x: jax.Array, token_emb: jax.Array, mlm_bias: jax.Array
+          ) -> jax.Array:
+    """Hidden states (B, n, h) -> float32 logits (B, n, vocab): the MLM
+    head, tied to the token embedding (standard BERT)."""
+    with jax.named_scope(MLM_HEAD_SCOPE):
+        logits = jnp.einsum("bsh,vh->bsv", x, token_emb.astype(x.dtype))
+        return logits.astype(jnp.float32) + mlm_bias
+
+
+def apply(config: BertConfig, params: Dict[str, Any],
+          token_ids: jax.Array,
+          attention_mask: jax.Array = None,
+          attention_fn=None) -> jax.Array:
+    """token_ids (B, S) int32 -> logits (B, S, vocab); the arguments are
+    :func:`encode`'s."""
+    x = encode(config, params, token_ids, attention_mask, attention_fn)
+    return _head(x, params["token_emb"], params["mlm_bias"])
+
+
+def _block_nll(x, token_emb, mlm_bias, targets):
+    """Summed cross-entropy of the positions of ``x`` (B, n, h) whose
+    ``targets`` (B, n) are not ``IGNORE_ID``."""
+    mask = targets != IGNORE_ID
+    logp = jax.nn.log_softmax(_head(x, token_emb, mlm_bias), axis=-1)
+    picked = jnp.take_along_axis(
+        logp, jnp.where(mask, targets, 0)[..., None], axis=-1)[..., 0]
+    return jnp.sum(jnp.where(mask, -picked, 0.0))
+
+
+def mlm_block_size(seq_len: int) -> int:
+    """Positions of a row in one block of the loss's walk: an eighth of
+    the row, rounded up to the TPU's sublane tile."""
+    return 8 * -(-seq_len // (8 * _MLM_BLOCKS_PER_ROW))
+
+
+def _compact(x, mlm_targets, block):
+    """Each row's positions that carry a target moved to its front (the
+    batch axis stays leading: a batch sharded over a data mesh exchanges
+    nothing), padded to whole blocks of ``block`` positions: hidden
+    states, targets, the order taken, and how many leading blocks hold a
+    target in some row."""
+    with jax.named_scope(MLM_HEAD_SCOPE):
+        pad = -x.shape[1] % block
+        order = jnp.argsort(mlm_targets == IGNORE_ID, axis=1, stable=True)
+        xs = jnp.take_along_axis(x, order[..., None], axis=1)
+        ts = jnp.take_along_axis(mlm_targets, order, axis=1)
+        xs = jnp.pad(xs, ((0, 0), (0, pad), (0, 0)))
+        ts = jnp.pad(ts, ((0, 0), (0, pad)), constant_values=IGNORE_ID)
+        fullest = jnp.max(jnp.sum(ts != IGNORE_ID, axis=1))
+        return xs, ts, order, (fullest + block - 1) // block
+
+
+def _block_of(a, k, block):
+    return jax.lax.dynamic_slice_in_dim(a, k * block, block, axis=1)
+
+
+@jax.custom_vjp
+def _masked_nll(x, token_emb, mlm_bias, mlm_targets):
+    """Summed cross-entropy over the positions of ``x`` (B, S, h) whose
+    ``mlm_targets`` (B, S) are not ``IGNORE_ID``: what
+    ``_block_nll`` gives over all of them at once, walked a block of each
+    row at a time and only as far as the fullest row's targets reach.
+    The walk stops on the device, from the mask alone, so every mask gets
+    the dense head's loss and gradients up to summation order: none
+    projects nothing, all masked walks every block. The backward makes a
+    block's logits again, so nothing (positions, vocab) outlives a block.
+    """
+    return _masked_nll_fwd(x, token_emb, mlm_bias, mlm_targets)[0]
+
+
+# The forward and the backward are jitted for their names' sake: a scope
+# entered straight under a transformation reaches the compiled step as
+# ``jvp(rsdl.bert.mlm_head)``, which no reader of a trace looks for; inside
+# a program of its own (as inside a loop's body) it stays as written.
+@jax.jit
+def _masked_nll_fwd(x, token_emb, mlm_bias, mlm_targets):
+    block = mlm_block_size(x.shape[1])
+    xs, ts, order, blocks = _compact(x, mlm_targets, block)
+    with jax.named_scope(MLM_HEAD_SCOPE):
+        emb = token_emb.astype(x.dtype)
+
+    def add_block(k, total):
+        with jax.named_scope(MLM_HEAD_SCOPE):
+            return total + _block_nll(_block_of(xs, k, block), emb,
+                                      mlm_bias, _block_of(ts, k, block))
+
+    total = jax.lax.fori_loop(0, blocks, add_block, jnp.float32(0))
+    return total, (xs, ts, order, blocks, emb, mlm_bias)
+
+
+@jax.jit
+def _masked_nll_bwd(residuals, cotangent):
+    xs, ts, order, blocks, emb, mlm_bias = residuals
+    block = mlm_block_size(order.shape[1])
+
+    def add_block(k, grads):
+        d_xs, d_emb, d_bias = grads
+        with jax.named_scope(MLM_HEAD_SCOPE):
+            targets = _block_of(ts, k, block)
+            _, vjp = jax.vjp(
+                lambda x, e, b: _block_nll(x, e, b, targets),
+                _block_of(xs, k, block), emb, mlm_bias)
+            dx, de, db = vjp(cotangent)
+            return (jax.lax.dynamic_update_slice_in_dim(
+                        d_xs, dx, k * block, axis=1),
+                    d_emb + de.astype(jnp.float32), d_bias + db)
+
+    with jax.named_scope(MLM_HEAD_SCOPE):
+        zeros = (jnp.zeros_like(xs), jnp.zeros(emb.shape, jnp.float32),
+                 jnp.zeros_like(mlm_bias))
+    d_xs, d_emb, d_bias = jax.lax.fori_loop(0, blocks, add_block, zeros)
+    with jax.named_scope(MLM_HEAD_SCOPE):
+        back = jnp.argsort(order, axis=1)
+        d_x = jnp.take_along_axis(d_xs[:, :order.shape[1]],
+                                  back[..., None], axis=1)
+    return d_x, d_emb, d_bias, None
+
+
+_masked_nll.defvjp(_masked_nll_fwd, _masked_nll_bwd)
 
 
 def loss_fn(config: BertConfig, params: Dict[str, Any],
             token_ids: jax.Array, mlm_targets: jax.Array,
             attention_mask: jax.Array = None,
             attention_fn=None) -> jax.Array:
-    """Masked-LM cross-entropy over positions where targets != IGNORE_ID."""
-    logits = apply(config, params, token_ids, attention_mask, attention_fn)
-    mask = (mlm_targets != IGNORE_ID)
-    safe_targets = jnp.where(mask, mlm_targets, 0).astype(jnp.int32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    token_logp = jnp.take_along_axis(
-        logp, safe_targets[..., None], axis=-1)[..., 0]
-    total = jnp.sum(jnp.where(mask, -token_logp, 0.0))
-    count = jnp.maximum(jnp.sum(mask), 1)
+    """Masked-LM cross-entropy over positions where targets != IGNORE_ID.
+    Only those positions are projected onto the vocabulary
+    (:func:`_masked_nll`)."""
+    seq_len = token_ids.shape[1]
+    block = mlm_block_size(seq_len)
+    # Counted when a step is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_mlm_head_total",
+        "Masked-LM losses traced, by how the head walks the vocabulary "
+        "projection", kind="blocked").inc()
+    rt_metrics.gauge(
+        "rsdl_mlm_head_block_positions",
+        "Positions of a row in one block of the masked-LM head's walk, "
+        "last loss traced").set(block)
+    rt_metrics.gauge(
+        "rsdl_mlm_head_blocks_per_row",
+        "Blocks the masked-LM head's walk takes over a fully masked row, "
+        "last loss traced").set(-(-seq_len // block))
+    x = encode(config, params, token_ids, attention_mask, attention_fn)
+    total = _masked_nll(x, params["token_emb"], params["mlm_bias"],
+                        mlm_targets.astype(jnp.int32))
+    count = jnp.maximum(jnp.sum(mlm_targets != IGNORE_ID), 1)
     return total / count

@@ -35,6 +35,11 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- embedding lookup under a mesh (ops/embedding.py; counted when a
     #    step is traced, not when it runs; kind = rows | dense) --
     "rsdl_embedding_grad_exchange_total": ("counter", ("kind",)),
+    # -- masked-LM head (models/bert.py; set when a loss is traced, not
+    #    when it runs; kind = blocked) --
+    "rsdl_mlm_head_total": ("counter", ("kind",)),
+    "rsdl_mlm_head_block_positions": ("gauge", ()),
+    "rsdl_mlm_head_blocks_per_row": ("gauge", ()),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

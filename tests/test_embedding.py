@@ -348,3 +348,32 @@ def test_mesh_lookup_gradient_compiles_for_four_chips(four_chips,
     for op in ("all-reduce", "all-gather", "reduce-scatter"):
         assert _collectives_holding(hlo, op, vocab, embed) == 0
     assert _collectives_holding(hlo, "all-gather", batch, embed) == 1
+
+
+def test_bert_mlm_head_gradient_compiles_for_the_chip_in_blocks(four_chips):
+    """``bert-base-mlm``'s head at the cell's batch through the TPU's own
+    compiler (kept in this file: one process may load that compiler): the
+    loss's gradient holds logits of one block of 64 positions a row and
+    nothing of ``[32,512,30522]``."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import bert
+    cfg = bert.bert_base()
+    batch, seq = 32, cfg.max_seq_len
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = jax.jit(jax.grad(bert._masked_nll, argnums=(0, 1, 2))).lower(
+        on_chip((batch, seq, cfg.hidden_dim), cfg.compute_dtype),
+        on_chip((cfg.vocab_size, cfg.hidden_dim), jnp.float32),
+        on_chip((cfg.vocab_size,), jnp.float32),
+        on_chip((batch, seq), jnp.int32)).compile().as_text()
+    block = bert.mlm_block_size(seq)
+    assert block == 64
+    assert re.search(rf"f32\[{batch},{block},{cfg.vocab_size}\]", hlo)
+    assert not re.search(rf"\[{batch},{seq},{cfg.vocab_size}\]", hlo)
+    assert not re.search(rf"\[{batch * seq},{cfg.vocab_size}\]", hlo)

@@ -1,5 +1,10 @@
 """Tests for the ResNet and BERT model families (models/resnet.py, bert.py)."""
 
+import dataclasses
+import json
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -11,6 +16,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_shuffling_data_loader_tpu.models import bert, resnet
 from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
 from ray_shuffling_data_loader_tpu.parallel.trainer import SpmdTrainer
+from ray_shuffling_data_loader_tpu.runtime import metrics
+from ray_shuffling_data_loader_tpu.workloads import bert_mlm
 
 
 def test_resnet_forward_shape():
@@ -105,6 +112,173 @@ def test_bert_mlm_loss_ignores_unmasked():
     loss0 = bert.loss_fn(cfg, params, tokens,
                          jnp.full((2, 8), bert.IGNORE_ID, jnp.int32))
     assert float(loss0) == 0.0
+
+
+_MLM_BATCH, _MLM_SEQ = 8, 64
+
+
+def _dense_mlm_loss(cfg, params, tokens, targets):
+    """The head as it was before the blocked walk: every position
+    projected, one float32 log-softmax over the whole, then the mask."""
+    logits = bert.apply(cfg, params, tokens)
+    mask = targets != bert.IGNORE_ID
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    picked = jnp.take_along_axis(
+        logp, jnp.where(mask, targets, 0)[..., None], axis=-1)[..., 0]
+    return jnp.sum(jnp.where(mask, -picked, 0.0)) / jnp.maximum(
+        jnp.sum(mask), 1)
+
+
+@pytest.fixture(scope="module")
+def mlm_f32():
+    """``bert_tiny`` in float32 with a bias that is not zero, and the
+    value-and-gradient of the model's loss and of the dense reference,
+    each compiled once for every mask."""
+    cfg = dataclasses.replace(bert.bert_tiny(), compute_dtype=jnp.float32)
+    params = bert.init(cfg, jax.random.key(0))
+    params["mlm_bias"] = 0.1 * jax.random.normal(
+        jax.random.key(5), params["mlm_bias"].shape)
+    tokens = jax.random.randint(
+        jax.random.key(1), (_MLM_BATCH, _MLM_SEQ),
+        bert_mlm.NUM_SPECIAL_TOKENS, cfg.vocab_size)
+    got = jax.jit(jax.value_and_grad(
+        lambda p, t, y: bert.loss_fn(cfg, p, t, y)))
+    want = jax.jit(jax.value_and_grad(
+        lambda p, t, y: _dense_mlm_loss(cfg, p, t, y)))
+    return cfg, params, tokens, got, want
+
+
+def _mlm_targets(case, cfg, tokens):
+    none = jnp.full(tokens.shape, bert.IGNORE_ID, jnp.int32)
+    block = bert.mlm_block_size(_MLM_SEQ)
+    if case == "none":
+        return none
+    if case == "one_position":
+        return none.at[3, 17].set(tokens[3, 17])
+    if case == "draw_15_pct":
+        return bert_mlm.mlm_mask(tokens, jax.random.key(2),
+                                 cfg.vocab_size)[1]
+    if case == "row_at_block_edge":
+        # one row fills the first block exactly, another spills one over
+        return (none.at[2, 5:5 + block].set(tokens[2, 5:5 + block])
+                .at[6, 40:41 + block].set(tokens[6, 40:41 + block]))
+    if case == "one_row_full":
+        return none.at[4].set(tokens[4])
+    assert case == "all"
+    return tokens
+
+
+@pytest.mark.parametrize("case", ["none", "one_position", "draw_15_pct",
+                                  "row_at_block_edge", "one_row_full",
+                                  "all"])
+def test_bert_mlm_loss_matches_the_dense_head_for_every_mask(mlm_f32, case):
+    """The blocked walk over the masked positions gives the loss and every
+    leaf's gradient that the dense head gives, whatever the mask."""
+    cfg, params, tokens, got, want = mlm_f32
+    assert 1 < bert.mlm_block_size(_MLM_SEQ) < _MLM_SEQ
+    targets = _mlm_targets(case, cfg, tokens)
+    got_loss, got_grads = got(params, tokens, targets)
+    want_loss, want_grads = want(params, tokens, targets)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(got_grads):
+        np.testing.assert_allclose(
+            np.asarray(leaf), np.asarray(flat_want[path]), rtol=1e-4,
+            atol=1e-6, err_msg=jax.tree_util.keystr(path))
+    if case == "none":
+        assert float(got_loss) == 0.0
+
+
+def test_bert_mlm_loss_pads_a_row_to_whole_blocks(mlm_f32):
+    """A sequence length that is no whole number of blocks (20 = 8 + 8 +
+    4): the walk's last block is padded with ignored positions."""
+    cfg, params, tokens, _, _ = mlm_f32
+    tokens = tokens[:4, :20]
+    assert tokens.shape[1] % bert.mlm_block_size(tokens.shape[1])
+    targets = jnp.where(tokens % 2 == 0, tokens, bert.IGNORE_ID)
+    got_loss, got_grads = jax.value_and_grad(
+        lambda p: bert.loss_fn(cfg, p, tokens, targets))(params)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: _dense_mlm_loss(cfg, p, tokens, targets))(params)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    for got, want in zip(jax.tree.leaves(got_grads),
+                         jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-6)
+
+
+def test_bert_mlm_compiled_gradient_holds_no_full_logits(mlm_f32):
+    """The compiled gradient keeps nothing of (batch, seq, vocab), runs
+    the head under the scope the device trace's reader looks for, and the
+    trace counted the walk it compiled."""
+    cfg, params, tokens, _, _ = mlm_f32
+    traced = metrics.get("rsdl_mlm_head_total", {"kind": "blocked"})
+    before = 0 if traced is None else traced.value
+    text = jax.jit(jax.grad(
+        lambda p, t, y: bert.loss_fn(cfg, p, t, y))).lower(
+            params, tokens, tokens).compile().as_text()
+    block = bert.mlm_block_size(_MLM_SEQ)
+    vocab = cfg.vocab_size
+    assert re.search(rf"\[{_MLM_BATCH},{block},{vocab}\]", text)
+    assert not re.search(rf"\[{_MLM_BATCH},{_MLM_SEQ},{vocab}\]", text)
+    assert not re.search(rf"\[{_MLM_BATCH * _MLM_SEQ},{vocab}\]", text)
+    # The scope names what runs inside the walk's loops and not the loops:
+    # a reader that sums operations under it counts each once.
+    from chipbench import xplane
+    under = {name for name, op_name in xplane.hlo_op_names(text).items()
+             if xplane.under_scope(op_name, bert.MLM_HEAD_SCOPE)}
+    assert any(name.startswith(("dot", "fusion")) for name in under), under
+    assert not any(name.startswith("while") for name in under), under
+    assert metrics.get("rsdl_mlm_head_total",
+                       {"kind": "blocked"}).value == before + 1
+    assert metrics.get("rsdl_mlm_head_block_positions").value == block
+    assert metrics.get("rsdl_mlm_head_blocks_per_row").value == (
+        _MLM_SEQ // block)
+
+
+def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
+    """Through ``SpmdTrainer`` on four devices with the batch sharded, the
+    first loss is the one-device loss: the compaction is per row."""
+    cfg, params, tokens, got, _ = mlm_f32
+    targets = _mlm_targets("draw_15_pct", cfg, tokens)
+    want_loss, _ = got(params, tokens, targets)
+    mesh = mesh_mod.make_mesh(num_devices=4)
+    trainer = SpmdTrainer(
+        mesh, lambda p, t, y: bert.loss_fn(cfg, p, t, y), params,
+        optax.adam(1e-3))
+    sharded = mesh_mod.batch_sharding(mesh)
+    loss = trainer.train_step(jax.device_put(tokens, sharded),
+                              jax.device_put(targets, sharded))
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+
+
+def test_the_benchmarks_mlm_head_pct_reads_the_models_scope():
+    """``mlm_head_pct`` is data: the scope reader ``grad_exchange_pct``
+    uses, pointed at the scope the model names, in ``bert_train`` alone;
+    a program without the scope (the parent's side) gives it nothing."""
+    from chipbench import manifest
+    bench = manifest.load_manifest()
+    entry = next(m for m in bench["per_layer"] if m["name"] == "mlm_head_pct")
+    assert entry == bench["per_layer"][-1]
+    assert entry == {"name": "mlm_head_pct", "unit": "%", "better": "lower",
+                     "source": "device_trace", "layer": "model",
+                     "moves": "train_rows_per_s", "workloads": ["bert_train"]}
+    with open(os.path.join(manifest.BENCH_DIR, "layers",
+                           "mlm_head_pct.json")) as f:
+        layer = json.load(f)
+    with open(os.path.join(manifest.BENCH_DIR, "layers",
+                           "grad_exchange_pct.json")) as f:
+        exchange = json.load(f)
+    assert layer["args"].pop("scope") == bert.MLM_HEAD_SCOPE
+    assert exchange["args"].pop("scope") != bert.MLM_HEAD_SCOPE
+    assert layer == exchange
+    for cell in bench["workloads"]:
+        reported = {m["name"]
+                    for m in manifest.resolve_cell(cell["name"]).per_layer}
+        assert ("mlm_head_pct" in reported) == (cell["name"] == "bert_train")
+    reader = manifest.layer_reader("mlm_head_pct")
+    assert reader({"trace": None}) is None
+    assert reader({"trace": object(), "step_op_names": {}}) is None
 
 
 def test_bert_specs_match_tree():
