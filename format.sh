@@ -8,7 +8,7 @@ cd "$(dirname "$0")"
 PY_DIRS=(ray_shuffling_data_loader_tpu tests benchmarks examples)
 
 echo "-- compile check"
-python -m compileall -q "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py setup.py
+python -m compileall -q "${PY_DIRS[@]}" chip_smoke.py __graft_entry__.py setup.py
 
 if python -c 'import yapf' 2>/dev/null; then
     echo "-- yapf (diff mode)"
@@ -37,11 +37,11 @@ if python -c 'import ray_shuffling_data_loader_tpu.analysis' 2>/dev/null; then
         echo "-- rsdl-lint (concurrency + locksan cross-check)"
         python -m ray_shuffling_data_loader_tpu.analysis --concurrency \
             --locksan-graph .rsdl-locksan-graph.json \
-            "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py tools
+            "${PY_DIRS[@]}" chip_smoke.py __graft_entry__.py tools
     else
         echo "-- rsdl-lint (concurrency)"
         python -m ray_shuffling_data_loader_tpu.analysis --concurrency \
-            "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py tools
+            "${PY_DIRS[@]}" chip_smoke.py __graft_entry__.py tools
     fi
 else
     echo "-- rsdl-lint deps not importable, skipping"
@@ -60,7 +60,7 @@ if [ "${RSDL_LOCKSAN_SUITE:-0}" = "1" ]; then
         -p no:cacheprovider >/dev/null
     python -m ray_shuffling_data_loader_tpu.analysis --concurrency \
         --locksan-graph .rsdl-locksan-graph.json \
-        "${PY_DIRS[@]}" bench.py chip_smoke.py __graft_entry__.py tools
+        "${PY_DIRS[@]}" chip_smoke.py __graft_entry__.py tools
 fi
 
 # Epoch-plan IR self-test (tools/rsdl_plan.py, stdlib-only): builds a
@@ -70,20 +70,6 @@ fi
 echo "-- rsdl-plan (check mode)"
 python tools/rsdl_plan.py --check >/dev/null
 
-# Stage microbenchmarks (tools/rsdl_microbench.py): per-kernel numbers
-# (parquet decode, partition plan, fused gather, shm IPC handoff) in
-# informational mode, so a kernel-level regression surfaces before the
-# next full bench round. Always rc 0; the hard gate stays bench.py
-# --baseline. RSDL_MICROBENCH=0 skips it (costs a few seconds).
-if [ "${RSDL_MICROBENCH:-1}" != "0" ]; then
-    if python -c 'import pyarrow, numpy' 2>/dev/null; then
-        echo "-- rsdl-microbench (check mode)"
-        python tools/rsdl_microbench.py --check >/dev/null
-    else
-        echo "-- rsdl-microbench deps not importable, skipping"
-    fi
-fi
-
 # Delivery-latency sketch self-test (tools/rsdl_top.py, stdlib-only):
 # observes disjoint values in two registries, merges them through the
 # shard-federation path, and requires the merged quantiles to equal a
@@ -92,31 +78,5 @@ fi
 # silently-wrong p99 on a dashboard.
 echo "-- rsdl-top (check-latency mode)"
 python tools/rsdl_top.py --check-latency >/dev/null
-
-# Bench regression check (tools/rsdl_bench_diff.py, stdlib-only): when
-# committed bench records are present, compare the two newest and print
-# the per-metric verdict. Check mode is informational (rc 0) — the hard
-# gates are `bench.py --baseline <record>` at measurement time and the
-# two-file CLI form in CI.
-if ls BENCH_r*.json >/dev/null 2>&1; then
-    echo "-- rsdl-bench-diff (check mode)"
-    python tools/rsdl_bench_diff.py --check .
-fi
-
-# Regression-forensics self-test (tools/rsdl_regress.py, stdlib-only):
-# synthesizes a two-round pair with a planted suspect (one stage 3x
-# slower, its latency histogram shifted, one env knob appeared) and
-# requires the differential engine to rank the plant #1 — alignment,
-# bucket-overlap significance, or suspect-scoring drift fails here,
-# not in a forensic report that quietly blames the wrong stage.
-echo "-- rsdl-regress (check mode)"
-python tools/rsdl_regress.py --check >/dev/null
-
-# Run-report schema smoke (tools/rsdl_report.py, stdlib-only): validates
-# that the committed bench records (and any history/capsule artifacts
-# handed to it) still parse against the report's schema without writing
-# HTML. Informational (rc 0), same contract as the checks above.
-echo "-- rsdl-report (check mode)"
-python tools/rsdl_report.py --check
 
 echo "OK"

@@ -111,7 +111,7 @@ class Config:
     # come from runtime/metric_names.py (library code; tests may mint
     # throwaway test_* names, which the rule ignores by prefix anyway).
     metric_catalog_globs: Tuple[str, ...] = (
-        "ray_shuffling_data_loader_tpu/*", "bench.py")
+        "ray_shuffling_data_loader_tpu/*",)
     # fnmatch patterns of library files where fresh (seed, epoch, task)
     # key-derivation arithmetic is a lineage-outside-plan violation —
     # resume/recovery must query plan/ir.py, not re-derive keys.
@@ -127,7 +127,7 @@ class Config:
     # through storage/ (the tiered cache + chaos-site boundary), never
     # raw pyarrow.parquet reads.
     dataset_read_globs: Tuple[str, ...] = (
-        "ray_shuffling_data_loader_tpu/*", "bench.py")
+        "ray_shuffling_data_loader_tpu/*",)
     # Files exempt from raw-dataset-read: the storage plane itself and
     # the low-level fileio primitive it is built on.
     dataset_read_exempt_globs: Tuple[str, ...] = (
@@ -200,10 +200,6 @@ class Config:
     tenancy_entry_names: Tuple[str, ...] = (
         "serve_queue", "serve_pipeline", "server_config", "register",
         "make_prefetcher")
-    # fnmatch patterns of files that assemble the bench JSON record —
-    # their numeric emissions must be gated by a rsdl_bench_diff rule
-    # or declared informational (rules_bench.py).
-    bench_record_globs: Tuple[str, ...] = ("bench.py", "*/bench.py")
 
     @classmethod
     def from_dict(cls, data: dict) -> "Config":
@@ -249,9 +245,9 @@ def register(cls):
 def all_rules() -> Dict[str, Rule]:
     """The registry, with the built-in rule modules imported."""
     from ray_shuffling_data_loader_tpu.analysis import (  # noqa: F401
-        rules_arrow, rules_bench, rules_executor, rules_hygiene, rules_jax,
-        rules_lock, rules_metrics, rules_perf, rules_plan, rules_runtime,
-        rules_storage, rules_telemetry, rules_tenancy)
+        rules_arrow, rules_executor, rules_hygiene, rules_jax, rules_lock,
+        rules_metrics, rules_perf, rules_plan, rules_runtime, rules_storage,
+        rules_telemetry, rules_tenancy)
     return dict(_REGISTRY)
 
 

@@ -6,8 +6,7 @@ straight from source buffers, and the process backend hands whole tables
 across processes as shared-memory segments. One careless conversion in a
 hot path silently re-materializes the very bytes the design avoids
 copying — and the regression shows up only as a throughput drift nobody
-can attribute (the r03 -> r05 ingest regression was exactly such a
-drift).
+can attribute.
 
 ``copy-in-hot-path`` pins the discipline in the three hot-path modules
 (``shuffle.py``, ``dataset.py``, ``jax_dataset.py``):

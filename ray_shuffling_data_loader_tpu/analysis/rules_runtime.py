@@ -26,7 +26,7 @@ be created with a timeout (``create_connection(addr, timeout=...)``)
 or have ``settimeout`` called on it; ``settimeout(None)`` counts as
 configured — an *explicit* infinite wait is a reviewed decision, the
 silent default is the bug (PR-5 satellite: queue timeouts now resolve
-through ``RSDL_QUEUE_TIMEOUT``).
+through ``RSDL_QUEUE_TIMEOUT_S``).
 """
 
 from __future__ import annotations
@@ -207,7 +207,7 @@ class SocketOpNoTimeoutRule(Rule):
     description = ("blocking socket `recv`/`accept`/`connect` on a socket "
                    "with no timeout configured — waits forever on a wedged "
                    "peer, past the watchdog and the lease sweeper; call "
-                   "`settimeout` (policy key RSDL_QUEUE_TIMEOUT for the "
+                   "`settimeout` (policy key RSDL_QUEUE_TIMEOUT_S for the "
                    "queue plane) or create with `timeout=`")
 
     def check(self, tree: ast.Module,
@@ -250,6 +250,6 @@ class SocketOpNoTimeoutRule(Rule):
                     f"configured waits forever on a wedged peer (past the "
                     f"watchdog and the lease sweeper); call "
                     f"`{base}.settimeout(...)` — policy-resolved, e.g. "
-                    f"RSDL_QUEUE_TIMEOUT — or construct it with "
+                    f"RSDL_QUEUE_TIMEOUT_S — or construct it with "
                     f"`timeout=`; `settimeout(None)` is accepted as an "
                     f"explicit, reviewed infinite wait")

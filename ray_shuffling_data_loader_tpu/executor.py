@@ -113,11 +113,11 @@ def wait(refs: Sequence[TaskRef],
     return done_refs, not_done
 
 
-# Last shuffle-worker pool created in this process (bench honesty
-# fields): the bench record must report the EFFECTIVE data-plane width
-# and backend, not os.cpu_count() — a 1-wide pool on a 96-core host must
-# not claim 96-way normalization (ISSUE 7 satellite). Only real worker
-# pools register; single-thread driver/utility pools do not.
+# Last shuffle-worker pool created in this process: a benchmark record
+# reports the EFFECTIVE data-plane width and backend, not os.cpu_count()
+# — a 1-wide pool on a 96-core host must not claim 96-way normalization.
+# Only real worker pools register; single-thread driver/utility pools do
+# not.
 _pool_info_lock = threading.Lock()
 _last_pool_info = {"backend": None, "workers": None, "pids": []}
 
@@ -130,8 +130,8 @@ def note_worker_pool(backend: str, workers: int, pids: Sequence[int]) -> None:
 
 
 def last_worker_pool() -> dict:
-    """``{backend, workers, pids}`` of the most recent worker pool (the
-    bench record's executor_* fields); ``backend`` None if none yet."""
+    """``{backend, workers, pids}`` of the most recent worker pool;
+    ``backend`` None if none yet."""
     with _pool_info_lock:
         return dict(_last_pool_info)
 
@@ -199,7 +199,7 @@ class Executor:
             note_worker_pool("thread", num_workers, [os.getpid()])
 
     #: Data-plane discriminator (procpool.ProcessPoolExecutor says
-    #: "process"); shuffle_epoch and the bench record key off it.
+    #: "process"); shuffle_epoch keys off it.
     backend = "thread"
 
     @property
@@ -208,7 +208,7 @@ class Executor:
 
     def worker_pids(self) -> List[int]:
         """PIDs actually executing tasks — for the thread backend that is
-        this process alone (the bench record's honesty fields)."""
+        this process alone."""
         return [os.getpid()]
 
     def submit(self, fn: Callable, *args, **kwargs) -> TaskRef:

@@ -1083,9 +1083,8 @@ class JaxShufflingDataset:
 
         # Runtime health/degradation policy (runtime/policy.py): explicit
         # runtime_policy kwargs > RSDL_JAX_DATASET_* env > RSDL_* env >
-        # library defaults. RSDL_DEVICE_REBATCH=0 — the old bench-only
-        # mitigation, promoted — makes the per-batch path the library
-        # default for every "auto" construction.
+        # library defaults. RSDL_DEVICE_REBATCH=0 makes the per-batch
+        # path the library default for every "auto" construction.
         from ray_shuffling_data_loader_tpu.runtime import (policy as
                                                            rt_policy)
         self._runtime_policy = rt_policy.resolve_all(

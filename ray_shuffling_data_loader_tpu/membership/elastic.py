@@ -23,8 +23,8 @@ a join never causes replay.
 
 The ``member_crash`` chaos site fires here, through
 ``MembershipManager.maybe_crash``, at the moment a rank's worker picks
-up its next reducer — the mid-epoch kill the dryrun and bench elastic
-legs drive.
+up its next reducer — the mid-epoch kill the dryrun's elastic scene
+drives.
 """
 
 from __future__ import annotations
@@ -72,8 +72,7 @@ class ElasticShuffleRunner:
         self.map_transform = map_transform
         self.reduce_transform = reduce_transform
         self.on_bad_file = on_bad_file
-        #: Stats of the most recent :meth:`run_epoch` — the bench
-        #: elastic leg's raw numbers.
+        #: Stats of the most recent :meth:`run_epoch`.
         self.last_stats: Dict[str, float] = {}
 
     # -- one epoch -----------------------------------------------------
@@ -213,8 +212,8 @@ def trainer_streams(reducer_outputs: Sequence, num_trainers: int) -> List:
 
 
 def total_rows(reducer_outputs: Sequence) -> int:
-    """Summed row count over reducer outputs (the bench elastic leg's
-    ``rows_lost`` check compares this against the fixed-world run)."""
+    """Summed row count over reducer outputs (a ``rows_lost`` check
+    compares this against the fixed-world run)."""
     return sum(t.num_rows for t in reducer_outputs)
 
 

@@ -84,8 +84,8 @@ def mlperf_config() -> DLRMConfig:
     """MLPerf-DLRM-v2-like widths on the reference's 19-column schema:
     full ``DATA_SPEC_VOCAB_SIZES``, embed 128, top MLP 1024-1024-512-256.
     The tables alone are 2,912,607 x 128 float32 = 1.49 GB, so dense Adam
-    holds 4.5 GB of state — the configuration bench.py's train phase and
-    chip_smoke.py run at full width on one chip."""
+    holds 4.5 GB of state — the configuration chip_smoke.py and the
+    benchmark's ``dlrm-mlperf`` run at full width on one chip."""
     return DLRMConfig(embed_dim=128, top_hidden=(1024, 1024, 512, 256))
 
 

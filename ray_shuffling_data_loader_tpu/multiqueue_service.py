@@ -819,9 +819,12 @@ class QueueServer:
             conn.settimeout(self._timeout_s or None)
             thread = threading.Thread(target=self._serve_conn, args=(conn,),
                                       daemon=True, name="rsdl-qserve-conn")
+            # Registered and started under one lock: close() joins what
+            # it finds registered, and a thread not yet started cannot be
+            # joined.
             with self._conn_lock:
                 self._conn_threads.add(thread)
-            thread.start()
+                thread.start()
 
     def _state(self, queue_idx: int) -> _QueueState:
         with self._states_lock:

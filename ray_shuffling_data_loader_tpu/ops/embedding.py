@@ -24,10 +24,9 @@ non-matmul op. Three strategies, dispatched by :func:`lookup`:
   (:func:`pallas_lookup`).
 
 ``auto`` picks ``one_hot`` for vocab <= ONE_HOT_MAX_VOCAB; above it, the
-Pallas gather on a real TPU when the embed dim is 128-lane aligned
-(kernel-isolated on-chip measurement: ~25-30% faster than XLA gather at
-vocab 1M / embed 128 / batch 64k — benchmarks/bench_embedding.py), else
-XLA ``take``.
+Pallas gather on a real TPU when the embed dim is 128-lane aligned,
+else XLA ``take``. (Whether the gather beats ``take`` on the chip is not
+measured by the benchmark: ROADMAP A7.)
 """
 
 from __future__ import annotations

@@ -209,7 +209,7 @@ def shuffle_epoch_distributed(epoch: int,
     # and mask the original error. Maps MAY retry (duplicate sends are
     # dropped by the receiving transport).
     local_reducers = plan.local_reducers(transport.host_id)
-    # Loopback worlds (tests, bench_distributed, single-machine emulation)
+    # Loopback worlds (tests, single-machine emulation)
     # run every "host" on this one machine — split the cores; a real
     # deployment owns its cores per host. The driver's epoch throttle keeps
     # up to ``concurrent_epochs`` epochs' reducers in flight.

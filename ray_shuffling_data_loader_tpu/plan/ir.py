@@ -298,8 +298,8 @@ class EpochPlan:
 
     def annotate_costs(self, stage_costs: Mapping[str, float]) -> None:
         """Stamp advisory per-stage cost annotations (seconds) onto every
-        node of each stage — the telemetry feedback hook (bench and the
-        scheduler pass stage p50s from ``telemetry.attribution()``)."""
+        node of each stage — the telemetry feedback hook (the
+        scheduler passes stage p50s from ``telemetry.attribution()``)."""
         for node in self.nodes.values():
             cost = stage_costs.get(node.stage)
             if cost is not None:

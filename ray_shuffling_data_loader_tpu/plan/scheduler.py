@@ -82,9 +82,9 @@ Dispatcher = Callable[[ir.PlanNode, int], ex.TaskRef]
 #: median (bounded memory; stragglers are judged against recent peers).
 _MEDIAN_WINDOW = 64
 
-# Process-wide speculation/steal totals (the bench record's
-# ``speculation`` block reads deltas of these; the registry counters
-# carry the same numbers per stage for the exposition/rsdl_top view).
+# Process-wide speculation/steal totals (callers read deltas of these;
+# the registry counters carry the same numbers per stage for the
+# exposition/rsdl_top view).
 _totals_lock = threading.Lock()
 _totals = {"speculative_launched": 0, "speculative_won": 0,
            "speculative_wasted": 0, "steals": 0}

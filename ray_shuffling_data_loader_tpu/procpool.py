@@ -3,8 +3,8 @@
 The thread executor (executor.py) keeps the whole map/reduce hot path in
 ONE Python process — pyarrow and the native kernels release the GIL, but
 everything interpreter-bound (per-task Python bookkeeping, numpy fallback
-arms, Parquet metadata churn) serializes on it, and BENCH_r05 measured the
-stream producer-bound there. This module is the multicore plane behind the
+arms, Parquet metadata churn) serializes on it. This module is the
+multicore plane behind the
 same ``Executor`` contract (``submit`` / ``submit_once`` / ``wait`` /
 ``TaskRef``):
 
@@ -706,6 +706,7 @@ class ProcessPoolExecutor:
         # (segment path, bytes). Charged to the buffer ledger below.
         self._table_segs: Dict[str, "tuple[str, int]"] = {}
         self._table_seg_inflight: set = set()
+        self._table_seg_grants = 0
         self._table_seg_bytes = 0
         self._cache_full = False
         self._ledger_ids: List[int] = []
@@ -819,17 +820,21 @@ class ProcessPoolExecutor:
             entry = self._table_segs.get(filename)
             return entry[0] if entry else None
 
-    def plan_table_seg_write(self, filename: str, file_index: int
-                             ) -> Optional[str]:
+    def plan_table_seg_write(self, filename: str) -> Optional[str]:
         """Decide (driver-authoritative, so concurrent epochs cannot race)
         whether this map task should publish the decoded table as a cache
-        segment; returns the target path or None."""
+        segment; returns the target path or None. The path is numbered by
+        grant, not by the file's index in its epoch: a streaming run's
+        windows hold different files under the same indices, and the
+        cache outlives the epoch."""
         with self._lock:
             if (self._cache_full or filename in self._table_segs
                     or filename in self._table_seg_inflight):
                 return None
             self._table_seg_inflight.add(filename)
-        return self.segment_path(f"table_f{file_index}.arrow")
+            grant = self._table_seg_grants
+            self._table_seg_grants += 1
+        return self.segment_path(f"table_f{grant}.arrow")
 
     def note_table_seg(self, filename: str, path: Optional[str],
                        nbytes: int) -> None:
@@ -1131,7 +1136,7 @@ def process_epoch(plan,
             "table_seg": pool.cached_table_seg(filename),
         }
         if payload["table_seg"] is None:
-            grant = (pool.plan_table_seg_write(filename, file_index)
+            grant = (pool.plan_table_seg_write(filename)
                      if allow_cache_write else None)
             payload["cache_grant"] = grant is not None
             payload["write_table_seg"] = grant or pool.segment_path(

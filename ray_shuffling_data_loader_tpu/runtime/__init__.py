@@ -13,9 +13,8 @@ watchdog's stats hookup):
   ``gc.collect()`` polling cadence in the shuffle's epoch-launch
   budget wait.
 - :mod:`.policy` — the degradation-policy registry (env-var + kwargs
-  resolution) that turns bench-only mitigations like
-  ``RSDL_BENCH_DEVICE_REBATCH=0`` into library defaults
-  (``RSDL_DEVICE_REBATCH=0``) with per-component overrides.
+  resolution) that makes mitigations like ``RSDL_DEVICE_REBATCH=0``
+  library defaults with per-component overrides.
 - :mod:`.retry` — the ONE bounded/jittered :class:`RetryPolicy` every
   retry loop in the pipeline routes through (executor task retries,
   transport redial, remote-queue fetch, lineage recompute).

@@ -230,7 +230,7 @@ def spec_for_node(site: str, node, delay_ms: Optional[int] = None,
     node keeps the chaos key and the task's lineage key equal by
     construction (they join in telemetry by ``(kind, epoch, task)``).
     ``delay_ms`` builds a ``delayN`` straggler rule (the speculation
-    bench leg's injector) instead of a failure rule.
+    tests' injector) instead of a failure rule.
     """
     if site not in SITES:
         raise ValueError(f"unknown chaos site {site!r} "
@@ -308,7 +308,7 @@ _install_lock = threading.Lock()
 
 
 def install(spec: str, seed: int = 0) -> FaultInjector:
-    """Programmatically activate a chaos spec (tests, bench --chaos)."""
+    """Programmatically activate a chaos spec (tests, the dryrun)."""
     global _ACTIVE, _injector
     injector = FaultInjector(parse_spec(spec), seed=seed)
     with _install_lock:

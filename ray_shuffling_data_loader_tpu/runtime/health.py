@@ -1,7 +1,8 @@
 """Declarative SLO/health detectors + auto-captured incident capsules.
 
-Every diagnostic this pipeline had was post-hoc: bench.py prints a
-verdict after the run, rsdl_trace explains an epoch after the dump.
+Every other diagnostic of this pipeline is post-hoc: the run summary
+names a bottleneck after the run, rsdl_trace explains an epoch after the
+dump.
 This module is the *during*: a set of declarative detectors evaluated on
 the history ring (runtime/history.py) at every tick — on the watchdog
 monitor thread, so an armed health plane costs one brief callback per
@@ -820,8 +821,8 @@ class HealthMonitor:
             return sum(s.fires for s in self._states.values())
 
     def summary(self) -> Dict[str, Any]:
-        """Bench-record shape: per-detector episode counts + the last
-        breach evidence of every detector that ever fired."""
+        """Per-detector episode counts + the last breach evidence of
+        every detector that ever fired."""
         with self._lock:
             detectors = {}
             for name, state in self._states.items():
@@ -841,7 +842,7 @@ class HealthMonitor:
 
 
 # ---------------------------------------------------------------------------
-# Arm/disarm: the one-call ops-plane switch (bench, dryrun, drivers)
+# Arm/disarm: the one-call ops-plane switch (dryrun, drivers)
 # ---------------------------------------------------------------------------
 
 _armed_lock = threading.Lock()
@@ -861,8 +862,8 @@ def arm(interval_s: Optional[float] = None,
         **threshold_overrides: Any) -> Optional[HealthMonitor]:
     """Start history ticking and attach a monitor over it (None when the
     ``health`` policy key disarms the plane). Re-arming replaces the
-    previous monitor — per-phase arming (bench.py) gets a fresh ring and
-    fresh hysteresis state each time."""
+    previous monitor — per-phase arming gets a fresh ring and fresh
+    hysteresis state each time."""
     from ray_shuffling_data_loader_tpu.runtime import policy
     if not policy.resolve(component, "health"):
         return None
@@ -949,12 +950,9 @@ def capture_incident(reason: str = "on-demand",
                      base_dir: Optional[str] = None,
                      profile_s: Optional[float] = None,
                      wait_s: Optional[float] = None,
-                     cooldown_s: Optional[float] = None,
-                     stem: Optional[str] = None) -> Optional[str]:
+                     cooldown_s: Optional[float] = None) -> Optional[str]:
     """Write one incident capsule directory; returns its path (None when
-    suppressed by the capture cooldown). ``stem`` overrides the
-    ``rsdl-incident-<pid>-<seq>`` directory name — bench.py names its
-    per-round flight capsules after the record they accompany.
+    suppressed by the capture cooldown).
 
     Layout (rendered by ``tools/rsdl_incident.py``)::
 
@@ -983,9 +981,8 @@ def capture_incident(reason: str = "on-demand",
         _capsule_seq += 1
         seq = _capsule_seq
     detector = (verdict or {}).get("detector")
-    if stem is None:
-        stem = f"rsdl-incident-{os.getpid()}-{seq}" + (
-            f"-{detector}" if detector else "")
+    stem = f"rsdl-incident-{os.getpid()}-{seq}" + (
+        f"-{detector}" if detector else "")
     capsule = os.path.join(_capsule_base_dir(base_dir), stem)
     traces_dir = os.path.join(capsule, "traces")
     os.makedirs(traces_dir, exist_ok=True)

@@ -30,7 +30,7 @@ dynamic edges the static graph lacks are findings, static cycles
 confirmed dynamically are hard failures.
 
 Overhead is one dict update per acquisition under a dedicated real
-lock — fine for tests and chaos soaks, not meant for production runs.
+lock — fine for tests and chaos runs, not meant for production runs.
 Stdlib-only, like everything else in ``runtime/``.
 """
 

@@ -1,8 +1,8 @@
 """Typed metrics registry with Prometheus text-format exposition.
 
 The pipeline's quantitative state used to live in ad-hoc snapshot dicts
-(``stats.watchdog_stats()``, ``stats.fault_stats()``, per-phase bench
-dicts) with no shared naming, no types, and no way to observe a live
+(``stats.watchdog_stats()``, ``stats.fault_stats()``) with no shared
+naming, no types, and no way to observe a live
 run without instrumenting the caller. This module is the ONE registry:
 typed counters / gauges / fixed-bucket histograms behind a
 ``metrics.get(name)`` API, exposable as Prometheus text format to a
@@ -595,8 +595,8 @@ def sketch_quantiles(samples: Dict[str, "Dict[Labels, float]"],
     structural ``c`` label, optionally restricted by ``label_filter``
     equality; returns ``{group_labels: {"p50": s, ..., "count": n}}``
     (quantile keys are ``p<100q>`` in seconds). Tools (rsdl_top, the
-    run report), the health detectors and the bench latency leg all
-    read the plane through this one function.
+    run report) and the health detectors read the plane through this
+    one function.
     """
     grouped: Dict[Labels, Dict[float, int]] = {}
     for labels, value in samples.get(f"{name}_centroid", {}).items():
@@ -618,47 +618,6 @@ def sketch_quantiles(samples: Dict[str, "Dict[Labels, float]"],
             stats[f"p{int(round(q * 100))}"] = _centroid_quantile(
                 counts, total, q)
         out[key] = stats
-    return out
-
-
-def distribution_masses(samples: Dict[str, "Dict[Labels, float]"],
-                        family: str, kind: str
-                        ) -> "Dict[Labels, Dict[float, float]]":
-    """Per-group bucket/centroid mass of one distribution family from
-    PARSED exposition samples: ``{group_labels: {edge: mass}}``.
-
-    For histograms the cumulative ``_bucket`` series is differenced into
-    per-bucket mass (edge = ``le`` upper bound, ``+Inf`` included); for
-    sketches the ``_centroid`` counts are already masses (edge = the
-    centroid value). Group labels drop the structural ``le``/``c``
-    label. This is the one shape the differential engine
-    (``runtime/regress.py``) compares distributions in, so histogram
-    and sketch families diff through identical bucket-overlap math.
-    """
-    struct_label = "le" if kind == "histogram" else "c"
-    series = samples.get(
-        f"{family}_bucket" if kind == "histogram" else f"{family}_centroid",
-        {})
-    grouped: Dict[Labels, Dict[float, float]] = {}
-    for labels, value in series.items():
-        d = dict(labels)
-        edge_txt = d.pop(struct_label, None)
-        if edge_txt is None:
-            continue
-        edge = float("inf") if edge_txt == "+Inf" else float(edge_txt)
-        key = tuple(sorted(d.items()))
-        grouped.setdefault(key, {})[edge] = \
-            grouped.get(key, {}).get(edge, 0.0) + value
-    if kind != "histogram":
-        return grouped
-    out: Dict[Labels, Dict[float, float]] = {}
-    for key, cumulative in grouped.items():
-        masses: Dict[float, float] = {}
-        prev = 0.0
-        for edge in sorted(cumulative):
-            masses[edge] = max(0.0, cumulative[edge] - prev)
-            prev = cumulative[edge]
-        out[key] = masses
     return out
 
 
@@ -918,8 +877,8 @@ def start_exporter(path: Optional[str] = None, port: Optional[int] = None,
 
     With no arguments, resolves ``metrics_file`` / ``metrics_port`` /
     ``metrics_interval_s`` from the runtime policy registry
-    (``RSDL_METRICS_FILE=/run/rsdl.prom python bench.py`` is the
-    zero-code way to watch any run with ``tools/rsdl_top.py``). Returns
+    (``RSDL_METRICS_FILE=/run/rsdl.prom`` on a driver that calls this
+    is the zero-code way to watch a run with ``tools/rsdl_top.py``). Returns
     ``(stop_event, http_port_or_None)``; idempotent — a second call
     stops the previous file-writer loop first.
     """

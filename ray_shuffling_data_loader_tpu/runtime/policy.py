@@ -1,16 +1,14 @@
 """Degradation-policy registry: one resolution surface for runtime knobs.
 
-The pipeline accumulated operational mitigations that lived only in the
-bench harness (``RSDL_BENCH_DEVICE_REBATCH=0`` to force the per-batch
-transfer path, ad-hoc timeouts in module constants). Production traffic
-needs those to be LIBRARY behavior: every runtime knob resolves through
-this module, with one precedence order everywhere::
+Operational mitigations (forcing the per-batch transfer path, deadlines
+that were module constants) are LIBRARY behavior: every runtime knob
+resolves through this module, with one precedence order everywhere::
 
     explicit kwarg > RSDL_<COMPONENT>_<KEY> env > RSDL_<KEY> env
                    > registered component default > library default
 
 Components are short names for the subsystem consulting the policy
-(``jax_dataset``, ``shuffle``, ``spill``, ``bench``). Example: a host
+(``jax_dataset``, ``shuffle``, ``spill``). Example: a host
 whose bulk device transfers stall exports ``RSDL_DEVICE_REBATCH=0`` and
 every loader in every process uses per-batch transfers, while
 ``RSDL_JAX_DATASET_BULK_TRANSFER_DEADLINE_S=5`` tightens only the
@@ -44,9 +42,8 @@ def _parse_tristate(raw: str):
 #: key -> (library default, parser for env-var strings). The parser also
 #: normalizes programmatic overrides where cheap (bools stay bools).
 _KEYS: Dict[str, "tuple[Any, Callable[[str], Any]]"] = {
-    # Bulk device-rebatch mode: "auto" / True / False. A False here turns
-    # the bench-only RSDL_BENCH_DEVICE_REBATCH=0 mitigation into the
-    # library default for every loader in the process.
+    # Bulk device-rebatch mode: "auto" / True / False. A False here makes
+    # the per-batch path the default for every loader in the process.
     "device_rebatch": ("auto", _parse_tristate),
     # Progress watchdog over the bulk transfer/carve path.
     "watchdog": (True, _parse_bool),
@@ -148,13 +145,6 @@ _KEYS: Dict[str, "tuple[Any, Callable[[str], Any]]"] = {
     "incident_dir": ("", str),
     "incident_profile_s": (0.25, float),
     "incident_wait_s": (2.0, float),
-    # Per-round bench flight capsules (bench.py + runtime/regress.py):
-    # after the phases finish (outside every timed window) bench.py
-    # captures an incident-layout capsule beside the record —
-    # RSDL_BENCH_CAPSULE=0 restores pre-capsule bench behavior exactly.
-    # Capture dir "" = the record's directory (cwd).
-    "bench_capsule": (True, _parse_bool),
-    "bench_capsule_dir": ("", str),
     # Cross-process queue service (multiqueue_service.py) socket hygiene:
     # recv timeout applied to BOTH serve_queue connections and
     # RemoteQueue dials (0 = no timeout — a deliberate infinite wait;
@@ -284,8 +274,7 @@ _KEYS: Dict[str, "tuple[Any, Callable[[str], Any]]"] = {
     # Storage plane (storage/): which StorageSource dataset reads resolve
     # to when nothing is installed programmatically — "local" (direct
     # filesystem/fsspec reads, the historical behavior), "sim" (the
-    # hermetic SimulatedObjectStore over local files, for tests and the
-    # 1-CPU bench's remote leg).
+    # hermetic SimulatedObjectStore over local files, for tests).
     "storage_backend": ("local", str),
     # Plan-driven cache warming: when the active file cache exposes a
     # prefetcher, the plan scheduler issues prefetch tasks on idle lanes
@@ -412,5 +401,5 @@ def resolve_all(component: str, **overrides: Any) -> Dict[str, Any]:
 
 
 def describe(component: str = "library") -> Dict[str, Any]:
-    """Resolved snapshot for diagnostics (bench JSON, bug reports)."""
+    """Resolved snapshot for diagnostics (bug reports)."""
     return resolve_all(component)

@@ -3,8 +3,8 @@
 The JAX profiler bridge (utils/tracing.py) answers "what is the DEVICE
 doing"; the flight recorder answers "which stage is slow". What neither
 answers is "which PYTHON FRAMES are burning the host CPU the producer
-bound is made of" (BENCH_r05: ``host_cpus: 1``, stall 97.4%). This
-module is the stdlib answer, always available in production:
+bound is made of". This module is the stdlib answer, always available
+in production:
 
 - **Stack sampling** — a daemon thread walks ``sys._current_frames()``
   on a fixed interval and folds each named thread's stack into
@@ -24,7 +24,7 @@ module is the stdlib answer, always available in production:
 
 Zero overhead when off (no thread is started); overhead when on is one
 frames snapshot per interval. ``maybe_sample()`` is the env-driven
-bench/driver entry: profiling engages when the ``profiler`` policy key
+driver entry: profiling engages when the ``profiler`` policy key
 (``RSDL_PROFILER=1``) or ``RSDL_PROFILE_FOLDED=<path>`` is set, and the
 folded output lands at that path.
 
@@ -198,7 +198,7 @@ class SamplingProfiler:
         return path
 
     def summary(self, top: int = 5) -> Dict[str, Any]:
-        """Compact report for the bench record: sample counts, stage
+        """Compact report: sample counts, stage
         billing, busiest threads by samples and by CPU seconds."""
         folded = self.folded()
         hot = sorted(folded.items(), key=lambda kv: -kv[1])[:top]

@@ -30,8 +30,8 @@ fixed-bucket histograms (mergeable — :mod:`runtime.metrics`), and
 consumer's batch-wait share of wall clock exceeds the policy threshold
 the verdict names the busiest producer stage; otherwise the pipeline
 keeps up and the verdict is ``train_step`` (compute-bound — the goal
-state). The verdict lands in bench JSON, the trial CSV, and a human
-one-liner logged at each epoch's completion.
+state). The verdict lands in the trial CSV and a human one-liner
+logged at each epoch's completion.
 
 Every event also feeds the metrics registry (``rsdl_events_total`` by
 kind, ``rsdl_stage_seconds`` by stage), so the exposition endpoint and
@@ -40,8 +40,8 @@ kind, ``rsdl_stage_seconds`` by stage), so the exposition endpoint and
 Overhead: disabled, ``record()`` is one global load (the
 :mod:`runtime.faults` fast-path pattern). Enabled, it is one
 ``monotonic()`` read, one tuple, and two lock round-trips — measured
-by :func:`measure_record_overhead` and reported by bench.py as
-``telemetry_overhead_pct`` (contract: <= 2% of the ingest path).
+by :func:`measure_record_overhead` (tests/test_telemetry.py holds it
+under a per-event ceiling).
 
 **One span vocabulary** — :func:`span` / :func:`span_begin` /
 :func:`span_end` are the only way the program times a stage. One call
@@ -127,7 +127,7 @@ def annotation_names() -> frozenset:
     return frozenset(SPAN_NAMES.values()) | {STEP_ANNOTATION}
 
 
-#: The decomposition's stage order (CSV columns, bench JSON, rsdl_top).
+#: The decomposition's stage order (CSV columns, rsdl_top).
 STAGES: Tuple[str, ...] = ("map_read", "reduce", "queue_wait", "fetch",
                            "convert", "device_transfer", "train_step")
 
@@ -409,7 +409,7 @@ def _apply_enabled_locked() -> None:
     no-ops: the RSDL_TELEMETRY=0 hard-off fast path. Every caller uses
     module-attribute access (``rt_telemetry.record(...)``), so the swap
     takes effect process-wide; the disabled cost is one no-op call
-    (bench proves it via :func:`measure_disabled_overhead`)."""
+    (:func:`measure_disabled_overhead`)."""
     g = globals()
     if _ENABLED:
         g["record"] = _record_impl
@@ -486,7 +486,7 @@ def enabled() -> bool:
 
 def configure(enabled_flag: Optional[bool] = None,
               capacity: Optional[int] = None) -> None:
-    """Reconfigure in place (tests, bench): a fresh ring / attributor,
+    """Reconfigure in place (tests): a fresh ring / attributor,
     resolving unset arguments from the policy registry."""
     global _ENABLED, _recorder, _attribution
     from ray_shuffling_data_loader_tpu.runtime import policy
@@ -998,7 +998,7 @@ def install_signal_dump(signum: int = signal.SIGUSR1) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Overhead self-measurement (bench.py's telemetry_overhead_pct evidence)
+# Overhead self-measurement (the recorder's own self-test)
 # ---------------------------------------------------------------------------
 
 
@@ -1007,8 +1007,8 @@ def measure_record_overhead(samples: int = 2000) -> float:
     doubles of everything the real path touches — ring, events counter,
     stage histogram, attribution observe — so the number is the full
     per-event cost, not just the ring append (the live recorder is not
-    polluted). Bench multiplies this by the events recorded in its
-    timed window: the self-measured ``telemetry_overhead_pct``."""
+    polluted). Times the events recorded in a window it gives that
+    window's telemetry overhead."""
     probe = FlightRecorder(capacity=256)
     probe_counter = metrics.Counter()
     probe_attr = StageAttribution()
@@ -1027,8 +1027,8 @@ def measure_record_overhead(samples: int = 2000) -> float:
 
 def measure_disabled_overhead(samples: int = 2000) -> float:
     """Seconds per call of the RSDL_TELEMETRY=0 hard-off fast path (the
-    no-op ``record`` the public name rebinds to). Bench reports it as
-    ``telemetry_overhead_off_pct`` — the proof the off switch is ~free."""
+    no-op ``record`` the public name rebinds to): the proof the off
+    switch is ~free."""
     start = time.perf_counter()
     for i in range(samples):
         _noop_record("probe", epoch=0, task=i, dur_s=1e-6)

@@ -440,52 +440,6 @@ def turnovers(events: Sequence[Dict[str, Any]],
     return out
 
 
-def stage_table(analysis: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
-    """Per-stage summary of one :func:`analyze` result, normalized per
-    epoch so two runs with different epoch counts compare directly:
-    ``{stage: {cp_ms, cp_ms_per_epoch, pct, self_ms}}``. The epoch
-    normalization is what lets ``runtime/regress.py`` align stages
-    across rounds by ``(kind, epoch-normalized rank)`` instead of raw
-    wall totals."""
-    n_epochs = max(1, len(analysis.get("epochs") or []))
-    self_ms = analysis.get("self_time_ms", {})
-    table: Dict[str, Dict[str, float]] = {}
-    for entry in analysis.get("critical_path", []):
-        stage = entry["stage"]
-        table[stage] = {
-            "cp_ms": entry["cp_ms"],
-            "cp_ms_per_epoch": round(entry["cp_ms"] / n_epochs, 3),
-            "pct": entry["pct"],
-            "self_ms": self_ms.get(stage, 0.0),
-        }
-    # Stages with self time but no critical-path presence still appear
-    # (cp 0): a stage ENTERING the path between two rounds needs its
-    # baseline row to diff against.
-    for stage, ms in self_ms.items():
-        table.setdefault(stage, {
-            "cp_ms": 0.0, "cp_ms_per_epoch": 0.0, "pct": 0.0,
-            "self_ms": ms,
-        })
-    return table
-
-
-def bench_fields(events: Sequence[Dict[str, Any]],
-                 whatif_speedup: float = 2.0) -> Dict[str, Any]:
-    """The bench-record slice of :func:`analyze`: compact
-    ``critical_path`` / ``self_time_ms`` / ``whatif`` / straggler
-    fields over the recorder's retained window (ring overwrite means
-    *recent* epochs — exactly the steady state a bench wants)."""
-    analysis = analyze(events, whatif_speedup=whatif_speedup)
-    stragglers = [s for s in analysis["stragglers"] if s["cp_ms"] > 0]
-    return {
-        "critical_path": analysis["critical_path"][:8],
-        "self_time_ms": analysis["self_time_ms"],
-        "whatif": analysis["whatif"],
-        "trace_straggler": stragglers[0] if stragglers else None,
-        "trace_epochs_analyzed": len(analysis["epochs"]),
-    }
-
-
 # ---------------------------------------------------------------------------
 # Perfetto / chrome-trace export
 # ---------------------------------------------------------------------------

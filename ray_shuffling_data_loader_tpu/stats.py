@@ -24,8 +24,8 @@ Memory utilization sampling replaces the raylet gRPC store probe
 
 The watchdog/fault recorders below no longer own private integer
 counters: their counts ARE typed counters in the runtime metrics
-registry (runtime/metrics.py), so the Prometheus exposition, the bench
-JSON, and these snapshot dicts read the same cells — ``snapshot()`` is
+registry (runtime/metrics.py), so the Prometheus exposition and these
+snapshot dicts read the same cells — ``snapshot()`` is
 now a *reader* of the registry, kept for its stable dict schema.
 """
 
@@ -351,7 +351,7 @@ class BatchWaitStats:
 
 # ---------------------------------------------------------------------------
 # Watchdog / stall reporting (runtime/watchdog.py files structured reports
-# here; the bench harness and the CSV writers read the process totals)
+# here; the CSV writers read the process totals)
 # ---------------------------------------------------------------------------
 
 
@@ -447,8 +447,8 @@ def watchdog_stats() -> WatchdogStats:
 
 # ---------------------------------------------------------------------------
 # Fault / recovery accounting (runtime/faults.py injects, runtime/retry.py
-# and the shuffle's lineage recovery record; bench.py and the trial CSV
-# read the process totals)
+# and the shuffle's lineage recovery record; the trial CSV reads the
+# process totals)
 # ---------------------------------------------------------------------------
 
 

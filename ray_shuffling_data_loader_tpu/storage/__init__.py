@@ -13,9 +13,9 @@ Every dataset read in the pipeline funnels through this package:
 
 This module owns the PROCESS-WIDE source: :func:`get_source` resolves
 it lazily from the ``RSDL_STORAGE_BACKEND`` policy knob ("local" |
-"sim"), :func:`set_source` installs one programmatically (tests, the
-bench's remote leg — same process-local caveat as programmatic chaos:
-process-backend workers resolve their own from the inherited env).
+"sim"), :func:`set_source` installs one programmatically (tests — same
+process-local caveat as programmatic chaos: process-backend workers
+resolve their own from the inherited env).
 
 :func:`read_table` / :func:`open_parquet` are the routed read calls
 ``shuffle._read_map_table`` and the fused streaming pipeline use; they
@@ -99,7 +99,7 @@ def _inject(epoch: Optional[int], task: Optional[int]) -> None:
         # Surface the measured stall (usually 0; the injected delay when
         # a delayN rule fired) as a plain stage event so a storage_stall
         # fault is JOINABLE by its (kind, epoch, task) key in the
-        # chaos/telemetry correlation (bench.py `fault_events_joinable`)
+        # chaos/telemetry correlation
         # — a raise-shape stall joins through the recovery re-read that
         # lands here with the rule already spent. No entry in
         # trace.STAGE_RANK, so it never enters critical-path

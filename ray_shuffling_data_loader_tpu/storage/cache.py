@@ -1,7 +1,6 @@
 """The explicit cache hierarchy: hot RAM over disk over remote.
 
-BENCH_r07 put the cold path at 2.79M rows/s against 5.03M cached — a
-96.6% stall refetching and re-decoding bytes the host already saw.
+A cold path refetches and re-decodes bytes the host already saw.
 Before this module the repo had three independent caches (the RAM
 ``FileTableCache``, an ad-hoc ``DiskTableCache``, the process
 backend's shm segment arena) that accounted memory independently and
@@ -96,9 +95,7 @@ class DiskTier:
     IPC file to local scratch; every later epoch memory-maps it — no
     decompression, no parse, zero-copy columns whose pages fault in
     lazily and remain reclaimable page cache, so RSS stays bounded no
-    matter how large the corpus is. Measured on the bench host: parquet
-    decode ~184 ns/row vs mmap open ~0; the one-time IPC write costs
-    ~132 ns/row.
+    matter how large the corpus is.
 
     Integrity: ``put`` records the written file's ``native.crc32``;
     ``get`` re-verifies before trusting the mapping (sequential page-in

@@ -20,8 +20,8 @@ failure shape of the real thing:
     first-byte latency, sustained bandwidth, multiplicative jitter and
     a transient error rate, every draw a pure function of
     ``(seed, path, attempt)`` — a fixed seed reproduces the identical
-    stall/error sequence on any host, which is what lets the 1-CPU
-    bench and the tests exercise cold remote ingest hermetically.
+    stall/error sequence on any host, which is what lets the tests
+    exercise cold remote ingest hermetically.
 
 Every fetch funnels through the module-level :func:`read_table` /
 :func:`open_parquet` in ``storage/__init__.py``, which is also where
@@ -231,7 +231,7 @@ class SimulatedObjectStore(StorageSource):
     blip). All draws are pure functions of ``(seed, path, attempt)``
     via sha256 — no RNG state, so a fixed seed reproduces the byte-
     identical timing/error sequence on any host, which is what makes
-    the bench's remote leg and the chaos soak comparable across runs.
+    chaos runs comparable with each other.
 
     Knobs resolve through :mod:`runtime.policy`
     (``RSDL_STORAGE_SIM_FIRST_BYTE_MS`` / ``_MB_PER_S`` /

@@ -22,7 +22,7 @@ Two implementations:
     what the directory says today.
 
 :class:`SyntheticEventSource`
-    A seeded, hermetic arrival process for tests and the 1-CPU bench:
+    A seeded, hermetic arrival process for tests:
     arrival times and event order are pure functions of
     ``(seed, event_index)`` via sha256 — the
     :class:`storage.source.SimulatedObjectStore` contract — so a fixed
@@ -174,7 +174,7 @@ class SyntheticEventSource(StreamSource):
     idiom — no RNG state, bit-reproducible on any host). ``poll(now)``
     releases every not-yet-yielded event whose arrival time is <= ``now``;
     ``poll()`` with no clock releases exactly the next event — the
-    drive-by-count mode tests and the bench use.
+    drive-by-count mode tests use.
 
     ``total_events`` bounds the stream (``exhausted`` turns True after
     the last event); ``None`` streams forever.

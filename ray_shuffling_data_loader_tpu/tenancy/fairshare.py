@@ -200,7 +200,7 @@ def simulate_rounds(fair: FairShare, demands: Dict[str, int],
                     advance: Optional[Callable[[], None]] = None
                     ) -> Dict[str, int]:
     """Deterministic DRR simulation used by the fairness-convergence
-    tests and the bench's sanity path: every round, each tenant with
+    tests: every round, each tenant with
     remaining demand is offered pops while ``grant`` allows; returns
     delivered bytes per tenant. No wall clock involved (callers pass a
     fake clock into ``fair``; ``advance``, if given, steps that clock

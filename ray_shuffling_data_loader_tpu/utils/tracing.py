@@ -43,7 +43,7 @@ def profile_trace(log_dir: str) -> Iterator[None]:
 @contextlib.contextmanager
 def maybe_profile(env_var: str = "RSDL_PROFILE_DIR") -> Iterator[None]:
     """Capture a trace iff the env var names a directory — the zero-code
-    way to profile any run: ``RSDL_PROFILE_DIR=/tmp/tr python bench.py``."""
+    way to profile a run whose driver enters this context."""
     log_dir: Optional[str] = os.environ.get(env_var)
     if not log_dir:
         yield

@@ -49,26 +49,6 @@ def tmp_parquet_dir(tmp_path):
     return str(tmp_path / "parquet")
 
 
-@pytest.fixture
-def bench_env(tmp_path):
-    """Environment factory for a tiny CPU ``bench.py`` subprocess that
-    writes nothing into the checkout: data, the round capsule and the
-    compile cache all land under ``tmp_path``."""
-
-    def make(**knobs):
-        env = dict(os.environ)
-        env.update(RSDL_BENCH_CPU="1", RSDL_BENCH_ROWS="20000",
-                   RSDL_BENCH_FILES="2", RSDL_BENCH_EPOCHS="2",
-                   RSDL_BENCH_BATCH="2048",
-                   RSDL_BENCH_DATA=str(tmp_path / "data"),
-                   RSDL_BENCH_CAPSULE_DIR=str(tmp_path / "capsules"),
-                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
-        env.update(knobs)
-        return env
-
-    return make
-
-
 def pytest_sessionfinish(session, exitstatus):
     if _LOCKSAN is not None and _LOCKSAN.installed():
         out = _LOCKSAN.dump()

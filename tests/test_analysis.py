@@ -21,8 +21,7 @@ from ray_shuffling_data_loader_tpu.analysis import cli, core
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: The trees the format.sh gate runs over.
 GATE_PATHS = ["ray_shuffling_data_loader_tpu", "tests", "benchmarks",
-              "examples", "bench.py", "chip_smoke.py", "__graft_entry__.py",
-              "tools"]
+              "examples", "chip_smoke.py", "__graft_entry__.py", "tools"]
 
 
 def lint(source, path="pkg/mod.py", **config_kwargs):
@@ -649,34 +648,6 @@ TENANT_BYPASS_AMBIENT_OK = """
         return True
 """
 
-UNGATED_BENCH_ASSIGN_BAD = """
-    def main(record, leg):
-        record["surprise_rows_per_hour"] = round(leg.rate * 3600, 1)
-"""
-
-UNGATED_BENCH_UPDATE_BAD = """
-    def main(record, leg):
-        record.update({
-            "surprise_latency_ms": round(leg.wait * 1000, 3),
-        })
-"""
-
-UNGATED_BENCH_OK = """
-    BENCH_INFORMATIONAL_KEYS = frozenset({
-        "debug_probe_count",
-    })
-
-    def main(record, leg):
-        # gated exactly by a DEFAULT_RULES key
-        record["train_rows_per_sec"] = round(leg.rate, 1)
-        # gated as a refinement of the train_rows_per_sec family
-        record["train_rows_per_sec_median"] = round(leg.median, 1)
-        # declared informational in the module's own allowlist
-        record["debug_probe_count"] = round(leg.probes)
-        # non-numeric emissions are out of scope
-        record["backend"] = leg.backend
-"""
-
 CASES = [
     ("lock-mutation", LOCK_MUTATION_BAD, LOCK_MUTATION_OK, {}),
     ("lock-blocking-call", LOCK_BLOCKING_BAD, LOCK_BLOCKING_OK, {}),
@@ -734,10 +705,6 @@ CASES = [
      {"path": "ray_shuffling_data_loader_tpu/storage/remote.py"}),
     ("tenant-context-bypass", TENANT_BYPASS_BAD, TENANT_BYPASS_AMBIENT_OK,
      {"path": "ray_shuffling_data_loader_tpu/multiqueue_service.py"}),
-    ("ungated-bench-metric", UNGATED_BENCH_ASSIGN_BAD, UNGATED_BENCH_OK,
-     {"path": "bench.py"}),
-    ("ungated-bench-metric", UNGATED_BENCH_UPDATE_BAD, UNGATED_BENCH_OK,
-     {"path": "bench.py"}),
 ]
 
 
@@ -817,7 +784,8 @@ def test_unregistered_metric_scoped_to_library_code():
     # mint throwaway metrics); library paths are.
     flagged, _ = lint(UNREGISTERED_METRIC_BAD, path="tests/test_x.py")
     assert "unregistered-metric" not in flagged
-    flagged, _ = lint(UNREGISTERED_METRIC_BAD, path="bench.py")
+    flagged, _ = lint(UNREGISTERED_METRIC_BAD,
+                      path="ray_shuffling_data_loader_tpu/shuffle.py")
     assert "unregistered-metric" in flagged
 
 
@@ -826,10 +794,11 @@ def test_raw_dataset_read_scoped_and_exempt():
     parquet IO; tests and tools read datasets freely."""
     for exempt in ("ray_shuffling_data_loader_tpu/storage/source.py",
                    "ray_shuffling_data_loader_tpu/utils/fileio.py",
-                   "tests/test_x.py", "tools/rsdl_microbench.py"):
+                   "tests/test_x.py", "tools/rsdl_top.py"):
         flagged, _ = lint(RAW_DATASET_READ_BAD, path=exempt)
         assert "raw-dataset-read" not in flagged, exempt
-    flagged, violations = lint(RAW_DATASET_READ_BAD, path="bench.py")
+    flagged, violations = lint(
+        RAW_DATASET_READ_BAD, path="ray_shuffling_data_loader_tpu/shuffle.py")
     assert "raw-dataset-read" in flagged
     # read_table and ParquetFile are each their own finding.
     assert sum(1 for v in violations

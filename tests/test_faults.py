@@ -341,8 +341,13 @@ rows = sum(r.result().num_rows for r in refs)
 print(json.dumps({"rows": rows,
                   "stats": stats.fault_stats().snapshot()}))
 """
+    # A rule's fire-once state is per process: every pool worker would
+    # inherit the spec and fire it again on the recompute it is handed,
+    # so whether recovery exhausts would depend on which worker that is.
+    # One process, one injector: the thread backend.
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               RSDL_CHAOS_SPEC="map_read:file0", RSDL_CHAOS_SEED="0")
+               RSDL_CHAOS_SPEC="map_read:file0", RSDL_CHAOS_SEED="0",
+               RSDL_EXECUTOR_BACKEND="thread")
     proc = subprocess.run([sys.executable, "-c", code] + list(filenames),
                           capture_output=True, text=True, timeout=300,
                           env=env)

@@ -49,9 +49,12 @@ def test_datagen_to_remote_uri():
     assert total == 64
 
 
-def test_shuffle_dataset_end_to_end_over_remote_uri():
+def test_shuffle_dataset_end_to_end_over_remote_uri(monkeypatch):
     """Full pipeline — datagen write, shuffle_map read, cache keyed on the
-    URI — against a remote (memory://) corpus."""
+    URI — against a remote (memory://) corpus. fsspec's ``memory://`` and
+    the ``FileTableCache`` both live in this process, so the map tasks
+    must too: the thread backend, whatever the host's core count picks."""
+    monkeypatch.setenv("RSDL_EXECUTOR_BACKEND", "thread")
     filenames, _ = datagen.generate_data(
         num_rows=128, num_files=2, num_row_groups_per_file=2,
         max_row_group_skew=0.0, data_dir="memory://e2e", seed=0)

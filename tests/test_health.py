@@ -446,8 +446,11 @@ def test_chaos_delay_to_detector_to_capsule_end_to_end(tmp_path, rng,
         fire_ticks=2, clear_ticks=50, incident_dir=str(tmp_path / "inc"),
         slo_droop_window_ticks=8, slo_droop_floor_eps=2.0)
     assert monitor is not None
-    rt_faults.install("reduce_gather:epoch1:delay400,"
-                      "reduce_gather:epoch2:delay400", seed=0)
+    # The detector compares rates smoothed over window_ticks x interval_s
+    # = 0.4 s: only a silence longer than that window (plus fire_ticks)
+    # can read as a droop, so the injected delay has to outlast it.
+    rt_faults.install("reduce_gather:epoch1:delay1000,"
+                      "reduce_gather:epoch2:delay1000", seed=0)
     try:
         run_shuffle(files, lambda ti, e, refs: [r.result() for r in refs]
                     if refs is not None else None,
@@ -510,17 +513,15 @@ def test_rsdl_report_check_and_html_build(tmp_path, monkeypatch):
                                          profile_s=0.0, wait_s=0.0)
     tool = os.path.join(_REPO_ROOT, "tools", "rsdl_report.py")
     check = subprocess.run(
-        [sys.executable, tool, "--check", "--bench-dir", _REPO_ROOT,
-         "--capsule", capsule],
+        [sys.executable, tool, "--check", "--capsule", capsule],
         capture_output=True, text=True, timeout=120)
     assert check.returncode == 0, check.stderr
     assert "0 invalid" in check.stdout, check.stdout
     out_html = str(tmp_path / "report.html")
     build = subprocess.run(
-        [sys.executable, tool, "--bench-dir", _REPO_ROOT,
-         "--capsule", capsule, "-o", out_html],
+        [sys.executable, tool, "--capsule", capsule, "-o", out_html],
         capture_output=True, text=True, timeout=120)
     assert build.returncode == 0, build.stderr
     text = open(out_html).read()
     assert "<svg" in text and "rsdl run report" in text
-    assert "Bench trajectory" in text
+    assert "Incident" in text and "report-test" in text

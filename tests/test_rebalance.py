@@ -8,6 +8,7 @@ mid-decision — each recovering to a bit-identical delivered stream)."""
 
 import os
 import threading
+import time
 
 import pyarrow as pa
 import pytest
@@ -582,6 +583,12 @@ def test_kill9_source_mid_prepare_aborts_and_stream_bit_identical(
             assert rt_sup.wait_for_server(tuple(address), timeout_s=60)
         got = _drain_rank(shard_map, filenames, epochs, trainers, seed,
                           rank=0, on_table=on_table)
+        # The consumer can finish from what it had prefetched before the
+        # supervisor's thread has run on the child's death, and a stop()
+        # that comes first leaves the death uncounted: give it its turn.
+        deadline = time.monotonic() + 30.0
+        while supervisors[0].restarts < 1 and time.monotonic() < deadline:
+            time.sleep(0.05)
     finally:
         for supervisor in supervisors:
             supervisor.stop()

@@ -58,15 +58,14 @@ def test_policy_env_precedence(monkeypatch):
                           override=1.25) == 1.25
 
 
-def test_policy_bench_mitigation_becomes_library_default(monkeypatch):
-    """RSDL_DEVICE_REBATCH=0 (the old bench-only mitigation, promoted)
-    forces the per-batch path as the library default."""
+def test_policy_global_device_rebatch_is_the_library_default(monkeypatch):
+    """RSDL_DEVICE_REBATCH=0 forces the per-batch path for every
+    component; a component-scoped name wins for that component only."""
     monkeypatch.setenv("RSDL_DEVICE_REBATCH", "0")
     assert policy.resolve("jax_dataset", "device_rebatch") is False
-    assert policy.resolve("bench", "device_rebatch") is False
     monkeypatch.setenv("RSDL_JAX_DATASET_DEVICE_REBATCH", "auto")
     assert policy.resolve("jax_dataset", "device_rebatch") == "auto"
-    assert policy.resolve("bench", "device_rebatch") is False
+    assert policy.resolve("shuffle", "device_rebatch") is False
 
 
 def test_policy_register_defaults_env_still_wins(monkeypatch):
@@ -318,41 +317,3 @@ def test_watchdog_disabled_by_policy(tmp_path):
         assert ds._converter.watchdog is None
     finally:
         ds.close()
-
-
-# ---------------------------------------------------------------------------
-# bench aggregation helpers (median-of-N + congestion marker)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_aggregate_train_runs_median_and_congestion():
-    import bench
-
-    quiet = [{"step_ms_mean": 1.00, "rows_per_s": 100.0, "stall_pct": 1.0},
-             {"step_ms_mean": 1.02, "rows_per_s": 99.0, "stall_pct": 1.1},
-             {"step_ms_mean": 0.98, "rows_per_s": 101.0, "stall_pct": 0.9}]
-    agg = bench._aggregate_train_runs(quiet)
-    assert agg["runs"] == 3
-    assert agg["train_step_ms_median"] == 1.00
-    assert agg["congested_runs"] == 0 and agg["congested"] is False
-
-    congested = [{"step_ms_mean": 1.00, "rows_per_s": 100.0,
-                  "stall_pct": 1.0},
-                 {"step_ms_mean": 5.00, "rows_per_s": 20.0,
-                  "stall_pct": 1.0},
-                 {"step_ms_mean": 1.02, "rows_per_s": 99.0,
-                  "stall_pct": 1.0}]
-    agg = bench._aggregate_train_runs(congested)
-    assert agg["train_step_ms_median"] == pytest.approx(1.02)
-    assert agg["congested_runs"] == 1 and agg["congested"] is True
-    # The median run, not the congested outlier, carries the contract.
-    assert agg["train_rows_per_sec_median"] == pytest.approx(99.0)
-
-
-def test_bench_aggregate_single_run_passthrough():
-    import bench
-
-    agg = bench._aggregate_train_runs(
-        [{"step_ms_mean": 2.0, "rows_per_s": 10.0, "stall_pct": 0.5}])
-    assert agg["runs"] == 1
-    assert agg["congested"] is False

@@ -1,5 +1,5 @@
 """Causal tracing layer: merge, critical path, what-if, Perfetto,
-profiler, manual spans, wire-frame task propagation, bench diff gate."""
+profiler, manual spans, wire-frame task propagation."""
 
 import json
 import os
@@ -348,97 +348,6 @@ def test_reduce_outputs_carry_lineage_metadata(tmp_path):
     assert tasks == [0, 1]
     assert all(t.schema.metadata[b"rsdl.trace"].startswith(b"9:0:")
                for t in outputs)
-
-
-# ---------------------------------------------------------------------------
-# bench integration pieces
-# ---------------------------------------------------------------------------
-
-
-def test_bench_fields_shape():
-    fields = rt_trace.bench_fields(_synthetic_epoch())
-    assert {"critical_path", "self_time_ms", "whatif",
-            "trace_straggler", "trace_epochs_analyzed"} <= set(fields)
-    assert fields["trace_straggler"]["stage"] == "map_read"
-    assert fields["trace_epochs_analyzed"] == 1
-    json.dumps(fields)  # must be JSON-serializable as-is
-
-
-def _load_bench_diff():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_bd", os.path.join(REPO_ROOT, "tools", "rsdl_bench_diff.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-def _faster_old_baseline(tmp_path, bd):
-    """A baseline in the oldest record shape (headline keys only, no
-    ``rows_per_s_per_core``) whose cached ingest is 2.9x BENCH_r05's —
-    the size of drop the gate exists to catch."""
-    cur = bd.load_record(os.path.join(REPO_ROOT, "BENCH_r05.json"))
-    base = {key: cur[key] for key in (
-        "metric", "unit", "vs_baseline", "stall_pct", "stall_s",
-        "batch_wait_mean_ms", "step_ms", "cache_mode",
-        "baseline_files_fraction", "host_cpus", "timed_epochs")}
-    base["value"] = round(cur["value"] * 2.9, 1)
-    path = tmp_path / "faster_baseline.json"
-    path.write_text(json.dumps(base))
-    return str(path)
-
-
-def test_bench_diff_flags_headline_regression(tmp_path):
-    bd = _load_bench_diff()
-    base_path = _faster_old_baseline(tmp_path, bd)
-    cur_path = os.path.join(REPO_ROOT, "BENCH_r05.json")
-    findings = bd.compare_records(bd.load_record(base_path),
-                                  bd.load_record(cur_path))
-    bad = [f for f in findings if not f["ok"]]
-    assert any(f["key"] == "value" for f in bad), findings
-    # CLI form: rc 1, the acceptance-gate invocation.
-    assert bd.main([base_path, cur_path]) == 1
-    # Identical records: clean.
-    assert bd.main([cur_path, cur_path]) == 0
-    # Threshold override: a 99% allowance forgives even this drop.
-    assert bd.main(["--threshold", "value=99",
-                    "--threshold", "rows_per_s_per_core=99",
-                    base_path, cur_path]) == 0
-
-
-def test_bench_diff_check_mode_is_informational():
-    bd = _load_bench_diff()
-    assert bd.main(["--check", REPO_ROOT]) == 0
-
-
-def test_bench_diff_derives_per_core_rate_for_old_records(tmp_path):
-    bd = _load_bench_diff()
-    # The oldest records predate the rows_per_s_per_core key but carry
-    # value + host_cpus; the per-core lower-bad rule must fire against
-    # them instead of silently skipping the one host-width-proof metric.
-    base = bd.derive_metrics(
-        bd.load_record(_faster_old_baseline(tmp_path, bd)))
-    assert base["rows_per_s_per_core"] == pytest.approx(
-        base["value"] / base["host_cpus"])
-    findings = bd.compare_records(
-        base, bd.derive_metrics({"value": base["value"] * 0.5,
-                                 "host_cpus": base["host_cpus"]}))
-    per_core = [f for f in findings
-                if f["key"] == "rows_per_s_per_core"][0]
-    assert not per_core["ok"]
-    # An emitted value always wins over the derived one.
-    rec = bd.derive_metrics({"value": 100.0, "host_cpus": 4,
-                             "rows_per_s_per_core": 99.0})
-    assert rec["rows_per_s_per_core"] == 99.0
-
-
-def test_bench_diff_ceiling_applies_to_current_only():
-    bd = _load_bench_diff()
-    findings = bd.compare_records(
-        {"value": 100.0}, {"value": 100.0, "telemetry_overhead_pct": 3.0})
-    ceiling = [f for f in findings
-               if f["key"] == "telemetry_overhead_pct"][0]
-    assert not ceiling["ok"]
 
 
 # ---------------------------------------------------------------------------

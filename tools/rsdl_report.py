@@ -9,21 +9,18 @@ individual tools tell separately:
   (``--history history.json``, or the one embedded in a capsule);
 - **critical path + what-if** from recorder dumps (``--trace-dir``, or
   the capsule's ``traces/``);
-- **health**: detector verdicts from a capsule and/or the bench
-  record's ``health`` section;
-- **worker scaling** from the newest bench record's ``worker_scaling``;
-- **bench trajectory** across the committed ``BENCH_r*.json`` rounds.
+- **health**: the detector verdict of an incident capsule;
+- **delivery latency & freshness** from the capsule and the history.
 
 Usage::
 
-    tools/rsdl_report.py -o report.html                  # BENCH_r* in .
     tools/rsdl_report.py --history hist.json --trace-dir /tmp/rsdl-trace \
         -o report.html
     tools/rsdl_report.py --capsule <capsule-dir> -o report.html
-    tools/rsdl_report.py --check [DIR]    # schema-only smoke, no HTML
+    tools/rsdl_report.py --check          # schema-only smoke, no HTML
 
-``--check`` validates whatever inputs exist (bench records parse,
-history slices load, trace dumps merge) and prints one line per source
+``--check`` validates whatever inputs exist (history slices load, trace
+dumps merge, the capsule's manifest parses) and prints one line per source
 — informational mode for format.sh, always exit 0 unless the arguments
 themselves are unusable.
 
@@ -37,7 +34,6 @@ import html
 import importlib.util
 import json
 import os
-import re
 import sys
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -61,27 +57,6 @@ def _load_by_path(stem: str):
 # ---------------------------------------------------------------------------
 # Input loading (each loader returns None when its source is absent)
 # ---------------------------------------------------------------------------
-
-
-def load_bench_records(directory: str):
-    """``[(round, record)]`` sorted by round number; raw bench JSON or
-    the committed ``BENCH_r*`` wrapper form."""
-    out = []
-    for path in sorted(glob.glob(os.path.join(directory, "BENCH_r*.json"))):
-        match = re.search(r"BENCH_r(\d+)\.json$", path)
-        if not match:
-            continue
-        with open(path, encoding="utf-8") as f:
-            data = json.load(f)
-        record = data.get("parsed") if isinstance(
-            data.get("parsed"), dict) else data
-        if not isinstance(record, dict) or "value" not in record:
-            # A failed round commits a wrapper with parsed=null — part
-            # of the trajectory's honesty, not a reason to refuse the
-            # report; the round simply has no numbers to plot.
-            continue
-        out.append((int(match.group(1)), record))
-    return out or None
 
 
 def load_history(path):
@@ -223,61 +198,6 @@ def _table(headers, rows) -> str:
     return f"<table><thead><tr>{head}</tr></thead><tbody>{body}</tbody></table>"
 
 
-def _biggest_change(prev, rec) -> str:
-    """One phrase per trajectory row: the largest relative move among
-    the numeric keys two consecutive rounds share (the per-round 'what
-    changed vs previous' cell — the forensic headline, with the full
-    story in `tools/rsdl_regress.py prev cur`)."""
-    best_key, best_pct = None, 0.0
-    for key in set(prev) & set(rec):
-        p, c = prev.get(key), rec.get(key)
-        if isinstance(p, bool) or isinstance(c, bool):
-            continue
-        if not isinstance(p, (int, float)) or \
-                not isinstance(c, (int, float)) or p == 0:
-            continue
-        pct = 100.0 * (c - p) / abs(p)
-        if abs(pct) > abs(best_pct):
-            best_key, best_pct = key, pct
-    if best_key is None or abs(best_pct) < 2.0:
-        return "–"
-    return f"{html.escape(best_key)} {best_pct:+.0f}%"
-
-
-def _section_bench(records) -> str:
-    if not records:
-        return ""
-    latest_round, latest = records[-1]
-    parts = [f"<h2>Bench trajectory (r{records[0][0]}–r{latest_round})</h2>"]
-    parts.append(
-        f"<p class='stat'>{_fmt(latest.get('value'))}"
-        f"<small>{html.escape(str(latest.get('unit', 'rows/s')))} — "
-        f"{html.escape(str(latest.get('metric', '')))} @ r{latest_round}"
-        "</small></p>")
-    pts = [(r, rec.get("value", 0.0)) for r, rec in records]
-    parts.append(spark_svg(pts, unit=" rows/s"))
-    rows = []
-    prev_rec = None
-    for r, rec in records:
-        health = rec.get("health") or {}
-        fires = health.get("fires")
-        rows.append((
-            f"r{r:02d}", _fmt(rec.get("value")),
-            _fmt(rec.get("stall_pct")),
-            _fmt(rec.get("train_mfu_pct")),
-            html.escape(str(rec.get("bottleneck_stage") or "–")),
-            html.escape(str(rec.get("executor_backend") or "–")),
-            ("<span class='breach'>" + str(fires) + " FIRED</span>"
-             if fires else ("0" if fires == 0 else "–")),
-            (_biggest_change(prev_rec, rec) if prev_rec else "–"),
-        ))
-        prev_rec = rec
-    parts.append(_table(
-        ("round", "rows/s", "stall %", "mfu %", "bottleneck", "backend",
-         "health fires", "vs prev"), rows))
-    return "".join(parts)
-
-
 def _section_history(ring) -> str:
     if ring is None:
         return ""
@@ -333,56 +253,24 @@ def _section_traces(traced) -> str:
     return "".join(parts)
 
 
-def _section_health(manifest, records) -> str:
-    parts = []
-    if manifest:
-        verdict = manifest.get("verdict") or {}
-        parts.append("<h2>Incident</h2>")
-        parts.append(
-            "<p><span class='breach'>"
-            + html.escape(str(verdict.get("detector")
-                              or manifest.get("reason", "incident")))
-            + " FIRED</span> — "
-            + html.escape(str(verdict.get("detail", "")))
-            + f" (pids {html.escape(str(manifest.get('pids')))})</p>")
-    latest = records[-1][1] if records else None
-    health = (latest or {}).get("health")
-    if health:
-        parts.append("<h2>Health (latest bench record)</h2>")
-        rows = []
-        for phase, entry in sorted(health.get("by_phase", {}).items()):
-            for name, d in sorted(entry.get("detectors", {}).items()):
-                fires = d.get("fires", 0)
-                rows.append((
-                    html.escape(phase), html.escape(name),
-                    ("<span class='breach'>" + str(fires)
-                     + " FIRED</span>") if fires else "0",
-                    html.escape(str((d.get("last") or {}).get(
-                        "detail", "–"))),
-                ))
-        if rows:
-            parts.append(_table(("phase", "detector", "fires", "last "
-                                 "breach"), rows))
-        else:
-            parts.append(f"<p class='sub'>armed, {health.get('fires', 0)} "
-                         "fires</p>")
-    return "".join(parts)
+def _section_health(manifest) -> str:
+    if not manifest:
+        return ""
+    verdict = manifest.get("verdict") or {}
+    return (
+        "<h2>Incident</h2>"
+        "<p><span class='breach'>"
+        + html.escape(str(verdict.get("detector")
+                          or manifest.get("reason", "incident")))
+        + " FIRED</span> — "
+        + html.escape(str(verdict.get("detail", "")))
+        + f" (pids {html.escape(str(manifest.get('pids')))})</p>")
 
 
-def _section_latency(records, ring, manifest) -> str:
-    """Delivery latency & freshness: the bench latency leg's headline
-    p99s, the capsule's frozen per-queue quantiles, and a freshness
-    sparkline (worst queue per tick) from the history ring."""
-    parts = []
-    latest = records[-1][1] if records else {}
-    rows = []
-    for key, label in (("delivery_p50_ms", "delivery p50"),
-                       ("delivery_p95_ms", "delivery p95"),
-                       ("delivery_p99_ms", "delivery p99"),
-                       ("freshness_p99_ms", "freshness p99")):
-        if latest.get(key) is not None:
-            rows.append((html.escape(label), _fmt(latest.get(key)),
-                         "ms"))
+def _section_latency(ring, manifest) -> str:
+    """Delivery latency & freshness: the capsule's frozen per-queue
+    quantiles and a freshness sparkline (worst queue per tick) from the
+    history ring."""
     capsule_latency = (manifest or {}).get("latency") or {}
     fresh_pts = []
     if ring is not None:
@@ -391,13 +279,9 @@ def _section_latency(records, ring, manifest) -> str:
                 "rsdl_delivery_freshness_seconds")
             if series:
                 fresh_pts.append((snap["t"], max(series.values())))
-    if not rows and not capsule_latency and len(fresh_pts) < 2:
+    if not capsule_latency and len(fresh_pts) < 2:
         return ""
-    parts.append("<h2>Delivery latency &amp; freshness</h2>")
-    if rows:
-        parts.append("<p class='sub'>bench latency leg "
-                     "(birth→delivered / birth→device)</p>")
-        parts.append(_table(("span", "value", "unit"), rows))
+    parts = ["<h2>Delivery latency &amp; freshness</h2>"]
     if capsule_latency:
         parts.append("<p class='sub'>capsule snapshot — per hop/queue "
                      "(seconds)</p>")
@@ -414,78 +298,13 @@ def _section_latency(records, ring, manifest) -> str:
     return "".join(parts)
 
 
-def _section_scaling(records) -> str:
-    latest = records[-1][1] if records else None
-    scaling = (latest or {}).get("worker_scaling")
-    if not scaling:
-        return ""
-    parts = ["<h2>Worker scaling</h2>"]
-    legs = scaling.get("legs") or scaling.get("runs")
-    if isinstance(legs, list) and legs:
-        headers = sorted({k for leg in legs for k in leg
-                          if isinstance(leg, dict)})
-        rows = [tuple(_fmt(leg.get(h)) for h in headers) for leg in legs]
-        parts.append(_table(headers, rows))
-    else:
-        rows = [(html.escape(str(k)), _fmt(v))
-                for k, v in sorted(scaling.items())
-                if not isinstance(v, (dict, list))]
-        parts.append(_table(("metric", "value"), rows))
-    return "".join(parts)
-
-
-def _section_tenancy(records) -> str:
-    """Per-tenant QoS columns from the contention bench leg (bench.py
-    ``tenancy`` phase): fairness ratio against the configured weight
-    split, per-tenant throughput, and the hot tenant's contended-vs-
-    solo p99 multiple. Skips cleanly for records predating the leg."""
-    rows = []
-    for r, rec in records:
-        if rec.get("tenancy_fairness_ratio") is None:
-            continue
-        rows.append((
-            f"r{r:02d}",
-            _fmt(rec.get("tenancy_fairness_ratio")),
-            _fmt(rec.get("tenancy_weight_ratio")),
-            _fmt(rec.get("tenancy_hot_rows_per_sec")),
-            _fmt(rec.get("tenancy_cold_rows_per_sec")),
-            _fmt(rec.get("tenancy_hot_p99_ms_solo")),
-            _fmt(rec.get("tenancy_hot_p99_ms_contended")),
-            _fmt(rec.get("tenancy_latency_ratio_x")),
-            ("ok" if rec.get("tenancy_ok") else
-             "<span class='breach'>FAIL</span>")
-            if rec.get("tenancy_ok") is not None else "–",
-        ))
-    if not rows:
-        return ""
-    return "".join([
-        "<h2>Tenancy contention</h2>",
-        "<p class='sub'>hot streaming tenant vs cold batch-replay "
-        "tenant on shared shards — delivered-rows ratio should track "
-        "the weight split, hot p99 should hold near solo</p>",
-        _table(("round", "fairness ratio", "weights", "hot rows/s",
-                "cold rows/s", "hot p99 solo ms", "hot p99 cont ms",
-                "p99 ratio", "ok"), rows),
-    ])
-
-
-def build_html(records, ring, traced, manifest) -> str:
-    latest = records[-1][1] if records else {}
-    sub = []
-    if latest:
-        sub.append(f"host_cpus {latest.get('host_cpus')}")
-        sub.append(f"backend {latest.get('executor_backend')}")
-        sub.append(f"workers {latest.get('executor_workers')}")
+def build_html(ring, traced, manifest) -> str:
     body = (
         "<h1>rsdl run report</h1>"
-        f"<p class='sub'>{html.escape(' · '.join(str(s) for s in sub))}</p>"
-        + _section_health(manifest, records)
-        + _section_latency(records, ring, manifest)
+        + _section_health(manifest)
+        + _section_latency(ring, manifest)
         + _section_history(ring)
-        + _section_traces(traced)
-        + _section_scaling(records)
-        + _section_tenancy(records)
-        + _section_bench(records))
+        + _section_traces(traced))
     return ("<!DOCTYPE html><html><head><meta charset='utf-8'>"
             "<title>rsdl run report</title>"
             f"<style>{_CSS}</style></head>"
@@ -499,10 +318,8 @@ def build_html(records, ring, traced, manifest) -> str:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
-        description="standalone-HTML run report over bench records, "
-                    "history slices, trace dumps and incident capsules")
-    parser.add_argument("--bench-dir", default=".",
-                        help="directory of BENCH_r*.json (default .)")
+        description="standalone-HTML run report over history slices, "
+                    "trace dumps and incident capsules")
     parser.add_argument("--history", default=None,
                         help="history slice JSON (rsdl-history-v1)")
     parser.add_argument("--trace-dir", default=None,
@@ -538,8 +355,6 @@ def main(argv=None) -> int:
                        + ("ok" if value is not None else "absent"))
         return value
 
-    records = _load("bench-records",
-                    lambda: load_bench_records(args.bench_dir))
     ring = _load("history", lambda: load_history(history_path))
     traced = _load("traces", lambda: load_traces(trace_dir))
     manifest = _load("capsule",
@@ -557,11 +372,11 @@ def main(argv=None) -> int:
         for line in failures:
             print(f"rsdl-report: INVALID {line}", file=sys.stderr)
         return 1
-    if not any((records, ring, traced, manifest)):
-        print("rsdl-report: no inputs found (no BENCH_r*.json, history, "
-              "traces, or capsule)", file=sys.stderr)
+    if not any((ring, traced, manifest)):
+        print("rsdl-report: no inputs found (no history, traces, or "
+              "capsule)", file=sys.stderr)
         return 2
-    text = build_html(records, ring, traced, manifest)
+    text = build_html(ring, traced, manifest)
     with open(args.out, "w", encoding="utf-8") as f:
         f.write(text)
     print(f"rsdl-report: {args.out} ({len(text)} bytes; "
