@@ -74,7 +74,7 @@ class SmokeSize:
     gather_table: Tuple[int, int]     # (vocab, embed_dim)
     gather_batch: int
     attention_shape: Tuple[int, int, int]  # (batch, heads, head_dim)
-    attention_seqs: Tuple[int, int]   # (aligned, padded)
+    attention_seqs: Tuple[int, ...]   # in blocks, padded, one block
     epochs: int = 2
 
 
@@ -86,7 +86,7 @@ def full_size() -> SmokeSize:
         learning_rate=1e-3,
         gather_table=(max(cfg.vocab_sizes), cfg.embed_dim),
         gather_batch=2048, attention_shape=(2, 4, 64),
-        attention_seqs=(2048, 1000))
+        attention_seqs=(2048, 1000, 512))
 
 
 def tiny_size() -> SmokeSize:
@@ -99,7 +99,7 @@ def tiny_size() -> SmokeSize:
     return SmokeSize(
         model=cfg, batch_per_device=16, steps_per_epoch=8, num_files=2,
         learning_rate=1e-2, gather_table=(4096, 128), gather_batch=64,
-        attention_shape=(1, 2, 32), attention_seqs=(256, 200))
+        attention_shape=(1, 2, 32), attention_seqs=(256, 200, 64))
 
 
 # -- kernels ---------------------------------------------------------------

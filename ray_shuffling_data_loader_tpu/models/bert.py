@@ -12,7 +12,10 @@ TPU-first choices:
   "model", attention-out and FFN-out split row-wise, so each transformer
   block needs exactly two psums; embeddings column-sharded.
 - bf16 compute / f32 params & softmax accumulation; static seq_len, fused
-  QKV projection; attention is two batched matmuls on the MXU.
+  QKV projection. Attention reads that projection in place through the
+  Pallas flash kernels (ops/flash_attention.py: scores and weights stay in
+  VMEM) on the chip from the measured crossover up, and is two batched
+  matmuls around a materialized softmax below it and off the chip.
 - MLM loss masks with a -100 ignore-id convention (positions to predict
   carry their target id, others -100) and projects only those positions
   onto the vocabulary, in blocks (``_masked_nll``).
@@ -21,18 +24,23 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict
+import functools
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
+from ray_shuffling_data_loader_tpu.ops import flash_attention, on_tpu
+from ray_shuffling_data_loader_tpu.parallel.mesh import DATA_AXIS
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 
 IGNORE_ID = -100
 
-# The name a device trace shows the head's operations under.
+# The names a device trace shows the head's and a layer's attention's
+# operations under.
 MLM_HEAD_SCOPE = "rsdl.bert.mlm_head"
+ATTENTION_SCOPE = "rsdl.bert.attention"
 # The loss walks a row's masked positions in this many blocks at most: at
 # the paper's 15 % the fullest row of a batch ends in the second.
 _MLM_BLOCKS_PER_ROW = 8
@@ -138,10 +146,96 @@ def _layer_norm(x, scale, bias, eps=1e-12):
     return out.astype(x.dtype)
 
 
+def _split_heads(qkv, num_heads: int):
+    """(B, S, 3 x hidden) -> q, k, v, each (B, H, S, D)."""
+    b, s, width = qkv.shape
+    return [x.reshape(b, s, num_heads, -1).transpose(0, 2, 1, 3)
+            for x in jnp.split(qkv, 3, axis=-1)]
+
+
+def _merge_heads(x):
+    """(B, H, S, D) -> (B, S, hidden)."""
+    b, _, s, _ = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, -1)
+
+
+# The attentions are jitted for the scope's sake, as ``_masked_nll_fwd``
+# below: inside a program of its own the name stays as written.
+@functools.partial(jax.jit, static_argnums=(2,))
+def _inline_attention(qkv, bias, num_heads: int):
+    """Attention of a fused projection ``qkv`` (B, S, 3 x hidden) as XLA
+    has it: two batched matmuls around a float32 softmax of materialized
+    (B, H, S, S) scores; the backward is autodiff's."""
+    with jax.named_scope(ATTENTION_SCOPE):
+        q, k, v = _split_heads(qkv, num_heads)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
+        scores = scores / jnp.sqrt(q.shape[-1])
+        if bias is not None:
+            scores = scores + bias
+        weights = jax.nn.softmax(scores, axis=-1).astype(qkv.dtype)
+        return _merge_heads(jnp.einsum("bhqk,bhkd->bhqd", weights, v))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _flash_attention(qkv, bias, num_heads: int):
+    """``_inline_attention``'s result from the Pallas flash kernels: they
+    read q, k and v where the projection left them and keep a block's
+    scores and weights in VMEM, forward and backward."""
+    return _flash_attention_fwd(qkv, bias, num_heads)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _flash_attention_fwd(qkv, bias, num_heads):
+    with jax.named_scope(ATTENTION_SCOPE):
+        out, lse = flash_attention.qkv_forward(qkv, bias, num_heads,
+                                               interpret=not on_tpu())
+    saved = out if flash_attention.saves_out(qkv, num_heads) else None
+    return out, (qkv, bias, saved, lse)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _flash_attention_bwd(num_heads, residuals, cotangent):
+    qkv, bias, out, lse = residuals
+    with jax.named_scope(ATTENTION_SCOPE):
+        return flash_attention.qkv_backward(qkv, bias, out, lse, cotangent,
+                                            num_heads, interpret=not on_tpu())
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
+def _attention(qkv, bias, num_heads: int, mesh: Optional[Mesh]):
+    """A layer's attention, (B, S, 3 x hidden) -> (B, S, hidden), by what
+    the trace can observe: the flash kernels where they beat the inline
+    path (``flash_attention.beats_inline``: on the chip, from the measured
+    sequence length up) and the bias is none or key-side, else inline.
+    GSPMD cannot partition a Mosaic kernel, so under a ``mesh`` of more
+    than one device the kernels run once per shard of its data axis (rows
+    are independent: nothing is exchanged)."""
+    key_side = bias is None or bias.shape == (qkv.shape[0], 1, 1,
+                                               qkv.shape[1])
+    flash = key_side and flash_attention.beats_inline(qkv.shape[1])
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_bert_attention_total",
+        "BERT layers' attentions traced, by implementation: the Pallas "
+        "flash kernels or XLA's inline softmax over materialized scores",
+        kind="flash" if flash else "inline").inc()
+    if not flash:
+        return _inline_attention(qkv, bias, num_heads)
+    attend = functools.partial(_flash_attention, num_heads=num_heads)
+    if mesh is not None and mesh.size > 1:
+        attend = jax.shard_map(
+            attend, mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
+            out_specs=P(DATA_AXIS), check_vma=False)
+    return attend(qkv, bias)
+
+
 def encode(config: BertConfig, params: Dict[str, Any],
            token_ids: jax.Array,
            attention_mask: jax.Array = None,
-           attention_fn=None) -> jax.Array:
+           attention_fn=None,
+           mesh: Optional[Mesh] = None) -> jax.Array:
     """token_ids (B, S) int32 -> hidden states (B, S, hidden_dim) in the
     compute dtype, after the last transformer layer.
 
@@ -149,12 +243,18 @@ def encode(config: BertConfig, params: Dict[str, Any],
 
     ``attention_fn(q, k, v, bias) -> (B, H, S, D)`` swaps the attention
     implementation — e.g. ``ops.ring_attention.make_attention_fn(mesh,
-    seq_axis)`` for sequence-parallel long-context runs, or the Pallas
-    flash kernel. None = inline full attention on the MXU.
+    seq_axis)`` for sequence-parallel long-context runs. None = the model
+    chooses (:func:`_attention`): the Pallas flash kernels on the chip
+    from ``flash_attention.FLASH_MIN_SEQ_LEN`` up, XLA's inline attention
+    below it and on every other backend.
+
+    ``mesh``: the mesh the calling step is jitted over, when it spans more
+    than one device (``ops/embedding.py:lookup``'s convention): a trace
+    cannot see it, and the kernels must be told.
     """
     dtype = config.compute_dtype
     b, s = token_ids.shape
-    h, nh, hd = config.hidden_dim, config.num_heads, config.head_dim
+    nh = config.num_heads
 
     x = (jnp.take(params["token_emb"], token_ids, axis=0, mode="clip")
          + params["pos_emb"][:s][None, :, :]).astype(dtype)
@@ -168,20 +268,11 @@ def encode(config: BertConfig, params: Dict[str, Any],
 
     def layer_fn(x, lp, bias):
         qkv = x @ lp["qkv_w"].astype(dtype) + lp["qkv_b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
         if attention_fn is not None:
-            attended = attention_fn(q, k, v, bias)
+            attended = _merge_heads(
+                attention_fn(*_split_heads(qkv, nh), bias))
         else:
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
-            scores = scores / jnp.sqrt(hd)
-            if bias is not None:
-                scores = scores + bias
-            weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
-            attended = jnp.einsum("bhqk,bhkd->bhqd", weights, v)
-        attended = attended.transpose(0, 2, 1, 3).reshape(b, s, h)
+            attended = _attention(qkv, bias, nh, mesh)
         attn_out = (attended @ lp["attn_out_w"].astype(dtype)
                     + lp["attn_out_b"].astype(dtype))
         x = _layer_norm(x + attn_out, lp["ln1"]["scale"], lp["ln1"]["bias"])
@@ -210,10 +301,11 @@ def _head(x: jax.Array, token_emb: jax.Array, mlm_bias: jax.Array
 def apply(config: BertConfig, params: Dict[str, Any],
           token_ids: jax.Array,
           attention_mask: jax.Array = None,
-          attention_fn=None) -> jax.Array:
+          attention_fn=None,
+          mesh: Optional[Mesh] = None) -> jax.Array:
     """token_ids (B, S) int32 -> logits (B, S, vocab); the arguments are
     :func:`encode`'s."""
-    x = encode(config, params, token_ids, attention_mask, attention_fn)
+    x = encode(config, params, token_ids, attention_mask, attention_fn, mesh)
     return _head(x, params["token_emb"], params["mlm_bias"])
 
 
@@ -322,10 +414,12 @@ _masked_nll.defvjp(_masked_nll_fwd, _masked_nll_bwd)
 def loss_fn(config: BertConfig, params: Dict[str, Any],
             token_ids: jax.Array, mlm_targets: jax.Array,
             attention_mask: jax.Array = None,
-            attention_fn=None) -> jax.Array:
+            attention_fn=None,
+            mesh: Optional[Mesh] = None) -> jax.Array:
     """Masked-LM cross-entropy over positions where targets != IGNORE_ID.
     Only those positions are projected onto the vocabulary
-    (:func:`_masked_nll`)."""
+    (:func:`_masked_nll`). ``attention_fn`` and ``mesh`` are
+    :func:`encode`'s."""
     seq_len = token_ids.shape[1]
     block = mlm_block_size(seq_len)
     # Counted when a step is traced, not when it runs.
@@ -341,7 +435,7 @@ def loss_fn(config: BertConfig, params: Dict[str, Any],
         "rsdl_mlm_head_blocks_per_row",
         "Blocks the masked-LM head's walk takes over a fully masked row, "
         "last loss traced").set(-(-seq_len // block))
-    x = encode(config, params, token_ids, attention_mask, attention_fn)
+    x = encode(config, params, token_ids, attention_mask, attention_fn, mesh)
     total = _masked_nll(x, params["token_emb"], params["mlm_bias"],
                         mlm_targets.astype(jnp.int32))
     count = jnp.maximum(jnp.sum(mlm_targets != IGNORE_ID), 1)

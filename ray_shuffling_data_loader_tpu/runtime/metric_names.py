@@ -40,6 +40,9 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_mlm_head_total": ("counter", ("kind",)),
     "rsdl_mlm_head_block_positions": ("gauge", ()),
     "rsdl_mlm_head_blocks_per_row": ("gauge", ()),
+    # -- a BERT layer's attention (models/bert.py; counted when a layer is
+    #    traced, not when it runs; kind = flash | inline) --
+    "rsdl_bert_attention_total": ("counter", ("kind",)),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

@@ -176,18 +176,15 @@ if __name__ == "__main__":
         opt = optax.adam(1e-4)
         opt_state = opt.init(params)
 
-        # Measured-best attention for this sequence length: Pallas flash
-        # on-chip from S=1024 up, XLA's fused inline attention below.
-        from ray_shuffling_data_loader_tpu.ops.flash_attention import (
-            auto_attention_fn)
-        attention_fn = auto_attention_fn(args.seq_len)
-
+        # No attention_fn: the model chooses (the Pallas flash kernels on
+        # the chip from flash_attention.FLASH_MIN_SEQ_LEN up, XLA's inline
+        # attention below it and on the CPU). The step is jitted on one
+        # device, so it has no mesh to tell ``loss_fn`` of.
         @jax.jit
         def step(params, opt_state, tokens, key):
             inputs, targets = mlm_mask(tokens, key, args.vocab_size)
             loss, grads = jax.value_and_grad(
-                lambda p: bert.loss_fn(cfg, p, inputs, targets,
-                                       attention_fn=attention_fn))(params)
+                lambda p: bert.loss_fn(cfg, p, inputs, targets))(params)
             updates, opt_state = opt.update(grads, opt_state)
             return optax.apply_updates(params, updates), opt_state, loss
 

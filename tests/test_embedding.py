@@ -377,3 +377,62 @@ def test_bert_mlm_head_gradient_compiles_for_the_chip_in_blocks(four_chips):
     assert re.search(rf"f32\[{batch},{block},{cfg.vocab_size}\]", hlo)
     assert not re.search(rf"\[{batch},{seq},{cfg.vocab_size}\]", hlo)
     assert not re.search(rf"\[{batch * seq},{cfg.vocab_size}\]", hlo)
+
+
+def _bert_base_step(four_chips, monkeypatch, mesh, sharding):
+    """The loss and gradients of ``bert-base-mlm`` (two of its twelve
+    layers: the widths are what the compiler may refuse) at
+    ``bert_train``'s batch a chip, lowered for described chips with the
+    model's choice of attention as on the chip."""
+    import dataclasses
+
+    from ray_shuffling_data_loader_tpu.models import bert
+    from ray_shuffling_data_loader_tpu.ops import flash_attention
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(bert, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(bert.bert_base(), num_layers=2)
+    replicated = sharding()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=replicated),
+        jax.eval_shape(lambda: bert.init(cfg, jax.random.key(0))))
+    chips = 1 if mesh is None else mesh.size
+    tokens = jax.ShapeDtypeStruct((32 * chips, cfg.max_seq_len), jnp.int32,
+                                  sharding=sharding("data"))
+    return jax.jit(jax.value_and_grad(
+        lambda p, t, y: bert.loss_fn(cfg, p, t, y, mesh=mesh))).lower(
+            params, tokens, tokens)
+
+
+def test_bert_attention_compiles_for_the_chip_without_scores(four_chips,
+                                                            monkeypatch):
+    """``bert_train``'s loss and gradients through the TPU's own compiler:
+    a forward and a backward Mosaic kernel a layer, reading the fused
+    projection in place, and no array over (512, 512) in the program."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    hlo = _bert_base_step(four_chips, monkeypatch, None,
+                          lambda *axes: one_chip).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 4
+    assert not re.search(r"\[32,12,512,512\]", hlo)
+    assert not re.search(r"bf16\[32,12,512,64\]", hlo)   # no head-major copy
+    assert re.search(r"custom-call\(.*bf16\[32,512,2304\]", hlo)
+
+
+def test_bert_attention_lowers_for_four_chips_only_with_the_mesh(
+        four_chips, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: a step over four chips
+    lowers once ``loss_fn`` is told the mesh (the kernels then run a
+    shard of the batch a chip), and not without."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def on_mesh(*axes):
+        return NamedSharding(four_chips, P(*axes))
+
+    lowered = _bert_base_step(four_chips, monkeypatch, four_chips, on_mesh)
+    # One forward and one backward program, called by both layers.
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _bert_base_step(four_chips, monkeypatch, None, on_mesh)

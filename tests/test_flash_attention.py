@@ -179,10 +179,220 @@ def test_pick_aligned_block_floor(seq, preferred, align, exp):
     assert fa._pick_aligned_block(seq, preferred, align) == exp
 
 
-def test_auto_attention_fn_dispatch():
-    """CPU backend -> inline (None); the TPU>=1024 branch is covered by
-    construction (make_flash_attention_fn) without needing a chip."""
-    assert fa.auto_attention_fn(4096) is None  # tests pin the cpu backend
-    assert fa.FLASH_MIN_SEQ_LEN == 1024
+def test_auto_attention_fn_dispatch(monkeypatch):
+    """The rule a model chooses by (``beats_inline``): off the chip never
+    (the kernels would run interpreted), on it from the sequence length
+    PR 31 measured the crossover at, head width 64."""
+    assert not fa.beats_inline(4096)  # tests pin the cpu backend
+    assert fa.FLASH_MIN_SEQ_LEN == 192
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    assert fa.beats_inline(fa.FLASH_MIN_SEQ_LEN)
+    assert not fa.beats_inline(fa.FLASH_MIN_SEQ_LEN - 1)
     fn = fa.make_flash_attention_fn(interpret=True)
     assert callable(fn)
+
+
+# -- PR 31: operands in their own dtype, the one-block kernels, the model's
+# -- choice ---------------------------------------------------------------
+
+
+def _f32_attention(qkv, bias, heads):
+    """The float32 answer: ``bert._inline_attention`` on float32."""
+    return bert._inline_attention(qkv.astype(jnp.float32), bias, heads)
+
+
+@pytest.mark.parametrize("seq", [512, 200])
+def test_flash_bf16_operands_against_the_inline_path(rng, seq):
+    """One row, two heads of 64 (a full 128-lane block of the fused
+    projection), bf16: the kernels' loss and gradients (q, k, v and a
+    key-side bias) are the inline attention's to bf16's resolution, and
+    no further from the float32 answer than the inline path is."""
+    heads, d = 2, 64
+    qkv = jnp.asarray(rng.standard_normal((1, seq, 3 * heads * d)),
+                      jnp.bfloat16)
+    keep = rng.integers(0, 4, (1, seq)) > 0
+    bias = jnp.where(jnp.asarray(keep)[:, None, None, :], 0.0,
+                     -1e9).astype(jnp.float32)
+    weight = jnp.asarray(rng.standard_normal((1, seq, heads * d)),
+                         jnp.float32)
+    assert fa._packs(qkv, heads) == 2
+
+    def graded(attend):
+        return jax.value_and_grad(
+            lambda x, b: jnp.sum(attend(x, b, heads).astype(jnp.float32)
+                                 * weight) / seq, argnums=(0, 1))(qkv, bias)
+
+    flash_loss, flash_grads = graded(bert._flash_attention)
+    inline_loss, inline_grads = graded(bert._inline_attention)
+    exact_loss, exact_grads = graded(_f32_attention)
+    assert flash_grads[0].dtype == jnp.bfloat16
+
+    def gap(a, b):
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                     - b.astype(jnp.float32))))
+
+    assert abs(float(flash_loss) - float(inline_loss)) < 2e-3
+    assert abs(float(flash_loss) - float(exact_loss)) <= max(
+        1e-3, 2 * abs(float(inline_loss) - float(exact_loss)))
+    for got, inline, exact in zip(flash_grads, inline_grads, exact_grads):
+        scale = float(jnp.max(jnp.abs(exact)))
+        assert gap(got, inline) < 2e-2 * scale
+        assert gap(got, exact) <= max(1e-2 * scale, 1.5 * gap(inline, exact))
+
+
+@pytest.mark.parametrize("lse_covers", ["these_keys", "more_keys"])
+def test_one_block_backward_equals_the_two_kernel_backward(rng, lse_covers):
+    """The same inputs through the single backward kernel (the sequence
+    in one block) and through dq + dk/dv (blocks of 16) give the same
+    gradients, also with a global lse over keys this call does not hold
+    (a ring hop)."""
+    q, k, v = _qkv(rng)
+    more_k, more_v = _qkv(rng)[:2]
+    bias = jnp.where(jnp.asarray(rng.integers(0, 2, (B, S)))[:, None, None, :]
+                     > 0, 0.0, ra.NEG_INF).astype(jnp.float32)
+    do = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
+    if lse_covers == "these_keys":
+        out, lse = fa.flash_forward(q, k, v, bias, interpret=True)
+    else:
+        all_k = jnp.concatenate([k, more_k], axis=2)
+        all_v = jnp.concatenate([v, more_v], axis=2)
+        all_bias = jnp.concatenate([bias, jnp.zeros_like(bias)], axis=3)
+        out, lse = fa.flash_forward(q, all_k, all_v, all_bias,
+                                    interpret=True)
+    assert fa._one_block(S, S, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
+    one = fa.flash_backward(q, k, v, bias, out, lse, do, interpret=True)
+    two = fa.flash_backward(q, k, v, bias, out, lse, do, 16, 16, True)
+    for got, want in zip(one, two):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def _attention_counts():
+    from ray_shuffling_data_loader_tpu.runtime import metrics
+    counts = {}
+    for kind in ("flash", "inline"):
+        traced = metrics.get("rsdl_bert_attention_total", {"kind": kind})
+        counts[kind] = 0 if traced is None else traced.value
+    return counts
+
+
+def _two_head_bert(seq, layers=2, dtype=jnp.float32):
+    config = bert.BertConfig(vocab_size=64, hidden_dim=128, num_layers=layers,
+                             num_heads=2, ffn_dim=64, max_seq_len=seq,
+                             compute_dtype=dtype)
+    return config, bert.init(config, jax.random.key(0))
+
+
+def _pallas_calls(jaxpr) -> int:
+    """``pallas_call`` equations in ``jaxpr`` and the programs it calls."""
+    return sum(
+        1 if eqn.primitive.name == "pallas_call" else sum(
+            _pallas_calls(sub)
+            for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("chip,seq,masked,hook,want", [
+    (False, 512, False, False, "inline"),   # every CPU run
+    (True, 128, False, False, "inline"),    # under the crossover
+    (True, 512, False, False, "flash"),
+    (True, 512, True, False, "flash"),      # a key-side bias
+    (True, 512, False, True, None),         # a caller's attention_fn wins
+])
+def test_encode_chooses_its_attention(monkeypatch, chip, seq, masked, hook,
+                                      want):
+    """``attention_fn=None``: the kernels on the chip from the measured
+    sequence length up, XLA's inline attention elsewhere; one count a
+    layer traced, by kind."""
+    config, params = _two_head_bert(seq)
+    monkeypatch.setattr(fa, "on_tpu", lambda: chip)
+    tokens = jnp.zeros((2, seq), jnp.int32)
+    mask = jnp.ones((2, seq), jnp.int32) if masked else None
+    calls = []
+
+    def attention_fn(q, k, v, bias):
+        calls.append(q.shape)
+        return q
+
+    before = _attention_counts()
+    jaxpr = jax.make_jaxpr(lambda p: bert.encode(
+        config, p, tokens, mask, attention_fn if hook else None))(params)
+    after = _attention_counts()
+    traced = {kind: after[kind] - before[kind] for kind in after}
+    assert traced == {kind: config.num_layers * (kind == want)
+                      for kind in traced}
+    assert len(calls) == config.num_layers * hook
+    assert _pallas_calls(jaxpr.jaxpr) == config.num_layers * (
+        want == "flash")
+
+
+def _shapes_outside_kernels(jaxpr, found):
+    """Every array shape in ``jaxpr`` and the programs it calls, a
+    ``pallas_call``'s own body left out."""
+    for eqn in jaxpr.eqns:
+        for var in (*eqn.invars, *eqn.outvars):
+            found.add(getattr(var.aval, "shape", ()))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _shapes_outside_kernels(sub, found)
+    return found
+
+
+def test_the_losses_gradient_holds_no_scores_outside_the_kernels(
+        monkeypatch):
+    """With the kernels taken nothing (B, H, S, S) is left in the loss's
+    gradient but inside the ``pallas_call``s; with the inline path it is."""
+    seq, batch = 512, 2
+    config, params = _two_head_bert(seq, layers=1, dtype=jnp.bfloat16)
+    tokens = jnp.zeros((batch, seq), jnp.int32)
+
+    def shapes():
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: bert.loss_fn(config, p, tokens, tokens)))(params)
+        return _shapes_outside_kernels(jaxpr.jaxpr, set())
+
+    scores = (batch, config.num_heads, seq, seq)
+    assert scores in shapes()
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    with_kernels = shapes()
+    assert not [s for s in with_kernels if s[-2:] == (seq, seq)]
+    assert (batch, seq, 3 * config.hidden_dim) in with_kernels
+
+
+def test_flash_bert_loss_on_a_data_mesh_equals_one_device(rng, monkeypatch):
+    """A step jitted over four devices tells ``loss_fn`` its mesh: the
+    kernels run once a shard of the batch under ``shard_map`` and the loss
+    and gradients are the one-device ones (and the inline path's)."""
+    from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
+    seq = 128
+    config, params = _two_head_bert(seq, layers=1)
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "FLASH_MIN_SEQ_LEN", seq)
+    tokens = jnp.asarray(rng.integers(0, 64, (8, seq)), jnp.int32)
+    targets = jnp.where(jnp.asarray(rng.random((8, seq)) < 0.15), tokens,
+                        bert.IGNORE_ID)
+    mask = jnp.asarray(rng.integers(0, 4, (8, seq)) > 0, jnp.int32)
+
+    def graded(mesh, attention_fn=None):
+        return jax.jit(jax.value_and_grad(lambda p, t, y, m: bert.loss_fn(
+            config, p, t, y, m, attention_fn, mesh)))
+
+    before = _attention_counts()
+    want_loss, want_grads = graded(None)(params, tokens, targets, mask)
+    mesh = mesh_mod.make_mesh(num_devices=4)
+    sharded = mesh_mod.batch_sharding(mesh)
+    got_loss, got_grads = graded(mesh)(
+        params, *(jax.device_put(a, sharded) for a in (tokens, targets, mask)))
+    assert _attention_counts()["flash"] - before["flash"] == 2
+    monkeypatch.setattr(fa, "on_tpu", lambda: False)
+    inline_loss, inline_grads = graded(None)(params, tokens, targets, mask)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    np.testing.assert_allclose(float(got_loss), float(inline_loss),
+                               rtol=1e-5)
+    for got, want, inline in zip(*(jax.tree.leaves(g) for g in (
+            got_grads, want_grads, inline_grads))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(inline),
+                                   rtol=5e-3, atol=2e-5)

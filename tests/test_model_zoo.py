@@ -252,33 +252,63 @@ def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
 
 
-def test_the_benchmarks_mlm_head_pct_reads_the_models_scope():
-    """``mlm_head_pct`` is data: the scope reader ``grad_exchange_pct``
-    uses, pointed at the scope the model names, in ``bert_train`` alone;
-    a program without the scope (the parent's side) gives it nothing."""
+@pytest.mark.parametrize("name,scope,layer", [
+    ("mlm_head_pct", bert.MLM_HEAD_SCOPE, "model"),
+    ("attention_pct", bert.ATTENTION_SCOPE, "kernels"),
+])
+def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
+                                                            layer):
+    """``mlm_head_pct`` (PR 29) and ``attention_pct`` (PR 31) are data:
+    the scope reader ``grad_exchange_pct`` uses, pointed at a scope the
+    model names, in ``bert_train`` alone; a program without the scope (the
+    parent's side) gives it nothing."""
     from chipbench import manifest
     bench = manifest.load_manifest()
-    entry = next(m for m in bench["per_layer"] if m["name"] == "mlm_head_pct")
-    assert entry == bench["per_layer"][-1]
-    assert entry == {"name": "mlm_head_pct", "unit": "%", "better": "lower",
-                     "source": "device_trace", "layer": "model",
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry in bench["per_layer"][-2:]
+    assert entry == {"name": name, "unit": "%", "better": "lower",
+                     "source": "device_trace", "layer": layer,
                      "moves": "train_rows_per_s", "workloads": ["bert_train"]}
     with open(os.path.join(manifest.BENCH_DIR, "layers",
-                           "mlm_head_pct.json")) as f:
-        layer = json.load(f)
+                           f"{name}.json")) as f:
+        reads = json.load(f)
     with open(os.path.join(manifest.BENCH_DIR, "layers",
                            "grad_exchange_pct.json")) as f:
         exchange = json.load(f)
-    assert layer["args"].pop("scope") == bert.MLM_HEAD_SCOPE
-    assert exchange["args"].pop("scope") != bert.MLM_HEAD_SCOPE
-    assert layer == exchange
+    assert reads["args"].pop("scope") == scope
+    assert exchange["args"].pop("scope") != scope
+    assert reads == exchange
     for cell in bench["workloads"]:
         reported = {m["name"]
                     for m in manifest.resolve_cell(cell["name"]).per_layer}
-        assert ("mlm_head_pct" in reported) == (cell["name"] == "bert_train")
-    reader = manifest.layer_reader("mlm_head_pct")
+        assert (name in reported) == (cell["name"] == "bert_train")
+    reader = manifest.layer_reader(name)
     assert reader({"trace": None}) is None
     assert reader({"trace": object(), "step_op_names": {}}) is None
+
+
+def test_bert_attention_runs_under_its_scope():
+    """Both attentions are programs of their own, so the scope reaches a
+    compiled step as written, forward and backward, where a reader of the
+    device trace finds it (entered straight under ``grad`` it would read
+    ``jvp(rsdl.bert.attention)``)."""
+    from chipbench import xplane
+    cfg = bert.BertConfig(vocab_size=64, hidden_dim=128, num_layers=1,
+                          num_heads=2, ffn_dim=64, max_seq_len=128,
+                          compute_dtype=jnp.float32)
+    params = bert.init(cfg, jax.random.key(0))
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    qkv = jnp.zeros((2, 128, 3 * 128), jnp.float32)
+    inline = jax.jit(jax.grad(
+        lambda p: bert.loss_fn(cfg, p, tokens, tokens))).lower(
+            params).compile().as_text()
+    flash = jax.jit(jax.grad(lambda x: jnp.sum(
+        bert._flash_attention(x, None, 2)))).lower(qkv).compile().as_text()
+    for text in (inline, flash):
+        under = [op_name for op_name in xplane.hlo_op_names(text).values()
+                 if xplane.under_scope(op_name, bert.ATTENTION_SCOPE)]
+        assert any("transpose(" in op_name for op_name in under), under
+        assert any("transpose(" not in op_name for op_name in under), under
 
 
 def test_bert_specs_match_tree():
