@@ -7,7 +7,11 @@ full width of one model the repo supports, and checks what comes out:
 1. kernels: the Pallas embedding gather (bit-equal to XLA ``take`` at the
    largest DLRM table) and flash attention forward + backward (against
    the plain-XLA reference, at an aligned and at a padded sequence
-   length), compiled by Mosaic — not interpreted;
+   length; and the decoder's path: eight query heads of 128 over one
+   key/value head, causal and under a window, off their projections),
+   compiled by Mosaic — not interpreted; the sparse-expert layer's walk
+   (``ops/moe.py``) against its float32 loop of dense products at the
+   decoder's widths;
 2. loader -> device feed -> train step: seeded DLRM Parquet
    (``data_generation.generate_data``) through ``JaxShufflingDataset`` at
    library defaults into ``parallel.trainer.SpmdTrainer`` over
@@ -75,6 +79,13 @@ class SmokeSize:
     gather_batch: int
     attention_shape: Tuple[int, int, int]  # (batch, heads, head_dim)
     attention_seqs: Tuple[int, ...]   # in blocks, padded, one block
+    # the decoder's attention: (heads, key/value heads, head_dim), the
+    # windows (None: the whole triangle) and the lengths it is checked at
+    masked_attention: Tuple[int, int, int]
+    masked_attention_windows: Tuple[Optional[int], ...]
+    masked_attention_seqs: Tuple[int, ...]   # in blocks, padded
+    # its expert layer: (tokens, hidden, width, experts, held, top_k, tile)
+    moe_shape: Tuple[int, int, int, int, int, int, int]
     epochs: int = 2
 
 
@@ -86,7 +97,11 @@ def full_size() -> SmokeSize:
         learning_rate=1e-3,
         gather_table=(max(cfg.vocab_sizes), cfg.embed_dim),
         gather_batch=2048, attention_shape=(2, 4, 64),
-        attention_seqs=(2048, 1000, 512))
+        attention_seqs=(2048, 1000, 512),
+        masked_attention=(8, 1, 128),
+        masked_attention_windows=(None, 1024),
+        masked_attention_seqs=(4096, 1000),
+        moe_shape=(8192, 2304, 896, 64, 16, 8, 1152))
 
 
 def tiny_size() -> SmokeSize:
@@ -99,7 +114,10 @@ def tiny_size() -> SmokeSize:
     return SmokeSize(
         model=cfg, batch_per_device=16, steps_per_epoch=8, num_files=2,
         learning_rate=1e-2, gather_table=(4096, 128), gather_batch=64,
-        attention_shape=(1, 2, 32), attention_seqs=(256, 200, 64))
+        attention_shape=(1, 2, 32), attention_seqs=(256, 200, 64),
+        masked_attention=(4, 1, 16), masked_attention_windows=(24,),
+        masked_attention_seqs=(40,),
+        moe_shape=(48, 16, 8, 8, 2, 2, 8))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -110,19 +128,31 @@ def _mosaic_calls(jitted, *args) -> int:
     return jitted.lower(*args).as_text().count("tpu_custom_call")
 
 
-def _plain_attention(q, k, v):
-    """Reference XLA attention in float32: full (B, H, S, S) scores."""
+def _plain_attention(q, k, v, causal: bool = False,
+                     window: Optional[int] = None):
+    """Reference XLA attention in float32: full (B, H, S, S) scores; ``k``
+    and ``v`` of H heads or of a divisor of H."""
     import jax
     import jax.numpy as jnp
     scale = q.shape[-1] ** -0.5
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x.astype(jnp.float32), group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k) * scale
+    if causal:
+        ahead = (jnp.arange(s.shape[-2])[:, None]
+                 - jnp.arange(s.shape[-1])[None, :])
+        seen = ahead >= 0 if window is None else (ahead >= 0) & (
+            ahead < window)
+        s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
 def _check_attention(flash, seq_len: int, b: int, h: int, d: int,
-                     fwd_tol: float = 2e-2, grad_tol: float = 2e-2) -> None:
+                     fwd_tol: float = 2e-2, grad_tol: float = 2e-2,
+                     kv_heads: Optional[int] = None, plain=None,
+                     what: str = "flash attention",
+                     relative: bool = False) -> None:
     """Max-abs-error of the compiled flash forward AND backward against
     the float32 XLA reference, checked, not just printed.
 
@@ -130,22 +160,27 @@ def _check_attention(flash, seq_len: int, b: int, h: int, d: int,
     Mosaic-compiled kernel's numerics on the device (bf16 inputs, fp32
     accumulation: the tolerance is the bf16 resolution bound that
     tests/test_flash_attention.py uses for bf16 inputs). Errors are
-    computed on the device and fetched as scalars.
+    computed on the device and fetched as scalars; ``relative`` takes each
+    as a share of its reference's largest magnitude (a key/value head's
+    gradient sums eight query heads': bf16 resolves it more coarsely).
     """
     import jax
     import jax.numpy as jnp
 
     shape = (b, h, seq_len, d)
+    kv_shape = (b, kv_heads or h, seq_len, d)
+    plain = plain or _plain_attention
     kq, kk, kv = jax.random.split(jax.random.key(42), 3)
     q = jax.random.normal(kq, shape, jnp.bfloat16)
-    k = jax.random.normal(kk, shape, jnp.bfloat16)
-    v = jax.random.normal(kv, shape, jnp.bfloat16)
+    k = jax.random.normal(kk, kv_shape, jnp.bfloat16)
+    v = jax.random.normal(kv, kv_shape, jnp.bfloat16)
 
     @jax.jit
     def errors(q, k, v):
         out_f = flash(q, k, v).astype(jnp.float32)
-        out_r = _plain_attention(q, k, v)
-        fwd_err = jnp.max(jnp.abs(out_f - out_r))
+        out_r = plain(q, k, v)
+        fwd_err = jnp.max(jnp.abs(out_f - out_r)) / (
+            jnp.max(jnp.abs(out_r)) if relative else 1.0)
         # Grads of a non-trivial scalar (weighted sum keeps the cotangent
         # dense and non-uniform) through both implementations.
         w = jax.random.normal(jax.random.key(7), shape, jnp.float32)
@@ -154,23 +189,23 @@ def _check_attention(flash, seq_len: int, b: int, h: int, d: int,
             return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
 
         gf = jax.grad(functools.partial(loss, flash), (0, 1, 2))(q, k, v)
-        gr = jax.grad(functools.partial(loss, _plain_attention),
-                      (0, 1, 2))(q, k, v)
+        gr = jax.grad(functools.partial(loss, plain), (0, 1, 2))(q, k, v)
         grad_err = jnp.max(jnp.asarray(
             [jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))
+             / (jnp.max(jnp.abs(b)) if relative else 1.0)
              for a, b in zip(gf, gr)]))
         return fwd_err, grad_err
 
     fwd_err, grad_err = (float(x) for x in errors(q, k, v))
-    _info(f"kernels: flash attention S={seq_len} max|flash-xla| fwd "
+    _info(f"kernels: {what} S={seq_len} max|flash-xla| fwd "
           f"{fwd_err:.3e} (tol {fwd_tol:.0e}), grad {grad_err:.3e} "
           f"(tol {grad_tol:.0e})")
     _check(fwd_err <= fwd_tol,
-           f"flash forward differs from the XLA reference at S={seq_len}: "
-           f"{fwd_err} > {fwd_tol}")
+           f"{what}: forward differs from the XLA reference at "
+           f"S={seq_len}: {fwd_err} > {fwd_tol}")
     _check(grad_err <= grad_tol,
-           f"flash backward differs from the XLA reference at S={seq_len}: "
-           f"{grad_err} > {grad_tol}")
+           f"{what}: backward differs from the XLA reference at "
+           f"S={seq_len}: {grad_err} > {grad_tol}")
 
 
 def kernels_phase(size: SmokeSize, interpret: bool) -> None:
@@ -211,6 +246,105 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
                    f"flash attention at S={seq} did not lower to a Mosaic "
                    "kernel")
         _check_attention(flash, seq, b, h, d)
+    _masked_attention_checks(size, interpret)
+    _check_moe(size.moe_shape)
+
+
+def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
+    """The decoder's attention off its projections (B, S, H x D): grouped
+    heads, causal over the row and under a window, at a length in blocks
+    and at a padded one, against the masked float32 softmax."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+
+    heads, kv_heads, d = size.masked_attention
+
+    def packed(x):      # (B, H, S, D) -> (B, S, H x D), and back
+        b, h, s, _ = x.shape
+        return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+    def unpacked(x, h):
+        b, s, _ = x.shape
+        return x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+
+    for seq in size.masked_attention_seqs:
+        for span in size.masked_attention_windows:
+            # the decoder's own attention: its kernels, tiles and custom_vjp
+            def flash(q, k, v):
+                return unpacked(mellum._flash_attention(
+                    packed(q), packed(k), packed(v), heads, kv_heads, span),
+                    heads)
+
+            if not interpret:
+                probe = jnp.zeros((1, heads, seq, d), jnp.bfloat16)
+                kv_probe = jnp.zeros((1, kv_heads, seq, d), jnp.bfloat16)
+                _check(_mosaic_calls(jax.jit(flash), probe, kv_probe,
+                                     kv_probe) == 1,
+                       f"masked attention at S={seq} did not lower to a "
+                       "Mosaic kernel")
+            _check_attention(
+                flash, seq, 1, heads, d, kv_heads=kv_heads, relative=True,
+                plain=functools.partial(_plain_attention, causal=True,
+                                        window=span),
+                what=(f"{heads}:{kv_heads} heads of {d}, causal"
+                      + (f", window {span}" if span else "")))
+
+
+def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
+    """The expert layer's walk (bf16 operands, tiles of one expert) against
+    the plain float32 loop of dense products under the routing's weights,
+    forward and the gradients of the tokens and of two of the weights,
+    as shares of each one's largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.ops import moe
+
+    tokens, hidden, width, experts, held, top_k, tile = shape
+    keys = jax.random.split(jax.random.key(11), 6)
+    x = jax.random.normal(keys[0], (tokens, hidden), jnp.bfloat16)
+    router = 0.02 * jax.random.normal(keys[1], (hidden, experts))
+    gate, up = (0.02 * jax.random.normal(k, (held, hidden, width))
+                for k in keys[2:4])
+    down = 0.02 * jax.random.normal(keys[4], (held, width, hidden))
+    mix = jax.random.normal(keys[5], (tokens, hidden))
+
+    def walked(x, router, gate, up, down):
+        return moe.moe(x, router, gate, up, down, (0, held), top_k, tile)
+
+    def plain(x, router, gate, up, down):
+        with jax.default_matmul_precision("highest"):
+            xf = x.astype(jnp.float32)
+            ids, weights = moe.route(xf @ router, top_k)
+            out = jnp.zeros_like(xf)
+            for e in range(held):
+                w = jnp.sum(jnp.where(ids == e, weights, 0.0), axis=-1,
+                            keepdims=True)
+                out += w * ((jax.nn.silu(xf @ gate[e]) * (xf @ up[e]))
+                            @ down[e])
+            return out
+
+    @jax.jit
+    def errors(*args):
+        def loss(f, *a):
+            return jnp.sum(f(*a).astype(jnp.float32) * mix)
+
+        got = (walked(*args), *jax.grad(
+            functools.partial(loss, walked), (0, 2, 4))(*args))
+        want = (plain(*args), *jax.grad(
+            functools.partial(loss, plain), (0, 2, 4))(*args))
+        return [jnp.max(jnp.abs(g.astype(jnp.float32) - w))
+                / jnp.max(jnp.abs(w)) for g, w in zip(got, want)]
+
+    errs = [float(e) for e in errors(x, router, gate, up, down)]
+    _info(f"kernels: expert layer {tokens} tokens x {hidden}, {held} of "
+          f"{experts} experts of {width}, top-{top_k}, tiles of {tile}: "
+          "max|walk-f32| / max|f32| out, d tokens, d gate, d down "
+          + ", ".join(f"{e:.3e}" for e in errs) + f" (tol {tol:.0e})")
+    _check(max(errs) <= tol, "the expert layer's walk differs from the "
+           f"float32 loop of dense products: {errs} > {tol}")
 
 
 # -- loader -> device feed -> train step -------------------------------------

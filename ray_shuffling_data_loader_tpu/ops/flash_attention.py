@@ -25,7 +25,14 @@ Two families, chosen by shape at trace time:
   ``_dkv_kernel``): grid (B, H, q-blocks, k-blocks) with the inner
   dimension carrying running max / denominator / accumulator in VMEM
   scratch; the backward is two kernels (dq streaming K; dk/dv(+dbias)
-  streaming Q) that each make s and p again.
+  streaming Q) that each make s and p again. This family takes a
+  structural mask, ``causal`` and ``window`` as static arguments (the
+  inner dimension runs over the blocks some query of the outer block
+  sees and no further; only the diagonal's and the window edge's blocks
+  compare positions), query heads in groups over fewer key/value heads
+  (fetched once a block, repeated nowhere), and heads of whole lanes
+  (128) cut straight from (B, S, H x D) projections (``grouped_forward``
+  / ``grouped_backward``). This is what ``models/mellum.py`` takes.
 
 Both take a key-side bias (B, 1, 1, S) and an lse that may cover more keys
 than the call holds (ring attention's hops, ops/ring_attention.py). Mosaic
@@ -38,7 +45,11 @@ layer's attention, 16,384 tokens, 12 heads of 64, bf16, in a ``lax.scan``):
 at S = 512 these kernels off the fused projection take 1.44-1.54 ms where
 the inline path takes 4.58, the kernels as PR 30 left them (float32
 operands, two-kernel backward, head-major copies) 3.62 and JAX's bundled
-``pallas.ops.tpu.flash_attention`` 4.97.
+``pallas.ops.tpu.flash_attention`` 4.97. The blocked family under a
+mask (PR 32; forward / backward of one layer, 4 rows of 8,192 tokens, 32
+query heads over 4 key/value heads of 128, bf16, ms): the whole triangle in
+1024 x 1024 tiles 22.1 / 61.6 (112 / 140 TFLOP/s over the live tiles), a
+window of 1,024 in 1024 x 1024 tiles 10.1 / 29.0, in 512 x 512 12.9 / 27.6.
 """
 
 from __future__ import annotations
@@ -210,117 +221,207 @@ def _bwd1_kernel(*refs, scale: float, heads: int, has_delta: bool,
 
 
 # -- kernels: the sequence in blocks -----------------------------------------
+#
+# A grid step sees one (bq, D) block of one head's queries and one (bk, D)
+# block of its keys, whatever arrays they are cut from (``_rows``); lse and
+# delta are (bq, 1) columns. ``group`` query heads read one key/value head
+# (the specs divide the head's index: nothing is repeated in HBM).
+#
+# The structural mask (``_Mask``) is static. The innermost grid dimension
+# runs over the blocks that some query of the outer block can see, counted
+# from the first one (``_k_span`` / ``_q_span``): a window's grid is as
+# long as its band, not as the sequence. A step past the outer block's
+# last live block does nothing, and its specs name the block already
+# resident, so nothing is fetched for it either. A live block that every
+# query of it sees whole runs unmasked; the diagonal's and the window
+# edge's blocks compare positions.
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale: float):
-    """Grid (B, H, num_q, num_k), K innermost. Blocks: q/o (1,1,bq,D);
-    k/v (1,1,bk,D); bias (1,1,1,bk) or absent; lse (1,1,bq,1). Scratch
-    m/l (bq,1), acc (bq,D) persist across the K iterations of one
-    q-block."""
-    j = pl.program_id(3)
-    num_k = pl.num_programs(3)
+class _Mask(NamedTuple):
+    """Which keys a query sees: all (the default), those at or before it
+    (``causal``), and of those the last ``window`` (itself included)."""
+    causal: bool = False
+    window: Optional[int] = None
 
-    @pl.when(j == 0)
+
+def _mask_of(causal: bool, window: Optional[int]) -> _Mask:
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is a causal mask's: pass causal=True and "
+                         f"a window of at least 1 (got {causal=}, {window=})")
+    return _Mask(bool(causal), window)
+
+
+def _k_span(mask: _Mask, g, bq: int, bk: int, num_k: int, lo=min, hi=max):
+    """(first, last) key block some query of query block ``g`` sees."""
+    if not mask.causal:
+        return 0, num_k - 1
+    first = (0 if mask.window is None
+             else hi(g * bq - mask.window + 1, 0) // bk)
+    return first, lo(((g + 1) * bq - 1) // bk, num_k - 1)
+
+
+def _q_span(mask: _Mask, t, bq: int, bk: int, num_q: int, lo=min, hi=max):
+    """(first, last) query block that sees some key of key block ``t``."""
+    if not mask.causal:
+        return 0, num_q - 1
+    last = (num_q - 1 if mask.window is None
+            else lo(((t + 1) * bk + mask.window - 2) // bq, num_q - 1))
+    return lo((t * bk) // bq, num_q - 1), last
+
+
+def _longest(span, count: int) -> int:
+    """The longest ``span(block)`` over ``count`` outer blocks: the inner
+    grid dimension's extent."""
+    return max(last - first + 1 for first, last in map(span, range(count)))
+
+
+def _seen(mask: _Mask, g, kb, bq: int, bk: int):
+    """(bq, bk) booleans: query g * bq + r sees key kb * bk + c."""
+    ahead = (g * bq - kb * bk
+             + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+             - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    seen = ahead >= 0
+    return seen if mask.window is None else seen & (ahead < mask.window)
+
+
+def _on_live(mask: _Mask, g, kb, live, bq: int, bk: int, step) -> None:
+    """Run ``step(masked)`` for the (g, kb) block if it is live: unmasked
+    where every query of it sees every key of it."""
+    if not mask.causal:
+        step(False)
+        return
+    whole = (kb + 1) * bk - 1 <= g * bq
+    if mask.window is not None:
+        whole &= (g + 1) * bq - 1 - kb * bk < mask.window
+    pl.when(live & whole)(lambda: step(False))
+    pl.when(live & jnp.logical_not(whole))(lambda: step(True))
+
+
+def _fwd_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
+                bk: int, num_k: int, steps: int):
+    """Grid (B, H, num_q, steps), keys innermost. Blocks: q/o (bq, D);
+    k/v (bk, D); bias (1, bk) or absent; lse (bq, 1). Scratch m/l (bq, 1),
+    acc (bq, D) persist across the steps of one q-block."""
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[3 + has_bias:]
+    g, t = pl.program_id(2), pl.program_id(3)
+    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
+    kb = first + t
+
+    @pl.when(t == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]  # (bq, D), (bk, D)
-    s = _dot(q, k, _NT) * scale                      # (bq, bk)
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0, 0][None, :]
-    m_prev, l_prev = m_scr[:], l_scr[:]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    m_scr[:] = m_new
-    l_scr[:] = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * corr + _dot(p.astype(v.dtype), v, _NN)
+    def step(masked: bool):
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]     # (bq, D), (bk, D)
+        s = _dot(q, k, _NT) * scale                      # (bq, bk)
+        if has_bias:
+            s = s + bias_ref[...]
+        if masked:
+            # A query that sees nothing of this block adds weights of 1 at
+            # the running maximum's start; the diagonal's block, which
+            # comes after, scales them away (corr = exp(_NEG - m) = 0).
+            s = jnp.where(_seen(mask, g, kb, bq, bk), s, _NEG)
+        m_prev, l_prev = m_scr[:], l_scr[:]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + _dot(p.astype(v.dtype), v, _NN)
 
-    @pl.when(j == num_k - 1)
+    _on_live(mask, g, kb, kb <= last, bq, bk, step)
+
+    @pl.when(t == steps - 1)
     def _finish():
         l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:] + jnp.log(l)
+        o_ref[...] = (acc_scr[:] / l).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[:] + jnp.log(l)
 
 
-def _fwd_kernel_nobias(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       m_scr, l_scr, acc_scr, *, scale: float):
-    _fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, scale=scale)
+def _dq_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
+               bk: int, num_k: int, steps: int):
+    """Grid (B, H, num_q, steps), keys innermost: dq for one q-block."""
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs[3 + has_bias:]
+    g, t = pl.program_id(2), pl.program_id(3)
+    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
+    kb = first + t
 
-
-def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, scale: float):
-    """Grid (B, H, num_q, num_k), K innermost: dq for one q-block."""
-    j = pl.program_id(3)
-    num_k = pl.num_programs(3)
-
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-    s = _dot(q, k, _NT) * scale
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0, 0][None, :]
-    p = jnp.exp(s - lse_ref[0, 0])                   # softmax weights
-    dp = _dot(do, v, _NT)                            # (bq, bk)
-    ds = p * (dp - delta_ref[0, 0])
-    dq_scr[:] = dq_scr[:] + _dot(ds.astype(k.dtype), k, _NN) * scale
+    def step(masked: bool):
+        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+        s = _dot(q, k, _NT) * scale
+        if has_bias:
+            s = s + bias_ref[...]
+        if masked:
+            s = jnp.where(_seen(mask, g, kb, bq, bk), s, _NEG)
+        p = jnp.exp(s - lse_ref[...])                    # softmax weights
+        dp = _dot(do, v, _NT)                            # (bq, bk)
+        ds = p * (dp - delta_ref[...])
+        dq_scr[:] = dq_scr[:] + _dot(ds.astype(k.dtype), k, _NN) * scale
 
-    @pl.when(j == num_k - 1)
+    _on_live(mask, g, kb, kb <= last, bq, bk, step)
+
+    @pl.when(t == steps - 1)
     def _finish():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dq_kernel_nobias(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dq_scr, *, scale: float):
-    _dq_kernel(q_ref, k_ref, v_ref, None, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, scale=scale)
+def _dkv_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
+                bk: int, num_q: int, live_q: int, steps: int):
+    """Grid (B, Hkv, num_k, steps), queries innermost: dk/dv/dbias for one
+    k-block, over the ``steps // live_q`` query heads that read this
+    key/value head, ``live_q`` query blocks each. dbias is emitted per
+    key/value head (summed over heads by the caller)."""
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref = refs[3 + has_bias:][:5]
+    rest = refs[8 + has_bias:]
+    dbias_ref = rest[0] if has_bias else None
+    dk_scr, dv_scr = rest[has_bias:][:2]
+    dbias_scr = rest[has_bias + 2] if has_bias else None
+    t, u = pl.program_id(2), pl.program_id(3)
+    first, last = _q_span(mask, t, bq, bk, num_q, jnp.minimum, jnp.maximum)
+    g = first + u % live_q
 
-
-def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dbias_ref, dk_scr, dv_scr, dbias_scr, *,
-                scale: float):
-    """Grid (B, H, num_k, num_q), Q innermost: dk/dv/dbias for one
-    k-block. dbias is emitted per-head (summed over heads by the caller)."""
-    j = pl.program_id(3)
-    num_q = pl.num_programs(3)
-
-    @pl.when(j == 0)
+    @pl.when(u == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
-        if dbias_scr is not None:
+        if has_bias:
             dbias_scr[:] = jnp.zeros_like(dbias_scr)
 
-    q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-    s = _dot(q, k, _NT) * scale
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0, 0][None, :]
-    p = jnp.exp(s - lse_ref[0, 0])                   # (bq, bk)
-    dv_scr[:] = dv_scr[:] + _dot(p.astype(do.dtype), do, _TN)
-    dp = _dot(do, v, _NT)
-    ds = p * (dp - delta_ref[0, 0])
-    dk_scr[:] = dk_scr[:] + _dot(ds.astype(q.dtype), q, _TN) * scale
-    if dbias_scr is not None:
-        dbias_scr[:] = dbias_scr[:] + ds.sum(axis=0, keepdims=True)
+    def step(masked: bool):
+        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+        s = _dot(q, k, _NT) * scale
+        if has_bias:
+            s = s + bias_ref[...]
+        if masked:
+            s = jnp.where(_seen(mask, g, t, bq, bk), s, _NEG)
+        p = jnp.exp(s - lse_ref[...])                    # (bq, bk)
+        dv_scr[:] = dv_scr[:] + _dot(p.astype(do.dtype), do, _TN)
+        dp = _dot(do, v, _NT)
+        ds = p * (dp - delta_ref[...])
+        dk_scr[:] = dk_scr[:] + _dot(ds.astype(q.dtype), q, _TN) * scale
+        if has_bias:
+            dbias_scr[:] = dbias_scr[:] + ds.sum(axis=0, keepdims=True)
 
-    @pl.when(j == num_q - 1)
+    _on_live(mask, g, t, g <= last, bq, bk, step)
+
+    @pl.when(u == steps - 1)
     def _finish():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
-        if dbias_ref is not None:
-            dbias_ref[0, 0, 0] = dbias_scr[0]
-
-
-def _dkv_kernel_nobias(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float):
-    _dkv_kernel(q_ref, k_ref, v_ref, None, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, None, dk_scr, dv_scr, None, scale=scale)
+        dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
+        if has_bias:
+            dbias_ref[...] = dbias_scr[:]
 
 
 # -- block planning & padding ----------------------------------------------
@@ -394,8 +495,9 @@ def _prep_bias(bias, b: int, sk: int, sk_pad: int):
     if bias is not None and bias.shape != (b, 1, 1, sk):
         raise ValueError(
             f"flash_attention bias must be key-side (B, 1, 1, S) = "
-            f"{(b, 1, 1, sk)}, got {bias.shape}; full (.., S, S) biases "
-            "(e.g. causal masks) are not supported by this kernel")
+            f"{(b, 1, 1, sk)}, got {bias.shape}; a full (.., S, S) bias is "
+            "not supported: a causal mask or a window is structural, the "
+            "``causal`` / ``window`` arguments, not a tensor")
     if bias is None and sk_pad == sk:
         return None
     base = (jnp.zeros((b, 1, 1, sk), jnp.float32) if bias is None
@@ -539,50 +641,101 @@ def _backward1(layout: _Layout, q, k, v, do, lse, delta, bias, sq: int,
     return results[0], results[1], results[2], dbias
 
 
-# -- forward / backward dispatch --------------------------------------------
+# -- the sequence in blocks: layouts and launches ------------------------------
 
 
-def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
-                   interpret: bool):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    if _one_block(sq, sk, block_q, block_k):
-        sq_pad = _pad_one_block(sq, interpret)
-        sk_pad = _pad_one_block(sk, interpret)
-        out, lse = _forward1(
-            _bhsd_layout(b, h, d), _pad_rows(q, sq_pad), _pad_rows(k, sk_pad),
-            _pad_rows(v, sk_pad), _prep_bias(bias, b, sk, sk_pad), sq_pad,
-            sk_pad, interpret)
-        return out[:, :, :sq], lse.reshape(b, h, sq_pad, 1)[:, :, :sq]
+def _rows(packed: bool, n: int, d: int, index):
+    """BlockSpec of an (n, D) block of one head's rows, cut from a
+    (B, H, S, D) array or, ``packed``, from (B, S, H x D) as a projection
+    leaves it (D whole lanes); ``index(*grid ids) -> (batch, head, row
+    block)``."""
+    if packed:
+        def at(*ids):
+            batch, head, block = index(*ids)
+            return batch, block, head
+        return pl.BlockSpec((None, n, d), at)
+    return pl.BlockSpec((None, None, n, d), lambda *ids: (*index(*ids), 0))
+
+
+def _stats(n: int, index):
+    """lse / delta, (B, H, S, 1) float32: an (n, 1) column."""
+    return pl.BlockSpec((None, None, n, 1), lambda *ids: (*index(*ids), 0))
+
+
+def _dims(packed: bool, q, k, num_heads: Optional[int],
+          num_kv_heads: Optional[int]):
+    """(B, H, Hkv, Sq, Sk, D) of the operands in either layout."""
+    if not packed:
+        (b, h, sq, d), (_, hkv, sk, _) = q.shape, k.shape
+    else:
+        (b, sq, width), sk = q.shape, k.shape[1]
+        h, hkv, d = num_heads, num_kv_heads, width // num_heads
+    if h % hkv:
+        raise ValueError(f"{h} query heads do not share {hkv} key/value "
+                         "heads evenly")
+    return b, h, hkv, sq, sk, d
+
+
+def _blocked_plan(mask: _Mask, bias, b: int, sq: int, sk: int, block_q: int,
+                  block_k: int, interpret: bool):
+    """(bq, bk, sq_pad, sk_pad, bias array or None) of a blocked launch. A
+    causal mask is self-attention's: a padded key lies after every real
+    query and needs no bias to hide it."""
+    if mask.causal and sq != sk:
+        raise ValueError(f"a causal mask needs as many queries as keys, "
+                         f"got {sq} and {sk}")
     bq, bk, sq_pad, sk_pad = _plan(sq, sk, block_q, block_k, interpret)
-    scale = 1.0 / (d ** 0.5)
-    bias_arr = _prep_bias(bias, b, sk, sk_pad)
-    qp = _pad_rows(q, sq_pad)
-    kp, vp = _pad_rows(k, sk_pad), _pad_rows(v, sk_pad)
-    grid = (b, h, sq_pad // bq, sk_pad // bk)
+    bias_arr = (None if bias is None and mask.causal
+                else _prep_bias(bias, b, sk, sk_pad))
+    return bq, bk, sq_pad, sk_pad, bias_arr
 
-    in_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda i, j, g, t: (i, j, g, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda i, j, g, t: (i, j, t, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda i, j, g, t: (i, j, t, 0)),
-    ]
+
+def _query_major(mask: _Mask, group: int, bq: int, bk: int, num_k: int):
+    """Index maps of a (B, H, num_q, live key blocks) grid: ``(q_at, k_at)``
+    give (batch, head, row block) of the query-side and key-side blocks;
+    a step past the query block's last live key block names that one
+    again."""
+    def q_at(i, j, g, t):
+        return i, j, g
+
+    def k_at(i, j, g, t):
+        first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum,
+                              jnp.maximum)
+        return i, j // group, jnp.minimum(first + t, last)
+
+    return q_at, k_at
+
+
+def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
+                     interpret: bool, packed: bool = False,
+                     num_heads: Optional[int] = None,
+                     num_kv_heads: Optional[int] = None):
+    """``(out, lse (B, H, Sq, 1))`` from the blocked kernels; the operands
+    (B, H, S, D) with k and v of H or fewer heads, or ``packed``
+    (B, S, H x D)."""
+    b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
+    group, axis = h // hkv, 1 if packed else 2
+    bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
+        mask, bias, b, sq, sk, block_q, block_k, interpret)
+    qp = _pad_rows(q, sq_pad, axis)
+    kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, axis)
+    num_q, num_k = sq_pad // bq, sk_pad // bk
+    steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
+    q_at, k_at = _query_major(mask, group, bq, bk, num_k)
+    in_specs = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
+                _rows(packed, bk, d, k_at)]
     args = [qp, kp, vp]
     if bias_arr is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, 1, bk), lambda i, j, g, t: (i, 0, 0, t)))
+        in_specs.append(pl.BlockSpec(
+            (None, None, 1, bk), lambda *ids: (ids[0], 0, 0, k_at(*ids)[2])))
         args.append(bias_arr)
-        kernel = functools.partial(_fwd_kernel, scale=scale)
-    else:
-        kernel = functools.partial(_fwd_kernel_nobias, scale=scale)
-
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, scale=d ** -0.5,
+                          has_bias=bias_arr is not None, mask=mask, bq=bq,
+                          bk=bk, num_k=num_k, steps=steps),
+        grid=(b, h, num_q, steps),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda i, j, g, t: (i, j, g, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda i, j, g, t: (i, j, g, 0)),
-        ],
+        out_specs=[_rows(packed, bq, d, q_at), _stats(bq, q_at)],
         out_shape=[
             jax.ShapeDtypeStruct(qp.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
@@ -596,32 +749,159 @@ def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
         compiler_params=_compiler_params(interpret, _BLOCKED),
     )(*args)
     if sq_pad != sq:
-        out, lse = out[:, :, :sq], lse[:, :, :sq]
+        out = jax.lax.slice_in_dim(out, 0, sq, axis=axis)
+        lse = lse[:, :, :sq]
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _blocked_backward(q, k, v, bias, delta, lse, do, mask: _Mask,
+                      block_q: int, block_k: int, interpret: bool,
+                      packed: bool = False, num_heads: Optional[int] = None,
+                      num_kv_heads: Optional[int] = None):
+    """``(dq, dk, dv, dbias per key/value head (B, Hkv, 1, Sk) or None)``
+    from the blocked kernels; operands as ``_blocked_forward``'s, ``lse``
+    and ``delta`` (B, H, Sq, 1)."""
+    b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
+    group, axis = h // hkv, 1 if packed else 2
+    bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
+        mask, bias, b, sq, sk, block_q, block_k, interpret)
+    qp, dop = _pad_rows(q, sq_pad, axis), _pad_rows(do, sq_pad, axis)
+    kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, axis)
+    lsep, deltap = _pad_rows(lse, sq_pad), _pad_rows(delta, sq_pad)
+    num_q, num_k = sq_pad // bq, sk_pad // bk
+    has_bias = bias_arr is not None
+    static = dict(scale=d ** -0.5, has_bias=has_bias, mask=mask, bq=bq, bk=bk)
+
+    # dq: grid (B, H, num_q, live key blocks), K innermost.
+    steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
+    q_at, k_at = _query_major(mask, group, bq, bk, num_k)
+
+    def specs(q_at, k_at):
+        rows = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
+                _rows(packed, bk, d, k_at)]
+        if has_bias:
+            rows.append(pl.BlockSpec(
+                (None, None, 1, bk),
+                lambda *ids: (ids[0], 0, 0, k_at(*ids)[2])))
+        return rows + [_rows(packed, bq, d, q_at), _stats(bq, q_at),
+                       _stats(bq, q_at)]
+
+    args = [qp, kp, vp] + ([bias_arr] if has_bias else []) \
+        + [dop, lsep, deltap]
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, num_k=num_k, steps=steps, **static),
+        grid=(b, h, num_q, steps),
+        in_specs=specs(q_at, k_at),
+        out_specs=_rows(packed, bq, d, q_at),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        scratch_shapes=[_vmem((bq, d), jnp.float32)],
+        interpret=interpret,
+        compiler_params=_compiler_params(interpret, _BLOCKED),
+    )(*args)
+    if sq_pad != sq:
+        dq = jax.lax.slice_in_dim(dq, 0, sq, axis=axis)
+
+    # dk, dv: grid (B, Hkv, num_k, query heads of the group x live query
+    # blocks), Q innermost.
+    live_q = _longest(lambda t: _q_span(mask, t, bq, bk, num_q), num_k)
+    steps = group * live_q
+
+    def q_of(i, j, t, u):
+        first, last = _q_span(mask, t, bq, bk, num_q, jnp.minimum,
+                              jnp.maximum)
+        return (i, j * group + u // live_q,
+                jnp.minimum(first + u % live_q, last))
+
+    def k_of(i, j, t, u):
+        return i, j, t
+
+    out_specs = [_rows(packed, bk, d, k_of), _rows(packed, bk, d, k_of)]
+    out_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vp.shape, v.dtype)]
+    scratch = [_vmem((bk, d), jnp.float32), _vmem((bk, d), jnp.float32)]
+    if has_bias:
+        # Per key/value head: indexed by the head grid dim, unlike the
+        # input bias (which broadcasts over heads from index 0).
+        out_specs.append(pl.BlockSpec((None, None, 1, bk),
+                                      lambda i, j, t, u: (i, j, 0, t)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((b, hkv, 1, sk_pad), jnp.float32))
+        scratch.append(_vmem((1, bk), jnp.float32))
+    results = pl.pallas_call(
+        functools.partial(_dkv_kernel, num_q=num_q, live_q=live_q,
+                          steps=steps, **static),
+        grid=(b, hkv, num_k, steps),
+        in_specs=specs(q_of, k_of),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        interpret=interpret,
+        compiler_params=_compiler_params(interpret, _BLOCKED),
+    )(*args)
+    dk, dv = results[0], results[1]
+    if sk_pad != sk:
+        dk = jax.lax.slice_in_dim(dk, 0, sk, axis=axis)
+        dv = jax.lax.slice_in_dim(dv, 0, sk, axis=axis)
+    return dq, dk, dv, results[2][..., :sk] if has_bias else None
+
+
+# -- forward / backward dispatch --------------------------------------------
+
+
+def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
+                   interpret: bool, mask: _Mask = _Mask()):
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    if _takes_one_block(q, k, block_q, block_k, mask):
+        sq_pad = _pad_one_block(sq, interpret)
+        sk_pad = _pad_one_block(sk, interpret)
+        out, lse = _forward1(
+            _bhsd_layout(b, h, d), _pad_rows(q, sq_pad), _pad_rows(k, sk_pad),
+            _pad_rows(v, sk_pad), _prep_bias(bias, b, sk, sk_pad), sq_pad,
+            sk_pad, interpret)
+        return out[:, :, :sq], lse.reshape(b, h, sq_pad, 1)[:, :, :sq]
+    return _blocked_forward(q, k, v, bias, mask, block_q, block_k, interpret)
+
+
+def _takes_one_block(q, k, block_q: int, block_k: int, mask: _Mask) -> bool:
+    """The one-block kernels serve an unmasked sequence that fits one
+    block of each side, every query head with key/value heads of its
+    own; a mask or shared heads take the blocked kernels at any length."""
+    return (_one_block(q.shape[2], k.shape[2], block_q, block_k)
+            and not mask.causal and q.shape[1] == k.shape[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention(q: jax.Array,
                     k: jax.Array,
                     v: jax.Array,
                     bias: Optional[jax.Array] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    causal: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
     """Exact attention via the Pallas flash kernels.
 
     Args:
         q, k, v: (B, H, S, D), any float dtype: the products take them as
             they are and accumulate in float32; the softmax is float32.
+            ``k`` and ``v`` may have fewer heads than ``q``, a divisor of
+            its count: query head h reads key/value head h // (H / Hkv).
         bias: optional additive key-side bias, strictly (B, 1, 1, S).
         block_q/block_k: preferred VMEM tile sizes (``DEFAULT_BLOCK_*``).
             A sequence that fits one block of each takes the one-block
             kernels: a single backward kernel, no accumulator.
         interpret: run under the Pallas interpreter (CPU tests).
+        causal: query i sees keys j <= i only. Static: the mask is
+            structural, not a tensor; key blocks no query of a block
+            sees are not visited, forward or backward.
+        window: of those, the last ``window`` only (j > i - window).
 
     Neither forward nor backward materializes an O(S²) tensor in HBM.
     """
-    out, _ = _flash_forward(q, k, v, bias, block_q, block_k, interpret)
+    out, _ = _flash_forward(q, k, v, bias, block_q, block_k, interpret,
+                            _mask_of(causal, window))
     return out
 
 
@@ -633,15 +913,16 @@ def flash_forward(q, k, v, bias=None, block_q: int = DEFAULT_BLOCK_Q,
     return _flash_forward(q, k, v, bias, block_q, block_k, interpret)
 
 
-def _flash_fwd(q, k, v, bias, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, bias, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, bias, block_q, block_k, interpret, causal, window):
+    out, lse = _flash_forward(q, k, v, bias, block_q, block_k, interpret,
+                              _mask_of(causal, window))
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_bwd(block_q, block_k, interpret, residuals, do):
+def _flash_bwd(block_q, block_k, interpret, causal, window, residuals, do):
     q, k, v, bias, out, lse = residuals
     return flash_backward(q, k, v, bias, out, lse, do, block_q, block_k,
-                          interpret)
+                          interpret, causal, window)
 
 
 def _delta(do, out):
@@ -651,7 +932,8 @@ def _delta(do, out):
 
 
 def flash_backward(q, k, v, bias, out, lse, do, block_q: int = DEFAULT_BLOCK_Q,
-                   block_k: int = DEFAULT_BLOCK_K, interpret: bool = False):
+                   block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
+                   causal: bool = False, window: Optional[int] = None):
     """Backward kernels: ``(dq, dk, dv, dbias)`` from the standard flash
     residuals. ``lse`` may be global (covering MORE keys than ``k``) — the
     ring backward exploits this: with the global logsumexp, the recomputed
@@ -659,7 +941,8 @@ def flash_backward(q, k, v, bias, out, lse, do, block_q: int = DEFAULT_BLOCK_Q,
     this hop's keys, so per-hop grads sum to the exact global gradient."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    if _one_block(sq, sk, block_q, block_k):
+    mask = _mask_of(causal, window)
+    if _takes_one_block(q, k, block_q, block_k, mask):
         sq_pad = _pad_one_block(sq, interpret)
         sk_pad = _pad_one_block(sk, interpret)
         dq, dk, dv, dbias = _backward1(
@@ -671,91 +954,74 @@ def flash_backward(q, k, v, bias, out, lse, do, block_q: int = DEFAULT_BLOCK_Q,
         dbias = (None if bias is None
                  else dbias[..., :sk].astype(bias.dtype))
         return dq[:, :, :sq], dk[:, :, :sk], dv[:, :, :sk], dbias
-    bq, bk, sq_pad, sk_pad = _plan(sq, sk, block_q, block_k, interpret)
-    scale = 1.0 / (d ** 0.5)
-    bias_arr = _prep_bias(bias, b, sk, sk_pad)
-    delta = _delta(do, out)[..., None]  # (B, H, Sq, 1)
-    qp, dop = _pad_rows(q, sq_pad), _pad_rows(do, sq_pad)
-    kp, vp = _pad_rows(k, sk_pad), _pad_rows(v, sk_pad)
-    lsep, deltap = _pad_rows(lse[..., None] if lse.ndim == 3 else lse,
-                             sq_pad), _pad_rows(delta, sq_pad)
-    has_bias = bias_arr is not None
-
-    q_spec4 = pl.BlockSpec((1, 1, bq, d), lambda i, j, g, t: (i, j, g, 0))
-    k_spec4 = pl.BlockSpec((1, 1, bk, d), lambda i, j, g, t: (i, j, t, 0))
-    r_spec4 = pl.BlockSpec((1, 1, bq, 1), lambda i, j, g, t: (i, j, g, 0))
-    b_spec4 = pl.BlockSpec((1, 1, 1, bk), lambda i, j, g, t: (i, 0, 0, t))
-    in_specs = [q_spec4, k_spec4, k_spec4]
-    args = [qp, kp, vp]
-    if has_bias:
-        in_specs.append(b_spec4)
-        args.append(bias_arr)
-    in_specs += [q_spec4, r_spec4, r_spec4]
-    args += [dop, lsep, deltap]
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel if has_bias else _dq_kernel_nobias,
-                          scale=scale),
-        grid=(b, h, sq_pad // bq, sk_pad // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda i, j, g, t: (i, j, g, 0)),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        scratch_shapes=[_vmem((bq, d), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_compiler_params(interpret, _BLOCKED),
-    )(*args)
-    if sq_pad != sq:
-        dq = dq[:, :, :sq]
-
-    # Same inputs, but grid transposed: (B, H, num_k, num_q), Q innermost.
-    q_spec_t = pl.BlockSpec((1, 1, bq, d), lambda i, j, t, g: (i, j, g, 0))
-    k_spec_t = pl.BlockSpec((1, 1, bk, d), lambda i, j, t, g: (i, j, t, 0))
-    r_spec_t = pl.BlockSpec((1, 1, bq, 1), lambda i, j, t, g: (i, j, g, 0))
-    b_spec_t = pl.BlockSpec((1, 1, 1, bk), lambda i, j, t, g: (i, 0, 0, t))
-    in_specs_t = [q_spec_t, k_spec_t, k_spec_t]
-    if has_bias:
-        in_specs_t.append(b_spec_t)
-    in_specs_t += [q_spec_t, r_spec_t, r_spec_t]
-
-    out_specs = [k_spec_t, k_spec_t]
-    out_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                 jax.ShapeDtypeStruct(vp.shape, v.dtype)]
-    scratch = [_vmem((bk, d), jnp.float32), _vmem((bk, d), jnp.float32)]
-    if has_bias:
-        # Per-head dbias: indexed by the head grid dim, unlike the input
-        # bias (which broadcasts over heads from index 0).
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1, bk), lambda i, j, t, g: (i, j, 0, t)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((b, h, 1, sk_pad), jnp.float32))
-        scratch.append(_vmem((1, bk), jnp.float32))
-
-    results = pl.pallas_call(
-        functools.partial(_dkv_kernel if has_bias else _dkv_kernel_nobias,
-                          scale=scale),
-        grid=(b, h, sk_pad // bk, sq_pad // bq),
-        in_specs=in_specs_t,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        compiler_params=_compiler_params(interpret, _BLOCKED),
-    )(*args)
-    dk, dv = results[0], results[1]
-    if sk_pad != sk:
-        dk, dv = dk[:, :, :sk], dv[:, :, :sk]
-
-    dbias = None
-    if bias is not None:
-        dbias_h = results[2][:, :, :, :sk]
-        dbias = dbias_h.sum(axis=1, keepdims=True).astype(bias.dtype)
-    return dq, dk, dv, dbias
+    dq, dk, dv, dbias = _blocked_backward(
+        q, k, v, bias, _delta(do, out)[..., None],
+        lse[..., None] if lse.ndim == 3 else lse, do, mask, block_q, block_k,
+        interpret)
+    if bias is None:
+        return dq, dk, dv, None
+    return dq, dk, dv, dbias.sum(axis=1, keepdims=True).astype(bias.dtype)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+# -- grouped heads off their projections -------------------------------------
+
+
+def _reads_in_place(head_dim: int, interpret: bool) -> bool:
+    """Whether the blocked kernels cut a head's (rows, D) blocks straight
+    from a (B, S, H x D) projection: where D is whole lanes (the
+    interpreter cuts anything)."""
+    return interpret or head_dim % _LANES == 0
+
+
+def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
+                    causal: bool = True, window: Optional[int] = None,
+                    block_q: int = DEFAULT_BLOCK_Q,
+                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = False):
+    """Self-attention of projections as their matmuls leave them: ``q``
+    (B, S, H x D), ``k`` and ``v`` (B, S, Hkv x D), a head's D side by
+    side; query head h reads key/value head h // (H / Hkv), which is
+    fetched once a block and repeated nowhere. Returns ``(out
+    (B, S, H x D), lse (B, H, S, 1))``. Where D is whole lanes (128) the
+    kernels read and write these arrays in place: no (B, S, H, D) ->
+    (B, H, S, D) copy on either side. Without a custom_vjp of its own: the
+    caller pairs it with :func:`grouped_backward` under its own scope
+    (models/mellum.py does)."""
+    mask = _mask_of(causal, window)
+    if _reads_in_place(q.shape[-1] // num_heads, interpret):
+        return _blocked_forward(q, k, v, None, mask, block_q, block_k,
+                                interpret, True, num_heads, num_kv_heads)
+    out, lse = _blocked_forward(
+        _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
+        _split_heads(v, num_kv_heads), None, mask, block_q, block_k,
+        interpret)
+    return _merge_heads(out), lse
+
+
+def grouped_backward(q, k, v, out, lse, do, num_heads: int,
+                     num_kv_heads: int, causal: bool = True,
+                     window: Optional[int] = None,
+                     block_q: int = DEFAULT_BLOCK_Q,
+                     block_k: int = DEFAULT_BLOCK_K, interpret: bool = False):
+    """``(dq, dk, dv)`` in the operands' layouts from
+    :func:`grouped_forward`'s operands and results and the output's
+    cotangent ``do`` (B, S, H x D)."""
+    mask = _mask_of(causal, window)
+    b, s, _ = q.shape
+    delta = _delta(do.reshape(b, s, num_heads, -1),
+                   out.reshape(b, s, num_heads, -1))     # (B, S, H)
+    delta = delta.transpose(0, 2, 1)[..., None]
+    if _reads_in_place(q.shape[-1] // num_heads, interpret):
+        return _blocked_backward(q, k, v, None, delta, lse, do, mask,
+                                 block_q, block_k, interpret, True,
+                                 num_heads, num_kv_heads)[:3]
+    dq, dk, dv, _ = _blocked_backward(
+        _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
+        _split_heads(v, num_kv_heads), None, delta, lse,
+        _split_heads(do, num_heads), mask, block_q, block_k, interpret)
+    return _merge_heads(dq), _merge_heads(dk), _merge_heads(dv)
 
 
 # -- attention straight off the fused projection -----------------------------

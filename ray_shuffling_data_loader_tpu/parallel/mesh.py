@@ -13,6 +13,10 @@ Axis convention used across the framework:
   host group replaces the reference's Horovod ranks.
 - ``"model"`` — tensor-parallel sharding of params (TP / column-parallel
   embeddings in models/).
+- ``"expert"`` — the chips that share a sparse-expert layer, each holding
+  a share of its experts. A name only: no mesh here has the axis yet. A
+  chip's share is told to the layer as ``held = (first, count)``
+  (``ops/moe.py``), and one chip runs it without its exchange.
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
+EXPERT_AXIS = "expert"
 
 
 def make_mesh(num_devices: Optional[int] = None,

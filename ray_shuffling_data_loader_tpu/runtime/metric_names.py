@@ -43,6 +43,15 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- a BERT layer's attention (models/bert.py; counted when a layer is
     #    traced, not when it runs; kind = flash | inline) --
     "rsdl_bert_attention_total": ("counter", ("kind",)),
+    # -- a decoder layer's attention and its sparse-expert layer
+    #    (models/mellum.py; counted or set when a layer is traced, not when
+    #    it runs; attention's kind = window | full | inline, the expert
+    #    layer's = share | all of the router's experts held here) --
+    "rsdl_lm_attention_total": ("counter", ("kind",)),
+    "rsdl_moe_layer_total": ("counter", ("kind",)),
+    "rsdl_moe_experts_held": ("gauge", ()),
+    "rsdl_moe_experts_routed": ("gauge", ()),
+    "rsdl_moe_top_k": ("gauge", ()),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

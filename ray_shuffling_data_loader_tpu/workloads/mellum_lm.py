@@ -1,0 +1,92 @@
+"""Causal-LM workload: packed pre-tokenized rows -> next-token training of
+the sparse-expert decoder (``models/mellum.py``).
+
+Rows are fixed-length packed token sequences stored as
+``FixedSizeList<int32>`` columns, 32 KB a row at 8,192 tokens: the shuffle
+moves them untouched (the fused reduce falls back to Arrow concat+take for
+list columns) and ``JaxShufflingDataset`` delivers ``(batch, seq_len)``
+int32 arrays. The next-token targets are the row itself shifted by one,
+made on the device inside the loss: nothing but the rows travels.
+
+The entry point trains one chip's share of an expert-parallel deployment:
+the chip holds ``experts_held`` of the router's experts and a slice of the
+vocabulary, and the ids are drawn from the slice.
+"""
+
+from __future__ import annotations
+
+from ray_shuffling_data_loader_tpu.workloads import bert_mlm
+
+# The files are BERT's: a ``FixedSizeList<int32>`` column of ids from 4 up
+# between a first id of 1 and a last of 2, a label column and the key. What
+# differs is on the device: no masking, the row is its own target.
+generate_packed_parquet = bert_mlm.generate_tokenized_parquet
+mellum_lm_spec = bert_mlm.bert_mlm_spec
+
+
+def make_loss(config):
+    """``loss(params, features, label)`` for ``SpmdTrainer``: the decoder's
+    next-token loss over the batch's rows."""
+    from ray_shuffling_data_loader_tpu.models import mellum
+
+    def loss(params, features, label):
+        return mellum.loss_fn(config, params, features[0])
+
+    return loss
+
+
+if __name__ == "__main__":
+    # Smoke driver: packed shards -> shuffle -> device feed -> SpmdTrainer
+    # over one device. The defaults are the tiny preset; --full trains one
+    # chip's share of Mellum2-12B-A2.5B at its published widths (a TPU).
+    import argparse
+    import tempfile
+    import timeit
+
+    parser = argparse.ArgumentParser(description="causal-LM workload smoke")
+    parser.add_argument("--num-sequences", type=int, default=64)
+    parser.add_argument("--num-files", type=int, default=4)
+    parser.add_argument("--num-epochs", type=int, default=2)
+    parser.add_argument("--batch-size", type=int, default=4)
+    parser.add_argument("--seq-len", type=int, default=64)
+    parser.add_argument("--full", action="store_true",
+                        help="the published widths (models.mellum."
+                        "mellum2_ep4_share) at 8,192-token rows")
+    args = parser.parse_args()
+
+    import jax
+    import optax
+
+    from ray_shuffling_data_loader_tpu.jax_dataset import JaxShufflingDataset
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
+    from ray_shuffling_data_loader_tpu.parallel.trainer import SpmdTrainer
+    from ray_shuffling_data_loader_tpu.plan import ir as plan_ir
+
+    cfg = mellum.mellum2_ep4_share() if args.full else mellum.mellum_tiny()
+    seq_len = 8192 if args.full else args.seq_len
+    with tempfile.TemporaryDirectory() as tmpdir:
+        filenames, _ = generate_packed_parquet(
+            args.num_sequences, args.num_files, tmpdir, seq_len=seq_len,
+            vocab_size=cfg.vocab_size)
+        ds = JaxShufflingDataset(
+            filenames, num_epochs=args.num_epochs, num_trainers=1,
+            batch_size=args.batch_size, rank=0, drop_last=True,
+            **mellum_lm_spec(seq_len))
+        trainer = SpmdTrainer(
+            mesh_mod.make_mesh(num_devices=1), make_loss(cfg),
+            mellum.init(cfg, jax.random.key(0)), optax.adam(1e-4))
+        start = timeit.default_timer()
+        rows = steps = 0
+        for epoch in plan_ir.epoch_range(0, args.num_epochs):
+            ds.set_epoch(epoch)
+            for features, label in ds:
+                loss = trainer.train_step(features, label)
+                rows += label.shape[0]
+                steps += 1
+        jax.block_until_ready(loss)
+        duration = timeit.default_timer() - start
+        print(f"{rows} rows / {steps} steps in {duration:.2f}s "
+              f"({rows * seq_len / duration:,.0f} tokens/s), final loss "
+              f"{float(loss):.4f}, stall "
+              f"{ds.batch_wait_stats.summary()['total']:.2f}s")

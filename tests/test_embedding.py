@@ -380,7 +380,7 @@ def test_bert_mlm_head_gradient_compiles_for_the_chip_in_blocks(four_chips):
 
 
 def _bert_base_step(four_chips, monkeypatch, mesh, sharding):
-    """The loss and gradients of ``bert-base-mlm`` (two of its twelve
+    """The loss and gradients of ``bert-base-mlm`` (one of its twelve
     layers: the widths are what the compiler may refuse) at
     ``bert_train``'s batch a chip, lowered for described chips with the
     model's choice of attention as on the chip."""
@@ -390,7 +390,7 @@ def _bert_base_step(four_chips, monkeypatch, mesh, sharding):
     from ray_shuffling_data_loader_tpu.ops import flash_attention
     monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
     monkeypatch.setattr(bert, "on_tpu", lambda: True)
-    cfg = dataclasses.replace(bert.bert_base(), num_layers=2)
+    cfg = dataclasses.replace(bert.bert_base(), num_layers=1)
     replicated = sharding()
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -415,7 +415,7 @@ def test_bert_attention_compiles_for_the_chip_without_scores(four_chips,
     one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
     hlo = _bert_base_step(four_chips, monkeypatch, None,
                           lambda *axes: one_chip).compile().as_text()
-    assert hlo.count("tpu_custom_call") == 4
+    assert hlo.count("tpu_custom_call") == 2
     assert not re.search(r"\[32,12,512,512\]", hlo)
     assert not re.search(r"bf16\[32,12,512,64\]", hlo)   # no head-major copy
     assert re.search(r"custom-call\(.*bf16\[32,512,2304\]", hlo)
@@ -432,7 +432,39 @@ def test_bert_attention_lowers_for_four_chips_only_with_the_mesh(
         return NamedSharding(four_chips, P(*axes))
 
     lowered = _bert_base_step(four_chips, monkeypatch, four_chips, on_mesh)
-    # One forward and one backward program, called by both layers.
+    # One forward and one backward program.
     assert lowered.as_text().count("tpu_custom_call") == 2
     with pytest.raises(NotImplementedError, match="shard_map"):
         _bert_base_step(four_chips, monkeypatch, None, on_mesh)
+
+
+def test_the_decoders_attention_compiles_for_the_chip(four_chips):
+    """``mellum_train_8k``'s window layer through the TPU's own compiler,
+    a row of 8,192 tokens at the published widths: three Mosaic kernels
+    (forward, dq, dk/dv) that read 32 query heads of 128 and their 4
+    key/value heads where the projections left them, with a grid as long
+    as the band (``tests/test_mellum.py``) and no (S, S) array."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    cfg = mellum.mellum2_ep4_share()
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    q, kv = (jax.ShapeDtypeStruct((1, 8192, heads * cfg.head_dim),
+                                  jnp.bfloat16, sharding=one_chip)
+             for heads in (cfg.num_heads, cfg.num_kv_heads))
+
+    def both(q, k, v, do):
+        args = (cfg.num_heads, cfg.num_kv_heads, True, cfg.sliding_window)
+        out, lse = fa.grouped_forward(
+            q, k, v, *args, *mellum._blocks(cfg.sliding_window, False))
+        return fa.grouped_backward(
+            q, k, v, out, lse, do, *args,
+            *mellum._blocks(cfg.sliding_window, True))
+
+    hlo = jax.jit(both).lower(q, kv, kv, q).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 3
+    assert not re.search(r"\[\d+(,\d+)*,8192,8192\]", hlo)
+    assert not re.search(r"bf16\[1,32,8192,128\]", hlo)  # no head-major copy

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_shuffling_data_loader_tpu.models import bert, resnet
+from ray_shuffling_data_loader_tpu.models import bert, mellum, resnet
 from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
 from ray_shuffling_data_loader_tpu.parallel.trainer import SpmdTrainer
 from ray_shuffling_data_loader_tpu.runtime import metrics
@@ -252,23 +252,27 @@ def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
 
 
-@pytest.mark.parametrize("name,scope,layer", [
-    ("mlm_head_pct", bert.MLM_HEAD_SCOPE, "model"),
-    ("attention_pct", bert.ATTENTION_SCOPE, "kernels"),
+@pytest.mark.parametrize("name,scope,layer,cell", [
+    ("mlm_head_pct", bert.MLM_HEAD_SCOPE, "model", "bert_train"),
+    ("attention_pct", bert.ATTENTION_SCOPE, "kernels", "bert_train"),
+    ("moe_pct", mellum.MOE_SCOPE, "kernels", "mellum_train_8k"),
+    ("lm_attention_pct", mellum.ATTENTION_SCOPE, "kernels",
+     "mellum_train_8k"),
+    ("lm_head_pct", mellum.HEAD_SCOPE, "model", "mellum_train_8k"),
 ])
 def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
-                                                            layer):
-    """``mlm_head_pct`` (PR 29) and ``attention_pct`` (PR 31) are data:
-    the scope reader ``grad_exchange_pct`` uses, pointed at a scope the
-    model names, in ``bert_train`` alone; a program without the scope (the
-    parent's side) gives it nothing."""
+                                                            layer, cell):
+    """``mlm_head_pct`` (PR 29), ``attention_pct`` (PR 31) and the
+    decoder's three (PR 32) are data: the scope reader
+    ``grad_exchange_pct`` uses, pointed at a scope the model names, in
+    that model's cell alone; a program without the scope (the parent's
+    side) gives it nothing."""
     from chipbench import manifest
     bench = manifest.load_manifest()
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry in bench["per_layer"][-2:]
     assert entry == {"name": name, "unit": "%", "better": "lower",
                      "source": "device_trace", "layer": layer,
-                     "moves": "train_rows_per_s", "workloads": ["bert_train"]}
+                     "moves": "train_rows_per_s", "workloads": [cell]}
     with open(os.path.join(manifest.BENCH_DIR, "layers",
                            f"{name}.json")) as f:
         reads = json.load(f)
@@ -278,10 +282,10 @@ def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
     assert reads["args"].pop("scope") == scope
     assert exchange["args"].pop("scope") != scope
     assert reads == exchange
-    for cell in bench["workloads"]:
+    for other in bench["workloads"]:
         reported = {m["name"]
-                    for m in manifest.resolve_cell(cell["name"]).per_layer}
-        assert (name in reported) == (cell["name"] == "bert_train")
+                    for m in manifest.resolve_cell(other["name"]).per_layer}
+        assert (name in reported) == (other["name"] == cell)
     reader = manifest.layer_reader(name)
     assert reader({"trace": None}) is None
     assert reader({"trace": object(), "step_op_names": {}}) is None
