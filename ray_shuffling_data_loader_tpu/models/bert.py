@@ -201,7 +201,14 @@ def _flash_attention_bwd(num_heads, residuals, cotangent):
                                             num_heads, interpret=not on_tpu())
 
 
-_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+def _counted_flash_attention_bwd(num_heads, residuals, cotangent):
+    # Counted here, once a layer: the program under it is traced once.
+    flash_attention.count_backward(flash_attention.qkv_backward_kind(
+        residuals[0], num_heads, interpret=not on_tpu()))
+    return _flash_attention_bwd(num_heads, residuals, cotangent)
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
 
 
 def _attention(qkv, bias, num_heads: int, mesh: Optional[Mesh]):

@@ -239,11 +239,14 @@ def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
     """The kernels' tiles: the defaults, but half a window in a window
     layer's backward, whole lanes at least. A band of 1,024 keys is two
     live tiles of 1024 x 1024 a query block, both cut by the mask, or
-    three of 512 x 512 with one whole. Measured on a v5e (PR 32; 4 rows of
-    8,192, 32 : 4 heads of 128, window 1,024; forward / backward, ms):
-    256 23.6 / 47.5, 512 12.9 / 27.6, 1024 10.1 / 29.0; the whole
-    triangle, 1024 x 1024 against 512 x 512: 22.1 / 61.6 against
-    38.2 / 76.7."""
+    three of 512 x 512 with one whole. Measured on a v5e (4 rows of
+    8,192, 32 : 4 heads of 128, ms). The one-kernel backward (PR 33;
+    the dq + dk/dv pair it replaced in brackets), under a window of
+    1,024: tiles of 256 22.2 (47.5), 512 15.5 (27.5), 1024 17.7 (29.0),
+    512 x 1024 18.3, 1024 x 512 18.4; the whole triangle: 1024 40.6
+    (61.5), 512 46.2 (76.7), 512 x 1024 43.2, 1024 x 512 42.5. The
+    forward (PR 32), window / triangle: 256 23.6, 512 12.9 / 38.2, 1024
+    10.1 / 22.1."""
     side = flash_attention.DEFAULT_BLOCK_Q
     if window is not None and backward:
         side = min(side, max(128, window // 2))
@@ -275,7 +278,16 @@ def _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent):
             *_blocks(window, True), interpret=not on_tpu())
 
 
-_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+def _counted_flash_attention_bwd(heads, kv_heads, window, residuals,
+                                 cotangent):
+    # Counted here, once a layer: the program under it is traced once.
+    q, k = residuals[:2]
+    flash_attention.count_backward(flash_attention.grouped_backward_kind(
+        q, k, heads, *_blocks(window, True), interpret=not on_tpu()))
+    return _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent)
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
 
 
 def _attention(config: MellumConfig, q, k, v, layer_type: str):

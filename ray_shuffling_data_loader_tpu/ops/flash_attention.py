@@ -21,11 +21,17 @@ Two families, chosen by shape at trace time:
   many heads side by side as fill the lanes (``qkv_forward`` /
   ``qkv_backward``: no (B, S, H, D) -> (B, H, S, D) copies on either side
   of the kernel). This is what ``models/bert.py`` takes.
-- *The sequence in blocks* (``_fwd_kernel``, ``_dq_kernel``,
-  ``_dkv_kernel``): grid (B, H, q-blocks, k-blocks) with the inner
-  dimension carrying running max / denominator / accumulator in VMEM
-  scratch; the backward is two kernels (dq streaming K; dk/dv(+dbias)
-  streaming Q) that each make s and p again. This family takes a
+- *The sequence in blocks* (``_fwd_kernel``, ``_bwd_kernel``): grid
+  (B, H, q-blocks, k-blocks) with the inner dimension carrying running
+  max / denominator / accumulator in VMEM scratch. The backward is one
+  kernel too: a live block's s, p, dp and ds are made once and feed dq
+  (scratch, a query block's steps), dk and dv (a key/value head's whole
+  sequence in float32 scratch, gathered over its query heads and their
+  blocks) and dbias: five products and one exponential pass a block.
+  Where a head's dk and dv outgrow VMEM (``_fused_fits``: past 16,384
+  keys of 128 in bf16) the backward is the older pair (``_dq_kernel``
+  streaming K; ``_dkv_kernel`` streaming Q), which each make s and p
+  again: seven products. This family takes a
   structural mask, ``causal`` and ``window`` as static arguments (the
   inner dimension runs over the blocks some query of the outer block
   sees and no further; only the diagonal's and the window edge's blocks
@@ -62,6 +68,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ray_shuffling_data_loader_tpu.ops import on_tpu
+from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 
 _NEG = -1e30   # accumulator init
 _MASK = -1e9   # padded-key bias (finite, matches ring_attention.NEG_INF)
@@ -424,6 +431,76 @@ def _dkv_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
             dbias_ref[...] = dbias_scr[:]
 
 
+def _bwd_kernel(*refs, scale: float, has_bias: bool, has_delta: bool,
+                mask: _Mask, group: int, bq: int, bk: int, num_q: int,
+                num_k: int, steps: int):
+    """Grid (B, H, num_q, steps), keys innermost: dq, dk, dv (and dbias)
+    from one s, p, dp and ds a live block. dq gathers over a query block's
+    steps in scratch, as in ``_dq_kernel``. dk and dv of a key/value head
+    gather over everything that reads it, its ``group`` query heads (which
+    the grid visits one after the other) times their query blocks, in
+    float32 scratch that holds the head's whole sequence; their output
+    blocks are the whole sequence too, stay resident meanwhile and are
+    written at the head's last step. Blocks as ``_dq_kernel``'s, but
+    dk/dv (Sk, D), dbias (num_k, 1, bk) per key/value head, and in place
+    of delta, unless the caller brings one (``has_delta``), the forward's
+    output (bq, D): delta = sum_d do o is taken here, in float32, once a
+    query block."""
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    do_ref, lse_ref, aux_ref, dq_ref, dk_ref, dv_ref = refs[3 + has_bias:][:6]
+    rest = refs[9 + has_bias:]
+    dbias_ref = rest[0] if has_bias else None
+    dq_scr, delta_scr, dk_scr, dv_scr = rest[has_bias:]
+    j, g, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
+    kb = first + t
+    keys = pl.ds(pl.multiple_of(kb * bk, bk), bk)
+    opens = (j % group == 0) & (g == 0) & (t == 0)
+    closes = (j % group == group - 1) & (g == num_q - 1) & (t == steps - 1)
+
+    @pl.when(opens)
+    def _init_head():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+        if has_bias:
+            dbias_ref[...] = jnp.zeros_like(dbias_ref)
+
+    @pl.when(t == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        delta_scr[...] = (aux_ref[...] if has_delta
+                          else _delta(do_ref[...], aux_ref[...], True))
+
+    def step(masked: bool):
+        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+        s = _dot(q, k, _NT) * scale                      # (bq, bk)
+        if has_bias:
+            s = s + bias_ref[...]
+        if masked:
+            s = jnp.where(_seen(mask, g, kb, bq, bk), s, _NEG)
+        p = jnp.exp(s - lse_ref[...])                    # softmax weights
+        dp = _dot(do, v, _NT)
+        ds = p * (dp - delta_scr[...])
+        if has_bias:
+            dbias_ref[kb] += ds.sum(axis=0, keepdims=True)
+        ds = ds.astype(q.dtype)
+        dv_scr[keys, :] += _dot(p.astype(do.dtype), do, _TN)
+        dk_scr[keys, :] += _dot(ds, q, _TN) * scale
+        dq_scr[...] += _dot(ds, k, _NN) * scale
+
+    _on_live(mask, g, kb, kb <= last, bq, bk, step)
+
+    @pl.when(t == steps - 1)
+    def _finish():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
+    @pl.when(closes)
+    def _finish_head():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
 # -- block planning & padding ----------------------------------------------
 
 
@@ -754,27 +831,52 @@ def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
     return out, lse
 
 
-def _blocked_backward(q, k, v, bias, delta, lse, do, mask: _Mask,
+def _fused_fits(bq: int, bk: int, sk_pad: int, d: int, dtype) -> bool:
+    """Whether ``_bwd_kernel``'s scoped VMEM holds a key/value head's whole
+    dk and dv, float32 scratch and the output's two buffers each, beside
+    the handful of float32 (bq, bk) arrays of a step. 8,192 keys of 128 in
+    bf16 are 16 MiB, 16,384 are 32; past that the backward is the split
+    pair, whose blocks do not grow with the sequence."""
+    head = sk_pad * _round_up(d, _LANES)
+    resident = 2 * head * (4 + 2 * jnp.dtype(dtype).itemsize)
+    return resident + 6 * 4 * bq * bk <= _VMEM_LIMIT_BYTES
+
+
+def _blocked_kind(sq: int, sk: int, d: int, dtype, block_q: int,
+                  block_k: int, interpret: bool) -> str:
+    """Which backward the blocked family launches for these sizes."""
+    bq, bk, _, sk_pad = _plan(sq, sk, block_q, block_k, interpret)
+    return "fused" if _fused_fits(bq, bk, sk_pad, d, dtype) else "split"
+
+
+def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
                       block_q: int, block_k: int, interpret: bool,
                       packed: bool = False, num_heads: Optional[int] = None,
-                      num_kv_heads: Optional[int] = None):
+                      num_kv_heads: Optional[int] = None, delta=None):
     """``(dq, dk, dv, dbias per key/value head (B, Hkv, 1, Sk) or None)``
-    from the blocked kernels; operands as ``_blocked_forward``'s, ``lse``
-    and ``delta`` (B, H, Sq, 1)."""
+    from the blocked kernels: one (``_bwd_kernel``) where a key/value
+    head's dk and dv fit in VMEM (``_fused_fits``), else the split pair.
+    Operands as ``_blocked_forward``'s, ``out`` and ``do`` laid out as
+    ``q``, ``lse`` (B, H, Sq, 1). ``delta`` (B, H, Sq, 1) float32 is the
+    caller's sum_d do o where it has one already; ``out`` is then not
+    read."""
     b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
     group, axis = h // hkv, 1 if packed else 2
     bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
         mask, bias, b, sq, sk, block_q, block_k, interpret)
+    fused = _fused_fits(bq, bk, sk_pad, d, k.dtype)
+    if delta is None and not fused:
+        heads = (b, sq, h, d) if packed else do.shape
+        delta = _delta(do.reshape(heads), out.reshape(heads), True)
+        if packed:
+            delta = delta.transpose(0, 2, 1, 3)          # (B, H, Sq, 1)
     qp, dop = _pad_rows(q, sq_pad, axis), _pad_rows(do, sq_pad, axis)
     kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, axis)
-    lsep, deltap = _pad_rows(lse, sq_pad), _pad_rows(delta, sq_pad)
+    aux = (_pad_rows(out, sq_pad, axis) if delta is None
+           else _pad_rows(delta, sq_pad))
     num_q, num_k = sq_pad // bq, sk_pad // bk
     has_bias = bias_arr is not None
     static = dict(scale=d ** -0.5, has_bias=has_bias, mask=mask, bq=bq, bk=bk)
-
-    # dq: grid (B, H, num_q, live key blocks), K innermost.
-    steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
-    q_at, k_at = _query_major(mask, group, bq, bk, num_k)
 
     def specs(q_at, k_at):
         rows = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
@@ -784,65 +886,106 @@ def _blocked_backward(q, k, v, bias, delta, lse, do, mask: _Mask,
                 (None, None, 1, bk),
                 lambda *ids: (ids[0], 0, 0, k_at(*ids)[2])))
         return rows + [_rows(packed, bq, d, q_at), _stats(bq, q_at),
-                       _stats(bq, q_at)]
+                       _stats(bq, q_at) if delta is not None
+                       else _rows(packed, bq, d, q_at)]
 
     args = [qp, kp, vp] + ([bias_arr] if has_bias else []) \
-        + [dop, lsep, deltap]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, num_k=num_k, steps=steps, **static),
-        grid=(b, h, num_q, steps),
-        in_specs=specs(q_at, k_at),
-        out_specs=_rows(packed, bq, d, q_at),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        scratch_shapes=[_vmem((bq, d), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_compiler_params(interpret, _BLOCKED),
-    )(*args)
+        + [dop, _pad_rows(lse, sq_pad), aux]
+    # Query-major: grid (B, H, num_q, live key blocks), K innermost.
+    steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
+    q_at, k_at = _query_major(mask, group, bq, bk, num_k)
+    dq_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
+    dkv_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vp.shape, v.dtype)]
+    dq_scratch = _vmem((bq, d), jnp.float32)
+
+    if fused:
+        def head_at(i, j, g, t):
+            return i, j // group, 0
+
+        out_specs = [_rows(packed, bq, d, q_at),
+                     _rows(packed, sk_pad, d, head_at),
+                     _rows(packed, sk_pad, d, head_at)]
+        out_shape = [dq_shape] + dkv_shape
+        if has_bias:
+            out_specs.append(pl.BlockSpec(
+                (None, None, num_k, 1, bk),
+                lambda i, j, g, t: (i, j // group, 0, 0, 0)))
+            out_shape.append(
+                jax.ShapeDtypeStruct((b, hkv, num_k, 1, bk), jnp.float32))
+        dq, dk, dv, *dbias = pl.pallas_call(
+            functools.partial(_bwd_kernel, has_delta=delta is not None,
+                              group=group, num_q=num_q, num_k=num_k,
+                              steps=steps, **static),
+            grid=(b, h, num_q, steps),
+            in_specs=specs(q_at, k_at),
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[dq_scratch, _vmem((bq, 1), jnp.float32),
+                            _vmem((sk_pad, d), jnp.float32),
+                            _vmem((sk_pad, d), jnp.float32)],
+            interpret=interpret,
+            # Only the rows are independent: a key/value head's dk and dv
+            # gather over its query heads and their blocks.
+            compiler_params=_compiler_params(
+                interpret, ("parallel", "arbitrary", "arbitrary",
+                            "arbitrary")),
+        )(*args)
+        dbias = dbias[0].reshape(b, hkv, 1, sk_pad) if has_bias else None
+    else:
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, num_k=num_k, steps=steps, **static),
+            grid=(b, h, num_q, steps),
+            in_specs=specs(q_at, k_at),
+            out_specs=_rows(packed, bq, d, q_at),
+            out_shape=dq_shape,
+            scratch_shapes=[dq_scratch],
+            interpret=interpret,
+            compiler_params=_compiler_params(interpret, _BLOCKED),
+        )(*args)
+
+        # dk, dv: grid (B, Hkv, num_k, query heads of the group x live
+        # query blocks), Q innermost.
+        live_q = _longest(lambda t: _q_span(mask, t, bq, bk, num_q), num_k)
+        steps = group * live_q
+
+        def q_of(i, j, t, u):
+            first, last = _q_span(mask, t, bq, bk, num_q, jnp.minimum,
+                                  jnp.maximum)
+            return (i, j * group + u // live_q,
+                    jnp.minimum(first + u % live_q, last))
+
+        def k_of(i, j, t, u):
+            return i, j, t
+
+        out_specs = [_rows(packed, bk, d, k_of), _rows(packed, bk, d, k_of)]
+        scratch = [_vmem((bk, d), jnp.float32), _vmem((bk, d), jnp.float32)]
+        if has_bias:
+            # Per key/value head: indexed by the head grid dim, unlike the
+            # input bias (which broadcasts over heads from index 0).
+            out_specs.append(pl.BlockSpec((None, None, 1, bk),
+                                          lambda i, j, t, u: (i, j, 0, t)))
+            dkv_shape.append(
+                jax.ShapeDtypeStruct((b, hkv, 1, sk_pad), jnp.float32))
+            scratch.append(_vmem((1, bk), jnp.float32))
+        dk, dv, *dbias = pl.pallas_call(
+            functools.partial(_dkv_kernel, num_q=num_q, live_q=live_q,
+                              steps=steps, **static),
+            grid=(b, hkv, num_k, steps),
+            in_specs=specs(q_of, k_of),
+            out_specs=out_specs,
+            out_shape=dkv_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+            compiler_params=_compiler_params(interpret, _BLOCKED),
+        )(*args)
+        dbias = dbias[0] if has_bias else None
     if sq_pad != sq:
         dq = jax.lax.slice_in_dim(dq, 0, sq, axis=axis)
-
-    # dk, dv: grid (B, Hkv, num_k, query heads of the group x live query
-    # blocks), Q innermost.
-    live_q = _longest(lambda t: _q_span(mask, t, bq, bk, num_q), num_k)
-    steps = group * live_q
-
-    def q_of(i, j, t, u):
-        first, last = _q_span(mask, t, bq, bk, num_q, jnp.minimum,
-                              jnp.maximum)
-        return (i, j * group + u // live_q,
-                jnp.minimum(first + u % live_q, last))
-
-    def k_of(i, j, t, u):
-        return i, j, t
-
-    out_specs = [_rows(packed, bk, d, k_of), _rows(packed, bk, d, k_of)]
-    out_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                 jax.ShapeDtypeStruct(vp.shape, v.dtype)]
-    scratch = [_vmem((bk, d), jnp.float32), _vmem((bk, d), jnp.float32)]
-    if has_bias:
-        # Per key/value head: indexed by the head grid dim, unlike the
-        # input bias (which broadcasts over heads from index 0).
-        out_specs.append(pl.BlockSpec((None, None, 1, bk),
-                                      lambda i, j, t, u: (i, j, 0, t)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((b, hkv, 1, sk_pad), jnp.float32))
-        scratch.append(_vmem((1, bk), jnp.float32))
-    results = pl.pallas_call(
-        functools.partial(_dkv_kernel, num_q=num_q, live_q=live_q,
-                          steps=steps, **static),
-        grid=(b, hkv, num_k, steps),
-        in_specs=specs(q_of, k_of),
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        compiler_params=_compiler_params(interpret, _BLOCKED),
-    )(*args)
-    dk, dv = results[0], results[1]
     if sk_pad != sk:
         dk = jax.lax.slice_in_dim(dk, 0, sk, axis=axis)
         dv = jax.lax.slice_in_dim(dv, 0, sk, axis=axis)
-    return dq, dk, dv, results[2][..., :sk] if has_bias else None
+    return dq, dk, dv, dbias[..., :sk] if has_bias else None
 
 
 # -- forward / backward dispatch --------------------------------------------
@@ -925,39 +1068,46 @@ def _flash_bwd(block_q, block_k, interpret, causal, window, residuals, do):
                           interpret, causal, window)
 
 
-def _delta(do, out):
+def _delta(do, out, keepdims: bool = False):
     """delta_i = sum_d do_i * o_i, the softmax backward's correction term,
     over the trailing axis in float32."""
-    return jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    return jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+                   keepdims=keepdims)
 
 
 def flash_backward(q, k, v, bias, out, lse, do, block_q: int = DEFAULT_BLOCK_Q,
                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
-                   causal: bool = False, window: Optional[int] = None):
+                   causal: bool = False, window: Optional[int] = None,
+                   delta=None):
     """Backward kernels: ``(dq, dk, dv, dbias)`` from the standard flash
     residuals. ``lse`` may be global (covering MORE keys than ``k``) — the
     ring backward exploits this: with the global logsumexp, the recomputed
     per-hop weights ``exp(s - lse)`` are the global softmax restricted to
-    this hop's keys, so per-hop grads sum to the exact global gradient."""
+    this hop's keys, so per-hop grads sum to the exact global gradient.
+    ``delta`` (B, H, Sq) float32 is sum_d do o where the caller has it
+    already (the ring takes it once for all its hops); ``out`` is then
+    not read."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     mask = _mask_of(causal, window)
     if _takes_one_block(q, k, block_q, block_k, mask):
         sq_pad = _pad_one_block(sq, interpret)
         sk_pad = _pad_one_block(sk, interpret)
+        if delta is None:
+            delta = _delta(do, out)
         dq, dk, dv, dbias = _backward1(
             _bhsd_layout(b, h, d), _pad_rows(q, sq_pad), _pad_rows(k, sk_pad),
             _pad_rows(v, sk_pad), _pad_rows(do, sq_pad),
             _pad_rows(lse.reshape(b, h, 1, sq), sq_pad, 3),
-            _pad_rows(_delta(do, out).reshape(b, h, 1, sq), sq_pad, 3),
+            _pad_rows(delta.reshape(b, h, 1, sq), sq_pad, 3),
             _prep_bias(bias, b, sk, sk_pad), sq_pad, sk_pad, interpret)
         dbias = (None if bias is None
                  else dbias[..., :sk].astype(bias.dtype))
         return dq[:, :, :sq], dk[:, :, :sk], dv[:, :, :sk], dbias
     dq, dk, dv, dbias = _blocked_backward(
-        q, k, v, bias, _delta(do, out)[..., None],
-        lse[..., None] if lse.ndim == 3 else lse, do, mask, block_q, block_k,
-        interpret)
+        q, k, v, bias, out, lse[..., None] if lse.ndim == 3 else lse, do,
+        mask, block_q, block_k, interpret,
+        delta=None if delta is None else delta[..., None])
     if bias is None:
         return dq, dk, dv, None
     return dq, dk, dv, dbias.sum(axis=1, keepdims=True).astype(bias.dtype)
@@ -1009,19 +1159,37 @@ def grouped_backward(q, k, v, out, lse, do, num_heads: int,
     :func:`grouped_forward`'s operands and results and the output's
     cotangent ``do`` (B, S, H x D)."""
     mask = _mask_of(causal, window)
-    b, s, _ = q.shape
-    delta = _delta(do.reshape(b, s, num_heads, -1),
-                   out.reshape(b, s, num_heads, -1))     # (B, S, H)
-    delta = delta.transpose(0, 2, 1)[..., None]
     if _reads_in_place(q.shape[-1] // num_heads, interpret):
-        return _blocked_backward(q, k, v, None, delta, lse, do, mask,
+        return _blocked_backward(q, k, v, None, out, lse, do, mask,
                                  block_q, block_k, interpret, True,
                                  num_heads, num_kv_heads)[:3]
     dq, dk, dv, _ = _blocked_backward(
         _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
-        _split_heads(v, num_kv_heads), None, delta, lse,
-        _split_heads(do, num_heads), mask, block_q, block_k, interpret)
+        _split_heads(v, num_kv_heads), None, _split_heads(out, num_heads),
+        lse, _split_heads(do, num_heads), mask, block_q, block_k, interpret)
     return _merge_heads(dq), _merge_heads(dk), _merge_heads(dv)
+
+
+def grouped_backward_kind(q, k, num_heads: int,
+                          block_q: int = DEFAULT_BLOCK_Q,
+                          block_k: int = DEFAULT_BLOCK_K,
+                          interpret: bool = False) -> str:
+    """Which backward :func:`grouped_backward` launches for these
+    operands: ``"fused"`` (one kernel) or ``"split"`` (dq, then dk/dv)."""
+    s, d = q.shape[1], q.shape[-1] // num_heads
+    return _blocked_kind(s, s, d, k.dtype, block_q, block_k, interpret)
+
+
+def count_backward(kind: str) -> None:
+    """One attention backward of ``kind`` (``one_block`` | ``fused`` |
+    ``split``) traced: the models call it where their backward rule runs,
+    once a layer (the jitted program under it is traced once a shape)."""
+    rt_metrics.counter(
+        "rsdl_attention_backward_total",
+        "Attention backwards traced, by the kernels that compute them: "
+        "one for a sequence in one block, one for a sequence in blocks "
+        "whose dk and dv fit in VMEM, or the dq and dk/dv pair",
+        kind=kind).inc()
 
 
 # -- attention straight off the fused projection -----------------------------
@@ -1105,6 +1273,15 @@ def saves_out(qkv, num_heads: int) -> bool:
     """Whether :func:`qkv_backward` reads the forward's output: not where
     the one-block kernel takes delta from its own p and dp."""
     return _packs(qkv, num_heads) is None
+
+
+def qkv_backward_kind(qkv, num_heads: int, interpret: bool = False) -> str:
+    """Which backward :func:`qkv_backward` launches for this projection."""
+    _, s, width = qkv.shape
+    if _one_block(s, s, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K):
+        return "one_block"
+    return _blocked_kind(s, s, width // (3 * num_heads), qkv.dtype,
+                         DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret)
 
 
 # From this sequence length the kernels beat XLA's inline attention on the

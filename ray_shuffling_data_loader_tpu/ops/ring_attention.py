@@ -253,12 +253,13 @@ def _ring_flash_bwd_impl(axis_name: str, block_q: int, block_k: int,
     n = jax.lax.psum(1, axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     has_bias = kv_bias is not None
+    delta = fa._delta(do, out)      # once: every hop's queries are these
 
     def step(i, carry):
         k_c, v_c, bias_c, dk_c, dv_c, dbias_c, dq = carry
         dq_h, dk_h, dv_h, dbias_h = fa.flash_backward(
-            q, k_c, v_c, bias_c, out, lse, do, block_q=block_q,
-            block_k=block_k, interpret=interpret)
+            q, k_c, v_c, bias_c, None, lse, do, block_q=block_q,
+            block_k=block_k, interpret=interpret, delta=delta)
         dq = dq + dq_h.astype(jnp.float32)
         dk_c = dk_c + dk_h.astype(jnp.float32)
         dv_c = dv_c + dv_h.astype(jnp.float32)

@@ -43,6 +43,10 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- a BERT layer's attention (models/bert.py; counted when a layer is
     #    traced, not when it runs; kind = flash | inline) --
     "rsdl_bert_attention_total": ("counter", ("kind",)),
+    # -- an attention's backward kernels (ops/flash_attention.py; counted by
+    #    the models when a layer's backward is traced, not when it runs;
+    #    kind = one_block | fused | split) --
+    "rsdl_attention_backward_total": ("counter", ("kind",)),
     # -- a decoder layer's attention and its sparse-expert layer
     #    (models/mellum.py; counted or set when a layer is traced, not when
     #    it runs; attention's kind = window | full | inline, the expert

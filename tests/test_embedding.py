@@ -438,12 +438,16 @@ def test_bert_attention_lowers_for_four_chips_only_with_the_mesh(
         _bert_base_step(four_chips, monkeypatch, None, on_mesh)
 
 
-def test_the_decoders_attention_compiles_for_the_chip(four_chips):
-    """``mellum_train_8k``'s window layer through the TPU's own compiler,
-    a row of 8,192 tokens at the published widths: three Mosaic kernels
-    (forward, dq, dk/dv) that read 32 query heads of 128 and their 4
+@pytest.mark.parametrize("layer_type", ["sliding_attention",
+                                        "full_attention"])
+def test_the_decoders_attention_compiles_for_the_chip(four_chips, layer_type):
+    """A layer of ``mellum_train_8k`` of either kind through the TPU's own
+    compiler, a row of 8,192 tokens at the published widths: two Mosaic
+    kernels (forward, and one backward that holds a key/value head's dk
+    and dv in VMEM) that read 32 query heads of 128 and their 4
     key/value heads where the projections left them, with a grid as long
-    as the band (``tests/test_mellum.py``) and no (S, S) array."""
+    as the band (``tests/test_mellum.py``) and no (S, S) array, nor
+    delta's (B, H, S, 1) column."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -451,20 +455,58 @@ def test_the_decoders_attention_compiles_for_the_chip(four_chips):
     from ray_shuffling_data_loader_tpu.models import mellum
     from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
     cfg = mellum.mellum2_ep4_share()
+    window = cfg.sliding_window if layer_type == mellum.SLIDING else None
     one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
     q, kv = (jax.ShapeDtypeStruct((1, 8192, heads * cfg.head_dim),
                                   jnp.bfloat16, sharding=one_chip)
              for heads in (cfg.num_heads, cfg.num_kv_heads))
 
     def both(q, k, v, do):
-        args = (cfg.num_heads, cfg.num_kv_heads, True, cfg.sliding_window)
+        args = (cfg.num_heads, cfg.num_kv_heads, True, window)
         out, lse = fa.grouped_forward(
-            q, k, v, *args, *mellum._blocks(cfg.sliding_window, False))
+            q, k, v, *args, *mellum._blocks(window, False))
         return fa.grouped_backward(
-            q, k, v, out, lse, do, *args,
-            *mellum._blocks(cfg.sliding_window, True))
+            q, k, v, out, lse, do, *args, *mellum._blocks(window, True))
 
     hlo = jax.jit(both).lower(q, kv, kv, q).compile().as_text()
-    assert hlo.count("tpu_custom_call") == 3
+    assert hlo.count("tpu_custom_call") == 2
     assert not re.search(r"\[\d+(,\d+)*,8192,8192\]", hlo)
     assert not re.search(r"bf16\[1,32,8192,128\]", hlo)  # no head-major copy
+    assert len(re.findall(r"f32\[1,32,8192,1\]", hlo.split("ENTRY")[1])) \
+        <= 3  # lse: the forward's result and the backward's operand
+
+
+def test_the_decoders_step_names_one_attention_backward_a_layer(
+        four_chips, monkeypatch):
+    """The decoder's loss and gradients through the TPU's own compiler, a
+    row of 8,192 tokens at the published attention widths (a window layer
+    and a full one; the experts, the vocabulary and the hidden size cut:
+    they are not what is counted): under ``rsdl.lm.attention`` a layer
+    has three Mosaic calls, its forward, its forward made again and one
+    backward, where the dq and dk/dv pair made four."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from chipbench import xplane
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(mellum, "on_tpu", lambda: True)
+    cfg = mellum.MellumConfig(
+        vocab_size=2048, hidden_size=256, layer_types=(mellum.SLIDING,
+                                                       mellum.FULL),
+        num_experts=8, experts_held=(0, 2), top_k=2, expert_width=128)
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mellum.init(cfg, jax.random.key(0))))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    hlo = jax.jit(jax.grad(lambda p, t: mellum.loss_fn(cfg, p, t))).lower(
+        params, tokens).compile().as_text()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    assert len(calls) == 3 * cfg.num_layers, calls
+    assert all(xplane.under_scope(c, mellum.ATTENTION_SCOPE) for c in calls)
+    assert len([c for c in calls if "_flash_attention_bwd" in c]) == (
+        cfg.num_layers), calls

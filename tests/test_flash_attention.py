@@ -240,12 +240,22 @@ def test_flash_bf16_operands_against_the_inline_path(rng, seq):
         assert gap(got, exact) <= max(1e-2 * scale, 1.5 * gap(inline, exact))
 
 
+@pytest.fixture(params=["fused", "split"])
+def blocked_kernels(request, monkeypatch):
+    """The blocked family's backward, both ways: the one kernel, which
+    every shape of these tests fits, and the dq + dk/dv pair that a
+    sequence too long for VMEM takes."""
+    if request.param == "split":
+        monkeypatch.setattr(fa, "_fused_fits", lambda *sizes: False)
+    return request.param
+
+
 @pytest.mark.parametrize("lse_covers", ["these_keys", "more_keys"])
-def test_one_block_backward_equals_the_two_kernel_backward(rng, lse_covers):
-    """The same inputs through the single backward kernel (the sequence
-    in one block) and through dq + dk/dv (blocks of 16) give the same
-    gradients, also with a global lse over keys this call does not hold
-    (a ring hop)."""
+def test_one_block_backward_equals_the_blocked_backward(rng, lse_covers,
+                                                        blocked_kernels):
+    """The same inputs through the one-block backward kernel and through
+    the blocked family's (blocks of 16) give the same gradients, also
+    with a global lse over keys this call does not hold (a ring hop)."""
     q, k, v = _qkv(rng)
     more_k, more_v = _qkv(rng)[:2]
     bias = jnp.where(jnp.asarray(rng.integers(0, 2, (B, S)))[:, None, None, :]
@@ -396,3 +406,164 @@ def test_flash_bert_loss_on_a_data_mesh_equals_one_device(rng, monkeypatch):
                                    rtol=1e-4, atol=1e-6)
         np.testing.assert_allclose(np.asarray(got), np.asarray(inline),
                                    rtol=5e-3, atol=2e-5)
+
+
+# -- the blocked family's backward: one kernel, or the pair past VMEM ----------
+
+
+def _plain_attention(q, k, v, bias, causal, window):
+    """(B, H, S, D) float32 softmax attention as XLA has it inline, k and
+    v of fewer heads, a key-side bias, the structural mask."""
+    s, group = q.shape[2], q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if bias is not None:
+        scores = scores + bias
+    ahead = jnp.arange(s)[:, None] - jnp.arange(k.shape[2])[None, :]
+    seen = jnp.ones(ahead.shape, bool)
+    if causal:
+        seen &= ahead >= 0
+    if window is not None:
+        seen &= ahead < window
+    weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
+
+
+def _packed(x):
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+@pytest.mark.parametrize(
+    "seq,heads,kv_heads,causal,window,biased,block,layout", [
+        (64, 2, 2, True, None, False, 16, "bhsd"),
+        (64, 2, 2, True, None, False, 16, "packed"),
+        (64, 2, 2, True, 5, False, 16, "packed"),
+        (64, 2, 2, True, 32, False, 16, "bhsd"),
+        (64, 2, 2, True, 23, False, 16, "packed"),
+        (48, 8, 2, True, None, False, 16, "bhsd"),
+        (48, 8, 2, True, 20, False, 16, "packed"),
+        (64, 2, 2, False, None, True, 16, "bhsd"),
+        (1000, 2, 1, True, None, False, 256, "packed"),
+        (1000, 1, 1, False, None, True, 256, "bhsd"),
+        (64, 2, 1, True, 23, False, 16, "own_delta"),
+    ], ids=["causal", "causal_in_place", "window_under_a_tile",
+            "window_of_two_tiles", "window_of_no_whole_tiles", "heads_8_to_2",
+            "heads_8_to_2_in_place_window", "key_bias_and_dbias",
+            "padded_1000_in_place", "padded_1000_key_bias",
+            "the_callers_delta"])
+def test_blocked_backward_is_the_float32_vjp(
+        seq, heads, kv_heads, causal, window, biased, block, layout,
+        blocked_kernels, monkeypatch):
+    """dq, dk, dv (and dbias) of the blocked family against ``jax.vjp`` of
+    the inline float32 attention: under each mask, with grouped heads, a
+    key bias, a length the chip pads (its plan under the interpreter),
+    both layouts, and with a delta the caller brings (``out`` unread)."""
+    if seq % block:
+        plan = fa._plan
+        monkeypatch.setattr(
+            fa, "_plan", lambda sq, sk, bq, bk, interpret: plan(
+                sq, sk, bq, bk, False))
+        assert fa._plan(seq, seq, block, block, True)[3] > seq
+    key = jax.random.key(seq + heads + (window or 0))
+    q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape)
+                   for i, shape in enumerate([
+                       (2, heads, seq, 16), (2, kv_heads, seq, 16),
+                       (2, kv_heads, seq, 16), (2, heads, seq, 16)]))
+    bias = None
+    if biased:
+        bias = jnp.where(jax.random.bernoulli(
+            jax.random.fold_in(key, 4), 0.7, (2, 1, 1, seq)), 0.0,
+            ra.NEG_INF).astype(jnp.float32)
+    want_out, vjp = jax.vjp(
+        lambda *a: _plain_attention(*a, causal, window), q, k, v, bias)
+    want = vjp(do)
+    mask = fa._Mask(causal, window)
+    if layout == "bhsd":
+        out, lse = fa._flash_forward(q, k, v, bias, block, block, True, mask)
+        got = fa.flash_backward(q, k, v, bias, out, lse, do, block, block,
+                                True, causal, window)
+    else:
+        operands = [_packed(x) for x in (q, k, v)]
+        out, lse = fa.grouped_forward(*operands, heads, kv_heads, causal,
+                                      window, block, block, True)
+        if layout == "packed":
+            got = fa.grouped_backward(*operands, out, lse, _packed(do), heads,
+                                      kv_heads, causal, window, block, block,
+                                      True)
+        else:
+            delta = fa._delta(do, want_out)[..., None]
+            got = fa._blocked_backward(
+                *operands, None, None, lse, _packed(do), mask, block, block,
+                True, True, heads, kv_heads, delta=delta)[:3]
+        want = [_packed(x) for x in want[:3]]
+        want_out = _packed(want_out)
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    assert len(got) == 3 or (got[3] is None) == (bias is None)
+    for grad, plain in zip(got, want):
+        if grad is not None:
+            np.testing.assert_allclose(grad, plain, atol=5e-5)
+
+
+def test_the_blocked_backward_is_one_kernel_where_dk_and_dv_fit(
+        blocked_kernels):
+    """One ``pallas_call`` in the backward's program, two past VMEM; and
+    which it is follows from the shape alone."""
+    q = jnp.zeros((1, 64, 4 * 16))
+    kv = jnp.zeros((1, 64, 2 * 16))
+    lse = jnp.zeros((1, 4, 64, 1))
+    jaxpr = jax.make_jaxpr(lambda *a: fa.grouped_backward(
+        *a, 4, 2, True, 20, 16, 16, True))(q, kv, kv, q, lse, q)
+    assert _pallas_calls(jaxpr.jaxpr) == {"fused": 1, "split": 2}[
+        blocked_kernels]
+    if blocked_kernels == "fused":
+        bf16, f32 = jnp.bfloat16, jnp.float32
+        # mellum_train_8k's layers, and what no longer fits
+        for seq, dtype, tile, kind in [
+                (8192, bf16, 1024, "fused"), (8192, bf16, 512, "fused"),
+                (8192, f32, 1024, "fused"), (16384, bf16, 1024, "fused"),
+                (16384, f32, 1024, "split"), (32768, bf16, 1024, "split")]:
+            assert fa._blocked_kind(seq, seq, 128, dtype, tile, tile,
+                                    False) == kind, (seq, dtype, tile)
+
+
+def _backward_counts():
+    from ray_shuffling_data_loader_tpu.runtime import metrics
+    counts = {}
+    for kind in ("one_block", "fused", "split"):
+        traced = metrics.get("rsdl_attention_backward_total", {"kind": kind})
+        counts[kind] = 0 if traced is None else traced.value
+    return counts
+
+
+def test_the_backward_counter_names_each_cells_kernel(monkeypatch):
+    """Traced at the cells' own shapes (no kernel runs): every layer of
+    ``mellum_train_8k`` takes the one blocked kernel, every layer of
+    ``bert_train`` the one-block kernel; one count a layer."""
+    from ray_shuffling_data_loader_tpu.models import mellum
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    cfg = mellum.mellum2_ep4_share()
+    q, kv = (jax.ShapeDtypeStruct((4, 8192, n * cfg.head_dim), jnp.bfloat16)
+             for n in (cfg.num_heads, cfg.num_kv_heads))
+
+    def traced(graded, *shapes):
+        before = _backward_counts()
+        jax.eval_shape(jax.grad(graded), *shapes)
+        return {kind: count - before[kind]
+                for kind, count in _backward_counts().items()}
+
+    def decoder(q, k, v):
+        return sum(mellum._attention(cfg, q, k, v, layer_type).astype(
+            jnp.float32).sum() for layer_type in cfg.layer_types)
+
+    assert traced(decoder, q, kv, kv) == {
+        "one_block": 0, "fused": len(cfg.layer_types), "split": 0}
+    base = bert.bert_base()
+    qkv = jax.ShapeDtypeStruct((32, 512, 3 * base.hidden_dim), jnp.bfloat16)
+
+    def encoder(qkv):
+        return sum(bert._attention(qkv, None, base.num_heads, None).astype(
+            jnp.float32).sum() for _ in range(base.num_layers))
+
+    assert traced(encoder, qkv) == {
+        "one_block": base.num_layers, "fused": 0, "split": 0}

@@ -310,15 +310,24 @@ def tiny_f32():
 def test_loss_and_every_gradient_match_the_reference(tiny_f32, monkeypatch):
     """Seeded weights from the reference's own initialiser, the program's
     tree: the loss and every leaf's gradient, the Pallas kernels
-    (interpreted) under the model's own custom_vjp. XLA's inline
-    attention, which a CPU run takes, is held to the same reference by
+    (interpreted, the one-kernel backward) under the model's own
+    custom_vjp. XLA's inline attention, which a CPU run takes, is held to the same reference by
     the cell's rehearsal (tests/chipbench/test_chipbench_mellum.py)."""
     cfg, sizes, params, tokens, (want_loss, want_grads) = tiny_f32
     assert jax.tree.structure(params) == jax.tree.structure(
         mellum.init(cfg, jax.random.key(0)))
     monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
+
+    def backwards(kind):
+        metric = metrics.get("rsdl_attention_backward_total", {"kind": kind})
+        return 0 if metric is None else metric.value
+
+    before = backwards("fused"), backwards("split")
     loss, grads = jax.value_and_grad(
         lambda p: mellum.loss_fn(cfg, p, tokens))(params)
+    # one backward kernel a layer, counted where the layer's rule ran
+    assert (backwards("fused"), backwards("split")) == (
+        before[0] + cfg.num_layers, before[1])
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=2e-6)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
