@@ -79,13 +79,16 @@ class SmokeSize:
     gather_batch: int
     attention_shape: Tuple[int, int, int]  # (batch, heads, head_dim)
     attention_seqs: Tuple[int, ...]   # in blocks, padded, one block
-    # the decoder's attention: (heads, key/value heads, head_dim), the
-    # windows (None: the whole triangle) and the lengths it is checked at
-    masked_attention: Tuple[int, int, int]
-    masked_attention_windows: Tuple[Optional[int], ...]
-    masked_attention_seqs: Tuple[int, ...]   # in blocks, padded
-    # its expert layer: (tokens, hidden, width, experts, held, top_k, tile)
-    moe_shape: Tuple[int, int, int, int, int, int, int]
+    # the decoder's attention, heads of ``masked_attention_dim``: (query
+    # heads, key/value heads, window (None: the whole triangle), whether a
+    # gate a query head multiplies the output, the lengths it is checked
+    # at: in blocks, padded)
+    masked_attention_dim: int
+    masked_attention: Tuple[Tuple[int, int, Optional[int], bool,
+                                  Tuple[int, ...]], ...]
+    # its expert layer: (tokens, hidden, width, experts, held, top_k, tile,
+    # what a token's weights sum to)
+    moe_shapes: Tuple[Tuple[int, int, int, int, int, int, int, float], ...]
     epochs: int = 2
 
 
@@ -98,10 +101,16 @@ def full_size() -> SmokeSize:
         gather_table=(max(cfg.vocab_sizes), cfg.embed_dim),
         gather_batch=2048, attention_shape=(2, 4, 64),
         attention_seqs=(2048, 1000, 512),
-        masked_attention=(8, 1, 128),
-        masked_attention_windows=(None, 1024),
-        masked_attention_seqs=(4096, 1000),
-        moe_shape=(8192, 2304, 896, 64, 16, 8, 1152))
+        masked_attention_dim=128,
+        masked_attention=(
+            (8, 1, None, False, (4096, 1000)),
+            (8, 1, 1024, False, (4096, 1000)),
+            # laguna_train_8k's layers: gated, 48 : 8 over the triangle and
+            # 64 : 8 under a window of 512
+            (48, 8, None, True, (2048,)),
+            (64, 8, 512, True, (2048,))),
+        moe_shapes=((8192, 2304, 896, 64, 16, 8, 1152, 1.0),
+                    (16384, 2048, 512, 256, 32, 8, 640, 2.5)))
 
 
 def tiny_size() -> SmokeSize:
@@ -115,9 +124,9 @@ def tiny_size() -> SmokeSize:
         model=cfg, batch_per_device=16, steps_per_epoch=8, num_files=2,
         learning_rate=1e-2, gather_table=(4096, 128), gather_batch=64,
         attention_shape=(1, 2, 32), attention_seqs=(256, 200, 64),
-        masked_attention=(4, 1, 16), masked_attention_windows=(24,),
-        masked_attention_seqs=(40,),
-        moe_shape=(48, 16, 8, 8, 2, 2, 8))
+        masked_attention_dim=16,
+        masked_attention=((4, 1, 24, False, (40,)), (6, 2, 8, True, (40,))),
+        moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -247,19 +256,22 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
                    "kernel")
         _check_attention(flash, seq, b, h, d)
     _masked_attention_checks(size, interpret)
-    _check_moe(size.moe_shape)
+    _check_partial_rotary(size.masked_attention_dim)
+    for shape in size.moe_shapes:
+        _check_moe(shape)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
     """The decoder's attention off its projections (B, S, H x D): grouped
-    heads, causal over the row and under a window, at a length in blocks
-    and at a padded one, against the masked float32 softmax."""
+    heads, causal over the row and under a window, with and without the
+    gate a query head, at a length in blocks and at a padded one, against
+    the masked float32 softmax."""
     import jax
     import jax.numpy as jnp
 
     from ray_shuffling_data_loader_tpu.models import mellum
 
-    heads, kv_heads, d = size.masked_attention
+    d = size.masked_attention_dim
 
     def packed(x):      # (B, H, S, D) -> (B, S, H x D), and back
         b, h, s, _ = x.shape
@@ -269,13 +281,23 @@ def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
         b, s, _ = x.shape
         return x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
-    for seq in size.masked_attention_seqs:
-        for span in size.masked_attention_windows:
+    def gate_of(q):
+        # (B, H, S) float32, a function of q: the gate's own gradient is
+        # then checked through dq
+        return jax.nn.sigmoid(q.astype(jnp.float32).mean(axis=-1))
+
+    for heads, kv_heads, span, gated, seqs in size.masked_attention:
+        for seq in seqs:
             # the decoder's own attention: its kernels, tiles and custom_vjp
             def flash(q, k, v):
+                gate = gate_of(q).transpose(0, 2, 1) if gated else None
                 return unpacked(mellum._flash_attention(
-                    packed(q), packed(k), packed(v), heads, kv_heads, span),
-                    heads)
+                    packed(q), packed(k), packed(v), gate, heads, kv_heads,
+                    span), heads)
+
+            def plain(q, k, v):
+                out = _plain_attention(q, k, v, causal=True, window=span)
+                return out * gate_of(q)[..., None] if gated else out
 
             if not interpret:
                 probe = jnp.zeros((1, heads, seq, d), jnp.bfloat16)
@@ -286,10 +308,44 @@ def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
                        "Mosaic kernel")
             _check_attention(
                 flash, seq, 1, heads, d, kv_heads=kv_heads, relative=True,
-                plain=functools.partial(_plain_attention, causal=True,
-                                        window=span),
+                plain=plain,
                 what=(f"{heads}:{kv_heads} heads of {d}, causal"
-                      + (f", window {span}" if span else "")))
+                      + (f", window {span}" if span else "")
+                      + (", gated" if gated else "")))
+
+
+def _check_partial_rotary(dim: int, tol: float = 2e-2) -> None:
+    """The decoder's rotary positions over the first half of a head (the
+    rotation as a bf16 product with a signed permutation, the other half
+    passing) against the float32 formula written out."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+
+    cfg = mellum.DecoderConfig(head_dim=dim, full_rotary_factor=0.5)
+    heads, seq, rotated = 4, 64, dim // 2
+    x = jax.random.normal(jax.random.key(5), (2, seq, heads * dim),
+                          jnp.bfloat16)
+    cos, sin = mellum._rope_tables(cfg, mellum.FULL, seq)
+    got = jax.jit(lambda x: mellum._rope(x, heads, cos, sin, rotated))(x)
+    xf = x.astype(jnp.float32).reshape(2, seq, heads, dim)
+    turn = xf[..., :rotated]
+    turned = jnp.concatenate([-turn[..., rotated // 2:],
+                              turn[..., :rotated // 2]], -1)
+    want = jnp.concatenate([
+        turn * cos[:, None, :rotated] + turned * sin[:, None, :rotated],
+        xf[..., rotated:]], -1).reshape(x.shape)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                / jnp.max(jnp.abs(want)))
+    same = bool(jnp.array_equal(
+        got.reshape(xf.shape)[..., rotated:],
+        x.reshape(xf.shape)[..., rotated:]))
+    _info(f"kernels: rotary over {rotated} of {dim} dimensions a head: "
+          f"max|bf16-f32| / max|f32| {err:.3e} (tol {tol:.0e}), the rest "
+          f"passed unchanged: {same}")
+    _check(err <= tol and same, "partial rotary differs from the formula: "
+           f"{err} > {tol}, or the passed dimensions changed")
 
 
 def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
@@ -302,7 +358,7 @@ def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
 
     from ray_shuffling_data_loader_tpu.ops import moe
 
-    tokens, hidden, width, experts, held, top_k, tile = shape
+    tokens, hidden, width, experts, held, top_k, tile, scale = shape
     keys = jax.random.split(jax.random.key(11), 6)
     x = jax.random.normal(keys[0], (tokens, hidden), jnp.bfloat16)
     router = 0.02 * jax.random.normal(keys[1], (hidden, experts))
@@ -312,12 +368,13 @@ def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
     mix = jax.random.normal(keys[5], (tokens, hidden))
 
     def walked(x, router, gate, up, down):
-        return moe.moe(x, router, gate, up, down, (0, held), top_k, tile)
+        return moe.moe(x, router, gate, up, down, (0, held), top_k, tile,
+                       scale)
 
     def plain(x, router, gate, up, down):
         with jax.default_matmul_precision("highest"):
             xf = x.astype(jnp.float32)
-            ids, weights = moe.route(xf @ router, top_k)
+            ids, weights = moe.route(xf @ router, top_k, scale)
             out = jnp.zeros_like(xf)
             for e in range(held):
                 w = jnp.sum(jnp.where(ids == e, weights, 0.0), axis=-1,
@@ -340,7 +397,8 @@ def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
 
     errs = [float(e) for e in errors(x, router, gate, up, down)]
     _info(f"kernels: expert layer {tokens} tokens x {hidden}, {held} of "
-          f"{experts} experts of {width}, top-{top_k}, tiles of {tile}: "
+          f"{experts} experts of {width}, top-{top_k}, weights summing to "
+          f"{scale}, tiles of {tile}: "
           "max|walk-f32| / max|f32| out, d tokens, d gate, d down "
           + ", ".join(f"{e:.3e}" for e in errs) + f" (tol {tol:.0e})")
     _check(max(errs) <= tol, "the expert layer's walk differs from the "

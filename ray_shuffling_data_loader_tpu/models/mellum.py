@@ -1,33 +1,40 @@
 """A sparse-expert causal decoder with window and full attention mixed:
-one chip's share of a Mellum-2-style language model.
+one chip's share of a language model, by its configuration
+(``mellum2_ep4_share``, ``laguna_xs2_ep8_share``).
 
-Per layer, on the residual stream: RMSNorm, grouped-query attention
-(``num_heads`` query heads over ``num_kv_heads`` key/value heads, no
-biases) with rotary positions, then RMSNorm and a sparse-expert SwiGLU
-layer with no shared expert. ``layer_types`` gives each layer's attention:
-``sliding_attention`` is causal within the last ``sliding_window`` keys
-under plain rotary positions, ``full_attention`` is causal over the whole
-row under YaRN's (interpolated and extrapolated frequencies blended once,
-whatever the sequence length; cos and sin scaled by its attention factor).
-After the last layer RMSNorm and an untied head; the loss is the mean
-next-token negative log-likelihood. Same functional API as the other
-families: ``init``, ``loss_fn``.
+Per layer, on the residual stream: RMSNorm, grouped-query attention (a
+layer's own count of query heads over ``num_kv_heads`` key/value heads,
+no biases) with rotary positions, optionally a sigmoid gate a query head
+on the attention's output (``attention_gate``), then RMSNorm and the
+layer's MLP: a dense SwiGLU (``mlp_layer_types``) or a sparse-expert
+SwiGLU layer whose routed sum is scaled (``routed_scale``) and may have a
+shared expert beside it (``shared_expert_width``). ``layer_types`` gives
+each layer's attention: ``sliding_attention`` is causal within the last
+``sliding_window`` keys under plain rotary positions, ``full_attention``
+is causal over the whole row under YaRN's (interpolated and extrapolated
+frequencies blended once, whatever the sequence length; cos and sin
+scaled by its attention factor) over the first ``full_rotary_factor`` of
+a head's dimensions. After the last layer RMSNorm and an untied head; the
+loss is the mean next-token negative log-likelihood. Same functional API
+as the other families: ``init``, ``loss_fn``.
 
 Under expert parallelism a chip holds ``experts_held = (first, count)`` of
 the router's ``num_experts`` and a slice of the vocabulary: the expert
 layer routes over all the experts and adds what the held ones give
-(``ops/moe.py``), and the logits, ids and loss are over the slice. On one
-chip the layer runs without its exchange.
+(``ops/moe.py``), and the logits, ids and loss are over the slice. What
+every chip computes alike (attention, a dense layer, the shared expert) is
+whole here. On one chip the layer runs without its exchange.
 
 TPU-first choices:
-- bf16 compute, float32 parameters; the router, every softmax, the norms
-  and the rotary tables in float32.
+- bf16 compute, float32 parameters; the router, every softmax, the norms,
+  the gate's sigmoid and the rotary tables in float32.
 - Attention through the blocked Pallas kernels (``ops/flash_attention.py``)
   on the chip: they read q, k and v where the projections left them, fetch
-  a key/value head once for its eight query heads, and visit only the key
-  blocks a query block can see (a window layer's walk is as long as its
-  band). Off the chip, and below the measured crossover, XLA's masked
-  softmax over materialized scores.
+  a key/value head once for its group of query heads, and visit only the
+  key blocks a query block can see (a window layer's walk is as long as
+  its band). Off the chip, and below the measured crossover, XLA's masked
+  softmax over materialized scores. The head gate multiplies the kernel's
+  output under the same scope.
 - Every layer is made again in the backward pass (``jax.checkpoint``), a
   half at a time: a half keeps its bf16 input and nothing else.
 - The head's loss walks blocks of tokens, forward and backward, so that no
@@ -50,11 +57,15 @@ from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 
 IGNORE_ID = -100
 SLIDING, FULL = "sliding_attention", "full_attention"
+DENSE, SPARSE = "dense", "sparse"
 
-# The names a device trace shows a layer's attention's, its expert
-# layer's (``ops/moe.py``) and the head's operations under.
+# The names a device trace shows a layer's projections' (q, k, v, the gate
+# and ``wo``), its attention's, its expert layer's (``ops/moe.py``), its
+# dense MLP's or shared expert's and the head's operations under.
+PROJ_SCOPE = "rsdl.lm.proj"
 ATTENTION_SCOPE = "rsdl.lm.attention"
 MOE_SCOPE = moe.SCOPE
+MLP_SCOPE = "rsdl.lm.mlp"
 HEAD_SCOPE = "rsdl.lm.head"
 
 
@@ -70,20 +81,31 @@ class YarnConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class MellumConfig:
+class DecoderConfig:
     vocab_size: int = 24_576
     hidden_size: int = 2304
     layer_types: Tuple[str, ...] = (SLIDING, SLIDING, SLIDING, FULL)
+    # each layer's MLP, DENSE or SPARSE; None: every layer sparse
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
     num_heads: int = 32
+    # each layer's query heads; None: ``num_heads`` in every layer
+    heads_per_layer: Optional[Tuple[int, ...]] = None
     num_kv_heads: int = 4
     head_dim: int = 128
+    attention_gate: bool = False  # a sigmoid gate a query head on the output
     sliding_window: int = 1024
+    intermediate_size: int = 0    # a dense layer's SwiGLU width
     num_experts: int = 64                    # the router's width
     experts_held: Tuple[int, int] = (0, 16)  # (first, count) held here
     top_k: int = 8
     expert_width: int = 896
+    shared_expert_width: int = 0  # 0: no shared expert
+    routed_scale: float = 1.0     # what a token's routing weights sum to
     rms_norm_eps: float = 1e-6
     rope_theta: float = 500_000.0
+    sliding_rope_theta: Optional[float] = None   # None: ``rope_theta``
+    # the share of a head's dimensions a full layer rotates (the first)
+    full_rotary_factor: float = 1.0
     yarn: YarnConfig = YarnConfig()
     compute_dtype: Any = jnp.bfloat16
     published_layers: int = 28    # the uncut depth: scales ``init`` only
@@ -92,73 +114,138 @@ class MellumConfig:
     def num_layers(self) -> int:
         return len(self.layer_types)
 
+    def heads(self, layer: int) -> int:
+        """Query heads of layer ``layer``."""
+        return (self.num_heads if self.heads_per_layer is None
+                else self.heads_per_layer[layer])
 
-def mellum2_ep4_share() -> MellumConfig:
+    def mlp_type(self, layer: int) -> str:
+        return (SPARSE if self.mlp_layer_types is None
+                else self.mlp_layer_types[layer])
+
+
+def mellum2_ep4_share() -> DecoderConfig:
     """Mellum2-12B-A2.5B at its published widths, cut to one chip of four
     that share each layer by expert parallelism: 16 of the 64 experts, a
     quarter of the 98,304-id vocabulary, and one period of the layer
     pattern (three window layers, one full) of the 28 layers."""
-    return MellumConfig()
+    return DecoderConfig()
 
 
-def mellum_tiny() -> MellumConfig:
+def mellum_tiny() -> DecoderConfig:
     """For tests/CPU smoke runs: the same pattern, 8 experts of which
     the first two are held, top-2, window 8."""
-    return MellumConfig(vocab_size=512, hidden_size=64, num_heads=4,
-                        num_kv_heads=2, head_dim=16, sliding_window=8,
-                        num_experts=8, experts_held=(0, 2), top_k=2,
-                        expert_width=32)
+    return DecoderConfig(vocab_size=512, hidden_size=64, num_heads=4,
+                         num_kv_heads=2, head_dim=16, sliding_window=8,
+                         num_experts=8, experts_held=(0, 2), top_k=2,
+                         expert_width=32)
 
 
-def init(config: MellumConfig, key: jax.Array) -> Dict[str, Any]:
+_LAGUNA_PATTERN = dict(
+    layer_types=(FULL, SLIDING, SLIDING, SLIDING, FULL),
+    mlp_layer_types=(DENSE, SPARSE, SPARSE, SPARSE, SPARSE),
+    attention_gate=True, routed_scale=2.5, sliding_rope_theta=10_000.0,
+    full_rotary_factor=0.5, published_layers=40,
+    yarn=YarnConfig(factor=64.0, original_max_position_embeddings=4096,
+                    beta_fast=64.0, beta_slow=1.0,
+                    attention_factor=1.4158883083359672))
+
+
+def laguna_xs2_ep8_share() -> DecoderConfig:
+    """Laguna-XS.2 at its published widths, cut to one chip of eight that
+    share each layer by expert parallelism: 32 of the 256 experts, an
+    eighth of the 100,352-id vocabulary, and the published layers 0-4 of
+    40: the dense leading layer (full attention) and one period after it
+    (three window layers of 64 query heads, one full of 48)."""
+    return DecoderConfig(
+        vocab_size=12_544, hidden_size=2048, num_heads=48,
+        heads_per_layer=(48, 64, 64, 64, 48), num_kv_heads=8, head_dim=128,
+        sliding_window=512, intermediate_size=8192, num_experts=256,
+        experts_held=(0, 32), top_k=8, expert_width=512,
+        shared_expert_width=512, **_LAGUNA_PATTERN)
+
+
+def laguna_tiny() -> DecoderConfig:
+    """For tests/CPU smoke runs: Laguna's pattern (a dense first layer,
+    heads by layer, a head gate, a shared expert, half a head rotated in
+    the full layers), 8 experts of which the first two are held, top-2,
+    window 8."""
+    return DecoderConfig(
+        vocab_size=512, hidden_size=64, num_heads=6,
+        heads_per_layer=(6, 8, 8, 8, 6), num_kv_heads=2, head_dim=16,
+        sliding_window=8, intermediate_size=128, num_experts=8,
+        experts_held=(0, 2), top_k=2, expert_width=32,
+        shared_expert_width=32, **_LAGUNA_PATTERN)
+
+
+def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     """Seeded float32 weights: the embedding N(0, 1), matrices N(0, 0.02),
-    the two projections that write into the residual stream (``wo``,
+    the projections that write into the residual stream (``wo``, every
     ``down``) N(0, 0.02 / sqrt(2 x ``published_layers``)) (Megatron's
     scaled init), unit norm scales. At 0.02 everywhere the mean of a
     thousand values that uniform attention over random tokens makes
     outweighs a token's own embedding, and every token of a row routes
     alike."""
     h, f = config.hidden_size, config.expert_width
-    q_width = config.num_heads * config.head_dim
     kv_width = config.num_kv_heads * config.head_dim
     held = config.experts_held[1]
     residual = 0.02 / math.sqrt(2 * config.published_layers)
-    keys = iter(jax.random.split(key, 2 + 8 * config.num_layers))
+    keys = iter(jax.random.split(key, 2 + 12 * config.num_layers))
 
     def normal(shape, std=0.02):
         return std * jax.random.normal(next(keys), shape, jnp.float32)
+
+    def swiglu(prefix, shape, width):
+        return {f"{prefix}gate": normal((*shape, h, width)),
+                f"{prefix}up": normal((*shape, h, width)),
+                f"{prefix}down": normal((*shape, width, h), residual)}
 
     params: Dict[str, Any] = {"embed": normal((config.vocab_size, h), 1.0),
                               "head": normal((h, config.vocab_size)),
                               "final_norm": jnp.ones((h,), jnp.float32)}
     for layer in range(config.num_layers):
-        params[f"layer_{layer}"] = {
-            "attn_norm": jnp.ones((h,), jnp.float32),
-            "wq": normal((h, q_width)),
-            "wk": normal((h, kv_width)),
-            "wv": normal((h, kv_width)),
-            "wo": normal((q_width, h), residual),
-            "moe_norm": jnp.ones((h,), jnp.float32),
-            "router": normal((h, config.num_experts)),
-            "gate": normal((held, h, f)),
-            "up": normal((held, h, f)),
-            "down": normal((held, f, h), residual),
-        }
+        heads = config.heads(layer)
+        q_width = heads * config.head_dim
+        lp = {"attn_norm": jnp.ones((h,), jnp.float32),
+              "wq": normal((h, q_width)),
+              "wk": normal((h, kv_width)),
+              "wv": normal((h, kv_width)),
+              "wo": normal((q_width, h), residual)}
+        if config.attention_gate:
+            lp["wg"] = normal((h, heads))
+        if config.mlp_type(layer) == DENSE:
+            lp["mlp_norm"] = jnp.ones((h,), jnp.float32)
+            lp.update(swiglu("", (), config.intermediate_size))
+        else:
+            lp["moe_norm"] = jnp.ones((h,), jnp.float32)
+            lp["router"] = normal((h, config.num_experts))
+            lp.update(swiglu("", (held,), f))
+            if config.shared_expert_width:
+                lp.update(swiglu("shared_", (), config.shared_expert_width))
+        params[f"layer_{layer}"] = lp
     return params
 
 
 # -- rotary positions ----------------------------------------------------------
 
 
-def rope_inv_freq(config: MellumConfig, layer_type: str):
-    """``(inverse frequencies (head_dim / 2,), scale of cos and sin)`` of
-    a layer's rotary positions: plain for a window layer, YaRN's for a
+def rotated_dims(config: DecoderConfig, layer_type: str) -> int:
+    """How many of a head's dimensions (the first) a layer rotates."""
+    share = config.full_rotary_factor if layer_type == FULL else 1.0
+    return int(config.head_dim * share)
+
+
+def rope_inv_freq(config: DecoderConfig, layer_type: str):
+    """``(inverse frequencies (rotated dims / 2,), scale of cos and sin)``
+    of a layer's rotary positions: plain for a window layer, YaRN's for a
     full one: the interpolated frequencies (divided by ``factor``) below
     ``beta_slow`` rotations over the original context, the extrapolated
     ones above ``beta_fast``, a linear ramp between."""
-    dim = config.head_dim
-    pos_freqs = config.rope_theta ** (
-        jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    dim = rotated_dims(config, layer_type)
+    theta = config.rope_theta
+    if layer_type != FULL and config.sliding_rope_theta is not None:
+        theta = config.sliding_rope_theta
+    pos_freqs = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     if layer_type != FULL:
         return 1.0 / pos_freqs, 1.0
     yarn = config.yarn
@@ -166,7 +253,7 @@ def rope_inv_freq(config: MellumConfig, layer_type: str):
     def correction_dim(rotations: float) -> float:
         return (dim * math.log(yarn.original_max_position_embeddings
                                / (rotations * 2 * math.pi))
-                / (2 * math.log(config.rope_theta)))
+                / (2 * math.log(theta)))
 
     low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
     high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
@@ -178,33 +265,38 @@ def rope_inv_freq(config: MellumConfig, layer_type: str):
     return inv_freq, yarn.attention_factor
 
 
-def _rope_tables(config: MellumConfig, layer_type: str, seq_len: int):
-    """cos and sin, (S, head_dim) float32, a frequency at lanes d and
-    d + head_dim / 2 (the rotate-half convention)."""
+def _rope_tables(config: DecoderConfig, layer_type: str, seq_len: int):
+    """cos and sin, (S, head_dim) float32: over the rotated dimensions a
+    frequency at lanes d and d + rotated / 2 (the rotate-half convention),
+    over the rest 1 and 0 (they pass)."""
     inv_freq, scale = rope_inv_freq(config, layer_type)
     angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
     angles = jnp.concatenate([angles, angles], axis=-1)
-    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+    passed = ((0, 0), (0, config.head_dim - angles.shape[1]))
+    return (jnp.pad(jnp.cos(angles) * scale, passed, constant_values=1.0),
+            jnp.pad(jnp.sin(angles) * scale, passed))
 
 
-def _rotate_half(dim: int, dtype):
-    """(D, D) of 0 and +-1: ``x @ it`` is ``concat(-x2, x1)`` for
-    ``x = concat(x1, x2)``. As a product on the MXU (exact: a signed
+def _rotate_half(dim: int, rotated: int, dtype):
+    """(D, D) of 0 and +-1: ``x @ it`` is ``concat(-x2, x1, 0)`` for
+    ``x = concat(x1, x2, rest)``, x1 and x2 the halves of the first
+    ``rotated`` dimensions. As a product on the MXU (exact: a signed
     permutation) the rotation fuses into one pass over q; as slices and a
     concatenate XLA made a dozen float32 passes of it, 150 ms of the
     8k-token cell's step on a v5e (PERF.md section 6, PR 32)."""
-    half = dim // 2
+    half = rotated // 2
     swap = jnp.zeros((dim, dim), dtype)
     swap = swap.at[jnp.arange(half) + half, jnp.arange(half)].set(-1)
     return swap.at[jnp.arange(half), jnp.arange(half) + half].set(1)
 
 
-def _rope(x, heads: int, cos, sin):
-    """(B, S, heads x D) -> the same, each head's D rotated by position."""
+def _rope(x, heads: int, cos, sin, rotated: int):
+    """(B, S, heads x D) -> the same, the first ``rotated`` of each head's
+    D rotated by position."""
     b, s, width = x.shape
     x = x.reshape(b, s, heads, width // heads)
     turned = jnp.einsum("bshd,de->bshe", x,
-                        _rotate_half(x.shape[-1], x.dtype))
+                        _rotate_half(x.shape[-1], rotated, x.dtype))
     out = (x.astype(jnp.float32) * cos[:, None, :]
            + turned.astype(jnp.float32) * sin[:, None, :])
     return out.astype(x.dtype).reshape(b, s, width)
@@ -213,10 +305,20 @@ def _rope(x, heads: int, cos, sin):
 # -- attention -----------------------------------------------------------------
 
 
+def _gated(out, gate, heads: int):
+    """``out`` (B, S, H x D) with each head's D times its ``gate``
+    (B, S, H) float32; ``out`` itself where there is no gate."""
+    if gate is None:
+        return out
+    b, s, _ = out.shape
+    return (out.reshape(b, s, heads, -1).astype(jnp.float32)
+            * gate[..., None]).astype(out.dtype).reshape(out.shape)
+
+
 # The attentions are jitted for the scope's sake, as models/bert.py's: inside
 # a program of its own the name reaches the compiled step as written.
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _inline_attention(q, k, v, heads: int, kv_heads: int,
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
                       window: Optional[int]):
     """Causal grouped-query attention as XLA has it: float32 softmax over
     materialized (B, H, S, S) scores; the backward is autodiff's."""
@@ -232,50 +334,71 @@ def _inline_attention(q, k, v, heads: int, kv_heads: int,
             seen &= ahead < window
         weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v)
-        return out.reshape(b, s, -1)
+        return _gated(out.reshape(b, s, -1), gate, heads)
 
 
 def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
-    """The kernels' tiles: the defaults, but half a window in a window
-    layer's backward, whole lanes at least. A band of 1,024 keys is two
-    live tiles of 1024 x 1024 a query block, both cut by the mask, or
-    three of 512 x 512 with one whole. Measured on a v5e (4 rows of
-    8,192, 32 : 4 heads of 128, ms). The one-kernel backward (PR 33;
-    the dq + dk/dv pair it replaced in brackets), under a window of
-    1,024: tiles of 256 22.2 (47.5), 512 15.5 (27.5), 1024 17.7 (29.0),
-    512 x 1024 18.3, 1024 x 512 18.4; the whole triangle: 1024 40.6
-    (61.5), 512 46.2 (76.7), 512 x 1024 43.2, 1024 x 512 42.5. The
+    """The kernels' tiles: the defaults over the whole triangle; in a
+    window layer the window's width in the forward and half of it in the
+    backward, neither under 512 nor over the default. A band of 1,024
+    keys is two live tiles of 1024 x 1024 a query block, both cut by the
+    mask, or three of 512 x 512 with one whole; a band of 512 is two live
+    tiles of 512 x 512, both cut, or three of 256 x 256 with one whole,
+    and a tile of 256 costs more in steps than it saves in area.
+
+    Measured on a v5e, bf16, heads of 128, ms. **At ``mellum_train_8k``'s
+    shapes** (4 rows of 8,192, 32 : 4 heads). The one-kernel backward
+    (PR 33; the dq + dk/dv pair it replaced in brackets), under a window
+    of 1,024: tiles of 256 22.2 (47.5), 512 15.5 (27.5), 1024 17.7
+    (29.0), 512 x 1024 18.3, 1024 x 512 18.4; the whole triangle: 1024
+    40.6 (61.5), 512 46.2 (76.7), 512 x 1024 43.2, 1024 x 512 42.5. The
     forward (PR 32), window / triangle: 256 23.6, 512 12.9 / 38.2, 1024
-    10.1 / 22.1."""
+    10.1 / 22.1. **At ``laguna_train_8k``'s shapes** (PR 34; 2 rows of
+    8,192; forward / backward). 64 : 8 heads under a window of 512: tiles
+    of 128 29.5 / 34.0, 256 15.6 / 14.8, 512 9.6 / 11.3, 1024 9.9 / 17.7,
+    256 x 512 11.2 / 14.7, 512 x 256 16.2 / 14.2, 128 x 512 16.9 / 19.0,
+    512 x 128 31.4 / 24.4. 48 : 8 heads over the whole triangle: 1024
+    16.9 / 30.9, 512 28.9 / 35.2, 512 x 1024 19.4 / 32.9, 1024 x 512
+    30.2 / 32.4, 2048 x 1024 17.6 / 34.4."""
     side = flash_attention.DEFAULT_BLOCK_Q
-    if window is not None and backward:
-        side = min(side, max(128, window // 2))
+    if window is not None:
+        side = min(side, max(512, window // 2 if backward else window))
     return side, side
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention(q, k, v, heads: int, kv_heads: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_attention(q, k, v, gate, heads: int, kv_heads: int,
                      window: Optional[int]):
     """``_inline_attention``'s result from the blocked Pallas kernels."""
-    return _flash_attention_fwd(q, k, v, heads, kv_heads, window)[0]
+    return _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window)[0]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _flash_attention_fwd(q, k, v, heads, kv_heads, window):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window):
     with jax.named_scope(ATTENTION_SCOPE):
         out, lse = flash_attention.grouped_forward(
             q, k, v, heads, kv_heads, True, window, *_blocks(window, False),
             interpret=not on_tpu())
-    return out, (q, k, v, out, lse)
+        return _gated(out, gate, heads), (q, k, v, gate, out, lse)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent):
-    q, k, v, out, lse = residuals
+    q, k, v, gate, out, lse = residuals
     with jax.named_scope(ATTENTION_SCOPE):
-        return flash_attention.grouped_backward(
+        d_gate = None
+        if gate is not None:
+            # gated = gate x out: the kernels' cotangent is gate x d gated,
+            # the gate's is sum over a head's D of d gated x out
+            b, s, _ = out.shape
+            by_head = cotangent.reshape(b, s, heads, -1).astype(jnp.float32)
+            d_gate = jnp.sum(
+                by_head * out.reshape(by_head.shape).astype(jnp.float32),
+                axis=-1)
+            cotangent = _gated(cotangent, gate, heads)
+        return (*flash_attention.grouped_backward(
             q, k, v, out, lse, cotangent, heads, kv_heads, True, window,
-            *_blocks(window, True), interpret=not on_tpu())
+            *_blocks(window, True), interpret=not on_tpu()), d_gate)
 
 
 def _counted_flash_attention_bwd(heads, kv_heads, window, residuals,
@@ -290,10 +413,13 @@ def _counted_flash_attention_bwd(heads, kv_heads, window, residuals,
 _flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
 
 
-def _attention(config: MellumConfig, q, k, v, layer_type: str):
+def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
+               heads: int):
     """A layer's attention over rotated q (B, S, H x D) and k, v
-    (B, S, Hkv x D), by what the trace can observe: the kernels where they
-    beat the inline path (``flash_attention.beats_inline``)."""
+    (B, S, Hkv x D), each query head's output times its ``gate``
+    (B, S, H) where there is one, by what the trace can observe: the
+    kernels where they beat the inline path
+    (``flash_attention.beats_inline``)."""
     seq_len = q.shape[1]
     window = config.sliding_window if layer_type == SLIDING else None
     if window is not None and window >= seq_len:
@@ -308,7 +434,64 @@ def _attention(config: MellumConfig, q, k, v, layer_type: str):
         kind=("inline" if not flash else
               "window" if window is not None else "full")).inc()
     attend = _flash_attention if flash else _inline_attention
-    return attend(q, k, v, config.num_heads, config.num_kv_heads, window)
+    return attend(q, k, v, gate, heads, config.num_kv_heads, window)
+
+
+# -- the dense MLP and the shared expert -----------------------------------------
+
+
+@jax.custom_vjp
+def _swiglu(x, gate, up, down):
+    """``(silu(x G) * (x U)) D`` for x (B, S, h) in the compute dtype and
+    float32 weights G, U (h, f), D (f, h), cast to x's dtype for the
+    products. The backward is written out: it keeps ``x G`` and ``x U``
+    and makes nothing again."""
+    return _swiglu_fwd(x, gate, up, down)[0]
+
+
+# Jitted for their names' sake (models/bert.py:_masked_nll_fwd).
+@jax.jit
+def _swiglu_fwd(x, gate, up, down):
+    with jax.named_scope(MLP_SCOPE):
+        g = x @ gate.astype(x.dtype)
+        u = x @ up.astype(x.dtype)
+        h = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(x.dtype)
+        return h @ down.astype(x.dtype), (x, gate, up, down, g, u)
+
+
+@jax.jit
+def _swiglu_bwd(residuals, dy):
+    x, gate, up, down, g, u = residuals
+    with jax.named_scope(MLP_SCOPE):
+        tokens = (((0, 1), (0, 1)), ((), ()))    # a.T @ b over (B, S)
+
+        def weight_grad(a, b):
+            return jax.lax.dot_general(a, b, tokens,
+                                       preferred_element_type=jnp.float32)
+
+        dy = dy.astype(x.dtype)
+        g32, u32 = g.astype(jnp.float32), u.astype(jnp.float32)
+        sig = jax.nn.sigmoid(g32)
+        act = g32 * sig
+        dh = (dy @ down.astype(x.dtype).T).astype(jnp.float32)
+        du = (dh * act).astype(x.dtype)
+        dg = (dh * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(x.dtype)
+        dx = dg @ gate.astype(x.dtype).T + du @ up.astype(x.dtype).T
+        return (dx, weight_grad(x, dg), weight_grad(x, du),
+                weight_grad((act * u32).astype(x.dtype), dy))
+
+
+_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+def _mlp(kind: str, x, gate, up, down):
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_lm_mlp_total",
+        "Dense SwiGLUs traced, by what they are: a dense layer's MLP or a "
+        "sparse-expert layer's shared expert", kind=kind).inc()
+    return _swiglu(x, gate, up, down)
 
 
 # -- the decoder ---------------------------------------------------------------
@@ -321,9 +504,11 @@ def _rms_norm(x, scale, eps: float):
     return (normed * scale).astype(x.dtype)
 
 
-def _experts(config: MellumConfig, x, lp):
+def _experts(config: DecoderConfig, x, lp):
     """The held experts' part of a layer's sparse-expert sum, (B, S, h)."""
     first, count = config.experts_held
+    b, s, h = x.shape
+    tile = moe.tile_rows(b * s, config.top_k, config.num_experts)
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_moe_layer_total",
@@ -338,31 +523,73 @@ def _experts(config: MellumConfig, x, lp):
     rt_metrics.gauge("rsdl_moe_top_k",
                      "Experts a token picks, last layer traced"
                      ).set(config.top_k)
-    b, s, h = x.shape
+    rt_metrics.gauge("rsdl_moe_tile_rows",
+                     "Rows of one expert in a tile of the expert layer's "
+                     "walk, last layer traced").set(tile)
     out = moe.moe(x.reshape(b * s, h), lp["router"], lp["gate"], lp["up"],
-                  lp["down"], config.experts_held, config.top_k)
+                  lp["down"], config.experts_held, config.top_k, tile,
+                  config.routed_scale)
     return out.reshape(b, s, h)
 
 
-def _attention_half(config: MellumConfig, layer_type: str, x, lp):
+# Jitted for the scope's sake, as the attentions: the products of an
+# attention half that are no kernel's (q, k, v, the gate, ``wo``), their
+# weight gradients and the forward made again. The backward is autodiff's.
+@jax.jit
+def _project(x, weight):
+    """``x @ weight``, the float32 ``weight`` cast to x's dtype."""
+    with jax.named_scope(PROJ_SCOPE):
+        return x @ weight.astype(x.dtype)
+
+
+def _attention_half(config: DecoderConfig, layer: int, x, lp):
     """x + attention(RMSNorm(x)), the first half of a layer."""
-    dtype = config.compute_dtype
+    layer_type, heads = config.layer_types[layer], config.heads(layer)
     cos, sin = _rope_tables(config, layer_type, x.shape[1])
+    rotated = rotated_dims(config, layer_type)
     a = _rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
-    q = _rope(a @ lp["wq"].astype(dtype), config.num_heads, cos, sin)
-    k = _rope(a @ lp["wk"].astype(dtype), config.num_kv_heads, cos, sin)
-    v = a @ lp["wv"].astype(dtype)
-    return x + _attention(config, q, k, v, layer_type) @ lp["wo"].astype(
-        dtype)
+    q = _rope(_project(a, lp["wq"]), heads, cos, sin, rotated)
+    k = _rope(_project(a, lp["wk"]), config.num_kv_heads, cos, sin, rotated)
+    v = _project(a, lp["wv"])
+    gate = (jax.nn.sigmoid(_project(a, lp["wg"]).astype(jnp.float32))
+            if config.attention_gate else None)
+    return x + _project(_attention(config, q, k, v, gate, layer_type, heads),
+                        lp["wo"])
 
 
-def _expert_half(config: MellumConfig, x, lp):
-    """x + experts(RMSNorm(x)), the second half of a layer."""
-    return x + _experts(
-        config, _rms_norm(x, lp["moe_norm"], config.rms_norm_eps), lp)
+def _mlp_half(config: DecoderConfig, layer: int, x, lp):
+    """x + MLP(RMSNorm(x)), the second half of a layer: the dense SwiGLU,
+    or the held experts' part of the routed sum and the shared expert."""
+    if config.mlp_type(layer) == DENSE:
+        n = _rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
+        return x + _mlp("dense", n, lp["gate"], lp["up"], lp["down"])
+    n = _rms_norm(x, lp["moe_norm"], config.rms_norm_eps)
+    out = x + _experts(config, n, lp)
+    if config.shared_expert_width:
+        out = out + _mlp("shared", n, lp["shared_gate"], lp["shared_up"],
+                         lp["shared_down"])
+    return out
 
 
-def decode(config: MellumConfig, params: Dict[str, Any],
+def _checked(config: DecoderConfig) -> None:
+    first, count = config.experts_held
+    if first + count > config.num_experts:
+        raise ValueError(f"experts_held {config.experts_held} reaches past "
+                         f"the router's {config.num_experts} experts")
+    for name, kinds, known in (
+            ("layer_types", config.layer_types, (SLIDING, FULL)),
+            ("mlp_layer_types", config.mlp_layer_types, (DENSE, SPARSE))):
+        for kind in kinds or ():
+            if kind not in known:
+                raise ValueError(f"unknown {name} entry {kind!r}")
+    for name in ("mlp_layer_types", "heads_per_layer"):
+        per_layer = getattr(config, name)
+        if per_layer is not None and len(per_layer) != config.num_layers:
+            raise ValueError(f"{name} names {len(per_layer)} layers, "
+                             f"layer_types {config.num_layers}")
+
+
+def decode(config: DecoderConfig, params: Dict[str, Any],
            token_ids: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
     """token_ids (B, S) int32 -> hidden states (B, S, hidden) in the
     compute dtype, after the last layer's residual (before the final
@@ -376,22 +603,18 @@ def decode(config: MellumConfig, params: Dict[str, Any],
             "the decoder runs one chip's share of an expert-parallel "
             f"deployment; a mesh of {mesh.size} devices needs the expert "
             "layer's exchange across chips, which does not exist yet")
-    if config.experts_held[0] + config.experts_held[1] > config.num_experts:
-        raise ValueError(f"experts_held {config.experts_held} reaches past "
-                         f"the router's {config.num_experts} experts")
+    _checked(config)
     x = jnp.take(params["embed"], token_ids, axis=0,
                  mode="clip").astype(config.compute_dtype)
-    for layer, layer_type in enumerate(config.layer_types):
-        if layer_type not in (SLIDING, FULL):
-            raise ValueError(f"unknown layer type {layer_type!r}")
+    for layer in range(config.num_layers):
         # Each half is made again on its own in the backward pass: the
-        # expert half's backward runs before the attention half's q, k, v
+        # MLP half's backward runs before the attention half's q, k, v
         # and lse exist again, so the two halves' activations never sit
         # on the chip together (a half keeps its bf16 input).
         lp = params[f"layer_{layer}"]
         x = jax.checkpoint(functools.partial(
-            _attention_half, config, layer_type))(x, lp)
-        x = jax.checkpoint(functools.partial(_expert_half, config))(x, lp)
+            _attention_half, config, layer))(x, lp)
+        x = jax.checkpoint(functools.partial(_mlp_half, config, layer))(x, lp)
     return x
 
 
@@ -501,7 +724,7 @@ def next_token_targets(token_ids: jax.Array) -> jax.Array:
         axis=1)
 
 
-def loss_fn(config: MellumConfig, params: Dict[str, Any],
+def loss_fn(config: DecoderConfig, params: Dict[str, Any],
             token_ids: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
     """Mean next-token cross-entropy over the ``S - 1`` shifted positions
     of each row of ``token_ids`` (B, S), over this chip's slice of the

@@ -48,18 +48,9 @@ import jax.numpy as jnp
 #: sort, grouped products and combine, forward and backward.
 SCOPE = "rsdl.lm.moe"
 
-#: Rows of one expert in a tile of the walk. Larger tiles leave more of
-#: each expert's last tile empty; smaller ones make the loop long, and a
-#: tile's fixed cost (its index arithmetic, its gathers, the in-place adds
-#: of three expert gradients) is 0.26 ms in the backward on a v5e.
-#: Measured there (PR 32; 32,768 tokens x 2304, 16 of 64 experts of 896,
-#: top-8; forward / forward and backward, ms): 256 33.3 / 125.0, 512
-#: 31.4 / 82.8, 1024 30.9 / 64.7. Nine lanes' worth and not 1024: at
-#: 4,096 tokens an expert in expectation (a deployment's load at 8k-token
-#: rows; 3,730-4,530 under a balanced router) a power of two puts every
-#: expert on a tile's edge, 4 tiles or 5 by the seed's luck, and the walk
-#: is a tenth longer or shorter for it; 4 x 1152 holds every such load.
-DEFAULT_TILE = 1152
+#: The most rows of one expert an even routing puts in a tile of the walk
+#: (``tile_rows``).
+_TILE_LOAD = 1024
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -71,13 +62,15 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def route(logits, top_k: int):
+def route(logits, top_k: int, scale: float = 1.0):
     """``(ids (N, top_k) int32, weights (N, top_k) float32)``: softmax over
     all the experts in float32, the ``top_k`` largest, renormalised to sum
-    to one (``norm_topk_prob``). Differentiable in the weights."""
+    to ``scale`` (``norm_topk_prob``, then a model's
+    ``moe_routed_scaling_factor``). Differentiable in the weights."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top, ids = jax.lax.top_k(probs, top_k)
-    return ids.astype(jnp.int32), top / top.sum(axis=-1, keepdims=True)
+    return (ids.astype(jnp.int32),
+            scale * top / top.sum(axis=-1, keepdims=True))
 
 
 def _router_logits(x, router):
@@ -88,12 +81,41 @@ def _router_logits(x, router):
                                precision=jax.lax.Precision.HIGHEST)
 
 
-def _router_weights(x, router, top_k: int):
-    return route(_router_logits(x, router), top_k)[1]
+def _router_weights(x, router, top_k: int, scale: float):
+    return route(_router_logits(x, router), top_k, scale)[1]
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def tile_rows(tokens: int, top_k: int, experts: int) -> int:
+    """Rows of one expert in a tile of the walk, from the static shapes:
+    an expert's expected rows under an even routing (``tokens x top_k /
+    experts``) cut into as few tiles as hold ``_TILE_LOAD`` rows each, and
+    an eighth of room, in whole lanes. Larger tiles leave more of each
+    expert's last tile empty; smaller ones make the loop long, and a
+    tile's fixed cost (its index arithmetic, its gathers, the in-place
+    adds of three expert gradients) is 0.26 ms in the backward on a v5e.
+    The room and not the bare share: a power of two at the expected load
+    puts every expert on a tile's edge, one tile more or fewer by the
+    seed's luck, and the walk is longer or shorter for it.
+
+    Measured on a v5e, forward / forward and backward, ms. At
+    ``mellum_train_8k``'s shapes (PR 32; 32,768 tokens x 2304, 16 of 64
+    experts of 896, top-8: 4,096 rows an expert, 3,730-4,530 under a
+    balanced router): tiles of 256 33.3 / 125.0, 512 31.4 / 82.8, 1024
+    30.9 / 64.7; the rule gives 1152, and 4 x 1152 holds every such load
+    (PR 34: 1024 21.3 / 54.8, 1152 21.0 / 52.4). At ``laguna_train_8k``'s
+    (PR 34; 16,384 tokens x 2048, 32 of 256 experts of 512, top-8: 512
+    rows an expert, 413-581 under a balanced router): 256 8.7 / 17.9, 384
+    9.3 / 17.9, 512 8.6 / 16.1, 576 8.4 / 15.2, 640 8.4 / 15.1, 768
+    8.7 / 15.7, 1024 9.8 / 17.3, 1152 10.0 / 17.6; the rule gives 640,
+    one tile an expert."""
+    expected = -(-tokens * top_k // experts)
+    tiles = -(-expected // _TILE_LOAD)
+    share = -(-expected // tiles)
+    return _round_up(share + share // 8, 128)
 
 
 def round_rows(tokens: int, top_k: int, count: int, experts: int,
@@ -231,9 +253,9 @@ def _gathered(buffer, index, spare: int):
         return summed[jnp.argsort(order)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
-        tile: int = DEFAULT_TILE):
+        tile: int, scale: float = 1.0):
     """The held experts' part of a sparse-expert layer.
 
     Args:
@@ -245,20 +267,22 @@ def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
         held: ``(first, count)``: this chip holds experts ``first ..
             first + count`` of the router's.
         top_k: experts a token picks.
-        tile: rows of one expert in a tile of the walk.
+        tile: rows of one expert in a tile of the walk (the caller's:
+            :func:`tile_rows` has the rule the decoder follows).
+        scale: what a token's weights sum to (``route``).
 
     Returns (N, hidden) in ``x``'s dtype: for each token the sum over its
     picks that are held of weight x expert(token).
     """
-    return _moe_fwd(x, router, gate, up, down, held, top_k, tile)[0]
+    return _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale)[0]
 
 
 # Jitted for the scope's sake, as models/bert.py's head: inside a program
 # of its own (and inside a loop's body) the name reaches the compiled step
 # as written. The ``while`` instructions themselves carry no scope of the
 # program's, so nothing is counted twice.
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
-def _moe_fwd(x, router, gate, up, down, held, top_k, tile):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale):
     first, count = held
     tokens, hidden = x.shape
     if gate.shape[0] != count:
@@ -266,7 +290,7 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile):
                          f"{gate.shape[0]}")
     rows = round_rows(tokens, top_k, count, router.shape[1], tile)
     with jax.named_scope(SCOPE):
-        ids, weights = route(_router_logits(x, router), top_k)
+        ids, weights = route(_router_logits(x, router), top_k, scale)
         plan, position = _dispatch(ids, first, count, tile)
         flat = weights.reshape(-1)
         g16, u16, d16 = (w.astype(x.dtype) for w in (gate, up, down))
@@ -292,8 +316,8 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile):
     return out, (x, router, gate, up, down, weights, plan, position)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _moe_bwd(held, top_k, tile, residuals, dout):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _moe_bwd(held, top_k, tile, scale, residuals, dout):
     x, router, gate, up, down, weights, plan, position = residuals
     tokens, hidden = x.shape
     rows = round_rows(tokens, top_k, held[1], router.shape[1], tile)
@@ -342,7 +366,7 @@ def _moe_bwd(held, top_k, tile, residuals, dout):
         plan, position, rows, tile, tile_fn, gather_fn, grads, buffers)
     with jax.named_scope(SCOPE):
         _, router_vjp = jax.vjp(
-            lambda x, r: _router_weights(x, r, top_k), x, router)
+            lambda x, r: _router_weights(x, r, top_k, scale), x, router)
         d_x_router, d_router = router_vjp(d_weights)
         d_x = (d_x + d_x_router.astype(jnp.float32)).astype(x.dtype)
     return d_x, d_router, d_gate, d_up, d_down

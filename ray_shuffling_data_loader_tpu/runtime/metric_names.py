@@ -47,15 +47,18 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    the models when a layer's backward is traced, not when it runs;
     #    kind = one_block | fused | split) --
     "rsdl_attention_backward_total": ("counter", ("kind",)),
-    # -- a decoder layer's attention and its sparse-expert layer
-    #    (models/mellum.py; counted or set when a layer is traced, not when
-    #    it runs; attention's kind = window | full | inline, the expert
-    #    layer's = share | all of the router's experts held here) --
+    # -- a decoder layer's attention, its dense SwiGLUs and its
+    #    sparse-expert layer (models/mellum.py; counted or set when a layer
+    #    is traced, not when it runs; attention's kind = window | full |
+    #    inline, a SwiGLU's = dense | shared, the expert layer's = share |
+    #    all of the router's experts held here) --
     "rsdl_lm_attention_total": ("counter", ("kind",)),
+    "rsdl_lm_mlp_total": ("counter", ("kind",)),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_experts_held": ("gauge", ()),
     "rsdl_moe_experts_routed": ("gauge", ()),
     "rsdl_moe_top_k": ("gauge", ()),
+    "rsdl_moe_tile_rows": ("gauge", ()),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

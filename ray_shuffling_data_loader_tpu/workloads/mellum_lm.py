@@ -1,5 +1,6 @@
 """Causal-LM workload: packed pre-tokenized rows -> next-token training of
-the sparse-expert decoder (``models/mellum.py``).
+the sparse-expert decoder (``models/mellum.py``), at either of its
+configurations.
 
 Rows are fixed-length packed token sequences stored as
 ``FixedSizeList<int32>`` columns, 32 KB a row at 8,192 tokens: the shuffle
@@ -37,8 +38,8 @@ def make_loss(config):
 
 if __name__ == "__main__":
     # Smoke driver: packed shards -> shuffle -> device feed -> SpmdTrainer
-    # over one device. The defaults are the tiny preset; --full trains one
-    # chip's share of Mellum2-12B-A2.5B at its published widths (a TPU).
+    # over one device. The defaults are a tiny preset; --full trains one
+    # chip's share of the model at its published widths (a TPU).
     import argparse
     import tempfile
     import timeit
@@ -49,9 +50,15 @@ if __name__ == "__main__":
     parser.add_argument("--num-epochs", type=int, default=2)
     parser.add_argument("--batch-size", type=int, default=4)
     parser.add_argument("--seq-len", type=int, default=64)
+    parser.add_argument("--model", choices=("mellum", "laguna"),
+                        default="mellum",
+                        help="the decoder's configuration: Mellum2-12B-A2.5B "
+                        "or Laguna-XS.2 (its step fills the chip at "
+                        "--batch-size 2)")
     parser.add_argument("--full", action="store_true",
                         help="the published widths (models.mellum."
-                        "mellum2_ep4_share) at 8,192-token rows")
+                        "mellum2_ep4_share / laguna_xs2_ep8_share) at "
+                        "8,192-token rows")
     args = parser.parse_args()
 
     import jax
@@ -63,7 +70,10 @@ if __name__ == "__main__":
     from ray_shuffling_data_loader_tpu.parallel.trainer import SpmdTrainer
     from ray_shuffling_data_loader_tpu.plan import ir as plan_ir
 
-    cfg = mellum.mellum2_ep4_share() if args.full else mellum.mellum_tiny()
+    tiny, full = {"mellum": (mellum.mellum_tiny, mellum.mellum2_ep4_share),
+                  "laguna": (mellum.laguna_tiny,
+                             mellum.laguna_xs2_ep8_share)}[args.model]
+    cfg = full() if args.full else tiny()
     seq_len = 8192 if args.full else args.seq_len
     with tempfile.TemporaryDirectory() as tmpdir:
         filenames, _ = generate_packed_parquet(

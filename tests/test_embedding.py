@@ -438,31 +438,38 @@ def test_bert_attention_lowers_for_four_chips_only_with_the_mesh(
         _bert_base_step(four_chips, monkeypatch, None, on_mesh)
 
 
-@pytest.mark.parametrize("layer_type", ["sliding_attention",
-                                        "full_attention"])
-def test_the_decoders_attention_compiles_for_the_chip(four_chips, layer_type):
-    """A layer of ``mellum_train_8k`` of either kind through the TPU's own
-    compiler, a row of 8,192 tokens at the published widths: two Mosaic
-    kernels (forward, and one backward that holds a key/value head's dk
-    and dv in VMEM) that read 32 query heads of 128 and their 4
-    key/value heads where the projections left them, with a grid as long
-    as the band (``tests/test_mellum.py``) and no (S, S) array, nor
-    delta's (B, H, S, 1) column."""
+@pytest.mark.parametrize("build,layer", [
+    ("mellum2_ep4_share", 0), ("mellum2_ep4_share", 3),
+    ("laguna_xs2_ep8_share", 1), ("laguna_xs2_ep8_share", 4)],
+    ids=["sliding_attention", "full_attention", "laguna_sliding_64_to_8",
+         "laguna_full_48_to_8"])
+def test_the_decoders_attention_compiles_for_the_chip(four_chips, build,
+                                                      layer):
+    """A layer of ``mellum_train_8k`` or ``laguna_train_8k`` of either
+    kind through the TPU's own compiler, a row of 8,192 tokens at the
+    published widths: two Mosaic kernels (forward, and one backward that
+    holds a key/value head's dk and dv in VMEM) that read the layer's
+    query heads of 128 (32 over 4 key/value heads; 64 or 48 over 8, from
+    rows 8,192 or 6,144 wide) where the projections left them, with a
+    grid as long as the band (``tests/test_mellum.py``) and no (S, S)
+    array, nor delta's (B, H, S, 1) column."""
     import re
 
     from jax.sharding import SingleDeviceSharding
 
     from ray_shuffling_data_loader_tpu.models import mellum
     from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
-    cfg = mellum.mellum2_ep4_share()
-    window = cfg.sliding_window if layer_type == mellum.SLIDING else None
+    cfg = getattr(mellum, build)()
+    heads = cfg.heads(layer)
+    window = (cfg.sliding_window
+              if cfg.layer_types[layer] == mellum.SLIDING else None)
     one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
-    q, kv = (jax.ShapeDtypeStruct((1, 8192, heads * cfg.head_dim),
+    q, kv = (jax.ShapeDtypeStruct((1, 8192, n * cfg.head_dim),
                                   jnp.bfloat16, sharding=one_chip)
-             for heads in (cfg.num_heads, cfg.num_kv_heads))
+             for n in (heads, cfg.num_kv_heads))
 
     def both(q, k, v, do):
-        args = (cfg.num_heads, cfg.num_kv_heads, True, window)
+        args = (heads, cfg.num_kv_heads, True, window)
         out, lse = fa.grouped_forward(
             q, k, v, *args, *mellum._blocks(window, False))
         return fa.grouped_backward(
@@ -470,9 +477,13 @@ def test_the_decoders_attention_compiles_for_the_chip(four_chips, layer_type):
 
     hlo = jax.jit(both).lower(q, kv, kv, q).compile().as_text()
     assert hlo.count("tpu_custom_call") == 2
-    assert not re.search(r"\[\d+(,\d+)*,8192,8192\]", hlo)
-    assert not re.search(r"bf16\[1,32,8192,128\]", hlo)  # no head-major copy
-    assert len(re.findall(r"f32\[1,32,8192,1\]", hlo.split("ENTRY")[1])) \
+    # no scores: (B, S, 64 x 128) is 8,192 wide itself, a head's scores
+    # would be (B, H, S, S)
+    assert not re.search(r"\[\d+,\d+(,\d+)*,8192,8192\]", hlo)
+    # no head-major copy
+    assert not re.search(rf"bf16\[1,{heads},8192,128\]", hlo)
+    assert len(re.findall(rf"f32\[1,{heads},8192,1\]",
+                          hlo.split("ENTRY")[1])) \
         <= 3  # lse: the forward's result and the backward's operand
 
 
@@ -493,7 +504,7 @@ def test_the_decoders_step_names_one_attention_backward_a_layer(
     from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
     monkeypatch.setattr(fa, "on_tpu", lambda: True)
     monkeypatch.setattr(mellum, "on_tpu", lambda: True)
-    cfg = mellum.MellumConfig(
+    cfg = mellum.DecoderConfig(
         vocab_size=2048, hidden_size=256, layer_types=(mellum.SLIDING,
                                                        mellum.FULL),
         num_experts=8, experts_held=(0, 2), top_k=2, expert_width=128)

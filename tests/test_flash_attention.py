@@ -553,7 +553,8 @@ def test_the_backward_counter_names_each_cells_kernel(monkeypatch):
                 for kind, count in _backward_counts().items()}
 
     def decoder(q, k, v):
-        return sum(mellum._attention(cfg, q, k, v, layer_type).astype(
+        return sum(mellum._attention(cfg, q, k, v, None, layer_type,
+                                     cfg.num_heads).astype(
             jnp.float32).sum() for layer_type in cfg.layer_types)
 
     assert traced(decoder, q, kv, kv) == {

@@ -1,8 +1,10 @@
-"""The sparse-expert decoder (models/mellum.py), its expert layer
-(ops/moe.py) and the masked, grouped attention kernels
-(ops/flash_attention.py) against the plain reference
-(chipbench/references/mellum.py), at tiny sizes on the CPU."""
+"""The sparse-expert decoder (models/mellum.py) at both of its
+configurations, its expert layer (ops/moe.py) and the masked, grouped
+attention kernels (ops/flash_attention.py) against the plain references
+(chipbench/references/mellum.py, chipbench/references/laguna.py), at tiny
+sizes on the CPU."""
 
+import dataclasses
 import math
 import re
 
@@ -12,6 +14,7 @@ import numpy as np
 import pytest
 
 from chipbench import xplane
+from chipbench.references import laguna as laguna_ref
 from chipbench.references import mellum as ref
 from ray_shuffling_data_loader_tpu.models import mellum
 from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
@@ -19,18 +22,25 @@ from ray_shuffling_data_loader_tpu.ops import moe
 from ray_shuffling_data_loader_tpu.runtime import metrics
 
 
-def _sizes(cfg: mellum.MellumConfig, seq_len: int, held=None):
-    """The reference's view of a program configuration."""
+def _sizes(cfg: mellum.DecoderConfig, seq_len: int, held=None):
+    """Either reference's view of a program configuration."""
     first, count = cfg.experts_held if held is None else held
     yarn = cfg.yarn
+    layers = range(cfg.num_layers)
     return {
         "hidden_size": cfg.hidden_size, "head_dim": cfg.head_dim,
         "num_attention_heads": cfg.num_heads,
+        "num_attention_heads_per_layer": [cfg.heads(i) for i in layers],
         "num_key_value_heads": cfg.num_kv_heads,
         "num_hidden_layers": cfg.num_layers,
         "layer_types": list(cfg.layer_types),
+        "mlp_layer_types": [cfg.mlp_type(i) for i in layers],
+        "gating": cfg.attention_gate,
         "sliding_window": cfg.sliding_window,
+        "intermediate_size": cfg.intermediate_size,
         "moe_intermediate_size": cfg.expert_width,
+        "shared_expert_intermediate_size": cfg.shared_expert_width,
+        "moe_routed_scaling_factor": cfg.routed_scale,
         "num_experts": count, "experts_held_first": first,
         "num_experts_routed": cfg.num_experts,
         "num_experts_per_tok": cfg.top_k, "vocab_size": cfg.vocab_size,
@@ -43,9 +53,13 @@ def _sizes(cfg: mellum.MellumConfig, seq_len: int, held=None):
                 "original_max_position_embeddings":
                     yarn.original_max_position_embeddings,
                 "beta_fast": yarn.beta_fast, "beta_slow": yarn.beta_slow,
-                "attention_factor": yarn.attention_factor},
-            "sliding_attention": {"rope_type": "default",
-                                  "rope_theta": cfg.rope_theta}},
+                "attention_factor": yarn.attention_factor,
+                "partial_rotary_factor": cfg.full_rotary_factor},
+            "sliding_attention": {
+                "rope_type": "default",
+                "rope_theta": (cfg.rope_theta
+                               if cfg.sliding_rope_theta is None
+                               else cfg.sliding_rope_theta)}},
     }
 
 
@@ -162,13 +176,23 @@ def test_a_window_needs_a_causal_mask_and_a_mask_is_no_tensor():
 # -- rotary positions ----------------------------------------------------------------
 
 
-def test_yarn_frequencies_are_the_formula_written_out():
+@pytest.mark.parametrize(
+    "build,reference,dim,factor,original,fast,plain_base,scale", [
+        (mellum.mellum2_ep4_share, ref, 128, 16.0, 8192, 32.0, 500_000.0,
+         1.2772588722239782),
+        # half a head rotates in a full layer: 64 dimensions take the
+        # head's place in the formulas; the window layers' theta is 10,000
+        (mellum.laguna_xs2_ep8_share, laguna_ref, 64, 64.0, 4096, 64.0,
+         10_000.0, 1.4158883083359672),
+    ], ids=["mellum", "laguna_half_a_head"])
+def test_yarn_frequencies_are_the_formula_written_out(
+        build, reference, dim, factor, original, fast, plain_base, scale):
     """Peng et al. 2023 as the source's ``rope_parameters`` state it: below
     ``beta_slow`` turns over the original context a frequency is
     interpolated (divided by ``factor``), above ``beta_fast`` it is kept,
     between the two a linear ramp over the dimensions."""
-    cfg = mellum.mellum2_ep4_share()
-    dim, base, factor, original = 128, 500_000.0, 16.0, 8192
+    cfg = build()
+    base = 500_000.0
     want = []
     for i in range(dim // 2):
         plain = base ** (-2.0 * i / dim)
@@ -177,22 +201,22 @@ def test_yarn_frequencies_are_the_formula_written_out():
             return dim * math.log(original / (turns * 2 * math.pi)) / (
                 2 * math.log(base))
 
-        low, high = math.floor(where(32.0)), math.ceil(where(1.0))
+        low, high = math.floor(where(fast)), math.ceil(where(1.0))
         ramp = min(max((i - low) / (high - low), 0.0), 1.0)
         want.append(plain / factor * ramp + plain * (1.0 - ramp))
     assert 0 < low < high < dim // 2      # all three regimes are present
     sizes = _sizes(cfg, 8192)
-    for got, scale in (mellum.rope_inv_freq(cfg, mellum.FULL),
-                       ref.inv_freq(sizes, ref.FULL)):
+    for got, got_scale in (mellum.rope_inv_freq(cfg, mellum.FULL),
+                           reference.inv_freq(sizes, ref.FULL)):
         np.testing.assert_allclose(got, want, rtol=1e-5)
-        assert scale == 1.2772588722239782
+        assert got_scale == scale
         assert abs(scale - (0.1 * math.log(factor) + 1.0)) < 1e-12
-    for got, scale in (mellum.rope_inv_freq(cfg, mellum.SLIDING),
-                       ref.inv_freq(sizes, ref.SLIDING)):
+    for got, got_scale in (mellum.rope_inv_freq(cfg, mellum.SLIDING),
+                           reference.inv_freq(sizes, ref.SLIDING)):
         np.testing.assert_allclose(
-            got, [base ** (-2.0 * i / dim) for i in range(dim // 2)],
+            got, [plain_base ** (-2.0 * i / 128) for i in range(64)],
             rtol=1e-5)
-        assert scale == 1.0
+        assert got_scale == 1.0
 
 
 # -- the expert layer ----------------------------------------------------------------
@@ -226,19 +250,27 @@ def _tied(experts):
     return router.at[:, jnp.asarray(experts)].set(1.0)
 
 
-@pytest.mark.parametrize("held,router", [
-    ((2, 2), _favouring([2, 3])),         # every pick is held
-    ((2, 2), _favouring([0, 7])),         # none is
-    ((2, 2), _favouring([7, 2])),         # one held expert takes every token
-    ((2, 2), _tied([2, 3, 4])),           # ties: three equal, two picked
-    ((2, 2), None),                       # any routing, a share held
-    ((0, 8), None),                       # any routing, all held
+@pytest.mark.parametrize("held,router,tile,scale", [
+    ((2, 2), _favouring([2, 3]), 8, 1.0),   # every pick is held
+    ((2, 2), _favouring([0, 7]), 8, 1.0),   # none is
+    ((2, 2), _favouring([7, 2]), 8, 1.0),   # one held expert takes every
+                                            # token
+    ((2, 2), _tied([2, 3, 4]), 8, 1.0),     # ties: three equal, two picked
+    ((2, 2), None, 8, 1.0),                 # any routing, a share held
+    ((0, 8), None, 8, 1.0),                 # any routing, all held
+    ((2, 2), None, None, 2.5),              # the tile the shapes give (one
+                                            # of 128 rows an expert), the
+                                            # routed sum scaled
+    ((2, 2), _favouring([7, 2]), None, 2.5),
 ], ids=["every_pick_held", "none_held", "one_expert_takes_all", "ties",
-        "random_share", "random_all_held"])
-def test_the_expert_layer_is_exact_for_any_routing(held, router):
+        "random_share", "random_all_held", "ruled_tile_scaled",
+        "ruled_tile_one_expert_takes_all"])
+def test_the_expert_layer_is_exact_for_any_routing(held, router, tile,
+                                                   scale):
     """No capacity, no dropped token: output and all five gradients equal
     the plain loop of dense products under masks, walked in tiles of 8
-    rows (several tiles an expert, the last part empty)."""
+    rows (several tiles an expert, the last part empty) or of what
+    ``moe.tile_rows`` gives."""
     key = jax.random.key(held[1])
     x = jnp.abs(jax.random.normal(key, (24, _HIDDEN)))
     if router is None:
@@ -247,12 +279,16 @@ def test_the_expert_layer_is_exact_for_any_routing(held, router):
     weights = _expert_weights(jax.random.fold_in(key, 2), held[1])
     mix = jax.random.normal(jax.random.fold_in(key, 3), x.shape)
 
+    if tile is None:
+        tile = moe.tile_rows(x.shape[0], _TOP_K, _EXPERTS)
+
     def program(x, router, w):
         return moe.moe(x, router, w["gate"], w["up"], w["down"], held,
-                       _TOP_K, 8)
+                       _TOP_K, tile, scale)
 
     def plain(x, router, w):
-        return ref._experts(_moe_sizes(*held), x, dict(w, router=router))
+        return scale * ref._experts(_moe_sizes(*held), x,
+                                    dict(w, router=router))
 
     np.testing.assert_allclose(program(x, router, weights),
                                plain(x, router, weights), rtol=2e-5,
@@ -269,23 +305,73 @@ def test_the_expert_layer_is_exact_for_any_routing(held, router):
         not np.asarray(program(x, router, weights)).any())
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer():
-    """Eight experts over four chips, two each: the shares' outputs
-    summed are what the uncut reference gives for the whole layer (the
-    router, which every chip computes alike, counted once)."""
+def test_the_walks_tile_follows_the_shapes():
+    """1,152 rows at ``mellum_train_8k``'s shapes (4,096 rows an expert in
+    four tiles with an eighth of room), 640 at ``laguna_train_8k``'s (512
+    rows an expert in one), whole lanes at any."""
+    assert moe.tile_rows(4 * 8192, 8, 64) == 1152
+    assert moe.tile_rows(2 * 8192, 8, 256) == 640
+    assert moe.tile_rows(24, _TOP_K, _EXPERTS) == 128
+    for tokens, top_k, experts in ((4 * 8192, 8, 64), (2 * 8192, 8, 256),
+                                   (8192, 8, 256), (100, 2, 8)):
+        tile = moe.tile_rows(tokens, top_k, experts)
+        expected = -(-tokens * top_k // experts)
+        tiles = -(-expected // tile)
+        assert tile % 128 == 0 and tiles * tile >= expected * 1.1
+        assert tile <= 1152 + 128
+
+
+@pytest.mark.parametrize("shares,scale,shared", [
+    (4, 1.0, False),    # mellum2-12b-a2.5b-ep4: two of eight experts a chip
+    (8, 2.5, True),     # laguna-xs.2-ep8: one of eight a chip, the routed
+                        # sum scaled, a shared expert every chip computes
+], ids=["four_shares", "eight_shares_and_the_shared_expert_once"])
+def test_the_shares_add_up_to_the_uncut_layer(shares, scale, shared):
+    """Eight experts over the chips that share a layer: the shares'
+    routed parts summed, with what every chip computes alike (the router;
+    the shared expert) counted once, are what the uncut reference gives
+    for the whole layer."""
     key = jax.random.key(7)
     x = jax.random.normal(key, (24, _HIDDEN))
     router = jax.random.normal(jax.random.fold_in(key, 1),
                                (_HIDDEN, _EXPERTS))
     whole = _expert_weights(jax.random.fold_in(key, 2), _EXPERTS)
-    total = sum(
-        moe.moe(x, router, *(whole[n][first:first + 2]
-                             for n in ("gate", "up", "down")),
-                (first, 2), _TOP_K, 8)
-        for first in range(0, _EXPERTS, 2))
-    uncut = ref._experts(_moe_sizes(0, _EXPERTS), x,
-                         dict(whole, router=router))
-    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
+    each = _EXPERTS // shares
+    firsts = range(0, _EXPERTS, each)
+
+    def held(first):
+        return {n: whole[n][first:first + each]
+                for n in ("gate", "up", "down")}
+
+    if not shared:
+        total = sum(moe.moe(x, router, *held(first).values(), (first, each),
+                            _TOP_K, 8, scale) for first in firsts)
+        uncut = ref._experts(_moe_sizes(0, _EXPERTS), x,
+                             dict(whole, router=router))
+    else:
+        # a chip's sparse half as the program computes it, less the
+        # residual: its routed part and the shared expert
+        cfg = dataclasses.replace(
+            mellum.laguna_tiny(), hidden_size=_HIDDEN, expert_width=_WIDTH,
+            shared_expert_width=_WIDTH, compute_dtype=jnp.float32)
+        assert (cfg.routed_scale, cfg.top_k, cfg.mlp_type(1)) == (
+            scale, _TOP_K, mellum.SPARSE)
+        lone = {f"shared_{n}": w[0] for n, w in _expert_weights(
+            jax.random.fold_in(key, 3), 1).items()}
+        lp = dict(whole, router=router, moe_norm=jnp.ones((_HIDDEN,)),
+                  **lone)
+        halves = [mellum._mlp_half(
+            dataclasses.replace(cfg, experts_held=(first, each)), 1,
+            x[None], {**lp, **held(first)})[0] - x for first in firsts]
+        once = laguna_ref._swiglu(
+            laguna_ref._rms_norm(x, lp["moe_norm"], cfg.rms_norm_eps),
+            *lone.values())
+        total = sum(half - once for half in halves) + once
+        uncut = laguna_ref.mlp_half(_sizes(cfg, 24, (0, _EXPERTS)), 1, lp,
+                                    x) - x
+    # float32 sums of values near 50: eight halves less seven shared experts
+    tol = 1e-4 if shared else 2e-5
+    np.testing.assert_allclose(total, uncut, rtol=tol, atol=tol)
 
 
 # -- the decoder against the reference ----------------------------------------------
@@ -293,27 +379,43 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 _SEQ = 32
 
 
-@pytest.fixture(scope="module")
-def tiny_f32():
+def _mellum_f32():
     # one window layer and one full one: both kinds, half the compiling
-    cfg = mellum.MellumConfig(**{
-        **mellum.mellum_tiny().__dict__, "compute_dtype": jnp.float32,
-        "layer_types": (mellum.SLIDING, mellum.FULL)})
+    return ref, dataclasses.replace(
+        mellum.mellum_tiny(), compute_dtype=jnp.float32,
+        layer_types=(mellum.SLIDING, mellum.FULL))
+
+
+def _laguna_f32():
+    # the dense leading layer (full attention, 6 heads), a window layer of
+    # 8 heads and a full one of 6, both sparse with the shared expert
+    return laguna_ref, dataclasses.replace(
+        mellum.laguna_tiny(), compute_dtype=jnp.float32,
+        layer_types=(mellum.FULL, mellum.SLIDING, mellum.FULL),
+        mlp_layer_types=(mellum.DENSE, mellum.SPARSE, mellum.SPARSE),
+        heads_per_layer=(6, 8, 6))
+
+
+@pytest.fixture(scope="module", params=[_mellum_f32, _laguna_f32],
+                ids=["mellum", "laguna"])
+def tiny_f32(request):
+    reference, cfg = request.param()
     sizes = _sizes(cfg, _SEQ)
-    params = ref.init_params(sizes, jax.random.key(3))
+    params = reference.init_params(sizes, jax.random.key(3))
     tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
                                 cfg.vocab_size, jnp.int32)
-    return cfg, sizes, params, tokens, ref.value_and_grad(
-        sizes, params, [tokens], None, 0)
+    return cfg, sizes, params, tokens, reference.value_and_grad(
+        sizes, params, [tokens], None, 0), reference
 
 
 def test_loss_and_every_gradient_match_the_reference(tiny_f32, monkeypatch):
     """Seeded weights from the reference's own initialiser, the program's
     tree: the loss and every leaf's gradient, the Pallas kernels
     (interpreted, the one-kernel backward) under the model's own
-    custom_vjp. XLA's inline attention, which a CPU run takes, is held to the same reference by
-    the cell's rehearsal (tests/chipbench/test_chipbench_mellum.py)."""
-    cfg, sizes, params, tokens, (want_loss, want_grads) = tiny_f32
+    custom_vjp. XLA's inline attention, which a CPU run takes, is held to
+    the same references by the cells' rehearsals
+    (tests/chipbench/test_chipbench_mellum.py, test_chipbench_laguna.py)."""
+    cfg, sizes, params, tokens, (want_loss, want_grads), reference = tiny_f32
     assert jax.tree.structure(params) == jax.tree.structure(
         mellum.init(cfg, jax.random.key(0)))
     monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
@@ -334,8 +436,53 @@ def test_loss_and_every_gradient_match_the_reference(tiny_f32, monkeypatch):
         np.testing.assert_allclose(
             got, want, rtol=2e-3, atol=2e-6,
             err_msg=jax.tree_util.keystr(path))
-    assert ref.param_count(sizes) == sum(
+    assert reference.param_count(sizes) == sum(
         x.size for x in jax.tree.leaves(params))
+
+
+def test_lagunas_parameter_count_and_flops():
+    """691,623,936 parameters in the cut (11.07 GB at 16 bytes) and 33.44 B
+    at the published sizes, the published 33.4 B: the gate is one value a
+    query head; 19.7 TFLOP a row of 8,192 tokens, of which the
+    projections 43 %, attention's products 31 %, the dense layer 12.55 %,
+    the head 6.4 %, the shared and the routed experts 3.1 % each, the
+    router 0.5 %; 448 keys a query in a window layer."""
+    cfg = mellum.laguna_xs2_ep8_share()
+    sizes = _sizes(cfg, 8192)
+    assert laguna_ref.param_count(sizes) == 691_623_936
+    assert sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: mellum.init(cfg, jax.random.key(0))))) == 691_623_936
+    period = [(mellum.FULL, 48), (mellum.SLIDING, 64), (mellum.SLIDING, 64),
+              (mellum.SLIDING, 64)]
+    published = {
+        **sizes, "num_hidden_layers": 40, "num_experts": 256,
+        "vocab_size": 100_352,
+        "layer_types": [t for t, _ in period] * 10,
+        "num_attention_heads_per_layer": [n for _, n in period] * 10,
+        "mlp_layer_types": [mellum.DENSE] + 39 * [mellum.SPARSE]}
+    assert round(laguna_ref.param_count(published) / 1e9, 2) == 33.44
+    gates = 2048 * 10 * (48 + 3 * 64)
+    assert round((laguna_ref.param_count(published) + 127 * gates) / 1e9,
+                 1) == 34.1, "a gate as wide as q would not be 33.4 B"
+    assert round(laguna_ref._keys_per_query(sizes, ref.SLIDING)) == 496
+    assert abs(laguna_ref.train_flops_per_row(sizes) / 1e12 - 19.70) < 0.02
+    parts = laguna_ref._forward_flops_per_token(sizes)
+    shares = {k: round(100 * v / sum(parts.values()), 1)
+              for k, v in parts.items()}
+    assert shares == {"projections": 43.0, "attention": 31.2, "dense": 12.6,
+                      "shared": 3.1, "experts": 3.1, "router": 0.5,
+                      "head": 6.4}
+    # 512 rows an expert: the routed experts' float32 weights, read twice
+    # and their gradients written, outweigh their products
+    for work, names, bound in (
+            (laguna_ref.moe_work, ("experts", "router"), "bytes"),
+            (laguna_ref.mlp_work, ("dense", "shared"), "flops"),
+            (laguna_ref.proj_work, ("projections",), "flops"),
+            (laguna_ref.attention_work, ("attention",), "flops")):
+        flops, hbm_bytes = work(sizes, 2)
+        assert flops == 3.0 * 2 * 8192 * sum(parts[n] for n in names)
+        assert hbm_bytes > 0
+        assert (hbm_bytes / 819e9 > flops / 197e12) == (bound == "bytes")
 
 
 def test_the_cells_parameter_count_and_flops():
@@ -386,26 +533,86 @@ def test_the_heads_block_is_2048_tokens():
     assert mellum.head_block_size(3 * 7) == 24
 
 
-def test_rotary_positions_are_the_rotate_half_formula():
+@pytest.mark.parametrize("build,layer_type,rotated", [
+    (mellum.mellum_tiny, mellum.FULL, 16),
+    (mellum.laguna_tiny, mellum.FULL, 8),       # half a head, the rest pass
+    (mellum.laguna_tiny, mellum.SLIDING, 16),
+], ids=["mellum_full", "laguna_full_half_a_head", "laguna_sliding"])
+def test_rotary_positions_are_the_rotate_half_formula(build, layer_type,
+                                                      rotated):
     """The rotation as a product with a signed permutation equals
-    ``x cos + concat(-x2, x1) sin``, exactly."""
-    cfg = mellum.mellum_tiny()
+    ``x cos + concat(-x2, x1) sin`` over the rotated dimensions, exactly,
+    and leaves the others as they were; the reference's slices agree."""
+    cfg = build()
+    assert mellum.rotated_dims(cfg, layer_type) == rotated
     x = jax.random.normal(jax.random.key(0), (2, 8, 4 * cfg.head_dim))
-    cos, sin = mellum._rope_tables(cfg, mellum.FULL, 8)
+    cos, sin = mellum._rope_tables(cfg, layer_type, 8)
     heads = x.reshape(2, 8, 4, cfg.head_dim)
-    half = cfg.head_dim // 2
-    want = (heads * cos[:, None] + jnp.concatenate(
-        [-heads[..., half:], heads[..., :half]], -1) * sin[:, None])
-    np.testing.assert_allclose(mellum._rope(x, 4, cos, sin),
-                               want.reshape(x.shape), rtol=1e-6)
+    turn, rest = heads[..., :rotated], heads[..., rotated:]
+    half = rotated // 2
+    want = jnp.concatenate([
+        turn * cos[:, None, :rotated] + jnp.concatenate(
+            [-turn[..., half:], turn[..., :half]], -1)
+        * sin[:, None, :rotated], rest], -1)
+    got = mellum._rope(x, 4, cos, sin, rotated)
+    np.testing.assert_allclose(got, want.reshape(x.shape), rtol=1e-6)
+    np.testing.assert_array_equal(
+        got.reshape(heads.shape)[..., rotated:], rest)
+    inv_freq, scale = mellum.rope_inv_freq(cfg, layer_type)
+    assert inv_freq.shape == (half,)
+    angles = jnp.arange(8)[:, None] * jnp.concatenate([inv_freq, inv_freq])
+    np.testing.assert_allclose(
+        laguna_ref._rotate(heads[0], jnp.cos(angles) * scale,
+                           jnp.sin(angles) * scale),
+        want[0], rtol=1e-6)
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["inline", "kernels"])
+def test_the_head_gate_is_the_written_out_product(flash, monkeypatch):
+    """``(g_h a_h)_h`` for one value a query head: the gated attention is
+    the ungated one times the gate, and its gradients are those of that
+    product, through XLA's inline attention and through the kernels'
+    custom_vjp."""
+    monkeypatch.setattr(fa, "beats_inline", lambda seq_len: flash)
+    cfg = dataclasses.replace(mellum.laguna_tiny(),
+                              compute_dtype=jnp.float32)
+    heads, kv_heads, d, seq = 6, cfg.num_kv_heads, cfg.head_dim, 16
+    key = jax.random.key(5)
+    q, k, v, mix = (jax.random.normal(jax.random.fold_in(key, i),
+                                      (2, seq, n * d))
+                    for i, n in enumerate((heads, kv_heads, kv_heads,
+                                           heads)))
+    gate = jax.nn.sigmoid(jax.random.normal(jax.random.fold_in(key, 9),
+                                            (2, seq, heads)))
+
+    def gated(q, k, v, gate):
+        return mellum._attention(cfg, q, k, v, gate, mellum.SLIDING, heads)
+
+    def written_out(q, k, v, gate):
+        plain = mellum._attention(cfg, q, k, v, None, mellum.SLIDING, heads)
+        return (plain.reshape(2, seq, heads, d)
+                * gate[..., None]).reshape(2, seq, heads * d)
+
+    np.testing.assert_allclose(gated(q, k, v, gate),
+                               written_out(q, k, v, gate), atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(gated(*a) * mix), (0, 1, 2, 3))(
+        q, k, v, gate)
+    want = jax.grad(lambda *a: jnp.sum(written_out(*a) * mix),
+                    (0, 1, 2, 3))(q, k, v, gate)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
 
 
 def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
-    """The three scopes name operations of the compiled gradient as
-    written (forward and backward, not ``jvp(scope)``), never a ``while``
-    (a reader that sums under a scope counts each operation once), and
-    the trace counted what it compiled."""
-    cfg, _, params, tokens, _ = tiny_f32
+    """The scopes name operations of the compiled gradient as written
+    (forward and backward, not ``jvp(scope)``), never a ``while`` (a
+    reader that sums under a scope counts each operation once), and the
+    trace counted what it compiled."""
+    cfg, _, params, tokens, _, _ = tiny_f32
+    sparse = sum(cfg.mlp_type(i) == mellum.SPARSE
+                 for i in range(cfg.num_layers))
+    swiglus = {"dense": cfg.num_layers - sparse,
+               "shared": sparse if cfg.shared_expert_width else 0}
 
     def count(name, **labels):
         metric = metrics.get(name, labels or None)
@@ -414,11 +621,18 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
     before = {kind: count("rsdl_lm_attention_total", kind=kind)
               for kind in ("inline", "window", "full")}
     layers_before = count("rsdl_moe_layer_total", kind="share")
+    mlps_before = {kind: count("rsdl_lm_mlp_total", kind=kind)
+                   for kind in swiglus}
     text = jax.jit(jax.grad(lambda p, t: mellum.loss_fn(cfg, p, t))).lower(
         params, tokens).compile().as_text()
     names = xplane.hlo_op_names(text)
-    for scope in (mellum.ATTENTION_SCOPE, mellum.MOE_SCOPE,
-                  mellum.HEAD_SCOPE):
+    scopes = [mellum.PROJ_SCOPE, mellum.ATTENTION_SCOPE, mellum.MOE_SCOPE,
+              mellum.HEAD_SCOPE]
+    if any(swiglus.values()):
+        scopes.append(mellum.MLP_SCOPE)
+    else:
+        assert mellum.MLP_SCOPE not in text
+    for scope in scopes:
         under = {name: op_name for name, op_name in names.items()
                  if xplane.under_scope(op_name, scope)}
         assert any(name.startswith(("dot", "fusion")) for name in under), (
@@ -430,17 +644,25 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
         before["inline"] + cfg.num_layers)
     assert count("rsdl_lm_attention_total", kind="window") == before["window"]
     assert count("rsdl_moe_layer_total", kind="share") == (
-        layers_before + cfg.num_layers)
+        layers_before + sparse)
+    for kind, traced in swiglus.items():
+        assert count("rsdl_lm_mlp_total", kind=kind) == (
+            mlps_before[kind] + traced), kind
     assert count("rsdl_moe_experts_held") == 2
     assert count("rsdl_moe_experts_routed") == 8
     assert count("rsdl_moe_top_k") == 2
+    assert count("rsdl_moe_tile_rows") == 128
 
 
-def test_the_attention_counter_tells_window_from_full(monkeypatch):
+@pytest.mark.parametrize("build,windows,fulls", [
+    (mellum.mellum_tiny, 3, 1), (mellum.laguna_tiny, 3, 2)],
+    ids=["mellum", "laguna"])
+def test_the_attention_counter_tells_window_from_full(build, windows, fulls,
+                                                      monkeypatch):
     """On the chip the kernels take both kinds; a window that covers the
     row is the triangle."""
     monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
-    cfg = mellum.mellum_tiny()
+    cfg = build()
 
     def count(kind):
         metric = metrics.get("rsdl_lm_attention_total", {"kind": kind})
@@ -450,20 +672,35 @@ def test_the_attention_counter_tells_window_from_full(monkeypatch):
     jax.eval_shape(lambda p, t: mellum.loss_fn(cfg, p, t),
                    mellum.init(cfg, jax.random.key(0)),
                    jnp.zeros((1, 16), jnp.int32))
-    assert (count("window"), count("full")) == (before[0] + 3, before[1] + 1)
+    assert (count("window"), count("full")) == (before[0] + windows,
+                                                before[1] + fulls)
     jax.eval_shape(lambda p, t: mellum.loss_fn(cfg, p, t),
                    mellum.init(cfg, jax.random.key(0)),
                    jnp.zeros((1, 8), jnp.int32))
-    assert (count("window"), count("full")) == (before[0] + 3, before[1] + 5)
+    assert (count("window"), count("full")) == (
+        before[0] + windows, before[1] + fulls + windows + fulls)
 
 
-def test_a_mesh_of_several_devices_is_refused():
+@pytest.mark.parametrize("build", [mellum.mellum_tiny, mellum.laguna_tiny],
+                         ids=["mellum", "laguna"])
+def test_a_mesh_of_several_devices_is_refused(build):
     """Nothing stands in for the absent chips: the decoder runs one chip's
     share and says so when handed more."""
     from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
-    cfg = mellum.mellum_tiny()
+    cfg = build()
     assert mesh_mod.EXPERT_AXIS == "expert"
     with pytest.raises(NotImplementedError, match="exchange"):
         mellum.loss_fn(cfg, mellum.init(cfg, jax.random.key(0)),
                        jnp.zeros((2, 16), jnp.int32),
                        mesh_mod.make_mesh(num_devices=2))
+
+
+def test_per_layer_lists_must_name_every_layer():
+    cfg = dataclasses.replace(mellum.laguna_tiny(), heads_per_layer=(6, 8))
+    with pytest.raises(ValueError, match="heads_per_layer names 2 layers"):
+        mellum.loss_fn(cfg, {}, jnp.zeros((1, 16), jnp.int32))
+    cfg = dataclasses.replace(
+        mellum.mellum_tiny(), mlp_layer_types=("dense", "sparse", "sparse",
+                                               "wide"))
+    with pytest.raises(ValueError, match="mlp_layer_types entry 'wide'"):
+        mellum.loss_fn(cfg, {}, jnp.zeros((1, 16), jnp.int32))

@@ -252,27 +252,32 @@ def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
 
 
-@pytest.mark.parametrize("name,scope,layer,cell", [
-    ("mlm_head_pct", bert.MLM_HEAD_SCOPE, "model", "bert_train"),
-    ("attention_pct", bert.ATTENTION_SCOPE, "kernels", "bert_train"),
-    ("moe_pct", mellum.MOE_SCOPE, "kernels", "mellum_train_8k"),
-    ("lm_attention_pct", mellum.ATTENTION_SCOPE, "kernels",
-     "mellum_train_8k"),
-    ("lm_head_pct", mellum.HEAD_SCOPE, "model", "mellum_train_8k"),
+_DECODER_CELLS = ["mellum_train_8k", "laguna_train_8k"]
+
+
+@pytest.mark.parametrize("name,scope,layer,cells", [
+    ("mlm_head_pct", bert.MLM_HEAD_SCOPE, "model", ["bert_train"]),
+    ("attention_pct", bert.ATTENTION_SCOPE, "kernels", ["bert_train"]),
+    ("moe_pct", mellum.MOE_SCOPE, "kernels", _DECODER_CELLS),
+    ("lm_attention_pct", mellum.ATTENTION_SCOPE, "kernels", _DECODER_CELLS),
+    ("lm_head_pct", mellum.HEAD_SCOPE, "model", _DECODER_CELLS),
+    ("lm_mlp_pct", mellum.MLP_SCOPE, "model", ["laguna_train_8k"]),
+    ("lm_proj_pct", mellum.PROJ_SCOPE, "model", ["laguna_train_8k"]),
 ])
 def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
-                                                            layer, cell):
-    """``mlm_head_pct`` (PR 29), ``attention_pct`` (PR 31) and the
-    decoder's three (PR 32) are data: the scope reader
-    ``grad_exchange_pct`` uses, pointed at a scope the model names, in
-    that model's cell alone; a program without the scope (the parent's
+                                                            layer, cells):
+    """``mlm_head_pct`` (PR 29), ``attention_pct`` (PR 31), the decoder's
+    three (PR 32), its dense SwiGLUs' and its projections' (PR 34) are data: the scope
+    reader ``grad_exchange_pct`` uses, pointed at a scope the model
+    names, in the cells of that model's configurations that run the
+    scope and in no other; a program without the scope (the parent's
     side) gives it nothing."""
     from chipbench import manifest
     bench = manifest.load_manifest()
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
     assert entry == {"name": name, "unit": "%", "better": "lower",
                      "source": "device_trace", "layer": layer,
-                     "moves": "train_rows_per_s", "workloads": [cell]}
+                     "moves": "train_rows_per_s", "workloads": cells}
     with open(os.path.join(manifest.BENCH_DIR, "layers",
                            f"{name}.json")) as f:
         reads = json.load(f)
@@ -285,7 +290,7 @@ def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
     for other in bench["workloads"]:
         reported = {m["name"]
                     for m in manifest.resolve_cell(other["name"]).per_layer}
-        assert (name in reported) == (other["name"] == cell)
+        assert (name in reported) == (other["name"] in cells)
     reader = manifest.layer_reader(name)
     assert reader({"trace": None}) is None
     assert reader({"trace": object(), "step_op_names": {}}) is None
