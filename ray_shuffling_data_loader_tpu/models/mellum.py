@@ -36,7 +36,10 @@ TPU-first choices:
   softmax over materialized scores. The head gate multiplies the kernel's
   output under the same scope.
 - Every layer is made again in the backward pass (``jax.checkpoint``), a
-  half at a time: a half keeps its bf16 input and nothing else.
+  half at a time: a half keeps its bf16 input, and an attention half
+  whose attention the kernels compute also their two results, the ungated
+  output and the log-sum-exp a row, so that the backward pass makes the
+  norm, q, k, v and the gate again but runs no forward kernel twice.
 - The head's loss walks blocks of tokens, forward and backward, so that no
   (tokens, vocabulary) float32 array outlives a block.
 """
@@ -50,6 +53,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_shuffling_data_loader_tpu.ops import flash_attention, moe, on_tpu
@@ -67,6 +71,10 @@ ATTENTION_SCOPE = "rsdl.lm.attention"
 MOE_SCOPE = moe.SCOPE
 MLP_SCOPE = "rsdl.lm.mlp"
 HEAD_SCOPE = "rsdl.lm.head"
+
+# What an attention half's checkpoint keeps of the forward kernel
+# (``_flash_attention_fwd`` names them, ``decode``'s policy saves them).
+KEPT_OUT, KEPT_LSE = "rsdl.lm.attention.out", "rsdl.lm.attention.lse"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -379,6 +387,19 @@ def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window):
         out, lse = flash_attention.grouped_forward(
             q, k, v, heads, kv_heads, True, window, *_blocks(window, False),
             interpret=not on_tpu())
+        # The two residuals the half's checkpoint keeps (``decode``), so
+        # that the backward pass has them without this kernel run again;
+        # q, k, v and the gate it makes again. lse without the column's
+        # last axis: the chip stores (B, H, S, 1) float32 a 128-lane tile
+        # a value, 537 MB a layer of 4 x 32 x 8,192 where (B, H, S) is 4.
+        # The barrier has the column squeezed before the forward pass goes
+        # on with ``out``: left alone, XLA's schedule for the 8k-token
+        # cells squeezes it where the backward kernel wants it, and every
+        # layer's column waits till then (the compiled step's temporaries
+        # 8.10 GB against 6.46; PERF.md section 6, PR 35).
+        out, lse = jax.lax.optimization_barrier((out, lse[..., 0]))
+        out = checkpoint_name(out, KEPT_OUT)
+        lse = checkpoint_name(lse, KEPT_LSE)
         return _gated(out, gate, heads), (q, k, v, gate, out, lse)
 
 
@@ -397,8 +418,8 @@ def _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent):
                 axis=-1)
             cotangent = _gated(cotangent, gate, heads)
         return (*flash_attention.grouped_backward(
-            q, k, v, out, lse, cotangent, heads, kv_heads, True, window,
-            *_blocks(window, True), interpret=not on_tpu()), d_gate)
+            q, k, v, out, lse[..., None], cotangent, heads, kv_heads, True,
+            window, *_blocks(window, True), interpret=not on_tpu()), d_gate)
 
 
 def _counted_flash_attention_bwd(heads, kv_heads, window, residuals,
@@ -425,14 +446,21 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
     if window is not None and window >= seq_len:
         window = None       # the band covers the triangle: nothing to cut
     flash = flash_attention.beats_inline(seq_len)
+    kind = ("inline" if not flash else
+            "window" if window is not None else "full")
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_lm_attention_total",
         "Decoder layers' attentions traced, by what computes them: the "
         "Pallas kernels over a window's band or the whole triangle, or "
-        "XLA's inline softmax over materialized scores",
-        kind=("inline" if not flash else
-              "window" if window is not None else "full")).inc()
+        "XLA's inline softmax over materialized scores", kind=kind).inc()
+    if flash:
+        rt_metrics.counter(
+            "rsdl_lm_attention_kept_total",
+            "Decoder layers' attentions traced whose forward kernel's "
+            "output and log-sum-exp the layer's checkpoint keeps for the "
+            "backward pass: every layer the kernels compute",
+            kind=kind).inc()
     attend = _flash_attention if flash else _inline_attention
     return attend(q, k, v, gate, heads, config.num_kv_heads, window)
 
@@ -593,7 +621,8 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
            token_ids: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
     """token_ids (B, S) int32 -> hidden states (B, S, hidden) in the
     compute dtype, after the last layer's residual (before the final
-    norm). Every layer is made again in the backward pass.
+    norm). Every layer is made again in the backward pass, but for its
+    attention kernel's two results.
 
     ``mesh``: the mesh the calling step is jitted over (``ops/embedding.py:
     lookup``'s convention). One device only: the expert layer's exchange
@@ -606,14 +635,21 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     _checked(config)
     x = jnp.take(params["embed"], token_ids, axis=0,
                  mode="clip").astype(config.compute_dtype)
+    # Of an attention half, its bf16 input and the forward kernel's two
+    # results are kept, where the kernels run: 272 MB a layer of 4 rows of
+    # 8,192 tokens and 32 heads, against 19.5 ms (the whole triangle) or
+    # 8.4 ms (a window) of kernel run a second time. The inline attention
+    # names neither, and its half keeps its input only.
+    keep_kernel_results = jax.checkpoint_policies.save_only_these_names(
+        KEPT_OUT, KEPT_LSE)
     for layer in range(config.num_layers):
         # Each half is made again on its own in the backward pass: the
-        # MLP half's backward runs before the attention half's q, k, v
-        # and lse exist again, so the two halves' activations never sit
-        # on the chip together (a half keeps its bf16 input).
+        # MLP half's backward runs before the attention half's q, k and v
+        # exist again, so the two halves' activations never sit on the
+        # chip together.
         lp = params[f"layer_{layer}"]
-        x = jax.checkpoint(functools.partial(
-            _attention_half, config, layer))(x, lp)
+        x = jax.checkpoint(functools.partial(_attention_half, config, layer),
+                           policy=keep_kernel_results)(x, lp)
         x = jax.checkpoint(functools.partial(_mlp_half, config, layer))(x, lp)
     return x
 

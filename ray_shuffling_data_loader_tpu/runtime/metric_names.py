@@ -50,9 +50,12 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- a decoder layer's attention, its dense SwiGLUs and its
     #    sparse-expert layer (models/mellum.py; counted or set when a layer
     #    is traced, not when it runs; attention's kind = window | full |
-    #    inline, a SwiGLU's = dense | shared, the expert layer's = share |
-    #    all of the router's experts held here) --
+    #    inline, and window | full again for the layers whose checkpoint
+    #    keeps the forward kernel's results; a SwiGLU's = dense | shared,
+    #    the expert layer's = share | all of the router's experts held
+    #    here) --
     "rsdl_lm_attention_total": ("counter", ("kind",)),
+    "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_experts_held": ("gauge", ()),

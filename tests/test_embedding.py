@@ -493,8 +493,12 @@ def test_the_decoders_step_names_one_attention_backward_a_layer(
     row of 8,192 tokens at the published attention widths (a window layer
     and a full one; the experts, the vocabulary and the hidden size cut:
     they are not what is counted): under ``rsdl.lm.attention`` a layer
-    has three Mosaic calls, its forward, its forward made again and one
-    backward, where the dq and dk/dv pair made four."""
+    has two Mosaic calls, its forward and one backward (the dq and dk/dv
+    pair made two of that, and a checkpoint that kept only the half's
+    input ran the forward a second time), and what waits from the one to
+    the other of lse is (B, H, S): the kernels' (B, H, S, 1) column, a
+    128-lane tile a value on the chip, is squeezed before the forward
+    pass goes on and widened where the backward kernel takes it."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -517,7 +521,29 @@ def test_the_decoders_step_names_one_attention_backward_a_layer(
         params, tokens).compile().as_text()
     calls = re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
-    assert len(calls) == 3 * cfg.num_layers, calls
+    assert len(calls) == 2 * cfg.num_layers, calls
     assert all(xplane.under_scope(c, mellum.ATTENTION_SCOPE) for c in calls)
-    assert len([c for c in calls if "_flash_attention_bwd" in c]) == (
-        cfg.num_layers), calls
+    for rule in ("_flash_attention_fwd", "_flash_attention_bwd"):
+        assert len([c for c in calls if rule in c]) == cfg.num_layers, calls
+    # The compiled step's instructions stand in the order they run. No
+    # forward kernel's column is read from the first backward kernel on;
+    # a squeezed lse (XLA drops the row's axis of 1 too) is.
+    assert "is_scheduled=true" in hlo
+    step = hlo.split("ENTRY")[1].splitlines()
+    backward = next(i for i, line in enumerate(step)
+                    if "tpu_custom_call" in line
+                    and "_flash_attention_bwd" in line)
+
+    def made_forward(shape):
+        return [m.group(1) for m in (
+            re.match(rf"\s*(%[\w.-]+) = f32\[{shape}\]\{{.*"
+                     "_flash_attention_fwd", line)
+            for line in step[:backward]) if m]
+
+    columns = made_forward(f"1,{cfg.num_heads},8192,1")
+    kept = made_forward(f"(?:1,)?{cfg.num_heads},8192")
+    assert len(columns) == len(kept) == cfg.num_layers, (columns, kept)
+    later = "\n".join(step[backward:])
+    for names, read in ((columns, False), (kept, True)):
+        assert any(re.search(re.escape(name) + r"\b", later)
+                   for name in names) == read, names

@@ -5,6 +5,7 @@ attention kernels (ops/flash_attention.py) against the plain references
 sizes on the CPU."""
 
 import dataclasses
+import functools
 import math
 import re
 
@@ -20,6 +21,7 @@ from ray_shuffling_data_loader_tpu.models import mellum
 from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
 from ray_shuffling_data_loader_tpu.ops import moe
 from ray_shuffling_data_loader_tpu.runtime import metrics
+from tests.test_flash_attention import _pallas_calls
 
 
 def _sizes(cfg: mellum.DecoderConfig, seq_len: int, held=None):
@@ -438,6 +440,87 @@ def test_loss_and_every_gradient_match_the_reference(tiny_f32, monkeypatch):
             err_msg=jax.tree_util.keystr(path))
     assert reference.param_count(sizes) == sum(
         x.size for x in jax.tree.leaves(params))
+
+
+# -- what an attention half's checkpoint keeps ---------------------------------------
+
+
+def _loss_under_plain_checkpoints(cfg, params, tokens):
+    """``mellum.loss_fn`` with the policy taken off: the same halves, each
+    under a ``jax.checkpoint`` that keeps its input and nothing else, so
+    the backward pass runs an attention half's forward kernel again."""
+    x = jnp.take(params["embed"], tokens, axis=0,
+                 mode="clip").astype(cfg.compute_dtype)
+    for layer in range(cfg.num_layers):
+        lp = params[f"layer_{layer}"]
+        x = jax.checkpoint(functools.partial(
+            mellum._attention_half, cfg, layer))(x, lp)
+        x = jax.checkpoint(functools.partial(
+            mellum._mlp_half, cfg, layer))(x, lp)
+    x = mellum._rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    targets = mellum.next_token_targets(tokens)
+    return mellum._nll(x, params["head"], targets) / jnp.maximum(
+        jnp.sum(targets != mellum.IGNORE_ID), 1)
+
+
+def test_the_gradient_runs_a_layers_forward_kernel_once(tiny_f32,
+                                                        monkeypatch):
+    """A forward and a backward kernel a layer, gated or not, window or
+    full: the half's checkpoint keeps the forward kernel's results, where
+    a plain one runs it a second time to have them."""
+    cfg, _, params, tokens, _, _ = tiny_f32
+    monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
+    for loss, kernels in ((mellum.loss_fn, 2),
+                          (_loss_under_plain_checkpoints, 3)):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: loss(cfg, p, tokens)))(params)
+        assert _pallas_calls(jaxpr.jaxpr) == kernels * cfg.num_layers, loss
+
+
+def test_keeping_the_kernels_results_moves_no_bit(tiny_f32, monkeypatch):
+    """The kept output and log-sum-exp are the arrays the kernel run again
+    would return: the loss and every leaf's gradient are equal to the
+    last bit (operation by operation: under one ``jit`` XLA fuses the two
+    programs differently)."""
+    cfg, _, params, tokens, _, _ = tiny_f32
+    monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
+    loss, grads = jax.value_and_grad(
+        lambda p: mellum.loss_fn(cfg, p, tokens))(params)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: _loss_under_plain_checkpoints(cfg, p, tokens))(params)
+    assert float(loss) == float(want_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(got, want,
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["inline", "kernels"])
+def test_the_kept_counter_counts_the_kernels_layers(tiny_f32, flash,
+                                                    monkeypatch):
+    """One a layer the kernels compute, by its kind, beside
+    ``rsdl_lm_attention_total``; the inline path has no kernel's results
+    to keep."""
+    cfg, _, params, tokens, _, _ = tiny_f32
+    monkeypatch.setattr(fa, "beats_inline", lambda seq_len: flash)
+    kinds = ("window", "full", "inline")
+
+    def counts(name):
+        found = (metrics.get(name, {"kind": kind}) for kind in kinds)
+        return [0 if metric is None else metric.value for metric in found]
+
+    before = counts("rsdl_lm_attention_kept_total")
+    traced_before = counts("rsdl_lm_attention_total")
+    jax.eval_shape(lambda p: mellum.loss_fn(cfg, p, tokens), params)
+    windows = sum(kind == mellum.SLIDING for kind in cfg.layer_types)
+    rose = [after - b for after, b in zip(
+        counts("rsdl_lm_attention_kept_total"), before)]
+    assert rose == ([windows, cfg.num_layers - windows, 0] if flash
+                    else [0, 0, 0])
+    traced = [after - b for after, b in zip(
+        counts("rsdl_lm_attention_total"), traced_before)]
+    # every layer the kernels compute engages, and no other
+    assert traced == rose[:2] + [0 if flash else cfg.num_layers]
 
 
 def test_lagunas_parameter_count_and_flops():
