@@ -58,6 +58,7 @@ from jax.sharding import Mesh
 
 from ray_shuffling_data_loader_tpu.ops import flash_attention, moe, on_tpu
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
+from ray_shuffling_data_loader_tpu.utils import tracing
 
 IGNORE_ID = -100
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -532,8 +533,10 @@ def _rms_norm(x, scale, eps: float):
     return (normed * scale).astype(x.dtype)
 
 
-def _experts(config: DecoderConfig, x, lp):
-    """The held experts' part of a layer's sparse-expert sum, (B, S, h)."""
+def _experts(config: DecoderConfig, layer: int, x, lp):
+    """The held experts' part of a layer's sparse-expert sum, (B, S, h).
+    What its walk did this step (held pairs, tiles, rounds) goes out as
+    the step's ``moe_walk`` of this layer."""
     first, count = config.experts_held
     b, s, h = x.shape
     tile = moe.tile_rows(b * s, config.top_k, config.num_experts)
@@ -554,9 +557,10 @@ def _experts(config: DecoderConfig, x, lp):
     rt_metrics.gauge("rsdl_moe_tile_rows",
                      "Rows of one expert in a tile of the expert layer's "
                      "walk, last layer traced").set(tile)
-    out = moe.moe(x.reshape(b * s, h), lp["router"], lp["gate"], lp["up"],
-                  lp["down"], config.experts_held, config.top_k, tile,
-                  config.routed_scale)
+    out, walk = moe.moe_counted(
+        x.reshape(b * s, h), lp["router"], lp["gate"], lp["up"], lp["down"],
+        config.experts_held, config.top_k, tile, config.routed_scale)
+    tracing.step_stat("moe_walk", walk, layer=layer)
     return out.reshape(b, s, h)
 
 
@@ -592,7 +596,7 @@ def _mlp_half(config: DecoderConfig, layer: int, x, lp):
         n = _rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
         return x + _mlp("dense", n, lp["gate"], lp["up"], lp["down"])
     n = _rms_norm(x, lp["moe_norm"], config.rms_norm_eps)
-    out = x + _experts(config, n, lp)
+    out = x + _experts(config, layer, n, lp)
     if config.shared_expert_width:
         out = out + _mlp("shared", n, lp["shared_gate"], lp["shared_up"],
                          lp["shared_down"])
@@ -650,7 +654,11 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
         lp = params[f"layer_{layer}"]
         x = jax.checkpoint(functools.partial(_attention_half, config, layer),
                            policy=keep_kernel_results)(x, lp)
-        x = jax.checkpoint(functools.partial(_mlp_half, config, layer))(x, lp)
+        # What the half records of the step's own counters leaves its
+        # checkpoint as an output (counted in the forward pass, not again
+        # when the half is made again).
+        x = tracing.step_stats_of(jax.checkpoint(tracing.with_step_stats(
+            functools.partial(_mlp_half, config, layer))))(x, lp)
     return x
 
 

@@ -44,6 +44,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_shuffling_data_loader_tpu.runtime import telemetry
+
 #: The name a device trace shows the layer's operations under: router,
 #: sort, grouped products and combine, forward and backward.
 SCOPE = "rsdl.lm.moe"
@@ -154,6 +156,23 @@ def _dispatch(ids, first: int, count: int, tile: int):
     return (order, sizes, starts, tile_ends), position.reshape(ids.shape)
 
 
+def _walk_counts(plan, position, count: int, per_round: int):
+    """What the walk does for this routing, int32, in the order of
+    ``telemetry.STEP_STAT_FIELDS["moe_walk"]``."""
+    _, sizes, _, tile_ends = plan
+    tiles = tile_ends[-1]
+    counts = {
+        "pairs": jnp.int32(position.size),
+        "pairs_held": jnp.sum(sizes[:count]),
+        "tiles": tiles,
+        "rounds": (tiles + per_round - 1) // per_round,
+        "fullest_expert_rows": jnp.max(sizes[:count]),
+    }
+    return jnp.stack([counts[field] for field in
+                      telemetry.STEP_STAT_FIELDS["moe_walk"]]
+                     ).astype(jnp.int32)
+
+
 def _tile(i, plan, flat_weights, top_k: int, tile: int):
     """Tile ``i`` of the walk: its expert, each row's token (0 for an empty
     row) and its pick's weight (0 for an empty row)."""
@@ -253,10 +272,19 @@ def _gathered(buffer, index, spare: int):
         return summed[jnp.argsort(order)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
         tile: int, scale: float = 1.0):
-    """The held experts' part of a sparse-expert layer.
+    """:func:`moe_counted`'s first result alone: the held experts' part
+    of a sparse-expert layer."""
+    return moe_counted(x, router, gate, up, down, held, top_k, tile,
+                       scale)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
+                top_k: int, tile: int, scale: float = 1.0):
+    """The held experts' part of a sparse-expert layer, and what the walk
+    did for it.
 
     Args:
         x: (N, hidden) tokens, in the compute dtype.
@@ -271,8 +299,14 @@ def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
             :func:`tile_rows` has the rule the decoder follows).
         scale: what a token's weights sum to (``route``).
 
-    Returns (N, hidden) in ``x``'s dtype: for each token the sum over its
-    picks that are held of weight x expert(token).
+    Returns ``(out, walk)``: ``out`` (N, hidden) in ``x``'s dtype, for
+    each token the sum over its picks that are held of weight x
+    expert(token); ``walk`` int32, this call's routing as :func:`_dispatch`
+    planned it, one value a field of
+    ``telemetry.STEP_STAT_FIELDS["moe_walk"]``: all ``N x top_k`` pairs,
+    the pairs held, the tiles and the rounds the walk takes and the
+    fullest held expert's pairs. It has no gradient, and is not made again
+    where a ``jax.checkpoint`` makes the layer again.
     """
     return _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale)[0]
 
@@ -296,6 +330,7 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale):
         g16, u16, d16 = (w.astype(x.dtype) for w in (gate, up, down))
         out = jnp.zeros((tokens, hidden), jnp.float32)
         ys = jnp.zeros((rows + tile, hidden), x.dtype)
+        walk = _walk_counts(plan, position, count, rows // tile)
 
     def tile_fn(i, out, ys, at):
         expert, token, w = _tile(i, plan, flat, top_k, tile)
@@ -313,12 +348,13 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale):
     out = _walk(plan, position, rows, tile, tile_fn, gather_fn, out, ys)
     with jax.named_scope(SCOPE):
         out = out.astype(x.dtype)
-    return out, (x, router, gate, up, down, weights, plan, position)
+    return (out, walk), (x, router, gate, up, down, weights, plan, position)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _moe_bwd(held, top_k, tile, scale, residuals, dout):
+def _moe_bwd(held, top_k, tile, scale, residuals, cotangents):
     x, router, gate, up, down, weights, plan, position = residuals
+    dout = cotangents[0]            # the walk's counts have none
     tokens, hidden = x.shape
     rows = round_rows(tokens, top_k, held[1], router.shape[1], tile)
     with jax.named_scope(SCOPE):
@@ -372,4 +408,4 @@ def _moe_bwd(held, top_k, tile, scale, residuals, dout):
     return d_x, d_router, d_gate, d_up, d_down
 
 
-moe.defvjp(_moe_fwd, _moe_bwd)
+moe_counted.defvjp(_moe_fwd, _moe_bwd)

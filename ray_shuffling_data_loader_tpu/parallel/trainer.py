@@ -35,15 +35,30 @@ from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
 logger = setup_custom_logger(__name__)
 
 
+#: The name a device trace shows the optimizer's operations under: the
+#: transformation's update (Adam's moments) and its addition to the
+#: parameters.
+OPTIMIZER_SCOPE = "rsdl.train.optimizer"
+
+
 def make_train_step(loss_fn: Callable,
                     optimizer: optax.GradientTransformation) -> Callable:
     """Pure train-step function: (params, opt_state, *batch) ->
-    (params, opt_state, loss)."""
+    (params, opt_state, loss), and a fourth output where the loss
+    recorded any of the step's own counters while it was traced
+    (``tracing.step_stat``): the ``{key: device value}`` they were
+    recorded under. A loss that records none gives the three-output
+    program it always gave."""
+    counted_loss = tracing.with_step_stats(loss_fn)
 
     def train_step(params, opt_state, *batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        (loss, stats), grads = jax.value_and_grad(
+            counted_loss, has_aux=True)(params, *batch)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        if stats:
+            return params, opt_state, loss, stats
         return params, opt_state, loss
 
     return train_step
@@ -94,10 +109,15 @@ class SpmdTrainer:
         self._step_count = 0
 
     def train_step(self, *batch) -> jax.Array:
-        """One optimizer step; returns the (lazy) scalar loss."""
+        """One optimizer step; returns the (lazy) scalar loss. The step's
+        own counters, where the loss records any, go to ``tracing``'s ring
+        under this step's number, still on the device: nothing here waits
+        for them."""
         with tracing.step_span(self._step_count):
-            self.params, self.opt_state, loss = self._step(
+            self.params, self.opt_state, loss, *stats = self._step(
                 self.params, self.opt_state, *batch)
+        if stats:
+            tracing.keep_step_stats(self._step_count, stats[0])
         self._step_count += 1
         return loss
 
@@ -109,6 +129,7 @@ class SpmdTrainer:
 
     def block_until_ready(self) -> None:
         jax.block_until_ready((self.params, self.opt_state))
+        tracing.fold_step_stats(wait=True)
 
 
 def batch_shardings(mesh: Mesh, batch_example: Tuple,

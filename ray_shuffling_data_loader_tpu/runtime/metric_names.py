@@ -62,6 +62,18 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_moe_experts_routed": ("gauge", ()),
     "rsdl_moe_top_k": ("gauge", ()),
     "rsdl_moe_tile_rows": ("gauge", ()),
+    # -- the train step's own counters (utils/tracing.step_stat, folded by
+    #    runtime/telemetry.step_stats_folded once a step's values have
+    #    reached the host: counted when the step RAN, unlike the block
+    #    above; layer = the decoder's layer number, a handful) --
+    "rsdl_step_stats_folded_total": ("counter", ()),
+    "rsdl_moe_pairs_total": ("counter", ()),
+    "rsdl_moe_pairs_held_total": ("counter", ("layer",)),
+    "rsdl_moe_tiles_total": ("counter", ("layer",)),
+    "rsdl_moe_rounds_total": ("counter", ("layer",)),
+    "rsdl_moe_fullest_expert_rows": ("gauge", ("layer",)),
+    "rsdl_moe_tiles_per_step": ("histogram", ()),
+    "rsdl_moe_tiles_last_step": ("gauge", ()),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

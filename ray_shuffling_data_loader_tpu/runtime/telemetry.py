@@ -122,6 +122,26 @@ SPAN_NAMES: Dict[str, str] = {
 STEP_ANNOTATION = "rsdl.trainer.step"
 
 
+#: The step's own counters (``utils/tracing.step_stat``): stat name ->
+#: the fields of the int32 vector the device computes for it each step.
+#: ``moe_walk`` is one sparse-expert layer's walk (``ops/moe.py``): all
+#: (token, pick) pairs, those whose expert this chip holds, the tiles and
+#: rounds the walk took for them and the fullest held expert's pairs: what
+#: ``_dispatch`` already holds, and no pass over the tokens (a count of
+#: each token's held picks moved the compiled step's schedule, and 2.4 ms
+#: of a 680 ms step with it: PERF.md section 6, PR 36).
+#: :func:`step_stats_folded` has what each becomes in the registry.
+STEP_STAT_FIELDS: Dict[str, Tuple[str, ...]] = {
+    "moe_walk": ("pairs", "pairs_held", "tiles", "rounds",
+                 "fullest_expert_rows"),
+}
+
+#: Upper bounds of ``rsdl_moe_tiles_per_step``: an even routing walks 128
+#: and 256 tiles a step in the two decoder cells, a collapsed one 700.
+_TILES_PER_STEP_BUCKETS = (16, 32, 64, 96, 128, 160, 192, 256, 320, 384,
+                           512, 768, 1024, 2048, 4096)
+
+
 def annotation_names() -> frozenset:
     """Every ``TraceAnnotation`` name the program may emit."""
     return frozenset(SPAN_NAMES.values()) | {STEP_ANNOTATION}
@@ -902,6 +922,51 @@ def turnover_complete(epoch: int, total_s: float,
     attribution().release_line(
         epoch, suffix=f"; turnover={total_s * 1e3:.1f}ms ({split}, "
                       f"other {other_s * 1e3:.1f})")
+
+
+def step_stats_folded(step: int,
+                      stats: Dict[str, List[Dict[str, Any]]]) -> None:
+    """Train step ``step``'s own counters have reached the host
+    (``utils/tracing.fold_step_stats``): ``stats`` maps a stat's name
+    (:data:`STEP_STAT_FIELDS`) to one row a label set, labels and fields
+    together. One ``step_stats`` event with the step's number, and the
+    registry: ``moe_walk`` adds each layer's pairs, held pairs, tiles and
+    rounds to their counters, sets the layer's fullest-expert gauge, and
+    samples the step's tiles, all layers summed, into
+    ``rsdl_moe_tiles_per_step`` (and the gauge of the last step's)."""
+    if not _ENABLED:
+        return
+    record("step_stats", step=step, stats=stats)
+    metrics.counter("rsdl_step_stats_folded_total",
+                    "train steps whose own counters reached the host").inc()
+    walks = stats.get("moe_walk")
+    if not walks:
+        return
+    for row in walks:
+        layer = row.get("layer", "")
+        metrics.counter("rsdl_moe_pairs_total",
+                        "(token, pick) pairs routed by the sparse-expert "
+                        "layers, every folded step").inc(row["pairs"])
+        metrics.counter("rsdl_moe_pairs_held_total",
+                        "pairs whose expert this chip holds",
+                        layer=layer).inc(row["pairs_held"])
+        metrics.counter("rsdl_moe_tiles_total",
+                        "tiles the expert walk took", layer=layer
+                        ).inc(row["tiles"])
+        metrics.counter("rsdl_moe_rounds_total",
+                        "rounds (buffers of tiles) the expert walk took",
+                        layer=layer).inc(row["rounds"])
+        metrics.gauge("rsdl_moe_fullest_expert_rows",
+                      "pairs of the fullest held expert, last folded step",
+                      layer=layer).set(row["fullest_expert_rows"])
+    tiles = sum(row["tiles"] for row in walks)
+    metrics.histogram("rsdl_moe_tiles_per_step",
+                      "tiles the expert walk took in one step, all sparse "
+                      "layers summed", buckets=_TILES_PER_STEP_BUCKETS
+                      ).observe(tiles)
+    metrics.gauge("rsdl_moe_tiles_last_step",
+                  "tiles the expert walk took, all sparse layers summed, "
+                  "last folded step").set(tiles)
 
 
 def flush_epoch_log() -> None:

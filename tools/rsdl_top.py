@@ -393,6 +393,48 @@ def render_rebalance(parsed: dict) -> list:
     return [line]
 
 
+def render_step(parsed: dict) -> list:
+    """One step line from the train step's own counters
+    (utils/tracing.step_stat, folded by runtime/telemetry): the share of
+    the sparse layers' (token, pick) pairs whose expert this chip holds,
+    against the held share of the experts an even routing gives; the
+    tiles the expert walk took in the last folded step and in the mean
+    step, with the mean by layer, and the rounds a walk (over 1: a
+    routing that outgrew the buffer an even one fills); the fullest held
+    expert's pairs against a tile's rows. Silent when no step has folded
+    a walk."""
+    pairs = _scalar(parsed, "rsdl_moe_pairs_total")
+    steps = _scalar(parsed, "rsdl_moe_tiles_per_step_count")
+    if not pairs or not steps:
+        return []
+    held = _scalar(parsed, "rsdl_moe_pairs_held_total")
+    line = f"step: held pairs {100.0 * held / pairs:.1f}%"
+    routed = _scalar(parsed, "rsdl_moe_experts_routed")
+    if routed:
+        even = _scalar(parsed, "rsdl_moe_experts_held") / routed
+        line += f" (even routing {100.0 * even:.1f}%)"
+    line += (f"   tiles/step last "
+             f"{int(_scalar(parsed, 'rsdl_moe_tiles_last_step'))} mean "
+             f"{_scalar(parsed, 'rsdl_moe_tiles_per_step_sum') / steps:.1f}")
+    by_layer = _by_label(parsed, "rsdl_moe_tiles_total", "layer")
+    if by_layer:
+        line += " (by layer " + "/".join(
+            f"{by_layer[layer] / steps:.1f}"
+            for layer in sorted(by_layer, key=int)) + ")"
+        rounds = _scalar(parsed, "rsdl_moe_rounds_total")
+        line += f"   rounds/walk {rounds / (steps * len(by_layer)):.2f}"
+    fullest = _by_label(parsed, "rsdl_moe_fullest_expert_rows", "layer")
+    if fullest:
+        line += f"   fullest expert {int(max(fullest.values()))} rows"
+        tile = _scalar(parsed, "rsdl_moe_tile_rows")
+        if tile:
+            line += f" (tile {int(tile)})"
+    line += (f"   over {int(steps)} of "
+             f"{int(_scalar(parsed, 'rsdl_step_stats_folded_total'))} "
+             "folded steps")
+    return [line]
+
+
 def render_latency(parsed: dict, before: dict = None) -> list:
     """Per-queue delivery-latency lines (runtime/latency.py sketch):
     p50/p95/p99 of the end-to-end birth->delivered hop plus the queue's
@@ -547,6 +589,7 @@ def render(parsed: dict, before: dict = None, interval_s: float = None
     lines.extend(render_membership(parsed))
     lines.extend(render_rebalance(parsed))
     lines.extend(render_streaming(parsed))
+    lines.extend(render_step(parsed))
     lines.extend(render_latency(parsed, before=before if rate_mode
                                 else None))
     # Critical-path line (runtime/trace.py gauges, refreshed per epoch):
