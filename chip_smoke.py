@@ -11,7 +11,8 @@ full width of one model the repo supports, and checks what comes out:
    key/value head, causal and under a window, off their projections),
    compiled by Mosaic — not interpreted; the sparse-expert layer's walk
    (``ops/moe.py``) against its float32 loop of dense products at the
-   decoder's widths;
+   decoder's widths, its rows moved by DMA (a tile's fetch and a round's
+   combine, each also alone: equal to XLA's gather, nanoseconds a row);
 2. loader -> device feed -> train step: seeded DLRM Parquet
    (``data_generation.generate_data``) through ``JaxShufflingDataset`` at
    library defaults into ``parallel.trainer.SpmdTrainer`` over
@@ -126,7 +127,8 @@ def tiny_size() -> SmokeSize:
         attention_shape=(1, 2, 32), attention_seqs=(256, 200, 64),
         masked_attention_dim=16,
         masked_attention=((4, 1, 24, False, (40,)), (6, 2, 8, True, (40,))),
-        moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),))
+        moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),
+                    (48, 256, 8, 8, 2, 2, 8, 1.0)))     # rows of whole lanes
 
 
 # -- kernels ---------------------------------------------------------------
@@ -258,7 +260,7 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
     _masked_attention_checks(size, interpret)
     _check_partial_rotary(size.masked_attention_dim)
     for shape in size.moe_shapes:
-        _check_moe(shape)
+        _check_moe(shape, interpret)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
@@ -348,11 +350,14 @@ def _check_partial_rotary(dim: int, tol: float = 2e-2) -> None:
            f"{err} > {tol}, or the passed dimensions changed")
 
 
-def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
+def _check_moe(shape: Tuple[int, ...], interpret: bool,
+               tol: float = 2e-2) -> None:
     """The expert layer's walk (bf16 operands, tiles of one expert) against
     the plain float32 loop of dense products under the routing's weights,
     forward and the gradients of the tokens and of two of the weights,
-    as shares of each one's largest magnitude."""
+    as shares of each one's largest magnitude. On the chip the walk's rows
+    of whole lanes must move by DMA; such rows' movers are also run
+    alone."""
     import jax
     import jax.numpy as jnp
 
@@ -395,6 +400,14 @@ def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
         return [jnp.max(jnp.abs(g.astype(jnp.float32) - w))
                 / jnp.max(jnp.abs(w)) for g, w in zip(got, want)]
 
+    by_dma = moe.dma_takes(tokens, hidden, tile, x.dtype)
+    if not interpret:   # every shape the chip checks is a cell's
+        _check(by_dma and moe.rows_by_dma(tokens, hidden, tile, x.dtype)
+               and _mosaic_calls(jax.jit(walked), x, router, gate, up,
+                                 down) == 2,
+               f"the expert layer's rows at {tokens}x{hidden} in tiles of "
+               f"{tile} do not move by DMA (a tile's fetch and a round's "
+               "combine: two Mosaic kernels)")
     errs = [float(e) for e in errors(x, router, gate, up, down)]
     _info(f"kernels: expert layer {tokens} tokens x {hidden}, {held} of "
           f"{experts} experts of {width}, top-{top_k}, weights summing to "
@@ -403,6 +416,81 @@ def _check_moe(shape: Tuple[int, ...], tol: float = 2e-2) -> None:
           + ", ".join(f"{e:.3e}" for e in errs) + f" (tol {tol:.0e})")
     _check(max(errs) <= tol, "the expert layer's walk differs from the "
            f"float32 loop of dense products: {errs} > {tol}")
+    if by_dma:
+        _check_row_movers(shape, x, interpret)
+
+
+def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
+    """The walk's two row kernels alone, over a tile's worth of ``x``'s
+    rows and a round's buffer in which a pick is live as often as an even
+    routing holds it: equal to XLA's gather of the same rows, and on the
+    chip how long a row takes (the fetch: a tile's rows; the combine: the
+    live picks' rows, the float32 sums and their write included), timed
+    over calls that loop ON the device: one dispatch from the host costs
+    more than a fetch."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.ops import moe
+
+    tokens, hidden, _, experts, held, top_k, tile, _ = shape
+    rows = moe.round_rows(tokens, top_k, held, experts, tile)
+    keys = jax.random.split(jax.random.key(12), 5)
+    token = jnp.sort(jax.random.randint(keys[0], (tile,), 0, tokens))
+    index = jnp.where(
+        jax.random.bernoulli(keys[1], held / experts, (tokens, top_k)),
+        jax.random.randint(keys[2], (tokens, top_k), 0, rows), rows)
+    live = int(jnp.sum(index < rows))
+    buffer = jax.random.normal(keys[3], (rows + tile, hidden),
+                               x.dtype).at[rows:].set(0)
+    acc = jax.random.normal(keys[4], (tokens, hidden))
+    x_words, buffer_words = moe._words(x), moe._words(buffer)
+
+    def fetch(words, token):
+        return moe._fetch([words], token, x.dtype, interpret)[0]
+
+    def combine(acc, words, index):
+        return moe._combine_dma(acc, words, jnp.sort(index, axis=1), rows,
+                                interpret)
+
+    _check(bool(jnp.array_equal(fetch(x_words, token), x[token])),
+           f"the row fetch differs from XLA's gather at {tokens}x{hidden}")
+    _check(bool(jnp.array_equal(
+        combine(acc, buffer_words, index),
+        moe._combined(acc, buffer, index, rows, False))),
+        f"the combine differs from XLA's gathers at {tokens}x{hidden}")
+    if interpret:       # a time is the chip's to give
+        _info(f"kernels: expert layer's rows by DMA at {tokens}x{hidden} "
+              "equal to XLA's gather (interpreted, not timed)")
+        return
+
+    calls = 100
+
+    @jax.jit
+    def fetches(words, token):      # a kernel's call cannot be cut short
+        return jax.lax.fori_loop(
+            0, calls, lambda i, seen: seen + fetch(
+                words, jnp.roll(token, i))[0, 0].astype(jnp.float32), 0.0)
+
+    @jax.jit
+    def combines(acc, words, index):
+        return jax.lax.fori_loop(
+            0, calls, lambda i, acc: combine(acc, words, index), acc)
+
+    def seconds(looped, *args) -> float:
+        jax.block_until_ready(looped(*args))
+        start = timeit.default_timer()
+        jax.block_until_ready(looped(*args))
+        return (timeit.default_timer() - start) / calls
+
+    fetch_s = seconds(fetches, x_words, token)
+    combine_s = seconds(combines, acc, buffer_words, index)
+    _info(f"kernels: expert layer's rows by DMA at {tokens}x{hidden} "
+          f"(Mosaic) equal to XLA's gather: a tile's fetch "
+          f"{1e9 * fetch_s / tile:.1f} ns a row ({tile} rows, "
+          f"{1e6 * fetch_s:.1f} us), a round's combine "
+          f"{1e9 * combine_s / max(live, 1):.1f} ns a live row ({live} of "
+          f"{tokens * top_k} picks, {1e3 * combine_s:.3f} ms)")
 
 
 # -- loader -> device feed -> train step -------------------------------------

@@ -16,19 +16,37 @@ pairs whose expert is held are sorted by expert and cut into tiles of
 the occupied tiles are a prefix of a static worst case, and a loop walks
 them under a bound computed on the device (the idiom of
 ``models/bert.py:_masked_nll``): every pick held walks ``tokens x top_k /
-tile`` tiles and more, none held walks none. A tile gathers its tokens'
+tile`` tiles and more, none held walks none. A tile fetches its tokens'
 rows, runs the expert's three products on bf16 operands (float32
 accumulation: SwiGLU, ``silu(x G) * (x U)`` then ``D``), weighs the rows
 and puts them side by side in a buffer of one round's tiles
 (``round_rows``: what an even routing fills, and a quarter); when a round's
-tiles are done each token gathers its picks' rows from it and adds them
-up, and a routing that fills more than one buffer takes another round.
-Nothing of ``tokens x top_k`` rows is ever materialised, and nothing is
-scattered: XLA's scatter-add of a tile's rows into their tokens' took
-0.87 ms a tile on a v5e, fifteen times the tile's products (PERF.md
-section 6, PR 32). The backward is written out (``_moe_bwd``): the same
-walk, a tile's ``x G`` and ``x U`` made again, eight products a tile, the
-experts' gradients added in float32 in place, the tokens' gathered.
+tiles are done each token adds up its picks' rows of it, and a routing
+that fills more than one buffer takes another round. Nothing of ``tokens
+x top_k`` rows is ever materialised, and nothing is scattered: XLA's
+scatter-add of a tile's rows into their tokens' took 0.87 ms a tile on a
+v5e, fifteen times the tile's products (PERF.md section 6, PR 32). The
+backward is written out (``_moe_bwd``): the same walk, a tile's ``x G``
+and ``x U`` made again, eight products a tile, the experts' gradients
+added in float32 in place, the tokens' combined as the forward's sums.
+
+The rows move by DMA where they can (``rows_by_dma``: on the TPU, rows of
+whole lanes): a tile's rows in by ``_fetch``, one copy a row out of HBM,
+and the tokens' sums by ``_combine_dma``, one copy a LIVE pick, the
+float32 sums made in VMEM and written once. On a v5e a fetched row of
+4.6 KB takes 21 ns, 14 where two arrays are fetched off one index (the
+backward's ``x`` and ``dout``), and a token's sum 68 ns a live row at two
+live picks a token (102 at one: a block of 128 tokens costs 7 us whatever
+it fetches, a copy 35 ns of the scalar core's time; the HBM is not what
+bounds either). XLA's gather costs 45 ns a row inside the walk whatever
+the row holds, a dead pick's row of zeros too, and the parent of these
+kernels bent round that: it sorted the tokens by their count of live
+picks and gathered block by block, 2.3 rows a token for 2.0 live, and
+once more to put the sums back in order, 104 ns a live row (PERF.md
+section 6, PR 37, has all of it measured). Off the chip and at other shapes
+the fallback is the plain one: every pick's row gathered, the spare row
+of zeros for a dead pick, added in the same order, so the two paths agree
+bit for bit.
 
 The grouped products are XLA's dense ``dot_general`` on a tile, not a
 Pallas kernel and not ``jax.lax.ragged_dot``: one expert a tile makes each
@@ -43,7 +61,11 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ray_shuffling_data_loader_tpu.ops import on_tpu
+from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 #: The name a device trace shows the layer's operations under: router,
@@ -53,6 +75,18 @@ SCOPE = "rsdl.lm.moe"
 #: The most rows of one expert an even routing puts in a tile of the walk
 #: (``tile_rows``).
 _TILE_LOAD = 1024
+
+_LANES, _SUBLANES = 128, 8
+
+#: Rows a grid step of the row kernels moves, all of their copies in
+#: flight at once.
+_BLOCK = 128
+
+#: Tokens whose chains of tests an iteration of the combine's issue loop
+#: writes out: more make the kernel longer to lower (a process start pays
+#: that before it can even ask the compile cache), fewer leave the loop's
+#: own cost on each token.
+_UNROLL = 4
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -97,23 +131,27 @@ def tile_rows(tokens: int, top_k: int, experts: int) -> int:
     experts``) cut into as few tiles as hold ``_TILE_LOAD`` rows each, and
     an eighth of room, in whole lanes. Larger tiles leave more of each
     expert's last tile empty; smaller ones make the loop long, and a
-    tile's fixed cost (its index arithmetic, its gathers, the in-place
-    adds of three expert gradients) is 0.26 ms in the backward on a v5e.
-    The room and not the bare share: a power of two at the expected load
-    puts every expert on a tile's edge, one tile more or fewer by the
-    seed's luck, and the walk is longer or shorter for it.
+    tile's fixed cost (its index arithmetic, its row fetches' start, the
+    in-place adds of three expert gradients) is 0.26 ms in the backward
+    on a v5e. The room and not the bare share: a power of two at the
+    expected load puts every expert on a tile's edge, one tile more or
+    fewer by the seed's luck, and the walk is longer or shorter for it.
 
-    Measured on a v5e, forward / forward and backward, ms. At
-    ``mellum_train_8k``'s shapes (PR 32; 32,768 tokens x 2304, 16 of 64
-    experts of 896, top-8: 4,096 rows an expert, 3,730-4,530 under a
-    balanced router): tiles of 256 33.3 / 125.0, 512 31.4 / 82.8, 1024
-    30.9 / 64.7; the rule gives 1152, and 4 x 1152 holds every such load
-    (PR 34: 1024 21.3 / 54.8, 1152 21.0 / 52.4). At ``laguna_train_8k``'s
-    (PR 34; 16,384 tokens x 2048, 32 of 256 experts of 512, top-8: 512
-    rows an expert, 413-581 under a balanced router): 256 8.7 / 17.9, 384
-    9.3 / 17.9, 512 8.6 / 16.1, 576 8.4 / 15.2, 640 8.4 / 15.1, 768
-    8.7 / 15.7, 1024 9.8 / 17.3, 1152 10.0 / 17.6; the rule gives 640,
-    one tile an expert."""
+    Measured on a v5e, a layer alone, forward / forward and backward, ms,
+    the rows moved by DMA (PR 37; PR 32 and PR 34 measured the same with
+    XLA's gathers and chose the same tiles). At ``mellum_train_8k``'s
+    shapes (32,768 tokens x 2304, 16 of 64 experts of 896, top-8: 4,096
+    rows an expert, 3,730-4,530 under a balanced router): tiles of 512
+    18.8 / 57.2, 768 18.4 / 52.7, 1024 18.0 / 50.7, 1152 17.7 / 49.5,
+    1280 18.3 / 51.2, 2304 17.1 / 47.3; the rule gives 1152 (20.4 / 56.5
+    with XLA's gathers), and 4 x 1152 holds every such load. 2304, two
+    tiles an expert, is 4 % faster still at this routing: the rule is
+    left as it is until a PR of its own weighs that against the rounds a
+    less even routing then takes. At ``laguna_train_8k``'s (16,384 tokens
+    x 2048, 32 of 256 experts of 512, top-8: 512 rows an expert, 413-581
+    under a balanced router): 256 7.2 / 19.9, 384 7.5 / 19.9, 512 7.0 /
+    18.6, 640 6.6 / 16.9, 768 6.9 / 18.0, 1024 7.7 / 20.0; the rule gives
+    640, one tile an expert (7.8 / 20.0 with XLA's gathers)."""
     expected = -(-tokens * top_k // experts)
     tiles = -(-expected // _TILE_LOAD)
     share = -(-expected // tiles)
@@ -205,7 +243,7 @@ def _walk(plan, position, rows: int, tile: int, tile_fn, gather_fn, carry,
     buffers, index)`` adds to each token what its picks' rows hold
     (``index`` (N, top_k): a pick's row in the buffers, or ``rows``, a
     row nobody writes, for a pick outside the round). No scatter: a tile's
-    rows land side by side, and the tokens gather them."""
+    rows land side by side, and the tokens fetch them."""
     per_round = rows // tile
     tiles = plan[3][-1]
 
@@ -228,48 +266,282 @@ def _walk(plan, position, rows: int, tile: int, tile_fn, gather_fn, carry,
                              one_round, (carry, buffers))[0]
 
 
-#: The tokens gather their picks' rows in this many blocks, each as many
-#: times as its fullest token has picks in the round.
-_GATHER_BLOCKS = 16
+def dma_takes(tokens: int, hidden: int, tile: int, dtype) -> bool:
+    """Whether the kernels below can move such rows: float32 or bfloat16,
+    whole lanes of 32-bit words a row, in arrays of whole sublanes of
+    rows."""
+    dtype = jnp.dtype(dtype)
+    return (dtype in (jnp.float32, jnp.bfloat16)
+            and hidden * dtype.itemsize % (4 * _LANES) == 0
+            and tokens % _SUBLANES == 0 and tile % _SUBLANES == 0)
 
 
-def _gathered(buffer, index, spare: int):
-    """For each token the float32 sum of its picks' rows of ``buffer``;
-    ``index`` (N, top_k) holds ``spare``, a row of zeros, for a pick with
-    nothing in the buffer. Three picks in four are such where the chip
-    holds a quarter of the experts, and a row gathered costs the same
-    whatever it holds (45 ns on a v5e): so the tokens are taken in the
-    order of how many live picks they have, a block of them at a time,
-    and a block gathers only as many times as its first token's count.
-    At 32,768 tokens, top-8 and a quarter held that is 2.3 rows a token
-    and one more to put the sums back in the tokens' order, not 8."""
-    tokens, hidden = index.shape[0], buffer.shape[1]
-    blocks = _GATHER_BLOCKS if tokens % _GATHER_BLOCKS == 0 else 1
-    size = tokens // blocks
+def rows_by_dma(tokens: int, hidden: int, tile: int, dtype) -> bool:
+    """Whether the walk moves its rows by DMA and not by XLA's gather,
+    from what the trace can see: on the TPU, where :func:`dma_takes` the
+    rows."""
+    return on_tpu() and dma_takes(tokens, hidden, tile, dtype)
+
+
+def _words(a):
+    """``a`` (N, hidden) as rows of 32-bit words, which is what a one-row
+    DMA takes (a sublane of bf16 holds two rows, and Mosaic slices no
+    half sublane out of HBM): float32 as it is; bfloat16 as (N, hidden /
+    2) uint32, word ``j`` holding column ``j`` in its low half and column
+    ``j + hidden / 2`` in its high half. Halves and not neighbours: an
+    elementwise pass over two contiguous slices, no shuffle of the lanes,
+    and a half widened to float32 is one shift or one mask
+    (:func:`_halves`)."""
+    if a.dtype == jnp.float32:
+        return a
+    half = a.shape[1] // 2
+    # The bits as they are: widened through float32 instead, XLA drops
+    # the rounding to bf16 that the caller's cast asked for (excess
+    # precision is allowed it), and the words hold truncated values.
+    # Each half sliced before it is widened: one fusion, where widening
+    # the whole row first wrote it out at twice the size and read it back.
+    low, high = (jax.lax.bitcast_convert_type(part, jnp.uint16
+                                              ).astype(jnp.uint32)
+                 for part in (a[:, :half], a[:, half:]))
+    return low | (high << 16)
+
+
+def _halves(words):
+    """The two halves of words of :func:`_words`, float32: a bfloat16 is
+    the high half of the float32 of its value."""
+    return (jax.lax.bitcast_convert_type(words << 16, jnp.float32),
+            jax.lax.bitcast_convert_type(words & jnp.uint32(0xFFFF0000),
+                                         jnp.float32))
+
+
+def _tiled(words):
+    """``words`` (N, lanes) as (N / 8, lanes / 128, 8, 128): the (8, 128)
+    tiles the array is made of in HBM, in the order they lie there, so
+    XLA makes this a bitcast and moves nothing. Mosaic slices one row out
+    of a tile that is the array's whole width and refuses it of a wider
+    array (``Slice shape along dimension 0 must be aligned to tiling
+    (8)``): row ``r`` is ``[r // 8, :, r % 8]`` here, ``lanes / 128``
+    pieces of 512 bytes that one strided copy moves."""
+    rows, lanes = words.shape
+    return words.reshape(rows // _SUBLANES, _SUBLANES, lanes // _LANES,
+                         _LANES).transpose(0, 2, 1, 3)
+
+
+def _row_of(tiled_ref, row):
+    return tiled_ref.at[row // _SUBLANES, :, pl.ds(row % _SUBLANES, 1), :]
+
+
+def _block(rows: int) -> int:
+    """Rows a grid step of the kernels below moves, all of their copies
+    in flight at once: ``_BLOCK``, fewer where there are fewer, in whole
+    sublanes of bf16."""
+    return min(_BLOCK, _round_up(rows, 2 * _SUBLANES))
+
+
+def _fetch(sources, index, dtype, interpret: bool):
+    """``[s[index] for s in sources]`` in ``dtype``, each row by a DMA of
+    its own straight out of HBM: ``sources`` are (N, lanes) arrays of
+    :func:`_words`, ``index`` (rows,) int32, scalar-prefetched. A grid
+    step starts a block's copies back to back, each into its row of a
+    landing buffer of 128-lane pieces, waits for them, and writes the
+    pieces side by side, a bf16 row's words widened on the way."""
+    packed = jnp.dtype(dtype) != sources[0].dtype
+    lanes = sources[0].shape[1]
+    pieces = lanes // _LANES
+    hidden = 2 * lanes if packed else lanes
+    rows, count = index.shape[0], len(sources)
+    block = _block(rows)
+    padded = _round_up(rows, block)
+    if padded != rows:
+        index = jnp.pad(index, (0, padded - rows))
+
+    def kernel(index_ref, *refs):
+        srcs, outs = refs[:count], refs[count:2 * count]
+        landings, sem = refs[2 * count:-1], refs[-1]
+        base = pl.program_id(0) * block
+
+        def copies(row, j):
+            return [pltpu.make_async_copy(
+                _row_of(src, row), landing.at[:, pl.ds(j, 1), :], sem)
+                for src, landing in zip(srcs, landings)]
+
+        def start(group, _):
+            for j in range(_SUBLANES):      # unrolled: static strides
+                j = group * _SUBLANES + j
+                for copy in copies(index_ref[base + j], j):
+                    copy.start()
+
+        def wait(group, _):
+            for _ in range(_SUBLANES):
+                for copy in copies(0, 0):
+                    copy.wait()
+
+        jax.lax.fori_loop(0, block // _SUBLANES, start, None)
+        jax.lax.fori_loop(0, block // _SUBLANES, wait, None)
+        for landing, out in zip(landings, outs):
+            for piece in range(pieces):
+                at = piece * _LANES
+                parts = (_halves(landing[piece]) if packed
+                         else (landing[piece],))
+                for k, part in enumerate(parts):
+                    out[:, k * lanes + at:k * lanes + at + _LANES] = (
+                        part.astype(out.dtype))
+
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(padded // block,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * count,
+            out_specs=[pl.BlockSpec((block, hidden), lambda i, _: (i, 0))
+                       ] * count,
+            scratch_shapes=[pltpu.VMEM((pieces, block, _LANES),
+                                       sources[0].dtype)] * count
+            + [pltpu.SemaphoreType.DMA(())]),
+        out_shape=[jax.ShapeDtypeStruct((padded, hidden), dtype)] * count,
+        interpret=interpret,
+    )(index, *(_tiled(source) for source in sources))
+    return [out[:rows] for out in outs]
+
+
+def _combine_dma(acc, buffer, index, spare: int, interpret: bool):
+    """:func:`_combined` by DMA. ``buffer`` holds rows of :func:`_words`;
+    ``index`` (N, top_k) is sorted along a token's picks, live first. A
+    grid step takes a block of tokens in their own order. It starts one
+    copy a LIVE pick, pick ``j`` of token ``t`` landing in row ``t`` of
+    slot ``j``: eight tokens an iteration, written out, each a chain of
+    tests that ends at its first dead pick (a dead pick costs nothing, a
+    token one scalar read and one test more than it has picks). Then as
+    many waits. Then eight tokens at a time, the slots are added up in
+    float32, slot by slot, under each token's mask (a slot's rows of
+    tokens with fewer picks hold what an earlier block left there), and
+    the block of ``acc`` plus the sums is written once, in ``acc``'s
+    place."""
+    tokens, top_k = index.shape
+    packed = buffer.dtype != jnp.float32
+    lanes = buffer.shape[1]
+    pieces = lanes // _LANES
+    hidden = acc.shape[1]
+    block = _block(tokens)
+    padded = _round_up(tokens, block)
+    if padded != tokens:
+        index = jnp.pad(index, ((0, padded - tokens), (0, 0)),
+                        constant_values=spare)
+        acc = jnp.pad(acc, ((0, padded - tokens), (0, 0)))
+    # What the scalar core reads, a token a stride: its count of live
+    # picks, then their rows. Whole sublanes of a block of 1,024 words,
+    # which is how XLA lays a vector out in SMEM.
+    stride = _round_up(1 + top_k, _SUBLANES)
+    live = jnp.sum(index < spare, axis=1, keepdims=True, dtype=jnp.int32)
+    plan = jnp.pad(jnp.concatenate([live, index], axis=1),
+                   ((0, 0), (0, stride - 1 - top_k))).reshape(-1)
+
+    def kernel(plan_ref, picks_ref, acc_ref, buffer_ref, out_ref, slots,
+               sem):
+        def copy(row, j, t):
+            return pltpu.make_async_copy(
+                _row_of(buffer_ref, row), slots.at[j, :, pl.ds(t, 1), :],
+                sem)
+
+        def start(group, started):
+            for t in range(_UNROLL):        # written out: static strides
+                t = group * _UNROLL + t
+                live = plan_ref[t * stride]
+                started = started + live
+
+                def picks_from(j, t=t, live=live):
+                    @pl.when(live > j)
+                    def _():
+                        copy(plan_ref[t * stride + 1 + j], j, t).start()
+                        if j + 1 < top_k:
+                            picks_from(j + 1)
+
+                picks_from(0)
+            return started
+
+        started = jax.lax.fori_loop(0, block // _UNROLL, start,
+                                    jnp.int32(0))
+
+        def wait(_, carry):
+            for _ in range(_SUBLANES):
+                copy(0, 0, 0).wait()
+
+        jax.lax.fori_loop(0, started // _SUBLANES, wait, None)
+        jax.lax.fori_loop(0, started % _SUBLANES,
+                          lambda _, c: copy(0, 0, 0).wait(), None)
+
+        def add(group, _):
+            rows = pl.ds(pl.multiple_of(group * _SUBLANES, _SUBLANES),
+                         _SUBLANES)
+            picks = picks_ref[rows, :]
+            masks = [jnp.broadcast_to(picks[:, j:j + 1] < spare,
+                                      (_SUBLANES, _LANES))
+                     for j in range(top_k)]
+
+            def add_piece(piece, _):
+                sums = [jnp.zeros((_SUBLANES, _LANES), jnp.float32)
+                        for _ in range(2 if packed else 1)]
+                for j in range(top_k):
+                    words = slots[j, piece, rows, :]
+                    parts = _halves(words) if packed else (words,)
+                    sums = [s + jnp.where(masks[j], part, 0.0)
+                            for s, part in zip(sums, parts)]
+                for k, part in enumerate(sums):
+                    span = pl.ds(pl.multiple_of(
+                        k * lanes + piece * _LANES, _LANES), _LANES)
+                    out_ref[rows, span] = acc_ref[rows, span] + part
+
+            jax.lax.fori_loop(0, pieces, add_piece, None)
+
+        jax.lax.fori_loop(0, block // _SUBLANES, add, None)
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(padded // block,),
+        in_specs=[
+            pl.BlockSpec((block * stride,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block, top_k), lambda i: (i, 0)),
+            pl.BlockSpec((block, hidden), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((block, hidden), lambda i: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((top_k, pieces, block, _LANES), buffer.dtype),
+            pltpu.SemaphoreType.DMA(())],
+        out_shape=jax.ShapeDtypeStruct((padded, hidden), jnp.float32),
+        input_output_aliases={2: 0},
+        interpret=interpret,
+    )(plan, index, acc, _tiled(buffer))
+    return out[:tokens]
+
+
+def _movable(a, dma: bool):
+    """``a``'s rows as what moves them takes them: words for the DMA, as
+    they are for XLA's gather."""
+    return _words(a) if dma else a
+
+
+def _taken(sources, index, dtype, dma: bool):
+    """``[s[index] for s in sources]`` in ``dtype``: a tile's rows of the
+    tokens, out of :func:`_movable` ``sources``."""
+    if dma:
+        return _fetch(sources, index, dtype, not on_tpu())
+    return [source[index] for source in sources]
+
+
+def _combined(acc, buffer, index, spare: int, dma: bool):
+    """``acc`` (N, hidden) float32 plus, for each token, the float32 sum
+    of its picks' rows of ``buffer``, in ascending order of the rows;
+    ``index`` (N, top_k) holds ``spare`` for a pick with nothing in the
+    buffer, which costs a DMA nothing and XLA's gather a row of zeros
+    (``spare`` is a row nobody writes)."""
     with jax.named_scope(SCOPE):
         index = jnp.sort(index, axis=1)       # live picks first: < spare
-        count = jnp.sum(index < spare, axis=1, dtype=jnp.int32)
-        order = jnp.argsort(-count, stable=True)
-        index, count = index[order], count[order]
-        summed = jnp.zeros((tokens, hidden), jnp.float32)
-
-    def one_block(b, summed):
-        def add_pick(j, acc):
-            with jax.named_scope(SCOPE):
-                rows = jax.lax.dynamic_slice(index, (b * size, j),
-                                             (size, 1))[:, 0]
-                return acc + buffer[rows].astype(jnp.float32)
-
-        with jax.named_scope(SCOPE):
-            acc = jnp.zeros((size, hidden), jnp.float32)
-        acc = jax.lax.fori_loop(0, count[b * size], add_pick, acc)
-        with jax.named_scope(SCOPE):
-            return jax.lax.dynamic_update_slice_in_dim(summed, acc,
-                                                       b * size, axis=0)
-
-    summed = jax.lax.fori_loop(0, blocks, one_block, summed)
-    with jax.named_scope(SCOPE):
-        return summed[jnp.argsort(order)]
+        if dma:
+            return _combine_dma(acc, buffer, index, spare, not on_tpu())
+        summed = jnp.zeros(acc.shape, jnp.float32)
+        for j in range(index.shape[1]):
+            summed = summed + buffer[index[:, j]].astype(jnp.float32)
+        return acc + summed
 
 
 def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
@@ -280,7 +552,6 @@ def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
                        scale)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
                 top_k: int, tile: int, scale: float = 1.0):
     """The held experts' part of a sparse-expert layer, and what the walk
@@ -308,15 +579,29 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
     fullest held expert's pairs. It has no gradient, and is not made again
     where a ``jax.checkpoint`` makes the layer again.
     """
-    return _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale)[0]
+    dma = rows_by_dma(*x.shape, tile, x.dtype)
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_moe_gather_total",
+        "Sparse-expert layers traced, by what moves the walk's rows (a "
+        "tile's tokens in, the tokens' picks out): one DMA a row from a "
+        "Pallas kernel, or XLA's gather",
+        kind="dma" if dma else "xla").inc()
+    return _moe(x, router, gate, up, down, held, top_k, tile, scale, dma)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _moe(x, router, gate, up, down, held, top_k, tile, scale, dma):
+    return _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale,
+                    dma)[0]
 
 
 # Jitted for the scope's sake, as models/bert.py's head: inside a program
 # of its own (and inside a loop's body) the name reaches the compiled step
 # as written. The ``while`` instructions themselves carry no scope of the
 # program's, so nothing is counted twice.
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
-def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale, dma):
     first, count = held
     tokens, hidden = x.shape
     if gate.shape[0] != count:
@@ -329,21 +614,20 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale):
         flat = weights.reshape(-1)
         g16, u16, d16 = (w.astype(x.dtype) for w in (gate, up, down))
         out = jnp.zeros((tokens, hidden), jnp.float32)
-        ys = jnp.zeros((rows + tile, hidden), x.dtype)
+        x_rows = _movable(x, dma)
+        ys = _movable(jnp.zeros((rows + tile, hidden), x.dtype), dma)
         walk = _walk_counts(plan, position, count, rows // tile)
 
     def tile_fn(i, out, ys, at):
         expert, token, w = _tile(i, plan, flat, top_k, tile)
-        xs = x[token]
+        xs, = _taken([x_rows], token, x.dtype, dma)
         h = (jax.nn.silu(_dot(xs, _of(g16, expert), _NN))
              * _dot(xs, _of(u16, expert), _NN))
-        return out, _put(ys, _dot(h.astype(x.dtype), _of(d16, expert), _NN)
-                         * w, at)
+        y = _dot(h.astype(x.dtype), _of(d16, expert), _NN) * w
+        return out, _put(ys, _movable(y.astype(x.dtype), dma), at)
 
     def gather_fn(out, ys, index):
-        summed = _gathered(ys, index, rows)
-        with jax.named_scope(SCOPE):
-            return out + summed
+        return _combined(out, ys, index, rows, dma)
 
     out = _walk(plan, position, rows, tile, tile_fn, gather_fn, out, ys)
     with jax.named_scope(SCOPE):
@@ -351,8 +635,8 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale):
     return (out, walk), (x, router, gate, up, down, weights, plan, position)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _moe_bwd(held, top_k, tile, scale, residuals, cotangents):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _moe_bwd(held, top_k, tile, scale, dma, residuals, cotangents):
     x, router, gate, up, down, weights, plan, position = residuals
     dout = cotangents[0]            # the walk's counts have none
     tokens, hidden = x.shape
@@ -360,19 +644,20 @@ def _moe_bwd(held, top_k, tile, scale, residuals, cotangents):
     with jax.named_scope(SCOPE):
         flat = weights.reshape(-1)
         g16, u16, d16 = (w.astype(x.dtype) for w in (gate, up, down))
-        dout = dout.astype(x.dtype)
+        x_rows = _movable(x, dma)
+        dout_rows = _movable(dout.astype(x.dtype), dma)
         grads = (jnp.zeros((tokens, hidden), jnp.float32),
                  jnp.zeros((tokens, top_k), jnp.float32),
                  jnp.zeros(gate.shape, jnp.float32),
                  jnp.zeros(up.shape, jnp.float32),
                  jnp.zeros(down.shape, jnp.float32))
-        buffers = (jnp.zeros((rows + tile, hidden), x.dtype),
+        buffers = (_movable(jnp.zeros((rows + tile, hidden), x.dtype), dma),
                    jnp.zeros((rows + tile, 1), jnp.float32))
 
     def tile_fn(i, grads, buffers, at):
         d_x, d_weights, d_gate, d_up, d_down = grads
         expert, token, w = _tile(i, plan, flat, top_k, tile)
-        xs, dy = x[token], dout[token]
+        xs, dy = _taken([x_rows, dout_rows], token, x.dtype, dma)
         ge, ue, de = _of(g16, expert), _of(u16, expert), _of(d16, expert)
         g, u = _dot(xs, ge, _NN), _dot(xs, ue, _NN)
         sig = jax.nn.sigmoid(g)
@@ -390,13 +675,13 @@ def _moe_bwd(held, top_k, tile, scale, residuals, cotangents):
                  d_up.at[expert].add(_dot(xs, du, _TN)),
                  d_down.at[expert].add(
                      _dot((h * w).astype(x.dtype), dy, _TN))),
-                (_put(buffers[0], dxs, at), _put(buffers[1], dw, at)))
+                (_put(buffers[0], _movable(dxs.astype(x.dtype), dma), at),
+                 _put(buffers[1], dw, at)))
 
     def gather_fn(grads, buffers, index):
-        summed = _gathered(buffers[0], index, rows)
+        d_x = _combined(grads[0], buffers[0], index, rows, dma)
         with jax.named_scope(SCOPE):
-            return (grads[0] + summed, grads[1] + buffers[1][index, 0],
-                    *grads[2:])
+            return d_x, grads[1] + buffers[1][index, 0], *grads[2:]
 
     d_x, d_weights, d_gate, d_up, d_down = _walk(
         plan, position, rows, tile, tile_fn, gather_fn, grads, buffers)
@@ -408,4 +693,4 @@ def _moe_bwd(held, top_k, tile, scale, residuals, cotangents):
     return d_x, d_router, d_gate, d_up, d_down
 
 
-moe_counted.defvjp(_moe_fwd, _moe_bwd)
+_moe.defvjp(_moe_fwd, _moe_bwd)

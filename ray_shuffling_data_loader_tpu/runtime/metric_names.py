@@ -53,11 +53,12 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    inline, and window | full again for the layers whose checkpoint
     #    keeps the forward kernel's results; a SwiGLU's = dense | shared,
     #    the expert layer's = share | all of the router's experts held
-    #    here) --
+    #    here; ops/moe.py counts what moves the walk's rows, dma | xla) --
     "rsdl_lm_attention_total": ("counter", ("kind",)),
     "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
+    "rsdl_moe_gather_total": ("counter", ("kind",)),
     "rsdl_moe_experts_held": ("gauge", ()),
     "rsdl_moe_experts_routed": ("gauge", ()),
     "rsdl_moe_top_k": ("gauge", ()),

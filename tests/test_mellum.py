@@ -226,11 +226,11 @@ def test_yarn_frequencies_are_the_formula_written_out(
 _HIDDEN, _WIDTH, _EXPERTS, _TOP_K = 16, 8, 8, 2
 
 
-def _expert_weights(key, held):
+def _expert_weights(key, held, hidden=_HIDDEN):
     ks = jax.random.split(key, 3)
-    return {"gate": jax.random.normal(ks[0], (held, _HIDDEN, _WIDTH)),
-            "up": jax.random.normal(ks[1], (held, _HIDDEN, _WIDTH)),
-            "down": jax.random.normal(ks[2], (held, _WIDTH, _HIDDEN))}
+    return {"gate": jax.random.normal(ks[0], (held, hidden, _WIDTH)),
+            "up": jax.random.normal(ks[1], (held, hidden, _WIDTH)),
+            "down": jax.random.normal(ks[2], (held, _WIDTH, hidden))}
 
 
 def _moe_sizes(first, count):
@@ -238,49 +238,61 @@ def _moe_sizes(first, count):
             "num_experts_per_tok": _TOP_K}
 
 
-def _favouring(experts):
+def _favouring(experts, hidden=_HIDDEN):
     """A router under which every token of positive features picks
     ``experts`` (and only them, where there are ``_TOP_K``)."""
-    router = jnp.zeros((_HIDDEN, _EXPERTS))
+    router = jnp.zeros((hidden, _EXPERTS))
     for rank, e in enumerate(experts):
         router = router.at[:, e].set(1.0 + 0.1 * rank)
     return router
 
 
-def _tied(experts):
-    router = jnp.zeros((_HIDDEN, _EXPERTS))
+def _tied(experts, hidden=_HIDDEN):
+    router = jnp.zeros((hidden, _EXPERTS))
     return router.at[:, jnp.asarray(experts)].set(1.0)
 
 
-@pytest.mark.parametrize("held,router,tile,scale", [
-    ((2, 2), _favouring([2, 3]), 8, 1.0),   # every pick is held
-    ((2, 2), _favouring([0, 7]), 8, 1.0),   # none is
-    ((2, 2), _favouring([7, 2]), 8, 1.0),   # one held expert takes every
-                                            # token
-    ((2, 2), _tied([2, 3, 4]), 8, 1.0),     # ties: three equal, two picked
+#: (held, the router's maker, tile, scale)
+_ROUTINGS = pytest.mark.parametrize("held,router,tile,scale", [
+    ((2, 2), functools.partial(_favouring, [2, 3]), 8, 1.0),   # every
+                                                               # pick is held
+    ((2, 2), functools.partial(_favouring, [0, 7]), 8, 1.0),   # none is
+    ((2, 2), functools.partial(_favouring, [7, 2]), 8, 1.0),   # one held
+                                            # expert takes every token
+    ((2, 2), functools.partial(_tied, [2, 3, 4]), 8, 1.0),     # ties: three
+                                                    # equal, two picked
     ((2, 2), None, 8, 1.0),                 # any routing, a share held
     ((0, 8), None, 8, 1.0),                 # any routing, all held
     ((2, 2), None, None, 2.5),              # the tile the shapes give (one
                                             # of 128 rows an expert), the
                                             # routed sum scaled
-    ((2, 2), _favouring([7, 2]), None, 2.5),
+    ((2, 2), functools.partial(_favouring, [7, 2]), None, 2.5),
 ], ids=["every_pick_held", "none_held", "one_expert_takes_all", "ties",
         "random_share", "random_all_held", "ruled_tile_scaled",
         "ruled_tile_one_expert_takes_all"])
+
+
+def _routed_layer(held, router, hidden=_HIDDEN, dtype=jnp.float32):
+    """24 tokens of positive features, the router, the held experts'
+    weights and a mix for the gradients' loss."""
+    key = jax.random.key(held[1])
+    x = jnp.abs(jax.random.normal(key, (24, hidden))).astype(dtype)
+    router = (jax.random.normal(jax.random.fold_in(key, 1),
+                                (hidden, _EXPERTS)) if router is None
+              else router(hidden=hidden))
+    weights = _expert_weights(jax.random.fold_in(key, 2), held[1], hidden)
+    return x, router, weights, jax.random.normal(
+        jax.random.fold_in(key, 3), x.shape)
+
+
+@_ROUTINGS
 def test_the_expert_layer_is_exact_for_any_routing(held, router, tile,
                                                    scale):
     """No capacity, no dropped token: output and all five gradients equal
     the plain loop of dense products under masks, walked in tiles of 8
     rows (several tiles an expert, the last part empty) or of what
     ``moe.tile_rows`` gives."""
-    key = jax.random.key(held[1])
-    x = jnp.abs(jax.random.normal(key, (24, _HIDDEN)))
-    if router is None:
-        router = jax.random.normal(jax.random.fold_in(key, 1),
-                                   (_HIDDEN, _EXPERTS))
-    weights = _expert_weights(jax.random.fold_in(key, 2), held[1])
-    mix = jax.random.normal(jax.random.fold_in(key, 3), x.shape)
-
+    x, router, weights, mix = _routed_layer(held, router)
     if tile is None:
         tile = moe.tile_rows(x.shape[0], _TOP_K, _EXPERTS)
 
@@ -305,6 +317,131 @@ def test_the_expert_layer_is_exact_for_any_routing(held, router, tile,
     held_picks = ((picked >= held[0]) & (picked < sum(held))).sum()
     assert (held_picks == 0) == (
         not np.asarray(program(x, router, weights)).any())
+
+
+def _bits(a):
+    return np.asarray(a.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("dtype,hidden", [(jnp.float32, 128),
+                                          (jnp.bfloat16, 256)],
+                         ids=["float32", "bfloat16"])
+@_ROUTINGS
+def test_rows_moved_by_dma_move_no_bit(held, router, tile, scale, dtype,
+                                       hidden, monkeypatch):
+    """The same routings at rows of whole lanes, the walk's rows moved by
+    the kernels (interpreted here) and by XLA's gather: a gather is a
+    copy and the combine keeps its order, so output and all five
+    gradients are equal bit for bit. A bf16 row travels as words of two
+    halves; ``every_pick_held`` takes a second round."""
+    x, router, weights, mix = _routed_layer(held, router, hidden, dtype)
+    if tile is None:
+        tile = moe.tile_rows(x.shape[0], _TOP_K, _EXPERTS)
+
+    def program(x, router, w):
+        return moe.moe(x, router, w["gate"], w["up"], w["down"], held,
+                       _TOP_K, tile, scale)
+
+    def results(dma):
+        monkeypatch.setattr(moe, "rows_by_dma", lambda *shape: dma)
+        return [program(x, router, weights), *jax.tree.leaves(jax.grad(
+            lambda *a: jnp.sum(program(*a).astype(jnp.float32) * mix),
+            (0, 1, 2))(x, router, weights))]
+
+    def kernels(dma):
+        monkeypatch.setattr(moe, "rows_by_dma", lambda *shape: dma)
+        # a function of its own each time: a trace is kept by function
+        return _pallas_calls(jax.make_jaxpr(lambda *a: program(*a))(
+            x, router, weights).jaxpr)
+
+    # a tile's fetch and a round's combine
+    assert (kernels(False), kernels(True)) == (0, 2)
+    got, want = results(True), results(False)
+    assert len(got) == 6
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("dtype,hidden", [(jnp.float32, 128),
+                                          (jnp.float32, 384),
+                                          (jnp.bfloat16, 256),
+                                          (jnp.bfloat16, 768)],
+                         ids=["f32x128", "f32x384", "bf16x256", "bf16x768"])
+def test_the_row_fetch_is_a_copy(dtype, hidden):
+    """``source[index]`` a row a DMA: duplicate rows, the first and the
+    last, fewer rows than a block and more (two grid steps, the last
+    part empty), two sources off one index."""
+    first, second = (jax.random.normal(jax.random.key(k), (40, hidden)
+                                       ).astype(dtype) for k in (0, 1))
+    for index in ([0, 39, 7, 7, 7, 0, 39, 21, 3], list(range(39, -1, -1)) * 4):
+        index = jnp.asarray(index, jnp.int32)
+        got = moe._fetch([moe._words(first), moe._words(second)], index,
+                         dtype, True)
+        for g, source in zip(got, (first, second)):
+            assert g.dtype == dtype and g.shape == (len(index), hidden)
+            np.testing.assert_array_equal(_bits(g), _bits(source[index]))
+
+
+@pytest.mark.parametrize("second_round", [False, True],
+                         ids=["first_round", "second_round"])
+@pytest.mark.parametrize("dtype,hidden", [(jnp.float32, 128),
+                                          (jnp.bfloat16, 256),
+                                          (jnp.bfloat16, 768)],
+                         ids=["f32x128", "bf16x256", "bf16x768"])
+def test_the_combine_adds_live_picks_in_row_order(dtype, hidden,
+                                                  second_round):
+    """A token's sum is its live picks' rows added in float32 in
+    ascending order of the rows, onto what an earlier round left: a token
+    with no live pick keeps it, one with all eight adds eight, a row may
+    be picked twice (by one token and by two), and the spare row is never
+    read (it holds no zeros here)."""
+    top_k, spare = 8, 48
+    buffer = jax.random.normal(jax.random.key(0), (spare + 8, hidden)
+                               ).astype(dtype)
+    rng = np.random.default_rng(0)
+    index = np.full((136, top_k), spare, np.int32)
+    index[1] = [5, 3, 47, 0, 9, 21, 22, 40]             # all eight live
+    index[2] = [spare, 7, spare, 7, spare, spare, 2, spare]   # a row twice
+    index[3, 5] = 7                                     # and in two tokens
+    for t in range(4, 136):                     # token 0 and others: none
+        live = rng.integers(0, 5)
+        index[t, rng.choice(top_k, live, replace=False)] = rng.integers(
+            0, spare, live)
+    acc = (jax.random.normal(jax.random.key(1), (136, hidden))
+           if second_round else jnp.zeros((136, hidden)))
+    ordered = np.sort(index, axis=1)
+    want = np.zeros((136, hidden), np.float32)
+    rows = _bits(buffer)
+    for j in range(top_k):
+        live = ordered[:, j] < spare
+        want[live] += rows[ordered[live, j]]
+    want = np.asarray(acc) + want
+    # XLA's side reads the spare row for a dead pick: zeros there
+    plain = moe._combined(acc, buffer.at[spare:].set(0), jnp.asarray(index),
+                          spare, False)
+    got = moe._combine_dma(acc, moe._words(buffer),
+                           jnp.sort(jnp.asarray(index), axis=1), spare, True)
+    np.testing.assert_array_equal(np.asarray(plain), want)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(acc[0]))
+
+
+def test_what_moves_the_rows_follows_the_shapes(monkeypatch):
+    """By DMA on the chip where a row is whole lanes of 32-bit words of a
+    type the kernels widen, in arrays of whole sublanes; XLA's gather
+    otherwise, and everywhere off the chip."""
+    mellum_cell = (4 * 8192, 2304, 1152, jnp.bfloat16)
+    laguna_cell = (2 * 8192, 2048, 640, jnp.bfloat16)
+    assert not moe.rows_by_dma(*mellum_cell)        # the CPU
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    assert moe.rows_by_dma(*mellum_cell) and moe.rows_by_dma(*laguna_cell)
+    assert moe.rows_by_dma(24, 128, 8, jnp.float32)
+    assert not moe.rows_by_dma(24, 128, 8, jnp.bfloat16)    # half a word's
+    assert not moe.rows_by_dma(24, 16, 8, jnp.float32)      # lanes
+    assert not moe.rows_by_dma(24, 256, 8, jnp.float16)
+    assert not moe.rows_by_dma(20, 128, 8, jnp.float32)
+    assert not moe.rows_by_dma(24, 128, 12, jnp.float32)
 
 
 def test_the_walks_tile_follows_the_shapes():
@@ -704,6 +841,8 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
     before = {kind: count("rsdl_lm_attention_total", kind=kind)
               for kind in ("inline", "window", "full")}
     layers_before = count("rsdl_moe_layer_total", kind="share")
+    gathers_before = {kind: count("rsdl_moe_gather_total", kind=kind)
+                      for kind in ("dma", "xla")}
     mlps_before = {kind: count("rsdl_lm_mlp_total", kind=kind)
                    for kind in swiglus}
     text = jax.jit(jax.grad(lambda p, t: mellum.loss_fn(cfg, p, t))).lower(
@@ -728,6 +867,11 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
     assert count("rsdl_lm_attention_total", kind="window") == before["window"]
     assert count("rsdl_moe_layer_total", kind="share") == (
         layers_before + sparse)
+    # CPU: every walk's rows move by XLA's gather
+    assert count("rsdl_moe_gather_total", kind="xla") == (
+        gathers_before["xla"] + sparse)
+    assert count("rsdl_moe_gather_total", kind="dma") == (
+        gathers_before["dma"])
     for kind, traced in swiglus.items():
         assert count("rsdl_lm_mlp_total", kind=kind) == (
             mlps_before[kind] + traced), kind
@@ -762,6 +906,34 @@ def test_the_attention_counter_tells_window_from_full(build, windows, fulls,
                    jnp.zeros((1, 8), jnp.int32))
     assert (count("window"), count("full")) == (
         before[0] + windows, before[1] + fulls + windows + fulls)
+
+
+@pytest.mark.parametrize("chip,hidden,dma", [
+    (False, 256, False),    # every CPU run
+    (True, 256, True),      # the chip: bf16 rows of 128 words
+    (True, 64, False)],     # rows of a quarter of a lane's words
+    ids=["cpu", "chip", "chip_narrow_rows"])
+@pytest.mark.parametrize("build", [mellum.mellum_tiny, mellum.laguna_tiny],
+                         ids=["mellum", "laguna"])
+def test_the_gather_counter_tells_dma_from_xla(build, chip, hidden, dma,
+                                               monkeypatch):
+    """One count a sparse layer traced, by what moves its walk's rows:
+    nothing but the backend and the shapes chooses."""
+    monkeypatch.setattr(moe, "on_tpu", lambda: chip)
+    cfg = dataclasses.replace(build(), hidden_size=hidden)
+    sparse = sum(cfg.mlp_type(i) == mellum.SPARSE
+                 for i in range(cfg.num_layers))
+
+    def count(kind):
+        metric = metrics.get("rsdl_moe_gather_total", {"kind": kind})
+        return 0 if metric is None else metric.value
+
+    before = count("dma"), count("xla")
+    jax.eval_shape(lambda p, t: mellum.loss_fn(cfg, p, t),
+                   mellum.init(cfg, jax.random.key(0)),
+                   jnp.zeros((1, 16), jnp.int32))
+    assert (count("dma") - before[0], count("xla") - before[1]) == (
+        (sparse, 0) if dma else (0, sparse))
 
 
 @pytest.mark.parametrize("build", [mellum.mellum_tiny, mellum.laguna_tiny],
