@@ -1,6 +1,8 @@
-"""A sparse-expert causal decoder with window and full attention mixed:
-one chip's share of a language model, by its configuration
-(``mellum2_ep4_share``, ``laguna_xs2_ep8_share``).
+"""A causal decoder whose layers mix window attention, full attention
+and Mamba-2 state-space mixers, with dense or sparse-expert MLPs: one
+chip's share of a language model, by its configuration
+(``mellum2_ep4_share``, ``laguna_xs2_ep8_share``,
+``granite4_h_micro_period``).
 
 Per layer, on the residual stream: RMSNorm, grouped-query attention (a
 layer's own count of query heads over ``num_kv_heads`` key/value heads,
@@ -14,9 +16,20 @@ each layer's attention: ``sliding_attention`` is causal within the last
 is causal over the whole row under YaRN's (interpolated and extrapolated
 frequencies blended once, whatever the sequence length; cos and sin
 scaled by its attention factor) over the first ``full_rotary_factor`` of
-a head's dimensions. After the last layer RMSNorm and an untied head; the
-loss is the mean next-token negative log-likelihood. Same functional API
-as the other families: ``init``, ``loss_fn``.
+a head's dimensions; with ``rotary`` off (``position_embedding_type:
+"nope"``) an attention layer rotates nothing. A ``mamba`` layer's mixer is
+Mamba-2's: one projection to ``z | x B C | dt``, a depthwise causal
+convolution and ``silu`` over ``x B C``, the state-space scan
+(``ops/ssd.py``: ``mamba_heads`` heads of ``mamba_head_dim``, a state of
+``mamba_state``, B and C in one group), an RMSNorm gated by ``silu(z)`` and
+the projection back. Four scalars, all 1 unless a configuration says
+otherwise: ``embedding_multiplier`` on the embedding,
+``residual_multiplier`` on each half's output before it is added,
+``attention_multiplier`` as the softmax's scale in place of 1 / sqrt(head
+dimension), ``logits_scaling`` dividing the logits. After the last layer
+RMSNorm and the head, untied or (``tie_embeddings``) the embedding's own
+matrix; the loss is the mean next-token negative log-likelihood. Same
+functional API as the other families: ``init``, ``loss_fn``.
 
 Under expert parallelism a chip holds ``experts_held = (first, count)`` of
 the router's ``num_experts`` and a slice of the vocabulary: the expert
@@ -56,19 +69,23 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
-from ray_shuffling_data_loader_tpu.ops import flash_attention, moe, on_tpu
+from ray_shuffling_data_loader_tpu.ops import (flash_attention, moe, on_tpu,
+                                               ssd)
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 from ray_shuffling_data_loader_tpu.utils import tracing
 
 IGNORE_ID = -100
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, MAMBA = "sliding_attention", "full_attention", "mamba"
 DENSE, SPARSE = "dense", "sparse"
 
 # The names a device trace shows a layer's projections' (q, k, v, the gate
 # and ``wo``), its attention's, its expert layer's (``ops/moe.py``), its
-# dense MLP's or shared expert's and the head's operations under.
+# dense MLP's or shared expert's and the head's operations under; a Mamba
+# mixer's two projections are under the first, what lies between them (the
+# convolution, the scan, the gated norm: ``ops/ssd.py``) under ``SSM_SCOPE``.
 PROJ_SCOPE = "rsdl.lm.proj"
 ATTENTION_SCOPE = "rsdl.lm.attention"
+SSM_SCOPE = ssd.SCOPE
 MOE_SCOPE = moe.SCOPE
 MLP_SCOPE = "rsdl.lm.mlp"
 HEAD_SCOPE = "rsdl.lm.head"
@@ -116,6 +133,19 @@ class DecoderConfig:
     # the share of a head's dimensions a full layer rotates (the first)
     full_rotary_factor: float = 1.0
     yarn: YarnConfig = YarnConfig()
+    rotary: bool = True           # False: no positions at all ("nope")
+    # the softmax's scale; None: 1 / sqrt(head_dim)
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False  # the head is the embedding's matrix
+    # a ``mamba`` layer's mixer (Mamba-2; B and C in one group)
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
     compute_dtype: Any = jnp.bfloat16
     published_layers: int = 28    # the uncut depth: scales ``init`` only
 
@@ -131,6 +161,11 @@ class DecoderConfig:
     def mlp_type(self, layer: int) -> str:
         return (SPARSE if self.mlp_layer_types is None
                 else self.mlp_layer_types[layer])
+
+    @property
+    def mamba_width(self) -> int:
+        """Channels of a Mamba mixer's ``x`` (and of its gate ``z``)."""
+        return self.mamba_heads * self.mamba_head_dim
 
 
 def mellum2_ep4_share() -> DecoderConfig:
@@ -187,6 +222,43 @@ def laguna_tiny() -> DecoderConfig:
         shared_expert_width=32, **_LAGUNA_PATTERN)
 
 
+_GRANITE_PERIOD = dict(
+    layer_types=5 * (MAMBA,) + (FULL,) + 4 * (MAMBA,),
+    mlp_layer_types=10 * (DENSE,), rotary=False, tie_embeddings=True,
+    attention_multiplier=0.015625, embedding_multiplier=12.0,
+    residual_multiplier=0.22, logits_scaling=8.0, rms_norm_eps=1e-5,
+    rope_theta=10_000.0, published_layers=40)
+
+
+def granite4_h_micro_period() -> DecoderConfig:
+    """granite-4.0-h-micro at its published widths, cut to one period of
+    its layer pattern, the published layers 0-9 of 40 (five Mamba-2
+    layers, one attention layer of 32:8 heads of 64 without positions,
+    four more Mamba-2 layers; every MLP the dense SwiGLU of 8,192), and
+    an eighth of the 100,352-id vocabulary under the tied embedding: one
+    pipeline stage of four."""
+    return DecoderConfig(
+        vocab_size=12_544, hidden_size=2048, num_heads=32, num_kv_heads=8,
+        head_dim=64, intermediate_size=8192, mamba_heads=64,
+        mamba_head_dim=64, mamba_state=128, mamba_conv=4, mamba_chunk=256,
+        **_GRANITE_PERIOD)
+
+
+def granite_tiny() -> DecoderConfig:
+    """For tests/CPU smoke runs: granite's pattern at a third of a period
+    (two Mamba-2 layers, one attention layer, one more Mamba-2 layer), all
+    four multipliers off 1, 4 state-space heads of 8 with a state of 16
+    in chunks of 8."""
+    return DecoderConfig(
+        vocab_size=512, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=16, intermediate_size=128, mamba_heads=4, mamba_head_dim=8,
+        mamba_state=16, mamba_conv=4, mamba_chunk=8,
+        **{**_GRANITE_PERIOD,
+           "layer_types": (MAMBA, MAMBA, FULL, MAMBA),
+           "mlp_layer_types": 4 * (DENSE,),
+           "attention_multiplier": 0.0625})
+
+
 def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     """Seeded float32 weights: the embedding N(0, 1), matrices N(0, 0.02),
     the projections that write into the residual stream (``wo``, every
@@ -194,7 +266,12 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     scaled init), unit norm scales. At 0.02 everywhere the mean of a
     thousand values that uniform attention over random tokens makes
     outweighs a token's own embedding, and every token of a row routes
-    alike."""
+    alike. A tied embedding is the head too and is drawn as one, N(0,
+    0.02), and there is no ``head`` leaf. A Mamba mixer (state-spaces/
+    mamba's ``Mamba2``): the step ``dt`` log-uniform in [0.001, 0.1] as
+    ``dt_bias`` (its inverse softplus), ``A`` uniform in [1, 16] as
+    ``a_log``, ``D`` 1, the convolution's taps and bias uniform in
+    +-1 / sqrt(taps)."""
     h, f = config.hidden_size, config.expert_width
     kv_width = config.num_kv_heads * config.head_dim
     held = config.experts_held[1]
@@ -209,19 +286,44 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
                 f"{prefix}up": normal((*shape, h, width)),
                 f"{prefix}down": normal((*shape, width, h), residual)}
 
-    params: Dict[str, Any] = {"embed": normal((config.vocab_size, h), 1.0),
-                              "head": normal((h, config.vocab_size)),
-                              "final_norm": jnp.ones((h,), jnp.float32)}
+    def uniform(shape, low, high):
+        return jax.random.uniform(next(keys), shape, jnp.float32, low, high)
+
+    def mamba_mixer():
+        width, taps = config.mamba_width, config.mamba_conv
+        conved = width + 2 * config.mamba_state
+        dt = jnp.exp(uniform((config.mamba_heads,), math.log(0.001),
+                             math.log(0.1)))
+        edge = 1.0 / math.sqrt(taps)
+        return {"mamba_norm": jnp.ones((h,), jnp.float32),
+                "in_proj": normal((h, width + conved + config.mamba_heads)),
+                "conv_w": uniform((taps, conved), -edge, edge),
+                "conv_b": uniform((conved,), -edge, edge),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.log(uniform((config.mamba_heads,), 1.0, 16.0)),
+                "d": jnp.ones((config.mamba_heads,), jnp.float32),
+                "ssm_norm": jnp.ones((width,), jnp.float32),
+                "out_proj": normal((width, h), residual)}
+
+    params: Dict[str, Any] = {"final_norm": jnp.ones((h,), jnp.float32)}
+    if config.tie_embeddings:
+        params["embed"] = normal((config.vocab_size, h))
+    else:
+        params["embed"] = normal((config.vocab_size, h), 1.0)
+        params["head"] = normal((h, config.vocab_size))
     for layer in range(config.num_layers):
         heads = config.heads(layer)
         q_width = heads * config.head_dim
-        lp = {"attn_norm": jnp.ones((h,), jnp.float32),
-              "wq": normal((h, q_width)),
-              "wk": normal((h, kv_width)),
-              "wv": normal((h, kv_width)),
-              "wo": normal((q_width, h), residual)}
-        if config.attention_gate:
-            lp["wg"] = normal((h, heads))
+        if config.layer_types[layer] == MAMBA:
+            lp = mamba_mixer()
+        else:
+            lp = {"attn_norm": jnp.ones((h,), jnp.float32),
+                  "wq": normal((h, q_width)),
+                  "wk": normal((h, kv_width)),
+                  "wv": normal((h, kv_width)),
+                  "wo": normal((q_width, h), residual)}
+            if config.attention_gate:
+                lp["wg"] = normal((h, heads))
         if config.mlp_type(layer) == DENSE:
             lp["mlp_norm"] = jnp.ones((h,), jnp.float32)
             lp.update(swiglu("", (), config.intermediate_size))
@@ -326,17 +428,19 @@ def _gated(out, gate, heads: int):
 
 # The attentions are jitted for the scope's sake, as models/bert.py's: inside
 # a program of its own the name reaches the compiled step as written.
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
-                      window: Optional[int]):
+                      window: Optional[int], scale: Optional[float] = None):
     """Causal grouped-query attention as XLA has it: float32 softmax over
-    materialized (B, H, S, S) scores; the backward is autodiff's."""
+    materialized (B, H, S, S) scores, times ``scale`` (``None``: over the
+    root of a head's dimensions); the backward is autodiff's."""
     with jax.named_scope(ATTENTION_SCOPE):
         b, s, _ = q.shape
         q = q.reshape(b, s, kv_heads, heads // kv_heads, -1)
         k, v = (x.reshape(b, s, kv_heads, -1) for x in (k, v))
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32)
-        scores = scores / jnp.sqrt(q.shape[-1])
+        scores = (scores / jnp.sqrt(q.shape[-1]) if scale is None
+                  else scores * scale)
         ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
         seen = ahead >= 0
         if window is not None:
@@ -375,19 +479,20 @@ def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
     return side, side
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_attention(q, k, v, gate, heads: int, kv_heads: int,
-                     window: Optional[int]):
+                     window: Optional[int], scale: Optional[float] = None):
     """``_inline_attention``'s result from the blocked Pallas kernels."""
-    return _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window)[0]
+    return _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window,
+                                scale)[0]
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
-def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale):
     with jax.named_scope(ATTENTION_SCOPE):
         out, lse = flash_attention.grouped_forward(
             q, k, v, heads, kv_heads, True, window, *_blocks(window, False),
-            interpret=not on_tpu())
+            interpret=not on_tpu(), scale=scale)
         # The two residuals the half's checkpoint keeps (``decode``), so
         # that the backward pass has them without this kernel run again;
         # q, k, v and the gate it makes again. lse without the column's
@@ -404,8 +509,9 @@ def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window):
         return _gated(out, gate, heads), (q, k, v, gate, out, lse)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _flash_attention_bwd(heads, kv_heads, window, scale, residuals,
+                         cotangent):
     q, k, v, gate, out, lse = residuals
     with jax.named_scope(ATTENTION_SCOPE):
         d_gate = None
@@ -420,16 +526,18 @@ def _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent):
             cotangent = _gated(cotangent, gate, heads)
         return (*flash_attention.grouped_backward(
             q, k, v, out, lse[..., None], cotangent, heads, kv_heads, True,
-            window, *_blocks(window, True), interpret=not on_tpu()), d_gate)
+            window, *_blocks(window, True), interpret=not on_tpu(),
+            scale=scale), d_gate)
 
 
-def _counted_flash_attention_bwd(heads, kv_heads, window, residuals,
+def _counted_flash_attention_bwd(heads, kv_heads, window, scale, residuals,
                                  cotangent):
     # Counted here, once a layer: the program under it is traced once.
     q, k = residuals[:2]
     flash_attention.count_backward(flash_attention.grouped_backward_kind(
         q, k, heads, *_blocks(window, True), interpret=not on_tpu()))
-    return _flash_attention_bwd(heads, kv_heads, window, residuals, cotangent)
+    return _flash_attention_bwd(heads, kv_heads, window, scale, residuals,
+                                cotangent)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
@@ -439,8 +547,9 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
                heads: int):
     """A layer's attention over rotated q (B, S, H x D) and k, v
     (B, S, Hkv x D), each query head's output times its ``gate``
-    (B, S, H) where there is one, by what the trace can observe: the
-    kernels where they beat the inline path
+    (B, S, H) where there is one, the softmax scaled by
+    ``attention_multiplier`` where the configuration has one, by what the
+    trace can observe: the kernels where they beat the inline path
     (``flash_attention.beats_inline``)."""
     seq_len = q.shape[1]
     window = config.sliding_window if layer_type == SLIDING else None
@@ -463,7 +572,8 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
             "backward pass: every layer the kernels compute",
             kind=kind).inc()
     attend = _flash_attention if flash else _inline_attention
-    return attend(q, k, v, gate, heads, config.num_kv_heads, window)
+    return attend(q, k, v, gate, heads, config.num_kv_heads, window,
+                  config.attention_multiplier)
 
 
 # -- the dense MLP and the shared expert -----------------------------------------
@@ -574,19 +684,64 @@ def _project(x, weight):
         return x @ weight.astype(x.dtype)
 
 
+def _added(config: DecoderConfig, x, out):
+    """``x + residual_multiplier x out``, a half's output joining the
+    residual stream."""
+    if config.residual_multiplier == 1.0:
+        return x + out
+    return x + (out.astype(jnp.float32)
+                * config.residual_multiplier).astype(x.dtype)
+
+
 def _attention_half(config: DecoderConfig, layer: int, x, lp):
     """x + attention(RMSNorm(x)), the first half of a layer."""
     layer_type, heads = config.layer_types[layer], config.heads(layer)
-    cos, sin = _rope_tables(config, layer_type, x.shape[1])
-    rotated = rotated_dims(config, layer_type)
+    if config.rotary:
+        cos, sin = _rope_tables(config, layer_type, x.shape[1])
+        rotated = rotated_dims(config, layer_type)
+
+    def placed(projected, count):
+        return (_rope(projected, count, cos, sin, rotated) if config.rotary
+                else projected)
+
     a = _rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
-    q = _rope(_project(a, lp["wq"]), heads, cos, sin, rotated)
-    k = _rope(_project(a, lp["wk"]), config.num_kv_heads, cos, sin, rotated)
+    q = placed(_project(a, lp["wq"]), heads)
+    k = placed(_project(a, lp["wk"]), config.num_kv_heads)
     v = _project(a, lp["wv"])
     gate = (jax.nn.sigmoid(_project(a, lp["wg"]).astype(jnp.float32))
             if config.attention_gate else None)
-    return x + _project(_attention(config, q, k, v, gate, layer_type, heads),
-                        lp["wo"])
+    return _added(config, x, _project(
+        _attention(config, q, k, v, gate, layer_type, heads), lp["wo"]))
+
+
+def _mamba_half(config: DecoderConfig, layer: int, x, lp):
+    """x + Mamba-2(RMSNorm(x)), a ``mamba`` layer's first half: the two
+    projections under ``PROJ_SCOPE``, what lies between them under
+    ``SSM_SCOPE``. How much state crossed the scan's chunks goes out as
+    the step's ``ssm_scan`` of this layer."""
+    b, s, _ = x.shape
+    width, state = config.mamba_width, config.mamba_state
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_lm_ssm_total",
+        "Decoder layers' state-space mixers traced, by what computes the "
+        "scan: XLA's products over chunks", kind="chunked_xla").inc()
+    rt_metrics.gauge("rsdl_lm_ssm_chunk",
+                     "Positions in a chunk of the state-space scan, last "
+                     "layer traced").set(config.mamba_chunk)
+    n = _rms_norm(x, lp["mamba_norm"], config.rms_norm_eps)
+    z, xbc, dt = jnp.split(_project(n, lp["in_proj"]),
+                           [width, 2 * width + 2 * state], axis=-1)
+    xbc = ssd.causal_conv_silu(xbc, lp["conv_w"], lp["conv_b"])
+    xs, b_in, c_in = jnp.split(xbc, [width, width + state], axis=-1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    y, crossed = ssd.ssd_counted(
+        xs.reshape(b, s, config.mamba_heads, config.mamba_head_dim), dt,
+        lp["a_log"], b_in, c_in, lp["d"], config.mamba_chunk)
+    tracing.step_stat("ssm_scan", crossed, layer=layer)
+    y = ssd.gated_rms_norm(y.reshape(b, s, width), z, lp["ssm_norm"],
+                           config.rms_norm_eps)
+    return _added(config, x, _project(y, lp["out_proj"]))
 
 
 def _mlp_half(config: DecoderConfig, layer: int, x, lp):
@@ -594,12 +749,14 @@ def _mlp_half(config: DecoderConfig, layer: int, x, lp):
     or the held experts' part of the routed sum and the shared expert."""
     if config.mlp_type(layer) == DENSE:
         n = _rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
-        return x + _mlp("dense", n, lp["gate"], lp["up"], lp["down"])
+        return _added(config, x, _mlp("dense", n, lp["gate"], lp["up"],
+                                      lp["down"]))
     n = _rms_norm(x, lp["moe_norm"], config.rms_norm_eps)
-    out = x + _experts(config, layer, n, lp)
+    out = _added(config, x, _experts(config, layer, n, lp))
     if config.shared_expert_width:
-        out = out + _mlp("shared", n, lp["shared_gate"], lp["shared_up"],
-                         lp["shared_down"])
+        out = _added(config, out, _mlp(
+            "shared", n, lp["shared_gate"], lp["shared_up"],
+            lp["shared_down"]))
     return out
 
 
@@ -609,7 +766,7 @@ def _checked(config: DecoderConfig) -> None:
         raise ValueError(f"experts_held {config.experts_held} reaches past "
                          f"the router's {config.num_experts} experts")
     for name, kinds, known in (
-            ("layer_types", config.layer_types, (SLIDING, FULL)),
+            ("layer_types", config.layer_types, (SLIDING, FULL, MAMBA)),
             ("mlp_layer_types", config.mlp_layer_types, (DENSE, SPARSE))):
         for kind in kinds or ():
             if kind not in known:
@@ -619,6 +776,8 @@ def _checked(config: DecoderConfig) -> None:
         if per_layer is not None and len(per_layer) != config.num_layers:
             raise ValueError(f"{name} names {len(per_layer)} layers, "
                              f"layer_types {config.num_layers}")
+    if MAMBA in config.layer_types and config.mamba_heads < 1:
+        raise ValueError("a mamba layer needs mamba_heads")
 
 
 def decode(config: DecoderConfig, params: Dict[str, Any],
@@ -637,8 +796,10 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
             f"deployment; a mesh of {mesh.size} devices needs the expert "
             "layer's exchange across chips, which does not exist yet")
     _checked(config)
-    x = jnp.take(params["embed"], token_ids, axis=0,
-                 mode="clip").astype(config.compute_dtype)
+    x = jnp.take(params["embed"], token_ids, axis=0, mode="clip")
+    if config.embedding_multiplier != 1.0:
+        x = x * config.embedding_multiplier
+    x = x.astype(config.compute_dtype)
     # Of an attention half, its bf16 input and the forward kernel's two
     # results are kept, where the kernels run: 272 MB a layer of 4 rows of
     # 8,192 tokens and 32 heads, against 19.5 ms (the whole triangle) or
@@ -652,11 +813,16 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
         # exist again, so the two halves' activations never sit on the
         # chip together.
         lp = params[f"layer_{layer}"]
-        x = jax.checkpoint(functools.partial(_attention_half, config, layer),
-                           policy=keep_kernel_results)(x, lp)
-        # What the half records of the step's own counters leaves its
+        # What a half records of the step's own counters leaves its
         # checkpoint as an output (counted in the forward pass, not again
         # when the half is made again).
+        if config.layer_types[layer] == MAMBA:
+            x = tracing.step_stats_of(jax.checkpoint(tracing.with_step_stats(
+                functools.partial(_mamba_half, config, layer))))(x, lp)
+        else:
+            x = jax.checkpoint(
+                functools.partial(_attention_half, config, layer),
+                policy=keep_kernel_results)(x, lp)
         x = tracing.step_stats_of(jax.checkpoint(tracing.with_step_stats(
             functools.partial(_mlp_half, config, layer))))(x, lp)
     return x
@@ -677,11 +843,14 @@ def head_block_size(tokens: int) -> int:
     return min(HEAD_BLOCK_TOKENS, 8 * -(-tokens // 8))
 
 
-def _block_nll(x, head, targets):
+def _block_nll(x, head, targets, logits_scaling: float = 1.0):
     """Summed cross-entropy of the tokens of ``x`` (n, h) whose
-    ``targets`` (n,) are not ``IGNORE_ID``."""
+    ``targets`` (n,) are not ``IGNORE_ID``, the logits divided by
+    ``logits_scaling``."""
     mask = targets != IGNORE_ID
     logits = jnp.dot(x, head, preferred_element_type=jnp.float32)
+    if logits_scaling != 1.0:
+        logits = logits / logits_scaling
     logp = jax.nn.log_softmax(logits, axis=-1)
     picked = jnp.take_along_axis(
         logp, jnp.where(mask, targets, 0)[:, None], axis=-1)[:, 0]
@@ -702,20 +871,21 @@ def _flat_padded(x, targets, block):
             jnp.pad(ts, (0, pad), constant_values=IGNORE_ID))
 
 
-@jax.custom_vjp
-def _nll(x, head, targets):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _nll(x, head, targets, logits_scaling: float = 1.0):
     """Summed cross-entropy over the positions of ``x`` (B, S, h) whose
-    ``targets`` (B, S) are not ``IGNORE_ID``, against the untied ``head``
-    (h, vocab): what ``_block_nll`` gives over all of them at once, walked
-    a block of tokens at a time. The backward makes a block's logits
-    again, so nothing (tokens, vocab) outlives a block.
+    ``targets`` (B, S) are not ``IGNORE_ID``, against the ``head``
+    (h, vocab), its logits divided by ``logits_scaling``: what
+    ``_block_nll`` gives over all of them at once, walked a block of
+    tokens at a time. The backward makes a block's logits again, so
+    nothing (tokens, vocab) outlives a block.
     """
-    return _nll_fwd(x, head, targets)[0]
+    return _nll_fwd(x, head, targets, logits_scaling)[0]
 
 
 # Jitted for their names' sake (models/bert.py:_masked_nll_fwd).
-@jax.jit
-def _nll_fwd(x, head, targets):
+@functools.partial(jax.jit, static_argnums=(3,))
+def _nll_fwd(x, head, targets, logits_scaling):
     block = head_block_size(x.shape[0] * x.shape[1])
     with jax.named_scope(HEAD_SCOPE):
         xs, ts = _flat_padded(x, targets, block)
@@ -724,15 +894,16 @@ def _nll_fwd(x, head, targets):
     def add_block(k, total):
         with jax.named_scope(HEAD_SCOPE):
             return total + _block_nll(_block_of(xs, k, block), head16,
-                                      _block_of(ts, k, block))
+                                      _block_of(ts, k, block),
+                                      logits_scaling)
 
     total = jax.lax.fori_loop(0, xs.shape[0] // block, add_block,
                               jnp.float32(0))
     return total, (x, targets, head16)
 
 
-@jax.jit
-def _nll_bwd(residuals, cotangent):
+@functools.partial(jax.jit, static_argnums=(0,))
+def _nll_bwd(logits_scaling, residuals, cotangent):
     x, targets, head16 = residuals
     block = head_block_size(x.shape[0] * x.shape[1])
     with jax.named_scope(HEAD_SCOPE):
@@ -743,8 +914,9 @@ def _nll_bwd(residuals, cotangent):
         d_xs, d_head = grads
         with jax.named_scope(HEAD_SCOPE):
             block_targets = _block_of(ts, k, block)
-            _, vjp = jax.vjp(lambda x, w: _block_nll(x, w, block_targets),
-                             _block_of(xs, k, block), head16)
+            _, vjp = jax.vjp(
+                lambda x, w: _block_nll(x, w, block_targets, logits_scaling),
+                _block_of(xs, k, block), head16)
             dx, dw = vjp(cotangent)
             return (jax.lax.dynamic_update_slice_in_dim(
                         d_xs, dx, k * block, axis=0),
@@ -776,5 +948,8 @@ def loss_fn(config: DecoderConfig, params: Dict[str, Any],
     x = _rms_norm(decode(config, params, token_ids, mesh),
                   params["final_norm"], config.rms_norm_eps)
     targets = next_token_targets(token_ids.astype(jnp.int32))
-    total = _nll(x, params["head"], targets)
+    # A tied head is the embedding's own matrix: the one leaf takes the
+    # gradient of both uses.
+    head = params["embed"].T if config.tie_embeddings else params["head"]
+    total = _nll(x, head, targets, config.logits_scaling)
     return total / jnp.maximum(jnp.sum(targets != IGNORE_ID), 1)

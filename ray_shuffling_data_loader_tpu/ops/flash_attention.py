@@ -786,10 +786,12 @@ def _query_major(mask: _Mask, group: int, bq: int, bk: int, num_k: int):
 def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
                      interpret: bool, packed: bool = False,
                      num_heads: Optional[int] = None,
-                     num_kv_heads: Optional[int] = None):
+                     num_kv_heads: Optional[int] = None,
+                     scale: Optional[float] = None):
     """``(out, lse (B, H, Sq, 1))`` from the blocked kernels; the operands
     (B, H, S, D) with k and v of H or fewer heads, or ``packed``
-    (B, S, H x D)."""
+    (B, S, H x D). ``scale`` multiplies the scores (``None``: 1 /
+    sqrt(D))."""
     b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
     group, axis = h // hkv, 1 if packed else 2
     bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
@@ -807,7 +809,8 @@ def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
             (None, None, 1, bk), lambda *ids: (ids[0], 0, 0, k_at(*ids)[2])))
         args.append(bias_arr)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=d ** -0.5,
+        functools.partial(_fwd_kernel,
+                          scale=d ** -0.5 if scale is None else scale,
                           has_bias=bias_arr is not None, mask=mask, bq=bq,
                           bk=bk, num_k=num_k, steps=steps),
         grid=(b, h, num_q, steps),
@@ -852,7 +855,8 @@ def _blocked_kind(sq: int, sk: int, d: int, dtype, block_q: int,
 def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
                       block_q: int, block_k: int, interpret: bool,
                       packed: bool = False, num_heads: Optional[int] = None,
-                      num_kv_heads: Optional[int] = None, delta=None):
+                      num_kv_heads: Optional[int] = None, delta=None,
+                      scale: Optional[float] = None):
     """``(dq, dk, dv, dbias per key/value head (B, Hkv, 1, Sk) or None)``
     from the blocked kernels: one (``_bwd_kernel``) where a key/value
     head's dk and dv fit in VMEM (``_fused_fits``), else the split pair.
@@ -876,7 +880,8 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
            else _pad_rows(delta, sq_pad))
     num_q, num_k = sq_pad // bq, sk_pad // bk
     has_bias = bias_arr is not None
-    static = dict(scale=d ** -0.5, has_bias=has_bias, mask=mask, bq=bq, bk=bk)
+    static = dict(scale=d ** -0.5 if scale is None else scale,
+                  has_bias=has_bias, mask=mask, bq=bq, bk=bk)
 
     def specs(q_at, k_at):
         rows = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
@@ -1129,24 +1134,28 @@ def _reads_in_place(head_dim: int, interpret: bool) -> bool:
 def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = False):
+                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
+                    scale: Optional[float] = None):
     """Self-attention of projections as their matmuls leave them: ``q``
     (B, S, H x D), ``k`` and ``v`` (B, S, Hkv x D), a head's D side by
     side; query head h reads key/value head h // (H / Hkv), which is
     fetched once a block and repeated nowhere. Returns ``(out
     (B, S, H x D), lse (B, H, S, 1))``. Where D is whole lanes (128) the
     kernels read and write these arrays in place: no (B, S, H, D) ->
-    (B, H, S, D) copy on either side. Without a custom_vjp of its own: the
+    (B, H, S, D) copy on either side; narrower heads (64) are copied
+    head-major around the same kernels. ``scale`` multiplies the scores
+    (``None``: 1 / sqrt(D)). Without a custom_vjp of its own: the
     caller pairs it with :func:`grouped_backward` under its own scope
     (models/mellum.py does)."""
     mask = _mask_of(causal, window)
     if _reads_in_place(q.shape[-1] // num_heads, interpret):
         return _blocked_forward(q, k, v, None, mask, block_q, block_k,
-                                interpret, True, num_heads, num_kv_heads)
+                                interpret, True, num_heads, num_kv_heads,
+                                scale)
     out, lse = _blocked_forward(
         _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
         _split_heads(v, num_kv_heads), None, mask, block_q, block_k,
-        interpret)
+        interpret, scale=scale)
     return _merge_heads(out), lse
 
 
@@ -1154,7 +1163,8 @@ def grouped_backward(q, k, v, out, lse, do, num_heads: int,
                      num_kv_heads: int, causal: bool = True,
                      window: Optional[int] = None,
                      block_q: int = DEFAULT_BLOCK_Q,
-                     block_k: int = DEFAULT_BLOCK_K, interpret: bool = False):
+                     block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
+                     scale: Optional[float] = None):
     """``(dq, dk, dv)`` in the operands' layouts from
     :func:`grouped_forward`'s operands and results and the output's
     cotangent ``do`` (B, S, H x D)."""
@@ -1162,11 +1172,12 @@ def grouped_backward(q, k, v, out, lse, do, num_heads: int,
     if _reads_in_place(q.shape[-1] // num_heads, interpret):
         return _blocked_backward(q, k, v, None, out, lse, do, mask,
                                  block_q, block_k, interpret, True,
-                                 num_heads, num_kv_heads)[:3]
+                                 num_heads, num_kv_heads, scale=scale)[:3]
     dq, dk, dv, _ = _blocked_backward(
         _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
         _split_heads(v, num_kv_heads), None, _split_heads(out, num_heads),
-        lse, _split_heads(do, num_heads), mask, block_q, block_k, interpret)
+        lse, _split_heads(do, num_heads), mask, block_q, block_k, interpret,
+        scale=scale)
     return _merge_heads(dq), _merge_heads(dk), _merge_heads(dv)
 
 

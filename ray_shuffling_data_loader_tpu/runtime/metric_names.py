@@ -63,6 +63,10 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_moe_experts_routed": ("gauge", ()),
     "rsdl_moe_top_k": ("gauge", ()),
     "rsdl_moe_tile_rows": ("gauge", ()),
+    # -- a decoder layer's state-space mixer (models/mellum.py; counted or
+    #    set when a layer is traced; kind = what computes the scan) --
+    "rsdl_lm_ssm_total": ("counter", ("kind",)),
+    "rsdl_lm_ssm_chunk": ("gauge", ()),
     # -- the train step's own counters (utils/tracing.step_stat, folded by
     #    runtime/telemetry.step_stats_folded once a step's values have
     #    reached the host: counted when the step RAN, unlike the block
@@ -75,6 +79,8 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_moe_fullest_expert_rows": ("gauge", ("layer",)),
     "rsdl_moe_tiles_per_step": ("histogram", ()),
     "rsdl_moe_tiles_last_step": ("gauge", ()),
+    "rsdl_ssm_end_decay_mean": ("gauge", ("layer",)),
+    "rsdl_ssm_carry_abs_max": ("gauge", ("layer",)),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

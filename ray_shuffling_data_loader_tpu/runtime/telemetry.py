@@ -123,17 +123,22 @@ STEP_ANNOTATION = "rsdl.trainer.step"
 
 
 #: The step's own counters (``utils/tracing.step_stat``): stat name ->
-#: the fields of the int32 vector the device computes for it each step.
+#: the fields of the short vector the device computes for it each step.
 #: ``moe_walk`` is one sparse-expert layer's walk (``ops/moe.py``): all
 #: (token, pick) pairs, those whose expert this chip holds, the tiles and
 #: rounds the walk took for them and the fullest held expert's pairs: what
 #: ``_dispatch`` already holds, and no pass over the tokens (a count of
 #: each token's held picks moved the compiled step's schedule, and 2.4 ms
-#: of a 680 ms step with it: PERF.md section 6, PR 36).
+#: of a 680 ms step with it: PERF.md section 6, PR 36). ``ssm_scan`` is
+#: one state-space layer's chunked scan (``ops/ssd.py``), float32: the mean
+#: over rows, chunks and heads of a chunk's whole decay ``exp(cum_end)``
+#: (the share of the state a chunk starts from that reaches its end) and
+#: the largest ``|carry|`` handed from one chunk to the next.
 #: :func:`step_stats_folded` has what each becomes in the registry.
 STEP_STAT_FIELDS: Dict[str, Tuple[str, ...]] = {
     "moe_walk": ("pairs", "pairs_held", "tiles", "rounds",
                  "fullest_expert_rows"),
+    "ssm_scan": ("end_decay_mean", "carry_abs_max"),
 }
 
 #: Upper bounds of ``rsdl_moe_tiles_per_step``: an even routing walks 128
@@ -933,12 +938,24 @@ def step_stats_folded(step: int,
     registry: ``moe_walk`` adds each layer's pairs, held pairs, tiles and
     rounds to their counters, sets the layer's fullest-expert gauge, and
     samples the step's tiles, all layers summed, into
-    ``rsdl_moe_tiles_per_step`` (and the gauge of the last step's)."""
+    ``rsdl_moe_tiles_per_step`` (and the gauge of the last step's);
+    ``ssm_scan`` sets each layer's two gauges."""
     if not _ENABLED:
         return
     record("step_stats", step=step, stats=stats)
     metrics.counter("rsdl_step_stats_folded_total",
                     "train steps whose own counters reached the host").inc()
+    for row in stats.get("ssm_scan", ()):
+        layer = row.get("layer", "")
+        metrics.gauge("rsdl_ssm_end_decay_mean",
+                      "share of the state a chunk of the state-space scan "
+                      "starts from that reaches its end, mean over rows, "
+                      "chunks and heads, last folded step",
+                      layer=layer).set(row["end_decay_mean"])
+        metrics.gauge("rsdl_ssm_carry_abs_max",
+                      "largest value of the state handed from one chunk of "
+                      "the state-space scan to the next, last folded step",
+                      layer=layer).set(row["carry_abs_max"])
     walks = stats.get("moe_walk")
     if not walks:
         return

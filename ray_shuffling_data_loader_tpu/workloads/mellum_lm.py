@@ -1,6 +1,5 @@
 """Causal-LM workload: packed pre-tokenized rows -> next-token training of
-the sparse-expert decoder (``models/mellum.py``), at either of its
-configurations.
+the decoder (``models/mellum.py``), at any of its configurations.
 
 Rows are fixed-length packed token sequences stored as
 ``FixedSizeList<int32>`` columns, 32 KB a row at 8,192 tokens: the shuffle
@@ -9,9 +8,10 @@ list columns) and ``JaxShufflingDataset`` delivers ``(batch, seq_len)``
 int32 arrays. The next-token targets are the row itself shifted by one,
 made on the device inside the loss: nothing but the rows travels.
 
-The entry point trains one chip's share of an expert-parallel deployment:
-the chip holds ``experts_held`` of the router's experts and a slice of the
-vocabulary, and the ids are drawn from the slice.
+The entry point trains one chip's share of a deployment: of an
+expert-parallel one the chip holds ``experts_held`` of the router's experts,
+of the hybrid state-space model one pipeline stage; either way a slice of
+the vocabulary, and the ids are drawn from the slice.
 """
 
 from __future__ import annotations
@@ -50,15 +50,16 @@ if __name__ == "__main__":
     parser.add_argument("--num-epochs", type=int, default=2)
     parser.add_argument("--batch-size", type=int, default=4)
     parser.add_argument("--seq-len", type=int, default=64)
-    parser.add_argument("--model", choices=("mellum", "laguna"),
+    parser.add_argument("--model", choices=("mellum", "laguna", "granite"),
                         default="mellum",
-                        help="the decoder's configuration: Mellum2-12B-A2.5B "
-                        "or Laguna-XS.2 (its step fills the chip at "
-                        "--batch-size 2)")
+                        help="the decoder's configuration: Mellum2-12B-A2.5B, "
+                        "Laguna-XS.2 (its step fills the chip at "
+                        "--batch-size 2) or granite-4.0-h-micro "
+                        "(--batch-size 1; --seq-len in whole chunks of 8)")
     parser.add_argument("--full", action="store_true",
                         help="the published widths (models.mellum."
-                        "mellum2_ep4_share / laguna_xs2_ep8_share) at "
-                        "8,192-token rows")
+                        "mellum2_ep4_share / laguna_xs2_ep8_share / "
+                        "granite4_h_micro_period) at 8,192-token rows")
     args = parser.parse_args()
 
     import jax
@@ -72,7 +73,9 @@ if __name__ == "__main__":
 
     tiny, full = {"mellum": (mellum.mellum_tiny, mellum.mellum2_ep4_share),
                   "laguna": (mellum.laguna_tiny,
-                             mellum.laguna_xs2_ep8_share)}[args.model]
+                             mellum.laguna_xs2_ep8_share),
+                  "granite": (mellum.granite_tiny,
+                              mellum.granite4_h_micro_period)}[args.model]
     cfg = full() if args.full else tiny()
     seq_len = 8192 if args.full else args.seq_len
     with tempfile.TemporaryDirectory() as tmpdir:

@@ -547,3 +547,81 @@ def test_the_decoders_step_names_one_attention_backward_a_layer(
     for names, read in ((columns, False), (kept, True)):
         assert any(re.search(re.escape(name) + r"\b", later)
                    for name in names) == read, names
+
+
+def test_the_hybrid_decoders_attention_layer_compiles_for_the_chip(
+        four_chips):
+    """``granite_train_8k``'s one attention layer through the TPU's own
+    compiler, a row of 8,192 tokens: 32 query heads over 8 key/value
+    heads of 64 under the configuration's scale (1/64). Heads of 64 are
+    not whole lanes, so the blocked family takes them head-major
+    (``_reads_in_place``): still two Mosaic kernels, a forward and one
+    backward, and no (S, S) array."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    cfg = mellum.granite4_h_micro_period()
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.attention_multiplier) == (32, 8, 64, 1 / 64)
+    assert not fa._reads_in_place(cfg.head_dim, False)
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    q, kv = (jax.ShapeDtypeStruct((1, 8192, n * cfg.head_dim),
+                                  jnp.bfloat16, sharding=one_chip)
+             for n in (cfg.num_heads, cfg.num_kv_heads))
+
+    def both(q, k, v, do):
+        args = (cfg.num_heads, cfg.num_kv_heads, True, None)
+        out, lse = fa.grouped_forward(
+            q, k, v, *args, *mellum._blocks(None, False),
+            scale=cfg.attention_multiplier)
+        return fa.grouped_backward(
+            q, k, v, out, lse, do, *args, *mellum._blocks(None, True),
+            scale=cfg.attention_multiplier)
+
+    hlo = jax.jit(both).lower(q, kv, kv, q).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(r"\[\d+,\d+(,\d+)*,8192,8192\]", hlo)
+    assert fa.grouped_backward_kind(q, kv, cfg.num_heads) == "fused"
+
+
+def test_the_state_space_scan_compiles_for_the_chip_in_chunks(four_chips):
+    """A Mamba layer's scan of ``granite_train_8k`` (one row of 8,192
+    positions, 64 heads of 64, a state of 128, chunks of 256), forward and
+    backward, through the TPU's own compiler: XLA's products over chunks,
+    no Mosaic call, nothing of length S x S, the largest array every
+    head's chunk-by-chunk matrix (32 x 64 x 256 x 256), and temporaries
+    that leave the step its room."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import ssd
+    cfg = mellum.granite4_h_micro_period()
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    s, h = 8192, cfg.mamba_heads
+    x = shape((1, s, h, cfg.mamba_head_dim), jnp.bfloat16)
+    bc = shape((1, s, cfg.mamba_state), jnp.bfloat16)
+    head = shape((h,), jnp.float32)
+
+    def both(x, dt, a_log, b, c, d, dy):
+        (y, crossed), vjp = jax.vjp(
+            lambda *operands: ssd.ssd_counted(*operands, cfg.mamba_chunk),
+            x, dt, a_log, b, c, d)
+        return crossed, vjp((dy, jnp.zeros_like(crossed)))
+
+    compiled = jax.jit(both).lower(x, shape((1, s, h), jnp.float32), head,
+                                   bc, bc, head, x).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    assert not re.search(r"\[(\d+,)*8192,8192\]", hlo)
+    # (XLA folds the row's axis of 1 and may fold chunks and heads)
+    assert re.search(r"\[(1,)?(32,64|2048),256,256\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
