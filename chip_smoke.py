@@ -90,6 +90,9 @@ class SmokeSize:
     # its expert layer: (tokens, hidden, width, experts, held, top_k, tile,
     # what a token's weights sum to)
     moe_shapes: Tuple[Tuple[int, int, int, int, int, int, int, float], ...]
+    # its state-space scan: (rows, positions, heads, head width, state,
+    # chunk)
+    ssd_shapes: Tuple[Tuple[int, int, int, int, int, int], ...]
     epochs: int = 2
 
 
@@ -111,7 +114,9 @@ def full_size() -> SmokeSize:
             (48, 8, None, True, (2048,)),
             (64, 8, 512, True, (2048,))),
         moe_shapes=((8192, 2304, 896, 64, 16, 8, 1152, 1.0),
-                    (16384, 2048, 512, 256, 32, 8, 640, 2.5)))
+                    (16384, 2048, 512, 256, 32, 8, 640, 2.5)),
+        # granite_train_8k's nine Mamba layers
+        ssd_shapes=((1, 8192, 64, 64, 128, 256),))
 
 
 def tiny_size() -> SmokeSize:
@@ -128,7 +133,8 @@ def tiny_size() -> SmokeSize:
         masked_attention_dim=16,
         masked_attention=((4, 1, 24, False, (40,)), (6, 2, 8, True, (40,))),
         moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),
-                    (48, 256, 8, 8, 2, 2, 8, 1.0)))     # rows of whole lanes
+                    (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
+        ssd_shapes=((1, 256, 2, 64, 128, 128),))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -261,6 +267,8 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
     _check_partial_rotary(size.masked_attention_dim)
     for shape in size.moe_shapes:
         _check_moe(shape, interpret)
+    for shape in size.ssd_shapes:
+        _check_ssd(shape, interpret)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
@@ -491,6 +499,87 @@ def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
           f"{1e6 * fetch_s:.1f} us), a round's combine "
           f"{1e9 * combine_s / max(live, 1):.1f} ns a live row ({live} of "
           f"{tokens * top_k} picks, {1e3 * combine_s:.3f} ms)")
+
+
+def _check_ssd(shape: Tuple[int, ...], interpret: bool,
+               tol: float = 2e-2) -> None:
+    """The state-space scan's kernels (a head's ``L o (C B^T)`` tile in
+    VMEM) against XLA's einsums over the chunks, bf16 operands, ``dt`` and
+    ``A`` as the decoder's init draws them: ``y`` and the six gradients, as
+    shares of each one's largest magnitude. On the chip the scan must take
+    the kernels of its own accord, and both paths are timed, a layer
+    forward and forward + backward."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.ops import ssd
+
+    rows, seq, heads, width, state, chunk = shape
+    keys = jax.random.split(jax.random.key(13), 7)
+    # x as the mixer has it, (B, S, H x P): the scan's own layout
+    x = jax.random.normal(keys[0], (rows, seq, heads * width), jnp.bfloat16)
+    b, c = (jax.random.normal(k, (rows, seq, state), jnp.bfloat16)
+            for k in keys[1:3])
+    d = 1.0 + 0.1 * jax.random.normal(keys[3], (heads,))
+    dt = jnp.exp(jax.random.uniform(keys[4], (rows, seq, heads),
+                                    minval=jnp.log(0.001),
+                                    maxval=jnp.log(0.1)))
+    a_log = jnp.log(jax.random.uniform(keys[5], (heads,), minval=1.0,
+                                       maxval=16.0))
+    mix = jax.random.normal(keys[6], x.shape)
+    operands = (x, dt, a_log, b, c, d)
+
+    def scan(in_vmem: bool):
+        return lambda *a: ssd._ssd(*a, chunk, in_vmem)[0]
+
+    def with_grads(in_vmem: bool):
+        def loss(*a):
+            y = scan(in_vmem)(*a)
+            return jnp.sum(y.astype(jnp.float32) * mix), y
+
+        def all_of(*a):
+            grads, y = jax.grad(loss, range(6), has_aux=True)(*a)
+            return (y, *grads)
+
+        return jax.jit(all_of)
+
+    if not interpret:   # every shape the chip checks is a cell's
+        _check(ssd.scans_in_vmem(chunk, heads, width, state, x.dtype)
+               and ssd.passes_in_vmem(heads, width, state)
+               and _mosaic_calls(jax.jit(scan(True)), *operands) == 3
+               and _mosaic_calls(with_grads(True), *operands) == 6,
+               f"the scan at {heads} heads of {width}, state {state}, "
+               f"chunks of {chunk} does not run in VMEM (the chunks' end "
+               "states, the carry across them and their outputs, and the "
+               "same backwards: six Mosaic kernels)")
+    errs = [float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                  - w.astype(jnp.float32)))
+                  / jnp.max(jnp.abs(w.astype(jnp.float32))))
+            for g, w in zip(with_grads(True)(*operands),
+                            with_grads(False)(*operands))]
+    _info(f"kernels: state-space scan {rows} x {seq} positions, {heads} "
+          f"heads of {width}, state {state}, chunks of {chunk}: "
+          "max|vmem-einsums| / max|einsums| y, d x, d dt, d a_log, d b, "
+          "d c, d d " + ", ".join(f"{e:.3e}" for e in errs)
+          + f" (tol {tol:.0e})")
+    _check(max(errs) <= tol, "the scan's kernels differ from XLA's "
+           f"einsums: {errs} > {tol}")
+    if interpret:       # a time is the chip's to give
+        return
+
+    def ms(jitted, calls: int = 10) -> float:
+        jax.block_until_ready(jitted(*operands))
+        start = timeit.default_timer()
+        for _ in range(calls):
+            out = jitted(*operands)
+        jax.block_until_ready(out)
+        return 1e3 * (timeit.default_timer() - start) / calls
+
+    _info("kernels: state-space scan, ms a layer forward / forward + "
+          "backward: in VMEM "
+          f"{ms(jax.jit(scan(True))):.3f} / {ms(with_grads(True)):.3f}, "
+          f"XLA's einsums {ms(jax.jit(scan(False))):.3f} / "
+          f"{ms(with_grads(False)):.3f}")
 
 
 # -- loader -> device feed -> train step -------------------------------------

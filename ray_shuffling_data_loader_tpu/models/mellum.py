@@ -21,10 +21,12 @@ a head's dimensions; with ``rotary`` off (``position_embedding_type:
 Mamba-2's: one projection to ``z | x B C | dt``, a depthwise causal
 convolution and ``silu`` over ``x B C``, the state-space scan
 (``ops/ssd.py``: ``mamba_heads`` heads of ``mamba_head_dim``, a state of
-``mamba_state``, B and C in one group), an RMSNorm gated by ``silu(z)`` and
-the projection back. Four scalars, all 1 unless a configuration says
-otherwise: ``embedding_multiplier`` on the embedding,
-``residual_multiplier`` on each half's output before it is added,
+``mamba_state``, B and C in one group, in chunks of ``mamba_chunk``: on the
+chip, at shapes of whole lanes, Pallas kernels that keep a chunk's
+head-by-head tiles in VMEM; elsewhere XLA's einsums over the chunks), an
+RMSNorm gated by ``silu(z)`` and the projection back. Four scalars, all 1
+unless a configuration says otherwise: ``embedding_multiplier`` on the
+embedding, ``residual_multiplier`` on each half's output before it is added,
 ``attention_multiplier`` as the softmax's scale in place of 1 / sqrt(head
 dimension), ``logits_scaling`` dividing the logits. After the last layer
 RMSNorm and the head, untied or (``tie_embeddings``) the embedding's own
@@ -721,14 +723,23 @@ def _mamba_half(config: DecoderConfig, layer: int, x, lp):
     the step's ``ssm_scan`` of this layer."""
     b, s, _ = x.shape
     width, state = config.mamba_width, config.mamba_state
+    in_vmem = ssd.scans_in_vmem(
+        config.mamba_chunk, config.mamba_heads, config.mamba_head_dim, state,
+        x.dtype)
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_lm_ssm_total",
         "Decoder layers' state-space mixers traced, by what computes the "
-        "scan: XLA's products over chunks", kind="chunked_xla").inc()
+        "scan: Pallas kernels that keep a head's chunk-by-chunk tile in "
+        "VMEM, or XLA's products over chunks",
+        kind="chunked_vmem" if in_vmem else "chunked_xla").inc()
     rt_metrics.gauge("rsdl_lm_ssm_chunk",
                      "Positions in a chunk of the state-space scan, last "
                      "layer traced").set(config.mamba_chunk)
+    rt_metrics.gauge("rsdl_lm_ssm_in_vmem",
+                     "Whether the last state-space scan traced keeps its "
+                     "chunk-by-chunk tiles in VMEM (1) or sends them "
+                     "through HBM (0)").set(int(in_vmem))
     n = _rms_norm(x, lp["mamba_norm"], config.rms_norm_eps)
     z, xbc, dt = jnp.split(_project(n, lp["in_proj"]),
                            [width, 2 * width + 2 * state], axis=-1)

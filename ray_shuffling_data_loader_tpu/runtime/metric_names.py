@@ -67,6 +67,7 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    set when a layer is traced; kind = what computes the scan) --
     "rsdl_lm_ssm_total": ("counter", ("kind",)),
     "rsdl_lm_ssm_chunk": ("gauge", ()),
+    "rsdl_lm_ssm_in_vmem": ("gauge", ()),
     # -- the train step's own counters (utils/tracing.step_stat, folded by
     #    runtime/telemetry.step_stats_folded once a step's values have
     #    reached the host: counted when the step RAN, unlike the block

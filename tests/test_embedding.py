@@ -587,13 +587,20 @@ def test_the_hybrid_decoders_attention_layer_compiles_for_the_chip(
     assert fa.grouped_backward_kind(q, kv, cfg.num_heads) == "fused"
 
 
-def test_the_state_space_scan_compiles_for_the_chip_in_chunks(four_chips):
+@pytest.mark.parametrize("in_vmem", [False, True],
+                         ids=["einsums", "in_vmem"])
+def test_the_state_space_scan_compiles_for_the_chip_in_chunks(
+        four_chips, in_vmem, monkeypatch):
     """A Mamba layer's scan of ``granite_train_8k`` (one row of 8,192
     positions, 64 heads of 64, a state of 128, chunks of 256), forward and
-    backward, through the TPU's own compiler: XLA's products over chunks,
-    no Mosaic call, nothing of length S x S, the largest array every
-    head's chunk-by-chunk matrix (32 x 64 x 256 x 256), and temporaries
-    that leave the step its room."""
+    backward, through the TPU's own compiler, nothing of length S x S
+    either way. Where the code believes itself on the chip: six Mosaic
+    calls (the chunks' end states, the carry across them and their
+    outputs, and the same backwards) and no array of every head's
+    chunk-by-chunk matrix. Off it
+    (the fallback, and the kernels' oracle): XLA's products over chunks,
+    no Mosaic call, the largest array that matrix (32 x 64 x 256 x 256).
+    Temporaries that leave the step its room."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -602,6 +609,8 @@ def test_the_state_space_scan_compiles_for_the_chip_in_chunks(four_chips):
     from ray_shuffling_data_loader_tpu.ops import ssd
     cfg = mellum.granite4_h_micro_period()
     one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    if in_vmem:
+        monkeypatch.setattr(ssd, "on_tpu", lambda: True)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -615,13 +624,20 @@ def test_the_state_space_scan_compiles_for_the_chip_in_chunks(four_chips):
         (y, crossed), vjp = jax.vjp(
             lambda *operands: ssd.ssd_counted(*operands, cfg.mamba_chunk),
             x, dt, a_log, b, c, d)
-        return crossed, vjp((dy, jnp.zeros_like(crossed)))
+        return y, crossed, vjp((dy, jnp.zeros_like(crossed)))
 
     compiled = jax.jit(both).lower(x, shape((1, s, h), jnp.float32), head,
                                    bc, bc, head, x).compile()
     hlo = compiled.as_text()
-    assert "tpu_custom_call" not in hlo
     assert not re.search(r"\[(\d+,)*8192,8192\]", hlo)
     # (XLA folds the row's axis of 1 and may fold chunks and heads)
-    assert re.search(r"\[(1,)?(32,64|2048),256,256\]", hlo)
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    every_heads = re.search(r"\[(1,)?(32,64|2048),256,256\]", hlo)
+    if in_vmem:
+        assert hlo.count("tpu_custom_call") == 6
+        assert "while" not in hlo
+        assert not every_heads
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    else:
+        assert "tpu_custom_call" not in hlo
+        assert every_heads
+        assert compiled.memory_analysis().temp_size_in_bytes < 4e9

@@ -261,6 +261,8 @@ def test_the_step_reports_every_mamba_layers_scan(empty_ring):
     traced = metrics.get("rsdl_lm_ssm_total", {"kind": "chunked_xla"})
     assert traced is not None and traced.value >= 3
     assert metrics.get("rsdl_lm_ssm_chunk").value == cfg.mamba_chunk
+    # the CPU, and chunks of 8: XLA's einsums
+    assert metrics.get("rsdl_lm_ssm_in_vmem").value == 0
 
 
 def test_the_scans_statistics_fold_without_waiting_for_the_device(
@@ -285,7 +287,8 @@ def test_the_scans_statistics_fold_without_waiting_for_the_device(
     for name, entry in {"rsdl_ssm_end_decay_mean": ("gauge", ("layer",)),
                         "rsdl_ssm_carry_abs_max": ("gauge", ("layer",)),
                         "rsdl_lm_ssm_total": ("counter", ("kind",)),
-                        "rsdl_lm_ssm_chunk": ("gauge", ())}.items():
+                        "rsdl_lm_ssm_chunk": ("gauge", ()),
+                        "rsdl_lm_ssm_in_vmem": ("gauge", ())}.items():
         assert metric_names.METRIC_NAMES[name] == entry
 
 

@@ -1,8 +1,10 @@
 """The chunked state-space scan (ops/ssd.py) against the recurrence it
 computes, written a position at a time, in float32 on the CPU: output and
-every gradient, at several chunk lengths; what the carry between chunks is
-worth at the benchmark configuration's init and at the published one; the
-convolution and the gated norm beside it."""
+every gradient, at several chunk lengths, by XLA's einsums and (shapes of
+whole lanes, interpreted) by the kernels that keep a chunk's tiles in
+VMEM; what the carry between chunks is worth at the benchmark
+configuration's init and at the published one; which shapes the kernels
+take; the convolution and the gated norm beside it."""
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,16 @@ from ray_shuffling_data_loader_tpu.ops import ssd
 
 _B, _S, _H, _P, _N = 2, 32, 4, 8, 16
 _NAMES = ("x", "dt", "a_log", "b", "c", "d")
+#: The smallest shape the kernels take, (B, S, H, P, N): heads that fill
+#: whole lanes, a state of whole lanes; chunks of 128, so two are crossed.
+_LANE_ALIGNED = (1, 256, 4, 64, 128)
+_EINSUMS, _IN_VMEM = "einsums", "in_vmem"
+
+
+@pytest.fixture
+def in_vmem(monkeypatch):
+    """The kernels engaged off the chip (interpreted there)."""
+    monkeypatch.setattr(ssd, "scans_in_vmem", lambda *shape: True)
 
 
 def _recurrence(x, dt, a_log, b, c, d):
@@ -33,27 +45,36 @@ def _recurrence(x, dt, a_log, b, c, d):
     return jax.vmap(row)(x, dt, b, c)
 
 
-def _operands(init: str, seed: int = 0, seq: int = _S):
-    """Random operands; ``dt`` and ``A`` as the configuration's ``assumed``
-    init draws them (``dt`` log-uniform in [0.001, 0.1], ``A`` uniform in
-    [1, 16]) or as the published model's (``dt_bias`` 1, ``A`` from 1 to 64
-    over its 64 heads, 16 a head: four heads here)."""
+def _operands(init: str, seed: int = 0, seq: int = _S,
+              shape=(_B, None, _H, _P, _N)):
+    """Random operands of ``shape`` (B, S, H, P, N; ``seq`` where S is
+    None); ``dt`` and ``A`` as the configuration's ``assumed`` init draws
+    them (``dt`` log-uniform in [0.001, 0.1], ``A`` uniform in [1, 16]) or
+    as the published model's (``dt_bias`` 1, ``A`` from 1 to 64 over its 64
+    heads, 16 a head: four heads here)."""
+    batch, seq, heads, width, state = (seq if n is None else n for n in shape)
     keys = jax.random.split(jax.random.key(seed), 6)
-    x = jax.random.normal(keys[0], (_B, seq, _H, _P))
-    b = jax.random.normal(keys[1], (_B, seq, _N))
-    c = jax.random.normal(keys[2], (_B, seq, _N))
-    d = 1.0 + 0.1 * jax.random.normal(keys[3], (_H,))
+    x = jax.random.normal(keys[0], (batch, seq, heads, width))
+    b = jax.random.normal(keys[1], (batch, seq, state))
+    c = jax.random.normal(keys[2], (batch, seq, state))
+    d = 1.0 + 0.1 * jax.random.normal(keys[3], (heads,))
     if init == "assumed":
         dt = jnp.exp(jax.random.uniform(
-            keys[4], (_B, seq, _H), minval=jnp.log(0.001),
+            keys[4], (batch, seq, heads), minval=jnp.log(0.001),
             maxval=jnp.log(0.1)))
-        a_log = jnp.log(jax.random.uniform(keys[5], (_H,), minval=1.0,
+        a_log = jnp.log(jax.random.uniform(keys[5], (heads,), minval=1.0,
                                            maxval=16.0))
     else:
         dt = jax.nn.softplus(1.0 + 0.1 * jax.random.normal(
-            keys[4], (_B, seq, _H)))
-        a_log = jnp.log(jnp.linspace(1.0, 16.0 * _H, _H))
+            keys[4], (batch, seq, heads)))
+        a_log = jnp.log(jnp.linspace(1.0, 16.0 * heads, heads))
     return x, dt, a_log, b, c, d
+
+
+def _as(dtype, operands):
+    """``x``, ``b`` and ``c`` in the compute ``dtype``; the rest float32."""
+    x, dt, a_log, b, c, d = operands
+    return x.astype(dtype), dt, a_log, b.astype(dtype), c.astype(dtype), d
 
 
 def _weighted(fn, weights):
@@ -62,23 +83,69 @@ def _weighted(fn, weights):
     return lambda *operands: jnp.sum(fn(*operands) * weights)
 
 
-@pytest.mark.parametrize("chunk", [4, 8, _S])
-def test_output_and_every_gradient_match_the_recurrence(chunk):
-    operands = _operands("assumed")
+def _grads(fn, weights, operands):
+    return jax.grad(_weighted(fn, weights), argnums=range(6))(*operands)
+
+
+def _assert_close(got, want, rtol, atol, name):
+    """``atol`` as a share of ``want``'s largest magnitude (at least 1)."""
+    want = np.asarray(want, np.float32)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want, rtol=rtol,
+        atol=atol * max(float(np.max(np.abs(want))), 1.0), err_msg=name)
+
+
+@pytest.mark.parametrize("chunk,path", [
+    (4, _EINSUMS), (8, _EINSUMS), (_S, _EINSUMS), (128, _IN_VMEM),
+    (256, _IN_VMEM)])
+def test_output_and_every_gradient_match_the_recurrence(chunk, path,
+                                                        request):
+    if path == _IN_VMEM:
+        request.getfixturevalue("in_vmem")
+        operands = _operands("assumed", shape=_LANE_ALIGNED)
+    else:
+        operands = _operands("assumed")
     want = _recurrence(*operands)
     got, stats = ssd.ssd_counted(*operands, chunk)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     assert stats.shape == (2,) and 0.0 < float(stats[0]) <= 1.0
     weights = jax.random.normal(jax.random.key(9), want.shape)
-    want_grads = jax.grad(_weighted(_recurrence, weights),
-                          argnums=range(6))(*operands)
-    got_grads = jax.grad(
-        _weighted(lambda *ops: ssd.ssd(*ops, chunk), weights),
-        argnums=range(6))(*operands)
+    want_grads = _grads(_recurrence, weights, operands)
+    got_grads = _grads(lambda *ops: ssd.ssd(*ops, chunk), weights, operands)
     for name, got_g, want_g in zip(_NAMES, got_grads, want_grads):
-        scale = float(jnp.max(jnp.abs(want_g)))
-        np.testing.assert_allclose(got_g, want_g, rtol=2e-4,
-                                   atol=2e-5 * max(scale, 1.0), err_msg=name)
+        _assert_close(got_g, want_g, 2e-4, 2e-5, name)
+
+
+@pytest.mark.parametrize("dtype,chunk,shape", [
+    (jnp.float32, 128, _LANE_ALIGNED), (jnp.bfloat16, 128, _LANE_ALIGNED),
+    (jnp.bfloat16, 256, _LANE_ALIGNED),
+    # four heads of 32 side by side in a tile, and a head a tile
+    (jnp.float32, 128, (1, 256, 16, 32, 128)),
+    (jnp.float32, 128, (1, 256, 8, 128, 128))],
+    ids=["float32-128", "bfloat16-128", "bfloat16-256", "heads-of-32",
+         "heads-of-128"])
+def test_the_kernels_match_the_einsums(dtype, chunk, shape, monkeypatch):
+    """Output, both statistics and all six gradients, path against path
+    on the same operands: the kernels round where the einsums round (bf16
+    operands into every product, float32 ``cum``, ``exp``, accumulators
+    and carry), so the two differ by the order of their float32 sums
+    alone; bf16 rounds ``d b`` and ``d c`` after a sum over the heads
+    that the kernels make a head at a time."""
+    operands = _as(dtype, _operands("assumed", seed=5, shape=shape))
+    weights = jax.random.normal(jax.random.key(9), operands[0].shape)
+    scan = lambda *ops: ssd.ssd(*ops, chunk).astype(jnp.float32)
+    want, want_stats = ssd.ssd_counted(*operands, chunk)
+    want_grads = _grads(scan, weights, operands)
+    monkeypatch.setattr(ssd, "scans_in_vmem", lambda *shape: True)
+    got, got_stats = ssd.ssd_counted(*operands, chunk)
+    got_grads = _grads(scan, weights, operands)
+    assert got.dtype == want.dtype == dtype
+    exact = dtype == jnp.float32
+    _assert_close(got, want, 1e-5, 1e-6 if exact else 8e-3, "y")
+    np.testing.assert_allclose(got_stats, want_stats, rtol=1e-5)
+    for name, got_g, want_g in zip(_NAMES, got_grads, want_grads):
+        assert got_g.dtype == want_g.dtype, name
+        _assert_close(got_g, want_g, 1e-4, 1e-5 if exact else 8e-3, name)
 
 
 def test_the_chunk_length_does_not_change_the_answer():
@@ -100,13 +167,14 @@ def _without_carry(monkeypatch):
     # is traced again
     plain = ssd._ssd_fwd.__wrapped__
     monkeypatch.setattr(ssd, "_ssd_fwd", jax.jit(
-        lambda *operands: plain(*operands), static_argnums=(6,)))
+        lambda *operands: plain(*operands), static_argnums=(6, 7)))
 
 
-@pytest.mark.parametrize("init,caught", [("assumed", True),
-                                         ("published", False)])
+@pytest.mark.parametrize("init,caught,path", [
+    ("assumed", True, _EINSUMS), ("published", False, _EINSUMS),
+    ("assumed", True, _IN_VMEM)])
 def test_a_scan_without_its_carry_is_caught_at_the_assumed_init(
-        init, caught, monkeypatch):
+        init, caught, path, monkeypatch, request):
     """The control: the state each chunk starts from zeroed. At the
     configuration's init (slow heads: ``dt A`` from 0.001 a position) the
     output is off by a tenth of its norm, at nearly half of its values; at
@@ -115,10 +183,16 @@ def test_a_scan_without_its_carry_is_caught_at_the_assumed_init(
     positions of the next (1.4 % of the output's norm here, in under 2 % of
     its values, all of the one head with A = 1) and a comparison of norms
     has little to see the fault by: why the configuration does not use
-    it."""
-    operands = _operands(init, seed=2, seq=256)
+    it. The kernels call the same ``_carries`` between their two halves:
+    the seam is the einsums'."""
+    if path == _IN_VMEM:
+        request.getfixturevalue("in_vmem")
+        # four chunks: three of them start from a state
+        operands, chunk = _operands(
+            init, seed=2, seq=512, shape=(1, None) + _LANE_ALIGNED[2:]), 128
+    else:
+        operands, chunk = _operands(init, seed=2, seq=256), 64
     want = _recurrence(*operands)
-    chunk = 64
     np.testing.assert_allclose(ssd.ssd(*operands, chunk), want, rtol=1e-4,
                                atol=1e-4)
     _without_carry(monkeypatch)
@@ -129,6 +203,56 @@ def test_a_scan_without_its_carry_is_caught_at_the_assumed_init(
         assert off > 0.05 and values_off > 0.3, (off, values_off)
     else:
         assert off < 0.02 and values_off < 0.02, (off, values_off)
+
+
+def test_which_shapes_the_kernels_take(monkeypatch):
+    """In VMEM on the chip where a chunk and the state are whole lanes,
+    the heads block into whole lanes and the operands are bf16 or
+    float32; XLA's einsums otherwise, and everywhere off the chip."""
+    cell = (256, 64, 64, 128, jnp.bfloat16)    # granite_train_8k's layers
+    assert not ssd.scans_in_vmem(*cell)        # the CPU
+    assert ssd.vmem_takes(*cell)
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    assert ssd.scans_in_vmem(*cell)
+    assert ssd.scans_in_vmem(128, 2, 64, 128, jnp.float32)
+    assert ssd.scans_in_vmem(128, 4, 128, 256, jnp.bfloat16)
+    assert not ssd.scans_in_vmem(8, 64, 64, 128, jnp.bfloat16)  # granite_tiny
+    assert not ssd.scans_in_vmem(192, 64, 64, 128, jnp.bfloat16)
+    assert not ssd.scans_in_vmem(256, 3, 64, 128, jnp.bfloat16)    # 192 lanes
+    assert not ssd.scans_in_vmem(256, 4, 8, 128, jnp.bfloat16)     # 32 lanes
+    assert not ssd.scans_in_vmem(256, 4, 256, 128, jnp.bfloat16)   # two tiles
+    assert not ssd.scans_in_vmem(256, 64, 64, 16, jnp.bfloat16)    # the state
+    assert not ssd.scans_in_vmem(256, 64, 64, 128, jnp.float16)
+    # a grid step takes 512 lanes of heads where it can, in whole sublanes
+    # of their rows and whole tiles of heads side by side
+    assert [ssd._head_block(*heads) for heads in (
+        (64, 64), (4, 64), (2, 64), (32, 128), (12, 64), (3, 64),
+        (32, 32), (64, 8))] == [8, 4, 2, 8, 12, 0, 16, 32]
+
+
+@pytest.mark.parametrize("reverse", [False, True],
+                         ids=["carries", "carried_back"])
+def test_the_carrys_kernel_is_the_loop(reverse, monkeypatch):
+    """What each chunk starts from (or hands back), by the kernel that
+    holds it in VMEM across a sequential chunk axis, interpreted: bit for
+    bit the loop's, and the seam's two functions take it on the chip."""
+    keys = jax.random.split(jax.random.key(7), 2)
+    given = jax.random.normal(keys[0], (2, 5, 4, 16, 128))
+    decay = jax.random.uniform(keys[1], (2, 5, 4))
+    want = ssd._passed(given, decay, reverse)
+    np.testing.assert_array_equal(
+        ssd._passed_in_vmem(given, decay, reverse, True), want)
+    assert float(jnp.max(jnp.abs(want[:, 0 if not reverse else -1]))) == 0.0
+    seam = ssd._carried_back if reverse else ssd._carries
+    np.testing.assert_array_equal(seam(given, decay), want)
+    assert not ssd.passes_in_vmem(64, 64, 128)      # the CPU
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    assert ssd.passes_in_vmem(64, 64, 128) and ssd.passes_in_vmem(4, 16, 128)
+    assert not ssd.passes_in_vmem(4, 8, 16)         # granite_tiny's state
+    assert not ssd.passes_in_vmem(4, 12, 128)
+    # 2 MB of state a grid step: all of the cell's heads
+    assert ssd._heads_passed(64, 64, 128) == 64
+    assert ssd._heads_passed(64, 128, 256) == 16
 
 
 def test_a_sequence_of_part_chunks_is_refused():
