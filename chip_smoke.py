@@ -93,6 +93,7 @@ class SmokeSize:
     # its state-space scan: (rows, positions, heads, head width, state,
     # chunk)
     ssd_shapes: Tuple[Tuple[int, int, int, int, int, int], ...]
+    sscan_shapes: Tuple[Tuple[int, int, int, int, int], ...]
     epochs: int = 2
 
 
@@ -116,7 +117,9 @@ def full_size() -> SmokeSize:
         moe_shapes=((8192, 2304, 896, 64, 16, 8, 1152, 1.0),
                     (16384, 2048, 512, 256, 32, 8, 640, 2.5)),
         # granite_train_8k's nine Mamba layers
-        ssd_shapes=((1, 8192, 64, 64, 128, 256),))
+        ssd_shapes=((1, 8192, 64, 64, 128, 256),),
+        # phi4flash_train_8k's two Mamba-1 layers
+        sscan_shapes=((1, 8192, 5120, 16, 64),))
 
 
 def tiny_size() -> SmokeSize:
@@ -134,7 +137,8 @@ def tiny_size() -> SmokeSize:
         masked_attention=((4, 1, 24, False, (40,)), (6, 2, 8, True, (40,))),
         moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),
                     (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
-        ssd_shapes=((1, 256, 2, 64, 128, 128),))
+        ssd_shapes=((1, 256, 2, 64, 128, 128),),
+        sscan_shapes=((1, 32, 1024, 4, 8),))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -269,6 +273,8 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
         _check_moe(shape, interpret)
     for shape in size.ssd_shapes:
         _check_ssd(shape, interpret)
+    for shape in size.sscan_shapes:
+        _check_sscan(shape, interpret)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
@@ -501,14 +507,104 @@ def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
           f"{tokens * top_k} picks, {1e3 * combine_s:.3f} ms)")
 
 
+def _check_scan_paths(what: str, other: str, names: str, operands, mix,
+                      scan, mosaic_calls: Tuple[int, int], takes: bool,
+                      interpret: bool, tol: float,
+                      other_calls: int = 10) -> None:
+    """A scan's kernels (``scan(True)``) against its XLA path
+    (``scan(False)``, ``other``): the output and the six gradients, as
+    shares of each one's largest magnitude. On the chip the scan must take
+    the kernels of its own accord (``takes``, and ``mosaic_calls`` Mosaic
+    kernels forward / forward + backward), and both paths are timed, a
+    layer forward and forward + backward."""
+    import jax
+    import jax.numpy as jnp
+
+    def with_grads(in_vmem: bool):
+        def loss(*a):
+            y = scan(in_vmem)(*a)
+            return jnp.sum(y.astype(jnp.float32) * mix), y
+
+        def all_of(*a):
+            grads, y = jax.grad(loss, range(6), has_aux=True)(*a)
+            return (y, *grads)
+
+        return jax.jit(all_of)
+
+    if not interpret:   # every shape the chip checks is a cell's
+        _check(takes
+               and _mosaic_calls(jax.jit(scan(True)),
+                                 *operands) == mosaic_calls[0]
+               and _mosaic_calls(with_grads(True),
+                                 *operands) == mosaic_calls[1],
+               f"the {what} does not run in VMEM ({mosaic_calls[0]} Mosaic "
+               f"kernels forward, {mosaic_calls[1]} with the backward)")
+    errs = [float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                  - w.astype(jnp.float32)))
+                  / jnp.max(jnp.abs(w.astype(jnp.float32))))
+            for g, w in zip(with_grads(True)(*operands),
+                            with_grads(False)(*operands))]
+    _info(f"kernels: {what}: max|vmem-{other}| / max|{other}| {names} "
+          + ", ".join(f"{e:.3e}" for e in errs) + f" (tol {tol:.0e})")
+    _check(max(errs) <= tol, f"the {what}'s kernels differ from XLA's "
+           f"{other}: {errs} > {tol}")
+    if interpret:       # a time is the chip's to give
+        return
+
+    def ms(jitted, calls: int = 10) -> float:
+        jax.block_until_ready(jitted(*operands))
+        start = timeit.default_timer()
+        for _ in range(calls):
+            out = jitted(*operands)
+        jax.block_until_ready(out)
+        return 1e3 * (timeit.default_timer() - start) / calls
+
+    _info(f"kernels: {what}, ms a layer forward / forward + backward: in "
+          f"VMEM {ms(jax.jit(scan(True))):.3f} / "
+          f"{ms(with_grads(True)):.3f}, XLA's {other} "
+          f"{ms(jax.jit(scan(False)), other_calls):.3f} / "
+          f"{ms(with_grads(False), other_calls):.3f}")
+
+
+def _check_sscan(shape: Tuple[int, ...], interpret: bool,
+                 tol: float = 2e-2) -> None:
+    """Mamba-1's selective scan's kernels (the state in VMEM) against
+    XLA's loops over the chunks and their positions, bf16 ``u``, ``B`` and
+    ``C``, ``dt`` and ``A`` as the decoder's init draws them
+    (:func:`_check_scan_paths`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.ops import selective_scan as sscan
+
+    rows, seq, channels, state, chunk = shape
+    keys = jax.random.split(jax.random.key(17), 6)
+    u = jax.random.normal(keys[0], (rows, seq, channels), jnp.bfloat16)
+    b, c = (jax.random.normal(k, (rows, seq, state), jnp.bfloat16)
+            for k in keys[1:3])
+    d = 1.0 + 0.1 * jax.random.normal(keys[3], (channels,))
+    dt = jnp.exp(jax.random.uniform(keys[4], (rows, seq, channels),
+                                    minval=jnp.log(0.001),
+                                    maxval=jnp.log(0.1)))
+    a_log = jnp.log(jnp.broadcast_to(
+        jnp.arange(1, state + 1, dtype=jnp.float32), (channels, state)))
+    _check_scan_paths(
+        f"selective scan {rows} x {seq} positions, {channels} channels, "
+        f"state {state}, chunks of {chunk}", "loops",
+        "y, d u, d dt, d a_log, d b, d c, d d", (u, dt, a_log, b, c, d),
+        jax.random.normal(keys[5], u.shape),
+        lambda in_vmem: lambda *a: sscan._sscan(*a, chunk, in_vmem)[0],
+        (1, 2), sscan.scans_in_vmem(channels, state, chunk), interpret, tol,
+        other_calls=2)
+
+
 def _check_ssd(shape: Tuple[int, ...], interpret: bool,
                tol: float = 2e-2) -> None:
     """The state-space scan's kernels (a head's ``L o (C B^T)`` tile in
-    VMEM) against XLA's einsums over the chunks, bf16 operands, ``dt`` and
-    ``A`` as the decoder's init draws them: ``y`` and the six gradients, as
-    shares of each one's largest magnitude. On the chip the scan must take
-    the kernels of its own accord, and both paths are timed, a layer
-    forward and forward + backward."""
+    VMEM: the chunks' end states, the carry across them and their outputs,
+    and the same backwards) against XLA's einsums over the chunks, bf16
+    operands, ``dt`` and ``A`` as the decoder's init draws them
+    (:func:`_check_scan_paths`)."""
     import jax
     import jax.numpy as jnp
 
@@ -526,60 +622,14 @@ def _check_ssd(shape: Tuple[int, ...], interpret: bool,
                                     maxval=jnp.log(0.1)))
     a_log = jnp.log(jax.random.uniform(keys[5], (heads,), minval=1.0,
                                        maxval=16.0))
-    mix = jax.random.normal(keys[6], x.shape)
-    operands = (x, dt, a_log, b, c, d)
-
-    def scan(in_vmem: bool):
-        return lambda *a: ssd._ssd(*a, chunk, in_vmem)[0]
-
-    def with_grads(in_vmem: bool):
-        def loss(*a):
-            y = scan(in_vmem)(*a)
-            return jnp.sum(y.astype(jnp.float32) * mix), y
-
-        def all_of(*a):
-            grads, y = jax.grad(loss, range(6), has_aux=True)(*a)
-            return (y, *grads)
-
-        return jax.jit(all_of)
-
-    if not interpret:   # every shape the chip checks is a cell's
-        _check(ssd.scans_in_vmem(chunk, heads, width, state, x.dtype)
-               and ssd.passes_in_vmem(heads, width, state)
-               and _mosaic_calls(jax.jit(scan(True)), *operands) == 3
-               and _mosaic_calls(with_grads(True), *operands) == 6,
-               f"the scan at {heads} heads of {width}, state {state}, "
-               f"chunks of {chunk} does not run in VMEM (the chunks' end "
-               "states, the carry across them and their outputs, and the "
-               "same backwards: six Mosaic kernels)")
-    errs = [float(jnp.max(jnp.abs(g.astype(jnp.float32)
-                                  - w.astype(jnp.float32)))
-                  / jnp.max(jnp.abs(w.astype(jnp.float32))))
-            for g, w in zip(with_grads(True)(*operands),
-                            with_grads(False)(*operands))]
-    _info(f"kernels: state-space scan {rows} x {seq} positions, {heads} "
-          f"heads of {width}, state {state}, chunks of {chunk}: "
-          "max|vmem-einsums| / max|einsums| y, d x, d dt, d a_log, d b, "
-          "d c, d d " + ", ".join(f"{e:.3e}" for e in errs)
-          + f" (tol {tol:.0e})")
-    _check(max(errs) <= tol, "the scan's kernels differ from XLA's "
-           f"einsums: {errs} > {tol}")
-    if interpret:       # a time is the chip's to give
-        return
-
-    def ms(jitted, calls: int = 10) -> float:
-        jax.block_until_ready(jitted(*operands))
-        start = timeit.default_timer()
-        for _ in range(calls):
-            out = jitted(*operands)
-        jax.block_until_ready(out)
-        return 1e3 * (timeit.default_timer() - start) / calls
-
-    _info("kernels: state-space scan, ms a layer forward / forward + "
-          "backward: in VMEM "
-          f"{ms(jax.jit(scan(True))):.3f} / {ms(with_grads(True)):.3f}, "
-          f"XLA's einsums {ms(jax.jit(scan(False))):.3f} / "
-          f"{ms(with_grads(False)):.3f}")
+    _check_scan_paths(
+        f"state-space scan {rows} x {seq} positions, {heads} heads of "
+        f"{width}, state {state}, chunks of {chunk}", "einsums",
+        "y, d x, d dt, d a_log, d b, d c, d d", (x, dt, a_log, b, c, d),
+        jax.random.normal(keys[6], x.shape),
+        lambda in_vmem: lambda *a: ssd._ssd(*a, chunk, in_vmem)[0],
+        (3, 6), ssd.scans_in_vmem(chunk, heads, width, state, x.dtype)
+        and ssd.passes_in_vmem(heads, width, state), interpret, tol)
 
 
 # -- loader -> device feed -> train step -------------------------------------

@@ -1,8 +1,9 @@
-"""A causal decoder whose layers mix window attention, full attention
-and Mamba-2 state-space mixers, with dense or sparse-expert MLPs: one
-chip's share of a language model, by its configuration
-(``mellum2_ep4_share``, ``laguna_xs2_ep8_share``,
-``granite4_h_micro_period``).
+"""A causal decoder whose layers mix window attention, full attention,
+Mamba-2 and Mamba-1 state-space mixers, Gated Memory Units and
+cross-attention over an earlier layer's keys and values, with dense or
+sparse-expert MLPs: one chip's share of a language model, by its
+configuration (``mellum2_ep4_share``, ``laguna_xs2_ep8_share``,
+``granite4_h_micro_period``, ``phi4_mini_flash_junction``).
 
 Per layer, on the residual stream: RMSNorm, grouped-query attention (a
 layer's own count of query heads over ``num_kv_heads`` key/value heads,
@@ -32,6 +33,27 @@ dimension), ``logits_scaling`` dividing the logits. After the last layer
 RMSNorm and the head, untied or (``tie_embeddings``) the embedding's own
 matrix; the loss is the mean next-token negative log-likelihood. Same
 functional API as the other families: ``init``, ``loss_fn``.
+
+Three more ``layer_types`` (SambaY, Ren et al. 2025). A ``mamba1`` layer's
+mixer is Mamba-1's: ``W_in`` to ``u | z``, the convolution and ``silu``
+over ``u``, ``W_x`` to ``r | B | C``, ``dt = softplus(r W_dt + b_dt)``, the
+selective scan (``ops/selective_scan.py``: ``mamba1_width`` channels, a
+state of ``mamba1_state`` a channel, a decay a channel and state), ``y *
+silu(z)`` and ``W_out``; the last one before a ``gmu`` layer also gives
+out its scan's output ``M = y``. A ``gmu`` layer's mixer is a Gated
+Memory Unit, ``(silu(n W_1) * M) W_2``. A ``cross`` layer's attention has
+a ``W_q`` and a ``W_o`` only: its keys and values are the tensors the
+last ``full_attention`` layer before it made. ``M``, ``K`` and ``V`` leave
+the half that makes them as results and enter the halves that read them
+as arguments (:func:`decode`): kept once, never made again by a reader,
+their cotangents summed by autodiff before the maker's backward runs.
+With ``differential`` every attention layer is differential attention (Ye
+et al. 2024): query heads ``(2i, 2i + 1)`` and key heads ``(2j, 2j + 1)``
+are two softmax maps, value heads ``(2j, 2j + 1)`` side by side their
+values, the second map subtracted under a learned scalar ``lambda``, an
+RMSNorm a head pair, times ``1 - lambda_init`` (``published_indices``
+gives each layer's ``lambda_init``). ``norm`` ``"layer"`` is LayerNorm
+with a bias in place of every RMSNorm on the residual stream.
 
 Under expert parallelism a chip holds ``experts_held = (first, count)`` of
 the router's ``num_experts`` and a slice of the vocabulary: the expert
@@ -72,22 +94,30 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_shuffling_data_loader_tpu.ops import (flash_attention, moe, on_tpu,
-                                               ssd)
+                                               selective_scan, ssd)
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 from ray_shuffling_data_loader_tpu.utils import tracing
 
 IGNORE_ID = -100
 SLIDING, FULL, MAMBA = "sliding_attention", "full_attention", "mamba"
+MAMBA1, GMU, CROSS = "mamba1", "gmu", "cross"
 DENSE, SPARSE = "dense", "sparse"
+RMS_NORM, LAYER_NORM = "rms", "layer"
 
 # The names a device trace shows a layer's projections' (q, k, v, the gate
 # and ``wo``), its attention's, its expert layer's (``ops/moe.py``), its
 # dense MLP's or shared expert's and the head's operations under; a Mamba
 # mixer's two projections are under the first, what lies between them (the
 # convolution, the scan, the gated norm: ``ops/ssd.py``) under ``SSM_SCOPE``.
+# A Mamba-1 mixer's four projections and a Gated Memory Unit's two are under
+# the first too; what lies between a Mamba-1 mixer's (the convolution, the
+# softplus, the selective scan, the gate: ``ops/selective_scan.py``) under
+# ``SSCAN_SCOPE``, a memory unit's ``silu(n W_1) * M`` under ``GMU_SCOPE``.
 PROJ_SCOPE = "rsdl.lm.proj"
 ATTENTION_SCOPE = "rsdl.lm.attention"
 SSM_SCOPE = ssd.SCOPE
+SSCAN_SCOPE = selective_scan.SCOPE
+GMU_SCOPE = "rsdl.lm.gmu"
 MOE_SCOPE = moe.SCOPE
 MLP_SCOPE = "rsdl.lm.mlp"
 HEAD_SCOPE = "rsdl.lm.head"
@@ -148,6 +178,16 @@ class DecoderConfig:
     mamba_state: int = 128
     mamba_conv: int = 4
     mamba_chunk: int = 256
+    # a ``mamba1`` layer's mixer (Mamba-1; ``mamba_conv`` taps, the scan in
+    # chunks of ``mamba_chunk``)
+    mamba1_width: int = 0
+    mamba1_state: int = 16
+    mamba1_dt_rank: int = 0
+    norm: str = RMS_NORM          # LAYER_NORM: LayerNorm with a bias
+    # every attention layer differential; then each layer's index in the
+    # published model, which sets its ``lambda_init``
+    differential: bool = False
+    published_indices: Optional[Tuple[int, ...]] = None
     compute_dtype: Any = jnp.bfloat16
     published_layers: int = 28    # the uncut depth: scales ``init`` only
 
@@ -261,6 +301,47 @@ def granite_tiny() -> DecoderConfig:
            "attention_multiplier": 0.0625})
 
 
+_PHI4FLASH_JUNCTION = dict(
+    layer_types=(MAMBA1, SLIDING, MAMBA1, FULL, GMU, CROSS),
+    mlp_layer_types=6 * (DENSE,), published_indices=(0, 1, 16, 17, 18, 19),
+    differential=True, norm=LAYER_NORM, rotary=False, tie_embeddings=True,
+    rms_norm_eps=1e-5, published_layers=32)
+
+
+def phi4_mini_flash_junction() -> DecoderConfig:
+    """Phi-4-mini-flash-reasoning (SambaY) at its published widths, cut to
+    the junction of its two decoders, the published layers 0, 1, 16, 17,
+    18 and 19 of 32: one period of the self-decoder (Mamba-1, window-512
+    differential attention), the Mamba-1 layer that gives out ``M`` and
+    the full attention layer that gives out ``K`` and ``V``, one period
+    of the cross-decoder (a Gated Memory Unit over ``M``, cross-attention
+    over ``K`` and ``V``); 40:20 heads of 64, every MLP the dense SwiGLU
+    of 10,240, LayerNorm, no positions, and an eighth of the 200,064-id
+    vocabulary under the tied embedding."""
+    return DecoderConfig(
+        vocab_size=25_008, hidden_size=2560, num_heads=40, num_kv_heads=20,
+        head_dim=64, sliding_window=512, intermediate_size=10_240,
+        mamba1_width=5120, mamba1_state=16, mamba1_dt_rank=160,
+        mamba_conv=4, mamba_chunk=64, **_PHI4FLASH_JUNCTION)
+
+
+def phi4flash_tiny() -> DecoderConfig:
+    """For tests/CPU smoke runs: the junction's six kinds of layer at 8:4
+    heads of 8, a window of 8 and Mamba-1 mixers of 128 channels with a
+    state of 4 in chunks of 8."""
+    return DecoderConfig(
+        vocab_size=512, hidden_size=64, num_heads=8, num_kv_heads=4,
+        head_dim=8, sliding_window=8, intermediate_size=128,
+        mamba1_width=128, mamba1_state=4, mamba1_dt_rank=4, mamba_conv=4,
+        mamba_chunk=8, **_PHI4FLASH_JUNCTION)
+
+
+def lambda_init(published_index: int) -> float:
+    """A differential attention layer's ``lambda_init`` at its depth in
+    the published model (Ye et al. 2024)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * published_index)
+
+
 def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     """Seeded float32 weights: the embedding N(0, 1), matrices N(0, 0.02),
     the projections that write into the residual stream (``wo``, every
@@ -273,7 +354,10 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     mamba's ``Mamba2``): the step ``dt`` log-uniform in [0.001, 0.1] as
     ``dt_bias`` (its inverse softplus), ``A`` uniform in [1, 16] as
     ``a_log``, ``D`` 1, the convolution's taps and bias uniform in
-    +-1 / sqrt(taps)."""
+    +-1 / sqrt(taps). A Mamba-1 mixer (state-spaces/mamba's ``Mamba``):
+    ``dt`` the same through ``dt_bias``, ``A[c, n] = n + 1``, ``D`` 1. A
+    differential layer's four ``lambda`` vectors N(0, 0.1) and its head
+    pairs' norm scale 1. LayerNorm's biases 0."""
     h, f = config.hidden_size, config.expert_width
     kv_width = config.num_kv_heads * config.head_dim
     held = config.experts_held[1]
@@ -307,6 +391,36 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
                 "ssm_norm": jnp.ones((width,), jnp.float32),
                 "out_proj": normal((width, h), residual)}
 
+    def mamba1_mixer():
+        width, state = config.mamba1_width, config.mamba1_state
+        rank, taps = config.mamba1_dt_rank, config.mamba_conv
+        dt = jnp.exp(uniform((width,), math.log(0.001), math.log(0.1)))
+        edge = 1.0 / math.sqrt(taps)
+        return {"mamba_norm": jnp.ones((h,), jnp.float32),
+                "in_proj": normal((h, 2 * width)),
+                "conv_w": uniform((taps, width), -edge, edge),
+                "conv_b": uniform((width,), -edge, edge),
+                "x_proj": normal((width, rank + 2 * state)),
+                "dt_proj": normal((rank, width)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.log(jnp.broadcast_to(
+                    jnp.arange(1, state + 1, dtype=jnp.float32),
+                    (width, state))),
+                "d": jnp.ones((width,), jnp.float32),
+                "out_proj": normal((width, h), residual)}
+
+    def attention(layer_type, q_width):
+        lp = {"attn_norm": jnp.ones((h,), jnp.float32),
+              "wq": normal((h, q_width))}
+        if layer_type != CROSS:
+            lp.update(wk=normal((h, kv_width)), wv=normal((h, kv_width)))
+        lp["wo"] = normal((q_width, h), residual)
+        if config.differential:
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                lp[name] = normal((config.head_dim,), 0.1)
+            lp["subln"] = jnp.ones((2 * config.head_dim,), jnp.float32)
+        return lp
+
     params: Dict[str, Any] = {"final_norm": jnp.ones((h,), jnp.float32)}
     if config.tie_embeddings:
         params["embed"] = normal((config.vocab_size, h))
@@ -316,14 +430,17 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     for layer in range(config.num_layers):
         heads = config.heads(layer)
         q_width = heads * config.head_dim
-        if config.layer_types[layer] == MAMBA:
+        layer_type = config.layer_types[layer]
+        if layer_type == MAMBA:
             lp = mamba_mixer()
+        elif layer_type == MAMBA1:
+            lp = mamba1_mixer()
+        elif layer_type == GMU:
+            lp = {"gmu_norm": jnp.ones((h,), jnp.float32),
+                  "w1": normal((h, config.mamba1_width)),
+                  "w2": normal((config.mamba1_width, h), residual)}
         else:
-            lp = {"attn_norm": jnp.ones((h,), jnp.float32),
-                  "wq": normal((h, q_width)),
-                  "wk": normal((h, kv_width)),
-                  "wv": normal((h, kv_width)),
-                  "wo": normal((q_width, h), residual)}
+            lp = attention(layer_type, q_width)
             if config.attention_gate:
                 lp["wg"] = normal((h, heads))
         if config.mlp_type(layer) == DENSE:
@@ -336,6 +453,11 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
             if config.shared_expert_width:
                 lp.update(swiglu("shared_", (), config.shared_expert_width))
         params[f"layer_{layer}"] = lp
+    if config.norm == LAYER_NORM:
+        for tree in (params, *(params[f"layer_{layer}"]
+                               for layer in range(config.num_layers))):
+            for name in [n for n in tree if n.endswith("_norm")]:
+                tree[f"{name}_bias"] = jnp.zeros((h,), jnp.float32)
     return params
 
 
@@ -430,12 +552,14 @@ def _gated(out, gate, heads: int):
 
 # The attentions are jitted for the scope's sake, as models/bert.py's: inside
 # a program of its own the name reaches the compiled step as written.
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
-                      window: Optional[int], scale: Optional[float] = None):
+                      window: Optional[int], scale: Optional[float] = None,
+                      out_dtype=None):
     """Causal grouped-query attention as XLA has it: float32 softmax over
     materialized (B, H, S, S) scores, times ``scale`` (``None``: over the
-    root of a head's dimensions); the backward is autodiff's."""
+    root of a head's dimensions), the output in ``out_dtype`` (``None``:
+    v's); the backward is autodiff's."""
     with jax.named_scope(ATTENTION_SCOPE):
         b, s, _ = q.shape
         q = q.reshape(b, s, kv_heads, heads // kv_heads, -1)
@@ -448,7 +572,8 @@ def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
         if window is not None:
             seen &= ahead < window
         weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v,
+                         preferred_element_type=out_dtype)
         return _gated(out.reshape(b, s, -1), gate, heads)
 
 
@@ -481,20 +606,22 @@ def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
     return side, side
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_attention(q, k, v, gate, heads: int, kv_heads: int,
-                     window: Optional[int], scale: Optional[float] = None):
+                     window: Optional[int], scale: Optional[float] = None,
+                     out_dtype=None):
     """``_inline_attention``'s result from the blocked Pallas kernels."""
     return _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window,
-                                scale)[0]
+                                scale, out_dtype)[0]
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale,
+                         out_dtype=None):
     with jax.named_scope(ATTENTION_SCOPE):
         out, lse = flash_attention.grouped_forward(
             q, k, v, heads, kv_heads, True, window, *_blocks(window, False),
-            interpret=not on_tpu(), scale=scale)
+            interpret=not on_tpu(), scale=scale, out_dtype=out_dtype)
         # The two residuals the half's checkpoint keeps (``decode``), so
         # that the backward pass has them without this kernel run again;
         # q, k, v and the gate it makes again. lse without the column's
@@ -511,11 +638,14 @@ def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale):
         return _gated(out, gate, heads), (q, k, v, gate, out, lse)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _flash_attention_bwd(heads, kv_heads, window, scale, residuals,
-                         cotangent):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
+                         residuals, cotangent):
     q, k, v, gate, out, lse = residuals
     with jax.named_scope(ATTENTION_SCOPE):
+        if out_dtype is not None:
+            # the kernel's products take the operands' dtype
+            cotangent = cotangent.astype(q.dtype)
         d_gate = None
         if gate is not None:
             # gated = gate x out: the kernels' cotangent is gate x d gated,
@@ -532,27 +662,30 @@ def _flash_attention_bwd(heads, kv_heads, window, scale, residuals,
             scale=scale), d_gate)
 
 
-def _counted_flash_attention_bwd(heads, kv_heads, window, scale, residuals,
-                                 cotangent):
+def _counted_flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
+                                 residuals, cotangent):
     # Counted here, once a layer: the program under it is traced once.
     q, k = residuals[:2]
     flash_attention.count_backward(flash_attention.grouped_backward_kind(
         q, k, heads, *_blocks(window, True), interpret=not on_tpu()))
-    return _flash_attention_bwd(heads, kv_heads, window, scale, residuals,
-                                cotangent)
+    return _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
+                                residuals, cotangent)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
 
 
 def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
-               heads: int):
+               heads: int, kv_heads: Optional[int] = None, out_dtype=None):
     """A layer's attention over rotated q (B, S, H x D) and k, v
-    (B, S, Hkv x D), each query head's output times its ``gate``
-    (B, S, H) where there is one, the softmax scaled by
-    ``attention_multiplier`` where the configuration has one, by what the
-    trace can observe: the kernels where they beat the inline path
-    (``flash_attention.beats_inline``)."""
+    (B, S, Hkv x D; ``kv_heads`` of them, ``None``: the configuration's),
+    each query head's output times its ``gate`` (B, S, H) where there is
+    one, the softmax scaled by ``attention_multiplier`` where the
+    configuration has one, the output in ``out_dtype`` (``None``: the
+    operands'), by what the trace can observe: the kernels where they
+    beat the inline path (``flash_attention.beats_inline``)."""
+    if kv_heads is None:
+        kv_heads = config.num_kv_heads
     seq_len = q.shape[1]
     window = config.sliding_window if layer_type == SLIDING else None
     if window is not None and window >= seq_len:
@@ -574,8 +707,77 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
             "backward pass: every layer the kernels compute",
             kind=kind).inc()
     attend = _flash_attention if flash else _inline_attention
-    return attend(q, k, v, gate, heads, config.num_kv_heads, window,
-                  config.attention_multiplier)
+    return attend(q, k, v, gate, heads, kv_heads, window,
+                  config.attention_multiplier, out_dtype)
+
+
+# -- differential attention ----------------------------------------------------------
+
+
+def _diff_lambda(config: DecoderConfig, layer: int, lp):
+    """A differential layer's ``lambda``, a float32 scalar: ``exp(lq1 .
+    lk1) - exp(lq2 . lk2) + lambda_init``."""
+    return (jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
+            - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"]))
+            + lambda_init(config.published_indices[layer]))
+
+
+# Jitted for the scope's sake, as the attentions.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _diff_combine(maps, lam, scale, after: float, eps: float, dtype):
+    """``after x RMSNorm(A1 V - lam x A2 V) x scale`` a head pair: ``maps``
+    (B, S, pairs, 2, 2 D) float32 holds a pair's ``A1 V`` and ``A2 V``;
+    (B, S, pairs x 2 D) out, in ``dtype``."""
+    with jax.named_scope(ATTENTION_SCOPE):
+        diff = maps[..., 0, :] - lam * maps[..., 1, :]
+        normed = diff * jax.lax.rsqrt(
+            jnp.mean(diff * diff, axis=-1, keepdims=True) + eps)
+        return (after * (normed * scale)).astype(dtype).reshape(
+            *maps.shape[:2], -1)
+
+
+def _differential(config: DecoderConfig, layer: int, q, k, v, lp,
+                  layer_type: str):
+    """Differential attention over q (B, S, H x D), k and v (B, S, Hkv x
+    D). Query heads ``(2i, 2i + 1)`` are pair ``i``'s two maps' queries,
+    key heads ``(2j, 2j + 1)`` their keys, value heads ``(2j, 2j + 1)``
+    side by side the values ``V_j`` (2 D wide) of key/value pair ``j``,
+    which the ``H / Hkv`` query pairs ``i`` with ``i // (H / Hkv) = j``
+    read. The released form's four attentions a pair (each map over each
+    half of ``V_j``) as one call of :func:`_attention` over 2 H query
+    heads and 2 Hkv key/value heads of D, so the blocked kernels run it
+    as they are (a map's scores twice, once a value half); then
+    :func:`_diff_combine`. The attention's output stays float32 until
+    the subtraction: at the seeded weights both maps are near the running
+    mean of the values, ``lambda``'s gradient is what is left once the
+    pairs' norm has taken their common part out, and outputs rounded to
+    bf16 put 2-15 % on it (PERF.md section 6, PR 40). ``lambda`` goes out
+    as the step's ``diff_attention`` of this layer."""
+    b, s, _ = q.shape
+    d = config.head_dim
+    groups, per = config.num_kv_heads // 2, config.num_heads \
+        // config.num_kv_heads
+    # one attention head a (j, map, value half, query pair of j)
+    q = jnp.broadcast_to(
+        q.reshape(b, s, groups, per, 2, 1, d).transpose(0, 1, 2, 4, 5, 3, 6),
+        (b, s, groups, 2, 2, per, d))
+    k = jnp.broadcast_to(k.reshape(b, s, groups, 2, 1, d),
+                         (b, s, groups, 2, 2, d))
+    v = jnp.broadcast_to(v.reshape(b, s, groups, 1, 2, d),
+                         (b, s, groups, 2, 2, d))
+    maps = _attention(config, q.reshape(b, s, -1), k.reshape(b, s, -1),
+                      v.reshape(b, s, -1), None, layer_type,
+                      2 * config.num_heads, 2 * config.num_kv_heads,
+                      jnp.float32)
+    # (j, map, half, pair) -> (pair of all, map, both halves side by side)
+    maps = maps.reshape(b, s, groups, 2, 2, per, d).transpose(
+        0, 1, 2, 5, 3, 4, 6).reshape(b, s, groups * per, 2, 2 * d)
+    lam = _diff_lambda(config, layer, lp)
+    tracing.step_stat("diff_attention", jnp.reshape(lam, (1,)), layer=layer)
+    return _diff_combine(
+        maps, lam, lp["subln"],
+        1.0 - lambda_init(config.published_indices[layer]),
+        config.rms_norm_eps, q.dtype)
 
 
 # -- the dense MLP and the shared expert -----------------------------------------
@@ -645,6 +847,23 @@ def _rms_norm(x, scale, eps: float):
     return (normed * scale).astype(x.dtype)
 
 
+def _layer_norm(x, scale, bias, eps: float):
+    xf = x.astype(jnp.float32)
+    centered = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    normed = centered * jax.lax.rsqrt(
+        jnp.mean(centered * centered, axis=-1, keepdims=True) + eps)
+    return (normed * scale + bias).astype(x.dtype)
+
+
+def _norm(config: DecoderConfig, x, p, name: str):
+    """The configuration's norm of the residual stream under ``p``'s
+    scale ``name`` (and, LayerNorm, its bias ``name_bias``)."""
+    if config.norm == LAYER_NORM:
+        return _layer_norm(x, p[name], p[f"{name}_bias"],
+                           config.rms_norm_eps)
+    return _rms_norm(x, p[name], config.rms_norm_eps)
+
+
 def _experts(config: DecoderConfig, layer: int, x, lp):
     """The held experts' part of a layer's sparse-expert sum, (B, S, h).
     What its walk did this step (held pairs, tiles, rounds) goes out as
@@ -706,7 +925,7 @@ def _attention_half(config: DecoderConfig, layer: int, x, lp):
         return (_rope(projected, count, cos, sin, rotated) if config.rotary
                 else projected)
 
-    a = _rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
+    a = _norm(config, x, lp, "attn_norm")
     q = placed(_project(a, lp["wq"]), heads)
     k = placed(_project(a, lp["wk"]), config.num_kv_heads)
     v = _project(a, lp["wv"])
@@ -714,6 +933,17 @@ def _attention_half(config: DecoderConfig, layer: int, x, lp):
             if config.attention_gate else None)
     return _added(config, x, _project(
         _attention(config, q, k, v, gate, layer_type, heads), lp["wo"]))
+
+
+def _count_ssm(kind: str) -> None:
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_lm_ssm_total",
+        "Decoder layers' state-space mixers traced, by what computes the "
+        "scan: Mamba-2's chunked scan by Pallas kernels that keep a head's "
+        "chunk-by-chunk tile in VMEM or by XLA's products over chunks "
+        "(chunked_*), Mamba-1's selective scan by Pallas kernels that keep "
+        "the state in VMEM or by XLA's loops (selective_*)", kind=kind).inc()
 
 
 def _mamba_half(config: DecoderConfig, layer: int, x, lp):
@@ -726,13 +956,7 @@ def _mamba_half(config: DecoderConfig, layer: int, x, lp):
     in_vmem = ssd.scans_in_vmem(
         config.mamba_chunk, config.mamba_heads, config.mamba_head_dim, state,
         x.dtype)
-    # Counted when a layer is traced, not when it runs.
-    rt_metrics.counter(
-        "rsdl_lm_ssm_total",
-        "Decoder layers' state-space mixers traced, by what computes the "
-        "scan: Pallas kernels that keep a head's chunk-by-chunk tile in "
-        "VMEM, or XLA's products over chunks",
-        kind="chunked_vmem" if in_vmem else "chunked_xla").inc()
+    _count_ssm("chunked_vmem" if in_vmem else "chunked_xla")
     rt_metrics.gauge("rsdl_lm_ssm_chunk",
                      "Positions in a chunk of the state-space scan, last "
                      "layer traced").set(config.mamba_chunk)
@@ -740,7 +964,7 @@ def _mamba_half(config: DecoderConfig, layer: int, x, lp):
                      "Whether the last state-space scan traced keeps its "
                      "chunk-by-chunk tiles in VMEM (1) or sends them "
                      "through HBM (0)").set(int(in_vmem))
-    n = _rms_norm(x, lp["mamba_norm"], config.rms_norm_eps)
+    n = _norm(config, x, lp, "mamba_norm")
     z, xbc, dt = jnp.split(_project(n, lp["in_proj"]),
                            [width, 2 * width + 2 * state], axis=-1)
     xbc = ssd.causal_conv_silu(xbc, lp["conv_w"], lp["conv_b"])
@@ -755,14 +979,113 @@ def _mamba_half(config: DecoderConfig, layer: int, x, lp):
     return _added(config, x, _project(y, lp["out_proj"]))
 
 
+def _shared(tensors, kind: str):
+    """What an earlier layer made, as a later layer reads it."""
+    # Counted when a reader is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_lm_shared_total",
+        "Decoder layers traced that read what an earlier layer made: a "
+        "Gated Memory Unit the selective scan's output (memory), a "
+        "cross-attention layer the full attention layer's keys and values "
+        "(kv)", kind=kind).inc()
+    return tensors
+
+
+def _differential_half(config: DecoderConfig, layer: int, x, lp, kv=None):
+    """``(x + differential attention(norm(x)), (k, v))``, a differential
+    layer's first half, without positions. ``kv``: an earlier layer's keys
+    and values, which a ``cross`` layer (no ``wk``, no ``wv``) attends
+    over; they come back as they were."""
+    layer_type = config.layer_types[layer]
+    a = _norm(config, x, lp, "attn_norm")
+    q = _project(a, lp["wq"])
+    if layer_type == CROSS:
+        k, v = _shared(kv, "kv")
+        layer_type = FULL       # causal over the whole row
+    else:
+        k, v = _project(a, lp["wk"]), _project(a, lp["wv"])
+    out = _differential(config, layer, q, k, v, lp, layer_type)
+    return _added(config, x, _project(out, lp["wo"])), (k, v)
+
+
+def _mamba1_half(config: DecoderConfig, layer: int, x, lp):
+    """``(x + Mamba-1(norm(x)), y)``, a ``mamba1`` layer's first half and
+    its scan's output before the gate: the four projections under
+    ``PROJ_SCOPE``, what lies between them under ``SSCAN_SCOPE``. How much
+    state crossed the scan's chunks goes out as the step's ``ssm_scan`` of
+    this layer."""
+    width, state = config.mamba1_width, config.mamba1_state
+    rank = config.mamba1_dt_rank
+    in_vmem = selective_scan.scans_in_vmem(width, state, config.mamba_chunk)
+    _count_ssm("selective_vmem" if in_vmem else "selective_xla")
+    n = _norm(config, x, lp, "mamba_norm")
+    u, z = jnp.split(_project(n, lp["in_proj"]), 2, axis=-1)
+    u = selective_scan.causal_conv_silu(u, lp["conv_w"], lp["conv_b"])
+    r, b_in, c_in = jnp.split(_project(u, lp["x_proj"]),
+                              [rank, rank + state], axis=-1)
+    dt = selective_scan.softplus_step(_project(r, lp["dt_proj"]),
+                                      lp["dt_bias"])
+    y, crossed = selective_scan.selective_scan_counted(
+        u, dt, lp["a_log"], b_in, c_in, lp["d"], config.mamba_chunk)
+    tracing.step_stat("ssm_scan", crossed, layer=layer)
+    return _added(config, x, _project(selective_scan.gated(y, z),
+                                      lp["out_proj"])), y
+
+
+@jax.custom_vjp
+def _gmu_gated(gate, memory):
+    """``silu(gate) * memory``, float32 inside, in ``gate``'s dtype. The
+    backward is written out. Both passes sit between barriers: left to
+    XLA they become the epilogue of the product before them (``W_1``'s;
+    ``W_2``'s gradient's) or the prologue of the one after, under that
+    product's name, and a trace shows nothing under ``GMU_SCOPE``; what
+    the barriers cost is one more pass over (S, 5,120) bf16 each way."""
+    return _gmu_gated_fwd(gate, memory)[0]
+
+
+# Jitted for their names' sake (``_swiglu_fwd``).
+@jax.jit
+def _gmu_gated_fwd(gate, memory):
+    with jax.named_scope(GMU_SCOPE):
+        gate, memory = jax.lax.optimization_barrier((gate, memory))
+        out = (jax.nn.silu(gate.astype(jnp.float32))
+               * memory.astype(jnp.float32)).astype(gate.dtype)
+        return jax.lax.optimization_barrier(out), (gate, memory)
+
+
+@jax.jit
+def _gmu_gated_bwd(residuals, d_out):
+    gate, memory = residuals
+    with jax.named_scope(GMU_SCOPE):
+        d_out = jax.lax.optimization_barrier(d_out)
+        g32, d32 = gate.astype(jnp.float32), d_out.astype(jnp.float32)
+        sig = jax.nn.sigmoid(g32)
+        d_gate = (d32 * memory.astype(jnp.float32) * sig
+                  * (1.0 + g32 * (1.0 - sig))).astype(gate.dtype)
+        d_memory = (d32 * g32 * sig).astype(memory.dtype)
+        return jax.lax.optimization_barrier((d_gate, d_memory))
+
+
+_gmu_gated.defvjp(_gmu_gated_fwd, _gmu_gated_bwd)
+
+
+def _gmu_half(config: DecoderConfig, layer: int, x, lp, memory):
+    """x + ``(silu(norm(x) W_1) * M) W_2``, a ``gmu`` layer's first half:
+    a Gated Memory Unit over ``memory``, an earlier Mamba-1 layer's scan
+    output."""
+    n = _norm(config, x, lp, "gmu_norm")
+    mixed = _gmu_gated(_project(n, lp["w1"]), _shared(memory, "memory"))
+    return _added(config, x, _project(mixed, lp["w2"]))
+
+
 def _mlp_half(config: DecoderConfig, layer: int, x, lp):
     """x + MLP(RMSNorm(x)), the second half of a layer: the dense SwiGLU,
     or the held experts' part of the routed sum and the shared expert."""
     if config.mlp_type(layer) == DENSE:
-        n = _rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
+        n = _norm(config, x, lp, "mlp_norm")
         return _added(config, x, _mlp("dense", n, lp["gate"], lp["up"],
                                       lp["down"]))
-    n = _rms_norm(x, lp["moe_norm"], config.rms_norm_eps)
+    n = _norm(config, x, lp, "moe_norm")
     out = _added(config, x, _experts(config, layer, n, lp))
     if config.shared_expert_width:
         out = _added(config, out, _mlp(
@@ -777,7 +1100,8 @@ def _checked(config: DecoderConfig) -> None:
         raise ValueError(f"experts_held {config.experts_held} reaches past "
                          f"the router's {config.num_experts} experts")
     for name, kinds, known in (
-            ("layer_types", config.layer_types, (SLIDING, FULL, MAMBA)),
+            ("layer_types", config.layer_types,
+             (SLIDING, FULL, MAMBA, MAMBA1, GMU, CROSS)),
             ("mlp_layer_types", config.mlp_layer_types, (DENSE, SPARSE))):
         for kind in kinds or ():
             if kind not in known:
@@ -789,6 +1113,36 @@ def _checked(config: DecoderConfig) -> None:
                              f"layer_types {config.num_layers}")
     if MAMBA in config.layer_types and config.mamba_heads < 1:
         raise ValueError("a mamba layer needs mamba_heads")
+    if config.norm not in (RMS_NORM, LAYER_NORM):
+        raise ValueError(f"unknown norm {config.norm!r}")
+    kinds = config.layer_types
+    if MAMBA1 in kinds and (config.mamba1_width < 1
+                            or config.mamba1_dt_rank < 1):
+        raise ValueError("a mamba1 layer needs mamba1_width and "
+                         "mamba1_dt_rank")
+    for layer, kind in enumerate(kinds):
+        if kind == GMU and MAMBA1 not in kinds[:layer]:
+            raise ValueError(f"layer {layer} is a gmu with no mamba1 layer "
+                             "before it to give out its memory")
+        if kind == CROSS and FULL not in kinds[:layer]:
+            raise ValueError(f"layer {layer} is a cross layer with no "
+                             "full_attention layer before it to give out "
+                             "its keys and values")
+    if CROSS in kinds and not config.differential:
+        raise ValueError("a cross layer is differential attention")
+    if config.differential:
+        if (config.published_indices is None
+                or len(config.published_indices) != config.num_layers):
+            raise ValueError("differential attention needs each layer's "
+                             "published index")
+        if (config.rotary or config.attention_gate
+                or config.heads_per_layer is not None
+                or config.num_kv_heads % 2
+                or config.num_heads % config.num_kv_heads):
+            raise ValueError(
+                "differential attention pairs adjacent heads (an even "
+                "count of key/value heads that divides the query heads'), "
+                "without positions, head gate or heads by layer")
 
 
 def decode(config: DecoderConfig, params: Dict[str, Any],
@@ -796,7 +1150,11 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     """token_ids (B, S) int32 -> hidden states (B, S, hidden) in the
     compute dtype, after the last layer's residual (before the final
     norm). Every layer is made again in the backward pass, but for its
-    attention kernel's two results.
+    attention kernel's two results. What later layers read of an earlier
+    one (a ``mamba1`` layer's scan output, a ``full_attention`` layer's
+    keys and values) leaves its half's checkpoint as a result and enters
+    theirs as an argument: kept once, and autodiff sums what its readers
+    and its own layer hand back before its half's backward runs.
 
     ``mesh``: the mesh the calling step is jitted over (``ops/embedding.py:
     lookup``'s convention). One device only: the expert layer's exchange
@@ -818,18 +1176,34 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     # names neither, and its half keeps its input only.
     keep_kernel_results = jax.checkpoint_policies.save_only_these_names(
         KEPT_OUT, KEPT_LSE)
+    memory = kv = None      # the last mamba1 layer's y, full layer's k, v
     for layer in range(config.num_layers):
         # Each half is made again on its own in the backward pass: the
         # MLP half's backward runs before the attention half's q, k and v
         # exist again, so the two halves' activations never sit on the
         # chip together.
         lp = params[f"layer_{layer}"]
+        layer_type = config.layer_types[layer]
         # What a half records of the step's own counters leaves its
         # checkpoint as an output (counted in the forward pass, not again
         # when the half is made again).
-        if config.layer_types[layer] == MAMBA:
+        if layer_type == MAMBA:
             x = tracing.step_stats_of(jax.checkpoint(tracing.with_step_stats(
                 functools.partial(_mamba_half, config, layer))))(x, lp)
+        elif layer_type == MAMBA1:
+            x, memory = tracing.step_stats_of(jax.checkpoint(
+                tracing.with_step_stats(functools.partial(
+                    _mamba1_half, config, layer))))(x, lp)
+        elif layer_type == GMU:
+            x = jax.checkpoint(functools.partial(_gmu_half, config, layer))(
+                x, lp, memory)
+        elif config.differential:
+            x, made = tracing.step_stats_of(jax.checkpoint(
+                tracing.with_step_stats(functools.partial(
+                    _differential_half, config, layer)),
+                policy=keep_kernel_results))(x, lp, kv)
+            if layer_type == FULL:
+                kv = made
         else:
             x = jax.checkpoint(
                 functools.partial(_attention_half, config, layer),
@@ -956,8 +1330,8 @@ def loss_fn(config: DecoderConfig, params: Dict[str, Any],
     """Mean next-token cross-entropy over the ``S - 1`` shifted positions
     of each row of ``token_ids`` (B, S), over this chip's slice of the
     vocabulary. ``mesh`` is :func:`decode`'s."""
-    x = _rms_norm(decode(config, params, token_ids, mesh),
-                  params["final_norm"], config.rms_norm_eps)
+    x = _norm(config, decode(config, params, token_ids, mesh), params,
+              "final_norm")
     targets = next_token_targets(token_ids.astype(jnp.int32))
     # A tied head is the embedding's own matrix: the one leaf takes the
     # gradient of both uses.

@@ -787,11 +787,11 @@ def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
                      interpret: bool, packed: bool = False,
                      num_heads: Optional[int] = None,
                      num_kv_heads: Optional[int] = None,
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None, out_dtype=None):
     """``(out, lse (B, H, Sq, 1))`` from the blocked kernels; the operands
     (B, H, S, D) with k and v of H or fewer heads, or ``packed``
     (B, S, H x D). ``scale`` multiplies the scores (``None``: 1 /
-    sqrt(D))."""
+    sqrt(D)); ``out`` is in ``out_dtype`` (``None``: q's)."""
     b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
     group, axis = h // hkv, 1 if packed else 2
     bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
@@ -817,7 +817,8 @@ def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
         in_specs=in_specs,
         out_specs=[_rows(packed, bq, d, q_at), _stats(bq, q_at)],
         out_shape=[
-            jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            jax.ShapeDtypeStruct(qp.shape,
+                                 q.dtype if out_dtype is None else out_dtype),
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
@@ -1135,7 +1136,7 @@ def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, out_dtype=None):
     """Self-attention of projections as their matmuls leave them: ``q``
     (B, S, H x D), ``k`` and ``v`` (B, S, Hkv x D), a head's D side by
     side; query head h reads key/value head h // (H / Hkv), which is
@@ -1144,18 +1145,20 @@ def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
     kernels read and write these arrays in place: no (B, S, H, D) ->
     (B, H, S, D) copy on either side; narrower heads (64) are copied
     head-major around the same kernels. ``scale`` multiplies the scores
-    (``None``: 1 / sqrt(D)). Without a custom_vjp of its own: the
+    (``None``: 1 / sqrt(D)); ``out_dtype`` is ``out``'s (``None``: q's;
+    float32 where what follows subtracts two outputs that nearly
+    cancel). Without a custom_vjp of its own: the
     caller pairs it with :func:`grouped_backward` under its own scope
     (models/mellum.py does)."""
     mask = _mask_of(causal, window)
     if _reads_in_place(q.shape[-1] // num_heads, interpret):
         return _blocked_forward(q, k, v, None, mask, block_q, block_k,
                                 interpret, True, num_heads, num_kv_heads,
-                                scale)
+                                scale, out_dtype)
     out, lse = _blocked_forward(
         _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
         _split_heads(v, num_kv_heads), None, mask, block_q, block_k,
-        interpret, scale=scale)
+        interpret, scale=scale, out_dtype=out_dtype)
     return _merge_heads(out), lse
 
 

@@ -823,19 +823,24 @@ def ssd(x, dt, a_log, b, c, d, chunk: int) -> jax.Array:
 # autodiff's. Jitted for the scope's sake, as the scan.
 
 
-@jax.jit
-def causal_conv_silu(x, weight, bias):
+def conv_silu(x, weight, bias):
     """``silu`` of a depthwise causal convolution: ``x`` (B, S, C),
     ``weight`` (K, C) float32, ``bias`` (C,) float32; position t sees
     ``x_(t-K+1) .. x_t`` under ``weight[0] .. weight[K-1]`` (a
-    ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to S)."""
+    ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to S). Under the
+    caller's scope (``ops/selective_scan.py`` has its own)."""
+    taps, seq = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(_F32), ((0, 0), (taps - 1, 0), (0, 0)))
+    out = bias.astype(_F32) + sum(
+        weight[k].astype(_F32) * padded[:, k:k + seq] for k in range(taps))
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+@jax.jit
+def causal_conv_silu(x, weight, bias):
+    """:func:`conv_silu` under this mixer's scope."""
     with jax.named_scope(SCOPE):
-        taps, seq = weight.shape[0], x.shape[1]
-        padded = jnp.pad(x.astype(_F32), ((0, 0), (taps - 1, 0), (0, 0)))
-        out = bias.astype(_F32) + sum(
-            weight[k].astype(_F32) * padded[:, k:k + seq]
-            for k in range(taps))
-        return jax.nn.silu(out).astype(x.dtype)
+        return conv_silu(x, weight, bias)
 
 
 @functools.partial(jax.jit, static_argnums=(3,))

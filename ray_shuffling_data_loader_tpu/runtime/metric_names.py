@@ -68,6 +68,11 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_lm_ssm_total": ("counter", ("kind",)),
     "rsdl_lm_ssm_chunk": ("gauge", ()),
     "rsdl_lm_ssm_in_vmem": ("gauge", ()),
+    # -- tensors one layer makes and later layers read (models/mellum.py;
+    #    counted once a reader when it is traced; kind = memory, the
+    #    selective scan's output a Gated Memory Unit reads | kv, the full
+    #    attention layer's keys and values a cross-attention layer reads) --
+    "rsdl_lm_shared_total": ("counter", ("kind",)),
     # -- the train step's own counters (utils/tracing.step_stat, folded by
     #    runtime/telemetry.step_stats_folded once a step's values have
     #    reached the host: counted when the step RAN, unlike the block
@@ -82,6 +87,7 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_moe_tiles_last_step": ("gauge", ()),
     "rsdl_ssm_end_decay_mean": ("gauge", ("layer",)),
     "rsdl_ssm_carry_abs_max": ("gauge", ("layer",)),
+    "rsdl_lm_diff_lambda": ("gauge", ("layer",)),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

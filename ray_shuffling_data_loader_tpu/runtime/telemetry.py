@@ -133,12 +133,17 @@ STEP_ANNOTATION = "rsdl.trainer.step"
 #: one state-space layer's chunked scan (``ops/ssd.py``), float32: the mean
 #: over rows, chunks and heads of a chunk's whole decay ``exp(cum_end)``
 #: (the share of the state a chunk starts from that reaches its end) and
-#: the largest ``|carry|`` handed from one chunk to the next.
+#: the largest ``|carry|`` handed from one chunk to the next; Mamba-1's
+#: selective scan (``ops/selective_scan.py``) records the same two, the
+#: mean over channels and states. ``diff_attention`` is one differential
+#: attention layer's ``lambda``, the scalar its second softmax map is
+#: subtracted under (``models/mellum.py``).
 #: :func:`step_stats_folded` has what each becomes in the registry.
 STEP_STAT_FIELDS: Dict[str, Tuple[str, ...]] = {
     "moe_walk": ("pairs", "pairs_held", "tiles", "rounds",
                  "fullest_expert_rows"),
     "ssm_scan": ("end_decay_mean", "carry_abs_max"),
+    "diff_attention": ("lambda",),
 }
 
 #: Upper bounds of ``rsdl_moe_tiles_per_step``: an even routing walks 128
@@ -939,12 +944,18 @@ def step_stats_folded(step: int,
     rounds to their counters, sets the layer's fullest-expert gauge, and
     samples the step's tiles, all layers summed, into
     ``rsdl_moe_tiles_per_step`` (and the gauge of the last step's);
-    ``ssm_scan`` sets each layer's two gauges."""
+    ``ssm_scan`` sets each layer's two gauges, ``diff_attention`` each
+    layer's one."""
     if not _ENABLED:
         return
     record("step_stats", step=step, stats=stats)
     metrics.counter("rsdl_step_stats_folded_total",
                     "train steps whose own counters reached the host").inc()
+    for row in stats.get("diff_attention", ()):
+        metrics.gauge("rsdl_lm_diff_lambda",
+                      "the scalar a differential attention layer subtracts "
+                      "its second softmax map under, last folded step",
+                      layer=row.get("layer", "")).set(row["lambda"])
     for row in stats.get("ssm_scan", ()):
         layer = row.get("layer", "")
         metrics.gauge("rsdl_ssm_end_decay_mean",

@@ -49,6 +49,21 @@ def tmp_parquet_dir(tmp_path):
     return str(tmp_path / "parquet")
 
 
+@pytest.fixture(autouse=True, scope="session")
+def _chipbench_scratch_of_this_worker(tmp_path_factory):
+    """The benchmark's rehearsals in this process keep their run
+    directories out of the checkout's ``.chipbench_scratch``:
+    ``test_measurement_path_fails_without_a_tpu`` asserts that one empty
+    after its own subprocess, and under several workers another worker's
+    rehearsal stood in it (ROADMAP.md B9's flake; PR 40's added test files
+    moved the schedule onto it in every whole run)."""
+    from chipbench import harness
+    checkout_scratch = harness.SCRATCH_ROOT
+    harness.SCRATCH_ROOT = str(tmp_path_factory.mktemp("chipbench_scratch"))
+    yield
+    harness.SCRATCH_ROOT = checkout_scratch
+
+
 def pytest_sessionfinish(session, exitstatus):
     if _LOCKSAN is not None and _LOCKSAN.installed():
         out = _LOCKSAN.dump()

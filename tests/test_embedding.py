@@ -641,3 +641,80 @@ def test_the_state_space_scan_compiles_for_the_chip_in_chunks(
         assert "tpu_custom_call" not in hlo
         assert every_heads
         assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_the_selective_scan_compiles_for_the_chip_with_its_state_in_vmem(
+        four_chips, monkeypatch):
+    """A Mamba-1 layer's scan of ``phi4flash_train_8k`` (one row of 8,192
+    positions, 5,120 channels, a state of 16, chunks of 64), forward and
+    backward, through the TPU's own compiler (kept in this file: one
+    process may load that compiler): one Mosaic call each way, no array of
+    every position's state (8,192 x 5,120 x 16) and temporaries that leave
+    the step its room."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import selective_scan as sscan
+    cfg = mellum.phi4_mini_flash_junction()
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    monkeypatch.setattr(sscan, "on_tpu", lambda: True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    s, width, state = 8192, cfg.mamba1_width, cfg.mamba1_state
+    u = shape((1, s, width), jnp.bfloat16)
+    bc = shape((1, s, state), jnp.bfloat16)
+
+    def both(u, dt, a_log, b, c, d, dy):
+        (y, crossed), vjp = jax.vjp(
+            lambda *operands: sscan.selective_scan_counted(
+                *operands, cfg.mamba_chunk), u, dt, a_log, b, c, d)
+        return y, crossed, vjp((dy, jnp.zeros_like(crossed)))
+
+    compiled = jax.jit(both).lower(
+        u, shape((1, s, width), jnp.float32),
+        shape((width, state), jnp.float32), bc, bc,
+        shape((width,), jnp.float32), u).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert "while" not in hlo
+    assert not re.search(r"\[(1,)?8192,(5120,16|16,5120|16,40,128)\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("layer", [1, 3], ids=["window_512", "full"])
+def test_differential_attention_compiles_for_the_chip(four_chips, layer):
+    """A differential layer of ``phi4flash_train_8k`` through the TPU's
+    own compiler, a row of 8,192 tokens at the published widths: each map
+    over each half of its values as one call of the blocked family over
+    80 query heads and 40 key/value heads of 64: two Mosaic kernels
+    (forward, and the one-kernel backward) and no (S, S) array."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    cfg = mellum.phi4_mini_flash_junction()
+    heads, kv_heads = 2 * cfg.num_heads, 2 * cfg.num_kv_heads
+    window = (cfg.sliding_window
+              if cfg.layer_types[layer] == mellum.SLIDING else None)
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    q, kv = (jax.ShapeDtypeStruct((1, 8192, n * cfg.head_dim),
+                                  jnp.bfloat16, sharding=one_chip)
+             for n in (heads, kv_heads))
+
+    def both(q, k, v, do):
+        args = (heads, kv_heads, True, window)
+        out, lse = fa.grouped_forward(
+            q, k, v, *args, *mellum._blocks(window, False))
+        return fa.grouped_backward(
+            q, k, v, out, lse, do, *args, *mellum._blocks(window, True))
+
+    hlo = jax.jit(both).lower(q, kv, kv, q).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(r"\[\d+,\d+(,\d+)*,8192,8192\]", hlo)
+    assert fa.grouped_backward_kind(q, kv, heads) == "fused"

@@ -253,8 +253,9 @@ def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
 
 
 _SPARSE_CELLS = ["mellum_train_8k", "laguna_train_8k"]
-_DECODER_CELLS = _SPARSE_CELLS + ["granite_train_8k"]
-_DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k"]
+_DECODER_CELLS = _SPARSE_CELLS + ["granite_train_8k", "phi4flash_train_8k"]
+_DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k",
+                    "phi4flash_train_8k"]
 
 
 @pytest.mark.parametrize("name,scope,layer,cells", [
@@ -266,12 +267,15 @@ _DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k"]
     ("lm_mlp_pct", mellum.MLP_SCOPE, "model", _DENSE_MLP_CELLS),
     ("lm_proj_pct", mellum.PROJ_SCOPE, "model", _DENSE_MLP_CELLS),
     ("lm_ssm_pct", mellum.SSM_SCOPE, "kernels", ["granite_train_8k"]),
+    ("lm_sscan_pct", mellum.SSCAN_SCOPE, "kernels", ["phi4flash_train_8k"]),
+    ("lm_gmu_pct", mellum.GMU_SCOPE, "model", ["phi4flash_train_8k"]),
 ])
 def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
                                                             layer, cells):
     """``mlm_head_pct`` (PR 29), ``attention_pct`` (PR 31), the decoder's
-    three (PR 32), its dense SwiGLUs' and its projections' (PR 34) and its
-    state-space mixers' (PR 38) are data: the scope
+    three (PR 32), its dense SwiGLUs' and its projections' (PR 34), its
+    state-space mixers' (PR 38), its selective scans' and its memory
+    units' (PR 40) are data: the scope
     reader ``grad_exchange_pct`` uses, pointed at a scope the model
     names, in the cells of that model's configurations that run the
     scope and in no other; a program without the scope (the parent's
