@@ -76,7 +76,14 @@ TPU-first choices:
   half at a time: a half keeps its bf16 input, and an attention half
   whose attention the kernels compute also their two results, the ungated
   output and the log-sum-exp a row, so that the backward pass makes the
-  norm, q, k, v and the gate again but runs no forward kernel twice.
+  norm, q, k, v and the gate again but runs no forward kernel twice. An
+  MLP half keeps its SwiGLU's ``x G`` and ``x U`` too (a dense layer's MLP
+  or a sparse layer's shared expert; not the routed experts), so that the
+  backward pass makes the norm again and runs nine products a SwiGLU, not
+  eleven, in as many layers as the device's memory has room for by the
+  allocator's own count when the step is traced (``mlp_halves_kept``:
+  every layer of the four 8,192-token configurations on a v5e; a layer
+  there is no room for keeps its input alone).
 - The head's loss walks blocks of tokens, forward and backward, so that no
   (tokens, vocabulary) float32 array outlives a block.
 """
@@ -125,6 +132,10 @@ HEAD_SCOPE = "rsdl.lm.head"
 # What an attention half's checkpoint keeps of the forward kernel
 # (``_flash_attention_fwd`` names them, ``decode``'s policy saves them).
 KEPT_OUT, KEPT_LSE = "rsdl.lm.attention.out", "rsdl.lm.attention.lse"
+# What an MLP half's checkpoint keeps of a SwiGLU's forward, ``x G`` and
+# ``x U`` (``_swiglu_fwd`` names them), in the layers ``mlp_halves_kept``
+# finds room for.
+KEPT_GATE, KEPT_UP = "rsdl.lm.mlp.gate", "rsdl.lm.mlp.up"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -788,7 +799,11 @@ def _swiglu(x, gate, up, down):
     """``(silu(x G) * (x U)) D`` for x (B, S, h) in the compute dtype and
     float32 weights G, U (h, f), D (f, h), cast to x's dtype for the
     products. The backward is written out: it keeps ``x G`` and ``x U``
-    and makes nothing again."""
+    and makes nothing again, six products to the forward's three. Under a
+    ``jax.checkpoint`` those two outlive the forward pass only if its
+    policy saves their names (``KEPT_GATE``, ``KEPT_UP``: ``decode``'s
+    does, where there is room): a plain one runs both products a second
+    time to have them, eleven a step."""
     return _swiglu_fwd(x, gate, up, down)[0]
 
 
@@ -796,8 +811,11 @@ def _swiglu(x, gate, up, down):
 @jax.jit
 def _swiglu_fwd(x, gate, up, down):
     with jax.named_scope(MLP_SCOPE):
-        g = x @ gate.astype(x.dtype)
-        u = x @ up.astype(x.dtype)
+        # The two residuals an MLP half's checkpoint keeps where there is
+        # room (``decode``): the backward pass then has them without these
+        # two products run again. x, the half's norm, it makes again.
+        g = checkpoint_name(x @ gate.astype(x.dtype), KEPT_GATE)
+        u = checkpoint_name(x @ up.astype(x.dtype), KEPT_UP)
         h = (jax.nn.silu(g.astype(jnp.float32))
              * u.astype(jnp.float32)).astype(x.dtype)
         return h @ down.astype(x.dtype), (x, gate, up, down, g, u)
@@ -828,12 +846,18 @@ def _swiglu_bwd(residuals, dy):
 _swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
-def _mlp(kind: str, x, gate, up, down):
+def _mlp(kind: str, kept: bool, x, gate, up, down):
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_lm_mlp_total",
         "Dense SwiGLUs traced, by what they are: a dense layer's MLP or a "
         "sparse-expert layer's shared expert", kind=kind).inc()
+    if kept:
+        rt_metrics.counter(
+            "rsdl_lm_mlp_kept_total",
+            "Dense SwiGLUs traced whose two first products, x G and x U, "
+            "the layer's checkpoint keeps for the backward pass: every "
+            "layer the device's memory has room for", kind=kind).inc()
     return _swiglu(x, gate, up, down)
 
 
@@ -1078,20 +1102,86 @@ def _gmu_half(config: DecoderConfig, layer: int, x, lp, memory):
     return _added(config, x, _project(mixed, lp["w2"]))
 
 
-def _mlp_half(config: DecoderConfig, layer: int, x, lp):
+def _mlp_half(config: DecoderConfig, layer: int, x, lp, kept: bool = False):
     """x + MLP(RMSNorm(x)), the second half of a layer: the dense SwiGLU,
-    or the held experts' part of the routed sum and the shared expert."""
+    or the held experts' part of the routed sum and the shared expert.
+    ``kept``: the half's checkpoint keeps the SwiGLU's ``x G`` and ``x U``
+    (what the caller's policy does; counted here)."""
     if config.mlp_type(layer) == DENSE:
         n = _norm(config, x, lp, "mlp_norm")
-        return _added(config, x, _mlp("dense", n, lp["gate"], lp["up"],
-                                      lp["down"]))
+        return _added(config, x, _mlp("dense", kept, n, lp["gate"],
+                                      lp["up"], lp["down"]))
     n = _norm(config, x, lp, "moe_norm")
     out = _added(config, x, _experts(config, layer, n, lp))
     if config.shared_expert_width:
         out = _added(config, out, _mlp(
-            "shared", n, lp["shared_gate"], lp["shared_up"],
+            "shared", kept, n, lp["shared_gate"], lp["shared_up"],
             lp["shared_down"]))
     return out
+
+
+# -- what the MLP halves' checkpoints keep --------------------------------------
+
+#: What the rest of a step is taken to hold in temporaries when the kept
+#: products are given their room: rows of ``hidden_size`` in the compute
+#: dtype a token. The compiled steps of the four 8,192-token cells hold 37
+#: to 90 without the kept products (a described v5e's memory analysis, PR
+#: 41: 5.63 GB at 32,768 tokens of 2,304, 1.75 GB at 8,192 of 2,048, 5.90
+#: GB at 16,384 of 2,048, 3.76 GB at 8,192 of 2,560).
+STEP_ROWS_A_TOKEN = 96
+
+
+def kept_products_bytes(config: DecoderConfig, layer: int,
+                        tokens: int) -> int:
+    """Bytes of ``x G`` and ``x U`` of the SwiGLU layer ``layer``'s MLP
+    half runs through ``_swiglu``, a dense layer's MLP or a sparse layer's
+    shared expert, over ``tokens`` tokens: 0 where it has neither."""
+    width = (config.intermediate_size if config.mlp_type(layer) == DENSE
+             else config.shared_expert_width)
+    return 2 * tokens * width * jnp.dtype(config.compute_dtype).itemsize
+
+
+def _device_memory(mesh: Optional[Mesh]) -> Optional[Tuple[int, int]]:
+    """The allocator's ``(bytes_limit, bytes_in_use)`` on the device the
+    step is traced for, as it stands now: while a training step is traced
+    its parameters and optimizer state are what is in use. ``None`` where
+    the backend keeps no such statistics (the CPU's)."""
+    device = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]), int(stats.get("bytes_in_use", 0))
+
+
+def keep_room(config: DecoderConfig, tokens: int,
+              memory: Optional[Tuple[int, int]]) -> Optional[int]:
+    """Bytes the MLP halves' checkpoints may keep between them in a step
+    over ``tokens`` tokens traced with ``memory`` = ``(bytes_limit,
+    bytes_in_use)`` on its device: the limit, less a sixteenth of it, less
+    what is in use, less ``STEP_ROWS_A_TOKEN`` rows of ``hidden_size`` a
+    token for the rest of the step. ``None`` (no allocator to ask): no
+    bound."""
+    if memory is None:
+        return None
+    limit, in_use = memory
+    return (limit - limit // 16 - in_use - STEP_ROWS_A_TOKEN * tokens
+            * config.hidden_size * jnp.dtype(config.compute_dtype).itemsize)
+
+
+def mlp_halves_kept(config: DecoderConfig, tokens: int,
+                    room: Optional[int]) -> Tuple[bool, ...]:
+    """Which layers' MLP halves keep their SwiGLU's ``x G`` and ``x U``
+    across the checkpoint: layers take their bytes of ``room`` in order
+    while it lasts, and a layer it does not last for is made again whole,
+    as every layer was. ``None``: every SwiGLU keeps."""
+    kept = []
+    for layer in range(config.num_layers):
+        need = kept_products_bytes(config, layer, tokens)
+        keeps = need > 0 and (room is None or need <= room)
+        if keeps and room is not None:
+            room -= need
+        kept.append(keeps)
+    return tuple(kept)
 
 
 def _checked(config: DecoderConfig) -> None:
@@ -1150,11 +1240,12 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     """token_ids (B, S) int32 -> hidden states (B, S, hidden) in the
     compute dtype, after the last layer's residual (before the final
     norm). Every layer is made again in the backward pass, but for its
-    attention kernel's two results. What later layers read of an earlier
-    one (a ``mamba1`` layer's scan output, a ``full_attention`` layer's
-    keys and values) leaves its half's checkpoint as a result and enters
-    theirs as an argument: kept once, and autodiff sums what its readers
-    and its own layer hand back before its half's backward runs.
+    attention kernel's two results and, where the device's memory has
+    room, its SwiGLU's two first products. What later layers read of an
+    earlier one (a ``mamba1`` layer's scan output, a ``full_attention``
+    layer's keys and values) leaves its half's checkpoint as a result and
+    enters theirs as an argument: kept once, and autodiff sums what its
+    readers and its own layer hand back before its half's backward runs.
 
     ``mesh``: the mesh the calling step is jitted over (``ops/embedding.py:
     lookup``'s convention). One device only: the expert layer's exchange
@@ -1176,6 +1267,24 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     # names neither, and its half keeps its input only.
     keep_kernel_results = jax.checkpoint_policies.save_only_these_names(
         KEPT_OUT, KEPT_LSE)
+    # Of an MLP half, its bf16 input and its SwiGLU's ``x G`` and ``x U``,
+    # in the layers there is room for: 268 MB a layer of one row of 8,192
+    # tokens and a width of 8,192 (2.68 GB over ten such layers, 2.01 GB
+    # over six of 10,240; 0.67 GB for a dense layer and four shared
+    # experts of 512 at two rows), against 2.8 ms a layer under
+    # ``rsdl.lm.mlp`` with the two products run a second time (7.0 ms at
+    # 10,240; PERF.md section 6, PR 41). The half's norm, the routed
+    # experts and ``silu(x G) * (x U)`` are made again as they were.
+    keep_products = jax.checkpoint_policies.save_only_these_names(
+        KEPT_GATE, KEPT_UP)
+    room = keep_room(config, token_ids.size, _device_memory(mesh))
+    mlp_kept = mlp_halves_kept(config, token_ids.size, room)
+    if room is not None:
+        # Set when a step is traced, as the counters beside it.
+        rt_metrics.gauge(
+            "rsdl_lm_mlp_keep_room_bytes",
+            "Bytes the device's memory had for the MLP halves' kept "
+            "products, last decoder traced").set(room)
     memory = kv = None      # the last mamba1 layer's y, full layer's k, v
     for layer in range(config.num_layers):
         # Each half is made again on its own in the backward pass: the
@@ -1208,8 +1317,10 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
             x = jax.checkpoint(
                 functools.partial(_attention_half, config, layer),
                 policy=keep_kernel_results)(x, lp)
-        x = tracing.step_stats_of(jax.checkpoint(tracing.with_step_stats(
-            functools.partial(_mlp_half, config, layer))))(x, lp)
+        x = tracing.step_stats_of(jax.checkpoint(
+            tracing.with_step_stats(functools.partial(
+                _mlp_half, config, layer, kept=mlp_kept[layer])),
+            policy=keep_products if mlp_kept[layer] else None))(x, lp)
     return x
 
 

@@ -52,11 +52,15 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    is traced, not when it runs; attention's kind = window | full |
     #    inline, and window | full again for the layers whose checkpoint
     #    keeps the forward kernel's results; a SwiGLU's = dense | shared,
-    #    the expert layer's = share | all of the router's experts held
-    #    here; ops/moe.py counts what moves the walk's rows, dma | xla) --
+    #    and again for the layers whose checkpoint keeps its two first
+    #    products, beside the bytes the device's memory had for them; the
+    #    expert layer's = share | all of the router's experts held here;
+    #    ops/moe.py counts what moves the walk's rows, dma | xla) --
     "rsdl_lm_attention_total": ("counter", ("kind",)),
     "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
+    "rsdl_lm_mlp_kept_total": ("counter", ("kind",)),
+    "rsdl_lm_mlp_keep_room_bytes": ("gauge", ()),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_gather_total": ("counter", ("kind",)),
     "rsdl_moe_experts_held": ("gauge", ()),

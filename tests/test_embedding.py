@@ -718,3 +718,50 @@ def test_differential_attention_compiles_for_the_chip(four_chips, layer):
     assert hlo.count("tpu_custom_call") == 2
     assert not re.search(r"\[\d+,\d+(,\d+)*,8192,8192\]", hlo)
     assert fa.grouped_backward_kind(q, kv, heads) == "fused"
+
+
+@pytest.mark.parametrize("hidden,width", [(2048, 8192), (2560, 10240)],
+                         ids=["granite", "phi4flash"])
+def test_the_mlp_half_keeps_two_products_on_the_chip(four_chips, hidden,
+                                                     width, monkeypatch):
+    """Two layers with ``granite_train_8k``'s or ``phi4flash_train_8k``'s
+    dense SwiGLU through the TPU's own compiler, a row of 8,192 tokens:
+    nine products a layer under ``rsdl.lm.mlp`` where the half's
+    checkpoint keeps ``x G`` and ``x U``, eleven where there is no room
+    for them, and the kept ones hold their own bytes of the step's
+    temporaries and no more (no copy of them waits through the step, as a
+    kept log-sum-exp's once did)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(mellum, "on_tpu", lambda: True)
+    cfg = mellum.DecoderConfig(
+        vocab_size=2048, hidden_size=hidden, num_heads=32, num_kv_heads=8,
+        head_dim=64, layer_types=(mellum.FULL, mellum.FULL),
+        mlp_layer_types=(mellum.DENSE, mellum.DENSE),
+        intermediate_size=width, rotary=False)
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mellum.init(cfg, jax.random.key(0))))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+
+    def compiled(memory):
+        monkeypatch.setattr(mellum, "_device_memory", lambda mesh: memory)
+        program = jax.jit(jax.grad(
+            lambda p, t: mellum.loss_fn(cfg, p, t))).lower(
+                params, tokens).compile()
+        products = [line for line in program.as_text().splitlines()
+                    if mellum.MLP_SCOPE in line
+                    and (" convolution(" in line or " dot(" in line)]
+        return len(products), program.memory_analysis().temp_size_in_bytes
+
+    # the CPU's devices keep no statistics: every layer keeps
+    kept, kept_temporaries = compiled(None)
+    plain, plain_temporaries = compiled((1 << 30, 1 << 30))
+    assert (kept, plain) == (9 * cfg.num_layers, 11 * cfg.num_layers)
+    a_layer = mellum.kept_products_bytes(cfg, 0, 8192)
+    assert a_layer == 2 * 8192 * width * 2
+    assert kept_temporaries - plain_temporaries <= cfg.num_layers * a_layer

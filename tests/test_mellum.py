@@ -582,6 +582,13 @@ def test_loss_and_every_gradient_match_the_reference(tiny_f32, monkeypatch):
 # -- what an attention half's checkpoint keeps ---------------------------------------
 
 
+def _counts(name, kinds):
+    """The registry's counter ``name`` at each of ``kinds``, 0 where it
+    was never counted."""
+    found = (metrics.get(name, {"kind": kind}) for kind in kinds)
+    return [0 if metric is None else metric.value for metric in found]
+
+
 def _loss_under_plain_checkpoints(cfg, params, tokens):
     """``mellum.loss_fn`` with the policy taken off: the same halves, each
     under a ``jax.checkpoint`` that keeps its input and nothing else, so
@@ -598,6 +605,19 @@ def _loss_under_plain_checkpoints(cfg, params, tokens):
     targets = mellum.next_token_targets(tokens)
     return mellum._nll(x, params["head"], targets) / jnp.maximum(
         jnp.sum(targets != mellum.IGNORE_ID), 1)
+
+
+def _assert_equal_to_plain_checkpoints(cfg, params, tokens):
+    """The loss and every leaf's gradient, to the last bit."""
+    loss, grads = jax.value_and_grad(
+        lambda p: mellum.loss_fn(cfg, p, tokens))(params)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: _loss_under_plain_checkpoints(cfg, p, tokens))(params)
+    assert float(loss) == float(want_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(got, want,
+                                      err_msg=jax.tree_util.keystr(path))
 
 
 def test_the_gradient_runs_a_layers_forward_kernel_once(tiny_f32,
@@ -621,15 +641,7 @@ def test_keeping_the_kernels_results_moves_no_bit(tiny_f32, monkeypatch):
     programs differently)."""
     cfg, _, params, tokens, _, _ = tiny_f32
     monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
-    loss, grads = jax.value_and_grad(
-        lambda p: mellum.loss_fn(cfg, p, tokens))(params)
-    want_loss, want_grads = jax.value_and_grad(
-        lambda p: _loss_under_plain_checkpoints(cfg, p, tokens))(params)
-    assert float(loss) == float(want_loss)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
-        np.testing.assert_array_equal(got, want,
-                                      err_msg=jax.tree_util.keystr(path))
+    _assert_equal_to_plain_checkpoints(cfg, params, tokens)
 
 
 @pytest.mark.parametrize("flash", [False, True], ids=["inline", "kernels"])
@@ -640,12 +652,7 @@ def test_the_kept_counter_counts_the_kernels_layers(tiny_f32, flash,
     to keep."""
     cfg, _, params, tokens, _, _ = tiny_f32
     monkeypatch.setattr(fa, "beats_inline", lambda seq_len: flash)
-    kinds = ("window", "full", "inline")
-
-    def counts(name):
-        found = (metrics.get(name, {"kind": kind}) for kind in kinds)
-        return [0 if metric is None else metric.value for metric in found]
-
+    counts = functools.partial(_counts, kinds=("window", "full", "inline"))
     before = counts("rsdl_lm_attention_kept_total")
     traced_before = counts("rsdl_lm_attention_total")
     jax.eval_shape(lambda p: mellum.loss_fn(cfg, p, tokens), params)
@@ -658,6 +665,183 @@ def test_the_kept_counter_counts_the_kernels_layers(tiny_f32, flash,
         counts("rsdl_lm_attention_total"), traced_before)]
     # every layer the kernels compute engages, and no other
     assert traced == rose[:2] + [0 if flash else cfg.num_layers]
+
+
+# -- what an MLP half's checkpoint keeps -----------------------------------------------
+
+
+def _swiglus_by_kind(cfg):
+    """The SwiGLUs a trace of ``cfg`` runs: a dense layer's MLP, a sparse
+    layer's shared expert."""
+    sparse = sum(cfg.mlp_type(i) == mellum.SPARSE
+                 for i in range(cfg.num_layers))
+    return {"dense": cfg.num_layers - sparse,
+            "shared": sparse if cfg.shared_expert_width else 0}
+
+
+def _dense_f32():
+    # every layer's MLP the dense SwiGLU
+    return dataclasses.replace(
+        mellum.laguna_tiny(), compute_dtype=jnp.float32,
+        layer_types=(mellum.FULL, mellum.SLIDING),
+        mlp_layer_types=(mellum.DENSE, mellum.DENSE), heads_per_layer=(6, 8))
+
+
+def _shared_f32():
+    # every layer sparse with the shared expert beside the routed sum
+    return dataclasses.replace(
+        _dense_f32(), mlp_layer_types=(mellum.SPARSE, mellum.SPARSE))
+
+
+def _neither_f32():
+    # every layer sparse, no shared expert: no SwiGLU to keep anything of
+    return _mellum_f32()[1]
+
+
+@pytest.fixture(scope="module", params=[_dense_f32, _shared_f32, _neither_f32],
+                ids=["dense", "shared", "neither"])
+def swiglus(request):
+    """A tiny configuration, its seeded parameters and tokens, and the
+    SwiGLUs a trace of it runs by kind."""
+    cfg = request.param()
+    params = mellum.init(cfg, jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
+                                cfg.vocab_size, jnp.int32)
+    return cfg, params, tokens, _swiglus_by_kind(cfg)
+
+
+def _products(jaxpr, scope: str) -> int:
+    """``dot_general`` equations under ``scope`` in ``jaxpr`` and the
+    programs it calls."""
+    return sum(
+        (eqn.primitive.name == "dot_general"
+         and scope in str(eqn.source_info.name_stack))
+        + sum(_products(sub, scope)
+              for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+def test_the_gradient_runs_nine_products_a_swiglu(swiglus):
+    """Three forward and the written-out backward's six, a dense layer's
+    MLP or a shared expert: the half's checkpoint keeps ``x G`` and ``x U``
+    where a plain one runs the two a second time to have them (the third,
+    ``h D``, is dead there)."""
+    cfg, params, tokens, by_kind = swiglus
+    for loss, products in ((mellum.loss_fn, 9),
+                           (_loss_under_plain_checkpoints, 11)):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: loss(cfg, p, tokens)))(params)
+        assert _products(jaxpr.jaxpr, mellum.MLP_SCOPE) == (
+            products * sum(by_kind.values())), loss
+
+
+@pytest.mark.parametrize("policy,products", [
+    (None, 11),
+    (jax.checkpoint_policies.save_only_these_names(
+        mellum.KEPT_GATE, mellum.KEPT_UP), 9),
+    (jax.checkpoint_policies.save_only_these_names(mellum.KEPT_GATE), 10)],
+    ids=["plain", "both_kept", "one_kept"])
+def test_a_swiglu_under_a_checkpoint_runs_what_is_not_kept_again(policy,
+                                                                 products):
+    """The SwiGLU alone: its ``custom_vjp``'s residuals do not outlive a
+    checkpoint that does not name them."""
+    x = jnp.ones((2, 8, 16), jnp.float32)
+    weights = [jnp.ones(shape, jnp.float32)
+               for shape in ((16, 32), (16, 32), (32, 16))]
+
+    def loss(x, gate, up, down):
+        return jnp.sum(jax.checkpoint(mellum._swiglu, policy=policy)(
+            x, gate, up, down))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(x, *weights)
+    assert _products(jaxpr.jaxpr, mellum.MLP_SCOPE) == products
+
+
+def test_keeping_the_products_moves_no_bit(swiglus):
+    """The kept ``x G`` and ``x U`` are the arrays the half made again
+    would hold: the loss and every leaf's gradient are equal to the last
+    bit (operation by operation, as the kernels' results above)."""
+    cfg, params, tokens, _ = swiglus
+    _assert_equal_to_plain_checkpoints(cfg, params, tokens)
+
+
+_mlp_counts = functools.partial(_counts, kinds=("dense", "shared"))
+
+
+def test_the_kept_counter_counts_the_swiglus_by_kind(swiglus):
+    """One a SwiGLU whose products the checkpoint keeps, by its kind,
+    beside ``rsdl_lm_mlp_total``: off the chip every one, and none where
+    no layer has a SwiGLU."""
+    cfg, params, tokens, by_kind = swiglus
+    before = _mlp_counts("rsdl_lm_mlp_kept_total")
+    traced_before = _mlp_counts("rsdl_lm_mlp_total")
+    jax.eval_shape(lambda p: mellum.loss_fn(cfg, p, tokens), params)
+    rose = [after - b for after, b in zip(
+        _mlp_counts("rsdl_lm_mlp_kept_total"), before)]
+    assert rose == [by_kind["dense"], by_kind["shared"]]
+    assert rose == [after - b for after, b in zip(
+        _mlp_counts("rsdl_lm_mlp_total"), traced_before)]
+
+
+def _memory_with_room(cfg, tokens: int, room: int):
+    """An allocator's ``(bytes_limit, bytes_in_use)`` that leaves ``room``
+    bytes for kept products under ``mellum.keep_room``'s rule."""
+    limit = 1 << 30
+    rest = (mellum.STEP_ROWS_A_TOKEN * tokens * cfg.hidden_size
+            * jnp.dtype(cfg.compute_dtype).itemsize)
+    memory = limit, limit - limit // 16 - rest - room
+    assert mellum.keep_room(cfg, tokens, memory) == room
+    return memory
+
+
+@pytest.mark.parametrize("room,kept", [
+    # the dense layer's 2 x 64 x 128 float32 and both shared experts' 2 x
+    # 64 x 32
+    (65536 + 2 * 16384, (True, True, True)),
+    (65536 + 16384, (True, True, False)),
+    (2 * 16384, (False, True, True)),    # not the wide one: the two after it
+    (16383, (False, False, False)),
+    (-(1 << 20), (False, False, False))],
+    ids=["fits", "fits_but_the_last", "fits_but_the_widest", "fits_not",
+         "the_step_alone_does_not"])
+def test_the_layers_that_keep_are_those_the_memory_has_room_for(
+        room, kept, monkeypatch):
+    """What is kept follows the device's memory and the shapes: layers
+    take their room in order, a layer there is none for is made again
+    whole, the counter says which did, and the gradient is the same to the
+    last bit whichever did."""
+    _, cfg = _laguna_f32()      # a dense layer, two with the shared expert
+    params = mellum.init(cfg, jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
+                                cfg.vocab_size, jnp.int32)
+    memory = _memory_with_room(cfg, tokens.size, room)
+    assert mellum.mlp_halves_kept(cfg, tokens.size, room) == kept
+    monkeypatch.setattr(mellum, "_device_memory", lambda mesh: memory)
+    before = _mlp_counts("rsdl_lm_mlp_kept_total")
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: mellum.loss_fn(cfg, p, tokens)))(params)
+    rose = [after - b for after, b in zip(
+        _mlp_counts("rsdl_lm_mlp_kept_total"), before)]
+    kinds = [cfg.mlp_type(i) for i, keeps in enumerate(kept) if keeps]
+    assert rose == [kinds.count(mellum.DENSE), kinds.count(mellum.SPARSE)]
+    needs = [mellum.kept_products_bytes(cfg, i, tokens.size)
+             for i in range(cfg.num_layers)]
+    assert metrics.get("rsdl_lm_mlp_keep_room_bytes").value == room
+    swiglus = sum(need > 0 for need in needs)
+    assert _products(jaxpr.jaxpr, mellum.MLP_SCOPE) == (
+        11 * swiglus - 2 * sum(kept))
+    _assert_equal_to_plain_checkpoints(cfg, params, tokens)
+
+
+def test_without_an_allocator_to_ask_every_swiglu_keeps():
+    """The CPU's devices keep no memory statistics: ``None``, and the
+    rule keeps every SwiGLU and nothing of a layer that has none."""
+    assert mellum._device_memory(None) is None
+    cfg = mellum.laguna_tiny()
+    assert mellum.keep_room(cfg, 64, None) is None
+    assert mellum.mlp_halves_kept(cfg, 64, None) == (True,) * 5
+    assert mellum.mlp_halves_kept(mellum.mellum_tiny(), 64, None) == (
+        False,) * 4
 
 
 def test_lagunas_parameter_count_and_flops():
@@ -829,10 +1013,8 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
     reader that sums under a scope counts each operation once), and the
     trace counted what it compiled."""
     cfg, _, params, tokens, _, _ = tiny_f32
-    sparse = sum(cfg.mlp_type(i) == mellum.SPARSE
-                 for i in range(cfg.num_layers))
-    swiglus = {"dense": cfg.num_layers - sparse,
-               "shared": sparse if cfg.shared_expert_width else 0}
+    swiglus = _swiglus_by_kind(cfg)
+    sparse = cfg.num_layers - swiglus["dense"]
 
     def count(name, **labels):
         metric = metrics.get(name, labels or None)
