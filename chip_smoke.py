@@ -94,6 +94,8 @@ class SmokeSize:
     # chunk)
     ssd_shapes: Tuple[Tuple[int, int, int, int, int, int], ...]
     sscan_shapes: Tuple[Tuple[int, int, int, int, int], ...]
+    # the mixers' depthwise convolution: (rows, positions, channels, taps)
+    conv_shapes: Tuple[Tuple[int, int, int, int], ...]
     epochs: int = 2
 
 
@@ -119,7 +121,9 @@ def full_size() -> SmokeSize:
         # granite_train_8k's nine Mamba layers
         ssd_shapes=((1, 8192, 64, 64, 128, 256),),
         # phi4flash_train_8k's two Mamba-1 layers
-        sscan_shapes=((1, 8192, 5120, 16, 64),))
+        sscan_shapes=((1, 8192, 5120, 16, 64),),
+        # granite_train_8k's xBC and phi4flash_train_8k's u
+        conv_shapes=((1, 8192, 4352, 4), (1, 8192, 5120, 4)))
 
 
 def tiny_size() -> SmokeSize:
@@ -138,7 +142,8 @@ def tiny_size() -> SmokeSize:
         moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),
                     (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
         ssd_shapes=((1, 256, 2, 64, 128, 128),),
-        sscan_shapes=((1, 32, 1024, 4, 8),))
+        sscan_shapes=((1, 32, 1024, 4, 8),),
+        conv_shapes=((2, 64, 256, 4),))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -275,6 +280,8 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
         _check_ssd(shape, interpret)
     for shape in size.sscan_shapes:
         _check_sscan(shape, interpret)
+    for shape in size.conv_shapes:
+        _check_conv(shape, interpret)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
@@ -511,12 +518,12 @@ def _check_scan_paths(what: str, other: str, names: str, operands, mix,
                       scan, mosaic_calls: Tuple[int, int], takes: bool,
                       interpret: bool, tol: float,
                       other_calls: int = 10) -> None:
-    """A scan's kernels (``scan(True)``) against its XLA path
-    (``scan(False)``, ``other``): the output and the six gradients, as
-    shares of each one's largest magnitude. On the chip the scan must take
-    the kernels of its own accord (``takes``, and ``mosaic_calls`` Mosaic
-    kernels forward / forward + backward), and both paths are timed, a
-    layer forward and forward + backward."""
+    """A scan's (or the convolution's) kernels (``scan(True)``) against
+    its XLA path (``scan(False)``, ``other``): the output and every
+    operand's gradient, as shares of each one's largest magnitude. On the
+    chip the scan must take the kernels of its own accord (``takes``, and
+    ``mosaic_calls`` Mosaic kernels forward / forward + backward), and
+    both paths are timed, a layer forward and forward + backward."""
     import jax
     import jax.numpy as jnp
 
@@ -526,7 +533,8 @@ def _check_scan_paths(what: str, other: str, names: str, operands, mix,
             return jnp.sum(y.astype(jnp.float32) * mix), y
 
         def all_of(*a):
-            grads, y = jax.grad(loss, range(6), has_aux=True)(*a)
+            grads, y = jax.grad(loss, range(len(operands)),
+                                has_aux=True)(*a)
             return (y, *grads)
 
         return jax.jit(all_of)
@@ -630,6 +638,33 @@ def _check_ssd(shape: Tuple[int, ...], interpret: bool,
         lambda in_vmem: lambda *a: ssd._ssd(*a, chunk, in_vmem)[0],
         (3, 6), ssd.scans_in_vmem(chunk, heads, width, state, x.dtype)
         and ssd.passes_in_vmem(heads, width, state), interpret, tol)
+
+
+def _check_conv(shape: Tuple[int, ...], interpret: bool,
+                tol: float = 2e-2) -> None:
+    """The mixers' depthwise convolution and ``silu`` by its kernels (a
+    block of ``x`` read once each way, ``d x`` and the taps' and the
+    bias's sums in one backward pass) against XLA's pad, shifted slices
+    and autodiff, bf16 ``x`` (:func:`_check_scan_paths`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.ops import ssd
+
+    rows, seq, channels, taps = shape
+    keys = jax.random.split(jax.random.key(19), 4)
+    x = jax.random.normal(keys[0], (rows, seq, channels), jnp.bfloat16)
+    weight = jax.random.normal(keys[1], (taps, channels)) * taps ** -0.5
+    bias = 0.1 * jax.random.normal(keys[2], (channels,))
+    _check_scan_paths(
+        f"depthwise convolution {rows} x {seq} positions, {channels} "
+        f"channels, {taps} taps", "pad and slices", "y, d x, d w, d b",
+        (x, weight, bias), jax.random.normal(keys[3], x.shape),
+        lambda in_vmem: (
+            (lambda *a: ssd._conv_silu_in_vmem(*a, ssd.SCOPE)) if in_vmem
+            else ssd.conv_silu),
+        (1, 2), ssd.convs_in_vmem(seq, channels, taps, x.dtype), interpret,
+        tol)
 
 
 # -- loader -> device feed -> train step -------------------------------------

@@ -970,6 +970,21 @@ def _count_ssm(kind: str) -> None:
         "the state in VMEM or by XLA's loops (selective_*)", kind=kind).inc()
 
 
+def _conv_silu(conv, x, lp):
+    """A mixer's convolution (``conv``: its ``causal_conv_silu``) over
+    ``x`` (B, S, C), counted by what computes it."""
+    in_vmem = ssd.convs_in_vmem(x.shape[1], x.shape[2],
+                                lp["conv_w"].shape[0], x.dtype)
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_lm_conv_total",
+        "Decoder layers' state-space mixers traced, by what computes "
+        "their depthwise convolution: a Pallas kernel each way that reads "
+        "a block once (vmem) or XLA's pad, shifted slices and autodiff "
+        "(xla)", kind="vmem" if in_vmem else "xla").inc()
+    return conv(x, lp["conv_w"], lp["conv_b"])
+
+
 def _mamba_half(config: DecoderConfig, layer: int, x, lp):
     """x + Mamba-2(RMSNorm(x)), a ``mamba`` layer's first half: the two
     projections under ``PROJ_SCOPE``, what lies between them under
@@ -991,7 +1006,7 @@ def _mamba_half(config: DecoderConfig, layer: int, x, lp):
     n = _norm(config, x, lp, "mamba_norm")
     z, xbc, dt = jnp.split(_project(n, lp["in_proj"]),
                            [width, 2 * width + 2 * state], axis=-1)
-    xbc = ssd.causal_conv_silu(xbc, lp["conv_w"], lp["conv_b"])
+    xbc = _conv_silu(ssd.causal_conv_silu, xbc, lp)
     xs, b_in, c_in = jnp.split(xbc, [width, width + state], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
     y, crossed = ssd.ssd_counted(
@@ -1044,7 +1059,7 @@ def _mamba1_half(config: DecoderConfig, layer: int, x, lp):
     _count_ssm("selective_vmem" if in_vmem else "selective_xla")
     n = _norm(config, x, lp, "mamba_norm")
     u, z = jnp.split(_project(n, lp["in_proj"]), 2, axis=-1)
-    u = selective_scan.causal_conv_silu(u, lp["conv_w"], lp["conv_b"])
+    u = _conv_silu(selective_scan.causal_conv_silu, u, lp)
     r, b_in, c_in = jnp.split(_project(u, lp["x_proj"]),
                               [rank, rank + state], axis=-1)
     dt = selective_scan.softplus_step(_project(r, lp["dt_proj"]),

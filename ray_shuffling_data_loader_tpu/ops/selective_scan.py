@@ -424,15 +424,14 @@ def selective_scan_counted(u, dt, a_log, b, c, d, chunk: int
 
 
 # -- the passes beside the scan ---------------------------------------------------
-# Plain ``jax.numpy`` under the scope, float32 inside; the backward is
-# autodiff's. Jitted for the scope's sake, as the scan.
+# Under the scope, float32 inside. The convolution is ``ops/ssd.py``'s, a
+# pair of kernels where the shapes fit; the others are plain ``jax.numpy``
+# and their backward autodiff's, jitted for the scope's sake, as the scan.
 
 
-@jax.jit
 def causal_conv_silu(x, weight, bias):
     """``ssd.causal_conv_silu``'s values under this mixer's scope."""
-    with jax.named_scope(SCOPE):
-        return ssd.conv_silu(x, weight, bias)
+    return ssd.causal_conv_silu(x, weight, bias, SCOPE)
 
 
 @jax.jit

@@ -39,8 +39,10 @@ forward and three times backward: the fallback, and the kernels' oracle
 (tests/test_ssd.py, chip_smoke.py). Both round alike and call the same
 ``_carries`` / ``_carried_back`` between their chunk-parallel halves (one
 kernel with the chunk axis sequential where :func:`passes_in_vmem`, else a
-loop of XLA's). What the scan costs is read from a trace under ``SCOPE``
-(``lm_ssm_pct``, ``lm_ssm_roofline_pct``).
+loop of XLA's). The convolution has its own pair of kernels and its own
+question, :func:`convs_in_vmem` (below, "the passes beside the scan").
+What the three cost is read from a trace under ``SCOPE`` (``lm_ssm_pct``,
+``lm_ssm_roofline_pct``).
 """
 
 from __future__ import annotations
@@ -429,10 +431,12 @@ def _specs(batch, chunks, chunk, heads, width, state, block):
         scalars=pl.BlockSpec(memory_space=pltpu.SMEM))
 
 
-def _params(sequential_heads: bool):
+def _params(sequential: bool):
+    """A three-axis grid's, its last axis (the scan's head blocks, the
+    convolution's sequence blocks) walked in order or not."""
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel",
-                             "arbitrary" if sequential_heads else "parallel"),
+                             "arbitrary" if sequential else "parallel"),
         vmem_limit_bytes=_VMEM_BYTES)
 
 
@@ -819,16 +823,19 @@ def ssd(x, dt, a_log, b, c, d, chunk: int) -> jax.Array:
 
 
 # -- the passes beside the scan ------------------------------------------------
-# Plain ``jax.numpy`` under the scope, float32 inside; the backward is
-# autodiff's. Jitted for the scope's sake, as the scan.
+# Under the scope, float32 inside, jitted for the scope's sake, as the scan.
+# The convolution is a pair of Pallas kernels with a written backward where
+# :func:`convs_in_vmem` (the benchmark's cells), else :func:`conv_silu`'s
+# plain ``jax.numpy`` with autodiff's backward, which is also the kernels'
+# oracle (tests/test_ssd.py, chip_smoke.py); the gated norm is plain
+# ``jax.numpy`` and its backward autodiff's.
 
 
 def conv_silu(x, weight, bias):
     """``silu`` of a depthwise causal convolution: ``x`` (B, S, C),
     ``weight`` (K, C) float32, ``bias`` (C,) float32; position t sees
     ``x_(t-K+1) .. x_t`` under ``weight[0] .. weight[K-1]`` (a
-    ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to S). Under the
-    caller's scope (``ops/selective_scan.py`` has its own)."""
+    ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to S)."""
     taps, seq = weight.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(_F32), ((0, 0), (taps - 1, 0), (0, 0)))
     out = bias.astype(_F32) + sum(
@@ -836,11 +843,248 @@ def conv_silu(x, weight, bias):
     return jax.nn.silu(out).astype(x.dtype)
 
 
-@jax.jit
-def causal_conv_silu(x, weight, bias):
-    """:func:`conv_silu` under this mixer's scope."""
-    with jax.named_scope(SCOPE):
+#: Rows a grid step reads before its block (backward: after it too): a
+#: bf16 tile's depth, so that a block starts on a tile in either dtype.
+#: Its last (first) ``_SUBLANES`` rows are what the taps reach.
+_HALO = 16
+#: Positions and channels a grid step takes at the most, and the rows its
+#: inner loop takes at a time (what is live between two stores stays in
+#: vector registers, a 128-lane column at a time).
+_CONV_ROWS, _CONV_LANES, _CONV_STRIP = 2048, 512, 32
+
+
+def _conv_block(seq: int, channels: int) -> Tuple[int, int]:
+    """``(rows, lanes)`` of a grid step's block: the most whole strips
+    that divide ``seq`` and the most whole 128s that divide ``channels``,
+    within ``_CONV_ROWS`` and ``_CONV_LANES``; 0 where none does."""
+    def most(size, step, limit):
+        return max((n for n in range(step, min(size, limit) + 1, step)
+                    if size % n == 0), default=0)
+
+    return (most(seq, _CONV_STRIP, _CONV_ROWS),
+            most(channels, _LANES, _CONV_LANES))
+
+
+def conv_takes(seq: int, channels: int, taps: int, dtype) -> bool:
+    """Whether the kernels below can compute such a convolution: bfloat16
+    or float32, channels of whole lanes, a sequence of whole blocks, taps
+    that reach no further back than a tile (at most 8)."""
+    return (jnp.dtype(dtype) in (jnp.float32, jnp.bfloat16)
+            and 1 <= taps <= _SUBLANES and all(_conv_block(seq, channels)))
+
+
+def convs_in_vmem(seq: int, channels: int, taps: int, dtype) -> bool:
+    """Whether the convolution reads a block of ``x`` once, in a Pallas
+    kernel each way, from what the trace can see: on the TPU, where
+    :func:`conv_takes` the shapes. XLA's pad, shifted slices and autodiff
+    otherwise."""
+    return on_tpu() and conv_takes(seq, channels, taps, dtype)
+
+
+def _reaching(cur, tail, taps: int):
+    """``[x_t, x_(t-1), .. x_(t-K+1)]`` for the rows t of ``cur`` (R, L)
+    float32, ``tail`` (8, L) the rows before them: shifted on float32
+    tiles, down the sublanes."""
+    joined = jnp.concatenate([tail, cur], axis=0)
+    return [cur] + [pltpu.roll(joined, back, 0)[_SUBLANES:]
+                    for back in range(1, taps)]
+
+
+def _pre(reaching, w, bias):
+    """``bias + sum_k w[k] x_(t-K+1+k)``, summed in :func:`conv_silu`'s
+    order; ``w`` (K, L) and ``bias`` (1, L) float32."""
+    taps = len(reaching)
+    acc = w[0:1] * reaching[taps - 1]
+    for k in range(1, taps):
+        acc = acc + w[k:k + 1] * reaching[taps - 1 - k]
+    return bias + acc
+
+
+def _conv_specs(rows: int, lanes: int, taps: int):
+    """Block specs over the grid (B, lane blocks, sequence blocks), by
+    what they cut: ``x`` (B, S, C) a block, the ``_HALO`` rows ``before``
+    it (the first block's: its own, unread) and ``after`` it (the last
+    block's: its own), ``w`` (K, C), ``bias`` (1, C)."""
+    halos = rows // _HALO
+    return dict(
+        x=pl.BlockSpec((1, rows, lanes), lambda i, l, s: (i, s, l)),
+        before=pl.BlockSpec(
+            (1, _HALO, lanes),
+            lambda i, l, s: (i, jnp.maximum(s * halos - 1, 0), l)),
+        after=lambda blocks: pl.BlockSpec(
+            (1, _HALO, lanes),
+            lambda i, l, s: (i, jnp.minimum(s + 1, blocks - 1) * halos, l)),
+        w=pl.BlockSpec((taps, lanes), lambda i, l, s: (0, l)),
+        bias=pl.BlockSpec((1, lanes), lambda i, l, s: (0, l)))
+
+
+def _conv_fwd_in_vmem(x, weight, bias, interpret: bool):
+    """:func:`conv_silu` as one kernel: a grid step reads its block of
+    ``x`` once with the tile of rows before it, and a strip of rows at a
+    time widens to float32, shifts, sums, ``silu``, casts and writes. No
+    padded float32 copy of ``x`` reaches HBM."""
+    batch, seq, channels = x.shape
+    taps = weight.shape[0]
+    rows, lanes = _conv_block(seq, channels)
+    strip = _CONV_STRIP
+
+    def kernel(x_ref, before_ref, w_ref, bias_ref, y_ref):
+        w, b = w_ref[...], bias_ref[...]
+        # zeros before a row's first position
+        tail = jnp.where(pl.program_id(2) == 0, 0.0,
+                         before_ref[0].astype(_F32)[_HALO - _SUBLANES:])
+
+        def a_strip(n, tail):
+            at = pl.ds(pl.multiple_of(n * strip, strip), strip)
+            cur = x_ref[0, at, :].astype(_F32)
+            pre = _pre(_reaching(cur, tail, taps), w, b)
+            y_ref[0, at, :] = (pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
+            return cur[strip - _SUBLANES:]
+
+        jax.lax.fori_loop(0, rows // strip, a_strip, tail)
+
+    specs = _conv_specs(rows, lanes, taps)
+    return pl.pallas_call(
+        kernel, grid=(batch, channels // lanes, seq // rows),
+        in_specs=[specs["x"], specs["before"], specs["w"], specs["bias"]],
+        out_specs=specs["x"],
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_params(True), interpret=interpret,
+    )(x, x, weight.astype(_F32), bias.astype(_F32).reshape(1, channels))
+
+
+def _conv_bwd_in_vmem(x, weight, bias, dy, interpret: bool):
+    """``(d x, d weight, d bias)`` as one kernel, the strips of a block
+    walked last to first: ``pre`` made again, ``d pre = dy silu'(pre)``,
+    ``d x_t = sum_k w[k] d pre_(t+K-1-k)`` from the strip's own ``d pre``
+    and the first rows of the strip after it (of the block after it: made
+    from the tiles of ``x`` and ``dy`` read after the block; zeros after a
+    row's last position), and the taps' and the bias's sums down the rows
+    as whole (8, L) float32 tiles, which stay in VMEM across a row's
+    sequence blocks (the output's block does not move) and are folded,
+    over sublanes and rows of the batch, once, outside."""
+    batch, seq, channels = x.shape
+    taps = weight.shape[0]
+    rows, lanes = _conv_block(seq, channels)
+    strip, blocks = _CONV_STRIP, seq // rows
+    strips = rows // strip
+
+    def d_pre_of(cur, tail, dy_rows, w, b):
+        reaching = _reaching(cur, tail, taps)
+        pre = _pre(reaching, w, b)
+        sig = jax.nn.sigmoid(pre)
+        return dy_rows * (sig * (1.0 + pre * (1.0 - sig))), reaching
+
+    def folded(a):
+        """(R, L) -> (8, L): whole tiles added."""
+        out = a[:_SUBLANES]
+        for at in range(_SUBLANES, a.shape[0], _SUBLANES):
+            out = out + a[at:at + _SUBLANES]
+        return out
+
+    def kernel(x_ref, dy_ref, before_ref, x_after_ref, dy_after_ref, w_ref,
+               bias_ref, dx_ref, sums_ref):
+        s = pl.program_id(2)
+        w, b = w_ref[...], bias_ref[...]
+        # d pre of the tile after the block
+        head, _ = d_pre_of(
+            x_after_ref[0].astype(_F32)[:_SUBLANES],
+            x_ref[0, rows - _HALO:, :].astype(_F32)[_HALO - _SUBLANES:],
+            dy_after_ref[0].astype(_F32)[:_SUBLANES], w, b)
+        head = jnp.where(s == blocks - 1, 0.0, head)
+
+        def a_strip(n, held):
+            head, sums = held
+            first = (strips - 1 - n) * strip
+            at = pl.ds(pl.multiple_of(first, strip), strip)
+            earlier = x_ref[0, pl.ds(pl.multiple_of(
+                jnp.maximum(first - _HALO, 0), _HALO), _HALO), :]
+            tail = jnp.where(first == 0, before_ref[0], earlier).astype(
+                _F32)[_HALO - _SUBLANES:]
+            tail = jnp.where((first == 0) & (s == 0), 0.0, tail)
+            d_pre, reaching = d_pre_of(
+                x_ref[0, at, :].astype(_F32), tail,
+                dy_ref[0, at, :].astype(_F32), w, b)
+            joined = jnp.concatenate([d_pre, head], axis=0)
+            d_x = w[taps - 1:taps] * d_pre
+            for ahead in range(1, taps):
+                d_x = d_x + w[taps - 1 - ahead:taps - ahead] * pltpu.roll(
+                    joined, strip + _SUBLANES - ahead, 0)[:strip]
+            dx_ref[0, at, :] = d_x.astype(dx_ref.dtype)
+            # d w[k] = sum_t d pre_t x_(t-K+1+k), then d b = sum_t d pre_t
+            terms = [d_pre * reaching[taps - 1 - k] for k in range(taps)]
+            sums = tuple(acc + folded(term)
+                         for acc, term in zip(sums, terms + [d_pre]))
+            return d_pre[:_SUBLANES], sums
+
+        zeros = jnp.zeros((_SUBLANES, lanes), _F32)
+        _, sums = jax.lax.fori_loop(0, strips, a_strip,
+                                    (head, (zeros,) * (taps + 1)))
+
+        @pl.when(s == 0)
+        def _():
+            sums_ref[...] = jnp.zeros_like(sums_ref)
+
+        for k, acc in enumerate(sums):
+            sums_ref[0, k] += acc
+
+    specs = _conv_specs(rows, lanes, taps)
+    d_x, sums = pl.pallas_call(
+        kernel, grid=(batch, channels // lanes, blocks),
+        in_specs=[specs["x"], specs["x"], specs["before"],
+                  specs["after"](blocks), specs["after"](blocks), specs["w"],
+                  specs["bias"]],
+        out_specs=[specs["x"],
+                   pl.BlockSpec((1, taps + 1, _SUBLANES, lanes),
+                                lambda i, l, s: (i, 0, 0, l))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(
+                       (batch, taps + 1, _SUBLANES, channels), _F32)],
+        compiler_params=_params(True), interpret=interpret,
+    )(x, dy, x, x, dy, weight.astype(_F32),
+      bias.astype(_F32).reshape(1, channels))
+    sums = jnp.sum(sums, axis=(0, 2))
+    return (d_x, sums[:taps].astype(weight.dtype),
+            sums[taps].astype(bias.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu_in_vmem(x, weight, bias, scope):
+    return _conv_silu_fwd(x, weight, bias, scope)[0]
+
+
+# Jitted for the scope's sake, as the scan's two.
+@functools.partial(jax.jit, static_argnums=(3,))
+def _conv_silu_fwd(x, weight, bias, scope):
+    with jax.named_scope(scope):
+        return (_conv_fwd_in_vmem(x, weight, bias, not on_tpu()),
+                (x, weight, bias))
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _conv_silu_bwd(scope, residuals, dy):
+    x, weight, bias = residuals
+    with jax.named_scope(scope):
+        return _conv_bwd_in_vmem(x, weight, bias, dy.astype(x.dtype),
+                                 not on_tpu())
+
+
+_conv_silu_in_vmem.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _conv_silu_plain(x, weight, bias, scope):
+    with jax.named_scope(scope):
         return conv_silu(x, weight, bias)
+
+
+def causal_conv_silu(x, weight, bias, scope: str = SCOPE):
+    """:func:`conv_silu` under a mixer's scope (this one's;
+    ``ops/selective_scan.py`` gives its own): by the kernels where
+    :func:`convs_in_vmem`, else as it is."""
+    if convs_in_vmem(x.shape[1], x.shape[2], weight.shape[0], x.dtype):
+        return _conv_silu_in_vmem(x, weight, bias, scope)
+    return _conv_silu_plain(x, weight, bias, scope)
 
 
 @functools.partial(jax.jit, static_argnums=(3,))

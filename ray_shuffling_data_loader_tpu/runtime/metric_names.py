@@ -68,8 +68,10 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_moe_top_k": ("gauge", ()),
     "rsdl_moe_tile_rows": ("gauge", ()),
     # -- a decoder layer's state-space mixer (models/mellum.py; counted or
-    #    set when a layer is traced; kind = what computes the scan) --
+    #    set when a layer is traced; kind = what computes the scan; the
+    #    convolution's = vmem, a Pallas kernel each way | xla) --
     "rsdl_lm_ssm_total": ("counter", ("kind",)),
+    "rsdl_lm_conv_total": ("counter", ("kind",)),
     "rsdl_lm_ssm_chunk": ("gauge", ()),
     "rsdl_lm_ssm_in_vmem": ("gauge", ()),
     # -- tensors one layer makes and later layers read (models/mellum.py;

@@ -685,6 +685,43 @@ def test_the_selective_scan_compiles_for_the_chip_with_its_state_in_vmem(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+@pytest.mark.parametrize("channels", [4352, 5120],
+                         ids=["granite_xbc", "phi4flash_u"])
+def test_the_mixers_convolution_compiles_for_the_chip_as_two_kernels(
+        four_chips, channels, monkeypatch):
+    """The depthwise convolution of ``granite_train_8k``'s Mamba-2 layers
+    (``xBC``: 4,352 channels) and of ``phi4flash_train_8k``'s Mamba-1
+    layers (``u``: 5,120), one row of 8,192 positions in bf16, four taps,
+    forward and backward, through the TPU's own compiler: one Mosaic call
+    each way and no float32 array of the row's shape (the padded copy,
+    the shifted slices, ``pre``) outside them."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.ops import ssd
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x = shape((1, 8192, channels), jnp.bfloat16)
+    assert ssd.convs_in_vmem(8192, channels, 4, jnp.bfloat16)
+
+    def both(x, weight, bias, dy):
+        y, vjp = jax.vjp(ssd.causal_conv_silu, x, weight, bias)
+        return y, vjp(dy)
+
+    compiled = jax.jit(both).lower(
+        x, shape((4, channels), jnp.float32),
+        shape((channels,), jnp.float32), x).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(rf"f32\[(1,)?819\d,{channels}\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e7
+
+
 @pytest.mark.parametrize("layer", [1, 3], ids=["window_512", "full"])
 def test_differential_attention_compiles_for_the_chip(four_chips, layer):
     """A differential layer of ``phi4flash_train_8k`` through the TPU's

@@ -263,6 +263,9 @@ def test_the_step_reports_every_mamba_layers_scan(empty_ring):
     assert metrics.get("rsdl_lm_ssm_chunk").value == cfg.mamba_chunk
     # the CPU, and chunks of 8: XLA's einsums
     assert metrics.get("rsdl_lm_ssm_in_vmem").value == 0
+    # and XLA's pad and slices for the mixers' convolution (24 channels)
+    convs = metrics.get("rsdl_lm_conv_total", {"kind": "xla"})
+    assert convs is not None and convs.value >= 3
 
 
 def test_the_scans_statistics_fold_without_waiting_for_the_device(
@@ -287,6 +290,7 @@ def test_the_scans_statistics_fold_without_waiting_for_the_device(
     for name, entry in {"rsdl_ssm_end_decay_mean": ("gauge", ("layer",)),
                         "rsdl_ssm_carry_abs_max": ("gauge", ("layer",)),
                         "rsdl_lm_ssm_total": ("counter", ("kind",)),
+                        "rsdl_lm_conv_total": ("counter", ("kind",)),
                         "rsdl_lm_ssm_chunk": ("gauge", ()),
                         "rsdl_lm_ssm_in_vmem": ("gauge", ())}.items():
         assert metric_names.METRIC_NAMES[name] == entry

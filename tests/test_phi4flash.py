@@ -367,6 +367,8 @@ def test_the_step_reports_both_scans_and_the_three_lambdas(empty_ring):
         assert traced > shared[kind]
     traced = metrics.get("rsdl_lm_ssm_total", {"kind": "selective_xla"})
     assert traced is not None and traced.value >= 2
+    convs = metrics.get("rsdl_lm_conv_total", {"kind": "xla"})
+    assert convs is not None and convs.value >= 2
     assert telemetry.STEP_STAT_FIELDS["diff_attention"] == ("lambda",)
     for name, entry in {"rsdl_lm_diff_lambda": ("gauge", ("layer",)),
                         "rsdl_lm_shared_total": ("counter", ("kind",)),

@@ -4,7 +4,8 @@ every gradient, at several chunk lengths, by XLA's einsums and (shapes of
 whole lanes, interpreted) by the kernels that keep a chunk's tiles in
 VMEM; what the carry between chunks is worth at the benchmark
 configuration's init and at the published one; which shapes the kernels
-take; the convolution and the gated norm beside it."""
+take; the convolution (plain, and by its kernels, interpreted, against
+the plain one and autodiff) and the gated norm beside it."""
 
 import jax
 import jax.numpy as jnp
@@ -319,6 +320,160 @@ def test_the_convolution_is_causal_depthwise_and_silu():
     later = x.at[:, 9:].set(0.0)
     np.testing.assert_array_equal(
         ssd.causal_conv_silu(later, weight, bias)[:, :9], got[:, :9])
+
+
+def _conv_operands(shape, dtype, taps: int = 4, seed: int = 8):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(keys[0], shape, dtype)
+    weight = jax.random.normal(keys[1], (taps, shape[-1])) * taps ** -0.5
+    bias = 0.1 * jax.random.normal(keys[2], shape[-1:])
+    return x, weight, bias, jax.random.normal(keys[3], shape)
+
+
+def _conv_with_grads(conv, x, weight, bias, mix):
+    """``(y, d x, d w, d b)`` of ``sum(conv(x, w, b) * mix)``."""
+    def loss(x, weight, bias):
+        y = conv(x, weight, bias)
+        return jnp.sum(y.astype(jnp.float32) * mix), y
+
+    grads, y = jax.grad(loss, (0, 1, 2), has_aux=True)(x, weight, bias)
+    return (y, *grads)
+
+
+def _conv_kernels(x, weight, bias):
+    return ssd._conv_silu_in_vmem(x, weight, bias, ssd.SCOPE)
+
+
+@pytest.fixture
+def small_conv_blocks(monkeypatch):
+    """Blocks of 64 positions x 128 channels, so that a small array is
+    several of them."""
+    monkeypatch.setattr(ssd, "_CONV_ROWS", 64)
+    monkeypatch.setattr(ssd, "_CONV_LANES", 128)
+
+
+#: (B, S, C) by what the grid is over, at blocks of 64 x 128.
+_CONV_SHAPES = {"one_block": (1, 64, 128),
+                "sequence_blocks": (1, 192, 128),
+                "lane_blocks": (1, 64, 384),
+                "batch_2": (2, 128, 256)}
+
+
+@pytest.mark.parametrize("shape", list(_CONV_SHAPES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_convolutions_kernels_are_the_plain_one_and_autodiff(
+        dtype, shape, small_conv_blocks):
+    """The output, ``d x``, the taps' and the bias's gradients by the two
+    kernels, interpreted: float32 equal to rounding, bfloat16 the same
+    values to its step (``d w`` and ``d b`` are float32 sums either
+    way)."""
+    dims = _CONV_SHAPES[shape]
+    assert ssd._conv_block(*dims[1:]) == (64, 128)
+    x, weight, bias, mix = _conv_operands(dims, dtype)
+    got = _conv_with_grads(_conv_kernels, x, weight, bias, mix)
+    want = _conv_with_grads(ssd.conv_silu, x, weight, bias, mix)
+    step = {jnp.float32: 1e-6, jnp.bfloat16: 2.0 ** -8}[dtype]
+    for name, g, w, tol in zip(("y", "d x", "d w", "d b"), got, want,
+                               (step, step, 2e-6, 2e-6)):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= tol * np.abs(w).max(), name
+
+
+@pytest.mark.parametrize("taps", [1, 2, 8])
+def test_the_convolutions_kernels_take_other_taps(taps, small_conv_blocks):
+    x, weight, bias, mix = _conv_operands((1, 128, 128), jnp.float32, taps)
+    for g, w in zip(_conv_with_grads(_conv_kernels, x, weight, bias, mix),
+                    _conv_with_grads(ssd.conv_silu, x, weight, bias, mix)):
+        np.testing.assert_allclose(g, w, rtol=0, atol=2e-6 * float(
+            jnp.max(jnp.abs(w))))
+
+
+def test_an_impulse_crosses_the_seam_between_blocks_and_comes_back(
+        small_conv_blocks):
+    """Ones in a block's last three rows reach the next block's first
+    three positions and no further, and the cotangent of those three
+    positions comes back across the seam to the rows that fed them."""
+    _, weight, bias, _ = _conv_operands((1, 128, 128), jnp.float32)
+    x = jnp.zeros((1, 128, 128)).at[:, 61:64].set(1.0)
+    silent = jax.nn.silu(bias)
+    got = _conv_kernels(x, weight, bias)
+    np.testing.assert_allclose(got, ssd.conv_silu(x, weight, bias),
+                               rtol=1e-6, atol=1e-7)
+    for t in (64, 65, 66):
+        assert float(jnp.max(jnp.abs(got[0, t] - silent))) > 1e-3, t
+    np.testing.assert_allclose(got[0, 67:], jnp.broadcast_to(
+        silent, got[0, 67:].shape), rtol=1e-6, atol=1e-7)
+    # a loss that sees the second block alone
+    mix = jnp.zeros((1, 128, 128)).at[:, 64:67].set(1.0)
+
+    def d_x(conv):
+        return jax.grad(lambda x: jnp.sum(conv(x, weight, bias) * mix))(x)
+
+    back = d_x(_conv_kernels)
+    np.testing.assert_allclose(back, d_x(ssd.conv_silu), rtol=1e-5,
+                               atol=1e-7)
+    for t in (61, 62, 63):
+        assert float(jnp.max(jnp.abs(back[0, t]))) > 1e-3, t
+    assert float(jnp.max(jnp.abs(back[0, :61]))) == 0.0
+    assert float(jnp.max(jnp.abs(back[0, 67:]))) == 0.0
+
+
+def test_the_first_positions_see_zeros_and_the_last_hand_nothing_on(
+        small_conv_blocks):
+    """Before a row's first position the taps read zeros, not the tile
+    the first block's grid step fetches in that place (its own rows 8 to
+    15); after its last position no gradient comes back from the tile the
+    last block fetches (its own first rows). Row by row of the batch."""
+    x, weight, bias, mix = _conv_operands((2, 128, 128), jnp.float32)
+    y, d_x, _, _ = _conv_with_grads(_conv_kernels, x, weight, bias, mix)
+    for t in range(3):
+        pre = bias + sum(weight[3 - back] * x[:, t - back]
+                         for back in range(t + 1))
+        np.testing.assert_allclose(y[:, t], jax.nn.silu(pre), rtol=1e-6,
+                                   atol=1e-7)
+    pre = bias + sum(weight[3 - back] * x[:, 127 - back]
+                     for back in range(4))
+    sig = jax.nn.sigmoid(pre)
+    np.testing.assert_allclose(
+        d_x[:, 127], weight[3] * mix[:, 127] * sig * (1 + pre * (1 - sig)),
+        rtol=1e-5, atol=1e-7)
+    # what stands in the fetched tiles does not matter
+    loud = x.at[:, 8:16].multiply(100.0)
+    np.testing.assert_array_equal(_conv_kernels(loud, weight, bias)[:, :5],
+                                  y[:, :5])
+
+
+def test_which_shapes_the_convolutions_kernels_take(monkeypatch):
+    """On the chip, bfloat16 or float32, channels of whole lanes, a
+    sequence of whole blocks, at most 8 taps; XLA's pad and slices
+    otherwise, and everywhere off the chip."""
+    cell = (8192, 4352, 4, jnp.bfloat16)       # granite_train_8k's xBC
+    assert not ssd.convs_in_vmem(*cell)        # the CPU
+    assert ssd.conv_takes(*cell)
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    assert ssd.convs_in_vmem(*cell)
+    assert ssd.convs_in_vmem(8192, 5120, 4, jnp.bfloat16)  # phi4flash's u
+    assert ssd.convs_in_vmem(64, 128, 8, jnp.float32)
+    assert not ssd.convs_in_vmem(32, 64, 4, jnp.bfloat16)   # granite_tiny
+    assert not ssd.convs_in_vmem(8192, 4352 + 64, 4, jnp.bfloat16)
+    assert not ssd.convs_in_vmem(8192 + 8, 4352, 4, jnp.bfloat16)
+    assert not ssd.convs_in_vmem(8192, 4352, 9, jnp.bfloat16)
+    assert not ssd.convs_in_vmem(8192, 4352, 4, jnp.float16)
+    # a grid step takes the most whole strips and whole 128s that divide
+    assert [ssd._conv_block(*dims) for dims in (
+        (8192, 4352), (8192, 5120), (96, 384), (131 * 32, 128),
+        (48, 128), (64, 100))] == [
+            (2048, 256), (2048, 512), (96, 384), (32, 128), (0, 128),
+            (64, 0)]
+    # the wrappers take them of their own accord, or leave them
+    x, weight, bias, _ = _conv_operands((1, 64, 128), jnp.float32)
+    monkeypatch.setattr(ssd, "_conv_silu_in_vmem",
+                        lambda *a: "the kernels")
+    assert ssd.causal_conv_silu(x, weight, bias) == "the kernels"
+    assert ssd.causal_conv_silu(x[:, :, :64], weight[:, :64],
+                                bias[:64]).shape == (1, 64, 64)
 
 
 def test_the_gated_norm_runs_over_the_whole_width():
