@@ -12,7 +12,10 @@ full width of one model the repo supports, and checks what comes out:
    compiled by Mosaic — not interpreted; the sparse-expert layer's walk
    (``ops/moe.py``) against its float32 loop of dense products at the
    decoder's widths, its rows moved by DMA (a tile's fetch and a round's
-   combine, each also alone: equal to XLA's gather, nanoseconds a row);
+   combine, each also alone: equal to XLA's gather); the state-space
+   scan's, the selective scan's and the mixers' convolution's kernels
+   against XLA's paths, every gradient. Checked, not timed: the
+   benchmark reads the same kernels in the step;
 2. loader -> device feed -> train step: seeded DLRM Parquet
    (``data_generation.generate_data``) through ``JaxShufflingDataset`` at
    library defaults into ``parallel.trainer.SpmdTrainer`` over
@@ -444,11 +447,7 @@ def _check_moe(shape: Tuple[int, ...], interpret: bool,
 def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
     """The walk's two row kernels alone, over a tile's worth of ``x``'s
     rows and a round's buffer in which a pick is live as often as an even
-    routing holds it: equal to XLA's gather of the same rows, and on the
-    chip how long a row takes (the fetch: a tile's rows; the combine: the
-    live picks' rows, the float32 sums and their write included), timed
-    over calls that loop ON the device: one dispatch from the host costs
-    more than a fetch."""
+    routing holds it: equal to XLA's gather of the same rows."""
     import jax
     import jax.numpy as jnp
 
@@ -461,69 +460,30 @@ def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
     index = jnp.where(
         jax.random.bernoulli(keys[1], held / experts, (tokens, top_k)),
         jax.random.randint(keys[2], (tokens, top_k), 0, rows), rows)
-    live = int(jnp.sum(index < rows))
     buffer = jax.random.normal(keys[3], (rows + tile, hidden),
                                x.dtype).at[rows:].set(0)
     acc = jax.random.normal(keys[4], (tokens, hidden))
-    x_words, buffer_words = moe._words(x), moe._words(buffer)
-
-    def fetch(words, token):
-        return moe._fetch([words], token, x.dtype, interpret)[0]
-
-    def combine(acc, words, index):
-        return moe._combine_dma(acc, words, jnp.sort(index, axis=1), rows,
-                                interpret)
-
-    _check(bool(jnp.array_equal(fetch(x_words, token), x[token])),
+    fetched = moe._fetch([moe._words(x)], token, x.dtype, interpret)[0]
+    _check(bool(jnp.array_equal(fetched, x[token])),
            f"the row fetch differs from XLA's gather at {tokens}x{hidden}")
     _check(bool(jnp.array_equal(
-        combine(acc, buffer_words, index),
+        moe._combine_dma(acc, moe._words(buffer), jnp.sort(index, axis=1),
+                         rows, interpret),
         moe._combined(acc, buffer, index, rows, False))),
         f"the combine differs from XLA's gathers at {tokens}x{hidden}")
-    if interpret:       # a time is the chip's to give
-        _info(f"kernels: expert layer's rows by DMA at {tokens}x{hidden} "
-              "equal to XLA's gather (interpreted, not timed)")
-        return
-
-    calls = 100
-
-    @jax.jit
-    def fetches(words, token):      # a kernel's call cannot be cut short
-        return jax.lax.fori_loop(
-            0, calls, lambda i, seen: seen + fetch(
-                words, jnp.roll(token, i))[0, 0].astype(jnp.float32), 0.0)
-
-    @jax.jit
-    def combines(acc, words, index):
-        return jax.lax.fori_loop(
-            0, calls, lambda i, acc: combine(acc, words, index), acc)
-
-    def seconds(looped, *args) -> float:
-        jax.block_until_ready(looped(*args))
-        start = timeit.default_timer()
-        jax.block_until_ready(looped(*args))
-        return (timeit.default_timer() - start) / calls
-
-    fetch_s = seconds(fetches, x_words, token)
-    combine_s = seconds(combines, acc, buffer_words, index)
     _info(f"kernels: expert layer's rows by DMA at {tokens}x{hidden} "
-          f"(Mosaic) equal to XLA's gather: a tile's fetch "
-          f"{1e9 * fetch_s / tile:.1f} ns a row ({tile} rows, "
-          f"{1e6 * fetch_s:.1f} us), a round's combine "
-          f"{1e9 * combine_s / max(live, 1):.1f} ns a live row ({live} of "
-          f"{tokens * top_k} picks, {1e3 * combine_s:.3f} ms)")
+          f"({'interpreted' if interpret else 'Mosaic'}): a tile's fetch "
+          "and a round's combine equal to XLA's gather")
 
 
 def _check_scan_paths(what: str, other: str, names: str, operands, mix,
                       scan, mosaic_calls: Tuple[int, int], takes: bool,
-                      interpret: bool, tol: float,
-                      other_calls: int = 10) -> None:
+                      interpret: bool, tol: float) -> None:
     """A scan's (or the convolution's) kernels (``scan(True)``) against
     its XLA path (``scan(False)``, ``other``): the output and every
     operand's gradient, as shares of each one's largest magnitude. On the
     chip the scan must take the kernels of its own accord (``takes``, and
-    ``mosaic_calls`` Mosaic kernels forward / forward + backward), and
-    both paths are timed, a layer forward and forward + backward."""
+    ``mosaic_calls`` Mosaic kernels forward / forward + backward)."""
     import jax
     import jax.numpy as jnp
 
@@ -539,39 +499,24 @@ def _check_scan_paths(what: str, other: str, names: str, operands, mix,
 
         return jax.jit(all_of)
 
+    # three programs a shape: each lowered once, for every count and
+    # comparison that wants it
+    forward, kernels, xla = (jax.jit(scan(True)), with_grads(True),
+                             with_grads(False))
     if not interpret:   # every shape the chip checks is a cell's
         _check(takes
-               and _mosaic_calls(jax.jit(scan(True)),
-                                 *operands) == mosaic_calls[0]
-               and _mosaic_calls(with_grads(True),
-                                 *operands) == mosaic_calls[1],
+               and _mosaic_calls(forward, *operands) == mosaic_calls[0]
+               and _mosaic_calls(kernels, *operands) == mosaic_calls[1],
                f"the {what} does not run in VMEM ({mosaic_calls[0]} Mosaic "
                f"kernels forward, {mosaic_calls[1]} with the backward)")
     errs = [float(jnp.max(jnp.abs(g.astype(jnp.float32)
                                   - w.astype(jnp.float32)))
                   / jnp.max(jnp.abs(w.astype(jnp.float32))))
-            for g, w in zip(with_grads(True)(*operands),
-                            with_grads(False)(*operands))]
+            for g, w in zip(kernels(*operands), xla(*operands))]
     _info(f"kernels: {what}: max|vmem-{other}| / max|{other}| {names} "
           + ", ".join(f"{e:.3e}" for e in errs) + f" (tol {tol:.0e})")
     _check(max(errs) <= tol, f"the {what}'s kernels differ from XLA's "
            f"{other}: {errs} > {tol}")
-    if interpret:       # a time is the chip's to give
-        return
-
-    def ms(jitted, calls: int = 10) -> float:
-        jax.block_until_ready(jitted(*operands))
-        start = timeit.default_timer()
-        for _ in range(calls):
-            out = jitted(*operands)
-        jax.block_until_ready(out)
-        return 1e3 * (timeit.default_timer() - start) / calls
-
-    _info(f"kernels: {what}, ms a layer forward / forward + backward: in "
-          f"VMEM {ms(jax.jit(scan(True))):.3f} / "
-          f"{ms(with_grads(True)):.3f}, XLA's {other} "
-          f"{ms(jax.jit(scan(False)), other_calls):.3f} / "
-          f"{ms(with_grads(False), other_calls):.3f}")
 
 
 def _check_sscan(shape: Tuple[int, ...], interpret: bool,
@@ -602,8 +547,7 @@ def _check_sscan(shape: Tuple[int, ...], interpret: bool,
         "y, d u, d dt, d a_log, d b, d c, d d", (u, dt, a_log, b, c, d),
         jax.random.normal(keys[5], u.shape),
         lambda in_vmem: lambda *a: sscan._sscan(*a, chunk, in_vmem)[0],
-        (1, 2), sscan.scans_in_vmem(channels, state, chunk), interpret, tol,
-        other_calls=2)
+        (1, 2), sscan.scans_in_vmem(channels, state, chunk), interpret, tol)
 
 
 def _check_ssd(shape: Tuple[int, ...], interpret: bool,

@@ -87,10 +87,9 @@ _KEYS: Dict[str, "tuple[Any, Callable[[str], Any]]"] = {
     # inherit it through the environment.
     "trace_dir": ("", str),
     # Continuous sampling profiler (runtime/profiler.py): stdlib stack
-    # sampling over named threads + per-thread CPU attribution. Off by
-    # default; the interval bounds its overhead (~1 stack walk per
-    # thread per tick).
-    "profiler": (False, _parse_bool),
+    # sampling over named threads + per-thread CPU attribution, started
+    # by its caller (an incident capsule's burst); the interval bounds
+    # its overhead (~1 stack walk per thread per tick).
     "profiler_interval_s": (0.01, float),
     # Batch-wait share of wall clock above which the per-epoch verdict
     # names a producer stage instead of train_step (the <=10% stall

@@ -23,22 +23,20 @@ in production:
   the box each ``rsdl-worker_N`` actually used, GIL or not.
 
 Zero overhead when off (no thread is started); overhead when on is one
-frames snapshot per interval. ``maybe_sample()`` is the env-driven
-driver entry: profiling engages when the ``profiler`` policy key
-(``RSDL_PROFILER=1``) or ``RSDL_PROFILE_FOLDED=<path>`` is set, and the
-folded output lands at that path.
+frames snapshot per interval. A driver samples a block with
+``SamplingProfiler().start()`` ... ``stop()`` and ``write_folded(path)``;
+an incident capsule's burst (runtime/health.py) does the same.
 
 Stdlib-only (the runtime/ contract).
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
 
@@ -217,32 +215,3 @@ class SamplingProfiler:
                  "samples": v} for k, v in hot
             ],
         }
-
-
-@contextlib.contextmanager
-def maybe_sample(folded_env: str = "RSDL_PROFILE_FOLDED"
-                 ) -> Iterator[Optional[SamplingProfiler]]:
-    """Profile the block iff profiling is switched on: the ``profiler``
-    policy key (``RSDL_PROFILER=1``) or a folded-output path in
-    ``RSDL_PROFILE_FOLDED``. Yields the profiler (or None when off);
-    on exit writes the folded stacks when a path was given. The JAX
-    device-side twin stays ``utils.tracing.maybe_profile`` — run both
-    to see host frames and device ops over the same window."""
-    from ray_shuffling_data_loader_tpu.runtime import policy
-    folded_path = os.environ.get(folded_env) or None
-    if not folded_path and not policy.resolve("telemetry", "profiler"):
-        yield None
-        return
-    profiler = SamplingProfiler()
-    profiler.start()
-    try:
-        yield profiler
-    finally:
-        profiler.stop()
-        if folded_path:
-            try:
-                profiler.write_folded(folded_path)
-                logger.info("sampling profile: %d samples -> %s",
-                            profiler.samples, folded_path)
-            except OSError:
-                logger.exception("folded-stack write failed")

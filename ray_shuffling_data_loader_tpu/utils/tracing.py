@@ -5,10 +5,8 @@ The program's stage spans are ``runtime/telemetry.span`` /
 recorder and, under its fixed ``rsdl.*`` name
 (``telemetry.SPAN_NAMES``), in the profiler's trace, so a captured trace
 shows the host pipeline stages on the same timeline as the XLA device
-operations. This module captures: explicitly
-(:func:`profile_trace`) or env-driven
-(``RSDL_PROFILE_DIR=/tmp/trace python ...`` via :func:`maybe_profile`);
-view with TensorBoard's profile plugin or Perfetto.
+operations. This module captures (:func:`profile_trace`); view with
+TensorBoard's profile plugin or Perfetto.
 
 **The step's own counters.** What the device computes at run time and an
 operator wants to see (how many tiles the expert walk took this step)
@@ -31,7 +29,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
-import os
 import threading
 import time
 from typing import (Any, Callable, Deque, Dict, Iterator, List, Optional,
@@ -58,18 +55,6 @@ def profile_trace(log_dir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def maybe_profile(env_var: str = "RSDL_PROFILE_DIR") -> Iterator[None]:
-    """Capture a trace iff the env var names a directory — the zero-code
-    way to profile a run whose driver enters this context."""
-    log_dir: Optional[str] = os.environ.get(env_var)
-    if not log_dir:
-        yield
-        return
-    with profile_trace(log_dir):
-        yield
 
 
 # -- the step's own counters -------------------------------------------------

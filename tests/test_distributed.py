@@ -219,15 +219,100 @@ def test_distributed_trainers_per_host(small_dataset):
     assert sorted(union) == list(range(6000))
 
 
-def test_distributed_world4_tph2_multiepoch_bit_exact(small_dataset):
-    """The dryrun's widest topology, pinned in the suite too: world=4
-    with trainers_per_host=2 (8 global trainers), 2 epochs, 10 reducers
-    split unevenly over the 8 trainers — every stream bit-identical to
-    the single-host num_trainers=8 shuffle."""
+def _run_world_datasets(filenames, num_epochs, num_reducers, world, tph,
+                        seed, batch_size, spill_dir):
+    """The same world through the path a trainer uses: every host makes
+    its batch queue and shuffle, every local rank drains its stream
+    through a ``ShufflingDataset`` on that queue. One epoch at a time
+    (the throttle engages) and an in-flight budget of one byte, so that
+    every reducer output takes the spill tier. Returns
+    ``{global trainer: {epoch: [key, ...]}}``."""
+    from ray_shuffling_data_loader_tpu.dataset import ShufflingDataset
+
+    transports = tp.create_local_transports(world, recv_timeout_s=120.0)
+    results = {}
+    errors = []
+
+    def consume_rank(host_id, local_rank, queue, result):
+        try:
+            ds = ShufflingDataset(
+                filenames, num_epochs, num_trainers=tph,
+                batch_size=batch_size, rank=local_rank, batch_queue=queue,
+                shuffle_result=result if local_rank == 0 else None,
+                drop_last=False)
+            for epoch in range(num_epochs):
+                ds.set_epoch(epoch)
+                keys = [k for table in ds
+                        for k in table.column("key").to_pylist()]
+                results.setdefault(host_id * tph + local_rank,
+                                   {})[epoch] = keys
+        except BaseException as e:  # noqa: BLE001 - surfaced to the test
+            errors.append((host_id, e))
+
+    def host_main(host_id):
+        try:
+            queue, result = dist.create_distributed_batch_queue_and_shuffle(
+                filenames, num_epochs, num_reducers=num_reducers,
+                transport=transports[host_id], trainers_per_host=tph,
+                max_concurrent_epochs=1, seed=seed, queue_name=None,
+                num_workers=2, file_cache=None, max_inflight_bytes=1,
+                spill_dir=spill_dir)
+            ranks = [threading.Thread(
+                target=consume_rank, args=(host_id, r, queue, result),
+                daemon=True) for r in range(tph)]
+            for t in ranks:
+                t.start()
+            for t in ranks:
+                t.join(timeout=120)
+                assert not t.is_alive(), f"host {host_id} consumer hung"
+            result.result()
+            queue.shutdown()
+        except BaseException as e:  # noqa: BLE001 - surfaced to the test
+            errors.append((host_id, e))
+
+    threads = [threading.Thread(target=host_main, args=(h,), daemon=True)
+               for h in range(world)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=180)
+    # Closed before the liveness check: a host that died early leaves its
+    # peers in recv, and the real error would hide behind "hung".
+    for t in transports:
+        t.close()
+    if errors:
+        raise errors[0][1]
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive(), "distributed shuffle hung"
+    return results
+
+
+@pytest.mark.parametrize("through", ["refs", "spilled_datasets"])
+def test_distributed_world4_tph2_multiepoch_bit_exact(small_dataset,
+                                                      tmp_path, through):
+    """The widest topology: world=4 with trainers_per_host=2 (8 global
+    trainers), 2 epochs, 10 reducers split unevenly over the 8 trainers —
+    every stream bit-identical to the single-host num_trainers=8 shuffle,
+    as the consumer's refs and through each rank's dataset on its host's
+    batch queue with every reducer output spilled on the way."""
+    from ray_shuffling_data_loader_tpu import spill as spill_mod
+
     filenames = small_dataset
     num_epochs, num_reducers, world, tph, seed = 2, 10, 4, 2, 41
-    distributed = _run_world(filenames, num_epochs, num_reducers, world,
-                             seed=seed, trainers_per_host=tph)
+    if through == "refs":
+        distributed = _run_world(filenames, num_epochs, num_reducers, world,
+                                 seed=seed, trainers_per_host=tph)
+    else:
+        # asked of the managers' totals, not of a log line
+        spills_before, _ = spill_mod.process_spill_totals()
+        distributed = _run_world_datasets(
+            filenames, num_epochs, num_reducers, world, tph, seed,
+            batch_size=125, spill_dir=str(tmp_path / "spill"))
+        spills, spilled_bytes = spill_mod.process_spill_totals()
+        assert spills - spills_before >= 1, (
+            "max_inflight_bytes=1 with a spill_dir and no manager recorded "
+            f"a spilled reducer output ({spills} spills, {spilled_bytes} B)")
 
     collected = {}
 
@@ -238,6 +323,7 @@ def test_distributed_world4_tph2_multiepoch_bit_exact(small_dataset):
     run_shuffle(filenames, consumer, num_epochs, num_reducers,
                 num_trainers=world * tph, max_concurrent_epochs=2,
                 seed=seed, collect_stats=False)
+    assert len(collected) == world * tph * num_epochs
     for (trainer, epoch), refs in collected.items():
         keys = []
         for ref in refs:

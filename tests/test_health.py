@@ -418,19 +418,35 @@ def test_capture_incident_layout_and_cooldown(tmp_path, monkeypatch):
                                       profile_s=0.0, wait_s=0.0) is None
 
 
+#: The droop's run a backend: the thread backend's delay fires in this
+#: process; the process backend's in the pool's workers (they take the
+#: spec from their environment), and its epochs 0-1 set the baseline,
+#: spawn included.
+_DROOP_RUNS = {
+    "thread": dict(interval_s=0.05, files=3, epochs=3, delayed=(1, 2),
+                   delay_ms=1000, workers=None),
+    "process": dict(interval_s=0.1, files=4, epochs=5, delayed=(2, 3, 4),
+                    delay_ms=1500, workers=2),
+}
+
+
+@pytest.mark.parametrize("backend", sorted(_DROOP_RUNS))
 def test_chaos_delay_to_detector_to_capsule_end_to_end(tmp_path, rng,
-                                                       monkeypatch):
-    """The dryrun scene's in-process twin (thread backend): an injected
-    reduce_gather delay droops the activity rate mid-run, the armed
-    detector fires, and the auto-captured capsule parses through
-    tools/rsdl_incident.py."""
+                                                       monkeypatch, backend):
+    """An injected reduce_gather delay droops the activity rate mid-run,
+    the armed detector fires, and the auto-captured capsule parses through
+    tools/rsdl_incident.py as an operator would read it. On the process
+    backend the capsule's trace dumps span the driver and the pool
+    workers it SIGUSR1'd. (reduce_gather, not map_read: the process
+    backend's segment cache skips the decode after epoch 0.)"""
     import pyarrow as pa
     import pyarrow.parquet as pq
     from ray_shuffling_data_loader_tpu.runtime import faults as rt_faults
     from ray_shuffling_data_loader_tpu.shuffle import shuffle as run_shuffle
 
+    run = _DROOP_RUNS[backend]
     files = []
-    for i in range(3):
+    for i in range(run["files"]):
         n = 64
         path = str(tmp_path / f"e2e_{i}.parquet")
         pq.write_table(pa.table({
@@ -440,23 +456,27 @@ def test_chaos_delay_to_detector_to_capsule_end_to_end(tmp_path, rng,
     monkeypatch.setenv("RSDL_INCIDENT_DIR", str(tmp_path / "inc"))
     monkeypatch.setenv("RSDL_TRACE_DIR", str(tmp_path / "trace"))
     os.makedirs(str(tmp_path / "trace"), exist_ok=True)
+    # The detector compares rates smoothed over window_ticks x interval_s:
+    # only a silence longer than that window (plus fire_ticks) can read
+    # as a droop, so the injected delay has to outlast it.
+    spec = ",".join(f"reduce_gather:epoch{e}:delay{run['delay_ms']}"
+                    for e in run["delayed"])
+    monkeypatch.setenv("RSDL_CHAOS_SPEC", spec)     # a pool's workers
     rt_telemetry.configure()
     monitor = rt_health.arm(
-        interval_s=0.05, capacity=600, detectors=("throughput_droop",),
+        interval_s=run["interval_s"], capacity=600,
+        detectors=("throughput_droop",),
         fire_ticks=2, clear_ticks=50, incident_dir=str(tmp_path / "inc"),
         slo_droop_window_ticks=8, slo_droop_floor_eps=2.0)
     assert monitor is not None
-    # The detector compares rates smoothed over window_ticks x interval_s
-    # = 0.4 s: only a silence longer than that window (plus fire_ticks)
-    # can read as a droop, so the injected delay has to outlast it.
-    rt_faults.install("reduce_gather:epoch1:delay1000,"
-                      "reduce_gather:epoch2:delay1000", seed=0)
+    rt_faults.install(spec, seed=0)                 # this process's threads
     try:
         run_shuffle(files, lambda ti, e, refs: [r.result() for r in refs]
                     if refs is not None else None,
-                    3, num_reducers=3, num_trainers=1,
+                    run["epochs"], num_reducers=len(files), num_trainers=1,
                     max_concurrent_epochs=1, seed=7, collect_stats=False,
-                    file_cache=None, executor_backend="thread")
+                    file_cache=None, executor_backend=backend,
+                    num_workers=run["workers"])
         capsules = monitor.wait_captures(timeout_s=30.0)
     finally:
         rt_faults.clear()
@@ -471,7 +491,8 @@ def test_chaos_delay_to_detector_to_capsule_end_to_end(tmp_path, rng,
     assert out.returncode == 0, out.stderr
     incident = json.loads(out.stdout)
     assert incident["verdict"]["detector"] == "throughput_droop"
-    assert incident["pids"], incident
+    assert len(incident["pids"]) >= (2 if backend == "process" else 1), (
+        f"the capsule's trace dumps span only {incident['pids']}")
     assert incident["activity_rates"], "capsule history slice is empty"
 
 

@@ -468,32 +468,57 @@ class TestMemberChaosSites:
 
 class TestElasticResize:
 
-    def test_shrink_recomputes_and_grow_is_bit_identical(self, tmp_path):
+    @pytest.mark.parametrize("num_reducers, crashed, newcomer", [
+        (6, 2, 7),
+        (8, 1, 4)])     # two reducers a rank: the growth leaves 4 x 2
+    def test_shrink_recomputes_and_grow_is_bit_identical(
+            self, tmp_path, num_reducers, crashed, newcomer):
         files = _make_files(str(tmp_path / "data"))
         fixed = me.ElasticShuffleRunner(
-            files, 6, seed=11,
+            files, num_reducers, seed=11,
             manager=mem.MembershipManager([0, 1, 2, 3])).run(2)
 
-        rt_faults.install("member_crash:rank2:epoch0", seed=0)
-        manager = mem.MembershipManager([0, 1, 2, 3])
-        runner = me.ElasticShuffleRunner(files, 6, seed=11,
+        survivors = tuple(r for r in range(4) if r != crashed)
+        journal_path = str(tmp_path / "membership.journal")
+        rt_faults.install(f"member_crash:rank{crashed}:epoch0", seed=0)
+        manager = mem.MembershipManager([0, 1, 2, 3],
+                                        journal_path=journal_path)
+        runner = me.ElasticShuffleRunner(files, num_reducers, seed=11,
                                          manager=manager)
         epoch0 = runner.run_epoch(0)
-        assert manager.current_view().ranks == (0, 1, 3)
+        assert manager.current_view().ranks == survivors
         assert runner.last_stats["recomputed"] >= 1
         assert runner.last_stats["resize_stall_ms"] > 0.0
-        # Grow past the original world at the boundary: rejoin plus a
-        # brand-new rank -> an uneven 5-rank world.
-        manager.member_join(2)
-        manager.member_join(7)
+        # Grow past the original world at the boundary: the dead rank
+        # rejoins at a bumped incarnation, plus a brand-new rank -> an
+        # uneven 5-rank world.
+        manager.member_join(crashed)
+        manager.member_join(newcomer)
         epoch1 = runner.run_epoch(1)
-        assert manager.current_view().ranks == (0, 1, 2, 3, 7)
+        grown = manager.current_view()
+        assert grown.ranks == tuple(sorted(survivors + (crashed, newcomer)))
+        assert grown.incarnation(crashed) == 1
+        manager.close()
         rt_faults.clear()
+        hosts = list(plan_ir.reduce_placement(num_reducers,
+                                              grown.ranks).values())
+        assert len({hosts.count(r) for r in grown.ranks}) > 1, hosts
 
-        # Placement moved; CONTENT did not (lineage purity).
-        assert all(a.equals(b) for a, b in zip(fixed[0], epoch0))
-        assert all(a.equals(b) for a, b in zip(fixed[1], epoch1))
+        # Placement moved; CONTENT did not (lineage purity), and every
+        # row arrives once in either epoch.
+        for before, after in zip(fixed, (epoch0, epoch1)):
+            assert len(before) == len(after) == num_reducers
+            assert all(a.equals(b) for a, b in zip(before, after))
+            assert sorted(k for table in after
+                          for k in table.column("key").to_pylist()) == \
+                list(range(3 * 64))
         assert me.total_rows(epoch0) == me.total_rows(fixed[0])
+
+        # The views this run went through, chaos verdict included, replay
+        # from the journal to the live view, byte for byte.
+        assert mem.replay(journal_path) == grown
+        with open(journal_path, "rb") as f:
+            assert manager.journal.journal_bytes() == f.read()
 
     def test_every_rank_dead_driver_backstop_completes(self, tmp_path):
         files = _make_files(str(tmp_path / "data"), num_files=2)

@@ -103,6 +103,26 @@ def test_frame_corrupt_nacked_and_resent():
     assert delta["queue_frames_replayed"] >= 1
 
 
+def test_reset_on_one_queue_and_corrupt_frame_on_another_in_one_session():
+    """Both wire faults against one server and one consumer: the reset
+    tears queue 0's response, the corrupt byte lands in queue 1's; the
+    consumer reads both queues whole, in order, through the reconnect."""
+    queue = mq.MultiQueue(2)
+    for queue_idx in range(2):
+        for i in range(12):
+            queue.put(queue_idx, pa.table({"seq": [100 * queue_idx + i]}))
+        queue.put(queue_idx, None)
+    rt_faults.install(
+        "conn_reset_midframe:task0:after1,frame_corrupt:task1:after2",
+        seed=0)
+    with svc.serve_queue(queue) as server:
+        with svc.RemoteQueue(server.address, retries=12,
+                             max_batch=2) as remote:
+            for queue_idx in range(2):
+                assert _drain_remote(remote, queue_idx) == [
+                    100 * queue_idx + i for i in range(12)]
+
+
 def test_ack_lost_is_harmless():
     """Acks are cumulative: suppressing one GET's watermark changes
     nothing about delivery."""
@@ -420,6 +440,8 @@ def _kill9_scenario(tmp_parquet_dir, rows, epochs, reducers, seed,
     filenames, _ = dg.generate_data_local(rows, 2, 1, 0.0, tmp_parquet_dir)
     expected = _reference_streams(filenames, epochs, reducers, seed)
     journal = os.path.join(tmp_parquet_dir, "watermarks.wal")
+    restarts_before = rsdl_stats.process_recovery_totals()[
+        "queue_server_restarts"]
     supervisor, address = rt_sup.launch_supervised_queue_server(dict(
         filenames=filenames, num_epochs=epochs, num_trainers=1,
         num_reducers=reducers, seed=seed, max_concurrent_epochs=1,
@@ -431,6 +453,9 @@ def _kill9_scenario(tmp_parquet_dir, rows, epochs, reducers, seed,
     finally:
         supervisor.stop()
     assert supervisor.restarts >= len(kill_points)
+    # the deaths reach the process's own totals (the trial report's row)
+    assert rsdl_stats.process_recovery_totals()["queue_server_restarts"] \
+        >= restarts_before + len(kill_points)
     assert got == expected, {
         epoch: (len(got[epoch]), len(expected[epoch]))
         for epoch in expected}

@@ -91,6 +91,50 @@ def test_program_imports_nothing_above_it(subpackage):
 
 
 # ---------------------------------------------------------------------------
+# The dry run runs the sharded steps and stops
+# ---------------------------------------------------------------------------
+
+#: What ``__graft_entry__.py`` may import of the package: the sharded path
+#: (models and ops over parallel's mesh and trainer, fed by jax_dataset from
+#: files data_generation could have written) and what the loader-fed step
+#: reads of its supervision: the watchdog, its counters, the exposition.
+#: A control plane's scene belongs to that plane's file under tests/.
+DRY_RUN_IMPORTS = frozenset({
+    "models", "parallel", "ops", "jax_dataset", "data_generation",
+    "stats", "runtime.watchdog", "runtime.metrics", "runtime.telemetry"})
+
+
+def _package_imports(path):
+    """``(dotted name under the package, line)`` of every name ``path``
+    imports from it: ``from pkg.runtime import health`` gives
+    ``runtime.health``, ``from pkg.models.dlrm import init``
+    ``models.dlrm.init``."""
+    for node in ast.walk(ast.parse(_read(path), filename=path)):
+        if isinstance(node, ast.ImportFrom) and node.level == 0 and \
+                (node.module or "").split(".")[0] == PACKAGE:
+            under = (node.module or "").split(".")[1:]
+            for alias in node.names:
+                yield ".".join(under + [alias.name]), node.lineno
+        elif isinstance(node, ast.Import):
+            for alias in node.names:
+                if alias.name.split(".")[0] == PACKAGE:
+                    yield ".".join(alias.name.split(".")[1:]), node.lineno
+
+
+def test_the_dry_run_imports_only_the_sharded_path():
+    path = os.path.join(REPO_ROOT, "__graft_entry__.py")
+    found = list(_package_imports(path))
+    assert found, "the dry run imports nothing of the package"
+    beyond = [f"__graft_entry__.py:{line} imports {module}"
+              for module, line in found
+              if not any(module == allowed or module.startswith(allowed + ".")
+                         for allowed in DRY_RUN_IMPORTS)]
+    assert not beyond, (
+        "the dry run holds the steps that depend on the mesh; a control "
+        "plane's scene goes to its own test file:\n" + "\n".join(beyond))
+
+
+# ---------------------------------------------------------------------------
 # Documents name only files that exist
 # ---------------------------------------------------------------------------
 
@@ -211,8 +255,6 @@ NAMES_OUTSIDE_POLICY = {
     "RSDL_LOCKSAN_OUT": "runtime/locksan.py, where the order graph goes",
     "RSDL_LOCKSAN_SLOW_MS": "runtime/locksan.py",
     "RSDL_LOCKSAN_SUITE": "format.sh, the archival run of the suite",
-    "RSDL_PROFILE_DIR": "utils/tracing.py maybe_profile",
-    "RSDL_PROFILE_FOLDED": "runtime/profiler.py maybe_sample",
     "RSDL_TELEMETRY_SIGUSR1": "runtime/telemetry.py, at import",
     "RSDL_TPU_DISABLE_NATIVE": "native/__init__.py, native/image.py",
     "RSDL_TPU_LOG_LEVEL": "utils/logger.py",
@@ -224,7 +266,7 @@ _NAME_RE = re.compile(r"\bRSDL_[A-Z0-9_]*[A-Z0-9](?![A-Z0-9_*<])")
 
 #: Histories speak of names long gone; SURVEY.md speaks of the reference.
 _HISTORIES = frozenset({"PERF.md", "CHANGES.md", "ROADMAP.md", "ISSUE.md",
-                        "ADVICE.md", "PERF_LEDGER.jsonl", "SURVEY.md"})
+                        "PERF_LEDGER.jsonl", "SURVEY.md"})
 
 
 def _rsdl_names():

@@ -518,6 +518,43 @@ def _expected_rank_streams(config):
             for key, refs in streams.items()}
 
 
+def _kill9_mid_stream_then_rerun(first_args, rerun_args, out_path,
+                                 after_lines, done):
+    """Start a consumer process, kill -9 it once it has written
+    ``after_lines`` lines to ``out_path``, then run a fresh process to its
+    end (it must print ``done``)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    first = subprocess.Popen(first_args, cwd=REPO_ROOT, env=env,
+                             stdout=subprocess.PIPE, text=True)
+    deadline = time.monotonic() + 120
+    while time.monotonic() < deadline:
+        if os.path.exists(out_path) and \
+                sum(1 for _ in open(out_path)) >= after_lines:
+            break
+        time.sleep(0.05)
+    os.kill(first.pid, signal.SIGKILL)
+    first.wait(timeout=30)
+    assert first.returncode == -9
+    second = subprocess.run(rerun_args, cwd=REPO_ROOT, env=env,
+                            capture_output=True, text=True, timeout=240)
+    assert second.returncode == 0, second.stderr[-3000:]
+    assert done in second.stdout
+
+
+def _merged_positions(out_path):
+    """``{(epoch, position): keys}`` of the ``epoch:position:keys`` lines
+    two consumer processes appended; a position written twice (the
+    at-least-once replay across the crash) must be IDENTICAL."""
+    merged = {}
+    for line in open(out_path):
+        epoch_str, position_str, keys = line.strip().split(":", 2)
+        position = (int(epoch_str), int(position_str))
+        table = tuple(int(k) for k in keys.split(",") if k)
+        assert merged.setdefault(position, table) == table, \
+            f"the replay at {position} diverged across the crash"
+    return merged
+
+
 _STREAM_TRAINER_CODE = """
 import sys
 from ray_shuffling_data_loader_tpu import checkpoint as ckpt
@@ -596,41 +633,16 @@ def test_stream_trainer_kill9_mid_window_resume_exactly_once(
         host, port = address
         args = [sys.executable, "-c", _STREAM_TRAINER_CODE, host,
                 str(port), ckpt_path, out_path, str(seed), str(epochs)]
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        first = subprocess.Popen(args, cwd=REPO_ROOT, env=env,
-                                 stdout=subprocess.PIPE, text=True)
         # Kill mid-window-0: after a couple of its ~5 batches land.
-        deadline = time.monotonic() + 120
-        while time.monotonic() < deadline:
-            if os.path.exists(out_path) and \
-                    sum(1 for _ in open(out_path)) >= 2:
-                break
-            time.sleep(0.05)
-        os.kill(first.pid, signal.SIGKILL)
-        first.wait(timeout=30)
-        assert first.returncode == -9
-
-        second = subprocess.run(args, cwd=REPO_ROOT, env=env,
-                                capture_output=True, text=True,
-                                timeout=240)
-        assert second.returncode == 0, second.stderr[-3000:]
-        assert "TRAINER DONE" in second.stdout
+        _kill9_mid_stream_then_rerun(args, args, out_path, after_lines=2,
+                                     done="TRAINER DONE")
     finally:
         supervisor.stop()
 
-    # Offset accounting: merge by (epoch, batch offset); a position seen
-    # twice (the at-least-once replay across the crash) must be
-    # IDENTICAL, and the deduped positions must cover the fault-free
-    # grid exactly — zero missed, zero duplicated.
-    merged = {}
-    for line in open(out_path):
-        epoch_str, index_str, keys = line.strip().split(":", 2)
-        position = (int(epoch_str), int(index_str))
-        batch = tuple(int(k) for k in keys.split(",") if k)
-        if position in merged:
-            assert merged[position] == batch, \
-                f"replayed batch {position} diverged across the crash"
-        merged[position] = batch
+    # Offset accounting: merged by (epoch, batch offset), the deduped
+    # positions must cover the fault-free grid exactly — zero missed,
+    # zero duplicated.
+    merged = _merged_positions(out_path)
     for epoch in range(epochs):
         batches = [merged[(epoch, i + 1)]
                    for i in range(len(expected[epoch]))]
@@ -638,6 +650,73 @@ def test_stream_trainer_kill9_mid_window_resume_exactly_once(
             f"window-epoch {epoch} diverged from the fault-free grid"
     assert len(merged) == sum(len(v) for v in expected.values()), \
         "positions outside the fault-free grid were delivered"
+
+
+_POSITIONED_CONSUMER_CODE = """
+import sys
+import time
+from ray_shuffling_data_loader_tpu import multiqueue_service as svc
+from ray_shuffling_data_loader_tpu.plan import ir as plan_ir
+
+host, port, out_path, epochs, pace_s = sys.argv[1:6]
+# manual ack, never committed: the whole stream replays to a successor
+remote = svc.RemoteQueue((host, int(port)), retries=12, max_batch=2,
+                         ack_mode="manual", consumer_id=88)
+with open(out_path, "a") as out:
+    for epoch in plan_ir.epoch_range(0, int(epochs)):
+        queue_idx = plan_ir.queue_index(epoch, 0, 1)
+        while True:
+            item, row_offset = remote.get_positioned(queue_idx)
+            if item is None:
+                break
+            keys = ",".join(str(k) for k in
+                            item.column("key").to_pylist())
+            out.write(f"{epoch}:{row_offset}:{keys}\\n")
+            out.flush()
+            time.sleep(float(pace_s))    # a step a table: the kill lands
+remote.close()
+print("CONSUMER DONE")
+"""
+
+
+def test_stream_consumer_kill9_uncommitted_replays_the_same_row_offsets(
+        tmp_parquet_dir):
+    """The frames' own positions across a consumer's death: a consumer
+    that reads ``get_positioned`` under manual ack and commits nothing is
+    kill -9'd in the middle of the stream, and a fresh process under its
+    identity reads on. Merged by (window-epoch, row_offset), what the two
+    wrote is the fault-free lineage at the rows each table starts from:
+    the same offsets, the same tables at each, every row exactly once."""
+    seed, rows = 29, 64
+    files = _make_stream_files(tmp_parquet_dir, 6, rows=rows,
+                               prefix="positioned")
+    config = _streaming_server_config(files, tmp_parquet_dir,
+                                      num_trainers=1, num_reducers=3,
+                                      seed=seed)
+    epochs = len(config["epochs"])
+    expected = {}
+    for (_, epoch), tables in _expected_rank_streams(config).items():
+        row_offset = 0
+        for keys in tables:
+            expected[(epoch, row_offset)] = keys
+            row_offset += len(keys)
+
+    out_path = os.path.join(tmp_parquet_dir, "delivered.txt")
+    supervisor, address = rt_sup.launch_supervised_queue_server(config)
+    try:
+        assert rt_sup.wait_for_server(address, timeout_s=60)
+        args = [sys.executable, "-c", _POSITIONED_CONSUMER_CODE,
+                address[0], str(address[1]), out_path, str(epochs)]
+        # paced into window 1, then read on at full speed
+        _kill9_mid_stream_then_rerun(args + ["0.2"], args + ["0"], out_path,
+                                     after_lines=4, done="CONSUMER DONE")
+    finally:
+        supervisor.stop()
+
+    merged = _merged_positions(out_path)
+    assert merged == expected, sorted(set(merged) ^ set(expected))[:5]
+    assert sorted(k for table in merged.values() for k in table) == \
+        list(range(6 * rows)), "rows missed or invented across the kill"
 
 
 def test_stream_shard_kill9_at_window_boundary_replays_bit_identical(

@@ -213,18 +213,23 @@ def test_perfetto_export_valid_with_consistent_pid_tid(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_delay_chaos_straggler_ranked_first(tmp_path):
+def _three_key_files(tmp_path, rows):
     import pyarrow.parquet as pq
-
-    from ray_shuffling_data_loader_tpu.shuffle import shuffle as run_shuffle
 
     files = []
     for i in range(3):
         path = str(tmp_path / f"part_{i}.parquet")
-        pq.write_table(pa.table({"key": pa.array(range(i * 32,
-                                                       (i + 1) * 32))}),
+        pq.write_table(pa.table({"key": pa.array(range(i * rows,
+                                                       (i + 1) * rows))}),
                        path)
         files.append(path)
+    return files
+
+
+def test_delay_chaos_straggler_ranked_first(tmp_path):
+    from ray_shuffling_data_loader_tpu.shuffle import shuffle as run_shuffle
+
+    files = _three_key_files(tmp_path, 32)
     telemetry.configure(enabled_flag=True)
     rt_faults.install("map_read:file1:delay300", seed=0)
     try:
@@ -246,6 +251,65 @@ def test_delay_chaos_straggler_ranked_first(tmp_path):
         "stragglers"][:3]
     assert analysis["critical_path"][0]["stage"] in ("map_read", "reduce")
     assert analysis["whatif"]["map_read"]["epoch_time_saved_pct"] > 0
+
+
+def test_delay_chaos_straggler_ranked_first_across_processes(tmp_path,
+                                                            monkeypatch):
+    """The same straggler where the producer is another process: a
+    supervised queue server shuffles under the delay (the spec rides its
+    environment) and this process consumes over the wire. The child dumps
+    its recorder on SIGTERM -> atexit, this process dumps its own, the two
+    merge into one clock, and the merged analysis names the producer's
+    stage and the child's delayed task; the Perfetto export keeps both
+    pids."""
+    import glob
+
+    from ray_shuffling_data_loader_tpu.runtime import supervisor as rt_sup
+
+    files = _three_key_files(tmp_path, 64)
+    trace_dir = tmp_path / "trace"
+    trace_dir.mkdir()
+    monkeypatch.setenv("RSDL_TRACE_DIR", str(trace_dir))
+    # The delay has to dominate the child's first Parquet read, which
+    # lands on whichever file maps first: 0.3-0.6 s of pyarrow warm-up on
+    # an idle host, 1.6 s seen beside five other test workers. The child
+    # maps on threads: a pool's workers would each pay that cold start
+    # beside the delay.
+    monkeypatch.setenv("RSDL_CHAOS_SPEC", "map_read:file1:delay4000")
+    monkeypatch.setenv("RSDL_EXECUTOR_BACKEND", "thread")
+    telemetry.configure(enabled_flag=True)   # a fresh ring, dumped there
+    seed, epochs = 17, 1
+    supervisor, address = rt_sup.launch_supervised_queue_server(dict(
+        filenames=files, num_epochs=epochs, num_trainers=1, num_reducers=2,
+        seed=seed, max_concurrent_epochs=1,
+        journal_path=str(tmp_path / "trace.wal"), file_cache=None))
+    rows = 0
+    try:
+        assert rt_sup.wait_for_server(address, timeout_s=60)
+        with svc.RemoteQueue(address, retries=12, max_batch=2) as remote:
+            for queue_idx in range(epochs):
+                while (item := remote.get(queue_idx)) is not None:
+                    rows += item.num_rows
+    finally:
+        supervisor.stop()       # SIGTERM: the child's atexit dump
+    assert rows == epochs * 3 * 64
+    telemetry.dump(reason="test")
+    dumps = sorted(glob.glob(str(trace_dir / "*.jsonl")))
+    merged = rt_trace.merge_dumps(dumps)
+    pids = {m["pid"] for m in merged["processes"]}
+    assert os.getpid() in pids and len(pids) >= 2, (sorted(pids), dumps)
+    analysis = rt_trace.analyze(merged["events"])
+    top = analysis["stragglers"][0]
+    assert (top["stage"], top["task"]) == ("map_read", 1), analysis[
+        "stragglers"][:3]
+    assert analysis["critical_path"][0]["stage"] in ("map_read", "reduce")
+    assert analysis["whatif"]
+    perfetto = json.loads(json.dumps(rt_trace.to_perfetto(merged,
+                                                          seed=seed)))
+    events = perfetto["traceEvents"]
+    assert events and all(isinstance(e.get("pid"), int)
+                          and isinstance(e.get("tid"), int) for e in events)
+    assert pids <= {e["pid"] for e in events}
 
 
 # ---------------------------------------------------------------------------
@@ -384,18 +448,17 @@ def test_profiler_folds_named_thread_stacks_and_bills_stage():
         assert isinstance(profiler.cpu_by_thread(), dict)
 
 
-def test_profiler_write_folded_and_maybe_sample(tmp_path, monkeypatch):
+def test_profiler_write_folded_makes_the_directory_and_the_file(tmp_path):
     folded_path = str(tmp_path / "prof" / "stacks.folded")
-    monkeypatch.setenv("RSDL_PROFILE_FOLDED", folded_path)
-    with rt_profiler.maybe_sample() as prof:
-        assert prof is not None
-        deadline = time.monotonic() + 2.0
-        while prof.samples < 3 and time.monotonic() < deadline:
-            time.sleep(0.01)
-    assert os.path.exists(folded_path)
-    monkeypatch.delenv("RSDL_PROFILE_FOLDED")
-    with rt_profiler.maybe_sample() as prof:
-        assert prof is None  # off by default: zero overhead
+    prof = rt_profiler.SamplingProfiler(interval_s=0.005).start()
+    deadline = time.monotonic() + 2.0
+    while prof.samples < 3 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    prof.stop()
+    prof.write_folded(folded_path)
+    with open(folded_path) as f:
+        lines = f.read().splitlines()
+    assert lines and all(line.rsplit(" ", 1)[1].isdigit() for line in lines)
 
 
 # ---------------------------------------------------------------------------

@@ -101,12 +101,6 @@ def test_step_span_context():
         pass
 
 
-def test_maybe_profile_disabled(monkeypatch):
-    monkeypatch.delenv("RSDL_PROFILE_DIR", raising=False)
-    with tracing.maybe_profile():
-        pass
-
-
 def test_profile_trace_captures_pipeline(tmp_path, tmp_parquet_dir,
                                          monkeypatch):
     """A traced end-to-end pipeline run writes profiler artifacts and the
