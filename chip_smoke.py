@@ -13,9 +13,9 @@ full width of one model the repo supports, and checks what comes out:
    (``ops/moe.py``) against its float32 loop of dense products at the
    decoder's widths, its rows moved by DMA (a tile's fetch and a round's
    combine, each also alone: equal to XLA's gather); the state-space
-   scan's, the selective scan's and the mixers' convolution's kernels
-   against XLA's paths, every gradient. Checked, not timed: the
-   benchmark reads the same kernels in the step;
+   scan's, the selective scan's, the mixers' convolution's and the gated
+   short convolution's kernels against XLA's paths, every gradient.
+   Checked, not timed: the benchmark reads the same kernels in the step;
 2. loader -> device feed -> train step: seeded DLRM Parquet
    (``data_generation.generate_data``) through ``JaxShufflingDataset`` at
    library defaults into ``parallel.trainer.SpmdTrainer`` over
@@ -99,6 +99,8 @@ class SmokeSize:
     sscan_shapes: Tuple[Tuple[int, int, int, int, int], ...]
     # the mixers' depthwise convolution: (rows, positions, channels, taps)
     conv_shapes: Tuple[Tuple[int, int, int, int], ...]
+    # a ``conv`` layer's gated short convolution: the same four
+    sconv_shapes: Tuple[Tuple[int, int, int, int], ...]
     epochs: int = 2
 
 
@@ -126,7 +128,9 @@ def full_size() -> SmokeSize:
         # phi4flash_train_8k's two Mamba-1 layers
         sscan_shapes=((1, 8192, 5120, 16, 64),),
         # granite_train_8k's xBC and phi4flash_train_8k's u
-        conv_shapes=((1, 8192, 4352, 4), (1, 8192, 5120, 4)))
+        conv_shapes=((1, 8192, 4352, 4), (1, 8192, 5120, 4)),
+        # lfm2_train_8k's four conv operators
+        sconv_shapes=((2, 8192, 2048, 3),))
 
 
 def tiny_size() -> SmokeSize:
@@ -146,7 +150,7 @@ def tiny_size() -> SmokeSize:
                     (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
         ssd_shapes=((1, 256, 2, 64, 128, 128),),
         sscan_shapes=((1, 32, 1024, 4, 8),),
-        conv_shapes=((2, 64, 256, 4),))
+        conv_shapes=((2, 64, 256, 4),), sconv_shapes=((2, 64, 256, 3),))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -285,6 +289,8 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
         _check_sscan(shape, interpret)
     for shape in size.conv_shapes:
         _check_conv(shape, interpret)
+    for shape in size.sconv_shapes:
+        _check_sconv(shape, interpret)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
@@ -609,6 +615,33 @@ def _check_conv(shape: Tuple[int, ...], interpret: bool,
             else ssd.conv_silu),
         (1, 2), ssd.convs_in_vmem(seq, channels, taps, x.dtype), interpret,
         tol)
+
+
+def _check_sconv(shape: Tuple[int, ...], interpret: bool,
+                 tol: float = 2e-2) -> None:
+    """A ``conv`` layer's gated short convolution, ``C * conv(B * u)``, by
+    its kernels (a block of ``B | C | u`` read once each way, ``d (B | C |
+    u)`` and the taps' sums in one backward pass) against XLA's pad,
+    shifted slices and autodiff, bf16 ``B | C | u``
+    (:func:`_check_scan_paths`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.ops import sconv
+
+    rows, seq, channels, taps = shape
+    keys = jax.random.split(jax.random.key(23), 3)
+    bcu = jax.random.normal(keys[0], (rows, seq, 3 * channels), jnp.bfloat16)
+    weight = jax.random.uniform(keys[1], (taps, channels), jnp.float32,
+                                -taps ** -0.5, taps ** -0.5)
+    _check_scan_paths(
+        f"gated short convolution {rows} x {seq} positions, {channels} "
+        f"channels, {taps} taps", "pad and slices", "y, d bcu, d w",
+        (bcu, weight), jax.random.normal(keys[2], (rows, seq, channels)),
+        lambda in_vmem: (sconv._gated_in_vmem if in_vmem
+                         else sconv.gated_conv),
+        (1, 2), sconv.convs_in_vmem(seq, channels, taps, bcu.dtype),
+        interpret, tol)
 
 
 # -- loader -> device feed -> train step -------------------------------------

@@ -1,9 +1,10 @@
 """A causal decoder whose layers mix window attention, full attention,
-Mamba-2 and Mamba-1 state-space mixers, Gated Memory Units and
-cross-attention over an earlier layer's keys and values, with dense or
-sparse-expert MLPs: one chip's share of a language model, by its
-configuration (``mellum2_ep4_share``, ``laguna_xs2_ep8_share``,
-``granite4_h_micro_period``, ``phi4_mini_flash_junction``).
+Mamba-2 and Mamba-1 state-space mixers, gated short convolutions, Gated
+Memory Units and cross-attention over an earlier layer's keys and values,
+with dense or sparse-expert MLPs: one chip's share of a language model, by
+its configuration (``mellum2_ep4_share``, ``laguna_xs2_ep8_share``,
+``granite4_h_micro_period``, ``phi4_mini_flash_junction``,
+``lfm2_24b_a2b_ep8_share``).
 
 Per layer, on the residual stream: RMSNorm, grouped-query attention (a
 layer's own count of query heads over ``num_kv_heads`` key/value heads,
@@ -55,6 +56,27 @@ RMSNorm a head pair, times ``1 - lambda_init`` (``published_indices``
 gives each layer's ``lambda_init``). ``norm`` ``"layer"`` is LayerNorm
 with a bias in place of every RMSNorm on the residual stream.
 
+A seventh ``layer_types`` entry and two switches (LFM2, Liquid AI 2025). A
+``conv`` layer's operator is a gated short convolution (``ops/sconv.py``):
+``W_in`` to ``B | C | u``, ``B * u``, a depthwise causal convolution of
+``conv_taps`` taps with neither bias nor activation, ``C *`` the result,
+``W_out``; no state and no scan. With ``qk_norm`` each q head and each k
+head passes an RMSNorm (one scale of ``head_dim`` for q, one for k) before
+it is rotated. With ``expert_bias`` the router scores by a sigmoid an
+expert, PICKS by score plus the leaf ``expert_bias`` (under
+``stop_gradient``: Adam leaves it where it finds it) and WEIGHS by the
+picks' scores alone over their sum (``ops/moe.py:route``); with an
+``expert_bias_speed`` the train step moves that leaf after the
+optimizer's update by its balancing update, the speed times the share of
+the mean load an expert fell short of it this step: down where more than
+the mean of the tokens picked it, up where fewer did
+(``utils/tracing.leaf_move``). ``router_trains``
+``False`` holds the router's matrix under ``stop_gradient`` too: a chip
+that holds a share of the experts sees the router's gradient through its
+own experts alone, and that part applied alone draws every token's picks
+onto them. ``yarn`` ``None`` gives a full layer plain rotary, as a window
+layer's.
+
 Under expert parallelism a chip holds ``experts_held = (first, count)`` of
 the router's ``num_experts`` and a slice of the vocabulary: the expert
 layer routes over all the experts and adds what the held ones give
@@ -101,13 +123,15 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_shuffling_data_loader_tpu.ops import (flash_attention, moe, on_tpu,
-                                               selective_scan, ssd)
+                                               sconv, selective_scan, ssd)
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 from ray_shuffling_data_loader_tpu.utils import tracing
 
 IGNORE_ID = -100
+#: The standard deviation of a seeded ``expert_bias`` (``init``).
+EXPERT_BIAS_STD = 0.002
 SLIDING, FULL, MAMBA = "sliding_attention", "full_attention", "mamba"
-MAMBA1, GMU, CROSS = "mamba1", "gmu", "cross"
+MAMBA1, GMU, CROSS, CONV = "mamba1", "gmu", "cross", "conv"
 DENSE, SPARSE = "dense", "sparse"
 RMS_NORM, LAYER_NORM = "rms", "layer"
 
@@ -119,12 +143,16 @@ RMS_NORM, LAYER_NORM = "rms", "layer"
 # A Mamba-1 mixer's four projections and a Gated Memory Unit's two are under
 # the first too; what lies between a Mamba-1 mixer's (the convolution, the
 # softplus, the selective scan, the gate: ``ops/selective_scan.py``) under
-# ``SSCAN_SCOPE``, a memory unit's ``silu(n W_1) * M`` under ``GMU_SCOPE``.
+# ``SSCAN_SCOPE``, a memory unit's ``silu(n W_1) * M`` under ``GMU_SCOPE``. A
+# ``conv`` operator's two projections are under the first as well, its two
+# gates and the convolution between them (``ops/sconv.py``) under
+# ``SCONV_SCOPE``.
 PROJ_SCOPE = "rsdl.lm.proj"
 ATTENTION_SCOPE = "rsdl.lm.attention"
 SSM_SCOPE = ssd.SCOPE
 SSCAN_SCOPE = selective_scan.SCOPE
 GMU_SCOPE = "rsdl.lm.gmu"
+SCONV_SCOPE = sconv.SCOPE
 MOE_SCOPE = moe.SCOPE
 MLP_SCOPE = "rsdl.lm.mlp"
 HEAD_SCOPE = "rsdl.lm.head"
@@ -162,6 +190,9 @@ class DecoderConfig:
     num_kv_heads: int = 4
     head_dim: int = 128
     attention_gate: bool = False  # a sigmoid gate a query head on the output
+    # RMSNorm over each q head and each k head before rotary, one scale of
+    # ``head_dim`` for q and one for k
+    qk_norm: bool = False
     sliding_window: int = 1024
     intermediate_size: int = 0    # a dense layer's SwiGLU width
     num_experts: int = 64                    # the router's width
@@ -170,12 +201,25 @@ class DecoderConfig:
     expert_width: int = 896
     shared_expert_width: int = 0  # 0: no shared expert
     routed_scale: float = 1.0     # what a token's routing weights sum to
+    # the router scores by sigmoids and picks under the leaf
+    # ``expert_bias`` (``ops/moe.py:route``); False: softmax, top-k
+    expert_bias: bool = False
+    # the selection bias's balancing update (auxiliary-loss-free load
+    # balancing, Wang et al. 2024, in its proportional form): after each
+    # step an expert's bias moves by this times (1 - its picks over the
+    # mean picks an expert), this step's tokens; 0: it stays where it was
+    expert_bias_speed: float = 0.0
+    # False: the router's matrix takes no gradient. Its gradient is a sum
+    # over every chip that shares the layer; one chip's part alone, which
+    # reaches it through the held experts only, moves the picks onto them.
+    router_trains: bool = True
     rms_norm_eps: float = 1e-6
     rope_theta: float = 500_000.0
     sliding_rope_theta: Optional[float] = None   # None: ``rope_theta``
     # the share of a head's dimensions a full layer rotates (the first)
     full_rotary_factor: float = 1.0
-    yarn: YarnConfig = YarnConfig()
+    # a full layer's rotary; None: plain, as a window layer's
+    yarn: Optional[YarnConfig] = YarnConfig()
     rotary: bool = True           # False: no positions at all ("nope")
     # the softmax's scale; None: 1 / sqrt(head_dim)
     attention_multiplier: Optional[float] = None
@@ -194,6 +238,7 @@ class DecoderConfig:
     mamba1_width: int = 0
     mamba1_state: int = 16
     mamba1_dt_rank: int = 0
+    conv_taps: int = 3            # a ``conv`` layer's depthwise taps
     norm: str = RMS_NORM          # LAYER_NORM: LayerNorm with a bias
     # every attention layer differential; then each layer's index in the
     # published model, which sets its ``lambda_init``
@@ -347,6 +392,43 @@ def phi4flash_tiny() -> DecoderConfig:
         mamba_chunk=8, **_PHI4FLASH_JUNCTION)
 
 
+_LFM2_LAYERS = dict(
+    layer_types=(CONV, FULL, CONV, CONV, CONV),
+    mlp_layer_types=(DENSE, SPARSE, SPARSE, SPARSE, SPARSE), qk_norm=True,
+    expert_bias=True, expert_bias_speed=0.02, router_trains=False,
+    yarn=None, tie_embeddings=True,
+    rms_norm_eps=1e-5, rope_theta=1_000_000.0, conv_taps=3,
+    published_layers=40)
+
+
+def lfm2_24b_a2b_ep8_share() -> DecoderConfig:
+    """LFM2-24B-A2B at its published widths, cut to one chip of eight that
+    share each layer by expert parallelism: 8 of the 64 experts of 1,536
+    (top 4, sigmoid scores picked under a selection bias), an eighth of
+    the 65,536-id vocabulary under the tied embedding, and the published
+    layers 1-5 of 40: the second dense layer (a gated short convolution,
+    SwiGLU of 11,776) and one period after it (full attention of 32:8
+    normed heads of 64 under plain rotary, three more convolutions, all
+    sparse)."""
+    return DecoderConfig(
+        vocab_size=8192, hidden_size=2048, num_heads=32, num_kv_heads=8,
+        head_dim=64, intermediate_size=11_776, num_experts=64,
+        experts_held=(0, 8), top_k=4, expert_width=1536, **_LFM2_LAYERS)
+
+
+def lfm2_tiny() -> DecoderConfig:
+    """For tests/CPU smoke runs: LFM2's five layers at 4:2 heads of 16, 8
+    experts of which the first two are held, top-2. The bias's balancing
+    update at a two-hundredth of the share's speed: at a few dozen picks
+    an expert one pick is 3 % of a load."""
+    return DecoderConfig(**{
+        **_LFM2_LAYERS, **dict(
+            vocab_size=512, hidden_size=64, num_heads=4, num_kv_heads=2,
+            head_dim=16, intermediate_size=128, num_experts=8,
+            experts_held=(0, 2), top_k=2, expert_width=32,
+            expert_bias_speed=1e-4)})
+
+
 def lambda_init(published_index: int) -> float:
     """A differential attention layer's ``lambda_init`` at its depth in
     the published model (Ye et al. 2024)."""
@@ -368,7 +450,14 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     +-1 / sqrt(taps). A Mamba-1 mixer (state-spaces/mamba's ``Mamba``):
     ``dt`` the same through ``dt_bias``, ``A[c, n] = n + 1``, ``D`` 1. A
     differential layer's four ``lambda`` vectors N(0, 0.1) and its head
-    pairs' norm scale 1. LayerNorm's biases 0."""
+    pairs' norm scale 1. LayerNorm's biases 0. A ``conv`` operator's taps
+    uniform in +-1 / sqrt(taps) (``Conv1d``'s default), the q and k heads'
+    norm scales 1. A sigmoid router's ``expert_bias`` N(0,
+    ``EXPERT_BIAS_STD``): a hundredth of the spread of the seeded scores,
+    which changes a pick of one token in seventeen and leaves the experts'
+    loads near even until the balancing update has them (at zero ``score
+    + bias`` and ``score`` pick alike, and nothing would show a pick that
+    left the bias out)."""
     h, f = config.hidden_size, config.expert_width
     kv_width = config.num_kv_heads * config.head_dim
     held = config.experts_held[1]
@@ -420,12 +509,22 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
                 "d": jnp.ones((width,), jnp.float32),
                 "out_proj": normal((width, h), residual)}
 
+    def conv_operator():
+        edge = 1.0 / math.sqrt(config.conv_taps)
+        return {"conv_norm": jnp.ones((h,), jnp.float32),
+                "in_proj": normal((h, 3 * h)),
+                "conv_w": uniform((config.conv_taps, h), -edge, edge),
+                "out_proj": normal((h, h), residual)}
+
     def attention(layer_type, q_width):
         lp = {"attn_norm": jnp.ones((h,), jnp.float32),
               "wq": normal((h, q_width))}
         if layer_type != CROSS:
             lp.update(wk=normal((h, kv_width)), wv=normal((h, kv_width)))
         lp["wo"] = normal((q_width, h), residual)
+        if config.qk_norm:
+            lp["q_layernorm"] = jnp.ones((config.head_dim,), jnp.float32)
+            lp["k_layernorm"] = jnp.ones((config.head_dim,), jnp.float32)
         if config.differential:
             for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
                 lp[name] = normal((config.head_dim,), 0.1)
@@ -450,6 +549,8 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
             lp = {"gmu_norm": jnp.ones((h,), jnp.float32),
                   "w1": normal((h, config.mamba1_width)),
                   "w2": normal((config.mamba1_width, h), residual)}
+        elif layer_type == CONV:
+            lp = conv_operator()
         else:
             lp = attention(layer_type, q_width)
             if config.attention_gate:
@@ -460,6 +561,9 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
         else:
             lp["moe_norm"] = jnp.ones((h,), jnp.float32)
             lp["router"] = normal((h, config.num_experts))
+            if config.expert_bias:
+                lp["expert_bias"] = normal((config.num_experts,),
+                                           EXPERT_BIAS_STD)
             lp.update(swiglu("", (held,), f))
             if config.shared_expert_width:
                 lp.update(swiglu("shared_", (), config.shared_expert_width))
@@ -483,8 +587,9 @@ def rotated_dims(config: DecoderConfig, layer_type: str) -> int:
 
 def rope_inv_freq(config: DecoderConfig, layer_type: str):
     """``(inverse frequencies (rotated dims / 2,), scale of cos and sin)``
-    of a layer's rotary positions: plain for a window layer, YaRN's for a
-    full one: the interpolated frequencies (divided by ``factor``) below
+    of a layer's rotary positions: plain for a window layer (and for a
+    full one of a configuration without ``yarn``), YaRN's for a full one:
+    the interpolated frequencies (divided by ``factor``) below
     ``beta_slow`` rotations over the original context, the extrapolated
     ones above ``beta_fast``, a linear ramp between."""
     dim = rotated_dims(config, layer_type)
@@ -492,9 +597,9 @@ def rope_inv_freq(config: DecoderConfig, layer_type: str):
     if layer_type != FULL and config.sliding_rope_theta is not None:
         theta = config.sliding_rope_theta
     pos_freqs = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    if layer_type != FULL:
-        return 1.0 / pos_freqs, 1.0
     yarn = config.yarn
+    if layer_type != FULL or yarn is None:
+        return 1.0 / pos_freqs, 1.0
 
     def correction_dim(rotations: float) -> float:
         return (dim * math.log(yarn.original_max_position_embeddings
@@ -839,8 +944,12 @@ def _swiglu_bwd(residuals, dy):
         du = (dh * act).astype(x.dtype)
         dg = (dh * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(x.dtype)
         dx = dg @ gate.astype(x.dtype).T + du @ up.astype(x.dtype).T
-        return (dx, weight_grad(x, dg), weight_grad(x, du),
-                weight_grad((act * u32).astype(x.dtype), dy))
+        # float32 sums, handed back as the weights are held (float32; the
+        # benchmark's bf16_params control holds them in bfloat16)
+        return (dx, weight_grad(x, dg).astype(gate.dtype),
+                weight_grad(x, du).astype(up.dtype),
+                weight_grad((act * u32).astype(x.dtype),
+                            dy).astype(down.dtype))
 
 
 _swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
@@ -912,9 +1021,23 @@ def _experts(config: DecoderConfig, layer: int, x, lp):
     rt_metrics.gauge("rsdl_moe_tile_rows",
                      "Rows of one expert in a tile of the expert layer's "
                      "walk, last layer traced").set(tile)
+    # A sigmoid router's selection bias is a buffer in the parameter tree:
+    # no gradient reaches it, Adam leaves it where it finds it, and its
+    # balancing update (below) is the train step's to add.
+    bias = (jax.lax.stop_gradient(lp["expert_bias"])
+            if config.expert_bias else None)
+    router = (lp["router"] if config.router_trains
+              else jax.lax.stop_gradient(lp["router"]))
+    if config.expert_bias_speed:
+        even = b * s * config.top_k / config.num_experts
+        tracing.leaf_move(
+            (f"layer_{layer}", "expert_bias"),
+            config.expert_bias_speed * (1.0 - moe.loads(
+                jax.lax.stop_gradient(x.reshape(b * s, h)), router, bias,
+                config.top_k) / even))
     out, walk = moe.moe_counted(
-        x.reshape(b * s, h), lp["router"], lp["gate"], lp["up"], lp["down"],
-        config.experts_held, config.top_k, tile, config.routed_scale)
+        x.reshape(b * s, h), router, lp["gate"], lp["up"], lp["down"],
+        config.experts_held, config.top_k, tile, config.routed_scale, bias)
     tracing.step_stat("moe_walk", walk, layer=layer)
     return out.reshape(b, s, h)
 
@@ -938,20 +1061,33 @@ def _added(config: DecoderConfig, x, out):
                 * config.residual_multiplier).astype(x.dtype)
 
 
+def _head_norm(x, heads: int, scale, eps: float):
+    """(B, S, heads x D) -> the same, RMSNorm over each head's D under
+    the one ``scale`` (D,), float32 inside."""
+    b, s, width = x.shape
+    return _rms_norm(x.reshape(b, s, heads, width // heads), scale,
+                     eps).reshape(b, s, width)
+
+
 def _attention_half(config: DecoderConfig, layer: int, x, lp):
-    """x + attention(RMSNorm(x)), the first half of a layer."""
+    """x + attention(RMSNorm(x)), the first half of a layer; with
+    ``qk_norm`` each q head and each k head is normed before it is
+    rotated."""
     layer_type, heads = config.layer_types[layer], config.heads(layer)
     if config.rotary:
         cos, sin = _rope_tables(config, layer_type, x.shape[1])
         rotated = rotated_dims(config, layer_type)
 
-    def placed(projected, count):
+    def placed(projected, count, scale):
+        if config.qk_norm:
+            projected = _head_norm(projected, count, lp[scale],
+                                   config.rms_norm_eps)
         return (_rope(projected, count, cos, sin, rotated) if config.rotary
                 else projected)
 
     a = _norm(config, x, lp, "attn_norm")
-    q = placed(_project(a, lp["wq"]), heads)
-    k = placed(_project(a, lp["wk"]), config.num_kv_heads)
+    q = placed(_project(a, lp["wq"]), heads, "q_layernorm")
+    k = placed(_project(a, lp["wk"]), config.num_kv_heads, "k_layernorm")
     v = _project(a, lp["wv"])
     gate = (jax.nn.sigmoid(_project(a, lp["wg"]).astype(jnp.float32))
             if config.attention_gate else None)
@@ -970,19 +1106,36 @@ def _count_ssm(kind: str) -> None:
         "the state in VMEM or by XLA's loops (selective_*)", kind=kind).inc()
 
 
-def _conv_silu(conv, x, lp):
-    """A mixer's convolution (``conv``: its ``causal_conv_silu``) over
-    ``x`` (B, S, C), counted by what computes it."""
-    in_vmem = ssd.convs_in_vmem(x.shape[1], x.shape[2],
-                                lp["conv_w"].shape[0], x.dtype)
+def _count_conv(in_vmem: bool) -> None:
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_lm_conv_total",
-        "Decoder layers' state-space mixers traced, by what computes "
-        "their depthwise convolution: a Pallas kernel each way that reads "
-        "a block once (vmem) or XLA's pad, shifted slices and autodiff "
-        "(xla)", kind="vmem" if in_vmem else "xla").inc()
+        "Decoder layers' state-space mixers and gated short convolutions "
+        "traced, by what computes their depthwise convolution: a Pallas "
+        "kernel each way that reads a block once (vmem) or XLA's pad, "
+        "shifted slices and autodiff (xla)",
+        kind="vmem" if in_vmem else "xla").inc()
+
+
+def _conv_silu(conv, x, lp):
+    """A mixer's convolution (``conv``: its ``causal_conv_silu``) over
+    ``x`` (B, S, C), counted by what computes it."""
+    _count_conv(ssd.convs_in_vmem(x.shape[1], x.shape[2],
+                                  lp["conv_w"].shape[0], x.dtype))
     return conv(x, lp["conv_w"], lp["conv_b"])
+
+
+def _conv_half(config: DecoderConfig, layer: int, x, lp):
+    """x + ``(C * conv(B * u)) W_out`` with ``B | C | u = norm(x) W_in``,
+    a ``conv`` layer's first half (LFM2's gated short convolution): the
+    two projections under ``PROJ_SCOPE``, the gates and the convolution
+    between them under ``SCONV_SCOPE``."""
+    n = _norm(config, x, lp, "conv_norm")
+    bcu = _project(n, lp["in_proj"])
+    _count_conv(sconv.convs_in_vmem(bcu.shape[1], bcu.shape[2] // 3,
+                                    lp["conv_w"].shape[0], bcu.dtype))
+    return _added(config, x, _project(
+        sconv.causal_gated_conv(bcu, lp["conv_w"]), lp["out_proj"]))
 
 
 def _mamba_half(config: DecoderConfig, layer: int, x, lp):
@@ -1206,7 +1359,7 @@ def _checked(config: DecoderConfig) -> None:
                          f"the router's {config.num_experts} experts")
     for name, kinds, known in (
             ("layer_types", config.layer_types,
-             (SLIDING, FULL, MAMBA, MAMBA1, GMU, CROSS)),
+             (SLIDING, FULL, MAMBA, MAMBA1, GMU, CROSS, CONV)),
             ("mlp_layer_types", config.mlp_layer_types, (DENSE, SPARSE))):
         for kind in kinds or ():
             if kind not in known:
@@ -1220,6 +1373,10 @@ def _checked(config: DecoderConfig) -> None:
         raise ValueError("a mamba layer needs mamba_heads")
     if config.norm not in (RMS_NORM, LAYER_NORM):
         raise ValueError(f"unknown norm {config.norm!r}")
+    if config.expert_bias_speed and not config.expert_bias:
+        raise ValueError("expert_bias_speed moves an expert_bias")
+    if CONV in config.layer_types and config.conv_taps < 1:
+        raise ValueError("a conv layer needs conv_taps")
     kinds = config.layer_types
     if MAMBA1 in kinds and (config.mamba1_width < 1
                             or config.mamba1_dt_rank < 1):
@@ -1240,14 +1397,15 @@ def _checked(config: DecoderConfig) -> None:
                 or len(config.published_indices) != config.num_layers):
             raise ValueError("differential attention needs each layer's "
                              "published index")
-        if (config.rotary or config.attention_gate
+        if (config.rotary or config.attention_gate or config.qk_norm
                 or config.heads_per_layer is not None
                 or config.num_kv_heads % 2
                 or config.num_heads % config.num_kv_heads):
             raise ValueError(
                 "differential attention pairs adjacent heads (an even "
                 "count of key/value heads that divides the query heads'), "
-                "without positions, head gate or heads by layer")
+                "without positions, head gate, head norms or heads by "
+                "layer")
 
 
 def decode(config: DecoderConfig, params: Dict[str, Any],
@@ -1321,6 +1479,9 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
         elif layer_type == GMU:
             x = jax.checkpoint(functools.partial(_gmu_half, config, layer))(
                 x, lp, memory)
+        elif layer_type == CONV:
+            x = jax.checkpoint(functools.partial(_conv_half, config, layer))(
+                x, lp)
         elif config.differential:
             x, made = tracing.step_stats_of(jax.checkpoint(
                 tracing.with_step_stats(functools.partial(
@@ -1462,5 +1623,7 @@ def loss_fn(config: DecoderConfig, params: Dict[str, Any],
     # A tied head is the embedding's own matrix: the one leaf takes the
     # gradient of both uses.
     head = params["embed"].T if config.tie_embeddings else params["head"]
-    total = _nll(x, head, targets, config.logits_scaling)
+    # The loss's backward sums the head's gradient in float32 and hands it
+    # back so: float32 weights pass as they are.
+    total = _nll(x, head.astype(jnp.float32), targets, config.logits_scaling)
     return total / jnp.maximum(jnp.sum(targets != IGNORE_ID), 1)

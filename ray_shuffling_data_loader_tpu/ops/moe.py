@@ -2,7 +2,9 @@
 
 A language model's expert layer routes every token over ALL of its experts
 (softmax over the router's logits, the ``top_k`` largest, their weights
-renormalised) and adds up what the picked experts give. Under expert
+renormalised; or, given a selection bias, sigmoid scores, the ``top_k``
+largest of score plus bias, the scores of the picks renormalised:
+:func:`route`) and adds up what the picked experts give. Under expert
 parallelism a chip holds a share of the experts, ``held = (first, count)``
 of the router's width, as ``ops/embedding.py:lookup`` is told its mesh:
 :func:`moe` computes the part of the sum that the held experts give, for
@@ -98,15 +100,39 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def route(logits, top_k: int, scale: float = 1.0):
-    """``(ids (N, top_k) int32, weights (N, top_k) float32)``: softmax over
-    all the experts in float32, the ``top_k`` largest, renormalised to sum
-    to ``scale`` (``norm_topk_prob``, then a model's
-    ``moe_routed_scaling_factor``). Differentiable in the weights."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top, ids = jax.lax.top_k(probs, top_k)
+#: A router's scoring, by name: softmax over all the experts, or a sigmoid
+#: an expert with a selection bias (:func:`route`).
+SOFTMAX, SIGMOID_BIAS = "softmax", "sigmoid_bias"
+
+#: What a sigmoid router adds to the sum of a token's picked scores before
+#: it divides by it.
+_SIGMOID_SUM_EPS = 1e-6
+
+
+def route(logits, top_k: int, scale: float = 1.0, bias=None):
+    """``(ids (N, top_k) int32, weights (N, top_k) float32)``, float32
+    throughout, differentiable in the weights. Without ``bias`` the router
+    scores by softmax over all the experts: the ``top_k`` largest,
+    renormalised to sum to ``scale`` (``norm_topk_prob``, then a model's
+    ``moe_routed_scaling_factor``). With ``bias`` (experts,) it scores by a
+    sigmoid an expert (``SIGMOID_BIAS``): the picks are the ``top_k``
+    largest of ``score + bias``, the weights the picks' SCORES, the bias
+    left out, over their sum plus 1e-6, times ``scale``. The bias only
+    chooses: it takes no gradient (its balancing update moves it, by the
+    experts' :func:`loads`: ``models/mellum.py:_experts``)."""
+    if bias is None:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        top, ids = jax.lax.top_k(probs, top_k)
+        return (ids.astype(jnp.int32),
+                scale * top / top.sum(axis=-1, keepdims=True))
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    # only the picks' ids are read off the biased scores: nothing flows
+    # back to the bias
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    top = jnp.take_along_axis(scores, ids, axis=-1)
     return (ids.astype(jnp.int32),
-            scale * top / top.sum(axis=-1, keepdims=True))
+            scale * top / (top.sum(axis=-1, keepdims=True)
+                           + _SIGMOID_SUM_EPS))
 
 
 def _router_logits(x, router):
@@ -117,8 +143,20 @@ def _router_logits(x, router):
                                precision=jax.lax.Precision.HIGHEST)
 
 
-def _router_weights(x, router, top_k: int, scale: float):
-    return route(_router_logits(x, router), top_k, scale)[1]
+def _router_weights(x, router, top_k: int, scale: float, bias=None):
+    return route(_router_logits(x, router), top_k, scale, bias)[1]
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def loads(x, router, bias, top_k: int):
+    """(experts,) int32: how many of the tokens ``x`` (N, hidden) pick
+    each of the router's experts, held here or not, under :func:`route`.
+    What a selection bias's balancing update reads."""
+    with jax.named_scope(SCOPE):
+        ids, _ = route(_router_logits(x, router), top_k, 1.0, bias)
+        # counted by comparison, as ``_dispatch`` counts the held ones
+        return jnp.sum(ids.reshape(-1, 1) == jnp.arange(router.shape[1]),
+                       axis=0, dtype=jnp.int32)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -545,15 +583,15 @@ def _combined(acc, buffer, index, spare: int, dma: bool):
 
 
 def moe(x, router, gate, up, down, held: Tuple[int, int], top_k: int,
-        tile: int, scale: float = 1.0):
+        tile: int, scale: float = 1.0, bias=None):
     """:func:`moe_counted`'s first result alone: the held experts' part
     of a sparse-expert layer."""
     return moe_counted(x, router, gate, up, down, held, top_k, tile,
-                       scale)[0]
+                       scale, bias)[0]
 
 
 def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
-                top_k: int, tile: int, scale: float = 1.0):
+                top_k: int, tile: int, scale: float = 1.0, bias=None):
     """The held experts' part of a sparse-expert layer, and what the walk
     did for it.
 
@@ -569,6 +607,9 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
         tile: rows of one expert in a tile of the walk (the caller's:
             :func:`tile_rows` has the rule the decoder follows).
         scale: what a token's weights sum to (``route``).
+        bias: ``None`` for a softmax router; (experts,) float32, the
+            selection bias of a sigmoid router (``route``). It takes no
+            gradient.
 
     Returns ``(out, walk)``: ``out`` (N, hidden) in ``x``'s dtype, for
     each token the sum over its picks that are held of weight x
@@ -587,21 +628,29 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
         "tile's tokens in, the tokens' picks out): one DMA a row from a "
         "Pallas kernel, or XLA's gather",
         kind="dma" if dma else "xla").inc()
-    return _moe(x, router, gate, up, down, held, top_k, tile, scale, dma)
+    rt_metrics.counter(
+        "rsdl_moe_router_total",
+        "Sparse-expert layers traced, by the router's scoring: softmax "
+        "over all the experts, or a sigmoid an expert picked under a "
+        "selection bias and weighed without it",
+        kind=SOFTMAX if bias is None else SIGMOID_BIAS).inc()
+    return _moe(x, router, gate, up, down, bias, held, top_k, tile, scale,
+                dma)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _moe(x, router, gate, up, down, held, top_k, tile, scale, dma):
-    return _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale,
-                    dma)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _moe(x, router, gate, up, down, bias, held, top_k, tile, scale, dma):
+    return _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile,
+                    scale, dma)[0]
 
 
 # Jitted for the scope's sake, as models/bert.py's head: inside a program
 # of its own (and inside a loop's body) the name reaches the compiled step
 # as written. The ``while`` instructions themselves carry no scope of the
 # program's, so nothing is counted twice.
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
-def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale, dma):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile, scale,
+             dma):
     first, count = held
     tokens, hidden = x.shape
     if gate.shape[0] != count:
@@ -609,7 +658,7 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale, dma):
                          f"{gate.shape[0]}")
     rows = round_rows(tokens, top_k, count, router.shape[1], tile)
     with jax.named_scope(SCOPE):
-        ids, weights = route(_router_logits(x, router), top_k, scale)
+        ids, weights = route(_router_logits(x, router), top_k, scale, bias)
         plan, position = _dispatch(ids, first, count, tile)
         flat = weights.reshape(-1)
         g16, u16, d16 = (w.astype(x.dtype) for w in (gate, up, down))
@@ -632,12 +681,13 @@ def _moe_fwd(x, router, gate, up, down, held, top_k, tile, scale, dma):
     out = _walk(plan, position, rows, tile, tile_fn, gather_fn, out, ys)
     with jax.named_scope(SCOPE):
         out = out.astype(x.dtype)
-    return (out, walk), (x, router, gate, up, down, weights, plan, position)
+    return (out, walk), (x, router, gate, up, down, bias, weights, plan,
+                         position)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _moe_bwd(held, top_k, tile, scale, dma, residuals, cotangents):
-    x, router, gate, up, down, weights, plan, position = residuals
+    x, router, gate, up, down, bias, weights, plan, position = residuals
     dout = cotangents[0]            # the walk's counts have none
     tokens, hidden = x.shape
     rows = round_rows(tokens, top_k, held[1], router.shape[1], tile)
@@ -687,10 +737,16 @@ def _moe_bwd(held, top_k, tile, scale, dma, residuals, cotangents):
         plan, position, rows, tile, tile_fn, gather_fn, grads, buffers)
     with jax.named_scope(SCOPE):
         _, router_vjp = jax.vjp(
-            lambda x, r: _router_weights(x, r, top_k, scale), x, router)
+            lambda x, r: _router_weights(x, r, top_k, scale, bias), x,
+            router)
         d_x_router, d_router = router_vjp(d_weights)
         d_x = (d_x + d_x_router.astype(jnp.float32)).astype(x.dtype)
-    return d_x, d_router, d_gate, d_up, d_down
+        # the selection bias chooses and does not weigh: no gradient
+        d_bias = None if bias is None else jnp.zeros_like(bias)
+        # float32 sums, handed back as the weights are held
+        d_gate, d_up, d_down = (d.astype(w.dtype) for d, w in (
+            (d_gate, gate), (d_up, up), (d_down, down)))
+    return d_x, d_router, d_gate, d_up, d_down, d_bias
 
 
 _moe.defvjp(_moe_fwd, _moe_bwd)

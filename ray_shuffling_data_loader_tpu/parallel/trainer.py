@@ -41,6 +41,14 @@ logger = setup_custom_logger(__name__)
 OPTIMIZER_SCOPE = "rsdl.train.optimizer"
 
 
+def _moved(tree: Any, path: Tuple[str, ...], delta: Any) -> Any:
+    """``tree`` with the leaf at ``path`` (nested dictionaries' keys) moved
+    by ``delta``; nothing else is copied."""
+    if not path:
+        return tree + delta.astype(tree.dtype)
+    return {**tree, path[0]: _moved(tree[path[0]], path[1:], delta)}
+
+
 def make_train_step(loss_fn: Callable,
                     optimizer: optax.GradientTransformation) -> Callable:
     """Pure train-step function: (params, opt_state, *batch) ->
@@ -48,15 +56,20 @@ def make_train_step(loss_fn: Callable,
     recorded any of the step's own counters while it was traced
     (``tracing.step_stat``): the ``{key: device value}`` they were
     recorded under. A loss that records none gives the three-output
-    program it always gave."""
+    program it always gave. A leaf the loss moves by a rule of its own
+    (``tracing.leaf_move``: it takes no gradient, so the optimizer leaves
+    it) is moved after the optimizer's update."""
     counted_loss = tracing.with_step_stats(loss_fn)
 
     def train_step(params, opt_state, *batch):
         (loss, stats), grads = jax.value_and_grad(
             counted_loss, has_aux=True)(params, *batch)
+        moves, stats = tracing.leaf_moves(stats)
         with jax.named_scope(OPTIMIZER_SCOPE):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+            for path, delta in moves.items():
+                params = _moved(params, path, delta)
         if stats:
             return params, opt_state, loss, stats
         return params, opt_state, loss

@@ -55,7 +55,8 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    and again for the layers whose checkpoint keeps its two first
     #    products, beside the bytes the device's memory had for them; the
     #    expert layer's = share | all of the router's experts held here;
-    #    ops/moe.py counts what moves the walk's rows, dma | xla) --
+    #    ops/moe.py counts what moves the walk's rows, dma | xla, and the
+    #    router's scoring, softmax | sigmoid_bias) --
     "rsdl_lm_attention_total": ("counter", ("kind",)),
     "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
@@ -63,13 +64,15 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_lm_mlp_keep_room_bytes": ("gauge", ()),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_gather_total": ("counter", ("kind",)),
+    "rsdl_moe_router_total": ("counter", ("kind",)),
     "rsdl_moe_experts_held": ("gauge", ()),
     "rsdl_moe_experts_routed": ("gauge", ()),
     "rsdl_moe_top_k": ("gauge", ()),
     "rsdl_moe_tile_rows": ("gauge", ()),
     # -- a decoder layer's state-space mixer (models/mellum.py; counted or
     #    set when a layer is traced; kind = what computes the scan; the
-    #    convolution's = vmem, a Pallas kernel each way | xla) --
+    #    convolution's, a mixer's or a gated short convolution's = vmem, a
+    #    Pallas kernel each way | xla) --
     "rsdl_lm_ssm_total": ("counter", ("kind",)),
     "rsdl_lm_conv_total": ("counter", ("kind",)),
     "rsdl_lm_ssm_chunk": ("gauge", ()),

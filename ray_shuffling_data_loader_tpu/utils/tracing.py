@@ -21,7 +21,9 @@ to the registry, written to the flight recorder:
 ``telemetry.step_stats_folded``) only once their arrays are ready, so
 the step path never waits for the device; :func:`step_stats` returns the
 folded entries by the step's number, which is the ``step_num`` the
-``rsdl.trainer.step`` annotation carries into a profiler trace.
+``rsdl.trainer.step`` annotation carries into a profiler trace. A leaf's
+move that is no optimizer's (:func:`leaf_move`) leaves the loss the same
+way and stays on the device: the train step adds it to the leaf.
 """
 
 from __future__ import annotations
@@ -84,6 +86,32 @@ def step_stat(name: str, value: Any, **labels: Any) -> None:
                          f"{sorted(telemetry.STEP_STAT_FIELDS)}")
     key = (name, tuple(sorted((k, str(v)) for k, v in labels.items())))
     _record_stats({key: value})
+
+
+#: The name a leaf's move rides out of the loss under, beside the stats.
+LEAF_MOVE = "leaf_move"
+
+
+def leaf_move(path: Tuple[str, ...], delta: Any) -> None:
+    """Record that this step moves the parameter leaf at ``path`` (its
+    keys from the tree's root) by ``delta``, beside what the optimizer
+    does to it: for a leaf that takes no gradient and follows a rule of
+    its own (a router's selection bias under its balancing update). For
+    model code, while the step is traced; the value leaves the loss as a
+    stat does, and ``parallel/trainer.make_train_step`` adds it
+    (:func:`leaf_moves`). Outside :func:`with_step_stats` it is
+    dropped."""
+    _record_stats({(LEAF_MOVE, (("path", "/".join(path)),)): delta})
+
+
+def leaf_moves(stats: Dict[StatKey, Any]
+               ) -> Tuple[Dict[Tuple[str, ...], Any], Dict[StatKey, Any]]:
+    """``stats`` as :func:`with_step_stats` returned them, split into the
+    recorded moves, by each leaf's path, and the step's counters."""
+    moves = {tuple(dict(key[1])["path"].split("/")): value
+             for key, value in stats.items() if key[0] == LEAF_MOVE}
+    return moves, {key: value for key, value in stats.items()
+                   if key[0] != LEAF_MOVE}
 
 
 def _record_stats(stats: Dict[StatKey, Any]) -> None:

@@ -51,18 +51,20 @@ if __name__ == "__main__":
     parser.add_argument("--batch-size", type=int, default=4)
     parser.add_argument("--seq-len", type=int, default=64)
     parser.add_argument("--model", choices=("mellum", "laguna", "granite",
-                                            "phi4flash"),
+                                            "phi4flash", "lfm2"),
                         default="mellum",
                         help="the decoder's configuration: Mellum2-12B-A2.5B, "
                         "Laguna-XS.2 (its step fills the chip at "
                         "--batch-size 2), granite-4.0-h-micro or "
                         "Phi-4-mini-flash-reasoning's junction (both "
-                        "--batch-size 1; --seq-len in whole chunks of 8)")
+                        "--batch-size 1; --seq-len in whole chunks of 8) or "
+                        "LFM2-24B-A2B (--batch-size 2: 7.5 GB of state and "
+                        "4.9 GB of a step's temporaries; 4 would pass 15 GB)")
     parser.add_argument("--full", action="store_true",
                         help="the published widths (models.mellum."
                         "mellum2_ep4_share / laguna_xs2_ep8_share / "
-                        "granite4_h_micro_period / phi4_mini_flash_junction) "
-                        "at 8,192-token rows")
+                        "granite4_h_micro_period / phi4_mini_flash_junction "
+                        "/ lfm2_24b_a2b_ep8_share) at 8,192-token rows")
     args = parser.parse_args()
 
     import jax
@@ -80,7 +82,9 @@ if __name__ == "__main__":
                   "granite": (mellum.granite_tiny,
                               mellum.granite4_h_micro_period),
                   "phi4flash": (mellum.phi4flash_tiny,
-                                mellum.phi4_mini_flash_junction)}[args.model]
+                                mellum.phi4_mini_flash_junction),
+                  "lfm2": (mellum.lfm2_tiny,
+                           mellum.lfm2_24b_a2b_ep8_share)}[args.model]
     cfg = full() if args.full else tiny()
     seq_len = 8192 if args.full else args.seq_len
     with tempfile.TemporaryDirectory() as tmpdir:

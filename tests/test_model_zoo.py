@@ -252,10 +252,11 @@ def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
 
 
-_SPARSE_CELLS = ["mellum_train_8k", "laguna_train_8k"]
-_DECODER_CELLS = _SPARSE_CELLS + ["granite_train_8k", "phi4flash_train_8k"]
+_SPARSE_CELLS = ["mellum_train_8k", "laguna_train_8k", "lfm2_train_8k"]
+_DECODER_CELLS = ["mellum_train_8k", "laguna_train_8k", "granite_train_8k",
+                  "phi4flash_train_8k", "lfm2_train_8k"]
 _DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k",
-                    "phi4flash_train_8k"]
+                    "phi4flash_train_8k", "lfm2_train_8k"]
 
 
 @pytest.mark.parametrize("name,scope,layer,cells", [
@@ -269,13 +270,14 @@ _DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k",
     ("lm_ssm_pct", mellum.SSM_SCOPE, "kernels", ["granite_train_8k"]),
     ("lm_sscan_pct", mellum.SSCAN_SCOPE, "kernels", ["phi4flash_train_8k"]),
     ("lm_gmu_pct", mellum.GMU_SCOPE, "model", ["phi4flash_train_8k"]),
+    ("lm_sconv_pct", mellum.SCONV_SCOPE, "kernels", ["lfm2_train_8k"]),
 ])
 def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
                                                             layer, cells):
     """``mlm_head_pct`` (PR 29), ``attention_pct`` (PR 31), the decoder's
     three (PR 32), its dense SwiGLUs' and its projections' (PR 34), its
     state-space mixers' (PR 38), its selective scans' and its memory
-    units' (PR 40) are data: the scope
+    units' (PR 40), its gated short convolutions' (PR 44) are data: the scope
     reader ``grad_exchange_pct`` uses, pointed at a scope the model
     names, in the cells of that model's configurations that run the
     scope and in no other; a program without the scope (the parent's
@@ -302,6 +304,48 @@ def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
     reader = manifest.layer_reader(name)
     assert reader({"trace": None}) is None
     assert reader({"trace": object(), "step_op_names": {}}) is None
+
+
+@pytest.mark.parametrize("family", ["mellum", "laguna", "granite",
+                                    "phi4flash", "lfm2"])
+def test_the_decoders_builders_come_in_pairs(family):
+    """Each decoder configuration has a builder at its published widths
+    and a tiny one of the same pattern for the CPU: the same kinds of
+    layer and of MLP in the same order (the tiny one may be a shorter
+    run of them), the same switches, and both pass the decoder's own
+    checks and give a parameter tree a layer."""
+    full, tiny = {
+        "mellum": (mellum.mellum2_ep4_share, mellum.mellum_tiny),
+        "laguna": (mellum.laguna_xs2_ep8_share, mellum.laguna_tiny),
+        "granite": (mellum.granite4_h_micro_period, mellum.granite_tiny),
+        "phi4flash": (mellum.phi4_mini_flash_junction,
+                      mellum.phi4flash_tiny),
+        "lfm2": (mellum.lfm2_24b_a2b_ep8_share, mellum.lfm2_tiny),
+    }[family]
+    full, tiny = full(), tiny()
+    for cfg in (full, tiny):
+        mellum._checked(cfg)
+        shapes = jax.eval_shape(lambda k, cfg=cfg: mellum.init(cfg, k),
+                                jax.random.key(0))
+        assert sum(name.startswith("layer_") for name in shapes) \
+            == cfg.num_layers
+    assert set(tiny.layer_types) == set(full.layer_types)
+    assert (tiny.mlp_layer_types is None) == (full.mlp_layer_types is None)
+    for switch in ("qk_norm", "expert_bias", "router_trains",
+                   "tie_embeddings", "rotary",
+                   "differential", "attention_gate", "norm", "conv_taps",
+                   "published_layers", "rope_theta"):
+        assert getattr(tiny, switch) == getattr(full, switch), switch
+    assert (tiny.yarn is None) == (full.yarn is None)
+    assert tiny.hidden_size < full.hidden_size
+    if family == "lfm2":
+        assert full.layer_types == tiny.layer_types == (
+            mellum.CONV, mellum.FULL, mellum.CONV, mellum.CONV, mellum.CONV)
+        assert (full.hidden_size, full.num_heads, full.num_kv_heads,
+                full.head_dim, full.intermediate_size, full.expert_width,
+                full.num_experts, full.experts_held, full.top_k,
+                full.vocab_size) == (2048, 32, 8, 64, 11776, 1536, 64,
+                                     (0, 8), 4, 8192)
 
 
 def test_bert_attention_runs_under_its_scope():
