@@ -8,8 +8,9 @@ full width of one model the repo supports, and checks what comes out:
    largest DLRM table) and flash attention forward + backward (against
    the plain-XLA reference, at an aligned and at a padded sequence
    length; and the decoder's path: eight query heads of 128 over one
-   key/value head, causal and under a window, off their projections),
-   compiled by Mosaic — not interpreted; the sparse-expert layer's walk
+   key/value head, causal and under a window, off their projections;
+   and two key heads' maps over one value head of twice their width,
+   differential attention's launch), compiled by Mosaic — not interpreted; the sparse-expert layer's walk
    (``ops/moe.py``) against its float32 loop of dense products at the
    decoder's widths, its rows moved by DMA (a tile's fetch and a round's
    combine, each also alone: equal to XLA's gather); the state-space
@@ -90,6 +91,11 @@ class SmokeSize:
     masked_attention_dim: int
     masked_attention: Tuple[Tuple[int, int, Optional[int], bool,
                                   Tuple[int, ...]], ...]
+    # its attention over wide values, two key heads' maps over one value
+    # head of twice a key's width (differential attention's launch):
+    # (query heads, key heads, a key's width, window, lengths)
+    wide_attention: Tuple[Tuple[int, int, int, Optional[int],
+                                Tuple[int, ...]], ...]
     # its expert layer: (tokens, hidden, width, experts, held, top_k, tile,
     # what a token's weights sum to)
     moe_shapes: Tuple[Tuple[int, int, int, int, int, int, int, float], ...]
@@ -121,6 +127,10 @@ def full_size() -> SmokeSize:
             # 64 : 8 under a window of 512
             (48, 8, None, True, (2048,)),
             (64, 8, 512, True, (2048,))),
+        # phi4flash_train_8k's differential layers: 40 maps over 20 key
+        # heads of 64 and 10 value heads of 128
+        wide_attention=((40, 20, 64, None, (2048,)),
+                        (40, 20, 64, 512, (2048,))),
         moe_shapes=((8192, 2304, 896, 64, 16, 8, 1152, 1.0),
                     (16384, 2048, 512, 256, 32, 8, 640, 2.5)),
         # granite_train_8k's nine Mamba layers
@@ -146,6 +156,7 @@ def tiny_size() -> SmokeSize:
         attention_shape=(1, 2, 32), attention_seqs=(256, 200, 64),
         masked_attention_dim=16,
         masked_attention=((4, 1, 24, False, (40,)), (6, 2, 8, True, (40,))),
+        wide_attention=((8, 4, 16, 8, (40,)),),
         moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),
                     (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
         ssd_shapes=((1, 256, 2, 64, 128, 128),),
@@ -280,6 +291,7 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
                    "kernel")
         _check_attention(flash, seq, b, h, d)
     _masked_attention_checks(size, interpret)
+    _wide_attention_checks(size, interpret)
     _check_partial_rotary(size.masked_attention_dim)
     for shape in size.moe_shapes:
         _check_moe(shape, interpret)
@@ -291,6 +303,12 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
         _check_conv(shape, interpret)
     for shape in size.sconv_shapes:
         _check_sconv(shape, interpret)
+
+
+def _packed(x):
+    """(B, H, S, D) -> (B, S, H x D), as a projection leaves its heads."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
 def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
@@ -305,11 +323,7 @@ def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
 
     d = size.masked_attention_dim
 
-    def packed(x):      # (B, H, S, D) -> (B, S, H x D), and back
-        b, h, s, _ = x.shape
-        return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-
-    def unpacked(x, h):
+    def unpacked(x, h):     # (B, S, H x D) -> (B, H, S, D)
         b, s, _ = x.shape
         return x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
@@ -324,8 +338,8 @@ def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
             def flash(q, k, v):
                 gate = gate_of(q).transpose(0, 2, 1) if gated else None
                 return unpacked(mellum._flash_attention(
-                    packed(q), packed(k), packed(v), gate, heads, kv_heads,
-                    span), heads)
+                    _packed(q), _packed(k), _packed(v), gate, heads,
+                    kv_heads, span), heads)
 
             def plain(q, k, v):
                 out = _plain_attention(q, k, v, causal=True, window=span)
@@ -344,6 +358,46 @@ def _masked_attention_checks(size: SmokeSize, interpret: bool) -> None:
                 what=(f"{heads}:{kv_heads} heads of {d}, causal"
                       + (f", window {span}" if span else "")
                       + (", gated" if gated else "")))
+
+
+def _wide_attention_checks(size: SmokeSize, interpret: bool) -> None:
+    """The decoder's attention where two key heads share a value head of
+    twice their width, (B, S, Hkv / 2 x 2 D): a query head's output is
+    its map over both halves side by side, and the half that is its own
+    key head's values is plain grouped attention's output, which it is
+    checked against (the other half's cotangent is zero)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+
+    for heads, kv_heads, d, span, seqs in size.wide_attention:
+        own = jnp.arange(heads) // (heads // kv_heads) % 2
+
+        def flash(q, k, v):
+            both = mellum._flash_attention(
+                _packed(q), _packed(k), _packed(v), None, heads, kv_heads,
+                span, None, jnp.float32, kv_heads // 2)
+            b, s, _ = both.shape
+            halves = both.reshape(b, s, heads, 2, d).transpose(0, 2, 1, 3, 4)
+            return jnp.take_along_axis(
+                halves, own[None, :, None, None, None], axis=3)[:, :, :, 0]
+
+        for seq in seqs:
+            if not interpret:
+                probe = jnp.zeros((1, heads, seq, d), jnp.bfloat16)
+                kv_probe = jnp.zeros((1, kv_heads, seq, d), jnp.bfloat16)
+                _check(_mosaic_calls(jax.jit(flash), probe, kv_probe,
+                                     kv_probe) == 1,
+                       f"wide-value attention at S={seq} did not lower to "
+                       "a Mosaic kernel")
+            _check_attention(
+                flash, seq, 1, heads, d, kv_heads=kv_heads, relative=True,
+                plain=functools.partial(_plain_attention, causal=True,
+                                        window=span),
+                what=(f"{heads}:{kv_heads}:{kv_heads // 2} heads of {d} "
+                      f"over values of {2 * d}, causal"
+                      + (f", window {span}" if span else "")))
 
 
 def _check_partial_rotary(dim: int, tol: float = 2e-2) -> None:

@@ -668,18 +668,21 @@ def _gated(out, gate, heads: int):
 
 # The attentions are jitted for the scope's sake, as models/bert.py's: inside
 # a program of its own the name reaches the compiled step as written.
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
                       window: Optional[int], scale: Optional[float] = None,
-                      out_dtype=None):
+                      out_dtype=None, v_heads: Optional[int] = None):
     """Causal grouped-query attention as XLA has it: float32 softmax over
     materialized (B, H, S, S) scores, times ``scale`` (``None``: over the
     root of a head's dimensions), the output in ``out_dtype`` (``None``:
-    v's); the backward is autodiff's."""
+    v's); ``v`` of ``v_heads`` heads (``None``: ``kv_heads``) at a width
+    of their own, each read by the query heads of ``kv_heads / v_heads``
+    key heads; the backward is autodiff's."""
     with jax.named_scope(ATTENTION_SCOPE):
         b, s, _ = q.shape
         q = q.reshape(b, s, kv_heads, heads // kv_heads, -1)
-        k, v = (x.reshape(b, s, kv_heads, -1) for x in (k, v))
+        k = k.reshape(b, s, kv_heads, -1)
+        v = v.reshape(b, s, kv_heads if v_heads is None else v_heads, -1)
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32)
         scores = (scores / jnp.sqrt(q.shape[-1]) if scale is None
                   else scores * scale)
@@ -688,6 +691,8 @@ def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
         if window is not None:
             seen &= ahead < window
         weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        # a value head's maps: its key heads' query heads, side by side
+        weights = weights.reshape(b, v.shape[2], -1, s, s)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v,
                          preferred_element_type=out_dtype)
         return _gated(out.reshape(b, s, -1), gate, heads)
@@ -722,22 +727,23 @@ def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
     return side, side
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_attention(q, k, v, gate, heads: int, kv_heads: int,
                      window: Optional[int], scale: Optional[float] = None,
-                     out_dtype=None):
+                     out_dtype=None, v_heads: Optional[int] = None):
     """``_inline_attention``'s result from the blocked Pallas kernels."""
     return _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window,
-                                scale, out_dtype)[0]
+                                scale, out_dtype, v_heads)[0]
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale,
-                         out_dtype=None):
+                         out_dtype=None, v_heads=None):
     with jax.named_scope(ATTENTION_SCOPE):
         out, lse = flash_attention.grouped_forward(
             q, k, v, heads, kv_heads, True, window, *_blocks(window, False),
-            interpret=not on_tpu(), scale=scale, out_dtype=out_dtype)
+            interpret=not on_tpu(), scale=scale, out_dtype=out_dtype,
+            num_v_heads=v_heads)
         # The two residuals the half's checkpoint keeps (``decode``), so
         # that the backward pass has them without this kernel run again;
         # q, k, v and the gate it makes again. lse without the column's
@@ -754,8 +760,8 @@ def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale,
         return _gated(out, gate, heads), (q, k, v, gate, out, lse)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype, v_heads,
                          residuals, cotangent):
     q, k, v, gate, out, lse = residuals
     with jax.named_scope(ATTENTION_SCOPE):
@@ -775,31 +781,35 @@ def _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
         return (*flash_attention.grouped_backward(
             q, k, v, out, lse[..., None], cotangent, heads, kv_heads, True,
             window, *_blocks(window, True), interpret=not on_tpu(),
-            scale=scale), d_gate)
+            scale=scale, num_v_heads=v_heads), d_gate)
 
 
 def _counted_flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
-                                 residuals, cotangent):
+                                 v_heads, residuals, cotangent):
     # Counted here, once a layer: the program under it is traced once.
-    q, k = residuals[:2]
+    q, k, v = residuals[:3]
     flash_attention.count_backward(flash_attention.grouped_backward_kind(
-        q, k, heads, *_blocks(window, True), interpret=not on_tpu()))
+        q, k, heads, *_blocks(window, True), interpret=not on_tpu(),
+        value_dim=v.shape[-1] // (v_heads or kv_heads)))
     return _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
-                                residuals, cotangent)
+                                v_heads, residuals, cotangent)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
 
 
 def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
-               heads: int, kv_heads: Optional[int] = None, out_dtype=None):
+               heads: int, kv_heads: Optional[int] = None, out_dtype=None,
+               v_heads: Optional[int] = None):
     """A layer's attention over rotated q (B, S, H x D) and k, v
-    (B, S, Hkv x D; ``kv_heads`` of them, ``None``: the configuration's),
-    each query head's output times its ``gate`` (B, S, H) where there is
-    one, the softmax scaled by ``attention_multiplier`` where the
-    configuration has one, the output in ``out_dtype`` (``None``: the
-    operands'), by what the trace can observe: the kernels where they
-    beat the inline path (``flash_attention.beats_inline``)."""
+    (B, S, Hkv x D; ``kv_heads`` of them, ``None``: the configuration's;
+    or v of ``v_heads`` heads, a divisor of ``kv_heads``, at a width of
+    their own, where key heads share their values), each query head's
+    output times its ``gate`` (B, S, H) where there is one, the softmax
+    scaled by ``attention_multiplier`` where the configuration has one,
+    the output in ``out_dtype`` (``None``: the operands'), by what the
+    trace can observe: the kernels where they beat the inline path
+    (``flash_attention.beats_inline``)."""
     if kv_heads is None:
         kv_heads = config.num_kv_heads
     seq_len = q.shape[1]
@@ -812,9 +822,12 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_lm_attention_total",
-        "Decoder layers' attentions traced, by what computes them: the "
+        "Decoder layers' attentions traced, by what computes them (the "
         "Pallas kernels over a window's band or the whole triangle, or "
-        "XLA's inline softmax over materialized scores", kind=kind).inc()
+        "XLA's inline softmax over materialized scores) and by their "
+        "values: shaped as the keys, or wide, key heads sharing a value "
+        "head of a width of its own so that a map's scores are made once",
+        kind=kind, values="same" if v_heads is None else "wide").inc()
     if flash:
         rt_metrics.counter(
             "rsdl_lm_attention_kept_total",
@@ -824,7 +837,7 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
             kind=kind).inc()
     attend = _flash_attention if flash else _inline_attention
     return attend(q, k, v, gate, heads, kv_heads, window,
-                  config.attention_multiplier, out_dtype)
+                  config.attention_multiplier, out_dtype, v_heads)
 
 
 # -- differential attention ----------------------------------------------------------
@@ -842,10 +855,11 @@ def _diff_lambda(config: DecoderConfig, layer: int, lp):
 @functools.partial(jax.jit, static_argnums=(3, 4, 5))
 def _diff_combine(maps, lam, scale, after: float, eps: float, dtype):
     """``after x RMSNorm(A1 V - lam x A2 V) x scale`` a head pair: ``maps``
-    (B, S, pairs, 2, 2 D) float32 holds a pair's ``A1 V`` and ``A2 V``;
-    (B, S, pairs x 2 D) out, in ``dtype``."""
+    (B, S, Hkv / 2, 2, H / Hkv, 2 D) float32 holds ``A1 V`` and ``A2 V``
+    of the H / Hkv head pairs that read a key/value pair, as the
+    attention's heads lie; (B, S, pairs x 2 D) out, in ``dtype``."""
     with jax.named_scope(ATTENTION_SCOPE):
-        diff = maps[..., 0, :] - lam * maps[..., 1, :]
+        diff = maps[:, :, :, 0] - lam * maps[:, :, :, 1]
         normed = diff * jax.lax.rsqrt(
             jnp.mean(diff * diff, axis=-1, keepdims=True) + eps)
         return (after * (normed * scale)).astype(dtype).reshape(
@@ -859,12 +873,16 @@ def _differential(config: DecoderConfig, layer: int, q, k, v, lp,
     key heads ``(2j, 2j + 1)`` their keys, value heads ``(2j, 2j + 1)``
     side by side the values ``V_j`` (2 D wide) of key/value pair ``j``,
     which the ``H / Hkv`` query pairs ``i`` with ``i // (H / Hkv) = j``
-    read. The released form's four attentions a pair (each map over each
-    half of ``V_j``) as one call of :func:`_attention` over 2 H query
-    heads and 2 Hkv key/value heads of D, so the blocked kernels run it
-    as they are (a map's scores twice, once a value half); then
-    :func:`_diff_combine`. The attention's output stays float32 until
-    the subtraction: at the seeded weights both maps are near the running
+    read. One attention head a map, in one call of :func:`_attention`: H
+    query heads ordered ``(j, map, pair of j)`` so that the two query
+    heads of a key head and the four of a value pair are neighbours, the
+    Hkv key heads of D and the Hkv / 2 value heads ``V_j`` of 2 D as the
+    projections left them, so a map's scores are made once and its
+    product with the values is 2 D wide (the released form's four
+    attentions a pair, each map over each half of ``V_j``, made every
+    map's scores twice); then :func:`_diff_combine`, which reads the maps
+    as the heads lie. The attention's output stays float32 until the
+    subtraction: at the seeded weights both maps are near the running
     mean of the values, ``lambda``'s gradient is what is left once the
     pairs' norm has taken their common part out, and outputs rounded to
     bf16 put 2-15 % on it (PERF.md section 6, PR 40). ``lambda`` goes out
@@ -873,25 +891,14 @@ def _differential(config: DecoderConfig, layer: int, q, k, v, lp,
     d = config.head_dim
     groups, per = config.num_kv_heads // 2, config.num_heads \
         // config.num_kv_heads
-    # one attention head a (j, map, value half, query pair of j)
-    q = jnp.broadcast_to(
-        q.reshape(b, s, groups, per, 2, 1, d).transpose(0, 1, 2, 4, 5, 3, 6),
-        (b, s, groups, 2, 2, per, d))
-    k = jnp.broadcast_to(k.reshape(b, s, groups, 2, 1, d),
-                         (b, s, groups, 2, 2, d))
-    v = jnp.broadcast_to(v.reshape(b, s, groups, 1, 2, d),
-                         (b, s, groups, 2, 2, d))
-    maps = _attention(config, q.reshape(b, s, -1), k.reshape(b, s, -1),
-                      v.reshape(b, s, -1), None, layer_type,
-                      2 * config.num_heads, 2 * config.num_kv_heads,
-                      jnp.float32)
-    # (j, map, half, pair) -> (pair of all, map, both halves side by side)
-    maps = maps.reshape(b, s, groups, 2, 2, per, d).transpose(
-        0, 1, 2, 5, 3, 4, 6).reshape(b, s, groups * per, 2, 2 * d)
+    # one attention head a (j, map, query pair of j)
+    q = q.reshape(b, s, groups, per, 2, d).swapaxes(3, 4).reshape(b, s, -1)
+    maps = _attention(config, q, k, v, None, layer_type, config.num_heads,
+                      out_dtype=jnp.float32, v_heads=groups)
     lam = _diff_lambda(config, layer, lp)
     tracing.step_stat("diff_attention", jnp.reshape(lam, (1,)), layer=layer)
     return _diff_combine(
-        maps, lam, lp["subln"],
+        maps.reshape(b, s, groups, 2, per, 2 * d), lam, lp["subln"],
         1.0 - lambda_init(config.published_indices[layer]),
         config.rms_norm_eps, q.dtype)
 

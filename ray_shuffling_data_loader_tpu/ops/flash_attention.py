@@ -36,9 +36,12 @@ Two families, chosen by shape at trace time:
   inner dimension runs over the blocks some query of the outer block
   sees and no further; only the diagonal's and the window edge's blocks
   compare positions), query heads in groups over fewer key/value heads
-  (fetched once a block, repeated nowhere), and heads of whole lanes
-  (128) cut straight from (B, S, H x D) projections (``grouped_forward``
-  / ``grouped_backward``). This is what ``models/mellum.py`` takes.
+  (fetched once a block, repeated nowhere), values of a width and a head
+  count of their own where key heads share them (differential
+  attention's two maps a pair over values of 2 D: a map's scores are
+  made once), and heads of whole lanes (128) cut straight from
+  (B, S, H x D) projections (``grouped_forward`` / ``grouped_backward``).
+  This is what ``models/mellum.py`` takes.
 
 Both take a key-side bias (B, 1, 1, S) and an lse that may cover more keys
 than the call holds (ring attention's hops, ops/ring_attention.py). Mosaic
@@ -229,10 +232,14 @@ def _bwd1_kernel(*refs, scale: float, heads: int, has_delta: bool,
 
 # -- kernels: the sequence in blocks -----------------------------------------
 #
-# A grid step sees one (bq, D) block of one head's queries and one (bk, D)
-# block of its keys, whatever arrays they are cut from (``_rows``); lse and
-# delta are (bq, 1) columns. ``group`` query heads read one key/value head
-# (the specs divide the head's index: nothing is repeated in HBM).
+# A grid step sees one (bq, D) block of one head's queries, one (bk, D)
+# block of its keys and one (bk, Dv) block of its values, whatever arrays
+# they are cut from (``_rows``); lse and delta are (bq, 1) columns.
+# ``group`` query heads read one key head and ``v_group`` of them one value
+# head (the specs divide the head's index: nothing is repeated in HBM). The
+# values have a width and a head count of their own: Dv = D and as many
+# heads as the keys in plain grouped attention; wider and fewer where key
+# heads share their values (differential attention's two maps a pair).
 #
 # The structural mask (``_Mask``) is static. The innermost grid dimension
 # runs over the blocks that some query of the outer block can see, counted
@@ -306,9 +313,10 @@ def _on_live(mask: _Mask, g, kb, live, bq: int, bk: int, step) -> None:
 
 def _fwd_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
                 bk: int, num_k: int, steps: int):
-    """Grid (B, H, num_q, steps), keys innermost. Blocks: q/o (bq, D);
-    k/v (bk, D); bias (1, bk) or absent; lse (bq, 1). Scratch m/l (bq, 1),
-    acc (bq, D) persist across the steps of one q-block."""
+    """Grid (B, H, num_q, steps), keys innermost. Blocks: q (bq, D), k
+    (bk, D), v (bk, Dv), o (bq, Dv); bias (1, bk) or absent; lse (bq, 1).
+    Scratch m/l (bq, 1), acc (bq, Dv) persist across the steps of one
+    q-block."""
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if has_bias else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[3 + has_bias:]
@@ -432,20 +440,22 @@ def _dkv_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
 
 
 def _bwd_kernel(*refs, scale: float, has_bias: bool, has_delta: bool,
-                mask: _Mask, group: int, bq: int, bk: int, num_q: int,
-                num_k: int, steps: int):
+                mask: _Mask, group: int, v_group: int, bq: int, bk: int,
+                num_q: int, num_k: int, steps: int):
     """Grid (B, H, num_q, steps), keys innermost: dq, dk, dv (and dbias)
     from one s, p, dp and ds a live block. dq gathers over a query block's
-    steps in scratch, as in ``_dq_kernel``. dk and dv of a key/value head
-    gather over everything that reads it, its ``group`` query heads (which
-    the grid visits one after the other) times their query blocks, in
-    float32 scratch that holds the head's whole sequence; their output
-    blocks are the whole sequence too, stay resident meanwhile and are
-    written at the head's last step. Blocks as ``_dq_kernel``'s, but
-    dk/dv (Sk, D), dbias (num_k, 1, bk) per key/value head, and in place
-    of delta, unless the caller brings one (``has_delta``), the forward's
-    output (bq, D): delta = sum_d do o is taken here, in float32, once a
-    query block."""
+    steps in scratch, as in ``_dq_kernel``. dk of a key head gathers over
+    everything that reads it, its ``group`` query heads (which the grid
+    visits one after the other) times their query blocks, in float32
+    scratch that holds the head's whole sequence; dv of a value head
+    likewise over its ``v_group`` query heads (``group`` of them, or a
+    multiple where key heads share values). Their output blocks are the
+    whole sequence too, stay resident meanwhile and are written at the
+    head's last step. Blocks as ``_dq_kernel``'s, but v and do (., Dv),
+    dk (Sk, D), dv (Sk, Dv), dbias (num_k, 1, bk) per key head, and in
+    place of delta, unless the caller brings one (``has_delta``), the
+    forward's output (bq, Dv): delta = sum_d do o is taken here, in
+    float32, once a query block."""
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if has_bias else None
     do_ref, lse_ref, aux_ref, dq_ref, dk_ref, dv_ref = refs[3 + has_bias:][:6]
@@ -456,15 +466,30 @@ def _bwd_kernel(*refs, scale: float, has_bias: bool, has_delta: bool,
     first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
     kb = first + t
     keys = pl.ds(pl.multiple_of(kb * bk, bk), bk)
-    opens = (j % group == 0) & (g == 0) & (t == 0)
-    closes = (j % group == group - 1) & (g == num_q - 1) & (t == steps - 1)
+
+    def run_of(heads: int):
+        """(first, last) step of a run of ``heads`` query heads."""
+        return ((j % heads == 0) & (g == 0) & (t == 0),
+                (j % heads == heads - 1) & (g == num_q - 1)
+                & (t == steps - 1))
+
+    opens, closes = run_of(group)
+    shared = v_group != group     # key heads share a value head
 
     @pl.when(opens)
     def _init_head():
         dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+        if not shared:
+            dv_scr[...] = jnp.zeros_like(dv_scr)
         if has_bias:
             dbias_ref[...] = jnp.zeros_like(dbias_ref)
+
+    if shared:
+        v_opens, v_closes = run_of(v_group)
+
+        @pl.when(v_opens)
+        def _init_value_head():
+            dv_scr[...] = jnp.zeros_like(dv_scr)
 
     @pl.when(t == 0)
     def _init():
@@ -498,7 +523,13 @@ def _bwd_kernel(*refs, scale: float, has_bias: bool, has_delta: bool,
     @pl.when(closes)
     def _finish_head():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        if not shared:
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+    if shared:
+        @pl.when(v_closes)
+        def _finish_value_head():
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 # -- block planning & padding ----------------------------------------------
@@ -739,18 +770,52 @@ def _stats(n: int, index):
     return pl.BlockSpec((None, None, n, 1), lambda *ids: (*index(*ids), 0))
 
 
-def _dims(packed: bool, q, k, num_heads: Optional[int],
-          num_kv_heads: Optional[int]):
-    """(B, H, Hkv, Sq, Sk, D) of the operands in either layout."""
+class _Dims(NamedTuple):
+    """The operands' sizes: H query heads and Hkv key heads of D, Hv value
+    heads of Dv."""
+    b: int
+    h: int
+    hkv: int
+    hv: int
+    sq: int
+    sk: int
+    d: int
+    dv: int
+
+    @property
+    def group(self) -> int:
+        """Query heads to a key head."""
+        return self.h // self.hkv
+
+    @property
+    def v_group(self) -> int:
+        """Query heads to a value head."""
+        return self.h // self.hv
+
+
+def _dims(packed: bool, q, k, v, num_heads: Optional[int],
+          num_kv_heads: Optional[int], num_v_heads: Optional[int],
+          packed_v: bool) -> _Dims:
+    """The sizes of the operands, q and k in one layout and v in one
+    (``packed``, ``packed_v``); packed arrays say their head counts beside
+    them (``num_v_heads`` ``None``: the keys')."""
     if not packed:
         (b, h, sq, d), (_, hkv, sk, _) = q.shape, k.shape
     else:
         (b, sq, width), sk = q.shape, k.shape[1]
         h, hkv, d = num_heads, num_kv_heads, width // num_heads
+    if not packed_v:
+        hv, dv = v.shape[1], v.shape[3]
+    else:
+        hv = hkv if num_v_heads is None else num_v_heads
+        dv = v.shape[-1] // hv
     if h % hkv:
         raise ValueError(f"{h} query heads do not share {hkv} key/value "
                          "heads evenly")
-    return b, h, hkv, sq, sk, d
+    if hkv % hv:
+        raise ValueError(f"{hkv} key heads do not share {hv} value heads "
+                         "evenly")
+    return _Dims(b, h, hkv, hv, sq, sk, d, dv)
 
 
 def _blocked_plan(mask: _Mask, bias, b: int, sq: int, sk: int, block_q: int,
@@ -767,42 +832,52 @@ def _blocked_plan(mask: _Mask, bias, b: int, sq: int, sk: int, block_q: int,
     return bq, bk, sq_pad, sk_pad, bias_arr
 
 
-def _query_major(mask: _Mask, group: int, bq: int, bk: int, num_k: int):
-    """Index maps of a (B, H, num_q, live key blocks) grid: ``(q_at, k_at)``
-    give (batch, head, row block) of the query-side and key-side blocks;
-    a step past the query block's last live key block names that one
-    again."""
+def _query_major(mask: _Mask, dims: _Dims, bq: int, bk: int, num_k: int):
+    """Index maps of a (B, H, num_q, live key blocks) grid: ``(q_at, k_at,
+    v_at)`` give (batch, head, row block) of the query-side, the keys' and
+    the values' blocks; a step past the query block's last live key block
+    names that one again."""
     def q_at(i, j, g, t):
         return i, j, g
 
-    def k_at(i, j, g, t):
-        first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum,
-                              jnp.maximum)
-        return i, j // group, jnp.minimum(first + t, last)
+    def key_side(group: int):
+        def at(i, j, g, t):
+            first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum,
+                                  jnp.maximum)
+            return i, j // group, jnp.minimum(first + t, last)
+        return at
 
-    return q_at, k_at
+    return q_at, key_side(dims.group), key_side(dims.v_group)
 
 
 def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
                      interpret: bool, packed: bool = False,
                      num_heads: Optional[int] = None,
                      num_kv_heads: Optional[int] = None,
-                     scale: Optional[float] = None, out_dtype=None):
+                     scale: Optional[float] = None, out_dtype=None,
+                     num_v_heads: Optional[int] = None,
+                     packed_v: Optional[bool] = None):
     """``(out, lse (B, H, Sq, 1))`` from the blocked kernels; the operands
-    (B, H, S, D) with k and v of H or fewer heads, or ``packed``
-    (B, S, H x D). ``scale`` multiplies the scores (``None``: 1 /
-    sqrt(D)); ``out`` is in ``out_dtype`` (``None``: q's)."""
-    b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
-    group, axis = h // hkv, 1 if packed else 2
+    (B, H, S, D) with k of H or fewer heads and v of as many or fewer
+    again, at a width Dv of its own, or ``packed`` (B, S, H x D); v, and
+    ``out`` with it (as q at Dv), in a layout of their own where
+    ``packed_v`` says so (``None``: q's). ``scale`` multiplies the scores
+    (``None``: 1 / sqrt(D)); ``out`` is in ``out_dtype`` (``None``: q's)."""
+    packed_v = packed if packed_v is None else packed_v
+    dims = _dims(packed, q, k, v, num_heads, num_kv_heads, num_v_heads,
+                 packed_v)
+    b, h, sq, sk, d, dv = (dims.b, dims.h, dims.sq, dims.sk, dims.d,
+                           dims.dv)
+    axis, v_axis = 1 if packed else 2, 1 if packed_v else 2
     bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
         mask, bias, b, sq, sk, block_q, block_k, interpret)
     qp = _pad_rows(q, sq_pad, axis)
-    kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, axis)
+    kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, v_axis)
     num_q, num_k = sq_pad // bq, sk_pad // bk
     steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
-    q_at, k_at = _query_major(mask, group, bq, bk, num_k)
+    q_at, k_at, v_at = _query_major(mask, dims, bq, bk, num_k)
     in_specs = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
-                _rows(packed, bk, d, k_at)]
+                _rows(packed_v, bk, dv, v_at)]
     args = [qp, kp, vp]
     if bias_arr is not None:
         in_specs.append(pl.BlockSpec(
@@ -815,103 +890,122 @@ def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
                           bk=bk, num_k=num_k, steps=steps),
         grid=(b, h, num_q, steps),
         in_specs=in_specs,
-        out_specs=[_rows(packed, bq, d, q_at), _stats(bq, q_at)],
+        out_specs=[_rows(packed_v, bq, dv, q_at), _stats(bq, q_at)],
         out_shape=[
-            jax.ShapeDtypeStruct(qp.shape,
-                                 q.dtype if out_dtype is None else out_dtype),
+            jax.ShapeDtypeStruct(
+                (b, sq_pad, h * dv) if packed_v else (b, h, sq_pad, dv),
+                q.dtype if out_dtype is None else out_dtype),
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((bq, 1), jnp.float32),
             _vmem((bq, 1), jnp.float32),
-            _vmem((bq, d), jnp.float32),
+            _vmem((bq, dv), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_compiler_params(interpret, _BLOCKED),
     )(*args)
     if sq_pad != sq:
-        out = jax.lax.slice_in_dim(out, 0, sq, axis=axis)
+        out = jax.lax.slice_in_dim(out, 0, sq, axis=v_axis)
         lse = lse[:, :, :sq]
     return out, lse
 
 
-def _fused_fits(bq: int, bk: int, sk_pad: int, d: int, dtype) -> bool:
-    """Whether ``_bwd_kernel``'s scoped VMEM holds a key/value head's whole
-    dk and dv, float32 scratch and the output's two buffers each, beside
-    the handful of float32 (bq, bk) arrays of a step. 8,192 keys of 128 in
-    bf16 are 16 MiB, 16,384 are 32; past that the backward is the split
-    pair, whose blocks do not grow with the sequence."""
-    head = sk_pad * _round_up(d, _LANES)
-    resident = 2 * head * (4 + 2 * jnp.dtype(dtype).itemsize)
+def _fused_fits(bq: int, bk: int, sk_pad: int, d: int, dtype,
+                dv: Optional[int] = None) -> bool:
+    """Whether ``_bwd_kernel``'s scoped VMEM holds a key head's whole dk
+    (D wide) and a value head's whole dv (Dv wide; ``None``: D), float32
+    scratch and the output's two buffers each, beside the handful of
+    float32 (bq, bk) arrays of a step. 8,192 keys of D = Dv = 128 in bf16
+    are 16 MiB, 16,384 are 32, and as many at D = 64, which a tile pads
+    to its 128 lanes; past that the backward is the split pair, whose
+    blocks do not grow with the sequence."""
+    columns = _round_up(d, _LANES) + _round_up(d if dv is None else dv,
+                                               _LANES)
+    resident = sk_pad * columns * (4 + 2 * jnp.dtype(dtype).itemsize)
     return resident + 6 * 4 * bq * bk <= _VMEM_LIMIT_BYTES
 
 
 def _blocked_kind(sq: int, sk: int, d: int, dtype, block_q: int,
-                  block_k: int, interpret: bool) -> str:
+                  block_k: int, interpret: bool,
+                  dv: Optional[int] = None) -> str:
     """Which backward the blocked family launches for these sizes."""
     bq, bk, _, sk_pad = _plan(sq, sk, block_q, block_k, interpret)
-    return "fused" if _fused_fits(bq, bk, sk_pad, d, dtype) else "split"
+    return "fused" if _fused_fits(bq, bk, sk_pad, d, dtype, dv) else "split"
 
 
 def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
                       block_q: int, block_k: int, interpret: bool,
                       packed: bool = False, num_heads: Optional[int] = None,
                       num_kv_heads: Optional[int] = None, delta=None,
-                      scale: Optional[float] = None):
-    """``(dq, dk, dv, dbias per key/value head (B, Hkv, 1, Sk) or None)``
-    from the blocked kernels: one (``_bwd_kernel``) where a key/value
-    head's dk and dv fit in VMEM (``_fused_fits``), else the split pair.
-    Operands as ``_blocked_forward``'s, ``out`` and ``do`` laid out as
-    ``q``, ``lse`` (B, H, Sq, 1). ``delta`` (B, H, Sq, 1) float32 is the
-    caller's sum_d do o where it has one already; ``out`` is then not
+                      scale: Optional[float] = None,
+                      num_v_heads: Optional[int] = None,
+                      packed_v: Optional[bool] = None):
+    """``(dq, dk, dv, dbias per key head (B, Hkv, 1, Sk) or None)`` from
+    the blocked kernels: one (``_bwd_kernel``) where a key head's dk and a
+    value head's dv fit in VMEM (``_fused_fits``), else the split pair,
+    which takes values shaped as the keys only. Operands as
+    ``_blocked_forward``'s, ``out`` and ``do`` laid out as ``q`` at Dv (in
+    v's layout), ``lse`` (B, H, Sq, 1). ``delta`` (B, H, Sq, 1) float32 is
+    the caller's sum_d do o where it has one already; ``out`` is then not
     read."""
-    b, h, hkv, sq, sk, d = _dims(packed, q, k, num_heads, num_kv_heads)
-    group, axis = h // hkv, 1 if packed else 2
+    packed_v = packed if packed_v is None else packed_v
+    dims = _dims(packed, q, k, v, num_heads, num_kv_heads, num_v_heads,
+                 packed_v)
+    b, h, hkv, sq, sk, d, dv = (dims.b, dims.h, dims.hkv, dims.sq, dims.sk,
+                                dims.d, dims.dv)
+    group, axis, v_axis = dims.group, 1 if packed else 2, 1 if packed_v else 2
     bq, bk, sq_pad, sk_pad, bias_arr = _blocked_plan(
         mask, bias, b, sq, sk, block_q, block_k, interpret)
-    fused = _fused_fits(bq, bk, sk_pad, d, k.dtype)
+    fused = _fused_fits(bq, bk, sk_pad, d, k.dtype, dv)
+    if not fused and (dims.hv, dv) != (hkv, d):
+        raise ValueError(
+            f"{sk_pad} keys' dk and dv do not fit in VMEM, and the dq + "
+            "dk/dv pair gathers dv a key head: it takes values of the "
+            f"keys' width and head count, not {dims.hv} heads of {dv} "
+            f"beside {hkv} of {d}")
     if delta is None and not fused:
-        heads = (b, sq, h, d) if packed else do.shape
+        heads = (b, sq, h, dv) if packed_v else do.shape
         delta = _delta(do.reshape(heads), out.reshape(heads), True)
-        if packed:
+        if packed_v:
             delta = delta.transpose(0, 2, 1, 3)          # (B, H, Sq, 1)
-    qp, dop = _pad_rows(q, sq_pad, axis), _pad_rows(do, sq_pad, axis)
-    kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, axis)
-    aux = (_pad_rows(out, sq_pad, axis) if delta is None
+    qp, dop = _pad_rows(q, sq_pad, axis), _pad_rows(do, sq_pad, v_axis)
+    kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, v_axis)
+    aux = (_pad_rows(out, sq_pad, v_axis) if delta is None
            else _pad_rows(delta, sq_pad))
     num_q, num_k = sq_pad // bq, sk_pad // bk
     has_bias = bias_arr is not None
     static = dict(scale=d ** -0.5 if scale is None else scale,
                   has_bias=has_bias, mask=mask, bq=bq, bk=bk)
 
-    def specs(q_at, k_at):
+    def specs(q_at, k_at, v_at):
         rows = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
-                _rows(packed, bk, d, k_at)]
+                _rows(packed_v, bk, dv, v_at)]
         if has_bias:
             rows.append(pl.BlockSpec(
                 (None, None, 1, bk),
                 lambda *ids: (ids[0], 0, 0, k_at(*ids)[2])))
-        return rows + [_rows(packed, bq, d, q_at), _stats(bq, q_at),
+        return rows + [_rows(packed_v, bq, dv, q_at), _stats(bq, q_at),
                        _stats(bq, q_at) if delta is not None
-                       else _rows(packed, bq, d, q_at)]
+                       else _rows(packed_v, bq, dv, q_at)]
 
     args = [qp, kp, vp] + ([bias_arr] if has_bias else []) \
         + [dop, _pad_rows(lse, sq_pad), aux]
     # Query-major: grid (B, H, num_q, live key blocks), K innermost.
     steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
-    q_at, k_at = _query_major(mask, group, bq, bk, num_k)
+    q_at, k_at, v_at = _query_major(mask, dims, bq, bk, num_k)
     dq_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
     dkv_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
                  jax.ShapeDtypeStruct(vp.shape, v.dtype)]
     dq_scratch = _vmem((bq, d), jnp.float32)
 
     if fused:
-        def head_at(i, j, g, t):
-            return i, j // group, 0
+        def head_of(group: int):
+            return lambda i, j, g, t: (i, j // group, 0)
 
         out_specs = [_rows(packed, bq, d, q_at),
-                     _rows(packed, sk_pad, d, head_at),
-                     _rows(packed, sk_pad, d, head_at)]
+                     _rows(packed, sk_pad, d, head_of(group)),
+                     _rows(packed_v, sk_pad, dv, head_of(dims.v_group))]
         out_shape = [dq_shape] + dkv_shape
         if has_bias:
             out_specs.append(pl.BlockSpec(
@@ -921,18 +1015,19 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
                 jax.ShapeDtypeStruct((b, hkv, num_k, 1, bk), jnp.float32))
         dq, dk, dv, *dbias = pl.pallas_call(
             functools.partial(_bwd_kernel, has_delta=delta is not None,
-                              group=group, num_q=num_q, num_k=num_k,
-                              steps=steps, **static),
+                              group=group, v_group=dims.v_group,
+                              num_q=num_q, num_k=num_k, steps=steps,
+                              **static),
             grid=(b, h, num_q, steps),
-            in_specs=specs(q_at, k_at),
+            in_specs=specs(q_at, k_at, v_at),
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[dq_scratch, _vmem((bq, 1), jnp.float32),
                             _vmem((sk_pad, d), jnp.float32),
-                            _vmem((sk_pad, d), jnp.float32)],
+                            _vmem((sk_pad, dv), jnp.float32)],
             interpret=interpret,
-            # Only the rows are independent: a key/value head's dk and dv
-            # gather over its query heads and their blocks.
+            # Only the rows are independent: a key head's dk and a value
+            # head's dv gather over their query heads and their blocks.
             compiler_params=_compiler_params(
                 interpret, ("parallel", "arbitrary", "arbitrary",
                             "arbitrary")),
@@ -942,7 +1037,7 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, num_k=num_k, steps=steps, **static),
             grid=(b, h, num_q, steps),
-            in_specs=specs(q_at, k_at),
+            in_specs=specs(q_at, k_at, v_at),
             out_specs=_rows(packed, bq, d, q_at),
             out_shape=dq_shape,
             scratch_shapes=[dq_scratch],
@@ -964,7 +1059,7 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
         def k_of(i, j, t, u):
             return i, j, t
 
-        out_specs = [_rows(packed, bk, d, k_of), _rows(packed, bk, d, k_of)]
+        out_specs = [_rows(packed, bk, d, k_of), _rows(packed_v, bk, d, k_of)]
         scratch = [_vmem((bk, d), jnp.float32), _vmem((bk, d), jnp.float32)]
         if has_bias:
             # Per key/value head: indexed by the head grid dim, unlike the
@@ -978,7 +1073,7 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
             functools.partial(_dkv_kernel, num_q=num_q, live_q=live_q,
                               steps=steps, **static),
             grid=(b, hkv, num_k, steps),
-            in_specs=specs(q_of, k_of),
+            in_specs=specs(q_of, k_of, k_of),
             out_specs=out_specs,
             out_shape=dkv_shape,
             scratch_shapes=scratch,
@@ -990,7 +1085,7 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
         dq = jax.lax.slice_in_dim(dq, 0, sq, axis=axis)
     if sk_pad != sk:
         dk = jax.lax.slice_in_dim(dk, 0, sk, axis=axis)
-        dv = jax.lax.slice_in_dim(dv, 0, sk, axis=axis)
+        dv = jax.lax.slice_in_dim(dv, 0, sk, axis=v_axis)
     return dq, dk, dv, dbias[..., :sk] if has_bias else None
 
 
@@ -1132,34 +1227,49 @@ def _reads_in_place(head_dim: int, interpret: bool) -> bool:
     return interpret or head_dim % _LANES == 0
 
 
+def _value_heads(q, v, num_heads: int, num_kv_heads: int,
+                 num_v_heads: Optional[int], interpret: bool):
+    """``(Hv, whether the kernels read (B, S, H x D) q and k in place,
+    whether (B, S, Hv x Dv) v)``: each where its width is whole lanes."""
+    hv = num_kv_heads if num_v_heads is None else num_v_heads
+    return (hv, _reads_in_place(q.shape[-1] // num_heads, interpret),
+            _reads_in_place(v.shape[-1] // hv, interpret))
+
+
 def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
-                    scale: Optional[float] = None, out_dtype=None):
+                    scale: Optional[float] = None, out_dtype=None,
+                    num_v_heads: Optional[int] = None):
     """Self-attention of projections as their matmuls leave them: ``q``
-    (B, S, H x D), ``k`` and ``v`` (B, S, Hkv x D), a head's D side by
-    side; query head h reads key/value head h // (H / Hkv), which is
-    fetched once a block and repeated nowhere. Returns ``(out
-    (B, S, H x D), lse (B, H, S, 1))``. Where D is whole lanes (128) the
-    kernels read and write these arrays in place: no (B, S, H, D) ->
-    (B, H, S, D) copy on either side; narrower heads (64) are copied
-    head-major around the same kernels. ``scale`` multiplies the scores
-    (``None``: 1 / sqrt(D)); ``out_dtype`` is ``out``'s (``None``: q's;
-    float32 where what follows subtracts two outputs that nearly
-    cancel). Without a custom_vjp of its own: the
-    caller pairs it with :func:`grouped_backward` under its own scope
-    (models/mellum.py does)."""
+    (B, S, H x D), ``k`` (B, S, Hkv x D) and ``v`` (B, S, Hv x Dv), a
+    head's columns side by side; query head h reads key head h // (H /
+    Hkv) and value head h // (H / Hv), each fetched once a block and
+    repeated nowhere. ``num_v_heads`` (``None``: Hkv, and then Dv = D:
+    plain grouped attention) divides Hkv: where key heads share their
+    values (differential attention's two maps a pair, values of 2 D) a
+    map's scores are made once, not once a part of its values. Returns
+    ``(out (B, S, H x Dv), lse (B, H, S, 1))``. Where D is whole lanes
+    (128) the kernels read q and k and write dq and dk in these arrays in
+    place: no (B, S, H, D) -> (B, H, S, D) copy on either side; narrower
+    heads (64) are copied head-major around the same kernels; and so v,
+    ``out``, ``do`` and dv by Dv. ``scale`` multiplies
+    the scores (``None``: 1 / sqrt(D)); ``out_dtype`` is ``out``'s
+    (``None``: q's; float32 where what follows subtracts two outputs that
+    nearly cancel). Without a custom_vjp of its own: the caller pairs it
+    with :func:`grouped_backward` under its own scope (models/mellum.py
+    does)."""
     mask = _mask_of(causal, window)
-    if _reads_in_place(q.shape[-1] // num_heads, interpret):
-        return _blocked_forward(q, k, v, None, mask, block_q, block_k,
-                                interpret, True, num_heads, num_kv_heads,
-                                scale, out_dtype)
+    hv, in_place, v_in_place = _value_heads(q, v, num_heads, num_kv_heads,
+                                            num_v_heads, interpret)
+    if not in_place:
+        q, k = _split_heads(q, num_heads), _split_heads(k, num_kv_heads)
     out, lse = _blocked_forward(
-        _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
-        _split_heads(v, num_kv_heads), None, mask, block_q, block_k,
-        interpret, scale=scale, out_dtype=out_dtype)
-    return _merge_heads(out), lse
+        q, k, v if v_in_place else _split_heads(v, hv), None, mask, block_q,
+        block_k, interpret, in_place, num_heads, num_kv_heads, scale,
+        out_dtype, hv, v_in_place)
+    return out if v_in_place else _merge_heads(out), lse
 
 
 def grouped_backward(q, k, v, out, lse, do, num_heads: int,
@@ -1167,31 +1277,39 @@ def grouped_backward(q, k, v, out, lse, do, num_heads: int,
                      window: Optional[int] = None,
                      block_q: int = DEFAULT_BLOCK_Q,
                      block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None,
+                     num_v_heads: Optional[int] = None):
     """``(dq, dk, dv)`` in the operands' layouts from
     :func:`grouped_forward`'s operands and results and the output's
-    cotangent ``do`` (B, S, H x D)."""
+    cotangent ``do`` (B, S, H x Dv)."""
     mask = _mask_of(causal, window)
-    if _reads_in_place(q.shape[-1] // num_heads, interpret):
-        return _blocked_backward(q, k, v, None, out, lse, do, mask,
-                                 block_q, block_k, interpret, True,
-                                 num_heads, num_kv_heads, scale=scale)[:3]
+    hv, in_place, v_in_place = _value_heads(q, v, num_heads, num_kv_heads,
+                                            num_v_heads, interpret)
+    if not in_place:
+        q, k = _split_heads(q, num_heads), _split_heads(k, num_kv_heads)
+    if not v_in_place:
+        v, out, do = (_split_heads(x, heads) for x, heads in (
+            (v, hv), (out, num_heads), (do, num_heads)))
     dq, dk, dv, _ = _blocked_backward(
-        _split_heads(q, num_heads), _split_heads(k, num_kv_heads),
-        _split_heads(v, num_kv_heads), None, _split_heads(out, num_heads),
-        lse, _split_heads(do, num_heads), mask, block_q, block_k, interpret,
-        scale=scale)
-    return _merge_heads(dq), _merge_heads(dk), _merge_heads(dv)
+        q, k, v, None, out, lse, do, mask, block_q, block_k, interpret,
+        in_place, num_heads, num_kv_heads, scale=scale, num_v_heads=hv,
+        packed_v=v_in_place)
+    if not in_place:
+        dq, dk = _merge_heads(dq), _merge_heads(dk)
+    return dq, dk, dv if v_in_place else _merge_heads(dv)
 
 
 def grouped_backward_kind(q, k, num_heads: int,
                           block_q: int = DEFAULT_BLOCK_Q,
                           block_k: int = DEFAULT_BLOCK_K,
-                          interpret: bool = False) -> str:
+                          interpret: bool = False,
+                          value_dim: Optional[int] = None) -> str:
     """Which backward :func:`grouped_backward` launches for these
-    operands: ``"fused"`` (one kernel) or ``"split"`` (dq, then dk/dv)."""
+    operands, a value head ``value_dim`` wide (``None``: as a query's):
+    ``"fused"`` (one kernel) or ``"split"`` (dq, then dk/dv)."""
     s, d = q.shape[1], q.shape[-1] // num_heads
-    return _blocked_kind(s, s, d, k.dtype, block_q, block_k, interpret)
+    return _blocked_kind(s, s, d, k.dtype, block_q, block_k, interpret,
+                         value_dim)
 
 
 def count_backward(kind: str) -> None:

@@ -57,7 +57,7 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    expert layer's = share | all of the router's experts held here;
     #    ops/moe.py counts what moves the walk's rows, dma | xla, and the
     #    router's scoring, softmax | sigmoid_bias) --
-    "rsdl_lm_attention_total": ("counter", ("kind",)),
+    "rsdl_lm_attention_total": ("counter", ("kind", "values")),
     "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_kept_total": ("counter", ("kind",)),

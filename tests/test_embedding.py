@@ -587,6 +587,54 @@ def test_the_hybrid_decoders_attention_layer_compiles_for_the_chip(
     assert fa.grouped_backward_kind(q, kv, cfg.num_heads) == "fused"
 
 
+@pytest.mark.parametrize("layer", [1, 3], ids=["window_512", "triangle"])
+def test_the_junctions_differential_layers_compile_for_the_chip(four_chips,
+                                                                layer):
+    """A differential layer of ``phi4flash_train_8k`` through the TPU's
+    own compiler, a row of 8,192 tokens: one attention head a map, 40
+    query heads over 20 key heads of 64 and 10 value heads of 128. Two
+    Mosaic kernels, a forward and one backward (a key head's dk and a
+    value head's dv fit in VMEM), no (S, S) array; q and k are copied
+    head-major (64 is not whole lanes), the values, the float32 maps and
+    their cotangent are read and written where they lie."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    cfg = mellum.phi4_mini_flash_junction()
+    heads, kv_heads, v_heads = (cfg.num_heads, cfg.num_kv_heads,
+                                cfg.num_kv_heads // 2)
+    assert (heads, kv_heads, cfg.head_dim) == (40, 20, 64)
+    window = (cfg.sliding_window
+              if cfg.layer_types[layer] == mellum.SLIDING else None)
+    assert window == (512 if layer == 1 else None)
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    q, k, v, do = (jax.ShapeDtypeStruct((1, 8192, width), jnp.bfloat16,
+                                        sharding=one_chip)
+                   for width in (heads * 64, kv_heads * 64, v_heads * 128,
+                                 heads * 128))
+
+    def both(q, k, v, do):
+        args = (heads, kv_heads, True, window)
+        out, lse = fa.grouped_forward(
+            q, k, v, *args, *mellum._blocks(window, False),
+            out_dtype=jnp.float32, num_v_heads=v_heads)
+        return out, fa.grouped_backward(
+            q, k, v, out, lse, do, *args, *mellum._blocks(window, True),
+            num_v_heads=v_heads)
+
+    hlo = jax.jit(both).lower(q, k, v, do).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(r"\[\d+,\d+(,\d+)*,8192,8192\]", hlo)
+    assert re.search(rf"bf16\[1,{heads},8192,64\]", hlo)
+    assert not re.search(r"\[1,\d+,8192,128\]", hlo)
+    assert fa.grouped_backward_kind(
+        q, k, heads, *mellum._blocks(window, True),
+        value_dim=128) == "fused"
+
+
 @pytest.mark.parametrize("in_vmem", [False, True],
                          ids=["einsums", "in_vmem"])
 def test_the_state_space_scan_compiles_for_the_chip_in_chunks(

@@ -1,5 +1,7 @@
 """Pallas flash attention vs full attention (interpreter mode on CPU)."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -293,13 +295,19 @@ def _two_head_bert(seq, layers=2, dtype=jnp.float32):
     return config, bert.init(config, jax.random.key(0))
 
 
+def _pallas_eqns(jaxpr):
+    """The ``pallas_call`` equations of ``jaxpr`` and the programs it
+    calls, a kernel's own body left out."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _pallas_eqns(sub)
+
+
 def _pallas_calls(jaxpr) -> int:
-    """``pallas_call`` equations in ``jaxpr`` and the programs it calls."""
-    return sum(
-        1 if eqn.primitive.name == "pallas_call" else sum(
-            _pallas_calls(sub)
-            for sub in jax.core.jaxprs_in_params(eqn.params))
-        for eqn in jaxpr.eqns)
+    return sum(1 for _ in _pallas_eqns(jaxpr))
 
 
 @pytest.mark.parametrize("chip,seq,masked,hook,want", [
@@ -413,9 +421,10 @@ def test_flash_bert_loss_on_a_data_mesh_equals_one_device(rng, monkeypatch):
 
 def _plain_attention(q, k, v, bias, causal, window):
     """(B, H, S, D) float32 softmax attention as XLA has it inline, k and
-    v of fewer heads, a key-side bias, the structural mask."""
-    s, group = q.shape[2], q.shape[1] // k.shape[1]
-    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    v of fewer heads (v of fewer again, at a width of its own), a key-side
+    bias, the structural mask."""
+    s = q.shape[2]
+    k, v = (jnp.repeat(x, q.shape[1] // x.shape[1], axis=1) for x in (k, v))
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
     if bias is not None:
         scores = scores + bias
@@ -435,30 +444,50 @@ def _packed(x):
 
 
 @pytest.mark.parametrize(
-    "seq,heads,kv_heads,causal,window,biased,block,layout", [
-        (64, 2, 2, True, None, False, 16, "bhsd"),
-        (64, 2, 2, True, None, False, 16, "packed"),
-        (64, 2, 2, True, 5, False, 16, "packed"),
-        (64, 2, 2, True, 32, False, 16, "bhsd"),
-        (64, 2, 2, True, 23, False, 16, "packed"),
-        (48, 8, 2, True, None, False, 16, "bhsd"),
-        (48, 8, 2, True, 20, False, 16, "packed"),
-        (64, 2, 2, False, None, True, 16, "bhsd"),
-        (1000, 2, 1, True, None, False, 256, "packed"),
-        (1000, 1, 1, False, None, True, 256, "bhsd"),
-        (64, 2, 1, True, 23, False, 16, "own_delta"),
+    "seq,heads,kv_heads,causal,window,biased,block,layout,wide", [
+        (64, 2, 2, True, None, False, 16, "bhsd", False),
+        (64, 2, 2, True, None, False, 16, "packed", False),
+        (64, 2, 2, True, 5, False, 16, "packed", False),
+        (64, 2, 2, True, 32, False, 16, "bhsd", False),
+        (64, 2, 2, True, 23, False, 16, "packed", False),
+        (48, 8, 2, True, None, False, 16, "bhsd", False),
+        (48, 8, 2, True, 20, False, 16, "packed", False),
+        (64, 2, 2, False, None, True, 16, "bhsd", False),
+        (1000, 2, 1, True, None, False, 256, "packed", False),
+        (1000, 1, 1, False, None, True, 256, "bhsd", False),
+        (64, 2, 1, True, 23, False, 16, "own_delta", False),
+        (64, 8, 4, True, None, False, 16, "packed", True),
+        (64, 8, 4, True, 23, False, 16, "packed", True),
+        (48, 4, 2, True, None, False, 16, "bhsd", True),
+        (48, 4, 2, True, 20, False, 16, "bhsd", True),
+        (64, 8, 4, True, 23, False, 16, "values_in_place", True),
+        (48, 8, 4, True, None, False, 16, "copied", True),
     ], ids=["causal", "causal_in_place", "window_under_a_tile",
             "window_of_two_tiles", "window_of_no_whole_tiles", "heads_8_to_2",
             "heads_8_to_2_in_place_window", "key_bias_and_dbias",
             "padded_1000_in_place", "padded_1000_key_bias",
-            "the_callers_delta"])
+            "the_callers_delta", "wide_values_in_place",
+            "wide_values_in_place_window", "wide_values",
+            "wide_values_window", "wide_values_alone_in_place",
+            "wide_values_copied_head_major"])
 def test_blocked_backward_is_the_float32_vjp(
-        seq, heads, kv_heads, causal, window, biased, block, layout,
+        seq, heads, kv_heads, causal, window, biased, block, layout, wide,
         blocked_kernels, monkeypatch):
     """dq, dk, dv (and dbias) of the blocked family against ``jax.vjp`` of
     the inline float32 attention: under each mask, with grouped heads, a
     key bias, a length the chip pads (its plan under the interpreter),
-    both layouts, and with a delta the caller brings (``out`` unread)."""
+    both layouts, with a delta the caller brings (``out`` unread), and
+    with ``wide`` values: half as many heads as the keys at twice the
+    width, two key heads' maps over one value head (differential
+    attention's), read in place beside q and k, in place beside q and k
+    copied head-major (the chip's layouts at D = 64, Dv = 128), or copied
+    too. The one kernel gathers dv over a value head's query heads; the
+    dq + dk/dv pair refuses what is not shaped as the keys."""
+    if layout in ("values_in_place", "copied"):
+        monkeypatch.setattr(
+            fa, "_reads_in_place",
+            lambda d, interpret: layout == "values_in_place" and d == 32)
+        layout = "packed"
     if seq % block:
         plan = fa._plan
         monkeypatch.setattr(
@@ -466,10 +495,11 @@ def test_blocked_backward_is_the_float32_vjp(
                 sq, sk, bq, bk, False))
         assert fa._plan(seq, seq, block, block, True)[3] > seq
     key = jax.random.key(seq + heads + (window or 0))
+    v_heads, dv = (kv_heads // 2, 32) if wide else (kv_heads, 16)
     q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape)
                    for i, shape in enumerate([
                        (2, heads, seq, 16), (2, kv_heads, seq, 16),
-                       (2, kv_heads, seq, 16), (2, heads, seq, 16)]))
+                       (2, v_heads, seq, dv), (2, heads, seq, dv)]))
     bias = None
     if biased:
         bias = jnp.where(jax.random.bernoulli(
@@ -479,18 +509,24 @@ def test_blocked_backward_is_the_float32_vjp(
         lambda *a: _plain_attention(*a, causal, window), q, k, v, bias)
     want = vjp(do)
     mask = fa._Mask(causal, window)
+    refused = (pytest.raises(ValueError, match="the keys' width and head")
+               if wide and blocked_kernels == "split"
+               else contextlib.nullcontext())
     if layout == "bhsd":
         out, lse = fa._flash_forward(q, k, v, bias, block, block, True, mask)
-        got = fa.flash_backward(q, k, v, bias, out, lse, do, block, block,
-                                True, causal, window)
+        with refused:
+            got = fa.flash_backward(q, k, v, bias, out, lse, do, block, block,
+                                    True, causal, window)
     else:
         operands = [_packed(x) for x in (q, k, v)]
+        values = dict(num_v_heads=v_heads) if wide else {}
         out, lse = fa.grouped_forward(*operands, heads, kv_heads, causal,
-                                      window, block, block, True)
+                                      window, block, block, True, **values)
         if layout == "packed":
-            got = fa.grouped_backward(*operands, out, lse, _packed(do), heads,
-                                      kv_heads, causal, window, block, block,
-                                      True)
+            with refused:
+                got = fa.grouped_backward(
+                    *operands, out, lse, _packed(do), heads, kv_heads, causal,
+                    window, block, block, True, **values)
         else:
             delta = fa._delta(do, want_out)[..., None]
             got = fa._blocked_backward(
@@ -499,10 +535,67 @@ def test_blocked_backward_is_the_float32_vjp(
         want = [_packed(x) for x in want[:3]]
         want_out = _packed(want_out)
     np.testing.assert_allclose(out, want_out, atol=2e-5)
+    if wide and blocked_kernels == "split":
+        return
     assert len(got) == 3 or (got[3] is None) == (bias is None)
     for grad, plain in zip(got, want):
         if grad is not None:
             np.testing.assert_allclose(grad, plain, atol=5e-5)
+
+
+def _launch(eqn):
+    """(grid, each block's sizes, the scratch's shapes, conditionals in
+    the kernel's body) of a ``pallas_call``."""
+    mapping, body = eqn.params["grid_mapping"], eqn.params["jaxpr"]
+    blocks = [tuple(getattr(size, "block_size", None)
+                    for size in block.block_shape)
+              for block in mapping.block_mappings]
+    scratch = [var.aval.shape
+               for var in body.invars[-mapping.num_scratch_operands:]]
+    return (mapping.grid, blocks, scratch,
+            sum(e.primitive.name == "cond" for e in body.eqns))
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_values_shaped_as_the_keys_launch_what_they_always_did(which):
+    """With Dv = D and no value head count the kernels' launch does not
+    know that values may be wide: the program is the one an explicit
+    ``num_v_heads = Hkv`` gives, every block and scratch is D wide (sizes
+    written out: PR 44's launch), and the backward's body holds the six
+    conditionals it had. Wide values change the values' side alone, and
+    the backward opens and closes dv's scratch on a run of its own."""
+    q = jnp.zeros((1, 64, 4 * 16))
+    kv = jnp.zeros((1, 64, 2 * 16))
+    lse = jnp.zeros((1, 4, 64, 1))
+
+    def traced(v, out, **values):
+        if which == "forward":
+            return jax.make_jaxpr(lambda *a: fa.grouped_forward(
+                *a, 4, 2, True, 20, 16, 16, True, **values))(q, kv, v)
+        return jax.make_jaxpr(lambda *a: fa.grouped_backward(
+            *a, 4, 2, True, 20, 16, 16, True, **values))(
+                q, kv, v, out, lse, out)
+
+    plain = traced(kv, q)
+    assert str(plain) == str(traced(kv, q, num_v_heads=2))
+    (eqn,) = _pallas_eqns(plain.jaxpr)
+    row, column, whole = (None, 16, 16), (None, None, 16, 1), (None, 64, 16)
+    assert _launch(eqn) == {
+        "forward": ((1, 4, 4, 3), [row] * 4 + [column],
+                    [(16, 1), (16, 1), (16, 16)], 4),
+        "backward": ((1, 4, 4, 3), [row] * 4 + [column] + [row] * 2
+                     + [whole] * 2,
+                     [(16, 16), (16, 1), (64, 16), (64, 16)], 6)}[which]
+    (eqn,) = _pallas_eqns(traced(jnp.zeros((1, 64, 32)),
+                                 jnp.zeros((1, 64, 4 * 32)),
+                                 num_v_heads=1).jaxpr)
+    wide_row, wide_whole = (None, 16, 32), (None, 64, 32)
+    assert _launch(eqn) == {
+        "forward": ((1, 4, 4, 3), [row] * 2 + [wide_row] * 2 + [column],
+                    [(16, 1), (16, 1), (16, 32)], 4),
+        "backward": ((1, 4, 4, 3), [row] * 2 + [wide_row] * 2 + [column]
+                     + [wide_row, row, whole, wide_whole],
+                     [(16, 16), (16, 1), (64, 16), (64, 32)], 8)}[which]
 
 
 def test_the_blocked_backward_is_one_kernel_where_dk_and_dv_fit(
@@ -525,6 +618,15 @@ def test_the_blocked_backward_is_one_kernel_where_dk_and_dv_fit(
                 (16384, f32, 1024, "split"), (32768, bf16, 1024, "split")]:
             assert fa._blocked_kind(seq, seq, 128, dtype, tile, tile,
                                     False) == kind, (seq, dtype, tile)
+        # phi4flash_train_8k's layers: keys of 64 (a tile pads them to its
+        # 128 lanes), values of 128, the triangle's tiles and the window's
+        for tile in (1024, 512):
+            assert fa._blocked_kind(8192, 8192, 64, bf16, tile, tile, False,
+                                    128) == "fused"
+            assert fa._fused_fits(tile, tile, 8192, 64, bf16, 128)
+        assert fa._fused_fits(1024, 1024, 16384, 64, bf16, 128)
+        assert not fa._fused_fits(1024, 1024, 16384, 64, f32, 128)
+        assert not fa._fused_fits(1024, 1024, 16384, 128, bf16, 512)
 
 
 def _backward_counts():

@@ -582,10 +582,10 @@ def test_loss_and_every_gradient_match_the_reference(tiny_f32, monkeypatch):
 # -- what an attention half's checkpoint keeps ---------------------------------------
 
 
-def _counts(name, kinds):
-    """The registry's counter ``name`` at each of ``kinds``, 0 where it
-    was never counted."""
-    found = (metrics.get(name, {"kind": kind}) for kind in kinds)
+def _counts(name, kinds, **labels):
+    """The registry's counter ``name`` at each of ``kinds`` (and the other
+    ``labels`` it has), 0 where it was never counted."""
+    found = (metrics.get(name, {"kind": kind, **labels}) for kind in kinds)
     return [0 if metric is None else metric.value for metric in found]
 
 
@@ -654,7 +654,7 @@ def test_the_kept_counter_counts_the_kernels_layers(tiny_f32, flash,
     monkeypatch.setattr(fa, "beats_inline", lambda seq_len: flash)
     counts = functools.partial(_counts, kinds=("window", "full", "inline"))
     before = counts("rsdl_lm_attention_kept_total")
-    traced_before = counts("rsdl_lm_attention_total")
+    traced_before = counts("rsdl_lm_attention_total", values="same")
     jax.eval_shape(lambda p: mellum.loss_fn(cfg, p, tokens), params)
     windows = sum(kind == mellum.SLIDING for kind in cfg.layer_types)
     rose = [after - b for after, b in zip(
@@ -662,7 +662,7 @@ def test_the_kept_counter_counts_the_kernels_layers(tiny_f32, flash,
     assert rose == ([windows, cfg.num_layers - windows, 0] if flash
                     else [0, 0, 0])
     traced = [after - b for after, b in zip(
-        counts("rsdl_lm_attention_total"), traced_before)]
+        counts("rsdl_lm_attention_total", values="same"), traced_before)]
     # every layer the kernels compute engages, and no other
     assert traced == rose[:2] + [0 if flash else cfg.num_layers]
 
@@ -1020,7 +1020,8 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
         metric = metrics.get(name, labels or None)
         return 0 if metric is None else metric.value
 
-    before = {kind: count("rsdl_lm_attention_total", kind=kind)
+    before = {kind: count("rsdl_lm_attention_total", kind=kind,
+                          values="same")
               for kind in ("inline", "window", "full")}
     layers_before = count("rsdl_moe_layer_total", kind="share")
     gathers_before = {kind: count("rsdl_moe_gather_total", kind=kind)
@@ -1044,9 +1045,10 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
         assert not any(name.startswith("while") for name in under), scope
         assert not re.search(rf"jvp\({re.escape(scope)}\)", text)
     # CPU: every layer's attention is XLA's inline one
-    assert count("rsdl_lm_attention_total", kind="inline") == (
-        before["inline"] + cfg.num_layers)
-    assert count("rsdl_lm_attention_total", kind="window") == before["window"]
+    assert count("rsdl_lm_attention_total", kind="inline",
+                 values="same") == before["inline"] + cfg.num_layers
+    assert count("rsdl_lm_attention_total", kind="window",
+                 values="same") == before["window"]
     assert count("rsdl_moe_layer_total", kind="share") == (
         layers_before + sparse)
     # CPU: every walk's rows move by XLA's gather
@@ -1074,7 +1076,8 @@ def test_the_attention_counter_tells_window_from_full(build, windows, fulls,
     cfg = build()
 
     def count(kind):
-        metric = metrics.get("rsdl_lm_attention_total", {"kind": kind})
+        metric = metrics.get("rsdl_lm_attention_total",
+                             {"kind": kind, "values": "same"})
         return 0 if metric is None else metric.value
 
     before = count("window"), count("full")

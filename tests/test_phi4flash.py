@@ -132,10 +132,10 @@ def test_without_the_scans_carry_the_comparison_fails(tiny, monkeypatch):
 def test_differential_attention_is_the_written_out_form(tiny, flash,
                                                         monkeypatch):
     """One layer's differential attention alone, the program's (one call
-    of the attention over twice the heads, then the subtraction and the
-    pairs' norm) against the reference's (both maps written out a head
-    pair), under the window and over the whole row; with ``lambda`` 0,
-    the control, they differ."""
+    of the attention, one head a map over its pair's values at their own
+    width, then the subtraction and the pairs' norm) against the
+    reference's (both maps written out a head pair), under the window and
+    over the whole row; with ``lambda`` 0, the control, they differ."""
     cfg, sizes, params, _, _ = tiny
     if flash:
         monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
@@ -258,12 +258,24 @@ def test_the_counts_of_the_cut_and_of_the_published_model():
         3 * 8192 * (2 * 64 * 40 + 2 * 128 * 40) * keys)
 
 
-def test_the_neutral_values_leave_the_other_decoders_alone():
+def test_the_neutral_values_leave_the_other_decoders_alone(monkeypatch):
     """The three new layer types, LayerNorm and the differential switch
     are not in the other configurations' graphs: nothing of the selective
-    scan, of a memory unit or of a bias."""
+    scan, of a memory unit or of a bias. Nor of values wider than the
+    keys: with the kernels taken, the step's text is letter for letter
+    the one whose every attention names its value heads (as many as its
+    key heads), which no layer of these does."""
+    named = []
+
+    def naming(launch, kv_heads_at):
+        def launched(*operands, num_v_heads, **options):
+            named.append(num_v_heads)
+            return launch(*operands, num_v_heads=operands[kv_heads_at],
+                          **options)
+        return launched
+
     for build in (mellum.mellum_tiny, mellum.laguna_tiny,
-                  mellum.granite_tiny):
+                  mellum.granite_tiny, mellum.lfm2_tiny):
         cfg = build()
         assert (cfg.norm, cfg.differential, cfg.published_indices,
                 cfg.mamba1_width) == (mellum.RMS_NORM, False, None, 0)
@@ -271,12 +283,63 @@ def test_the_neutral_values_leave_the_other_decoders_alone():
         assert not any(name.endswith("_bias") and "norm" in name
                        for name in params)
         tokens = jnp.zeros((1, _SEQ), jnp.int32)
-        text = jax.jit(jax.grad(
-            lambda p: mellum.loss_fn(cfg, p, tokens))).lower(params).as_text(
-                debug_info=True)
-        assert mellum.SSCAN_SCOPE not in text
-        assert mellum.GMU_SCOPE not in text
-        assert "_diff_combine" not in text and "_gmu_gated" not in text
+
+        def text(debug_info=False):
+            return jax.jit(jax.grad(lambda p: mellum.loss_fn(
+                cfg, p, tokens))).lower(params).as_text(
+                    debug_info=debug_info)
+
+        inline = text(debug_info=True)
+        assert mellum.SSCAN_SCOPE not in inline
+        assert mellum.GMU_SCOPE not in inline
+        assert "_diff_combine" not in inline and "_gmu_gated" not in inline
+        if build not in (mellum.laguna_tiny, mellum.lfm2_tiny):
+            continue    # windows and a gate; q and k normed, plain rotary
+        with monkeypatch.context() as patch:
+            patch.setattr(fa, "beats_inline", lambda seq_len: True)
+            kernels = text()
+            assert "_diff_combine" not in kernels and not named
+            for launch, at in (("grouped_forward", 4),
+                               ("grouped_backward", 7)):
+                patch.setattr(fa, launch, naming(getattr(fa, launch), at))
+            jax.clear_caches()      # the attentions' jits: traced again
+            assert text() == kernels
+            # forward and backward, once a shape the layers' jits see
+            assert len(named) >= 2 and set(named) == {None}
+            named.clear()
+
+
+def test_the_attention_counter_tells_wide_values_from_the_keys_own(
+        monkeypatch):
+    """One count a layer traced: the junction's three differential layers
+    hand the kernels (or the inline path) values of their own width and
+    head count, ``values="wide"``; the attention layer of a Granite cut
+    hands them values shaped as its keys, ``"same"``."""
+    def counts():
+        found = {}
+        for kind in ("inline", "window", "full"):
+            for values in ("wide", "same"):
+                metric = metrics.get("rsdl_lm_attention_total",
+                                     {"kind": kind, "values": values})
+                found[kind, values] = 0 if metric is None else metric.value
+        return found
+
+    def traced(cfg):
+        before = counts()
+        jax.eval_shape(lambda p, t: mellum.loss_fn(cfg, p, t),
+                       mellum.init(cfg, jax.random.key(0)),
+                       jnp.zeros((1, _SEQ), jnp.int32))
+        return {key: count - before[key] for key, count in counts().items()
+                if count != before[key]}
+
+    assert traced(mellum.phi4flash_tiny()) == {("inline", "wide"): 3}
+    assert traced(mellum.granite_tiny()) == {("inline", "same"): 1}
+    monkeypatch.setattr(fa, "beats_inline", lambda seq_len: True)
+    assert traced(mellum.phi4flash_tiny()) == {("window", "wide"): 1,
+                                               ("full", "wide"): 2}
+    assert traced(mellum.granite_tiny()) == {("full", "same"): 1}
+    assert metric_names.METRIC_NAMES["rsdl_lm_attention_total"] == (
+        "counter", ("kind", "values"))
 
 
 def test_what_the_decoder_refuses():
