@@ -97,15 +97,25 @@ TPU-first choices:
 - Every layer is made again in the backward pass (``jax.checkpoint``), a
   half at a time: a half keeps its bf16 input, and an attention half
   whose attention the kernels compute also their two results, the ungated
-  output and the log-sum-exp a row, so that the backward pass makes the
-  norm, q, k, v and the gate again but runs no forward kernel twice. An
-  MLP half keeps its SwiGLU's ``x G`` and ``x U`` too (a dense layer's MLP
-  or a sparse layer's shared expert; not the routed experts), so that the
-  backward pass makes the norm again and runs nine products a SwiGLU, not
-  eleven, in as many layers as the device's memory has room for by the
-  allocator's own count when the step is traced (``mlp_halves_kept``:
-  every layer of the four 8,192-token configurations on a v5e; a layer
-  there is no room for keeps its input alone).
+  output and the log-sum-exp a row, so that the backward pass runs no
+  forward kernel twice. An MLP half keeps its SwiGLU's ``x G`` and ``x U``
+  too (a dense layer's MLP or a sparse layer's shared expert; not the
+  routed experts), so that the backward pass makes the norm again and
+  runs nine products a SwiGLU, not eleven; and a first half of any kind
+  keeps what its in-projections give (q, k, v and the head gate; a Mamba
+  mixer's ``z | x B C | dt``; a Mamba-1 mixer's ``u | z``, ``r | B | C``
+  and ``r W_dt``; a memory unit's ``n W_1``; a ``conv`` operator's ``B |
+  C | u``), so that the backward pass makes the norms, head norms,
+  rotary, convolution and scan again but runs three products a projection
+  weight, not four. Both in as many layers as the device's memory has
+  room for by the allocator's own count when the step is traced, out of
+  one running sum (``keep_room``): the SwiGLUs take theirs first, in
+  layer order (``mlp_halves_kept``: every layer of the five 8,192-token
+  configurations that has one, on a v5e), then the first halves theirs of
+  what is left (``first_halves_kept``: on a v5e every layer of LFM2's and
+  the junction's cuts, five of granite's ten, one of Laguna's five, none
+  of Mellum's, whose room is negative); a half there is no room for
+  keeps its input alone.
 - The head's loss walks blocks of tokens, forward and backward, so that no
   (tokens, vocabulary) float32 array outlives a block.
 """
@@ -164,6 +174,11 @@ KEPT_OUT, KEPT_LSE = "rsdl.lm.attention.out", "rsdl.lm.attention.lse"
 # ``x U`` (``_swiglu_fwd`` names them), in the layers ``mlp_halves_kept``
 # finds room for.
 KEPT_GATE, KEPT_UP = "rsdl.lm.mlp.gate", "rsdl.lm.mlp.up"
+# What a first half's checkpoint keeps of its in-projections, the products
+# that feed the half's operator and not the residual stream (``_project_in``
+# names them), in the layers ``first_halves_kept`` finds room for once the
+# SwiGLUs have taken theirs.
+KEPT_PROJ = "rsdl.lm.proj.in"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1059,6 +1074,16 @@ def _project(x, weight):
         return x @ weight.astype(x.dtype)
 
 
+def _project_in(x, weight, kept: bool):
+    """:func:`_project` of a first half's in-projection, a product that
+    feeds the half's operator. ``kept``: the half's checkpoint keeps it,
+    so the backward pass has it without the product run again (three
+    products a weight a step, not four); named only then, so that a half
+    that keeps nothing traces to what it always did."""
+    out = _project(x, weight)
+    return checkpoint_name(out, KEPT_PROJ) if kept else out
+
+
 def _added(config: DecoderConfig, x, out):
     """``x + residual_multiplier x out``, a half's output joining the
     residual stream."""
@@ -1076,10 +1101,12 @@ def _head_norm(x, heads: int, scale, eps: float):
                      eps).reshape(b, s, width)
 
 
-def _attention_half(config: DecoderConfig, layer: int, x, lp):
+def _attention_half(config: DecoderConfig, layer: int, x, lp,
+                    kept: bool = False):
     """x + attention(RMSNorm(x)), the first half of a layer; with
     ``qk_norm`` each q head and each k head is normed before it is
-    rotated."""
+    rotated. ``kept``: the half's checkpoint keeps q, k, v and the head
+    gate as their products left them (what the caller's policy does)."""
     layer_type, heads = config.layer_types[layer], config.heads(layer)
     if config.rotary:
         cos, sin = _rope_tables(config, layer_type, x.shape[1])
@@ -1093,10 +1120,12 @@ def _attention_half(config: DecoderConfig, layer: int, x, lp):
                 else projected)
 
     a = _norm(config, x, lp, "attn_norm")
-    q = placed(_project(a, lp["wq"]), heads, "q_layernorm")
-    k = placed(_project(a, lp["wk"]), config.num_kv_heads, "k_layernorm")
-    v = _project(a, lp["wv"])
-    gate = (jax.nn.sigmoid(_project(a, lp["wg"]).astype(jnp.float32))
+    q = placed(_project_in(a, lp["wq"], kept), heads, "q_layernorm")
+    k = placed(_project_in(a, lp["wk"], kept), config.num_kv_heads,
+               "k_layernorm")
+    v = _project_in(a, lp["wv"], kept)
+    gate = (jax.nn.sigmoid(
+        _project_in(a, lp["wg"], kept).astype(jnp.float32))
             if config.attention_gate else None)
     return _added(config, x, _project(
         _attention(config, q, k, v, gate, layer_type, heads), lp["wo"]))
@@ -1132,24 +1161,28 @@ def _conv_silu(conv, x, lp):
     return conv(x, lp["conv_w"], lp["conv_b"])
 
 
-def _conv_half(config: DecoderConfig, layer: int, x, lp):
+def _conv_half(config: DecoderConfig, layer: int, x, lp,
+               kept: bool = False):
     """x + ``(C * conv(B * u)) W_out`` with ``B | C | u = norm(x) W_in``,
     a ``conv`` layer's first half (LFM2's gated short convolution): the
     two projections under ``PROJ_SCOPE``, the gates and the convolution
-    between them under ``SCONV_SCOPE``."""
+    between them under ``SCONV_SCOPE``. ``kept``: the half's checkpoint
+    keeps ``B | C | u``."""
     n = _norm(config, x, lp, "conv_norm")
-    bcu = _project(n, lp["in_proj"])
+    bcu = _project_in(n, lp["in_proj"], kept)
     _count_conv(sconv.convs_in_vmem(bcu.shape[1], bcu.shape[2] // 3,
                                     lp["conv_w"].shape[0], bcu.dtype))
     return _added(config, x, _project(
         sconv.causal_gated_conv(bcu, lp["conv_w"]), lp["out_proj"]))
 
 
-def _mamba_half(config: DecoderConfig, layer: int, x, lp):
+def _mamba_half(config: DecoderConfig, layer: int, x, lp,
+                kept: bool = False):
     """x + Mamba-2(RMSNorm(x)), a ``mamba`` layer's first half: the two
     projections under ``PROJ_SCOPE``, what lies between them under
     ``SSM_SCOPE``. How much state crossed the scan's chunks goes out as
-    the step's ``ssm_scan`` of this layer."""
+    the step's ``ssm_scan`` of this layer. ``kept``: the half's
+    checkpoint keeps ``z | x B C | dt``."""
     b, s, _ = x.shape
     width, state = config.mamba_width, config.mamba_state
     in_vmem = ssd.scans_in_vmem(
@@ -1164,7 +1197,7 @@ def _mamba_half(config: DecoderConfig, layer: int, x, lp):
                      "chunk-by-chunk tiles in VMEM (1) or sends them "
                      "through HBM (0)").set(int(in_vmem))
     n = _norm(config, x, lp, "mamba_norm")
-    z, xbc, dt = jnp.split(_project(n, lp["in_proj"]),
+    z, xbc, dt = jnp.split(_project_in(n, lp["in_proj"], kept),
                            [width, 2 * width + 2 * state], axis=-1)
     xbc = _conv_silu(ssd.causal_conv_silu, xbc, lp)
     xs, b_in, c_in = jnp.split(xbc, [width, width + state], axis=-1)
@@ -1190,40 +1223,47 @@ def _shared(tensors, kind: str):
     return tensors
 
 
-def _differential_half(config: DecoderConfig, layer: int, x, lp, kv=None):
+def _differential_half(config: DecoderConfig, layer: int, x, lp, kv=None,
+                       kept: bool = False):
     """``(x + differential attention(norm(x)), (k, v))``, a differential
     layer's first half, without positions. ``kv``: an earlier layer's keys
     and values, which a ``cross`` layer (no ``wk``, no ``wv``) attends
-    over; they come back as they were."""
+    over; they come back as they were. ``kept``: the half's checkpoint
+    keeps q, and k and v where the layer makes them: a ``full_attention``
+    layer's, which leave the half as results too, are the one array
+    either way."""
     layer_type = config.layer_types[layer]
     a = _norm(config, x, lp, "attn_norm")
-    q = _project(a, lp["wq"])
+    q = _project_in(a, lp["wq"], kept)
     if layer_type == CROSS:
         k, v = _shared(kv, "kv")
         layer_type = FULL       # causal over the whole row
     else:
-        k, v = _project(a, lp["wk"]), _project(a, lp["wv"])
+        k = _project_in(a, lp["wk"], kept)
+        v = _project_in(a, lp["wv"], kept)
     out = _differential(config, layer, q, k, v, lp, layer_type)
     return _added(config, x, _project(out, lp["wo"])), (k, v)
 
 
-def _mamba1_half(config: DecoderConfig, layer: int, x, lp):
+def _mamba1_half(config: DecoderConfig, layer: int, x, lp,
+                 kept: bool = False):
     """``(x + Mamba-1(norm(x)), y)``, a ``mamba1`` layer's first half and
     its scan's output before the gate: the four projections under
     ``PROJ_SCOPE``, what lies between them under ``SSCAN_SCOPE``. How much
     state crossed the scan's chunks goes out as the step's ``ssm_scan`` of
-    this layer."""
+    this layer. ``kept``: the half's checkpoint keeps ``u | z``, ``r | B |
+    C`` and ``r W_dt``."""
     width, state = config.mamba1_width, config.mamba1_state
     rank = config.mamba1_dt_rank
     in_vmem = selective_scan.scans_in_vmem(width, state, config.mamba_chunk)
     _count_ssm("selective_vmem" if in_vmem else "selective_xla")
     n = _norm(config, x, lp, "mamba_norm")
-    u, z = jnp.split(_project(n, lp["in_proj"]), 2, axis=-1)
+    u, z = jnp.split(_project_in(n, lp["in_proj"], kept), 2, axis=-1)
     u = _conv_silu(selective_scan.causal_conv_silu, u, lp)
-    r, b_in, c_in = jnp.split(_project(u, lp["x_proj"]),
+    r, b_in, c_in = jnp.split(_project_in(u, lp["x_proj"], kept),
                               [rank, rank + state], axis=-1)
-    dt = selective_scan.softplus_step(_project(r, lp["dt_proj"]),
-                                      lp["dt_bias"])
+    dt = selective_scan.softplus_step(
+        _project_in(r, lp["dt_proj"], kept), lp["dt_bias"])
     y, crossed = selective_scan.selective_scan_counted(
         u, dt, lp["a_log"], b_in, c_in, lp["d"], config.mamba_chunk)
     tracing.step_stat("ssm_scan", crossed, layer=layer)
@@ -1268,12 +1308,14 @@ def _gmu_gated_bwd(residuals, d_out):
 _gmu_gated.defvjp(_gmu_gated_fwd, _gmu_gated_bwd)
 
 
-def _gmu_half(config: DecoderConfig, layer: int, x, lp, memory):
+def _gmu_half(config: DecoderConfig, layer: int, x, lp, memory,
+              kept: bool = False):
     """x + ``(silu(norm(x) W_1) * M) W_2``, a ``gmu`` layer's first half:
     a Gated Memory Unit over ``memory``, an earlier Mamba-1 layer's scan
-    output."""
+    output. ``kept``: the half's checkpoint keeps ``norm(x) W_1``."""
     n = _norm(config, x, lp, "gmu_norm")
-    mixed = _gmu_gated(_project(n, lp["w1"]), _shared(memory, "memory"))
+    mixed = _gmu_gated(_project_in(n, lp["w1"], kept),
+                       _shared(memory, "memory"))
     return _added(config, x, _project(mixed, lp["w2"]))
 
 
@@ -1295,7 +1337,7 @@ def _mlp_half(config: DecoderConfig, layer: int, x, lp, kept: bool = False):
     return out
 
 
-# -- what the MLP halves' checkpoints keep --------------------------------------
+# -- what the halves' checkpoints keep ------------------------------------------
 
 #: What the rest of a step is taken to hold in temporaries when the kept
 #: products are given their room: rows of ``hidden_size`` in the compute
@@ -1316,6 +1358,36 @@ def kept_products_bytes(config: DecoderConfig, layer: int,
     return 2 * tokens * width * jnp.dtype(config.compute_dtype).itemsize
 
 
+def in_projections_bytes(config: DecoderConfig, layer: int,
+                         tokens: int) -> int:
+    """Bytes of what layer ``layer``'s first half's in-projections give
+    over ``tokens`` tokens, the products ``_project_in`` names: an
+    attention layer's q, k, v and head gate (a ``cross`` layer's q alone:
+    its keys and values are the ``full_attention`` layer's, counted
+    there and nowhere else), a ``mamba`` layer's ``z | x B C | dt``, a
+    ``mamba1`` layer's ``u | z``, ``r | B | C`` and ``r W_dt``, a ``gmu``
+    layer's ``norm(x) W_1``, a ``conv`` layer's ``B | C | u``."""
+    kind = config.layer_types[layer]
+    if kind == MAMBA:
+        width = (2 * config.mamba_width + 2 * config.mamba_state
+                 + config.mamba_heads)
+    elif kind == MAMBA1:
+        width = (3 * config.mamba1_width + config.mamba1_dt_rank
+                 + 2 * config.mamba1_state)
+    elif kind == GMU:
+        width = config.mamba1_width
+    elif kind == CONV:
+        width = 3 * config.hidden_size
+    else:
+        heads = config.heads(layer)
+        width = heads * config.head_dim
+        if kind != CROSS:
+            width += 2 * config.num_kv_heads * config.head_dim
+        if config.attention_gate:
+            width += heads
+    return tokens * width * jnp.dtype(config.compute_dtype).itemsize
+
+
 def _device_memory(mesh: Optional[Mesh]) -> Optional[Tuple[int, int]]:
     """The allocator's ``(bytes_limit, bytes_in_use)`` on the device the
     step is traced for, as it stands now: while a training step is traced
@@ -1330,7 +1402,7 @@ def _device_memory(mesh: Optional[Mesh]) -> Optional[Tuple[int, int]]:
 
 def keep_room(config: DecoderConfig, tokens: int,
               memory: Optional[Tuple[int, int]]) -> Optional[int]:
-    """Bytes the MLP halves' checkpoints may keep between them in a step
+    """Bytes the halves' checkpoints may keep between them in a step
     over ``tokens`` tokens traced with ``memory`` = ``(bytes_limit,
     bytes_in_use)`` on its device: the limit, less a sixteenth of it, less
     what is in use, less ``STEP_ROWS_A_TOKEN`` rows of ``hidden_size`` a
@@ -1343,20 +1415,57 @@ def keep_room(config: DecoderConfig, tokens: int,
             * config.hidden_size * jnp.dtype(config.compute_dtype).itemsize)
 
 
+def _taken_in_order(needs, room: Optional[int]):
+    """``(which of the layers' ``needs`` bytes ``room`` lasts for, what is
+    left of it)``: layers take theirs in order, and one it does not last
+    for (or that needs none) takes nothing. ``None``: no bound."""
+    kept = []
+    for need in needs:
+        keeps = need > 0 and (room is None or need <= room)
+        if keeps and room is not None:
+            room -= need
+        kept.append(keeps)
+    return tuple(kept), room
+
+
+def _mlp_halves_taken(config: DecoderConfig, tokens: int,
+                      room: Optional[int]):
+    """:func:`_taken_in_order` of the layers' SwiGLUs' kept products."""
+    return _taken_in_order(
+        [kept_products_bytes(config, layer, tokens)
+         for layer in range(config.num_layers)], room)
+
+
 def mlp_halves_kept(config: DecoderConfig, tokens: int,
                     room: Optional[int]) -> Tuple[bool, ...]:
     """Which layers' MLP halves keep their SwiGLU's ``x G`` and ``x U``
     across the checkpoint: layers take their bytes of ``room`` in order
     while it lasts, and a layer it does not last for is made again whole,
     as every layer was. ``None``: every SwiGLU keeps."""
-    kept = []
-    for layer in range(config.num_layers):
-        need = kept_products_bytes(config, layer, tokens)
-        keeps = need > 0 and (room is None or need <= room)
-        if keeps and room is not None:
-            room -= need
-        kept.append(keeps)
-    return tuple(kept)
+    return _mlp_halves_taken(config, tokens, room)[0]
+
+
+def first_halves_kept(config: DecoderConfig, tokens: int,
+                      room: Optional[int]) -> Tuple[bool, ...]:
+    """Which layers' first halves keep their in-projections across the
+    checkpoint, out of the same ``room``: the SwiGLUs take theirs first
+    (:func:`mlp_halves_kept`, so none of them goes without for a first
+    half's sake), then the first halves take theirs of what is left, in
+    layer order while it lasts. ``None``: every first half keeps."""
+    _, left = _mlp_halves_taken(config, tokens, room)
+    return _taken_in_order(
+        [in_projections_bytes(config, layer, tokens)
+         for layer in range(config.num_layers)], left)[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _keeping(*names: str):
+    """The checkpoint policy that keeps the arrays named ``names`` and a
+    half's input; ``None``, a plain checkpoint, for no name. One object a
+    set of names: JAX caches what it derives from a half by its policy's
+    identity, and layers alike then share one program."""
+    return (jax.checkpoint_policies.save_only_these_names(*names)
+            if names else None)
 
 
 def _checked(config: DecoderConfig) -> None:
@@ -1421,11 +1530,14 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     compute dtype, after the last layer's residual (before the final
     norm). Every layer is made again in the backward pass, but for its
     attention kernel's two results and, where the device's memory has
-    room, its SwiGLU's two first products. What later layers read of an
-    earlier one (a ``mamba1`` layer's scan output, a ``full_attention``
-    layer's keys and values) leaves its half's checkpoint as a result and
-    enters theirs as an argument: kept once, and autodiff sums what its
-    readers and its own layer hand back before its half's backward runs.
+    room, its SwiGLU's two first products and then its first half's
+    in-projections. What later layers read of an earlier one (a
+    ``mamba1`` layer's scan output, a ``full_attention`` layer's keys and
+    values) leaves its half's checkpoint as a result and enters theirs as
+    an argument: kept once (a full layer that keeps its in-projections
+    keeps those keys and values as the one array, and makes neither
+    again), and autodiff sums what its readers and its own layer hand
+    back before its half's backward runs.
 
     ``mesh``: the mesh the calling step is jitted over (``ops/embedding.py:
     lookup``'s convention). One device only: the expert layer's exchange
@@ -1445,8 +1557,7 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     # 8,192 tokens and 32 heads, against 19.5 ms (the whole triangle) or
     # 8.4 ms (a window) of kernel run a second time. The inline attention
     # names neither, and its half keeps its input only.
-    keep_kernel_results = jax.checkpoint_policies.save_only_these_names(
-        KEPT_OUT, KEPT_LSE)
+    kernel_results = (KEPT_OUT, KEPT_LSE)
     # Of an MLP half, its bf16 input and its SwiGLU's ``x G`` and ``x U``,
     # in the layers there is room for: 268 MB a layer of one row of 8,192
     # tokens and a width of 8,192 (2.68 GB over ten such layers, 2.01 GB
@@ -1455,51 +1566,72 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     # ``rsdl.lm.mlp`` with the two products run a second time (7.0 ms at
     # 10,240; PERF.md section 6, PR 41). The half's norm, the routed
     # experts and ``silu(x G) * (x U)`` are made again as they were.
-    keep_products = jax.checkpoint_policies.save_only_these_names(
-        KEPT_GATE, KEPT_UP)
+    keep_products = _keeping(KEPT_GATE, KEPT_UP)
     room = keep_room(config, token_ids.size, _device_memory(mesh))
     mlp_kept = mlp_halves_kept(config, token_ids.size, room)
+    # Of any first half, its in-projections too, in the layers there is
+    # room for once the SwiGLUs have theirs: 201 MB a ``conv`` layer's
+    # ``B | C | u`` at two rows of 8,192 tokens of 2,048 and 101 MB an
+    # attention layer's q, k, v there, 139 MB a Mamba layer's ``z | x B C
+    # | dt`` at one row, 270-338 MB a layer of 48 or 64 gated heads of 128
+    # at two, against one product a projection weight a step (PERF.md
+    # section 6, PR 46). The half's norm, head norms, rotary, convolution
+    # and scan are made again as they were.
+    first_kept = first_halves_kept(config, token_ids.size, room)
     if room is not None:
         # Set when a step is traced, as the counters beside it.
         rt_metrics.gauge(
             "rsdl_lm_mlp_keep_room_bytes",
-            "Bytes the device's memory had for the MLP halves' kept "
-            "products, last decoder traced").set(room)
+            "Bytes the device's memory had for the halves' kept products "
+            "(the SwiGLUs' first, then the first halves' in-projections), "
+            "last decoder traced").set(room)
+
+    def first_half(layer, half, *names, stats=False):
+        """``half`` of layer ``layer`` under its checkpoint, which keeps
+        ``names`` and, where there is room, the half's in-projections."""
+        kept = first_kept[layer]
+        if kept:
+            names += (KEPT_PROJ,)
+            # Counted when a half is traced, not when it runs.
+            rt_metrics.counter(
+                "rsdl_lm_proj_kept_total",
+                "Decoder layers' first halves traced whose checkpoint keeps "
+                "the outputs of their in-projections for the backward pass, "
+                "by the layer's kind: every layer the device's memory has "
+                "room for once the SwiGLUs have taken theirs",
+                kind=config.layer_types[layer]).inc()
+        half = functools.partial(half, config, layer, kept=kept)
+        # What a half records of the step's own counters leaves its
+        # checkpoint as an output (counted in the forward pass, not again
+        # when the half is made again).
+        made = jax.checkpoint(
+            tracing.with_step_stats(half) if stats else half,
+            policy=_keeping(*names))
+        return tracing.step_stats_of(made) if stats else made
+
     memory = kv = None      # the last mamba1 layer's y, full layer's k, v
     for layer in range(config.num_layers):
         # Each half is made again on its own in the backward pass: the
         # MLP half's backward runs before the attention half's q, k and v
-        # exist again, so the two halves' activations never sit on the
-        # chip together.
+        # exist again (or are read again, where they are kept), so the two
+        # halves' activations never sit on the chip together.
         lp = params[f"layer_{layer}"]
         layer_type = config.layer_types[layer]
-        # What a half records of the step's own counters leaves its
-        # checkpoint as an output (counted in the forward pass, not again
-        # when the half is made again).
         if layer_type == MAMBA:
-            x = tracing.step_stats_of(jax.checkpoint(tracing.with_step_stats(
-                functools.partial(_mamba_half, config, layer))))(x, lp)
+            x = first_half(layer, _mamba_half, stats=True)(x, lp)
         elif layer_type == MAMBA1:
-            x, memory = tracing.step_stats_of(jax.checkpoint(
-                tracing.with_step_stats(functools.partial(
-                    _mamba1_half, config, layer))))(x, lp)
+            x, memory = first_half(layer, _mamba1_half, stats=True)(x, lp)
         elif layer_type == GMU:
-            x = jax.checkpoint(functools.partial(_gmu_half, config, layer))(
-                x, lp, memory)
+            x = first_half(layer, _gmu_half)(x, lp, memory)
         elif layer_type == CONV:
-            x = jax.checkpoint(functools.partial(_conv_half, config, layer))(
-                x, lp)
+            x = first_half(layer, _conv_half)(x, lp)
         elif config.differential:
-            x, made = tracing.step_stats_of(jax.checkpoint(
-                tracing.with_step_stats(functools.partial(
-                    _differential_half, config, layer)),
-                policy=keep_kernel_results))(x, lp, kv)
+            x, made = first_half(layer, _differential_half, *kernel_results,
+                                 stats=True)(x, lp, kv)
             if layer_type == FULL:
                 kv = made
         else:
-            x = jax.checkpoint(
-                functools.partial(_attention_half, config, layer),
-                policy=keep_kernel_results)(x, lp)
+            x = first_half(layer, _attention_half, *kernel_results)(x, lp)
         x = tracing.step_stats_of(jax.checkpoint(
             tracing.with_step_stats(functools.partial(
                 _mlp_half, config, layer, kept=mlp_kept[layer])),

@@ -53,7 +53,9 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    inline, and window | full again for the layers whose checkpoint
     #    keeps the forward kernel's results; a SwiGLU's = dense | shared,
     #    and again for the layers whose checkpoint keeps its two first
-    #    products, beside the bytes the device's memory had for them; the
+    #    products, beside the bytes the device's memory had for them; a
+    #    first half whose checkpoint keeps its in-projections counts by
+    #    the layer's kind, a ``layer_types`` entry; the
     #    expert layer's = share | all of the router's experts held here;
     #    ops/moe.py counts what moves the walk's rows, dma | xla, and the
     #    router's scoring, softmax | sigmoid_bias) --
@@ -62,6 +64,7 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_keep_room_bytes": ("gauge", ()),
+    "rsdl_lm_proj_kept_total": ("counter", ("kind",)),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_gather_total": ("counter", ("kind",)),
     "rsdl_moe_router_total": ("counter", ("kind",)),

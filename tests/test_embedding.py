@@ -813,9 +813,10 @@ def test_the_mlp_half_keeps_two_products_on_the_chip(four_chips, hidden,
     dense SwiGLU through the TPU's own compiler, a row of 8,192 tokens:
     nine products a layer under ``rsdl.lm.mlp`` where the half's
     checkpoint keeps ``x G`` and ``x U``, eleven where there is no room
-    for them, and the kept ones hold their own bytes of the step's
-    temporaries and no more (no copy of them waits through the step, as a
-    kept log-sum-exp's once did)."""
+    for them, and what is kept (those two and, where there is room for
+    them too, the attention half's q, k and v) holds its own bytes of the
+    step's temporaries and no more (no copy of it waits through the step,
+    as a kept log-sum-exp's once did)."""
     from jax.sharding import SingleDeviceSharding
 
     from ray_shuffling_data_loader_tpu.models import mellum
@@ -849,4 +850,8 @@ def test_the_mlp_half_keeps_two_products_on_the_chip(four_chips, hidden,
     assert (kept, plain) == (9 * cfg.num_layers, 11 * cfg.num_layers)
     a_layer = mellum.kept_products_bytes(cfg, 0, 8192)
     assert a_layer == 2 * 8192 * width * 2
-    assert kept_temporaries - plain_temporaries <= cfg.num_layers * a_layer
+    # 32 : 8 heads of 64: q, k, v
+    q_k_v = mellum.in_projections_bytes(cfg, 0, 8192)
+    assert q_k_v == 8192 * (2048 + 2 * 512) * 2
+    assert kept_temporaries - plain_temporaries <= cfg.num_layers * (
+        a_layer + q_k_v)

@@ -20,7 +20,7 @@ from chipbench.references import mellum as ref
 from ray_shuffling_data_loader_tpu.models import mellum
 from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
 from ray_shuffling_data_loader_tpu.ops import moe
-from ray_shuffling_data_loader_tpu.runtime import metrics
+from ray_shuffling_data_loader_tpu.runtime import metric_names, metrics
 from tests.test_flash_attention import _pallas_calls
 
 
@@ -607,17 +607,23 @@ def _loss_under_plain_checkpoints(cfg, params, tokens):
         jnp.sum(targets != mellum.IGNORE_ID), 1)
 
 
-def _assert_equal_to_plain_checkpoints(cfg, params, tokens):
-    """The loss and every leaf's gradient, to the last bit."""
-    loss, grads = jax.value_and_grad(
-        lambda p: mellum.loss_fn(cfg, p, tokens))(params)
-    want_loss, want_grads = jax.value_and_grad(
-        lambda p: _loss_under_plain_checkpoints(cfg, p, tokens))(params)
+def _assert_equal_to_the_last_bit(got, want):
+    """Two ``(loss, gradients)``: the loss and every leaf."""
+    (loss, grads), (want_loss, want_grads) = got, want
     assert float(loss) == float(want_loss)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
-        np.testing.assert_array_equal(got, want,
+    for (path, leaf), want_leaf in zip(flat, jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(leaf, want_leaf,
                                       err_msg=jax.tree_util.keystr(path))
+
+
+def _assert_equal_to_plain_checkpoints(cfg, params, tokens):
+    """The loss and every leaf's gradient, to the last bit."""
+    _assert_equal_to_the_last_bit(
+        jax.value_and_grad(
+            lambda p: mellum.loss_fn(cfg, p, tokens))(params),
+        jax.value_and_grad(
+            lambda p: _loss_under_plain_checkpoints(cfg, p, tokens))(params))
 
 
 def test_the_gradient_runs_a_layers_forward_kernel_once(tiny_f32,
@@ -794,42 +800,81 @@ def _memory_with_room(cfg, tokens: int, room: int):
     return memory
 
 
-@pytest.mark.parametrize("room,kept", [
-    # the dense layer's 2 x 64 x 128 float32 and both shared experts' 2 x
-    # 64 x 32
-    (65536 + 2 * 16384, (True, True, True)),
-    (65536 + 16384, (True, True, False)),
-    (2 * 16384, (False, True, True)),    # not the wide one: the two after it
-    (16383, (False, False, False)),
-    (-(1 << 20), (False, False, False))],
+_LAYER_KINDS = (mellum.SLIDING, mellum.FULL, mellum.MAMBA, mellum.MAMBA1,
+                mellum.GMU, mellum.CROSS, mellum.CONV)
+_proj_counts = functools.partial(_counts, kinds=_LAYER_KINDS)
+# every SwiGLU of ``_laguna_f32`` (below): the dense layer's 2 x 64 x 128
+# float32 and both shared experts' 2 x 64 x 32
+_SWIGLUS = 65536 + 2 * 16384
+# its first halves' q, k, v and head gate over 64 tokens in float32: 6 or 8
+# heads and 2 x 2 key/value heads of 16, a gate a query head
+_SIX_HEADS, _EIGHT_HEADS = 64 * 4 * (6 * 17 + 64), 64 * 4 * (8 * 17 + 64)
+_NONE = (False, False, False)
+
+
+@pytest.mark.parametrize("room,kept,first", [
+    (_SWIGLUS, (True, True, True), _NONE),
+    (65536 + 16384, (True, True, False), _NONE),
+    # not the wide one: the two after it
+    (2 * 16384, (False, True, True), _NONE),
+    (16383, _NONE, _NONE),
+    (-(1 << 20), _NONE, _NONE),
+    # the SwiGLUs first, all of them; the first halves of what is left
+    (_SWIGLUS + 2 * _SIX_HEADS + _EIGHT_HEADS, (True, True, True),
+     (True, True, True)),
+    (_SWIGLUS + _SIX_HEADS + _EIGHT_HEADS, (True, True, True),
+     (True, True, False)),
+    (_SWIGLUS + _SIX_HEADS + _EIGHT_HEADS - 1, (True, True, True),
+     (True, False, True)),
+    (_SWIGLUS + _SIX_HEADS - 1, (True, True, True), _NONE),
+    # a SwiGLU goes without before any first half keeps
+    (65536 + 16384 + 16383, (True, True, False), _NONE)],
     ids=["fits", "fits_but_the_last", "fits_but_the_widest", "fits_not",
-         "the_step_alone_does_not"])
+         "the_step_alone_does_not", "every_first_half_fits_too",
+         "first_halves_but_the_last", "first_halves_but_the_widest",
+         "no_first_half_fits", "a_swiglu_short_and_no_first_half"])
 def test_the_layers_that_keep_are_those_the_memory_has_room_for(
-        room, kept, monkeypatch):
-    """What is kept follows the device's memory and the shapes: layers
-    take their room in order, a layer there is none for is made again
-    whole, the counter says which did, and the gradient is the same to the
-    last bit whichever did."""
+        room, kept, first, monkeypatch):
+    """What is kept follows the device's memory and the shapes: the
+    SwiGLUs take their room in layer order, then the first halves'
+    in-projections theirs of what is left, a half there is none for is
+    made again whole, the counters say which did, and the gradient is the
+    same to the last bit whichever did."""
     _, cfg = _laguna_f32()      # a dense layer, two with the shared expert
     params = mellum.init(cfg, jax.random.key(3))
     tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
                                 cfg.vocab_size, jnp.int32)
     memory = _memory_with_room(cfg, tokens.size, room)
     assert mellum.mlp_halves_kept(cfg, tokens.size, room) == kept
+    assert mellum.first_halves_kept(cfg, tokens.size, room) == first
+    assert [mellum.in_projections_bytes(cfg, i, tokens.size)
+            for i in range(cfg.num_layers)] == [_SIX_HEADS, _EIGHT_HEADS,
+                                                _SIX_HEADS]
     monkeypatch.setattr(mellum, "_device_memory", lambda mesh: memory)
     before = _mlp_counts("rsdl_lm_mlp_kept_total")
+    first_before = _proj_counts("rsdl_lm_proj_kept_total")
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: mellum.loss_fn(cfg, p, tokens)))(params)
     rose = [after - b for after, b in zip(
         _mlp_counts("rsdl_lm_mlp_kept_total"), before)]
     kinds = [cfg.mlp_type(i) for i, keeps in enumerate(kept) if keeps]
     assert rose == [kinds.count(mellum.DENSE), kinds.count(mellum.SPARSE)]
+    first_rose = [after - b for after, b in zip(
+        _proj_counts("rsdl_lm_proj_kept_total"), first_before)]
+    kinds = [cfg.layer_types[i] for i, keeps in enumerate(first) if keeps]
+    assert first_rose == [kinds.count(mellum.SLIDING),
+                          kinds.count(mellum.FULL), 0, 0, 0, 0, 0]
     needs = [mellum.kept_products_bytes(cfg, i, tokens.size)
              for i in range(cfg.num_layers)]
+    # the room before anything is taken
     assert metrics.get("rsdl_lm_mlp_keep_room_bytes").value == room
     swiglus = sum(need > 0 for need in needs)
     assert _products(jaxpr.jaxpr, mellum.MLP_SCOPE) == (
         11 * swiglus - 2 * sum(kept))
+    # q, k, v and the gate four products where they are made again, three
+    # where they are kept, ``wo`` three
+    assert _products(jaxpr.jaxpr, mellum.PROJ_SCOPE) == (
+        (4 * 4 + 3) * cfg.num_layers - 4 * sum(first))
     _assert_equal_to_plain_checkpoints(cfg, params, tokens)
 
 
@@ -842,6 +887,158 @@ def test_without_an_allocator_to_ask_every_swiglu_keeps():
     assert mellum.mlp_halves_kept(cfg, 64, None) == (True,) * 5
     assert mellum.mlp_halves_kept(mellum.mellum_tiny(), 64, None) == (
         False,) * 4
+    # and every first half, of any kind
+    assert mellum.first_halves_kept(cfg, 64, None) == (True,) * 5
+    assert mellum.first_halves_kept(mellum.phi4flash_tiny(), 64, None) == (
+        True,) * 6
+
+
+# -- what a first half's checkpoint keeps ------------------------------------------------
+
+# (tiny configuration, projection weights, in-projections among them)
+_DECODERS = [
+    (mellum.lfm2_tiny, 12, 7), (mellum.granite_tiny, 10, 6),
+    (mellum.phi4flash_tiny, 20, 14), (mellum.laguna_tiny, 25, 20),
+    (mellum.mellum_tiny, 16, 12)]
+_DECODER_IDS = ["lfm2", "granite", "phi4flash", "laguna", "mellum"]
+
+
+@pytest.fixture(scope="module", params=_DECODERS, ids=_DECODER_IDS)
+def decoder(request):
+    """A tiny configuration of each decoder, seeded parameters and tokens,
+    and its counts of projection weights and of in-projections."""
+    build, weights, in_projections = request.param
+    cfg = build()
+    params = mellum.init(cfg, jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
+                                cfg.vocab_size, jnp.int32)
+    return cfg, params, tokens, weights, in_projections
+
+
+def _no_room(mesh):
+    return 1 << 30, 1 << 30
+
+
+@pytest.mark.parametrize("memory,again", [(None, 0), (_no_room, 1)],
+                         ids=["kept", "made_again"])
+def test_the_gradient_runs_three_products_a_projection_weight(
+        decoder, memory, again, monkeypatch):
+    """Forward and autodiff's two backward, for every weight under
+    ``rsdl.lm.proj``, where the first half's checkpoint keeps its
+    in-projections (off the chip, every half); one more an in-projection
+    where there is no room and the half makes it again. The projection
+    that writes into the residual stream is dead in the half made again,
+    and runs three either way."""
+    cfg, params, tokens, weights, in_projections = decoder
+    if memory is not None:
+        monkeypatch.setattr(mellum, "_device_memory", memory)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: mellum.loss_fn(cfg, p, tokens)))(params)
+    assert _products(jaxpr.jaxpr, mellum.PROJ_SCOPE) == (
+        3 * weights + again * in_projections)
+
+
+def _loss_and_grads(cfg, params, tokens):
+    return jax.value_and_grad(
+        lambda p: mellum.loss_fn(cfg, p, tokens))(params)
+
+
+def test_keeping_the_in_projections_moves_no_bit(decoder, monkeypatch):
+    """A kept in-projection is the array the half made again would hold,
+    whatever the half's kind: the loss and every leaf's gradient are equal
+    to the last bit to those of plain checkpoints, which keep a half's
+    input and nothing else (operation by operation, as the kernels'
+    results above)."""
+    cfg, params, tokens, _, _ = decoder
+    got = _loss_and_grads(cfg, params, tokens)
+    monkeypatch.setattr(mellum, "_keeping", lambda *names: None)
+    _assert_equal_to_the_last_bit(got, _loss_and_grads(cfg, params, tokens))
+
+
+def test_the_kept_counter_counts_the_first_halves_by_kind(decoder,
+                                                          monkeypatch):
+    """One a first half whose checkpoint keeps its in-projections, by the
+    layer's kind: off the chip every layer, and none where there is no
+    room."""
+    cfg, params, tokens, _, _ = decoder
+    assert metric_names.METRIC_NAMES["rsdl_lm_proj_kept_total"] == (
+        "counter", ("kind",))
+
+    def rose():
+        before = _proj_counts("rsdl_lm_proj_kept_total")
+        jax.eval_shape(lambda p: mellum.loss_fn(cfg, p, tokens), params)
+        return [after - b for after, b in zip(
+            _proj_counts("rsdl_lm_proj_kept_total"), before)]
+
+    assert rose() == [cfg.layer_types.count(kind) for kind in _LAYER_KINDS]
+    monkeypatch.setattr(mellum, "_device_memory", _no_room)
+    assert rose() == [0] * len(_LAYER_KINDS)
+
+
+@pytest.mark.parametrize("build,tokens,kinds,total", [
+    # a conv layer's B | C | u of 3 x 2,048, the attention layer's q, k, v
+    (mellum.lfm2_24b_a2b_ep8_share, 16_384,
+     {mellum.CONV: 201_326_592, mellum.FULL: 100_663_296}, 905_969_664),
+    # a Mamba layer's z | x B C | dt of 4,096 + 4,352 + 64
+    (mellum.granite4_h_micro_period, 8192,
+     {mellum.MAMBA: 139_460_608, mellum.FULL: 50_331_648}, 1_305_477_120),
+    # u | z, r | B | C and r W_dt of 10,240 + 192 + 5,120; the full layer's
+    # k and v, which the cross layer reads, in the full layer's and not
+    # again in the cross layer's
+    (mellum.phi4_mini_flash_junction, 8192,
+     {mellum.MAMBA1: 254_803_968, mellum.SLIDING: 83_886_080,
+      mellum.FULL: 83_886_080, mellum.GMU: 83_886_080,
+      mellum.CROSS: 41_943_040}, 803_209_216),
+    # 48 or 64 heads of 128, 8 key/value heads, a gate a query head
+    (mellum.laguna_xs2_ep8_share, 16_384,
+     {mellum.FULL: 270_008_320, mellum.SLIDING: 337_641_472},
+     1_552_941_056),
+    (mellum.mellum2_ep4_share, 32_768,
+     {mellum.SLIDING: 335_544_320, mellum.FULL: 335_544_320},
+     1_342_177_280)],
+    ids=_DECODER_IDS)
+def test_a_first_halfs_bytes_follow_the_widths(build, tokens, kinds, total):
+    """What the in-projections of each kind of first half give at the
+    8,192-token cells' sizes in bf16, and a full differential layer's keys
+    and values counted once: where they are made."""
+    cfg = build()
+    needs = [mellum.in_projections_bytes(cfg, i, tokens)
+             for i in range(cfg.num_layers)]
+    assert dict(zip(cfg.layer_types, needs)) == kinds
+    assert sum(needs) == total
+
+
+@pytest.mark.parametrize("build,tokens,in_use,mlp,first", [
+    # 12 bytes a parameter in use when the step is traced
+    (mellum.lfm2_24b_a2b_ep8_share, 16_384, 12 * 469_285_248, 1, 5),
+    (mellum.phi4_mini_flash_junction, 8192, 12 * 697_073_792, 6, 6),
+    (mellum.laguna_xs2_ep8_share, 16_384, 12 * 691_623_936, 5, 1),
+    (mellum.mellum2_ep4_share, 32_768, 12 * 595_153_152, 0, 0)],
+    ids=["lfm2", "phi4flash", "laguna", "mellum"])
+def test_the_cells_first_halves_on_a_v5e(build, tokens, in_use, mlp, first):
+    """The rule at the cells' sizes under a v5e's allocator limit: every
+    SwiGLU keeps as it did, and the first halves keep in layer order until
+    the room ends (all of two cells', the first of ``laguna_train_8k``'s,
+    none where the room is negative)."""
+    cfg = build()
+    room = mellum.keep_room(cfg, tokens, (16_909_336_064, in_use))
+    kept = mellum.mlp_halves_kept(cfg, tokens, room)
+    assert sum(kept) == mlp == sum(
+        mellum.kept_products_bytes(cfg, i, tokens) > 0
+        for i in range(cfg.num_layers))
+    assert mellum.first_halves_kept(cfg, tokens, room) == (
+        (True,) * first + (False,) * (cfg.num_layers - first))
+
+
+def test_granites_first_halves_skip_the_one_that_does_not_fit():
+    """``granite_train_8k`` on a v5e: four Mamba layers' in-projections
+    fit after the ten SwiGLUs, the fifth's do not, the attention layer's
+    (a third of their size) do."""
+    cfg = mellum.granite4_h_micro_period()
+    room = mellum.keep_room(cfg, 8192, (16_909_336_064, 12 * 772_160_448))
+    assert mellum.mlp_halves_kept(cfg, 8192, room) == (True,) * 10
+    assert mellum.first_halves_kept(cfg, 8192, room) == (
+        (True,) * 4 + (False, True) + (False,) * 4)
 
 
 def test_lagunas_parameter_count_and_flops():
