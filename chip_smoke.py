@@ -96,6 +96,10 @@ class SmokeSize:
     # (query heads, key heads, a key's width, window, lengths)
     wide_attention: Tuple[Tuple[int, int, int, Optional[int],
                                 Tuple[int, ...]], ...]
+    # its attention under block diffusion's mask, one row that holds a
+    # sequence twice: (query heads, key/value heads, block length, clean
+    # length)
+    diffusion_attention: Tuple[Tuple[int, int, int, int], ...]
     # its expert layer: (tokens, hidden, width, experts, held, top_k, tile,
     # what a token's weights sum to)
     moe_shapes: Tuple[Tuple[int, int, int, int, int, int, int, float], ...]
@@ -131,6 +135,8 @@ def full_size() -> SmokeSize:
         # heads of 64 and 10 value heads of 128
         wide_attention=((40, 20, 64, None, (2048,)),
                         (40, 20, 64, 512, (2048,))),
+        # sdar_train_8k's layers: one row of 16,384 positions
+        diffusion_attention=((32, 4, 4, 8192),),
         moe_shapes=((8192, 2304, 896, 64, 16, 8, 1152, 1.0),
                     (16384, 2048, 512, 256, 32, 8, 640, 2.5)),
         # granite_train_8k's nine Mamba layers
@@ -157,6 +163,7 @@ def tiny_size() -> SmokeSize:
         masked_attention_dim=16,
         masked_attention=((4, 1, 24, False, (40,)), (6, 2, 8, True, (40,))),
         wide_attention=((8, 4, 16, 8, (40,)),),
+        diffusion_attention=((4, 2, 4, 32),),
         moe_shapes=((48, 16, 8, 8, 2, 2, 8, 2.5),
                     (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
         ssd_shapes=((1, 256, 2, 64, 128, 128),),
@@ -292,6 +299,7 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
         _check_attention(flash, seq, b, h, d)
     _masked_attention_checks(size, interpret)
     _wide_attention_checks(size, interpret)
+    _diffusion_attention_checks(size, interpret)
     _check_partial_rotary(size.masked_attention_dim)
     for shape in size.moe_shapes:
         _check_moe(shape, interpret)
@@ -398,6 +406,84 @@ def _wide_attention_checks(size: SmokeSize, interpret: bool) -> None:
                 what=(f"{heads}:{kv_heads}:{kv_heads // 2} heads of {d} "
                       f"over values of {2 * d}, causal"
                       + (f", window {span}" if span else "")))
+
+
+def _diffusion_attention_checks(size: SmokeSize, interpret: bool,
+                                tol: float = 2e-2) -> None:
+    """The decoder's attention under block diffusion's mask, one row of 2
+    L positions off its projections, the decoder's own kernels, tiles and
+    custom_vjp: output and the three gradients against the inline form
+    (``mellum._inline_attention`` under the same booleans), which is run a
+    query head at a time (a head's (2 L, 2 L) float32 scores are 1 GB at
+    the cell's shape, and autodiff keeps four such) and summed over a
+    key/value head's group."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+
+    d = size.masked_attention_dim
+    for heads, kv_heads, block, length in size.diffusion_attention:
+        diffusion, group = (block, length), heads // kv_heads
+        keys = jax.random.split(jax.random.key(43), 4)
+        q, k, v, w = (jax.random.normal(key, (1, 2 * length, n * d),
+                                        jnp.bfloat16)
+                      for key, n in zip(keys, (heads, kv_heads, kv_heads,
+                                               heads)))
+
+        def kernels(q, k, v):
+            return mellum._flash_attention(q, k, v, None, heads, kv_heads,
+                                           None, None, None, None, diffusion)
+
+        if not interpret:
+            _check(_mosaic_calls(jax.jit(kernels), q, k, v) == 1,
+                   "attention under block diffusion's mask did not lower "
+                   "to a Mosaic kernel")
+        @jax.jit
+        def both(q, k, v):
+            out, vjp = jax.vjp(kernels, q, k, v)
+            return (out, *vjp(w))
+
+        seen = jax.jit(lambda: fa.diffusion_seen(*diffusion))()
+
+        @jax.jit
+        def one_head(q, k, v, w):
+            out, vjp = jax.vjp(
+                lambda q, k, v: mellum._inline_attention(
+                    q.astype(jnp.float32), k.astype(jnp.float32),
+                    v.astype(jnp.float32), None, 1, 1, None, None, None,
+                    None, seen), q, k, v)
+            return (out, *vjp(w.astype(jnp.float32)))
+
+        got = both(q, k, v)
+        worst = {name: 0.0 for name in ("out", "dq", "dk", "dv")}
+
+        def gap(mine, theirs):
+            return float(jnp.max(jnp.abs(mine.astype(jnp.float32) - theirs))
+                         / jnp.max(jnp.abs(theirs)))
+
+        for kv in range(kv_heads):
+            cols = slice(kv * d, (kv + 1) * d)
+            dk = dv = 0.0
+            for head in range(kv * group, (kv + 1) * group):
+                mine = slice(head * d, (head + 1) * d)
+                out, dq, dk_h, dv_h = one_head(q[..., mine], k[..., cols],
+                                               v[..., cols], w[..., mine])
+                worst["out"] = max(worst["out"], gap(got[0][..., mine], out))
+                worst["dq"] = max(worst["dq"], gap(got[1][..., mine], dq))
+                dk, dv = dk + dk_h, dv + dv_h
+            worst["dk"] = max(worst["dk"], gap(got[2][..., cols], dk))
+            worst["dv"] = max(worst["dv"], gap(got[3][..., cols], dv))
+        what = (f"{heads}:{kv_heads} heads of {d} under block diffusion's "
+                f"mask, {length} tokens twice in blocks of {block}")
+        _info(f"kernels: {what}: max|kernels-inline| / max|inline| "
+              + ", ".join(f"{name} {value:.3e}"
+                          for name, value in worst.items())
+              + f" (tol {tol:.0e})")
+        for name, value in worst.items():
+            _check(value <= tol, f"{what}: {name} differs from the inline "
+                   f"form: {value} > {tol}")
 
 
 def _check_partial_rotary(dim: int, tol: float = 2e-2) -> None:

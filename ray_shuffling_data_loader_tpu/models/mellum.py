@@ -4,7 +4,7 @@ Memory Units and cross-attention over an earlier layer's keys and values,
 with dense or sparse-expert MLPs: one chip's share of a language model, by
 its configuration (``mellum2_ep4_share``, ``laguna_xs2_ep8_share``,
 ``granite4_h_micro_period``, ``phi4_mini_flash_junction``,
-``lfm2_24b_a2b_ep8_share``).
+``lfm2_24b_a2b_ep8_share``, ``sdar_30b_a3b_ep8_share``).
 
 Per layer, on the residual stream: RMSNorm, grouped-query attention (a
 layer's own count of query heads over ``num_kv_heads`` key/value heads,
@@ -76,6 +76,21 @@ that holds a share of the experts sees the router's gradient through its
 own experts alone, and that part applied alone draws every token's picks
 onto them. ``yarn`` ``None`` gives a full layer plain rotary, as a window
 layer's.
+
+A second objective (SDAR, arXiv 2510.06303, after BD3-LMs, Arriola et al.
+2025). With ``diffusion_block`` B the decoder trains by block diffusion: a
+row ``x_0`` of L tokens is cut into blocks of B, each block draws a noise
+level ``t ~ U[0, 1)`` and each of its tokens becomes ``mask_token_id``
+with probability ``p = (1 - diffusion_eps) t + diffusion_eps``
+(:func:`diffusion_noise`, from a key that is an argument of the step);
+the decoder reads the clean row and the noised one as ONE row of 2 L
+positions, clean first, both copies at rotary positions 0..L-1, under one
+mask (``ops/flash_attention.py:_Mask``): a clean query sees the clean
+blocks up to and with its own, a noised query its own noised block and the
+clean blocks strictly before it, nothing clean sees anything noised. The
+final norm and the head run on the noised copy alone and the loss is
+``sum over the masked positions of -log p(x_0[i]) / p_block(i)`` at the
+same position (no shift), over ``rows x L``. Full attention layers only.
 
 Under expert parallelism a chip holds ``experts_held = (first, count)`` of
 the router's ``num_experts`` and a slice of the vocabulary: the expert
@@ -166,6 +181,16 @@ SCONV_SCOPE = sconv.SCOPE
 MOE_SCOPE = moe.SCOPE
 MLP_SCOPE = "rsdl.lm.mlp"
 HEAD_SCOPE = "rsdl.lm.head"
+# Block diffusion's draw of the noise, the masking and the joining of the
+# clean and the noised copy into one row (``_noised_beside_clean``).
+NOISE_SCOPE = "rsdl.lm.noise"
+# The element-wise passes between the products and the kernels (PR 47: 30 %
+# of ``sdar_train_8k``'s step ran under no scope, PERF.md section 5): a
+# norm of the residual stream or of the q and k heads, float32 inside
+# (``_norm``, ``_head_norm``), and the rotary over the q and k heads
+# (``_rope``), with the casts, copies and reshapes XLA makes for them.
+NORM_SCOPE = "rsdl.lm.norm"
+ROPE_SCOPE = "rsdl.lm.rope"
 
 # What an attention half's checkpoint keeps of the forward kernel
 # (``_flash_attention_fwd`` names them, ``decode``'s policy saves them).
@@ -259,6 +284,12 @@ class DecoderConfig:
     # published model, which sets its ``lambda_init``
     differential: bool = False
     published_indices: Optional[Tuple[int, ...]] = None
+    # block diffusion (module docstring): the block length, 0 for the
+    # next-token objective; the id a masked token becomes; the least
+    # masking probability of a block
+    diffusion_block: int = 0
+    mask_token_id: int = 3
+    diffusion_eps: float = 1e-3
     compute_dtype: Any = jnp.bfloat16
     published_layers: int = 28    # the uncut depth: scales ``init`` only
 
@@ -444,6 +475,42 @@ def lfm2_tiny() -> DecoderConfig:
             expert_bias_speed=1e-4)})
 
 
+_SDAR_LAYERS = dict(
+    qk_norm=True, router_trains=False, yarn=None, rope_theta=1_000_000.0,
+    rms_norm_eps=1e-6, diffusion_block=4, mask_token_id=3,
+    diffusion_eps=1e-3, published_layers=48)
+
+
+def sdar_30b_a3b_ep8_share() -> DecoderConfig:
+    """SDAR-30B-A3B-Chat at its published widths, cut to one chip of eight
+    that share each layer by expert parallelism: 16 of the 128 experts of
+    768 (softmax over all 128, top 8, renormalised), an eighth of the
+    vocabulary padded to 152,576 under an untied head, and six of the 48
+    layers, all alike (full attention of 32:4 normed heads of 128 under
+    plain rotary at theta 1e6, every MLP sparse): one pipeline stage of
+    eight. It trains by block diffusion in blocks of 4."""
+    return DecoderConfig(
+        vocab_size=19_072, hidden_size=2048, layer_types=6 * (FULL,),
+        num_heads=32, num_kv_heads=4, head_dim=128, num_experts=128,
+        experts_held=(0, 16), top_k=8, expert_width=768, **_SDAR_LAYERS)
+
+
+def sdar_tiny() -> DecoderConfig:
+    """For tests/CPU smoke runs: SDAR's layer twice at 4:2 heads of 16, 8
+    experts of 32 of which the first two are held, top-2, blocks of 4
+    (rows of 32 tokens are 64 positions)."""
+    return DecoderConfig(**{
+        **_SDAR_LAYERS, **dict(
+            vocab_size=512, hidden_size=64, layer_types=2 * (FULL,),
+            num_heads=4, num_kv_heads=2, head_dim=16, num_experts=8,
+            experts_held=(0, 2), top_k=2, expert_width=32)})
+
+
+#: The standard deviation of the seeded embedding row of a block-diffusion
+#: configuration's mask token (``init``).
+MASK_ROW_STD = 1e-4
+
+
 def lambda_init(published_index: int) -> float:
     """A differential attention layer's ``lambda_init`` at its depth in
     the published model (Ye et al. 2024)."""
@@ -472,7 +539,13 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     which changes a pick of one token in seventeen and leaves the experts'
     loads near even until the balancing update has them (at zero ``score
     + bias`` and ``score`` pick alike, and nothing would show a pick that
-    left the bias out)."""
+    left the bias out). A block-diffusion configuration's mask token's
+    row N(0, ``MASK_ROW_STD``): a token the checkpoint the diffusion
+    training starts from never saw. At the other rows' size every masked
+    position, a quarter of the 2 L, carries the one row and routes to the
+    one set of experts, which no chip of a trained model sees; this small,
+    a masked position's stream is what attention writes into it, and it
+    routes by its context."""
     h, f = config.hidden_size, config.expert_width
     kv_width = config.num_kv_heads * config.head_dim
     held = config.experts_held[1]
@@ -552,6 +625,9 @@ def init(config: DecoderConfig, key: jax.Array) -> Dict[str, Any]:
     else:
         params["embed"] = normal((config.vocab_size, h), 1.0)
         params["head"] = normal((h, config.vocab_size))
+    if config.diffusion_block:
+        params["embed"] = params["embed"].at[config.mask_token_id].multiply(
+            MASK_ROW_STD / (0.02 if config.tie_embeddings else 1.0))
     for layer in range(config.num_layers):
         heads = config.heads(layer)
         q_width = heads * config.head_dim
@@ -643,6 +719,16 @@ def _rope_tables(config: DecoderConfig, layer_type: str, seq_len: int):
             jnp.pad(jnp.sin(angles) * scale, passed))
 
 
+def _twice_rope_tables(config: DecoderConfig, layer_type: str,
+                       seq_len: int):
+    """``_rope_tables`` for a row of ``seq_len`` = 2 L positions that holds
+    a sequence twice (block diffusion's clean and noised copy): both
+    copies stand at positions 0..L-1, so the tables are made for L and
+    read twice."""
+    return tuple(jnp.concatenate([table, table]) for table in
+                 _rope_tables(config, layer_type, seq_len // 2))
+
+
 def _rotate_half(dim: int, rotated: int, dtype):
     """(D, D) of 0 and +-1: ``x @ it`` is ``concat(-x2, x1, 0)`` for
     ``x = concat(x1, x2, rest)``, x1 and x2 the halves of the first
@@ -660,12 +746,13 @@ def _rope(x, heads: int, cos, sin, rotated: int):
     """(B, S, heads x D) -> the same, the first ``rotated`` of each head's
     D rotated by position."""
     b, s, width = x.shape
-    x = x.reshape(b, s, heads, width // heads)
-    turned = jnp.einsum("bshd,de->bshe", x,
-                        _rotate_half(x.shape[-1], rotated, x.dtype))
-    out = (x.astype(jnp.float32) * cos[:, None, :]
-           + turned.astype(jnp.float32) * sin[:, None, :])
-    return out.astype(x.dtype).reshape(b, s, width)
+    with jax.named_scope(ROPE_SCOPE):
+        x = x.reshape(b, s, heads, width // heads)
+        turned = jnp.einsum("bshd,de->bshe", x,
+                            _rotate_half(x.shape[-1], rotated, x.dtype))
+        out = (x.astype(jnp.float32) * cos[:, None, :]
+               + turned.astype(jnp.float32) * sin[:, None, :])
+        return out.astype(x.dtype).reshape(b, s, width)
 
 
 # -- attention -----------------------------------------------------------------
@@ -686,13 +773,16 @@ def _gated(out, gate, heads: int):
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
                       window: Optional[int], scale: Optional[float] = None,
-                      out_dtype=None, v_heads: Optional[int] = None):
+                      out_dtype=None, v_heads: Optional[int] = None,
+                      seen=None):
     """Causal grouped-query attention as XLA has it: float32 softmax over
     materialized (B, H, S, S) scores, times ``scale`` (``None``: over the
     root of a head's dimensions), the output in ``out_dtype`` (``None``:
     v's); ``v`` of ``v_heads`` heads (``None``: ``kv_heads``) at a width
     of their own, each read by the query heads of ``kv_heads / v_heads``
-    key heads; the backward is autodiff's."""
+    key heads; the backward is autodiff's. ``seen`` (S, S) booleans, query
+    by key, take the causal mask's place where the mask is another
+    (block diffusion's: ``flash_attention.diffusion_seen``)."""
     with jax.named_scope(ATTENTION_SCOPE):
         b, s, _ = q.shape
         q = q.reshape(b, s, kv_heads, heads // kv_heads, -1)
@@ -701,10 +791,11 @@ def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32)
         scores = (scores / jnp.sqrt(q.shape[-1]) if scale is None
                   else scores * scale)
-        ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
-        seen = ahead >= 0
-        if window is not None:
-            seen &= ahead < window
+        if seen is None:
+            ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+            seen = ahead >= 0
+            if window is not None:
+                seen &= ahead < window
         weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
         # a value head's maps: its key heads' query heads, side by side
         weights = weights.reshape(b, v.shape[2], -1, s, s)
@@ -713,7 +804,9 @@ def _inline_attention(q, k, v, gate, heads: int, kv_heads: int,
         return _gated(out.reshape(b, s, -1), gate, heads)
 
 
-def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
+def _blocks(window: Optional[int], backward: bool,
+            diffusion: Optional[flash_attention.Diffusion] = None
+            ) -> Tuple[int, int]:
     """The kernels' tiles: the defaults over the whole triangle; in a
     window layer the window's width in the forward and half of it in the
     backward, neither under 512 nor over the default. A band of 1,024
@@ -735,30 +828,55 @@ def _blocks(window: Optional[int], backward: bool) -> Tuple[int, int]:
     256 x 512 11.2 / 14.7, 512 x 256 16.2 / 14.2, 128 x 512 16.9 / 19.0,
     512 x 128 31.4 / 24.4. 48 : 8 heads over the whole triangle: 1024
     16.9 / 30.9, 512 28.9 / 35.2, 512 x 1024 19.4 / 32.9, 1024 x 512
-    30.2 / 32.4, 2048 x 1024 17.6 / 34.4."""
+    30.2 / 32.4, 2048 x 1024 17.6 / 34.4.
+
+    **Under block diffusion's mask** (``diffusion``; PR 47) the defaults,
+    no larger than a copy. At ``sdar_train_8k``'s shape (one row of 8,192
+    tokens twice, 32 : 4 heads, blocks of 4; ``python3 -m
+    chipbench.probes.sdar_kernels`` on a v5e; forward / backward; tiles
+    visited a head): 1024 11.9 / 22.3 (80), 512 20.4 / 24.6 (288), 512 x
+    1024 14.0 / 23.7 (160), 1024 x 512 21.7 / 23.5 (160), 256 47.5 / 46.1
+    (1,088); 2048 x 1024 does not fit the one-kernel backward's VMEM and
+    the dq + dk/dv pair refuses the mask. The plainly causal walk over the
+    same 16,384 positions, for scale: 1024 18.7 / 35.9. How the walk
+    reaches a noised query block's own noised key block: the index map
+    sends the step after the run's last there
+    (``flash_attention._diffusion_visit``), which costs a tile of 1,024 x
+    1,024 for 4 live keys a query, 8 of the 80 tiles; two calls joined by
+    their lse (the ring's way) would read q twice and write out and lse
+    twice, and a kernel of its own for the in-block part (4 keys a query)
+    would save those 8 tiles, a tenth of the kernels' time: neither was
+    built (``PERF.md`` section 7)."""
     side = flash_attention.DEFAULT_BLOCK_Q
     if window is not None:
         side = min(side, max(512, window // 2 if backward else window))
+    if diffusion is not None:
+        side = min(side, diffusion[1])      # a tile lies in one copy
     return side, side
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_attention(q, k, v, gate, heads: int, kv_heads: int,
                      window: Optional[int], scale: Optional[float] = None,
-                     out_dtype=None, v_heads: Optional[int] = None):
-    """``_inline_attention``'s result from the blocked Pallas kernels."""
+                     out_dtype=None, v_heads: Optional[int] = None,
+                     diffusion: Optional[flash_attention.Diffusion] = None):
+    """``_inline_attention``'s result from the blocked Pallas kernels;
+    ``diffusion`` = ``(block length, clean length)`` names block
+    diffusion's mask where ``_inline_attention`` takes its booleans."""
     return _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window,
-                                scale, out_dtype, v_heads)[0]
+                                scale, out_dtype, v_heads, diffusion)[0]
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale,
-                         out_dtype=None, v_heads=None):
+                         out_dtype=None, v_heads=None, diffusion=None):
     with jax.named_scope(ATTENTION_SCOPE):
         out, lse = flash_attention.grouped_forward(
-            q, k, v, heads, kv_heads, True, window, *_blocks(window, False),
+            q, k, v, heads, kv_heads, diffusion is None, window,
+            *_blocks(window, False, diffusion),
             interpret=not on_tpu(), scale=scale, out_dtype=out_dtype,
-            num_v_heads=v_heads)
+            num_v_heads=v_heads, diffusion=diffusion)
         # The two residuals the half's checkpoint keeps (``decode``), so
         # that the backward pass has them without this kernel run again;
         # q, k, v and the gate it makes again. lse without the column's
@@ -775,9 +893,9 @@ def _flash_attention_fwd(q, k, v, gate, heads, kv_heads, window, scale,
         return _gated(out, gate, heads), (q, k, v, gate, out, lse)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
 def _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype, v_heads,
-                         residuals, cotangent):
+                         diffusion, residuals, cotangent):
     q, k, v, gate, out, lse = residuals
     with jax.named_scope(ATTENTION_SCOPE):
         if out_dtype is not None:
@@ -794,20 +912,22 @@ def _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype, v_heads,
                 axis=-1)
             cotangent = _gated(cotangent, gate, heads)
         return (*flash_attention.grouped_backward(
-            q, k, v, out, lse[..., None], cotangent, heads, kv_heads, True,
-            window, *_blocks(window, True), interpret=not on_tpu(),
-            scale=scale, num_v_heads=v_heads), d_gate)
+            q, k, v, out, lse[..., None], cotangent, heads, kv_heads,
+            diffusion is None, window, *_blocks(window, True, diffusion),
+            interpret=not on_tpu(), scale=scale, num_v_heads=v_heads,
+            diffusion=diffusion), d_gate)
 
 
 def _counted_flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
-                                 v_heads, residuals, cotangent):
+                                 v_heads, diffusion, residuals, cotangent):
     # Counted here, once a layer: the program under it is traced once.
     q, k, v = residuals[:3]
     flash_attention.count_backward(flash_attention.grouped_backward_kind(
-        q, k, heads, *_blocks(window, True), interpret=not on_tpu(),
+        q, k, heads, *_blocks(window, True, diffusion),
+        interpret=not on_tpu(),
         value_dim=v.shape[-1] // (v_heads or kv_heads)))
     return _flash_attention_bwd(heads, kv_heads, window, scale, out_dtype,
-                                v_heads, residuals, cotangent)
+                                v_heads, diffusion, residuals, cotangent)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _counted_flash_attention_bwd)
@@ -832,13 +952,17 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
     if window is not None and window >= seq_len:
         window = None       # the band covers the triangle: nothing to cut
     flash = flash_attention.beats_inline(seq_len)
-    kind = ("inline" if not flash else
-            "window" if window is not None else "full")
+    # block diffusion: the row is the sequence twice, clean copy first
+    diffusion = ((config.diffusion_block, seq_len // 2)
+                 if config.diffusion_block else None)
+    kind = ("inline" if not flash else "window" if window is not None
+            else "full" if diffusion is None else "diffusion")
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_lm_attention_total",
         "Decoder layers' attentions traced, by what computes them (the "
-        "Pallas kernels over a window's band or the whole triangle, or "
+        "Pallas kernels over a window's band, the whole triangle or block "
+        "diffusion's mask, or "
         "XLA's inline softmax over materialized scores) and by their "
         "values: shaped as the keys, or wide, key heads sharing a value "
         "head of a width of its own so that a map's scores are made once",
@@ -851,8 +975,47 @@ def _attention(config: DecoderConfig, q, k, v, gate, layer_type: str,
             "backward pass: every layer the kernels compute",
             kind=kind).inc()
     attend = _flash_attention if flash else _inline_attention
+    if flash and diffusion is not None:
+        _count_diffusion_tiles(diffusion)
+    # the kernels take block diffusion's mask by its three numbers, XLA as
+    # booleans; neither takes anything where the mask is causal
     return attend(q, k, v, gate, heads, kv_heads, window,
-                  config.attention_multiplier, out_dtype, v_heads)
+                  config.attention_multiplier, out_dtype, v_heads,
+                  diffusion if flash or diffusion is None
+                  else flash_attention.diffusion_seen(*diffusion))
+
+
+def _count_diffusion_tiles(diffusion: flash_attention.Diffusion) -> None:
+    """What block diffusion's mask lets through and what the kernels walk
+    to cover it, a head of a layer, forward and backward."""
+    block, clean_len = diffusion
+    # Set when a layer is traced, not when it runs.
+    for direction, backward in (("forward", False), ("backward", True)):
+        bq, bk = flash_attention.planned_blocks(
+            2 * clean_len, *_blocks(None, backward, diffusion),
+            interpret=not on_tpu())
+        visited, compared, live = flash_attention.diffusion_tiles(
+            block, clean_len, bq, bk)
+        rt_metrics.gauge(
+            "rsdl_lm_attention_tiles_visited",
+            "Tiles the attention kernels visit for one head of one layer "
+            "under block diffusion's mask, those that hold a live pair, "
+            "last layer traced", direction=direction).set(visited)
+        rt_metrics.gauge(
+            "rsdl_lm_attention_tiles_compared",
+            "Of the tiles visited, those that compare positions (the mask "
+            "cuts them), last layer traced",
+            direction=direction).set(compared)
+        rt_metrics.gauge(
+            "rsdl_lm_attention_tile_pairs",
+            "Query-key pairs in the tiles visited, what the kernels "
+            "compute for one head of one layer, last layer traced",
+            direction=direction).set(visited * bq * bk)
+        rt_metrics.gauge(
+            "rsdl_lm_attention_live_pairs",
+            "Query-key pairs block diffusion's mask lets through for one "
+            "head of one layer, what the attention needs, last layer "
+            "traced", direction=direction).set(live)
 
 
 # -- differential attention ----------------------------------------------------------
@@ -1013,10 +1176,11 @@ def _layer_norm(x, scale, bias, eps: float):
 def _norm(config: DecoderConfig, x, p, name: str):
     """The configuration's norm of the residual stream under ``p``'s
     scale ``name`` (and, LayerNorm, its bias ``name_bias``)."""
-    if config.norm == LAYER_NORM:
-        return _layer_norm(x, p[name], p[f"{name}_bias"],
-                           config.rms_norm_eps)
-    return _rms_norm(x, p[name], config.rms_norm_eps)
+    with jax.named_scope(NORM_SCOPE):
+        if config.norm == LAYER_NORM:
+            return _layer_norm(x, p[name], p[f"{name}_bias"],
+                               config.rms_norm_eps)
+        return _rms_norm(x, p[name], config.rms_norm_eps)
 
 
 def _experts(config: DecoderConfig, layer: int, x, lp):
@@ -1097,8 +1261,9 @@ def _head_norm(x, heads: int, scale, eps: float):
     """(B, S, heads x D) -> the same, RMSNorm over each head's D under
     the one ``scale`` (D,), float32 inside."""
     b, s, width = x.shape
-    return _rms_norm(x.reshape(b, s, heads, width // heads), scale,
-                     eps).reshape(b, s, width)
+    with jax.named_scope(NORM_SCOPE):
+        return _rms_norm(x.reshape(b, s, heads, width // heads), scale,
+                         eps).reshape(b, s, width)
 
 
 def _attention_half(config: DecoderConfig, layer: int, x, lp,
@@ -1109,7 +1274,8 @@ def _attention_half(config: DecoderConfig, layer: int, x, lp,
     gate as their products left them (what the caller's policy does)."""
     layer_type, heads = config.layer_types[layer], config.heads(layer)
     if config.rotary:
-        cos, sin = _rope_tables(config, layer_type, x.shape[1])
+        cos, sin = (_twice_rope_tables if config.diffusion_block
+                    else _rope_tables)(config, layer_type, x.shape[1])
         rotated = rotated_dims(config, layer_type)
 
     def placed(projected, count, scale):
@@ -1508,6 +1674,16 @@ def _checked(config: DecoderConfig) -> None:
                              "its keys and values")
     if CROSS in kinds and not config.differential:
         raise ValueError("a cross layer is differential attention")
+    if config.diffusion_block and (set(kinds) != {FULL}
+                                   or config.differential):
+        raise ValueError(
+            "block diffusion's mask is defined for full attention only: "
+            "not beside a window, a convolution, a scan, a memory unit, "
+            "cross-attention or differential attention (got layer_types "
+            f"{sorted(set(kinds))}, differential={config.differential})")
+    if config.diffusion_block < 0 or not 0.0 < config.diffusion_eps <= 1.0:
+        raise ValueError("diffusion_block counts tokens and diffusion_eps "
+                         "is a probability above 0")
     if config.differential:
         if (config.published_indices is None
                 or len(config.published_indices) != config.num_layers):
@@ -1538,6 +1714,12 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
     keeps those keys and values as the one array, and makes neither
     again), and autodiff sums what its readers and its own layer hand
     back before its half's backward runs.
+
+    With ``diffusion_block`` a row of ``token_ids`` is a sequence twice,
+    the clean copy and then the noised one (:func:`diffusion_noise`), S =
+    2 L positions that every layer's attention reads under block
+    diffusion's mask, both copies at rotary positions 0..L-1; what the
+    halves keep is counted over the 2 L.
 
     ``mesh``: the mesh the calling step is jitted over (``ops/embedding.py:
     lookup``'s convention). One device only: the expert layer's exchange
@@ -1654,10 +1836,12 @@ def head_block_size(tokens: int) -> int:
     return min(HEAD_BLOCK_TOKENS, 8 * -(-tokens // 8))
 
 
-def _block_nll(x, head, targets, logits_scaling: float = 1.0):
+def _block_nll(x, head, targets, logits_scaling: float = 1.0,
+               weights=None):
     """Summed cross-entropy of the tokens of ``x`` (n, h) whose
     ``targets`` (n,) are not ``IGNORE_ID``, the logits divided by
-    ``logits_scaling``."""
+    ``logits_scaling``, each token's times its ``weights`` (n,) where
+    there are any."""
     mask = targets != IGNORE_ID
     logits = jnp.dot(x, head, preferred_element_type=jnp.float32)
     if logits_scaling != 1.0:
@@ -1665,6 +1849,8 @@ def _block_nll(x, head, targets, logits_scaling: float = 1.0):
     logp = jax.nn.log_softmax(logits, axis=-1)
     picked = jnp.take_along_axis(
         logp, jnp.where(mask, targets, 0)[:, None], axis=-1)[:, 0]
+    if weights is not None:
+        picked = picked * weights
     return jnp.sum(jnp.where(mask, -picked, 0.0))
 
 
@@ -1672,61 +1858,69 @@ def _block_of(a, k, block):
     return jax.lax.dynamic_slice_in_dim(a, k * block, block, axis=0)
 
 
-def _flat_padded(x, targets, block):
+def _flat_padded(x, targets, block, weights=None):
     """(B, S, h) and (B, S) as tokens (N, h) and (N,), padded with
     ignored tokens to whole blocks: a block is then a run of rows, which a
-    loop reads and writes in place."""
+    loop reads and writes in place. With ``weights`` (B, S), those (N,)
+    as a third."""
     xs, ts = x.reshape(-1, x.shape[-1]), targets.reshape(-1)
     pad = -xs.shape[0] % block
-    return (jnp.pad(xs, ((0, pad), (0, 0))),
+    flat = (jnp.pad(xs, ((0, pad), (0, 0))),
             jnp.pad(ts, (0, pad), constant_values=IGNORE_ID))
+    if weights is None:
+        return flat
+    return (*flat, jnp.pad(weights.reshape(-1), (0, pad)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _nll(x, head, targets, logits_scaling: float = 1.0):
+def _nll(x, head, targets, logits_scaling: float = 1.0, weights=None):
     """Summed cross-entropy over the positions of ``x`` (B, S, h) whose
     ``targets`` (B, S) are not ``IGNORE_ID``, against the ``head``
-    (h, vocab), its logits divided by ``logits_scaling``: what
-    ``_block_nll`` gives over all of them at once, walked a block of
-    tokens at a time. The backward makes a block's logits again, so
-    nothing (tokens, vocab) outlives a block.
+    (h, vocab), its logits divided by ``logits_scaling``, each position's
+    times its float32 ``weights`` (B, S) where there are any (they take
+    no gradient): what ``_block_nll`` gives over all of them at once,
+    walked a block of tokens at a time. The backward makes a block's
+    logits again, so nothing (tokens, vocab) outlives a block.
     """
-    return _nll_fwd(x, head, targets, logits_scaling)[0]
+    return _nll_fwd(x, head, targets, logits_scaling, weights)[0]
 
 
 # Jitted for their names' sake (models/bert.py:_masked_nll_fwd).
 @functools.partial(jax.jit, static_argnums=(3,))
-def _nll_fwd(x, head, targets, logits_scaling):
+def _nll_fwd(x, head, targets, logits_scaling, weights=None):
     block = head_block_size(x.shape[0] * x.shape[1])
     with jax.named_scope(HEAD_SCOPE):
-        xs, ts = _flat_padded(x, targets, block)
+        xs, ts, *ws = _flat_padded(x, targets, block, weights)
         head16 = head.astype(x.dtype)
 
     def add_block(k, total):
         with jax.named_scope(HEAD_SCOPE):
             return total + _block_nll(_block_of(xs, k, block), head16,
                                       _block_of(ts, k, block),
-                                      logits_scaling)
+                                      logits_scaling,
+                                      *(_block_of(w, k, block) for w in ws))
 
     total = jax.lax.fori_loop(0, xs.shape[0] // block, add_block,
                               jnp.float32(0))
-    return total, (x, targets, head16)
+    return total, (x, targets, head16, weights)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _nll_bwd(logits_scaling, residuals, cotangent):
-    x, targets, head16 = residuals
+    x, targets, head16, weights = residuals
     block = head_block_size(x.shape[0] * x.shape[1])
     with jax.named_scope(HEAD_SCOPE):
-        xs, ts = _flat_padded(x, targets, block)
+        xs, ts, *ws = _flat_padded(x, targets, block, weights)
         zeros = (jnp.zeros_like(xs), jnp.zeros(head16.shape, jnp.float32))
 
     def add_block(k, grads):
         d_xs, d_head = grads
         with jax.named_scope(HEAD_SCOPE):
             block_targets = _block_of(ts, k, block)
+            block_weights = [_block_of(w, k, block) for w in ws]
             _, vjp = jax.vjp(
-                lambda x, w: _block_nll(x, w, block_targets, logits_scaling),
+                lambda x, w: _block_nll(x, w, block_targets, logits_scaling,
+                                        *block_weights),
                 _block_of(xs, k, block), head16)
             dx, dw = vjp(cotangent)
             return (jax.lax.dynamic_update_slice_in_dim(
@@ -1737,7 +1931,7 @@ def _nll_bwd(logits_scaling, residuals, cotangent):
                                      zeros)
     with jax.named_scope(HEAD_SCOPE):
         d_x = d_xs[:x.shape[0] * x.shape[1]].reshape(x.shape)
-    return d_x, d_head, None
+    return d_x, d_head, None, None
 
 
 _nll.defvjp(_nll_fwd, _nll_bwd)
@@ -1751,11 +1945,79 @@ def next_token_targets(token_ids: jax.Array) -> jax.Array:
         axis=1)
 
 
+# Jitted for the scope's sake, as the attentions.
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _noised_beside_clean(token_ids, key, block: int, mask_id: int,
+                         eps: float):
+    with jax.named_scope(NOISE_SCOPE):
+        rows, length = token_ids.shape
+        level_key, mask_key = jax.random.split(key)
+        level = jax.random.uniform(level_key, (rows, length // block),
+                                   jnp.float32)
+        prob = jnp.repeat((1.0 - eps) * level + eps, block, axis=1)
+        masked = jax.random.uniform(mask_key, (rows, length),
+                                    jnp.float32) < prob
+        noised = jnp.where(masked, jnp.asarray(mask_id, token_ids.dtype),
+                           token_ids)
+        return (jnp.concatenate([token_ids, noised], axis=1), masked,
+                1.0 / prob)
+
+
+def diffusion_noise(config: DecoderConfig, token_ids: jax.Array,
+                    key: jax.Array):
+    """Block diffusion's forward process on rows ``token_ids`` (B, L):
+    ``(both (B, 2 L), masked (B, L), weights (B, L))``. Each block of
+    ``diffusion_block`` tokens draws a level ``t ~ U[0, 1)`` and each of
+    its tokens is masked independently with probability ``p = (1 - eps) t
+    + eps``; ``both`` is the clean row and then the row with its masked
+    tokens replaced by ``mask_token_id``, ``weights`` the float32 ``1 /
+    p`` of each position's block."""
+    if token_ids.shape[1] % config.diffusion_block:
+        raise ValueError(
+            f"rows of {token_ids.shape[1]} tokens are not whole blocks of "
+            f"{config.diffusion_block}")
+    return _noised_beside_clean(
+        token_ids, key, config.diffusion_block, config.mask_token_id,
+        config.diffusion_eps)
+
+
+def _diffusion_loss(config: DecoderConfig, params: Dict[str, Any],
+                    token_ids: jax.Array, mesh: Optional[Mesh],
+                    key: Optional[jax.Array]) -> jax.Array:
+    """Block diffusion's loss (module docstring) over the rows
+    ``token_ids`` (B, L), the noise drawn from ``key``. Every noised
+    position goes through the head and the unmasked ones are ignored;
+    the clean copy's last hidden states feed nothing and are not
+    projected. How many positions were masked and what their weights sum
+    to go out as the step's ``lm_noise``."""
+    if key is None:
+        raise ValueError("block diffusion draws its noise from a key: "
+                         "loss_fn(config, params, token_ids, mesh, key)")
+    token_ids = token_ids.astype(jnp.int32)
+    rows, length = token_ids.shape
+    both, masked, weights = diffusion_noise(config, token_ids, key)
+    x = _norm(config, decode(config, params, both, mesh)[:, length:],
+              params, "final_norm")
+    tracing.step_stat("lm_noise", jnp.stack(
+        [jnp.sum(masked, dtype=jnp.float32),
+         jnp.sum(jnp.where(masked, weights, 0.0))]))
+    head = params["embed"].T if config.tie_embeddings else params["head"]
+    total = _nll(x, head.astype(jnp.float32),
+                 jnp.where(masked, token_ids, IGNORE_ID),
+                 config.logits_scaling, weights)
+    return total / (rows * length)
+
+
 def loss_fn(config: DecoderConfig, params: Dict[str, Any],
-            token_ids: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
+            token_ids: jax.Array, mesh: Optional[Mesh] = None,
+            key: Optional[jax.Array] = None) -> jax.Array:
     """Mean next-token cross-entropy over the ``S - 1`` shifted positions
     of each row of ``token_ids`` (B, S), over this chip's slice of the
-    vocabulary. ``mesh`` is :func:`decode`'s."""
+    vocabulary; with ``diffusion_block``, block diffusion's loss over the
+    rows, its noise drawn from ``key`` (a step's own: an argument of the
+    program, not a constant of it). ``mesh`` is :func:`decode`'s."""
+    if config.diffusion_block:
+        return _diffusion_loss(config, params, token_ids, mesh, key)
     x = _norm(config, decode(config, params, token_ids, mesh), params,
               "final_norm")
     targets = next_token_targets(token_ids.astype(jnp.int32))

@@ -35,7 +35,11 @@ Two families, chosen by shape at trace time:
   structural mask, ``causal`` and ``window`` as static arguments (the
   inner dimension runs over the blocks some query of the outer block
   sees and no further; only the diagonal's and the window edge's blocks
-  compare positions), query heads in groups over fewer key/value heads
+  compare positions) or block diffusion's over a row read twice
+  (``diffusion``: a clean and a noised copy side by side, a noised query
+  seeing its own noised block and the clean blocks before it; the walk
+  crosses the gap between them in one step), query heads in groups over
+  fewer key/value heads
   (fetched once a block, repeated nowhere), values of a width and a head
   count of their own where key heads share them (differential
   attention's two maps a pair over values of 2 D: a map's scores are
@@ -253,16 +257,38 @@ def _bwd1_kernel(*refs, scale: float, heads: int, has_delta: bool,
 
 class _Mask(NamedTuple):
     """Which keys a query sees: all (the default), those at or before it
-    (``causal``), and of those the last ``window`` (itself included)."""
+    (``causal``), and of those the last ``window`` (itself included); or,
+    with ``block``, block diffusion's mask (Arriola et al. 2025) over a
+    row of ``2 x clean_len`` positions that holds a sequence twice, the
+    clean copy and then the noised one. With
+    ``blk(i) = (i mod clean_len) // block``: a clean query i sees the
+    clean keys j with ``blk(j) <= blk(i)``; a noised query i sees the
+    clean keys j with ``blk(j) < blk(i)`` and the noised keys j with
+    ``blk(j) == blk(i)``; nothing else."""
     causal: bool = False
     window: Optional[int] = None
+    block: int = 0
+    clean_len: int = 0
 
 
-def _mask_of(causal: bool, window: Optional[int]) -> _Mask:
+#: ``(block length, clean length)``.
+Diffusion = Tuple[int, int]
+
+
+def _mask_of(causal: bool, window: Optional[int],
+             diffusion: Optional[Diffusion] = None) -> _Mask:
     if window is not None and (not causal or window < 1):
         raise ValueError("a window is a causal mask's: pass causal=True and "
                          f"a window of at least 1 (got {causal=}, {window=})")
-    return _Mask(bool(causal), window)
+    if diffusion is None:
+        return _Mask(bool(causal), window)
+    block, clean_len = diffusion
+    if causal or block < 1 or clean_len % block:
+        raise ValueError(
+            "block diffusion's mask is neither causal nor a window's: pass "
+            "causal=False and a block length of at least 1 that divides the "
+            f"clean length (got {causal=}, {window=}, {diffusion=})")
+    return _Mask(False, None, int(block), int(clean_len))
 
 
 def _k_span(mask: _Mask, g, bq: int, bk: int, num_k: int, lo=min, hi=max):
@@ -289,8 +315,138 @@ def _longest(span, count: int) -> int:
     return max(last - first + 1 for first, last in map(span, range(count)))
 
 
+def _choose(condition, a, b):
+    """``jnp.where`` for Python's own numbers: the walk below is counted
+    with them before anything is traced."""
+    return a if condition else b
+
+
+def _half(block_index, per_half: int, where=jnp.where):
+    """``(1 where block ``block_index`` of ``per_half`` a copy lies in the
+    noised copy, the second, else 0; its index within its copy)``."""
+    second = block_index >= per_half
+    return (where(second, 1, 0),
+            where(second, block_index - per_half, block_index))
+
+
+def _diffusion_visit(mask: _Mask, g, t, bq: int, bk: int, where=jnp.where,
+                     lo=jnp.minimum):
+    """``(key block, whether the step is live)`` of step ``t`` of query
+    block ``g``'s walk under block diffusion's mask. A query block's live
+    key blocks are a run of clean ones from the first on (up to the one
+    that holds its own positions: whole before it, cut by the mask there)
+    and, for a noised query block, past a gap of dead ones the noised
+    blocks that hold its own positions, which the walk reaches in the step
+    after the run's last. A step past the walk names its last block
+    again."""
+    q_noised, gl = _half(g, mask.clean_len // bq, where)
+    # the last clean key a query of this block sees ends its own diffusion
+    # block (a clean query's) or the one before it (a noised query's)
+    run_last = ((gl + 1) * bq - 1 - q_noised * mask.block) // bk
+    own_first, own_last = gl * bq // bk, ((gl + 1) * bq - 1) // bk
+    steps = run_last + 1 + q_noised * (own_last - own_first + 1)
+    in_run = t <= run_last
+    local = where(in_run, t, where(
+        q_noised == 1, lo(own_first + t - run_last - 1, own_last), run_last))
+    k_noised = where(in_run, 0, q_noised)
+    return local + k_noised * (mask.clean_len // bk), t < steps
+
+
+def _steps(mask: _Mask, bq: int, bk: int, num_q: int, num_k: int) -> int:
+    """The innermost grid dimension's extent: the longest walk of a query
+    block over its live key blocks."""
+    if not mask.block:
+        return _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
+    return max(sum(_diffusion_visit(mask, g, t, bq, bk, _choose, min)[1]
+                   for t in range(num_k)) for g in range(num_q))
+
+
+def _walk(mask: _Mask, g, t, bq: int, bk: int, num_k: int):
+    """``(key block, live)`` of step ``t`` of query block ``g``'s walk;
+    ``live()`` says whether the step does anything (asked where a kernel
+    needs it: the causal walk's programs stay as they were traced)."""
+    if mask.block:
+        kb, live = _diffusion_visit(mask, g, t, bq, bk)
+        return kb, lambda: live
+    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
+    kb = first + t
+    return kb, lambda: kb <= last
+
+
+def _block_index(rows, block: int):
+    """``rows // block`` for a vector of positions: a shift where the
+    block length is a power of two."""
+    if block & (block - 1) == 0:
+        return jax.lax.shift_right_logical(rows, block.bit_length() - 1)
+    return rows // block
+
+
+def _diffusion_seen(mask: _Mask, g, kb, bq: int, bk: int):
+    """``_seen`` under block diffusion's mask, the block's two halves as
+    scalars: a key's diffusion block between two bounds off the query's."""
+    q_noised, gl = _half(g, mask.clean_len // bq)
+    k_noised, kl = _half(kb, mask.clean_len // bk)
+    per_tile = (bq // mask.block, bk // mask.block)
+    q_blk = gl * per_tile[0] + _block_index(
+        jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0), mask.block)
+    k_blk = kl * per_tile[1] + _block_index(
+        jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1), mask.block)
+    blocks = mask.clean_len // mask.block
+    # clean keys: up to the query's block (a clean query) or the one
+    # before (a noised one), from the first; noised keys: a noised query's
+    # own block alone, a clean query none
+    upper = q_blk - q_noised * (1 - k_noised)
+    lower = q_blk + blocks * (k_noised * (1 - q_noised) - (1 - k_noised))
+    return (k_blk <= upper) & (k_blk >= lower)
+
+
+def diffusion_seen(block: int, clean_len: int):
+    """Block diffusion's mask as a plain ``(2 L, 2 L)`` boolean array,
+    ``[i, j]``: query i sees key j (``_Mask``): for XLA's inline attention
+    and the tests."""
+    index = jnp.arange(2 * clean_len)
+    clean = index < clean_len
+    blk = (index % clean_len) // block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return ((q_clean & k_clean & (k_blk <= q_blk))
+            | (~q_clean & k_clean & (k_blk < q_blk))
+            | (~q_clean & ~k_clean & (k_blk == q_blk)))
+
+
+def _whole(mask: _Mask, g, kb, bq: int, bk: int, where=jnp.where):
+    """Whether every query of block ``g`` sees every key of the live block
+    ``kb`` under block diffusion's mask: a clean key block that ends
+    before the query block's first diffusion block does (a noised query
+    block's: begins)."""
+    q_noised, gl = _half(g, mask.clean_len // bq, where)
+    k_noised, kl = _half(kb, mask.clean_len // bk, where)
+    return ((k_noised == 0)
+            & ((kl + 1) * bk <= gl * bq + mask.block * (1 - q_noised)))
+
+
+def diffusion_tiles(block: int, clean_len: int, bq: int, bk: int
+                    ) -> Tuple[int, int, int]:
+    """``(tiles the walk visits, of them those that compare positions,
+    live pairs)`` of one head's attention under block diffusion's mask in
+    tiles of ``bq`` x ``bk``: what the kernels
+    do, counted from the shapes. The live pairs are the mask's own,
+    ``L^2 + block x L``, whatever the tiles."""
+    mask = _Mask(False, None, block, clean_len)
+    num_q, num_k = 2 * clean_len // bq, 2 * clean_len // bk
+    visited = compared = 0
+    for g in range(num_q):
+        for t in range(num_k):
+            kb, live = _diffusion_visit(mask, g, t, bq, bk, _choose, min)
+            visited += live
+            compared += live and not _whole(mask, g, kb, bq, bk, _choose)
+    return visited, compared, clean_len * (clean_len + block)
+
+
 def _seen(mask: _Mask, g, kb, bq: int, bk: int):
     """(bq, bk) booleans: query g * bq + r sees key kb * bk + c."""
+    if mask.block:
+        return _diffusion_seen(mask, g, kb, bq, bk)
     ahead = (g * bq - kb * bk
              + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
              - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
@@ -301,12 +457,15 @@ def _seen(mask: _Mask, g, kb, bq: int, bk: int):
 def _on_live(mask: _Mask, g, kb, live, bq: int, bk: int, step) -> None:
     """Run ``step(masked)`` for the (g, kb) block if it is live: unmasked
     where every query of it sees every key of it."""
-    if not mask.causal:
+    if mask.block:
+        whole = _whole(mask, g, kb, bq, bk)
+    elif not mask.causal:
         step(False)
         return
-    whole = (kb + 1) * bk - 1 <= g * bq
-    if mask.window is not None:
-        whole &= (g + 1) * bq - 1 - kb * bk < mask.window
+    else:
+        whole = (kb + 1) * bk - 1 <= g * bq
+        if mask.window is not None:
+            whole &= (g + 1) * bq - 1 - kb * bk < mask.window
     pl.when(live & whole)(lambda: step(False))
     pl.when(live & jnp.logical_not(whole))(lambda: step(True))
 
@@ -321,8 +480,7 @@ def _fwd_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
     bias_ref = refs[3] if has_bias else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[3 + has_bias:]
     g, t = pl.program_id(2), pl.program_id(3)
-    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
-    kb = first + t
+    kb, live = _walk(mask, g, t, bq, bk, num_k)
 
     @pl.when(t == 0)
     def _init():
@@ -348,7 +506,7 @@ def _fwd_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
         l_scr[:] = l_prev * corr + p.sum(axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + _dot(p.astype(v.dtype), v, _NN)
 
-    _on_live(mask, g, kb, kb <= last, bq, bk, step)
+    _on_live(mask, g, kb, live(), bq, bk, step)
 
     @pl.when(t == steps - 1)
     def _finish():
@@ -364,8 +522,7 @@ def _dq_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
     bias_ref = refs[3] if has_bias else None
     do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs[3 + has_bias:]
     g, t = pl.program_id(2), pl.program_id(3)
-    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
-    kb = first + t
+    kb, live = _walk(mask, g, t, bq, bk, num_k)
 
     @pl.when(t == 0)
     def _init():
@@ -383,7 +540,7 @@ def _dq_kernel(*refs, scale: float, has_bias: bool, mask: _Mask, bq: int,
         ds = p * (dp - delta_ref[...])
         dq_scr[:] = dq_scr[:] + _dot(ds.astype(k.dtype), k, _NN) * scale
 
-    _on_live(mask, g, kb, kb <= last, bq, bk, step)
+    _on_live(mask, g, kb, live(), bq, bk, step)
 
     @pl.when(t == steps - 1)
     def _finish():
@@ -463,8 +620,7 @@ def _bwd_kernel(*refs, scale: float, has_bias: bool, has_delta: bool,
     dbias_ref = rest[0] if has_bias else None
     dq_scr, delta_scr, dk_scr, dv_scr = rest[has_bias:]
     j, g, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum, jnp.maximum)
-    kb = first + t
+    kb, live = _walk(mask, g, t, bq, bk, num_k)
     keys = pl.ds(pl.multiple_of(kb * bk, bk), bk)
 
     def run_of(heads: int):
@@ -514,7 +670,7 @@ def _bwd_kernel(*refs, scale: float, has_bias: bool, has_delta: bool,
         dk_scr[keys, :] += _dot(ds, q, _TN) * scale
         dq_scr[...] += _dot(ds, k, _NN) * scale
 
-    _on_live(mask, g, kb, kb <= last, bq, bk, step)
+    _on_live(mask, g, kb, live(), bq, bk, step)
 
     @pl.when(t == steps - 1)
     def _finish():
@@ -585,6 +741,13 @@ def _plan(sq: int, sk: int, block_q: int, block_k: int, interpret: bool):
     bk = _pick_aligned_block(sk, block_k, 128)
     sk_pad = _round_up(sk, bk)
     return bq, bk, sq_pad, sk_pad
+
+
+def planned_blocks(seq: int, block_q: int, block_k: int,
+                   interpret: bool = False) -> Tuple[int, int]:
+    """The tiles ``(bq, bk)`` the blocked kernels cut a self-attention
+    over ``seq`` positions into, given the preferred ones."""
+    return _plan(seq, seq, block_q, block_k, interpret)[:2]
 
 
 def _pad_rows(x, target: int, axis: int = 2):
@@ -827,6 +990,22 @@ def _blocked_plan(mask: _Mask, bias, b: int, sq: int, sk: int, block_q: int,
         raise ValueError(f"a causal mask needs as many queries as keys, "
                          f"got {sq} and {sk}")
     bq, bk, sq_pad, sk_pad = _plan(sq, sk, block_q, block_k, interpret)
+    if mask.block:
+        if not sq == sk == 2 * mask.clean_len:
+            raise ValueError(
+                f"block diffusion's mask over {mask.clean_len} clean "
+                f"positions needs {2 * mask.clean_len} queries and as many "
+                f"keys, got {sq} and {sk}")
+        if ((sq_pad, sk_pad) != (sq, sk) or mask.clean_len % bq
+                or mask.clean_len % bk or bq % mask.block
+                or bk % mask.block):
+            raise ValueError(
+                f"tiles of {bq} x {bk} do not fit block diffusion's mask "
+                f"over {mask.clean_len} clean positions in blocks of "
+                f"{mask.block}: a tile lies in one copy (its sides divide "
+                "the clean length) and holds whole blocks (the block length "
+                "divides its sides); other block lengths need a walk that "
+                "cuts a block between tiles, which does not exist")
     bias_arr = (None if bias is None and mask.causal
                 else _prep_bias(bias, b, sk, sk_pad))
     return bq, bk, sq_pad, sk_pad, bias_arr
@@ -842,6 +1021,8 @@ def _query_major(mask: _Mask, dims: _Dims, bq: int, bk: int, num_k: int):
 
     def key_side(group: int):
         def at(i, j, g, t):
+            if mask.block:
+                return i, j // group, _diffusion_visit(mask, g, t, bq, bk)[0]
             first, last = _k_span(mask, g, bq, bk, num_k, jnp.minimum,
                                   jnp.maximum)
             return i, j // group, jnp.minimum(first + t, last)
@@ -874,7 +1055,7 @@ def _blocked_forward(q, k, v, bias, mask: _Mask, block_q: int, block_k: int,
     qp = _pad_rows(q, sq_pad, axis)
     kp, vp = _pad_rows(k, sk_pad, axis), _pad_rows(v, sk_pad, v_axis)
     num_q, num_k = sq_pad // bq, sk_pad // bk
-    steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
+    steps = _steps(mask, bq, bk, num_q, num_k)
     q_at, k_at, v_at = _query_major(mask, dims, bq, bk, num_k)
     in_specs = [_rows(packed, bq, d, q_at), _rows(packed, bk, d, k_at),
                 _rows(packed_v, bk, dv, v_at)]
@@ -964,6 +1145,12 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
             "dk/dv pair gathers dv a key head: it takes values of the "
             f"keys' width and head count, not {dims.hv} heads of {dv} "
             f"beside {hkv} of {d}")
+    if not fused and mask.block:
+        raise ValueError(
+            f"{sk_pad} keys' dk and dv do not fit in VMEM, and the dq + "
+            "dk/dv pair does not take block diffusion's mask: under it a "
+            "clean key block is seen by two runs of query blocks, and "
+            "``_dkv_kernel`` walks one")
     if delta is None and not fused:
         heads = (b, sq, h, dv) if packed_v else do.shape
         delta = _delta(do.reshape(heads), out.reshape(heads), True)
@@ -992,7 +1179,7 @@ def _blocked_backward(q, k, v, bias, out, lse, do, mask: _Mask,
     args = [qp, kp, vp] + ([bias_arr] if has_bias else []) \
         + [dop, _pad_rows(lse, sq_pad), aux]
     # Query-major: grid (B, H, num_q, live key blocks), K innermost.
-    steps = _longest(lambda g: _k_span(mask, g, bq, bk, num_k), num_q)
+    steps = _steps(mask, bq, bk, num_q, num_k)
     q_at, k_at, v_at = _query_major(mask, dims, bq, bk, num_k)
     dq_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
     dkv_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
@@ -1241,7 +1428,8 @@ def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
                     scale: Optional[float] = None, out_dtype=None,
-                    num_v_heads: Optional[int] = None):
+                    num_v_heads: Optional[int] = None,
+                    diffusion: Optional[Diffusion] = None):
     """Self-attention of projections as their matmuls leave them: ``q``
     (B, S, H x D), ``k`` (B, S, Hkv x D) and ``v`` (B, S, Hv x Dv), a
     head's columns side by side; query head h reads key head h // (H /
@@ -1257,10 +1445,13 @@ def grouped_forward(q, k, v, num_heads: int, num_kv_heads: int,
     ``out``, ``do`` and dv by Dv. ``scale`` multiplies
     the scores (``None``: 1 / sqrt(D)); ``out_dtype`` is ``out``'s
     (``None``: q's; float32 where what follows subtracts two outputs that
-    nearly cancel). Without a custom_vjp of its own: the caller pairs it
+    nearly cancel). ``diffusion`` = ``(block length, clean length)``
+    with ``causal`` off: block
+    diffusion's mask over a row that holds a sequence twice (``_Mask``).
+    Without a custom_vjp of its own: the caller pairs it
     with :func:`grouped_backward` under its own scope (models/mellum.py
     does)."""
-    mask = _mask_of(causal, window)
+    mask = _mask_of(causal, window, diffusion)
     hv, in_place, v_in_place = _value_heads(q, v, num_heads, num_kv_heads,
                                             num_v_heads, interpret)
     if not in_place:
@@ -1278,11 +1469,12 @@ def grouped_backward(q, k, v, out, lse, do, num_heads: int,
                      block_q: int = DEFAULT_BLOCK_Q,
                      block_k: int = DEFAULT_BLOCK_K, interpret: bool = False,
                      scale: Optional[float] = None,
-                     num_v_heads: Optional[int] = None):
+                     num_v_heads: Optional[int] = None,
+                     diffusion: Optional[Diffusion] = None):
     """``(dq, dk, dv)`` in the operands' layouts from
     :func:`grouped_forward`'s operands and results and the output's
     cotangent ``do`` (B, S, H x Dv)."""
-    mask = _mask_of(causal, window)
+    mask = _mask_of(causal, window, diffusion)
     hv, in_place, v_in_place = _value_heads(q, v, num_heads, num_kv_heads,
                                             num_v_heads, interpret)
     if not in_place:

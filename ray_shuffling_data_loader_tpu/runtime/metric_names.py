@@ -50,8 +50,9 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- a decoder layer's attention, its dense SwiGLUs and its
     #    sparse-expert layer (models/mellum.py; counted or set when a layer
     #    is traced, not when it runs; attention's kind = window | full |
-    #    inline, and window | full again for the layers whose checkpoint
-    #    keeps the forward kernel's results; a SwiGLU's = dense | shared,
+    #    diffusion | inline, and window | full again for the layers whose
+    #    checkpoint keeps the forward kernel's results; a SwiGLU's = dense |
+    #    shared,
     #    and again for the layers whose checkpoint keeps its two first
     #    products, beside the bytes the device's memory had for them; a
     #    first half whose checkpoint keeps its in-projections counts by
@@ -61,6 +62,13 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    router's scoring, softmax | sigmoid_bias) --
     "rsdl_lm_attention_total": ("counter", ("kind", "values")),
     "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
+    # -- what block diffusion's mask lets through and what the attention
+    #    kernels walk to cover it, one head of one layer (models/mellum.py;
+    #    set when a layer is traced; direction = forward | backward) --
+    "rsdl_lm_attention_tiles_visited": ("gauge", ("direction",)),
+    "rsdl_lm_attention_tiles_compared": ("gauge", ("direction",)),
+    "rsdl_lm_attention_tile_pairs": ("gauge", ("direction",)),
+    "rsdl_lm_attention_live_pairs": ("gauge", ("direction",)),
     "rsdl_lm_mlp_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_keep_room_bytes": ("gauge", ()),
@@ -100,6 +108,8 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_ssm_end_decay_mean": ("gauge", ("layer",)),
     "rsdl_ssm_carry_abs_max": ("gauge", ("layer",)),
     "rsdl_lm_diff_lambda": ("gauge", ("layer",)),
+    "rsdl_lm_noise_masked_positions": ("gauge", ()),
+    "rsdl_lm_noise_weight_sum": ("gauge", ()),
     # -- watchdog / stats (stats.py) --
     "rsdl_watchdog_events_total": ("counter", ()),
     "rsdl_watchdog_escalations_total": ("counter", ()),

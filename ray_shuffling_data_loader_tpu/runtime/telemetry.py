@@ -144,6 +144,7 @@ STEP_STAT_FIELDS: Dict[str, Tuple[str, ...]] = {
                  "fullest_expert_rows"),
     "ssm_scan": ("end_decay_mean", "carry_abs_max"),
     "diff_attention": ("lambda",),
+    "lm_noise": ("masked", "weight_sum"),
 }
 
 #: Upper bounds of ``rsdl_moe_tiles_per_step``: an even routing walks 128
@@ -945,12 +946,22 @@ def step_stats_folded(step: int,
     samples the step's tiles, all layers summed, into
     ``rsdl_moe_tiles_per_step`` (and the gauge of the last step's);
     ``ssm_scan`` sets each layer's two gauges, ``diff_attention`` each
-    layer's one."""
+    layer's one, ``lm_noise`` the step's two."""
     if not _ENABLED:
         return
     record("step_stats", step=step, stats=stats)
     metrics.counter("rsdl_step_stats_folded_total",
                     "train steps whose own counters reached the host").inc()
+    for row in stats.get("lm_noise", ()):
+        metrics.gauge("rsdl_lm_noise_masked_positions",
+                      "positions block diffusion's noise masked, the "
+                      "batch's rows summed, last folded step"
+                      ).set(row["masked"])
+        metrics.gauge("rsdl_lm_noise_weight_sum",
+                      "sum over the masked positions of their loss "
+                      "weights, one over a block's masking probability "
+                      "(its expectation is the batch's tokens), last "
+                      "folded step").set(row["weight_sum"])
     for row in stats.get("diff_attention", ()):
         metrics.gauge("rsdl_lm_diff_lambda",
                       "the scalar a differential attention layer subtracts "

@@ -635,6 +635,54 @@ def test_the_junctions_differential_layers_compile_for_the_chip(four_chips,
         value_dim=128) == "fused"
 
 
+def test_attention_under_block_diffusions_mask_compiles_for_the_chip(
+        four_chips):
+    """A layer's attention of ``sdar_train_8k`` through the TPU's own
+    compiler: one row of 16,384 positions (8,192 tokens twice), 32 query
+    heads over 4 key/value heads of 128, bf16, under block diffusion's
+    mask in blocks of 4, at the decoder's own tiles. Two Mosaic kernels, a
+    forward and one backward (16,384 keys' dk and dv fit in VMEM), no
+    (S, S) array, q, k, v and the output read and written where the
+    projections leave them; the mask's scalar walk, its shifts and its
+    comparisons are what interpret mode lets through unasked."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
+    cfg = mellum.sdar_30b_a3b_ep8_share()
+    heads, kv_heads, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    assert (heads, kv_heads, d, cfg.diffusion_block) == (32, 4, 128, 4)
+    length = 8192
+    diffusion = (cfg.diffusion_block, length)
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    q, k, v, do = (jax.ShapeDtypeStruct((1, 2 * length, n * d),
+                                        jnp.bfloat16, sharding=one_chip)
+                   for n in (heads, kv_heads, kv_heads, heads))
+
+    def both(q, k, v, do):
+        args = (heads, kv_heads, False, None)
+        out, lse = fa.grouped_forward(
+            q, k, v, *args, *mellum._blocks(None, False, diffusion),
+            diffusion=diffusion)
+        return out, fa.grouped_backward(
+            q, k, v, out, lse, do, *args,
+            *mellum._blocks(None, True, diffusion), diffusion=diffusion)
+
+    hlo = jax.jit(both).lower(q, k, v, do).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(r"\[\d+,\d+(,\d+)*,16384,16384\]", hlo)
+    assert not re.search(rf"\[1,\d+,16384,{d}\]", hlo), "no head-major copy"
+    assert fa.grouped_backward_kind(
+        q, k, heads, *mellum._blocks(None, True, diffusion)) == "fused"
+    bq, bk = fa.planned_blocks(2 * length,
+                               *mellum._blocks(None, False, diffusion))
+    visited, compared, live = fa.diffusion_tiles(4, length, bq, bk)
+    assert 100 * live / (visited * bq * bk) > 60, "no dead tile is walked"
+    assert compared < visited
+
+
 @pytest.mark.parametrize("in_vmem", [False, True],
                          ids=["einsums", "in_vmem"])
 def test_the_state_space_scan_compiles_for_the_chip_in_chunks(

@@ -252,11 +252,14 @@ def test_bert_mlm_loss_on_a_data_mesh_equals_one_device(mlm_f32):
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
 
 
-_SPARSE_CELLS = ["mellum_train_8k", "laguna_train_8k", "lfm2_train_8k"]
+_SPARSE_CELLS = ["mellum_train_8k", "laguna_train_8k", "lfm2_train_8k",
+                 "sdar_train_8k"]
 _DECODER_CELLS = ["mellum_train_8k", "laguna_train_8k", "granite_train_8k",
-                  "phi4flash_train_8k", "lfm2_train_8k"]
+                  "phi4flash_train_8k", "lfm2_train_8k", "sdar_train_8k"]
 _DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k",
                     "phi4flash_train_8k", "lfm2_train_8k"]
+# ``mellum_train_8k``'s reference has no ``proj_work`` and lists neither
+_PROJ_CELLS = _DENSE_MLP_CELLS + ["sdar_train_8k"]
 
 
 @pytest.mark.parametrize("name,scope,layer,cells", [
@@ -266,18 +269,20 @@ _DENSE_MLP_CELLS = ["laguna_train_8k", "granite_train_8k",
     ("lm_attention_pct", mellum.ATTENTION_SCOPE, "kernels", _DECODER_CELLS),
     ("lm_head_pct", mellum.HEAD_SCOPE, "model", _DECODER_CELLS),
     ("lm_mlp_pct", mellum.MLP_SCOPE, "model", _DENSE_MLP_CELLS),
-    ("lm_proj_pct", mellum.PROJ_SCOPE, "model", _DENSE_MLP_CELLS),
+    ("lm_proj_pct", mellum.PROJ_SCOPE, "model", _PROJ_CELLS),
     ("lm_ssm_pct", mellum.SSM_SCOPE, "kernels", ["granite_train_8k"]),
     ("lm_sscan_pct", mellum.SSCAN_SCOPE, "kernels", ["phi4flash_train_8k"]),
     ("lm_gmu_pct", mellum.GMU_SCOPE, "model", ["phi4flash_train_8k"]),
     ("lm_sconv_pct", mellum.SCONV_SCOPE, "kernels", ["lfm2_train_8k"]),
+    ("lm_noise_pct", mellum.NOISE_SCOPE, "model", ["sdar_train_8k"]),
 ])
 def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
                                                             layer, cells):
     """``mlm_head_pct`` (PR 29), ``attention_pct`` (PR 31), the decoder's
     three (PR 32), its dense SwiGLUs' and its projections' (PR 34), its
     state-space mixers' (PR 38), its selective scans' and its memory
-    units' (PR 40), its gated short convolutions' (PR 44) are data: the scope
+    units' (PR 40), its gated short convolutions' (PR 44), block
+    diffusion's noise (PR 47) are data: the scope
     reader ``grad_exchange_pct`` uses, pointed at a scope the model
     names, in the cells of that model's configurations that run the
     scope and in no other; a program without the scope (the parent's
@@ -307,7 +312,7 @@ def test_the_benchmarks_scope_shares_read_the_models_scopes(name, scope,
 
 
 @pytest.mark.parametrize("family", ["mellum", "laguna", "granite",
-                                    "phi4flash", "lfm2"])
+                                    "phi4flash", "lfm2", "sdar"])
 def test_the_decoders_builders_come_in_pairs(family):
     """Each decoder configuration has a builder at its published widths
     and a tiny one of the same pattern for the CPU: the same kinds of
@@ -321,6 +326,7 @@ def test_the_decoders_builders_come_in_pairs(family):
         "phi4flash": (mellum.phi4_mini_flash_junction,
                       mellum.phi4flash_tiny),
         "lfm2": (mellum.lfm2_24b_a2b_ep8_share, mellum.lfm2_tiny),
+        "sdar": (mellum.sdar_30b_a3b_ep8_share, mellum.sdar_tiny),
     }[family]
     full, tiny = full(), tiny()
     for cfg in (full, tiny):
@@ -334,10 +340,12 @@ def test_the_decoders_builders_come_in_pairs(family):
     for switch in ("qk_norm", "expert_bias", "router_trains",
                    "tie_embeddings", "rotary",
                    "differential", "attention_gate", "norm", "conv_taps",
-                   "published_layers", "rope_theta"):
+                   "published_layers", "rope_theta", "diffusion_block",
+                   "mask_token_id", "diffusion_eps"):
         assert getattr(tiny, switch) == getattr(full, switch), switch
     assert (tiny.yarn is None) == (full.yarn is None)
     assert tiny.hidden_size < full.hidden_size
+    assert bool(full.diffusion_block) == (family == "sdar")
     if family == "lfm2":
         assert full.layer_types == tiny.layer_types == (
             mellum.CONV, mellum.FULL, mellum.CONV, mellum.CONV, mellum.CONV)
