@@ -111,6 +111,9 @@ class SmokeSize:
     conv_shapes: Tuple[Tuple[int, int, int, int], ...]
     # a ``conv`` layer's gated short convolution: the same four
     sconv_shapes: Tuple[Tuple[int, int, int, int], ...]
+    # the placing of an attention half's q heads, normed and rotated:
+    # (rows, positions, heads, head width)
+    place_shapes: Tuple[Tuple[int, int, int, int], ...]
     epochs: int = 2
 
 
@@ -146,7 +149,9 @@ def full_size() -> SmokeSize:
         # granite_train_8k's xBC and phi4flash_train_8k's u
         conv_shapes=((1, 8192, 4352, 4), (1, 8192, 5120, 4)),
         # lfm2_train_8k's four conv operators
-        sconv_shapes=((2, 8192, 2048, 3),))
+        sconv_shapes=((2, 8192, 2048, 3),),
+        # sdar_train_8k's and lfm2_train_8k's q heads
+        place_shapes=((1, 16384, 32, 128), (2, 8192, 32, 64)))
 
 
 def tiny_size() -> SmokeSize:
@@ -168,7 +173,8 @@ def tiny_size() -> SmokeSize:
                     (48, 256, 8, 8, 2, 2, 8, 1.0)),     # rows of whole lanes
         ssd_shapes=((1, 256, 2, 64, 128, 128),),
         sscan_shapes=((1, 32, 1024, 4, 8),),
-        conv_shapes=((2, 64, 256, 4),), sconv_shapes=((2, 64, 256, 3),))
+        conv_shapes=((2, 64, 256, 4),), sconv_shapes=((2, 64, 256, 3),),
+        place_shapes=((2, 64, 2, 128), (2, 64, 4, 64)))
 
 
 # -- kernels ---------------------------------------------------------------
@@ -311,6 +317,8 @@ def kernels_phase(size: SmokeSize, interpret: bool) -> None:
         _check_conv(shape, interpret)
     for shape in size.sconv_shapes:
         _check_sconv(shape, interpret)
+    for shape in size.place_shapes:
+        _check_place(shape, interpret)
 
 
 def _packed(x):
@@ -781,6 +789,39 @@ def _check_sconv(shape: Tuple[int, ...], interpret: bool,
         lambda in_vmem: (sconv._gated_in_vmem if in_vmem
                          else sconv.gated_conv),
         (1, 2), sconv.convs_in_vmem(seq, channels, taps, bcu.dtype),
+        interpret, tol)
+
+
+def _check_place(shape: Tuple[int, ...], interpret: bool,
+                 tol: float = 1e-2) -> None:
+    """The placing of q heads, each head's RMSNorm and the rotary over
+    the whole of it, by its kernels (the projection read once each way,
+    ``d x`` and the scale's sums in one backward pass) against the same
+    written out in float32 with autodiff's backward, bf16 ``x``
+    (:func:`_check_scan_paths`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_shuffling_data_loader_tpu.models import mellum
+    from ray_shuffling_data_loader_tpu.ops import rope
+
+    rows, seq, heads, dim = shape
+    cfg = mellum.DecoderConfig(head_dim=dim, yarn=None)
+    cos, sin = mellum._rope_tables(cfg, mellum.FULL, seq)
+    keys = jax.random.split(jax.random.key(29), 3)
+    x = jax.random.normal(keys[0], (rows, seq, heads * dim), jnp.bfloat16)
+    scale = jax.random.uniform(keys[1], (dim,), minval=0.5, maxval=1.5)
+    eps = cfg.rms_norm_eps
+    _check_scan_paths(
+        f"placing of {rows} x {seq} positions' {heads} heads of {dim}",
+        "float32 passes", "out, d x, d scale", (x, scale),
+        jax.random.normal(keys[2], x.shape),
+        lambda in_vmem: (
+            (lambda x, scale: rope.placed_in_vmem(
+                x, scale, cos, sin, dim, eps, interpret)) if in_vmem
+            else (lambda x, scale: rope.placed_plain(
+                x, heads, cos, sin, dim, scale, eps))),
+        (1, 2), rope.places_in_vmem(seq, heads * dim, dim, dim, x.dtype),
         interpret, tol)
 
 

@@ -109,6 +109,11 @@ TPU-first choices:
   its band). Off the chip, and below the measured crossover, XLA's masked
   softmax over materialized scores. The head gate multiplies the kernel's
   output under the same scope.
+- The q and k heads are placed, each head's RMSNorm where the
+  configuration has one and the rotary, by one Pallas kernel each way on
+  the chip (``ops/rope.py``: the projection read once and written once,
+  the rotation two rolls of the lanes; heads of 128 or of 64), and by
+  XLA's float32 passes (``_head_norm``, ``_rope``) everywhere else.
 - Every layer is made again in the backward pass (``jax.checkpoint``), a
   half at a time: a half keeps its bf16 input, and an attention half
   whose attention the kernels compute also their two results, the ungated
@@ -148,7 +153,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_shuffling_data_loader_tpu.ops import (flash_attention, moe, on_tpu,
-                                               sconv, selective_scan, ssd)
+                                               rope, sconv, selective_scan,
+                                               ssd)
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
 from ray_shuffling_data_loader_tpu.utils import tracing
 
@@ -188,9 +194,11 @@ NOISE_SCOPE = "rsdl.lm.noise"
 # of ``sdar_train_8k``'s step ran under no scope, PERF.md section 5): a
 # norm of the residual stream or of the q and k heads, float32 inside
 # (``_norm``, ``_head_norm``), and the rotary over the q and k heads
-# (``_rope``), with the casts, copies and reshapes XLA makes for them.
+# (``_rope``), with the casts, copies and reshapes XLA makes for them. Where
+# the q and k heads are placed by ``ops/rope.py``'s kernels (PR 48) their
+# norms are in the kernels, under the second.
 NORM_SCOPE = "rsdl.lm.norm"
-ROPE_SCOPE = "rsdl.lm.rope"
+ROPE_SCOPE = rope.SCOPE
 
 # What an attention half's checkpoint keeps of the forward kernel
 # (``_flash_attention_fwd`` names them, ``decode``'s policy saves them).
@@ -1266,6 +1274,17 @@ def _head_norm(x, heads: int, scale, eps: float):
                          eps).reshape(b, s, width)
 
 
+def _count_place(in_vmem: bool) -> None:
+    # Counted when a layer is traced, not when it runs.
+    rt_metrics.counter(
+        "rsdl_lm_place_total",
+        "Rotary attention layers' q and k projections traced (two a layer), "
+        "by what places their heads, the heads' RMSNorm where there is one "
+        "and the rotary: a Pallas kernel each way that reads the projection "
+        "once (vmem) or XLA's float32 passes and autodiff (xla)",
+        kind="vmem" if in_vmem else "xla").inc()
+
+
 def _attention_half(config: DecoderConfig, layer: int, x, lp,
                     kept: bool = False):
     """x + attention(RMSNorm(x)), the first half of a layer; with
@@ -1279,8 +1298,18 @@ def _attention_half(config: DecoderConfig, layer: int, x, lp,
         rotated = rotated_dims(config, layer_type)
 
     def placed(projected, count, scale):
-        if config.qk_norm:
-            projected = _head_norm(projected, count, lp[scale],
+        scale = lp[scale] if config.qk_norm else None
+        if config.rotary:
+            in_vmem = rope.places_in_vmem(
+                projected.shape[1], projected.shape[2], config.head_dim,
+                rotated, projected.dtype)
+            _count_place(in_vmem)
+            if in_vmem:
+                return rope.placed_in_vmem(projected, scale, cos, sin,
+                                           rotated, config.rms_norm_eps,
+                                           not on_tpu())
+        if scale is not None:
+            projected = _head_norm(projected, count, scale,
                                    config.rms_norm_eps)
         return (_rope(projected, count, cos, sin, rotated) if config.rotary
                 else projected)

@@ -56,7 +56,8 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    and again for the layers whose checkpoint keeps its two first
     #    products, beside the bytes the device's memory had for them; a
     #    first half whose checkpoint keeps its in-projections counts by
-    #    the layer's kind, a ``layer_types`` entry; the
+    #    the layer's kind, a ``layer_types`` entry; a rotary layer's q and
+    #    its k count by what places their heads, vmem | xla; the
     #    expert layer's = share | all of the router's experts held here;
     #    ops/moe.py counts what moves the walk's rows, dma | xla, and the
     #    router's scoring, softmax | sigmoid_bias) --
@@ -73,6 +74,7 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_lm_mlp_kept_total": ("counter", ("kind",)),
     "rsdl_lm_mlp_keep_room_bytes": ("gauge", ()),
     "rsdl_lm_proj_kept_total": ("counter", ("kind",)),
+    "rsdl_lm_place_total": ("counter", ("kind",)),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_gather_total": ("counter", ("kind",)),
     "rsdl_moe_router_total": ("counter", ("kind",)),

@@ -64,6 +64,24 @@ def _chipbench_scratch_of_this_worker(tmp_path_factory):
     harness.SCRATCH_ROOT = checkout_scratch
 
 
+@pytest.fixture
+def placings():
+    """``placings()``: the q and k projections the decoder has traced
+    since the test began, by what places their heads
+    (``rsdl_lm_place_total``'s kinds: ops/rope.py's kernels, ``vmem``, or
+    XLA's passes, ``xla``). A test that wants the kernels off the chip
+    sets ``ops.rope.on_tpu`` true: ``models/mellum.py`` asks its own
+    ``on_tpu`` whether to interpret them, and that one stays false."""
+    from ray_shuffling_data_loader_tpu.runtime import metrics
+
+    def count(kind):
+        metric = metrics.get("rsdl_lm_place_total", {"kind": kind})
+        return 0 if metric is None else metric.value
+
+    before = {kind: count(kind) for kind in ("vmem", "xla")}
+    return lambda: {kind: count(kind) - was for kind, was in before.items()}
+
+
 def pytest_sessionfinish(session, exitstatus):
     if _LOCKSAN is not None and _LOCKSAN.installed():
         out = _LOCKSAN.dump()

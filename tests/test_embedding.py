@@ -818,6 +818,50 @@ def test_the_mixers_convolution_compiles_for_the_chip_as_two_kernels(
     assert compiled.memory_analysis().temp_size_in_bytes < 1e7
 
 
+@pytest.mark.parametrize("batch,seq,heads,dim,rotated,normed", [
+    (1, 16384, 32, 128, 128, True), (1, 16384, 4, 128, 128, True),
+    (4, 8192, 32, 128, 128, False), (2, 8192, 48, 128, 64, False),
+    (2, 8192, 32, 64, 64, True),
+], ids=["sdar_q", "sdar_k", "mellum_q", "laguna_full_q", "lfm2_q"])
+def test_the_placing_of_the_heads_compiles_for_the_chip_as_two_kernels(
+        four_chips, batch, seq, heads, dim, rotated, normed, monkeypatch):
+    """The rotary cells' q and k projections in bf16 through the TPU's own
+    compiler, placed forward and backward: one Mosaic call each way and no
+    float32 array of the projection's shape outside them (the widened
+    copy, the normed one, the turned one); heads of 64 ride two a
+    register."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.ops import rope
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    monkeypatch.setattr(rope, "on_tpu", lambda: True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    width = heads * dim
+    x = shape((batch, seq, width), jnp.bfloat16)
+    table = shape((seq, dim), jnp.float32)
+    assert rope.places_in_vmem(seq, width, dim, rotated, jnp.bfloat16)
+
+    def both(x, scale, cos, sin, dy):
+        out, vjp = jax.vjp(
+            lambda x, scale: rope.placed_in_vmem(x, scale, cos, sin, rotated,
+                                                 1e-6, False), x, scale)
+        return out, vjp(dy)
+
+    compiled = jax.jit(both).lower(
+        x, shape((dim,), jnp.float32) if normed else None, table, table,
+        x).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(rf"f32\[({batch},)?{seq},({width}|{heads},{dim})\]",
+                         hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e7
+
+
 @pytest.mark.parametrize("layer", [1, 3], ids=["window_512", "full"])
 def test_differential_attention_compiles_for_the_chip(four_chips, layer):
     """A differential layer of ``phi4flash_train_8k`` through the TPU's

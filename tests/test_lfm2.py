@@ -19,7 +19,7 @@ import pytest
 from chipbench.references import lfm2 as ref
 from ray_shuffling_data_loader_tpu.models import mellum
 from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
-from ray_shuffling_data_loader_tpu.ops import moe, sconv
+from ray_shuffling_data_loader_tpu.ops import moe, rope, sconv
 from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
 from ray_shuffling_data_loader_tpu.parallel import trainer as trainer_mod
 from ray_shuffling_data_loader_tpu.runtime import metric_names, metrics
@@ -653,6 +653,29 @@ def test_the_heads_are_normed_before_they_are_rotated(tiny):
     assert scale == 1.0 and mellum.rotated_dims(cfg, mellum.FULL) == 16
     # and YaRN's where a configuration has it, as before
     assert mellum.rope_inv_freq(mellum.laguna_tiny(), mellum.FULL)[1] > 1.0
+
+
+def test_where_the_chip_would_the_kernels_place_the_heads(placings,
+                                                          monkeypatch):
+    """Heads of 64 as the cell's, two a register, normed and rotated: with
+    ``ops.rope.on_tpu`` true the two kernels (interpreted) place the
+    attention layer's q and k, and the loss and every gradient are the
+    XLA passes' to float32's rounding."""
+    cfg = dataclasses.replace(_tiny_f32(), head_dim=64)
+    params = _seeded(cfg, _sizes(cfg), jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
+                                cfg.vocab_size, jnp.int32)
+    attention = sum(kind == mellum.FULL for kind in cfg.layer_types)
+    step = jax.value_and_grad(lambda p: mellum.loss_fn(cfg, p, tokens))
+    want_loss, want_grads = step(params)
+    assert placings() == {"vmem": 0, "xla": 2 * attention}
+    monkeypatch.setattr(rope, "on_tpu", lambda: True)
+    loss, grads = step(params)
+    assert attention == 1
+    assert placings() == {"vmem": 2 * attention, "xla": 2 * attention}
+    _assert_matches(loss, grads, want_loss, want_grads)
+    assert metric_names.METRIC_NAMES["rsdl_lm_place_total"] == (
+        "counter", ("kind",))
 
 
 # -- names in the registry and in a compiled step ----------------------------------

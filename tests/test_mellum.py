@@ -19,7 +19,7 @@ from chipbench.references import laguna as laguna_ref
 from chipbench.references import mellum as ref
 from ray_shuffling_data_loader_tpu.models import mellum
 from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
-from ray_shuffling_data_loader_tpu.ops import moe
+from ray_shuffling_data_loader_tpu.ops import moe, rope
 from ray_shuffling_data_loader_tpu.runtime import metric_names, metrics
 from tests.test_flash_attention import _pallas_calls
 
@@ -1260,6 +1260,47 @@ def test_scopes_and_counters_reach_the_compiled_step(tiny_f32):
     assert count("rsdl_moe_experts_routed") == 8
     assert count("rsdl_moe_top_k") == 2
     assert count("rsdl_moe_tile_rows") == 128
+
+
+@pytest.mark.parametrize("build", [_mellum_f32, _laguna_f32],
+                         ids=["mellum", "laguna_half_a_head"])
+def test_where_the_chip_would_the_kernels_place_the_heads(build, placings,
+                                                          monkeypatch):
+    """Heads of 128 as the cells', rotated and not normed (Laguna's full
+    layers over half a head under YaRN's tables, its window layers over
+    the whole of it, 6 or 8 heads a layer): with ``ops.rope.on_tpu`` true
+    the two kernels (interpreted) place every layer's q and k, the loss
+    and every gradient are the XLA passes' to float32's rounding, and the
+    compiled gradient names the kernels' operations under
+    ``rsdl.lm.rope`` in a form the benchmark's reader knows."""
+    from chipbench.readers import wrapped_scopes
+    reference, cfg = build()
+    cfg = dataclasses.replace(cfg, head_dim=128)
+    params = reference.init_params(_sizes(cfg, _SEQ), jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
+                                cfg.vocab_size, jnp.int32)
+    step = jax.value_and_grad(lambda p: mellum.loss_fn(cfg, p, tokens))
+    want_loss, want_grads = step(params)
+    assert placings() == {"vmem": 0, "xla": 2 * cfg.num_layers}
+    monkeypatch.setattr(rope, "on_tpu", lambda: True)
+    loss, grads = step(params)
+    assert placings() == {"vmem": 2 * cfg.num_layers,
+                          "xla": 2 * cfg.num_layers}
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=2e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
+        scale = max(float(jnp.max(jnp.abs(want))), 1e-30)
+        np.testing.assert_allclose(got / scale, want / scale, rtol=1e-4,
+                                   atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+    names = xplane.hlo_op_names(jax.jit(step).lower(
+        params).compile().as_text()).values()
+    placed = [name for name in names if mellum.ROPE_SCOPE in name]
+    assert any("transpose" in name for name in placed), "the backward's"
+    assert any("transpose" not in name for name in placed), "the forward's"
+    assert all(xplane.under_scope(wrapped_scopes.unwrapped(name),
+                                  mellum.ROPE_SCOPE) for name in placed)
+    assert not any("dot_general" in name for name in placed)
 
 
 @pytest.mark.parametrize("build,windows,fulls", [

@@ -21,7 +21,7 @@ import pytest
 from chipbench.references import sdar as ref
 from ray_shuffling_data_loader_tpu.models import mellum
 from ray_shuffling_data_loader_tpu.ops import flash_attention as fa
-from ray_shuffling_data_loader_tpu.ops import moe
+from ray_shuffling_data_loader_tpu.ops import moe, rope
 from ray_shuffling_data_loader_tpu.parallel import mesh as mesh_mod
 from ray_shuffling_data_loader_tpu.parallel import trainer as trainer_mod
 from ray_shuffling_data_loader_tpu.runtime import metrics
@@ -560,6 +560,38 @@ def test_differential_attention_under_the_mask_is_refused():
         mellum.decode(cfg, {}, jnp.zeros((1, 8), jnp.int32))
 
 
+# -- the q and k heads placed by ops/rope.py's kernels ---------------------------
+
+
+def test_where_the_chip_would_the_kernels_place_the_heads(placings,
+                                                          monkeypatch):
+    """Heads of 128 as the cell's, normed and rotated at a doubled row's
+    positions: with ``ops.rope.on_tpu`` true the two kernels (interpreted)
+    place every layer's q and k, and the loss and every gradient are the
+    XLA passes' to float32's rounding."""
+    cfg = dataclasses.replace(mellum.sdar_tiny(), compute_dtype=jnp.float32,
+                              head_dim=128)
+    params = _seeded(_sizes(cfg), jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, _SEQ), 4,
+                                cfg.vocab_size, jnp.int32)
+    step = jax.value_and_grad(lambda p: mellum.loss_fn(
+        cfg, p, tokens, None, jax.random.key(7)))
+    want_loss, want_grads = step(params)
+    assert placings() == {"vmem": 0, "xla": 2 * cfg.num_layers}
+    monkeypatch.setattr(rope, "on_tpu", lambda: True)
+    loss, grads = step(params)
+    assert placings() == {"vmem": 2 * cfg.num_layers,
+                          "xla": 2 * cfg.num_layers}
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=2e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), want in zip(flat, jax.tree.leaves(want_grads)):
+        scale = max(float(jnp.max(jnp.abs(want))), 1e-30)
+        np.testing.assert_allclose(got / scale, want / scale, rtol=1e-4,
+                                   atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+    assert float(jnp.max(jnp.abs(grads["layer_0"]["q_layernorm"]))) > 0
+
+
 # -- names in the registry, in the step's counters and in a compiled step -----
 
 
@@ -684,3 +716,15 @@ def test_the_older_configurations_trace_to_the_parents_programs(
     text = re.sub(r" at (/root/\S+|0x[0-9a-f]+)", "", text)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == _PARENT_PROGRAMS[builder, attention]
+
+
+@pytest.mark.parametrize("builder", ["granite_tiny", "phi4flash_tiny"])
+def test_a_decoder_without_positions_places_nothing(builder, placings,
+                                                    monkeypatch):
+    """No rotary, no norm on the heads: with ``ops.rope.on_tpu`` true as
+    on the chip the step traces to the parent's program all the same and
+    counts no placing of either kind."""
+    monkeypatch.setattr(rope, "on_tpu", lambda: True)
+    test_the_older_configurations_trace_to_the_parents_programs(
+        builder, "inline", monkeypatch)
+    assert placings() == {"vmem": 0, "xla": 0}
