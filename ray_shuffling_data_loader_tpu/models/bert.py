@@ -34,13 +34,24 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ray_shuffling_data_loader_tpu.ops import flash_attention, on_tpu
 from ray_shuffling_data_loader_tpu.parallel.mesh import DATA_AXIS
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 IGNORE_ID = -100
 
-# The names a device trace shows the head's and a layer's attention's
-# operations under.
-MLM_HEAD_SCOPE = "rsdl.bert.mlm_head"
-ATTENTION_SCOPE = "rsdl.bert.attention"
+# The names a device trace shows the step's operations under: the head's
+# (with the loss's count and division) and a layer's attention's; the
+# token and position embeddings with their layer norm and the mask's bias;
+# a layer's qkv and output products, and its FFN's two products and
+# activation, each with the residual add and layer norm that close its
+# half of the layer. The three below are entered outside any ``jit`` of
+# their own, so a forward operation's name holds them inside the
+# transform's, ``jvp(rsdl.bert.mlp)`` (``chipbench/readers/
+# wrapped_scopes.py`` reads both forms).
+MLM_HEAD_SCOPE = telemetry.step_scope("rsdl.bert.mlm_head")
+ATTENTION_SCOPE = telemetry.step_scope("rsdl.bert.attention")
+EMBED_SCOPE = telemetry.step_scope("rsdl.bert.embed")
+PROJ_SCOPE = telemetry.step_scope("rsdl.bert.proj")
+MLP_SCOPE = telemetry.step_scope("rsdl.bert.mlp")
 # The loss walks a row's masked positions in this many blocks at most: at
 # the paper's 15 % the fullest row of a batch ends in the second.
 _MLM_BLOCKS_PER_ROW = 8
@@ -263,31 +274,39 @@ def encode(config: BertConfig, params: Dict[str, Any],
     b, s = token_ids.shape
     nh = config.num_heads
 
-    x = (jnp.take(params["token_emb"], token_ids, axis=0, mode="clip")
-         + params["pos_emb"][:s][None, :, :]).astype(dtype)
-    x = _layer_norm(x, params["emb_ln"]["scale"], params["emb_ln"]["bias"])
+    with jax.named_scope(EMBED_SCOPE):
+        x = (jnp.take(params["token_emb"], token_ids, axis=0, mode="clip")
+             + params["pos_emb"][:s][None, :, :]).astype(dtype)
+        x = _layer_norm(x, params["emb_ln"]["scale"],
+                        params["emb_ln"]["bias"])
 
-    if attention_mask is None:
-        bias = None  # no mask: skip the zero-add (and any SP bias rotation)
-    else:
-        bias = jnp.where(attention_mask[:, None, None, :] > 0, 0.0,
-                         -1e9).astype(jnp.float32)
+        if attention_mask is None:
+            bias = None  # no mask: skip the zero-add (and any SP rotation)
+        else:
+            bias = jnp.where(attention_mask[:, None, None, :] > 0, 0.0,
+                             -1e9).astype(jnp.float32)
 
     def layer_fn(x, lp, bias):
-        qkv = x @ lp["qkv_w"].astype(dtype) + lp["qkv_b"].astype(dtype)
+        with jax.named_scope(PROJ_SCOPE):
+            qkv = x @ lp["qkv_w"].astype(dtype) + lp["qkv_b"].astype(dtype)
         if attention_fn is not None:
-            attended = _merge_heads(
-                attention_fn(*_split_heads(qkv, nh), bias))
+            with jax.named_scope(ATTENTION_SCOPE):
+                attended = _merge_heads(
+                    attention_fn(*_split_heads(qkv, nh), bias))
         else:
             attended = _attention(qkv, bias, nh, mesh)
-        attn_out = (attended @ lp["attn_out_w"].astype(dtype)
-                    + lp["attn_out_b"].astype(dtype))
-        x = _layer_norm(x + attn_out, lp["ln1"]["scale"], lp["ln1"]["bias"])
-        ffn = jax.nn.gelu(x @ lp["ffn_in_w"].astype(dtype)
-                          + lp["ffn_in_b"].astype(dtype))
-        ffn = (ffn @ lp["ffn_out_w"].astype(dtype)
-               + lp["ffn_out_b"].astype(dtype))
-        return _layer_norm(x + ffn, lp["ln2"]["scale"], lp["ln2"]["bias"])
+        with jax.named_scope(PROJ_SCOPE):
+            attn_out = (attended @ lp["attn_out_w"].astype(dtype)
+                        + lp["attn_out_b"].astype(dtype))
+            x = _layer_norm(x + attn_out, lp["ln1"]["scale"],
+                            lp["ln1"]["bias"])
+        with jax.named_scope(MLP_SCOPE):
+            ffn = jax.nn.gelu(x @ lp["ffn_in_w"].astype(dtype)
+                              + lp["ffn_in_b"].astype(dtype))
+            ffn = (ffn @ lp["ffn_out_w"].astype(dtype)
+                   + lp["ffn_out_b"].astype(dtype))
+            return _layer_norm(x + ffn, lp["ln2"]["scale"],
+                               lp["ln2"]["bias"])
 
     if config.remat:
         layer_fn = jax.checkpoint(layer_fn)
@@ -445,5 +464,6 @@ def loss_fn(config: BertConfig, params: Dict[str, Any],
     x = encode(config, params, token_ids, attention_mask, attention_fn, mesh)
     total = _masked_nll(x, params["token_emb"], params["mlm_bias"],
                         mlm_targets.astype(jnp.int32))
-    count = jnp.maximum(jnp.sum(mlm_targets != IGNORE_ID), 1)
-    return total / count
+    with jax.named_scope(MLM_HEAD_SCOPE):
+        count = jnp.maximum(jnp.sum(mlm_targets != IGNORE_ID), 1)
+        return total / count

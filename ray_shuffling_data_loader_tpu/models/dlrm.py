@@ -31,6 +31,17 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu.models import mlp as mlp_mod
 from ray_shuffling_data_loader_tpu.ops import embedding
+from ray_shuffling_data_loader_tpu.runtime import telemetry
+
+# The names a device trace shows the step's operations under: the loop of
+# embedding lookups, one a sparse column, with their backward (a table's
+# scatter-add; under a mesh the shards' exchange, which keeps its own name
+# inside this one: ``ops/embedding.py``); the Gram product, the triangle's
+# gather and the concatenation; the bottom and the top MLP, and the loss,
+# which fuses into the top MLP's last layer.
+LOOKUP_SCOPE = telemetry.step_scope("rsdl.dlrm.lookup")
+INTERACTION_SCOPE = telemetry.step_scope("rsdl.dlrm.interaction")
+MLP_SCOPE = telemetry.step_scope("rsdl.dlrm.mlp")
 
 # The reference DATA_SPEC's categorical cardinalities
 # (reference: data_generation.py:74-95): 17 embedding columns + 2 one-hots.
@@ -152,29 +163,33 @@ def apply(config: DLRMConfig, params: Dict[str, Any],
     # One embedding lookup per feature (ops/embedding.py picks the hardware
     # path per table size). Tables are stacked feature-wise afterwards.
     vectors = []
-    for i in range(config.num_sparse):
-        idx = sparse[i].reshape(-1) if is_columns else sparse[:, i]
-        vectors.append(
-            embedding.lookup(params["embeddings"][f"table_{i}"], idx,
-                             dtype, mode=config.lookup_mode, mesh=mesh))
+    with jax.named_scope(LOOKUP_SCOPE):
+        for i in range(config.num_sparse):
+            idx = sparse[i].reshape(-1) if is_columns else sparse[:, i]
+            vectors.append(
+                embedding.lookup(params["embeddings"][f"table_{i}"], idx,
+                                 dtype, mode=config.lookup_mode, mesh=mesh))
     if config.dense_dim > 0:
         bottom_cfg = _mlp_cfg(config.dense_dim, config.bottom_hidden,
                               config.embed_dim, dtype)
-        vectors.append(
-            mlp_mod.apply(bottom_cfg, params["bottom"],
-                          dense).astype(dtype))
-    stacked = jnp.stack(vectors, axis=1)  # (batch, F, embed_dim)
-    # Dot interaction: upper triangle of the F x F Gram matrix — one
-    # batched matmul on the MXU (the DLRM signature op).
-    gram = jnp.einsum("bfe,bge->bfg", stacked, stacked)
-    f = stacked.shape[1]
-    iu, ju = jnp.triu_indices(f, k=1)
-    interactions = gram[:, iu, ju]  # (batch, F*(F-1)/2)
-    first_order = jnp.mean(stacked, axis=1)  # (batch, embed_dim)
-    top_in = jnp.concatenate(
-        [interactions, first_order], axis=1).astype(dtype)
+        with jax.named_scope(MLP_SCOPE):
+            vectors.append(
+                mlp_mod.apply(bottom_cfg, params["bottom"],
+                              dense).astype(dtype))
+    with jax.named_scope(INTERACTION_SCOPE):
+        stacked = jnp.stack(vectors, axis=1)  # (batch, F, embed_dim)
+        # Dot interaction: upper triangle of the F x F Gram matrix — one
+        # batched matmul on the MXU (the DLRM signature op).
+        gram = jnp.einsum("bfe,bge->bfg", stacked, stacked)
+        f = stacked.shape[1]
+        iu, ju = jnp.triu_indices(f, k=1)
+        interactions = gram[:, iu, ju]  # (batch, F*(F-1)/2)
+        first_order = jnp.mean(stacked, axis=1)  # (batch, embed_dim)
+        top_in = jnp.concatenate(
+            [interactions, first_order], axis=1).astype(dtype)
     top_cfg = _mlp_cfg(config.top_in_dim, config.top_hidden, 1, dtype)
-    return mlp_mod.apply(top_cfg, params["top"], top_in)
+    with jax.named_scope(MLP_SCOPE):
+        return mlp_mod.apply(top_cfg, params["top"], top_in)
 
 
 def validate_sparse_batch(config: DLRMConfig, sparse) -> None:
@@ -215,6 +230,7 @@ def loss_fn(config: DLRMConfig, params: Dict[str, Any],
     """Sigmoid BCE-with-logits, mean over the batch. ``mesh`` as in
     :func:`apply`."""
     logits = apply(config, params, dense, sparse, mesh)
-    return jnp.mean(
-        jnp.maximum(logits, 0) - logits * labels
-        + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+    with jax.named_scope(MLP_SCOPE):
+        return jnp.mean(
+            jnp.maximum(logits, 0) - logits * labels
+            + jnp.log1p(jnp.exp(-jnp.abs(logits))))

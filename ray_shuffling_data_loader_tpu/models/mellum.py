@@ -156,6 +156,7 @@ from ray_shuffling_data_loader_tpu.ops import (flash_attention, moe, on_tpu,
                                                rope, sconv, selective_scan,
                                                ssd)
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 from ray_shuffling_data_loader_tpu.utils import tracing
 
 IGNORE_ID = -100
@@ -178,18 +179,18 @@ RMS_NORM, LAYER_NORM = "rms", "layer"
 # ``conv`` operator's two projections are under the first as well, its two
 # gates and the convolution between them (``ops/sconv.py``) under
 # ``SCONV_SCOPE``.
-PROJ_SCOPE = "rsdl.lm.proj"
-ATTENTION_SCOPE = "rsdl.lm.attention"
+PROJ_SCOPE = telemetry.step_scope("rsdl.lm.proj")
+ATTENTION_SCOPE = telemetry.step_scope("rsdl.lm.attention")
 SSM_SCOPE = ssd.SCOPE
 SSCAN_SCOPE = selective_scan.SCOPE
-GMU_SCOPE = "rsdl.lm.gmu"
+GMU_SCOPE = telemetry.step_scope("rsdl.lm.gmu")
 SCONV_SCOPE = sconv.SCOPE
 MOE_SCOPE = moe.SCOPE
-MLP_SCOPE = "rsdl.lm.mlp"
-HEAD_SCOPE = "rsdl.lm.head"
+MLP_SCOPE = telemetry.step_scope("rsdl.lm.mlp")
+HEAD_SCOPE = telemetry.step_scope("rsdl.lm.head")
 # Block diffusion's draw of the noise, the masking and the joining of the
 # clean and the noised copy into one row (``_noised_beside_clean``).
-NOISE_SCOPE = "rsdl.lm.noise"
+NOISE_SCOPE = telemetry.step_scope("rsdl.lm.noise")
 # The element-wise passes between the products and the kernels (PR 47: 30 %
 # of ``sdar_train_8k``'s step ran under no scope, PERF.md section 5): a
 # norm of the residual stream or of the q and k heads, float32 inside
@@ -197,8 +198,22 @@ NOISE_SCOPE = "rsdl.lm.noise"
 # (``_rope``), with the casts, copies and reshapes XLA makes for them. Where
 # the q and k heads are placed by ``ops/rope.py``'s kernels (PR 48) their
 # norms are in the kernels, under the second.
-NORM_SCOPE = "rsdl.lm.norm"
+NORM_SCOPE = telemetry.step_scope("rsdl.lm.norm")
 ROPE_SCOPE = rope.SCOPE
+# What the step runs outside the parts named above, so that every operation
+# of it has a name (``telemetry.STEP_SCOPES``; a trace bills an operation to
+# the innermost scope on its path): the token gather with its multiplier,
+# its cast and its scatter-add backward; around each half of a layer, what
+# the half runs between its named parts (the residual add and its
+# multiplier, the splits and reshapes between a projection and its
+# operator, a head gate's sigmoid, the rotary tables, the cotangents' sums);
+# around the loss, the targets, the noised half's slice, the count and the
+# division. The last two hold no product, kernel or loop of their own
+# (``tests/test_step_scopes.py``): a part added without a name of its own
+# turns that test red.
+EMBED_SCOPE = telemetry.step_scope("rsdl.lm.embed")
+LAYER_SCOPE = telemetry.step_scope("rsdl.lm.layer")
+LOSS_SCOPE = telemetry.step_scope("rsdl.lm.loss")
 
 # What an attention half's checkpoint keeps of the forward kernel
 # (``_flash_attention_fwd`` names them, ``decode``'s policy saves them).
@@ -1166,6 +1181,18 @@ def _mlp(kind: str, kept: bool, x, gate, up, down):
 # -- the decoder ---------------------------------------------------------------
 
 
+def _in_layer(half):
+    """``half`` (a layer's first or second half) under ``LAYER_SCOPE``:
+    what it runs between its named parts is the layer's."""
+
+    @functools.wraps(half)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(LAYER_SCOPE):
+            return half(*args, **kwargs)
+
+    return scoped
+
+
 def _rms_norm(x, scale, eps: float):
     xf = x.astype(jnp.float32)
     normed = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -1285,6 +1312,7 @@ def _count_place(in_vmem: bool) -> None:
         kind="vmem" if in_vmem else "xla").inc()
 
 
+@_in_layer
 def _attention_half(config: DecoderConfig, layer: int, x, lp,
                     kept: bool = False):
     """x + attention(RMSNorm(x)), the first half of a layer; with
@@ -1356,6 +1384,7 @@ def _conv_silu(conv, x, lp):
     return conv(x, lp["conv_w"], lp["conv_b"])
 
 
+@_in_layer
 def _conv_half(config: DecoderConfig, layer: int, x, lp,
                kept: bool = False):
     """x + ``(C * conv(B * u)) W_out`` with ``B | C | u = norm(x) W_in``,
@@ -1371,6 +1400,7 @@ def _conv_half(config: DecoderConfig, layer: int, x, lp,
         sconv.causal_gated_conv(bcu, lp["conv_w"]), lp["out_proj"]))
 
 
+@_in_layer
 def _mamba_half(config: DecoderConfig, layer: int, x, lp,
                 kept: bool = False):
     """x + Mamba-2(RMSNorm(x)), a ``mamba`` layer's first half: the two
@@ -1418,6 +1448,7 @@ def _shared(tensors, kind: str):
     return tensors
 
 
+@_in_layer
 def _differential_half(config: DecoderConfig, layer: int, x, lp, kv=None,
                        kept: bool = False):
     """``(x + differential attention(norm(x)), (k, v))``, a differential
@@ -1440,6 +1471,7 @@ def _differential_half(config: DecoderConfig, layer: int, x, lp, kv=None,
     return _added(config, x, _project(out, lp["wo"])), (k, v)
 
 
+@_in_layer
 def _mamba1_half(config: DecoderConfig, layer: int, x, lp,
                  kept: bool = False):
     """``(x + Mamba-1(norm(x)), y)``, a ``mamba1`` layer's first half and
@@ -1503,6 +1535,7 @@ def _gmu_gated_bwd(residuals, d_out):
 _gmu_gated.defvjp(_gmu_gated_fwd, _gmu_gated_bwd)
 
 
+@_in_layer
 def _gmu_half(config: DecoderConfig, layer: int, x, lp, memory,
               kept: bool = False):
     """x + ``(silu(norm(x) W_1) * M) W_2``, a ``gmu`` layer's first half:
@@ -1514,6 +1547,7 @@ def _gmu_half(config: DecoderConfig, layer: int, x, lp, memory,
     return _added(config, x, _project(mixed, lp["w2"]))
 
 
+@_in_layer
 def _mlp_half(config: DecoderConfig, layer: int, x, lp, kept: bool = False):
     """x + MLP(RMSNorm(x)), the second half of a layer: the dense SwiGLU,
     or the held experts' part of the routed sum and the shared expert.
@@ -1759,10 +1793,11 @@ def decode(config: DecoderConfig, params: Dict[str, Any],
             f"deployment; a mesh of {mesh.size} devices needs the expert "
             "layer's exchange across chips, which does not exist yet")
     _checked(config)
-    x = jnp.take(params["embed"], token_ids, axis=0, mode="clip")
-    if config.embedding_multiplier != 1.0:
-        x = x * config.embedding_multiplier
-    x = x.astype(config.compute_dtype)
+    with jax.named_scope(EMBED_SCOPE):
+        x = jnp.take(params["embed"], token_ids, axis=0, mode="clip")
+        if config.embedding_multiplier != 1.0:
+            x = x * config.embedding_multiplier
+        x = x.astype(config.compute_dtype)
     # Of an attention half, its bf16 input and the forward kernel's two
     # results are kept, where the kernels run: 272 MB a layer of 4 rows of
     # 8,192 tokens and 32 heads, against 19.5 ms (the whole triangle) or
@@ -2022,19 +2057,22 @@ def _diffusion_loss(config: DecoderConfig, params: Dict[str, Any],
     if key is None:
         raise ValueError("block diffusion draws its noise from a key: "
                          "loss_fn(config, params, token_ids, mesh, key)")
-    token_ids = token_ids.astype(jnp.int32)
-    rows, length = token_ids.shape
-    both, masked, weights = diffusion_noise(config, token_ids, key)
-    x = _norm(config, decode(config, params, both, mesh)[:, length:],
-              params, "final_norm")
-    tracing.step_stat("lm_noise", jnp.stack(
-        [jnp.sum(masked, dtype=jnp.float32),
-         jnp.sum(jnp.where(masked, weights, 0.0))]))
-    head = params["embed"].T if config.tie_embeddings else params["head"]
-    total = _nll(x, head.astype(jnp.float32),
-                 jnp.where(masked, token_ids, IGNORE_ID),
-                 config.logits_scaling, weights)
-    return total / (rows * length)
+    with jax.named_scope(LOSS_SCOPE):
+        token_ids = token_ids.astype(jnp.int32)
+        rows, length = token_ids.shape
+        both, masked, weights = diffusion_noise(config, token_ids, key)
+    x = decode(config, params, both, mesh)
+    with jax.named_scope(LOSS_SCOPE):
+        x = _norm(config, x[:, length:], params, "final_norm")
+        tracing.step_stat("lm_noise", jnp.stack(
+            [jnp.sum(masked, dtype=jnp.float32),
+             jnp.sum(jnp.where(masked, weights, 0.0))]))
+        head = (params["embed"].T if config.tie_embeddings
+                else params["head"])
+        total = _nll(x, head.astype(jnp.float32),
+                     jnp.where(masked, token_ids, IGNORE_ID),
+                     config.logits_scaling, weights)
+        return total / (rows * length)
 
 
 def loss_fn(config: DecoderConfig, params: Dict[str, Any],
@@ -2047,13 +2085,16 @@ def loss_fn(config: DecoderConfig, params: Dict[str, Any],
     program, not a constant of it). ``mesh`` is :func:`decode`'s."""
     if config.diffusion_block:
         return _diffusion_loss(config, params, token_ids, mesh, key)
-    x = _norm(config, decode(config, params, token_ids, mesh), params,
-              "final_norm")
-    targets = next_token_targets(token_ids.astype(jnp.int32))
-    # A tied head is the embedding's own matrix: the one leaf takes the
-    # gradient of both uses.
-    head = params["embed"].T if config.tie_embeddings else params["head"]
-    # The loss's backward sums the head's gradient in float32 and hands it
-    # back so: float32 weights pass as they are.
-    total = _nll(x, head.astype(jnp.float32), targets, config.logits_scaling)
-    return total / jnp.maximum(jnp.sum(targets != IGNORE_ID), 1)
+    x = decode(config, params, token_ids, mesh)
+    with jax.named_scope(LOSS_SCOPE):
+        x = _norm(config, x, params, "final_norm")
+        targets = next_token_targets(token_ids.astype(jnp.int32))
+        # A tied head is the embedding's own matrix: the one leaf takes the
+        # gradient of both uses.
+        head = (params["embed"].T if config.tie_embeddings
+                else params["head"])
+        # The loss's backward sums the head's gradient in float32 and hands
+        # it back so: float32 weights pass as they are.
+        total = _nll(x, head.astype(jnp.float32), targets,
+                     config.logits_scaling)
+        return total / jnp.maximum(jnp.sum(targets != IGNORE_ID), 1)

@@ -41,6 +41,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ray_shuffling_data_loader_tpu.ops import on_tpu
 from ray_shuffling_data_loader_tpu.parallel.mesh import DATA_AXIS
 from ray_shuffling_data_loader_tpu.runtime import metrics as rt_metrics
+from ray_shuffling_data_loader_tpu.runtime import telemetry
+
+#: The name a device trace shows the four-chip lookup's backward under: the
+#: shards' exchange of indices and cotangent rows and the scatter-add of
+#: all of them.
+GRAD_EXCHANGE_SCOPE = telemetry.step_scope("rsdl.embedding.grad_exchange")
 
 # Above this vocab size the one-hot matmul's wasted FLOPs and VMEM
 # pressure outgrow the gather's latency; 2048 keeps the one-hot tile
@@ -169,8 +175,7 @@ def _mesh_gather_rows_bwd(mesh, interpret, residual, cotangent):
     indices, vocab = residual
 
     def exchange(indices, cotangent):
-        # The name a device trace shows these ops under.
-        with jax.named_scope("rsdl.embedding.grad_exchange"):
+        with jax.named_scope(GRAD_EXCHANGE_SCOPE):
             indices = jax.lax.all_gather(indices, DATA_AXIS, tiled=True)
             cotangent = jax.lax.all_gather(cotangent, DATA_AXIS, tiled=True)
             return jnp.zeros((vocab, cotangent.shape[-1]),

@@ -72,7 +72,16 @@ from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 #: The name a device trace shows the layer's operations under: router,
 #: sort, grouped products and combine, forward and backward.
-SCOPE = "rsdl.lm.moe"
+SCOPE = telemetry.step_scope("rsdl.lm.moe")
+
+#: The name around the walk's two ``jit``s, so outside its loops: what XLA
+#: makes under the ``jit``'s or a ``while``'s own name and no ``with``
+#: inside them reaches (the zero-fills of the buffers a loop carries, the
+#: layout copies in and out of them, a loop's counter and bounds: 19.7 ms
+#: of ``sdar_train_8k``'s 460, PERF.md section 6, PR 49). A name of its
+#: own and not ``SCOPE``: a reader that sums ``SCOPE`` (``moe_pct``) would
+#: count each ``while`` and its body both.
+LOOPS_SCOPE = telemetry.step_scope("rsdl.lm.moe_loops")
 
 #: The most rows of one expert an even routing puts in a tile of the walk
 #: (``tile_rows``).
@@ -634,8 +643,9 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
         "over all the experts, or a sigmoid an expert picked under a "
         "selection bias and weighed without it",
         kind=SOFTMAX if bias is None else SIGMOID_BIAS).inc()
-    return _moe(x, router, gate, up, down, bias, held, top_k, tile, scale,
-                dma)
+    with jax.named_scope(LOOPS_SCOPE):
+        return _moe(x, router, gate, up, down, bias, held, top_k, tile,
+                    scale, dma)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
@@ -646,8 +656,9 @@ def _moe(x, router, gate, up, down, bias, held, top_k, tile, scale, dma):
 
 # Jitted for the scope's sake, as models/bert.py's head: inside a program
 # of its own (and inside a loop's body) the name reaches the compiled step
-# as written. The ``while`` instructions themselves carry no scope of the
-# program's, so nothing is counted twice.
+# as written. The ``while`` instructions themselves are outside ``SCOPE``
+# (under ``LOOPS_SCOPE`` alone, which no metric sums), so nothing is counted
+# twice.
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile, scale,
              dma):

@@ -36,10 +36,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_shuffling_data_loader_tpu.ops import on_tpu
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 #: The name a device trace shows the rotary under, XLA's passes and the
 #: kernels (which hold the heads' norms too).
-SCOPE = "rsdl.lm.rope"
+SCOPE = telemetry.step_scope("rsdl.lm.rope")
 
 _F32 = jnp.float32
 _LANES, _SUBLANES = 128, 8

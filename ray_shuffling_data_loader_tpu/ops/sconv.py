@@ -39,10 +39,11 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_shuffling_data_loader_tpu.ops import on_tpu, ssd
 from ray_shuffling_data_loader_tpu.ops.ssd import (_CONV_STRIP, _HALO,
                                                    _SUBLANES, _reaching)
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 #: The name a device trace shows the operator's gates and convolution
 #: under (the projections around them are the decoder's).
-SCOPE = "rsdl.lm.sconv"
+SCOPE = telemetry.step_scope("rsdl.lm.sconv")
 
 _F32 = jnp.float32
 

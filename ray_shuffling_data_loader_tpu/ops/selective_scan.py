@@ -44,10 +44,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_shuffling_data_loader_tpu.ops import on_tpu, ssd
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 #: The name a device trace shows the mixer's convolution, softplus, scan
 #: and gate under (the projections around them are the decoder's).
-SCOPE = "rsdl.lm.sscan"
+SCOPE = telemetry.step_scope("rsdl.lm.sscan")
 
 _F32 = jnp.float32
 _LANES, _SUBLANES = 128, 8

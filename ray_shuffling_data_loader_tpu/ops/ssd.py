@@ -56,10 +56,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_shuffling_data_loader_tpu.ops import on_tpu
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 
 #: The name a device trace shows the mixer's convolution, scan and gated
 #: norm under (the projections around them are the decoder's).
-SCOPE = "rsdl.lm.ssm"
+SCOPE = telemetry.step_scope("rsdl.lm.ssm")
 
 _F32 = jnp.float32
 

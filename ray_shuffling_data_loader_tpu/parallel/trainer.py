@@ -29,6 +29,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu.parallel.mesh import DATA_AXIS
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 from ray_shuffling_data_loader_tpu.utils import tracing
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
 
@@ -38,7 +39,7 @@ logger = setup_custom_logger(__name__)
 #: The name a device trace shows the optimizer's operations under: the
 #: transformation's update (Adam's moments) and its addition to the
 #: parameters.
-OPTIMIZER_SCOPE = "rsdl.train.optimizer"
+OPTIMIZER_SCOPE = telemetry.step_scope("rsdl.train.optimizer")
 
 
 def _moved(tree: Any, path: Tuple[str, ...], delta: Any) -> Any:

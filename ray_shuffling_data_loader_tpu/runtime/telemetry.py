@@ -121,6 +121,64 @@ SPAN_NAMES: Dict[str, str] = {
 #: the profiler group device operations by step. Profiler-only.
 STEP_ANNOTATION = "rsdl.trainer.step"
 
+#: THE device-scope vocabulary: every ``jax.named_scope`` the jitted train
+#: step runs under -> (PERF.md's layer, the module that enters it). A
+#: module names the scope it enters with :func:`step_scope`, which knows
+#: no other name. The scopes partition the step: a device trace bills each
+#: operation to the innermost scope on its ``op_name`` (TensorBoard's
+#: profile plugin over ``utils/tracing.profile_trace`` groups by these
+#: names; ``chipbench/readers/step_scopes.py`` tabulates them and reads
+#: what is left as ``step_unscoped_pct``). Three hold what a model runs
+#: outside its named parts and no product, kernel or loop of their own
+#: (``tests/test_step_scopes.py``): ``rsdl.lm.layer``, ``rsdl.lm.loss``
+#: (``models/mellum.py``) and ``rsdl.lm.moe_loops`` (``ops/moe.py``).
+#: What stays outside by nature: the containers (``while``,
+#: ``conditional``, ``call``: their bodies' operations are in the trace
+#: beside them, and a reader that sums a scope would count both) and the
+#: instructions XLA emits with no ``op_name`` (``copy-start`` / ``-done``,
+#: ``async-done``, ``slice-start`` / ``-done``). A scope has no off
+#: switch: it is a name on the compiled step's instructions and costs
+#: nothing at run time. PERF.md section 3 tabulates this list with what
+#: is under each scope and the metric that reads it.
+STEP_SCOPES: Dict[str, Tuple[str, str]] = {
+    "rsdl.train.optimizer": ("trainer", "parallel/trainer.py"),
+    "rsdl.embedding.grad_exchange": ("collectives", "ops/embedding.py"),
+    "rsdl.dlrm.lookup": ("kernels", "models/dlrm.py"),
+    "rsdl.dlrm.interaction": ("model", "models/dlrm.py"),
+    "rsdl.dlrm.mlp": ("model", "models/dlrm.py"),
+    "rsdl.bert.mask": ("model", "workloads/bert_mlm.py"),
+    "rsdl.bert.embed": ("model", "models/bert.py"),
+    "rsdl.bert.proj": ("model", "models/bert.py"),
+    "rsdl.bert.attention": ("kernels", "models/bert.py"),
+    "rsdl.bert.mlp": ("model", "models/bert.py"),
+    "rsdl.bert.mlm_head": ("model", "models/bert.py"),
+    "rsdl.lm.embed": ("model", "models/mellum.py"),
+    "rsdl.lm.layer": ("model", "models/mellum.py"),
+    "rsdl.lm.norm": ("model", "models/mellum.py"),
+    "rsdl.lm.proj": ("model", "models/mellum.py"),
+    "rsdl.lm.rope": ("model", "ops/rope.py"),
+    "rsdl.lm.attention": ("kernels", "models/mellum.py"),
+    "rsdl.lm.ssm": ("kernels", "ops/ssd.py"),
+    "rsdl.lm.sscan": ("kernels", "ops/selective_scan.py"),
+    "rsdl.lm.gmu": ("model", "models/mellum.py"),
+    "rsdl.lm.sconv": ("kernels", "ops/sconv.py"),
+    "rsdl.lm.mlp": ("model", "models/mellum.py"),
+    "rsdl.lm.moe": ("kernels", "ops/moe.py"),
+    "rsdl.lm.moe_loops": ("kernels", "ops/moe.py"),
+    "rsdl.lm.noise": ("model", "models/mellum.py"),
+    "rsdl.lm.head": ("model", "models/mellum.py"),
+    "rsdl.lm.loss": ("model", "models/mellum.py"),
+}
+
+
+def step_scope(name: str) -> str:
+    """``name``, a key of :data:`STEP_SCOPES`: how a module names a scope
+    it enters. A name the vocabulary lacks is a ``KeyError`` when the
+    module is imported."""
+    if name not in STEP_SCOPES:
+        raise KeyError(name)
+    return name
+
 
 #: The step's own counters (``utils/tracing.step_stat``): stat name ->
 #: the fields of the short vector the device computes for it each step.

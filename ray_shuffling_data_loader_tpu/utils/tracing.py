@@ -5,8 +5,11 @@ The program's stage spans are ``runtime/telemetry.span`` /
 recorder and, under its fixed ``rsdl.*`` name
 (``telemetry.SPAN_NAMES``), in the profiler's trace, so a captured trace
 shows the host pipeline stages on the same timeline as the XLA device
-operations. This module captures (:func:`profile_trace`); view with
-TensorBoard's profile plugin or Perfetto.
+operations, which carry the step's own scopes (``telemetry.STEP_SCOPES``:
+every operation of the jitted train step under one ``rsdl.*``
+``jax.named_scope``). This module captures (:func:`profile_trace`); view
+with TensorBoard's profile plugin, which groups device time by those
+names, or Perfetto.
 
 **The step's own counters.** What the device computes at run time and an
 operator wants to see (how many tiles the expert walk took this step)

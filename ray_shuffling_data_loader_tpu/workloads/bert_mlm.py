@@ -25,6 +25,7 @@ import pyarrow.parquet as pq
 
 from ray_shuffling_data_loader_tpu import workloads
 from ray_shuffling_data_loader_tpu.models.bert import IGNORE_ID
+from ray_shuffling_data_loader_tpu.runtime import telemetry
 from ray_shuffling_data_loader_tpu.utils.logger import setup_custom_logger
 
 logger = setup_custom_logger(__name__)
@@ -40,6 +41,9 @@ CLS_ID = 1
 SEP_ID = 2
 MASK_ID = 3
 NUM_SPECIAL_TOKENS = 4
+#: The name a device trace shows the dynamic masking's draws and selects
+#: under (:func:`mlm_mask`, inside the jitted step).
+MASK_SCOPE = telemetry.step_scope("rsdl.bert.mask")
 
 
 def generate_file(file_index: int, global_row_index: int, num_rows: int,
@@ -105,18 +109,19 @@ def mlm_mask(tokens,
     import jax
     import jax.numpy as jnp
 
-    select_key, action_key, random_key = jax.random.split(key, 3)
-    maskable = tokens >= num_special_tokens
-    selected = (jax.random.uniform(select_key, tokens.shape) < mask_prob) \
-        & maskable
-    action = jax.random.uniform(action_key, tokens.shape)
-    random_tokens = jax.random.randint(
-        random_key, tokens.shape, num_special_tokens, vocab_size,
-        dtype=tokens.dtype)
-    inputs = jnp.where(
-        selected & (action < 0.8), mask_token_id,
-        jnp.where(selected & (action >= 0.9), random_tokens, tokens))
-    targets = jnp.where(selected, tokens, IGNORE_ID)
+    with jax.named_scope(MASK_SCOPE):
+        select_key, action_key, random_key = jax.random.split(key, 3)
+        maskable = tokens >= num_special_tokens
+        selected = (jax.random.uniform(select_key, tokens.shape)
+                    < mask_prob) & maskable
+        action = jax.random.uniform(action_key, tokens.shape)
+        random_tokens = jax.random.randint(
+            random_key, tokens.shape, num_special_tokens, vocab_size,
+            dtype=tokens.dtype)
+        inputs = jnp.where(
+            selected & (action < 0.8), mask_token_id,
+            jnp.where(selected & (action >= 0.9), random_tokens, tokens))
+        targets = jnp.where(selected, tokens, IGNORE_ID)
     return inputs, targets
 
 
