@@ -600,8 +600,10 @@ def _check_moe(shape: Tuple[int, ...], interpret: bool,
 
 def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
     """The walk's two row kernels alone, over a tile's worth of ``x``'s
-    rows and a round's buffer in which a pick is live as often as an even
-    routing holds it: equal to XLA's gather of the same rows."""
+    rows and the first round of a random routing's walk: the fetch equal
+    to XLA's gather of the same rows, the combine by runs equal to XLA's
+    gathers' sums to float32's rounding (the same additions, a chunk of
+    the slab summed before it joins the chunks before it)."""
     import jax
     import jax.numpy as jnp
 
@@ -611,23 +613,27 @@ def _check_row_movers(shape: Tuple[int, ...], x, interpret: bool) -> None:
     rows = moe.round_rows(tokens, top_k, held, experts, tile)
     keys = jax.random.split(jax.random.key(12), 5)
     token = jnp.sort(jax.random.randint(keys[0], (tile,), 0, tokens))
-    index = jnp.where(
-        jax.random.bernoulli(keys[1], held / experts, (tokens, top_k)),
-        jax.random.randint(keys[2], (tokens, top_k), 0, rows), rows)
+    ids = jax.lax.top_k(jax.random.uniform(keys[1], (tokens, experts)),
+                        top_k)[1].astype(jnp.int32)
+    plan, position = moe._dispatch(ids, 0, held, tile)
+    runs = moe._runs(ids, plan, 0, held, tile, moe._block(tokens))
+    index = jnp.where((position >= 0) & (position < rows), position, rows)
     buffer = jax.random.normal(keys[3], (rows + tile, hidden),
                                x.dtype).at[rows:].set(0)
     acc = jax.random.normal(keys[4], (tokens, hidden))
     fetched = moe._fetch([moe._words(x)], token, x.dtype, interpret)[0]
     _check(bool(jnp.array_equal(fetched, x[token])),
            f"the row fetch differs from XLA's gather at {tokens}x{hidden}")
-    _check(bool(jnp.array_equal(
-        moe._combine_dma(acc, moe._words(buffer), jnp.sort(index, axis=1),
-                         rows, interpret),
-        moe._combined(acc, buffer, index, rows, False))),
-        f"the combine differs from XLA's gathers at {tokens}x{hidden}")
+    want = moe._combined(acc, buffer, index, rows)
+    got = moe._combine_runs(acc, buffer, index, rows, runs, 0, interpret)
+    gap = float(jnp.max(jnp.abs(got - want) / (1.0 + jnp.abs(want))))
+    _check(gap <= 1e-6,
+           f"the combine by runs differs from XLA's gathers' sums at "
+           f"{tokens}x{hidden}: {gap:.3e} > 1e-06")
     _info(f"kernels: expert layer's rows by DMA at {tokens}x{hidden} "
           f"({'interpreted' if interpret else 'Mosaic'}): a tile's fetch "
-          "and a round's combine equal to XLA's gather")
+          "equal to XLA's gather, a round's combine by runs to XLA's "
+          f"gathers' sums within {gap:.1e}")
 
 
 def _check_scan_paths(what: str, other: str, names: str, operands, mix,

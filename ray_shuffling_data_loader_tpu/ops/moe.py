@@ -33,22 +33,45 @@ and ``x U`` made again, eight products a tile, the experts' gradients
 added in float32 in place, the tokens' combined as the forward's sums.
 
 The rows move by DMA where they can (``rows_by_dma``: on the TPU, rows of
-whole lanes): a tile's rows in by ``_fetch``, one copy a row out of HBM,
-and the tokens' sums by ``_combine_dma``, one copy a LIVE pick, the
-float32 sums made in VMEM and written once. On a v5e a fetched row of
-4.6 KB takes 21 ns, 14 where two arrays are fetched off one index (the
-backward's ``x`` and ``dout``), and a token's sum 68 ns a live row at two
-live picks a token (102 at one: a block of 128 tokens costs 7 us whatever
-it fetches, a copy 35 ns of the scalar core's time; the HBM is not what
-bounds either). XLA's gather costs 45 ns a row inside the walk whatever
-the row holds, a dead pick's row of zeros too, and the parent of these
-kernels bent round that: it sorted the tokens by their count of live
-picks and gathered block by block, 2.3 rows a token for 2.0 live, and
-once more to put the sums back in order, 104 ns a live row (PERF.md
-section 6, PR 37, has all of it measured). Off the chip and at other shapes
-the fallback is the plain one: every pick's row gathered, the spare row
-of zeros for a dead pick, added in the same order, so the two paths agree
-bit for bit.
+whole lanes). A tile's rows come in by ``_fetch``, one copy a row out of
+HBM: on a v5e a fetched row of 4.6 KB takes 21 ns, 14 where two arrays are
+fetched off one index (the backward's ``x`` and ``dout``). The tokens' sums
+are made by ``_combine_runs`` (``sums_by_runs``): the sort above is stable,
+so the picks that the tokens of one block of 128 consecutive tokens send to
+one held expert lie in consecutive rows of the round's buffer, and a block
+needs one copy a whole tile of 16 bf16 rows that such a run touches, not
+one a live pick: 31 copies a block at ``mellum_train_8k``'s shapes (runs of
+16 rows; 255 live picks), 23 at ``sdar_train_8k``'s (8; 128), 37 at
+``laguna_train_8k``'s (4; 128), 11 at ``lfm2_train_8k``'s (8; 63). The
+copies land side by side in a slab in VMEM, a block ahead of the sums; a
+token's sum is a product on the MXU of a matrix of ones, where its picks
+lie, with the slab, 128 rows at a time; the float32 sums are written once.
+The scalar core tests no pick. Measured on a v5e, a round's combine alone,
+20 in a loop on the device with what XLA makes for it (``_landing``) and
+the loop's own copy of the sums (1.00 ms at the first shape, 0.46 at the
+others): 1.83 ms at ``mellum_train_8k``'s shapes, 0.75 at
+``sdar_train_8k``'s, 0.91 at ``laguna_train_8k``'s, 0.48 at
+``lfm2_train_8k``'s, 0.53 at runs of ONE row (32 of 256 experts, top-2: 16
+rows landed a live one), that is 28 / 46 / 56 / 60 / 128 ns a live row.
+What bounds it now is the HBM: the float32 sums read and written and the
+tiles landed, 2.3 MB a block for 1.2 MB of live rows at the first shape,
+2.4 for 0.5 at the third (PERF.md section 6, PR 50). Its parent,
+``_combine_dma`` (PR 37), issued one copy a LIVE pick of a one-row word
+array and read 3.55 / 1.21 / 1.20 / 0.83 / 0.72 ms in the same loop (68 ns
+a live row at two live picks a token, 102 at one, by PR 37's own measure: a
+block of 128 tokens cost it 7 us of tests and masked adds whatever it
+fetched, a copy 35 ns of the scalar core's time; the HBM bounded neither),
+and ITS parent XLA's gather: 45 ns a row inside the walk whatever the row
+holds, a dead pick's row of zeros too, bent round by sorting the tokens by
+their count of live picks, 104 ns a live row (PERF.md section 6, PR 37).
+Off the chip and at other shapes the fallback is the plain one: every
+pick's row gathered, the spare row of zeros for a dead pick, added in
+ascending order of the rows. The kernel makes the same float32 additions of
+the same rows, but a chunk of the slab is summed before it joins the chunks
+before it: equal to the gathers' sums to float32's rounding, and to the
+bit where the order cannot matter (two picks a token; rows whose sums
+float32 holds exactly, which bf16 rows of like size are: every shape above
+read equal to the bit on the chip).
 
 The grouped products are XLA's dense ``dot_general`` on a tile, not a
 Pallas kernel and not ``jax.lax.ragged_dot``: one expert a tile makes each
@@ -89,15 +112,12 @@ _TILE_LOAD = 1024
 
 _LANES, _SUBLANES = 128, 8
 
-#: Rows a grid step of the row kernels moves, all of their copies in
-#: flight at once.
+#: Rows a grid step of the fetch moves, all of their copies in flight at
+#: once, and tokens a grid step of the combine sums.
 _BLOCK = 128
 
-#: Tokens whose chains of tests an iteration of the combine's issue loop
-#: writes out: more make the kernel longer to lower (a process start pays
-#: that before it can even ask the compile cache), fewer leave the loop's
-#: own cost on each token.
-_UNROLL = 4
+#: Rows of the landing slab that one product of the runs' combine takes.
+_CHUNK = 128
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -216,6 +236,12 @@ def round_rows(tokens: int, top_k: int, count: int, experts: int,
     return min(worst, _round_up(even + even // 4, tile)) + count * tile
 
 
+def _held(ids, first: int, count: int):
+    """Each pick's held expert, from 0; ``count`` for an absent one's."""
+    local = ids - first
+    return jnp.where((local >= 0) & (local < count), local, count)
+
+
 def _dispatch(ids, first: int, count: int, tile: int):
     """The walk's plan from the picks ``ids`` (N, top_k). The pairs are
     sorted by held expert (pairs of absent experts last) and each held
@@ -224,8 +250,7 @@ def _dispatch(ids, first: int, count: int, tile: int):
     expert's pair count and start among them, and the running count of
     tiles by expert; ``position`` (N, top_k) is each pair's row in the
     padded order, -1 for an absent expert's."""
-    local = ids.reshape(-1) - first
-    expert = jnp.where((local >= 0) & (local < count), local, count)
+    expert = _held(ids.reshape(-1), first, count)
     order = jnp.argsort(expert, stable=True).astype(jnp.int32)
     # counted by comparison: a scatter-add of every pair into a handful
     # of bins is the serial kind
@@ -305,9 +330,10 @@ def _walk(plan, position, rows: int, tile: int, tile_fn, gather_fn, carry,
             r * per_round, jnp.minimum((r + 1) * per_round, tiles),
             one_tile, (carry, buffers))
         with jax.named_scope(SCOPE):
-            local = position - r * rows
+            first_row = r * rows
+            local = position - first_row
             index = jnp.where((local >= 0) & (local < rows), local, rows)
-        return gather_fn(carry, buffers, index), buffers
+        return gather_fn(carry, buffers, index, first_row), buffers
 
     return jax.lax.fori_loop(0, (tiles + per_round - 1) // per_round,
                              one_round, (carry, buffers))[0]
@@ -450,120 +476,224 @@ def _fetch(sources, index, dtype, interpret: bool):
     return [out[:rows] for out in outs]
 
 
-def _combine_dma(acc, buffer, index, spare: int, interpret: bool):
-    """:func:`_combined` by DMA. ``buffer`` holds rows of :func:`_words`;
-    ``index`` (N, top_k) is sorted along a token's picks, live first. A
-    grid step takes a block of tokens in their own order. It starts one
-    copy a LIVE pick, pick ``j`` of token ``t`` landing in row ``t`` of
-    slot ``j``: eight tokens an iteration, written out, each a chain of
-    tests that ends at its first dead pick (a dead pick costs nothing, a
-    token one scalar read and one test more than it has picks). Then as
-    many waits. Then eight tokens at a time, the slots are added up in
-    float32, slot by slot, under each token's mask (a slot's rows of
-    tokens with fewer picks hold what an earlier block left there), and
-    the block of ``acc`` plus the sums is written once, in ``acc``'s
+def _group(dtype) -> int:
+    """Rows of one tile of an array of ``dtype`` as it lies in HBM (8 of
+    float32, 16 of bfloat16, by 128 lanes): the least that one copy of
+    whole rows moves in one piece, whatever the array's width."""
+    return _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+
+
+def _runs(ids, plan, first: int, count: int, tile: int, block: int):
+    """The combine's plan, beside :func:`_dispatch`'s: ``(starts, counts,
+    expert)``. The sort is stable, so inside a held expert's run of the
+    padded order the pairs stand by ascending token, and the picks that
+    the tokens of one block of ``block`` consecutive tokens send to one
+    held expert lie in consecutive rows: ``starts`` and ``counts``
+    (blocks, count) are each such run's first padded row and its rows
+    (counted by comparison, as ``sizes`` is); ``expert`` (N, top_k) is
+    each pick's held expert, ``count`` for an absent one's."""
+    tokens = ids.shape[0]
+    _, sizes, _, tile_ends = plan
+    expert = _held(ids, first, count)
+    blocks = -(-tokens // block)
+    by_block = jnp.pad(expert, ((0, blocks * block - tokens), (0, 0)),
+                       constant_values=count).reshape(blocks, -1, 1)
+    counts = jnp.sum(by_block == jnp.arange(count), axis=1, dtype=jnp.int32)
+    padded_start = (tile_ends - (sizes[:count] + tile - 1) // tile) * tile
+    return (padded_start + jnp.cumsum(counts, axis=0) - counts, counts,
+            expert)
+
+
+def _landing(runs, index, first_row, rows: int, group: int, block: int):
+    """Where a round's runs land in a block's slab: ``(first_group,
+    groups, slot)``. A run is clipped to the round's ``rows`` (one may
+    straddle two rounds) and fetched as the ``groups`` whole groups of
+    ``group`` rows it touches, from group ``first_group`` of the buffer
+    on, the held experts' one after another in ascending order (both
+    (blocks x count,)); ``slot`` (blocks x block, top_k) is the slab's
+    row that each pick of ``index`` then lies in, -1 for a pick with
+    nothing in the round. One value a pick out of a table of (block,
+    expert), by comparison: XLA's gather of single values is the serial
+    kind."""
+    starts, counts, expert = runs
+    blocks, count = starts.shape
+    tokens, top_k = index.shape
+    low = jnp.clip(starts - first_row, 0, rows)
+    high = jnp.clip(starts + counts - first_row, 0, rows)
+    first_group = low // group
+    groups = jnp.where(high > low, (high - 1) // group - first_group + 1, 0)
+    shift = (jnp.cumsum(groups, axis=1) - groups - first_group) * group
+    pad = ((0, blocks * block - tokens), (0, 0))
+    of_pick = (jnp.pad(expert, pad, constant_values=count).reshape(
+        blocks, block, top_k, 1) == jnp.arange(count))
+    shift = jnp.sum(jnp.where(of_pick, shift[:, None, None, :], 0), axis=-1)
+    index = jnp.pad(index, pad, constant_values=rows)
+    slot = jnp.where(index < rows, index + shift.reshape(index.shape), -1)
+    return first_group.reshape(-1), groups.reshape(-1), slot
+
+
+def _slab_rows(block: int, top_k: int, count: int, group: int) -> int:
+    """Rows of the slab that holds whatever a block's runs land: a run of
+    ``n`` rows touches ``(n - 1) // group + 2`` groups at most, a token
+    picks an expert once (``n <= block``), and the block's runs hold
+    ``block x top_k`` rows between them; in whole chunks."""
+    groups = min(block * top_k // group + 2 * count,
+                 count * (block // group + 1))
+    return _round_up(groups * group, _CHUNK)
+
+
+def _slabs_bytes(tokens: int, hidden: int, dtype, top_k: int,
+                 count: int) -> int:
+    """Bytes of VMEM that the two halves of such a slab take."""
+    dtype = jnp.dtype(dtype)
+    return (2 * _slab_rows(_block(tokens), top_k, count, _group(dtype))
+            * hidden * dtype.itemsize)
+
+
+#: The most bytes that the two halves of the runs' landing slab may take
+#: of a v5e's 128 MiB of VMEM (`mellum_train_8k`'s take 14.2 MB,
+#: `laguna_train_8k`'s 16.8).
+_SLAB_BYTES = 48 << 20
+
+
+def sums_by_runs(tokens: int, hidden: int, tile: int, dtype, top_k: int,
+                 count: int) -> bool:
+    """Whether the tokens' sums are made by :func:`_combine_runs` and not
+    by XLA's gathers, from what the trace can see: where the rows move by
+    DMA (:func:`rows_by_dma`) and a slab of whatever a block's runs can
+    land fits VMEM twice."""
+    return (rows_by_dma(tokens, hidden, tile, dtype)
+            and _slabs_bytes(tokens, hidden, dtype, top_k, count)
+            <= _SLAB_BYTES)
+
+
+def _combine_runs(acc, buffer, index, spare: int, runs, first_row,
+                  interpret: bool):
+    """:func:`_combined` by runs. ``buffer`` holds the round's rows as
+    they are. A grid step takes a block of tokens. It starts one copy a
+    group of rows that a run of the block touches (:func:`_landing`),
+    whole groups side by side into a slab in VMEM, and a step ahead:
+    block ``i + 1``'s copies are started before block ``i``'s are waited
+    for, into the slab's other half. The rows of a group that are another
+    block's are landed and ignored (a zero times a row is zero while the
+    row is finite: a NaN in one token's row reaches the tokens of the
+    blocks that land it). Each token's sum is then a product on
+    the MXU, ``_CHUNK`` rows of the slab at a time: a matrix of ones where
+    ``slot`` says a pick of the token lies, times the rows (exact ones
+    times the rows' own values, float32 accumulation: the same float32
+    additions as :func:`_combined`'s, a chunk's made before it joins the
+    chunks before it). The scalar core tests no pick and issues a copy a
+    group, not a row. ``acc`` plus the sums is written once, in ``acc``'s
     place."""
     tokens, top_k = index.shape
-    packed = buffer.dtype != jnp.float32
-    lanes = buffer.shape[1]
-    pieces = lanes // _LANES
     hidden = acc.shape[1]
     block = _block(tokens)
-    padded = _round_up(tokens, block)
+    group = _group(buffer.dtype)
+    first_group, groups, slot = _landing(runs, index, first_row, spare,
+                                         group, block)
+    padded = slot.shape[0]
+    steps = padded // block
+    count = groups.shape[0] // steps
+    slab_rows = _slab_rows(block, top_k, count, group)
     if padded != tokens:
-        index = jnp.pad(index, ((0, padded - tokens), (0, 0)),
-                        constant_values=spare)
         acc = jnp.pad(acc, ((0, padded - tokens), (0, 0)))
-    # What the scalar core reads, a token a stride: its count of live
-    # picks, then their rows. Whole sublanes of a block of 1,024 words,
-    # which is how XLA lays a vector out in SMEM.
-    stride = _round_up(1 + top_k, _SUBLANES)
-    live = jnp.sum(index < spare, axis=1, keepdims=True, dtype=jnp.int32)
-    plan = jnp.pad(jnp.concatenate([live, index], axis=1),
-                   ((0, 0), (0, stride - 1 - top_k))).reshape(-1)
+    # float32 rows (no cell's: the tests') as they are, not rounded to
+    # the MXU's bf16
+    precision = (jax.lax.Precision.HIGHEST if buffer.dtype == jnp.float32
+                 else None)
 
-    def kernel(plan_ref, picks_ref, acc_ref, buffer_ref, out_ref, slots,
-               sem):
-        def copy(row, j, t):
+    def kernel(first_ref, groups_ref, slot_ref, acc_ref, buffer_ref,
+               out_ref, slab, landed, sems):
+        i = pl.program_id(0)
+
+        def copy(source, at, half):
             return pltpu.make_async_copy(
-                _row_of(buffer_ref, row), slots.at[j, :, pl.ds(t, 1), :],
-                sem)
+                buffer_ref.at[pl.ds(pl.multiple_of(source * group, group),
+                                    group), :],
+                slab.at[half, pl.ds(pl.multiple_of(at * group, group),
+                                    group), :],
+                sems.at[half])
 
-        def start(group, started):
-            for t in range(_UNROLL):        # written out: static strides
-                t = group * _UNROLL + t
-                live = plan_ref[t * stride]
-                started = started + live
+        def start(step, half):
+            def run(e, at):
+                source = first_ref[step * count + e]
+                n = groups_ref[step * count + e]
+                jax.lax.fori_loop(
+                    0, n, lambda k, _: copy(source + k, at + k,
+                                            half).start(), None)
+                return at + n
 
-                def picks_from(j, t=t, live=live):
-                    @pl.when(live > j)
-                    def _():
-                        copy(plan_ref[t * stride + 1 + j], j, t).start()
-                        if j + 1 < top_k:
-                            picks_from(j + 1)
+            landed[half] = jax.lax.fori_loop(0, count, run, jnp.int32(0))
 
-                picks_from(0)
-            return started
+        @pl.when(i == 0)
+        def _():
+            # what no copy lands may hold anything, a NaN's bits too, and
+            # a chunk's product reads whole chunks: zeros times zeros
+            def clear(c, _):
+                at = pl.ds(pl.multiple_of(c * _CHUNK, _CHUNK), _CHUNK)
+                for half in range(2):
+                    slab[half, at, :] = jnp.zeros((_CHUNK, hidden),
+                                                  slab.dtype)
 
-        started = jax.lax.fori_loop(0, block // _UNROLL, start,
-                                    jnp.int32(0))
+            jax.lax.fori_loop(0, slab_rows // _CHUNK, clear, None)
+            start(0, 0)
 
-        def wait(_, carry):
-            for _ in range(_SUBLANES):
-                copy(0, 0, 0).wait()
+        @pl.when(i + 1 < steps)
+        def _():
+            start(i + 1, (i + 1) % 2)
 
-        jax.lax.fori_loop(0, started // _SUBLANES, wait, None)
-        jax.lax.fori_loop(0, started % _SUBLANES,
-                          lambda _, c: copy(0, 0, 0).wait(), None)
+        half = i % 2
+        jax.lax.fori_loop(0, landed[half],
+                          lambda _, c: copy(0, 0, half).wait(), None)
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block, _CHUNK), 1)
 
-        def add(group, _):
-            rows = pl.ds(pl.multiple_of(group * _SUBLANES, _SUBLANES),
-                         _SUBLANES)
-            picks = picks_ref[rows, :]
-            masks = [jnp.broadcast_to(picks[:, j:j + 1] < spare,
-                                      (_SUBLANES, _LANES))
-                     for j in range(top_k)]
+        def add(c, _):
+            at = pl.ds(pl.multiple_of(c * _CHUNK, _CHUNK), _CHUNK)
+            picks = slot_ref[...] - c * _CHUNK
+            ones = picks[:, 0:1] == lane
+            for j in range(1, top_k):
+                ones = ones | (picks[:, j:j + 1] == lane)
+            out_ref[...] += jax.lax.dot_general(
+                jnp.where(ones, 1.0, 0.0).astype(slab.dtype),
+                slab[half, at, :], _NN, precision=precision,
+                preferred_element_type=jnp.float32)
 
-            def add_piece(piece, _):
-                sums = [jnp.zeros((_SUBLANES, _LANES), jnp.float32)
-                        for _ in range(2 if packed else 1)]
-                for j in range(top_k):
-                    words = slots[j, piece, rows, :]
-                    parts = _halves(words) if packed else (words,)
-                    sums = [s + jnp.where(masks[j], part, 0.0)
-                            for s, part in zip(sums, parts)]
-                for k, part in enumerate(sums):
-                    span = pl.ds(pl.multiple_of(
-                        k * lanes + piece * _LANES, _LANES), _LANES)
-                    out_ref[rows, span] = acc_ref[rows, span] + part
-
-            jax.lax.fori_loop(0, pieces, add_piece, None)
-
-        jax.lax.fori_loop(0, block // _SUBLANES, add, None)
+        jax.lax.fori_loop(
+            0, (landed[half] * group + _CHUNK - 1) // _CHUNK, add, None)
+        out_ref[...] = acc_ref[...] + out_ref[...]
 
     out = pl.pallas_call(
         kernel,
-        grid=(padded // block,),
-        in_specs=[
-            pl.BlockSpec((block * stride,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block, top_k), lambda i: (i, 0)),
-            pl.BlockSpec((block, hidden), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((block, hidden), lambda i: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((top_k, pieces, block, _LANES), buffer.dtype),
-            pltpu.SemaphoreType.DMA(())],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((block, top_k), lambda i, *_: (i, 0)),
+                pl.BlockSpec((block, hidden), lambda i, *_: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((block, hidden), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, slab_rows, hidden), buffer.dtype),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((padded, hidden), jnp.float32),
-        input_output_aliases={2: 0},
+        # acc is the fourth operand, after the two prefetched and slot
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            # a step starts the next one's copies: in order
+            dimension_semantics=("arbitrary",),
+            # the slabs, the blocks of the sums in and out, and room
+            vmem_limit_bytes=_slabs_bytes(tokens, hidden, buffer.dtype,
+                                          top_k, count)
+            + 8 * block * hidden * 4 + (8 << 20)),
         interpret=interpret,
-    )(plan, index, acc, _tiled(buffer))
+    )(first_group, groups, slot, acc, buffer)
     return out[:tokens]
 
 
 def _movable(a, dma: bool):
-    """``a``'s rows as what moves them takes them: words for the DMA, as
-    they are for XLA's gather."""
+    """The rows that a tile fetches, as what moves them takes them: words
+    for the DMA, as they are for XLA's gather."""
     return _words(a) if dma else a
 
 
@@ -575,16 +705,19 @@ def _taken(sources, index, dtype, dma: bool):
     return [source[index] for source in sources]
 
 
-def _combined(acc, buffer, index, spare: int, dma: bool):
+def _combined(acc, buffer, index, spare: int, runs=None, first_row=0):
     """``acc`` (N, hidden) float32 plus, for each token, the float32 sum
     of its picks' rows of ``buffer``, in ascending order of the rows;
     ``index`` (N, top_k) holds ``spare`` for a pick with nothing in the
-    buffer, which costs a DMA nothing and XLA's gather a row of zeros
-    (``spare`` is a row nobody writes)."""
+    buffer, which costs XLA's gather a row of zeros (``spare`` is a row
+    nobody writes) and the kernel nothing. Given the layer's ``runs``
+    (:func:`_runs`; ``first_row``: the round's first row of the padded
+    order) the rows move by :func:`_combine_runs`."""
     with jax.named_scope(SCOPE):
+        if runs is not None:
+            return _combine_runs(acc, buffer, index, spare, runs,
+                                 first_row, not on_tpu())
         index = jnp.sort(index, axis=1)       # live picks first: < spare
-        if dma:
-            return _combine_dma(acc, buffer, index, spare, not on_tpu())
         summed = jnp.zeros(acc.shape, jnp.float32)
         for j in range(index.shape[1]):
             summed = summed + buffer[index[:, j]].astype(jnp.float32)
@@ -630,6 +763,7 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
     where a ``jax.checkpoint`` makes the layer again.
     """
     dma = rows_by_dma(*x.shape, tile, x.dtype)
+    runs = sums_by_runs(*x.shape, tile, x.dtype, top_k, held[1])
     # Counted when a layer is traced, not when it runs.
     rt_metrics.counter(
         "rsdl_moe_gather_total",
@@ -638,6 +772,12 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
         "Pallas kernel, or XLA's gather",
         kind="dma" if dma else "xla").inc()
     rt_metrics.counter(
+        "rsdl_moe_combine_total",
+        "Sparse-expert layers traced, by what makes the tokens' sums of a "
+        "round's rows: whole runs of a block's rows landed in VMEM and a "
+        "product on the MXU, or XLA's gather of every pick's row",
+        kind="runs" if runs else "xla").inc()
+    rt_metrics.counter(
         "rsdl_moe_router_total",
         "Sparse-expert layers traced, by the router's scoring: softmax "
         "over all the experts, or a sigmoid an expert picked under a "
@@ -645,13 +785,14 @@ def moe_counted(x, router, gate, up, down, held: Tuple[int, int],
         kind=SOFTMAX if bias is None else SIGMOID_BIAS).inc()
     with jax.named_scope(LOOPS_SCOPE):
         return _moe(x, router, gate, up, down, bias, held, top_k, tile,
-                    scale, dma)
+                    scale, dma, runs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def _moe(x, router, gate, up, down, bias, held, top_k, tile, scale, dma):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _moe(x, router, gate, up, down, bias, held, top_k, tile, scale, dma,
+         runs):
     return _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile,
-                    scale, dma)[0]
+                    scale, dma, runs)[0]
 
 
 # Jitted for the scope's sake, as models/bert.py's head: inside a program
@@ -659,9 +800,9 @@ def _moe(x, router, gate, up, down, bias, held, top_k, tile, scale, dma):
 # as written. The ``while`` instructions themselves are outside ``SCOPE``
 # (under ``LOOPS_SCOPE`` alone, which no metric sums), so nothing is counted
 # twice.
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
 def _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile, scale,
-             dma):
+             dma, runs):
     first, count = held
     tokens, hidden = x.shape
     if gate.shape[0] != count:
@@ -675,8 +816,15 @@ def _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile, scale,
         g16, u16, d16 = (w.astype(x.dtype) for w in (gate, up, down))
         out = jnp.zeros((tokens, hidden), jnp.float32)
         x_rows = _movable(x, dma)
-        ys = _movable(jnp.zeros((rows + tile, hidden), x.dtype), dma)
+    # The carried buffer's zero-fill, as ever under ``LOOPS_SCOPE`` alone
+    # (its parent's, of words, lost the name to XLA's folding): only the
+    # spare tile's zeros are ever read, and only by XLA's gathers.
+    ys = jnp.zeros((rows + tile, hidden), x.dtype)
+    with jax.named_scope(SCOPE):
         walk = _walk_counts(plan, position, count, rows // tile)
+        # made where the combine reads it, and nowhere else
+        runs = (_runs(ids, plan, first, count, tile, _block(tokens))
+                if runs else None)
 
     def tile_fn(i, out, ys, at):
         expert, token, w = _tile(i, plan, flat, top_k, tile)
@@ -684,21 +832,22 @@ def _moe_fwd(x, router, gate, up, down, bias, held, top_k, tile, scale,
         h = (jax.nn.silu(_dot(xs, _of(g16, expert), _NN))
              * _dot(xs, _of(u16, expert), _NN))
         y = _dot(h.astype(x.dtype), _of(d16, expert), _NN) * w
-        return out, _put(ys, _movable(y.astype(x.dtype), dma), at)
+        return out, _put(ys, y, at)
 
-    def gather_fn(out, ys, index):
-        return _combined(out, ys, index, rows, dma)
+    def gather_fn(out, ys, index, first_row):
+        return _combined(out, ys, index, rows, runs, first_row)
 
     out = _walk(plan, position, rows, tile, tile_fn, gather_fn, out, ys)
     with jax.named_scope(SCOPE):
         out = out.astype(x.dtype)
     return (out, walk), (x, router, gate, up, down, bias, weights, plan,
-                         position)
+                         position, runs)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def _moe_bwd(held, top_k, tile, scale, dma, residuals, cotangents):
-    x, router, gate, up, down, bias, weights, plan, position = residuals
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _moe_bwd(held, top_k, tile, scale, dma, _, residuals, cotangents):
+    (x, router, gate, up, down, bias, weights, plan, position,
+     runs) = residuals
     dout = cotangents[0]            # the walk's counts have none
     tokens, hidden = x.shape
     rows = round_rows(tokens, top_k, held[1], router.shape[1], tile)
@@ -712,8 +861,8 @@ def _moe_bwd(held, top_k, tile, scale, dma, residuals, cotangents):
                  jnp.zeros(gate.shape, jnp.float32),
                  jnp.zeros(up.shape, jnp.float32),
                  jnp.zeros(down.shape, jnp.float32))
-        buffers = (_movable(jnp.zeros((rows + tile, hidden), x.dtype), dma),
-                   jnp.zeros((rows + tile, 1), jnp.float32))
+    buffers = (jnp.zeros((rows + tile, hidden), x.dtype),   # as ``ys``
+               jnp.zeros((rows + tile, 1), jnp.float32))
 
     def tile_fn(i, grads, buffers, at):
         d_x, d_weights, d_gate, d_up, d_down = grads
@@ -736,11 +885,10 @@ def _moe_bwd(held, top_k, tile, scale, dma, residuals, cotangents):
                  d_up.at[expert].add(_dot(xs, du, _TN)),
                  d_down.at[expert].add(
                      _dot((h * w).astype(x.dtype), dy, _TN))),
-                (_put(buffers[0], _movable(dxs.astype(x.dtype), dma), at),
-                 _put(buffers[1], dw, at)))
+                (_put(buffers[0], dxs, at), _put(buffers[1], dw, at)))
 
-    def gather_fn(grads, buffers, index):
-        d_x = _combined(grads[0], buffers[0], index, rows, dma)
+    def gather_fn(grads, buffers, index, first_row):
+        d_x = _combined(grads[0], buffers[0], index, rows, runs, first_row)
         with jax.named_scope(SCOPE):
             return d_x, grads[1] + buffers[1][index, 0], *grads[2:]
 

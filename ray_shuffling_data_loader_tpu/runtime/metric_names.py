@@ -59,8 +59,9 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     #    the layer's kind, a ``layer_types`` entry; a rotary layer's q and
     #    its k count by what places their heads, vmem | xla; the
     #    expert layer's = share | all of the router's experts held here;
-    #    ops/moe.py counts what moves the walk's rows, dma | xla, and the
-    #    router's scoring, softmax | sigmoid_bias) --
+    #    ops/moe.py counts what moves the walk's rows, dma | xla, what
+    #    makes the tokens' sums of them, runs | xla, and the router's
+    #    scoring, softmax | sigmoid_bias) --
     "rsdl_lm_attention_total": ("counter", ("kind", "values")),
     "rsdl_lm_attention_kept_total": ("counter", ("kind",)),
     # -- what block diffusion's mask lets through and what the attention
@@ -77,6 +78,7 @@ METRIC_NAMES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "rsdl_lm_place_total": ("counter", ("kind",)),
     "rsdl_moe_layer_total": ("counter", ("kind",)),
     "rsdl_moe_gather_total": ("counter", ("kind",)),
+    "rsdl_moe_combine_total": ("counter", ("kind",)),
     "rsdl_moe_router_total": ("counter", ("kind",)),
     "rsdl_moe_experts_held": ("gauge", ()),
     "rsdl_moe_experts_routed": ("gauge", ()),

@@ -862,6 +862,46 @@ def test_the_placing_of_the_heads_compiles_for_the_chip_as_two_kernels(
     assert compiled.memory_analysis().temp_size_in_bytes < 6e7
 
 
+@pytest.mark.parametrize("tokens,hidden,experts,count,top_k", [
+    (4 * 8192, 2304, 64, 16, 8), (2 * 8192, 2048, 128, 16, 8),
+    (2 * 8192, 2048, 256, 32, 8), (2 * 8192, 2048, 64, 8, 4),
+], ids=["mellum", "sdar", "laguna", "lfm2"])
+def test_the_combine_by_runs_compiles_for_the_chip(
+        four_chips, tokens, hidden, experts, count, top_k, monkeypatch):
+    """A round's combine at the four sparse cells' shapes through the
+    TPU's own compiler: one Mosaic call, the sums in the place of the
+    sums it is given, and both halves of the landing slab inside the VMEM
+    it asks for."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_shuffling_data_loader_tpu.ops import moe
+    one_chip = SingleDeviceSharding(four_chips.devices.flat[0])
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    tile = moe.tile_rows(tokens, top_k, experts)
+    rows = moe.round_rows(tokens, top_k, count, experts, tile)
+    blocks = tokens // moe._block(tokens)
+    assert moe.sums_by_runs(tokens, hidden, tile, jnp.bfloat16, top_k, count)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def combined(acc, buffer, index, starts, counts, expert, first_row):
+        return moe._combined(acc, buffer, index, rows,
+                             (starts, counts, expert), first_row)
+
+    picks, runs = (tokens, top_k), (blocks, count)
+    compiled = jax.jit(combined, donate_argnums=0).lower(
+        shape((tokens, hidden), jnp.float32),
+        shape((rows + tile, hidden), jnp.bfloat16), shape(picks, jnp.int32),
+        shape(runs, jnp.int32), shape(runs, jnp.int32),
+        shape(picks, jnp.int32), shape((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "output_to_operand_aliasing" in hlo
+    # nothing of the sums' size beside the sums
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e7
+
+
 @pytest.mark.parametrize("layer", [1, 3], ids=["window_512", "full"])
 def test_differential_attention_compiles_for_the_chip(four_chips, layer):
     """A differential layer of ``phi4flash_train_8k`` through the TPU's

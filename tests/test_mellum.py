@@ -331,9 +331,10 @@ def test_rows_moved_by_dma_move_no_bit(held, router, tile, scale, dtype,
                                        hidden, monkeypatch):
     """The same routings at rows of whole lanes, the walk's rows moved by
     the kernels (interpreted here) and by XLA's gather: a gather is a
-    copy and the combine keeps its order, so output and all five
-    gradients are equal bit for bit. A bf16 row travels as words of two
-    halves; ``every_pick_held`` takes a second round."""
+    copy and the combine adds the same rows in float32 (two picks a
+    token here, one sum whatever the order), so output and all five
+    gradients are equal bit for bit. A fetched bf16 row travels as words
+    of two halves; ``every_pick_held`` takes a second round."""
     x, router, weights, mix = _routed_layer(held, router, hidden, dtype)
     if tile is None:
         tile = moe.tile_rows(x.shape[0], _TOP_K, _EXPERTS)
@@ -383,48 +384,128 @@ def test_the_row_fetch_is_a_copy(dtype, hidden):
             np.testing.assert_array_equal(_bits(g), _bits(source[index]))
 
 
+@_ROUTINGS
+def test_the_plan_of_runs_is_what_the_positions_say(held, router, tile,
+                                                    scale):
+    """In blocks of eight tokens: the picks that a block's tokens send to
+    one held expert lie in consecutive rows of the padded order, by
+    ascending token, from the run's start on, and the runs of a block are
+    exactly its live picks."""
+    x, router, _, _ = _routed_layer(held, router)
+    if tile is None:
+        tile = moe.tile_rows(x.shape[0], _TOP_K, _EXPERTS)
+    ids, _ = moe.route(moe._router_logits(x, router), _TOP_K, scale)
+    plan, position = moe._dispatch(ids, *held, tile)
+    starts, counts, expert = (np.asarray(a) for a in moe._runs(
+        ids, plan, *held, tile, 8))
+    position = np.asarray(position)
+    assert starts.shape == counts.shape == (3, held[1])
+    assert np.array_equal(expert < held[1], position >= 0)
+    assert counts.sum() == (position >= 0).sum()
+    for block in range(3):
+        tokens = slice(8 * block, 8 * block + 8)
+        rows = []
+        for e in range(held[1]):
+            run = position[tokens][expert[tokens] == e]    # by token
+            first = starts[block, e]
+            assert run.tolist() == list(range(first,
+                                              first + counts[block, e]))
+            rows += run.tolist()
+        assert sorted(rows) == sorted(position[tokens][position[tokens]
+                                                       >= 0])
+
+
+def _round_of_runs(top_k, experts, held, tokens, dtype, hidden, round_):
+    """A routing's round as the combine takes it: the first block's tokens
+    pick ``top_k`` of ``experts`` at random, the second block's (where
+    there is one) none that is held, the rest at random again, and nobody
+    picks the second held expert."""
+    rng = np.random.default_rng(top_k)
+    first, count = held
+    open_to_all = [e for e in range(experts) if e != first + 1]
+    absent = [e for e in open_to_all if not first <= e < first + count]
+    ids = np.stack([rng.choice(absent if 128 <= t < 256 else open_to_all,
+                               top_k, replace=False)
+                    for t in range(tokens)]).astype(np.int32)
+    tile = 16
+    plan, position = moe._dispatch(jnp.asarray(ids), first, count, tile)
+    runs = moe._runs(jnp.asarray(ids), plan, first, count, tile,
+                     moe._block(tokens))
+    starts, counts, _ = (np.asarray(a) for a in runs)
+    # three rounds hold the tiles or more: the most rows a round whose
+    # first two edges both cut a run
+    rows = next(rows for rows in range(int(plan[3][-1]) // 3 * tile, 0,
+                                       -tile)
+                if all(((starts < edge) & (starts + counts > edge)).any()
+                       for edge in (rows, 2 * rows)))
+    local = np.asarray(position) - round_ * rows
+    index = np.where((local >= 0) & (local < rows), local, rows)
+    buffer = jax.random.normal(jax.random.key(round_), (rows + tile, hidden)
+                               ).astype(dtype)
+    return runs, rows, jnp.asarray(index), buffer
+
+
 @pytest.mark.parametrize("second_round", [False, True],
                          ids=["first_round", "second_round"])
+@pytest.mark.parametrize("top_k,experts,held,tokens", [
+    (8, 16, (4, 8), 264),       # three blocks, the last of eight tokens
+    (2, 8, (2, 4), 300)],       # the last of 44
+    ids=["eight_picks", "two_picks"])
 @pytest.mark.parametrize("dtype,hidden", [(jnp.float32, 128),
                                           (jnp.bfloat16, 256),
                                           (jnp.bfloat16, 768)],
                          ids=["f32x128", "bf16x256", "bf16x768"])
-def test_the_combine_adds_live_picks_in_row_order(dtype, hidden,
-                                                  second_round):
-    """A token's sum is its live picks' rows added in float32 in
-    ascending order of the rows, onto what an earlier round left: a token
-    with no live pick keeps it, one with all eight adds eight, a row may
-    be picked twice (by one token and by two), and the spare row is never
-    read (it holds no zeros here)."""
-    top_k, spare = 8, 48
-    buffer = jax.random.normal(jax.random.key(0), (spare + 8, hidden)
-                               ).astype(dtype)
-    rng = np.random.default_rng(0)
-    index = np.full((136, top_k), spare, np.int32)
-    index[1] = [5, 3, 47, 0, 9, 21, 22, 40]             # all eight live
-    index[2] = [spare, 7, spare, 7, spare, spare, 2, spare]   # a row twice
-    index[3, 5] = 7                                     # and in two tokens
-    for t in range(4, 136):                     # token 0 and others: none
-        live = rng.integers(0, 5)
-        index[t, rng.choice(top_k, live, replace=False)] = rng.integers(
-            0, spare, live)
-    acc = (jax.random.normal(jax.random.key(1), (136, hidden))
-           if second_round else jnp.zeros((136, hidden)))
-    ordered = np.sort(index, axis=1)
-    want = np.zeros((136, hidden), np.float32)
-    rows = _bits(buffer)
-    for j in range(top_k):
-        live = ordered[:, j] < spare
-        want[live] += rows[ordered[live, j]]
-    want = np.asarray(acc) + want
-    # XLA's side reads the spare row for a dead pick: zeros there
-    plain = moe._combined(acc, buffer.at[spare:].set(0), jnp.asarray(index),
-                          spare, False)
-    got = moe._combine_dma(acc, moe._words(buffer),
-                           jnp.sort(jnp.asarray(index), axis=1), spare, True)
-    np.testing.assert_array_equal(np.asarray(plain), want)
-    np.testing.assert_array_equal(np.asarray(got), want)
-    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(acc[0]))
+def test_the_combine_by_runs_is_the_sum_of_the_live_picks(
+        dtype, hidden, top_k, experts, held, tokens, second_round):
+    """The runs' kernel, interpreted, against XLA's gathers of the same
+    round: the same float32 additions of the same rows, a slab's chunk
+    summed before it joins the chunks before it, so equal to float32's
+    rounding (to the last bit where a token has two picks). Onto a sum
+    that is not zero; a block with no live pick and a token with none
+    keep theirs to the bit; an expert gets no row; runs start inside a
+    group of rows and one straddles the round's edge; what lies past the
+    round's rows is never read (NaNs here, zeros on XLA's side)."""
+    round_ = int(second_round)
+    runs, rows, index, buffer = _round_of_runs(top_k, experts, held, tokens,
+                                               dtype, hidden, round_)
+    starts, counts, _ = (np.asarray(a) for a in runs)
+    group = moe._group(dtype)
+    edge = (round_ + 1) * rows
+    assert ((starts < edge) & (starts + counts > edge)).any()
+    assert ((counts > 0) & (starts % group != 0)).any()
+    assert (counts[:, 1] == 0).all() and (counts[:, 0] > 0).any()
+    acc = jax.random.normal(jax.random.key(2), (tokens, hidden))
+    want = moe._combined(acc, buffer.at[rows:].set(0), index, rows)
+    got = moe._combined(acc, buffer.at[rows:].set(jnp.nan), index, rows,
+                        runs, round_ * rows)
+    assert got.dtype == jnp.float32 and got.shape == acc.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=4e-6)
+    live = np.asarray(index < rows).sum(axis=1)
+    assert (live == 0).any() and not live[128:256].any()
+    np.testing.assert_array_equal(np.asarray(got)[live == 0],
+                                  np.asarray(acc)[live == 0])
+    if top_k == 2:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        assert live.max() > 2       # sums of several rows
+
+
+def test_the_runs_slab_holds_whatever_a_block_lands():
+    """A run of ``n`` rows from any row on touches ``(n - 1) // group +
+    2`` groups at most; the slab is sized for the block whose every pick
+    is held, in whole chunks."""
+    for top_k, count, group in ((8, 16, 16), (8, 32, 16), (4, 8, 16),
+                                (2, 2, 8), (8, 256, 16)):
+        slab = moe._slab_rows(128, top_k, count, group)
+        assert slab % moe._CHUNK == 0
+        # the fullest: every expert's run starts on a group's last row
+        for runs in ([128 * top_k // count] * count,
+                     [128] * top_k + [0] * (count - top_k)):
+            assert sum(runs) <= 128 * top_k
+            touched = sum((group - 1 + n - 1) // group + 1
+                          for n in runs if n)
+            assert touched * group <= slab
 
 
 def test_what_moves_the_rows_follows_the_shapes(monkeypatch):
@@ -442,6 +523,32 @@ def test_what_moves_the_rows_follows_the_shapes(monkeypatch):
     assert not moe.rows_by_dma(24, 256, 8, jnp.float16)
     assert not moe.rows_by_dma(20, 128, 8, jnp.float32)
     assert not moe.rows_by_dma(24, 128, 12, jnp.float32)
+
+
+def test_what_makes_the_sums_follows_the_shapes(monkeypatch):
+    """By runs on the chip at every cell's shapes (the rows move by DMA
+    and two slabs of whatever a block can land fit VMEM: 14.2 MB at
+    ``mellum_train_8k``'s, 16.8 at ``laguna_train_8k``'s), by XLA's
+    gathers off it, where the rows do not move by DMA and where the slabs
+    would not fit."""
+    cells = {"mellum": (4 * 8192, 2304, 1152, jnp.bfloat16, 8, 16),
+             "sdar": (2 * 8192, 2048, 1152, jnp.bfloat16, 8, 16),
+             "laguna": (2 * 8192, 2048, 640, jnp.bfloat16, 8, 32),
+             "lfm2": (2 * 8192, 2048, 1152, jnp.bfloat16, 4, 8)}
+    assert moe.tile_rows(2 * 8192, 8, 128) == moe.tile_rows(
+        2 * 8192, 4, 64) == 1152
+    assert not any(moe.sums_by_runs(*cell) for cell in cells.values())
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    assert all(moe.sums_by_runs(*cell) for cell in cells.values())
+    assert moe.sums_by_runs(24, 128, 8, jnp.float32, 2, 2)
+    assert not moe.sums_by_runs(24, 16, 8, jnp.float32, 2, 2)   # no DMA
+    # every one of 256 experts of rows of 8,192 held: 300 MB of slabs
+    assert moe.rows_by_dma(2 * 8192, 8192, 640, jnp.bfloat16)
+    assert not moe.sums_by_runs(2 * 8192, 8192, 640, jnp.bfloat16, 8, 256)
+    slabs = {name: 2 * moe._slab_rows(128, cell[4], cell[5], 16) * cell[1]
+             * 2 for name, cell in cells.items()}
+    assert slabs == {"mellum": 14_155_776, "sdar": 12_582_912,
+                     "laguna": 16_777_216, "lfm2": 6_291_456}
 
 
 def test_the_walks_tile_follows_the_shapes():
@@ -1357,6 +1464,34 @@ def test_the_gather_counter_tells_dma_from_xla(build, chip, hidden, dma,
                    jnp.zeros((1, 16), jnp.int32))
     assert (count("dma") - before[0], count("xla") - before[1]) == (
         (sparse, 0) if dma else (0, sparse))
+
+
+@pytest.mark.parametrize("chip,hidden,runs", [
+    (False, 256, False),    # every CPU run
+    (True, 256, True),      # the chip: whole groups of bf16 rows
+    (True, 64, False)],     # rows that no DMA moves
+    ids=["cpu", "chip", "chip_narrow_rows"])
+@pytest.mark.parametrize("build", [mellum.mellum_tiny, mellum.laguna_tiny],
+                         ids=["mellum", "laguna"])
+def test_the_combine_counter_tells_runs_from_xla(build, chip, hidden, runs,
+                                                 monkeypatch):
+    """One count a sparse layer traced, by what makes the tokens' sums of
+    a round's rows: nothing but the backend and the shapes chooses."""
+    monkeypatch.setattr(moe, "on_tpu", lambda: chip)
+    cfg = dataclasses.replace(build(), hidden_size=hidden)
+    sparse = sum(cfg.mlp_type(i) == mellum.SPARSE
+                 for i in range(cfg.num_layers))
+
+    def count(kind):
+        metric = metrics.get("rsdl_moe_combine_total", {"kind": kind})
+        return 0 if metric is None else metric.value
+
+    before = count("runs"), count("xla")
+    jax.eval_shape(lambda p, t: mellum.loss_fn(cfg, p, t),
+                   mellum.init(cfg, jax.random.key(0)),
+                   jnp.zeros((1, 16), jnp.int32))
+    assert (count("runs") - before[0], count("xla") - before[1]) == (
+        (sparse, 0) if runs else (0, sparse))
 
 
 @pytest.mark.parametrize("build", [mellum.mellum_tiny, mellum.laguna_tiny],
